@@ -311,8 +311,7 @@ def main():
     rounds = args.rounds or (2 if args.smoke else 4)
 
     from deepspeed_tpu.utils.compile_cache import setup_compile_cache
-    setup_compile_cache(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+    setup_compile_cache()
 
     ok = True
     results = {}

@@ -353,8 +353,7 @@ def preempt_worker(args):
     from deepspeed_tpu.elasticity import compute_elastic_config
     from deepspeed_tpu.utils.compile_cache import setup_compile_cache
 
-    setup_compile_cache(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+    setup_compile_cache()
     world = jax.device_count()
     final_batch, _valid, micro = compute_elastic_config(
         {"elasticity": PREEMPT_ELASTIC}, world_size=world,
@@ -965,8 +964,7 @@ def main():
     import jax
     on_tpu = jax.default_backend() not in ("cpu",)
     from deepspeed_tpu.utils.compile_cache import setup_compile_cache
-    setup_compile_cache(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+    setup_compile_cache()
     if args.trace_overhead:
         # even in smoke mode the ratio needs a few interleaved reps — a
         # single 8-step pair on 2 shared cores measures the scheduler

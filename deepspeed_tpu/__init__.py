@@ -11,11 +11,6 @@ over ICI/DCN instead of NCCL, Pallas kernels instead of CUDA.
 __version__ = "0.1.0"
 version = __version__
 
-from deepspeed_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.apply()
-del _jax_compat
-
 from deepspeed_tpu.config import DeepSpeedTPUConfig, ConfigError
 from deepspeed_tpu import comm
 from deepspeed_tpu import ops  # noqa: F401

@@ -169,6 +169,12 @@ def get_topology() -> MeshTopology:
     return _TOPOLOGY
 
 
+def topology_if_set() -> Optional[MeshTopology]:
+    """The ambient topology, or None where nothing has set one (unlike
+    :func:`get_topology`, this never builds a default over every device)."""
+    return _TOPOLOGY
+
+
 def reset_topology():
     global _TOPOLOGY
     _TOPOLOGY = None

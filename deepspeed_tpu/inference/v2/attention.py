@@ -113,6 +113,14 @@ class AttentionKernelSpec:
         here COMPOSES: int8 KV pages run under the prefix cache, spec
         decode, preempt-offload and the cross-engine page fabric (the PR
         that collapsed those three former refusals into this table)."""
+        tp = cfg.tensor_parallel
+        if tp > 1 and (spec.num_heads % tp or spec.num_kv_heads % tp):
+            raise ValueError(
+                f"tensor_parallel={tp} does not divide num_heads="
+                f"{spec.num_heads} and num_kv_heads={spec.num_kv_heads}: "
+                "the paged kernels shard whole heads over the 'tensor' "
+                "axis, and the engine does not fall back to tp=1 — pick a "
+                "tensor_parallel that divides both")
         if cfg.kv_quant.enabled:
             if cfg.tensor_parallel > 1:
                 raise NotImplementedError(

@@ -2,7 +2,7 @@
 onto the steady-state decode hot path (docs/SERVING.md "Speculative
 decoding").
 
-Decode is memory-bound (bench_full: hbm_frac 0.62 on MHA-32): every decode
+Decode is memory-bound: every decode
 step streams the full model from HBM to emit ONE token per sequence. This
 subsystem makes each step pay for up to ``k + 1`` tokens instead:
 

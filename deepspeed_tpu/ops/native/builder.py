@@ -4,20 +4,23 @@ The TPU analog of the reference's ``op_builder`` JIT system
 (``op_builder/builder.py:108`` ``OpBuilder.load()`` which lazily compiles
 ``csrc/`` extensions via ``torch.utils.cpp_extension``): here a single C++17
 translation unit is compiled with ``g++`` on first use and cached next to the
-source; loading is via ``ctypes`` (no pybind11 in this environment). Every
-consumer degrades gracefully to a pure-Python path when no compiler exists, the
-same way reference builders report ``is_compatible() == False``.
+source, under a name keyed by source hash and host; loading is via ``ctypes``
+(no pybind11 in this environment). Every consumer degrades gracefully to a
+pure-Python path when no compiler exists, the same way reference builders
+report ``is_compatible() == False``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
 import threading
 from typing import Optional
 
+from deepspeed_tpu.utils.compile_cache import host_fingerprint
 from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.threads import make_lock
 
@@ -25,7 +28,6 @@ _SRC = os.path.join(os.path.dirname(__file__), "csrc", "ds_native.cpp")
 _BUILD_DIR = os.environ.get(
     "DS_TPU_NATIVE_BUILD_DIR",
     os.path.join(os.path.dirname(__file__), "_build"))
-_LIB_PATH = os.path.join(_BUILD_DIR, "libds_native.so")
 
 _lock = make_lock("ops.builder")
 _lib: Optional[ctypes.CDLL] = None
@@ -34,13 +36,21 @@ _tried = False
 _BASE_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    return os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH)
+def _lib_path() -> str:
+    """The library for THIS source on THIS host: the name carries a hash of
+    the source and the host's CPU-feature fingerprint. The build uses
+    ``-march=native``, and the build directory travels with a copied working
+    tree, so a library built on another host (or from other source) must
+    never be the one that loads here — it simply has another name. A host
+    whose features are unreadable ("" fingerprint) shares nothing: its key
+    is the host name."""
+    with open(_SRC, "rb") as f:
+        src = hashlib.sha1(f.read()).hexdigest()[:12]
+    host = host_fingerprint() or f"host-{os.uname().nodename}"
+    return os.path.join(_BUILD_DIR, f"libds_native-{src}-{host}.so")
 
 
-def _compile() -> bool:
+def _compile(lib_path: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # Unique temp output so concurrent builds (multi-process launch on a cold
     # cache) never interleave writes; os.replace makes the publish atomic.
@@ -56,7 +66,7 @@ def _compile() -> bool:
                 logger.warning(f"native build failed to launch g++: {e}")
                 return False
             if proc.returncode == 0:
-                os.replace(tmp_out, _LIB_PATH)
+                os.replace(tmp_out, lib_path)
                 return True
         logger.warning(f"native build failed:\n{proc.stderr[-2000:]}")
         return False
@@ -102,9 +112,10 @@ def load_native() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         try:
-            if _needs_build() and not _compile():
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path) and not _compile(lib_path):
                 return None
-            _lib = _bind(ctypes.CDLL(_LIB_PATH))
+            _lib = _bind(ctypes.CDLL(lib_path))
         except (OSError, AttributeError) as e:
             # AttributeError: stale cached .so missing a newer symbol — degrade
             # to the Python fallback rather than crashing consumers.

@@ -30,15 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 pltpu = import_pltpu()
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------------------------- #
@@ -251,7 +248,7 @@ class _BSA:
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
-            interpret=_interpret(),
+            interpret=_backend.interpret(),
         )(*scalars, *tensors)
 
     def fwd(self, q, k, v, scale):

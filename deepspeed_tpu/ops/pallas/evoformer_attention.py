@@ -33,15 +33,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 pltpu = import_pltpu()
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block(t: int, preferred: int) -> int:
@@ -142,7 +139,7 @@ def _fwd(q, k, v, mask, pair, scale, R, block):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v, mask, pair)
     return o, lse
 
@@ -278,7 +275,7 @@ def _bwd(q, k, v, mask, pair, o, lse, do, scale, R, block):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v, mask_op, pair, do, lse, delta)
 
     dkv_in = [
@@ -307,7 +304,7 @@ def _bwd(q, k, v, mask, pair, o, lse, do, scale, R, block):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v, mask_op, pair, do, lse, delta)
 
     # d(pair_bias): accumulate ds over each group's rows, tile-by-tile — the
@@ -334,7 +331,7 @@ def _bwd(q, k, v, mask, pair, o, lse, do, scale, R, block):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v, mask_op, pair, do, lse, delta)
 
     return dq, dk, dv, dpair

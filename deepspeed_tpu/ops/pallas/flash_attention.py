@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 pltpu = import_pltpu()
@@ -32,11 +33,6 @@ pltpu = import_pltpu()
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    # CPU (tests) runs kernels through the Pallas interpreter; TPU compiles them.
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block(t: int, preferred: int) -> int:
@@ -132,7 +128,7 @@ def _fwd(q, k, v, scale: float, causal: bool,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v)
     return o, lse
 
@@ -272,7 +268,7 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(seg, seg, qT, kT, vT)
     out = jnp.swapaxes(o[0], 0, 1)[:R]
     if with_lse:
@@ -400,7 +396,7 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -429,7 +425,7 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -59,15 +59,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 pltpu = import_pltpu()
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def kv_quantize_rows(x: jax.Array):
@@ -946,7 +943,7 @@ def paged_decode_attention_sidebuf(q: jax.Array,
             out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
-            interpret=_interpret(),
+            interpret=_backend.interpret(),
         )(*operands)
 
     kernel = functools.partial(
@@ -987,7 +984,7 @@ def paged_decode_attention_sidebuf(q: jax.Array,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(*operands)
 
 
@@ -1016,8 +1013,13 @@ def _decode_kernel_smalld(bt_ref, cl_ref, q_ref, kv_ref, o_ref,
     @pl.when(jnp.logical_and(i * bs < ctx, (i + 1) * bs > lo))
     def _():
         q = q_ref[0].astype(jnp.float32)                       # [H, D]
-        tok = i * bs + jax.lax.broadcasted_iota(jnp.int32, (H, bs), 1)
-        mask = jnp.logical_and(tok < ctx, tok >= lo)
+        # one head group's [G, bs] mask serves every group (it depends on
+        # the token column only). Built at that shape, never sliced: the
+        # TPU compiler ABORTS the process (no Python exception) on a row
+        # slice of an i1 vector past the first 8-sublane tile, which an
+        # [H, bs] mask sliced per group hits at H > 8.
+        tok = i * bs + jax.lax.broadcasted_iota(jnp.int32, (groups, bs), 1)
+        mh = jnp.logical_and(tok < ctx, tok >= lo)
         for h in range(h_kv):
             rows = slice(h * groups, (h + 1) * groups)
             qh = q[rows, :]                                    # [G, D]
@@ -1030,7 +1032,6 @@ def _decode_kernel_smalld(bt_ref, cl_ref, q_ref, kv_ref, o_ref,
                 kpf = i * bs + jax.lax.broadcasted_iota(
                     jnp.float32, (groups, bs), 1)
                 sc = sc + _alibi_slope(h * groups + gof, H) * kpf
-            mh = mask[rows, :]
             sc = jnp.where(mh, sc, NEG_INF)
             m_prev = m_sc[rows, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
@@ -1080,7 +1081,7 @@ def _paged_decode_smalld(q, kv_pages, block_tables, ctx_lens, scale,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       q, kv_pages)
 
@@ -1181,7 +1182,7 @@ def paged_decode_attention(q: jax.Array,
             # the 2-slot DMA pipeline hands buffers across grid steps (and
             # across sequences), so iteration order must stay sequential
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(*operands)
     if with_lse:
         return res[0], res[1][:, :, 0]
@@ -1349,7 +1350,7 @@ def paged_decode_attention_step(q: jax.Array,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(*operands)
     out, kvf = res[0], res[1]
     # the write happens HERE, after the kernel: a canonical in-place scatter
@@ -1607,7 +1608,7 @@ def paged_chunk_attention_batched(q: jax.Array,
         out_shape=jax.ShapeDtypeStruct((NC, Cs, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(*operands)
 
 

@@ -66,11 +66,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 from deepspeed_tpu.ops.pallas.paged_attention import (
     NEG_INF, _alibi_slope, _chunk_mask, _colscale_pages, _flash_update,
-    _interpret, _kv_flat, _pick_pages_per_chunk, _scale_tile_rows,
+    _kv_flat, _pick_pages_per_chunk, _scale_tile_rows,
     _scales_to_tiles, _step_write_rows, kv_quantize_rows,
     paged_chunk_attention_batched, paged_decode_attention)
 
@@ -580,7 +581,7 @@ def paged_decode_attention_splitk_pallas(q: jax.Array,
             # the 2-slot DMA pipeline hands buffers across grid steps (and
             # across virtual rows), so iteration order stays sequential
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(*operands)
     out, lse = merge_splitk_partials(out_p.reshape(S, SP, H, D),
                                      lse_p[:, :, 0].reshape(S, SP, H))
@@ -625,7 +626,7 @@ def paged_decode_attention_splitk(q: jax.Array,
                                       softmax_scale=softmax_scale,
                                       window=window, with_lse=with_lse,
                                       kv_scales=kv_scales, alibi=alibi)
-    if q.shape[-1] % 128 == 0 and not _interpret():
+    if q.shape[-1] % 128 == 0 and not _backend.interpret():
         return paged_decode_attention_splitk_pallas(
             q, kv_pages, block_tables, ctx_lens, n_splits,
             softmax_scale=softmax_scale, window=window, with_lse=with_lse,

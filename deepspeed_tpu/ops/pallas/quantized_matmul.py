@@ -18,8 +18,8 @@ bf16/f32. M is padded to the sublane tile in the wrapper.
 Status: building block, deliberately NOT on the v2 serving path — round 5
 re-measured the whole M sweep with honest (>=512-iteration in-program)
 windows: XLA's convert-in-dot beats bf16 weights at every swept M in the
-median (typically 1.6-2.5x at M=32-128, 1.2-1.8x at M=256; bench.py
-bench_mixed_gemm re-records the sweep each run) while this standalone
+median (typically 1.6-2.5x at M=32-128, 1.2-1.8x at M=256; no benchmark
+re-records that sweep today) while this standalone
 kernel loses at every M — it cannot join the jitted program's
 latency-hiding schedule. Round 4's "convert eats the win at M>=128" (and
 the earlier "1.18x, not 2x" figure) were noisy-window artifacts; VERDICT
@@ -43,13 +43,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 pltpu = import_pltpu()
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def quantize_weight_int8(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -129,7 +126,7 @@ def quantized_matmul(a: jax.Array, w8: jax.Array, scale: jax.Array,
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(a, w8, scale.reshape(1, N))
     return out[:M]
 

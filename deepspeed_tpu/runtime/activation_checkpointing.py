@@ -56,7 +56,6 @@ POLICIES: Dict[str, Callable[[], Optional[Callable]]] = {
     # selective: save only per-layer attention outputs (tagged by the zoo
     # models via checkpoint_name "attn_out") — backward skips recomputing the
     # attention kernel, costing only B*T*C per layer of extra residency.
-    # Measured v5e-1, GPT-2-medium bs=64 T=1024: see bench.py comment.
     "attn_out_saveable": lambda: _cp.save_only_these_names("attn_out"),
     "offload_attn_out": lambda: _cp.save_and_offload_only_these_names(
         names_which_can_be_saved=[], names_which_can_be_offloaded=["attn_out"],

@@ -18,10 +18,8 @@ engine's exact weight layout (``out_shardings`` taken leaf-by-leaf from
 the live serving weights) out. No leaf touches the host — the bridge is
 listed in jaxlint's JL007 hot paths with an empty baseline, so any
 ``device_get``/``np.asarray``/``.item`` creeping in fails lint, not just
-review. On donating platforms the serving engine's OLD weights are passed
-as a donated operand so XLA may alias the new layout into their buffers
-(the compat shim strips donation where jaxlib can't honour it —
-``utils/jax_compat.py``).
+review. The serving engine's OLD weights are passed as a donated operand so
+XLA may alias the new layout into their buffers.
 
 :class:`RolloutLoop` drives the full cycle on top: train step(s) ->
 ``sync`` (the bridge program) -> ``swap`` (in-place rebind into the live

@@ -1,167 +1,25 @@
-"""Version shims so one source tree runs on both current and older jax.
+"""The package's import points for ``shard_map`` and the Pallas TPU module.
 
-The repo is written against the modern jax surface (``jax.shard_map``,
-``pltpu.CompilerParams``). Older releases (<= 0.4.x) ship the same
-functionality under earlier names; ``apply()`` aliases the new names onto the
-installed modules so every call site can use the modern spelling. Idempotent
-and a no-op on new jax.
+Both are plain pass-throughs on the one installation there is (jax 0.9,
+where ``jax.shard_map`` and ``pltpu.CompilerParams`` exist under those
+names). They remain so the call sites and jaxlint JL006, which sends every
+such import here, need not change before ROADMAP D12 removes this module:
 
-Call sites import the shimmed surfaces from HERE rather than from jax
-directly (enforced by jaxlint JL006):
-
-- ``from deepspeed_tpu.utils.jax_compat import shard_map`` — the modern
-  ``jax.shard_map`` signature (``check_vma``, ``axis_names``) on every
-  supported jax.
-- ``pltpu = jax_compat.import_pltpu()`` — ``jax.experimental.pallas.tpu``
-  with the ``CompilerParams`` alias guaranteed.
-
-Raw ``jax.experimental.shard_map`` / ``jax.experimental.pallas.tpu`` imports
-bypass the aliasing and break on one side of the rename fence.
+- ``from deepspeed_tpu.utils.jax_compat import shard_map``
+- ``pltpu = jax_compat.import_pltpu()``
 """
 
 from __future__ import annotations
 
-import os
-
-
-def _old_jax(jax) -> bool:
-    try:
-        major, minor = jax.__version__.split(".")[:2]
-        return (int(major), int(minor)) < (0, 5)
-    except Exception:
-        return False
-
-
-def _pinned_platform(jax) -> str:
-    """The platform pinned by config/env, or "" when undecided. Never
-    initializes a backend."""
-    plat = getattr(jax.config, "jax_platforms", None) \
-        or os.environ.get("JAX_PLATFORMS", "")
-    return str(plat).split(",")[0].strip()
-
 
 def shard_map(*args, **kwargs):
-    """``jax.shard_map`` with the modern signature on every supported jax.
-
-    This is the package's blessed entry point (jaxlint JL006): on old jax the
-    monkey-patched alias only exists after ``apply()`` ran, so importing
-    ``shard_map`` from jax directly is an import-order trap; importing it from
-    here is always safe."""
+    """``jax.shard_map``."""
     import jax
 
-    if not hasattr(jax, "shard_map"):
-        apply()
     return jax.shard_map(*args, **kwargs)
 
 
 def import_pltpu():
-    """``jax.experimental.pallas.tpu`` with ``CompilerParams`` guaranteed.
-
-    The blessed import path for Pallas TPU modules (jaxlint JL006)::
-
-        from deepspeed_tpu.utils.jax_compat import import_pltpu
-        pltpu = import_pltpu()
-
-    Raises ImportError where pallas itself is unavailable — same contract as
-    the raw import, but with the rename shims applied first."""
-    apply()
+    """``jax.experimental.pallas.tpu``."""
     from jax.experimental.pallas import tpu as pltpu  # jaxlint: disable=JL006
     return pltpu
-
-
-def apply() -> None:
-    import jax
-
-    if not hasattr(jax, "shard_map"):
-        # jax < 0.5: shard_map lives under jax.experimental and spells the
-        # replication-check kwarg check_rep (renamed check_vma later)
-        import functools
-        import inspect
-
-        from jax.experimental.shard_map import shard_map
-
-        if "check_vma" not in inspect.signature(shard_map).parameters:
-            inner = shard_map
-
-            @functools.wraps(inner)
-            def shard_map(*args, **kwargs):
-                if "check_vma" in kwargs:
-                    kwargs["check_rep"] = kwargs.pop("check_vma")
-                if "axis_names" in kwargs:
-                    # new jax maps only over axis_names (other axes stay
-                    # auto). Old shard_map's `auto` is too limited (raises
-                    # NotImplementedError on these programs), so emulate by
-                    # mapping over EVERY axis: the in/out specs only shard
-                    # the named axes, inputs are replicated over the rest,
-                    # and the callers' collectives only touch named axes —
-                    # identical math, but the replication oracle can't prove
-                    # the output is replicated, so drop check_rep.
-                    kwargs.pop("axis_names")
-                    kwargs["check_rep"] = False
-                return inner(*args, **kwargs)
-
-        jax.shard_map = shard_map
-
-    if _old_jax(jax) and not getattr(jax.jit, "_dstpu_nodonate", False):
-        # jaxlib 0.4.x's CPU client heap-corrupts on donated buffers in the
-        # fused train steps (reproducible glibc "corrupted double-linked
-        # list" / segfault in tests/unit/test_checkpoint_matrix.py; the same
-        # programs run clean with donation stripped). Donation only recycles
-        # buffer memory — dropping it never changes results — so on old-jax
-        # CPU runs every jit ignores donate_argnums/donate_argnames. The
-        # platform check reads config/env only (no backend init); when
-        # neither pins a platform the decision defers to the first CALL of
-        # the jitted function (_LazyDonationJit), so module-import-time jit
-        # wrapping never initializes a backend. TPU runs keep donation.
-        inner_jit = jax.jit
-
-        def _strip(kwargs):
-            kwargs = dict(kwargs)
-            kwargs.pop("donate_argnums", None)
-            kwargs.pop("donate_argnames", None)
-            return kwargs
-
-        class _LazyDonationJit:
-            """jit whose donation decision waits for the first call: at
-            wrap time the platform may be unpinned (config/env empty), and
-            asking jax.default_backend() then would initialize — and lock —
-            the backend during module import. By the first call (or any
-            attribute access, e.g. .lower), compilation is imminent anyway."""
-
-            def __init__(self, args, kwargs):
-                self._args, self._kwargs = args, kwargs
-                self._fn = None
-
-            def _materialize(self):
-                if self._fn is None:
-                    kw = (_strip(self._kwargs)
-                          if jax.default_backend() == "cpu" else self._kwargs)
-                    self._fn = inner_jit(*self._args, **kw)
-                return self._fn
-
-            def __call__(self, *a, **kw):
-                return self._materialize()(*a, **kw)
-
-            def __getattr__(self, name):
-                return getattr(self._materialize(), name)
-
-        def _jit(*args, **kwargs):
-            if "donate_argnums" in kwargs or "donate_argnames" in kwargs:
-                plat = _pinned_platform(jax)
-                if plat == "cpu":
-                    kwargs = _strip(kwargs)
-                elif not plat:
-                    return _LazyDonationJit(args, kwargs)
-            return inner_jit(*args, **kwargs)
-
-        _jit._dstpu_nodonate = True
-        _jit.__wrapped__ = inner_jit
-        jax.jit = _jit
-
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-    except Exception:  # pallas not importable on this platform — nothing to shim
-        return
-    if not hasattr(pltpu, "CompilerParams") and hasattr(pltpu, "TPUCompilerParams"):
-        # renamed TPUCompilerParams -> CompilerParams in newer jax
-        pltpu.CompilerParams = pltpu.TPUCompilerParams

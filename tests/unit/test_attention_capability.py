@@ -28,8 +28,10 @@ from deepspeed_tpu.inference.v2.config_v2 import (
 )
 
 
-def _spec(window=None, alibi=False, head_dim=128, num_kv_heads=2):
-    return SimpleNamespace(head_dim=head_dim, num_kv_heads=num_kv_heads,
+def _spec(window=None, alibi=False, head_dim=128, num_heads=8,
+          num_kv_heads=4):
+    return SimpleNamespace(head_dim=head_dim, num_heads=num_heads,
+                           num_kv_heads=num_kv_heads,
                            window=window, alibi=alibi)
 
 
@@ -76,6 +78,23 @@ class TestSplitTPRefusal:
         with pytest.raises(NotImplementedError,
                            match="kv_quant with tensor_parallel"):
             AttentionKernelSpec.validate_engine_build(_spec(), cfg)
+
+
+# --------------------------------------------------------------------- #
+# tensor parallelism that does not divide the heads is refused, not
+# quietly served at tp=1
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("heads, kv_heads, tp", [
+    (8, 2, 4),      # kv heads do not divide
+    (6, 6, 4),      # neither divides
+    (9, 3, 2),
+])
+def test_tp_not_dividing_heads_refused(heads, kv_heads, tp):
+    cfg = _cfg(tensor_parallel=tp)
+    with pytest.raises(ValueError, match="does not fall back to tp=1"):
+        AttentionKernelSpec.validate_engine_build(
+            _spec(num_heads=heads, num_kv_heads=kv_heads), cfg)
 
 
 # --------------------------------------------------------------------- #

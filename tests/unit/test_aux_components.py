@@ -160,25 +160,3 @@ def test_agent_rejects_incompatible_world_size():
     agent = DSElasticAgent(_ELASTIC_CFG, run_fn, device_counts=[7])
     with pytest.raises(ElasticityError):
         agent.run()
-
-
-# --------------------------------------------------------------------------- #
-# error classification (utils/errors.py) — retry only transport flakes
-# --------------------------------------------------------------------------- #
-
-def test_transient_error_spellings():
-    from deepspeed_tpu.utils.errors import is_transient_error
-    # all three gRPC deadline spellings + anchored UNAVAILABLE forms
-    for msg in ("DEADLINE_EXCEEDED: timed out",
-                "Deadline Exceeded while waiting",
-                "DeadlineExceeded",
-                "UNAVAILABLE: connection dropped",
-                "rpc status UNAVAILABLE",
-                "endpoint unavailable: socket closed",
-                "read body: response body closed"):
-        assert is_transient_error(RuntimeError(msg)), msg
-    # deterministic messages must NOT be retried
-    for msg in ("Mosaic failed to compile: bad layout",
-                "feature unavailable on this backend",
-                "sharding unavailable for this op"):
-        assert not is_transient_error(RuntimeError(msg)), msg

@@ -1,0 +1,194 @@
+"""Main-path Pallas kernels, compiled for a described TPU v5e at real widths.
+
+The suite runs every kernel through the Pallas interpreter on the CPU, which
+accepts programs the chip's compiler refuses (a slice not aligned to the
+tiling, too much VMEM) or aborts on. The TPU compiler is installed with jax
+and compiles for a chip that is described, not attached
+(``jax.experimental.topologies``), so these cases ask it directly: each one
+lowers a kernel at Mistral-7B width (32 q / 8 kv heads, head_dim 128,
+128-token pages) for ``v5e:2x2`` and asserts the Mosaic kernel is in the
+compiled program. Nothing runs; results are checked on the chip by
+``chip_smoke.py``'s kernels phase.
+
+The small-head-dim cases pin a repair: ``_paged_decode_smalld`` used to make
+the compiler ABORT THE PROCESS (``Check failed: limits[i] <= dim(i)``, no
+Python exception) at these four shapes — every 64/80/96-wide-head family
+would have killed a server at its first decode compile.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.pallas import _backend
+from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                      flash_attention_packed)
+from deepspeed_tpu.ops.pallas.paged_attention import (
+    kv_scale_tiles_shape, paged_chunk_attention_batched,
+    paged_decode_attention, paged_decode_attention_sidebuf,
+    paged_decode_attention_step)
+from deepspeed_tpu.ops.pallas.paged_splitk import (
+    paged_decode_attention_splitk_pallas, paged_sidebuf_attention_splitk)
+
+H, HKV, D, BS = 32, 8, 128, 128      # LlamaConfig.mistral_7b heads, page size
+WINDOW = 4096
+S, MB, NB = 8, 40, 64                # sequences, pages per sequence, pool
+BF16, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described chips of a v5e 2x2. The persistent compilation
+    cache is off around these compiles: an executable for a described device
+    is written to the cache but cannot be read back without a chip, so the
+    next run would warn and compile again anyway."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", prior)
+    cc.reset_cache()
+
+
+def _pool(h_kv=HKV, d=D, dtype=BF16):
+    return ((NB, 2, h_kv, BS, d), dtype)
+
+
+_Q = ((S, H, D), BF16)
+_BT = ((S, MB), I32)
+_CL = ((S,), I32)
+_NEW = ((S, HKV, D), BF16)
+_SCALES = (kv_scale_tiles_shape(NB, HKV, BS), F32)   # at-rest tile layout
+_SIDE = ((S, 16, HKV, D), BF16)                      # 16-step side slab
+_CHUNK_Q = ((4, 128, H, D), BF16)                    # 4 slots x 128 tokens
+_TRAIN = ((1, 4096, H, D), BF16)                     # kv heads repeated to H
+_PACKED = 1024
+
+
+def _flash_sq(q, k, v):
+    return jnp.sum(flash_attention(q, k, v, causal=True).astype(F32) ** 2)
+
+
+def _decode_smalld(h, h_kv, d):
+    return (paged_decode_attention,
+            [((S, h, d), BF16), _pool(h_kv, d), _BT, _CL])
+
+
+CASES = {
+    "flash_fwd": (lambda q, k, v: flash_attention(q, k, v, causal=True),
+                  [_TRAIN] * 3),
+    "flash_bwd": (jax.grad(_flash_sq, argnums=(0, 1, 2)), [_TRAIN] * 3),
+    "packed_prefill": (
+        lambda q, k, v, seg: flash_attention_packed(q, k, v, seg,
+                                                    window=WINDOW),
+        [((_PACKED, H, D), BF16), ((_PACKED, HKV, D), BF16),
+         ((_PACKED, HKV, D), BF16), ((_PACKED,), I32)]),
+    "decode": (paged_decode_attention, [_Q, _pool(), _BT, _CL]),
+    "decode_window": (
+        lambda *a: paged_decode_attention(*a, window=WINDOW),
+        [_Q, _pool(), _BT, _CL]),
+    "fused_step": (
+        lambda *a: paged_decode_attention_step(*a, window=WINDOW),
+        [_Q, _NEW, _NEW, _pool(), _BT, _CL]),
+    "chunk_batched": (
+        lambda *a: paged_chunk_attention_batched(*a, window=WINDOW),
+        [_CHUNK_Q, _pool(), ((4, MB), I32), ((4,), I32), ((4,), I32)]),
+    "sidebuf": (
+        lambda q, kv, bt, pl_, sk, sv, j: paged_decode_attention_sidebuf(
+            q, kv, bt, pl_, sk, sv, j, window=WINDOW),
+        [_Q, _pool(), _BT, _CL, _SIDE, _SIDE, ((), I32)]),
+    "splitk4": (
+        lambda *a: paged_decode_attention_splitk_pallas(*a, 4,
+                                                        window=WINDOW),
+        [_Q, _pool(), _BT, _CL]),
+    # no window here: with one, the side-buffer split takes the XLA scan
+    # (its window start is traced per sequence) and there is no kernel
+    "sidebuf_splitk2": (
+        lambda q, kv, bt, pl_, sk, sv, j: paged_sidebuf_attention_splitk(
+            q, kv, bt, pl_, sk, sv, j, n_splits=2),
+        [_Q, _pool(), _BT, _CL, _SIDE, _SIDE, ((), I32)]),
+    "decode_int8": (
+        lambda q, kv, bt, cl, sc: paged_decode_attention(
+            q, kv, bt, cl, kv_scales=sc),
+        [_Q, _pool(dtype=I8), _BT, _CL, _SCALES]),
+    "step_int8": (
+        lambda q, kn, vn, kv, bt, cl, sc: paged_decode_attention_step(
+            q, kn, vn, kv, bt, cl, kv_scales=sc),
+        [_Q, _NEW, _NEW, _pool(dtype=I8), _BT, _CL, _SCALES]),
+    # head_dim % 128 != 0: the BlockSpec-pipelined decode kernel
+    "smalld_h16_kv16_d64": _decode_smalld(16, 16, 64),
+    "smalld_h32_kv32_d80": _decode_smalld(32, 32, 80),
+    "smalld_h64_kv64_d96": _decode_smalld(64, 64, 96),
+    "smalld_h32_kv8_d64": _decode_smalld(32, 8, 64),
+    "smalld_step_h32_kv8_d64": (
+        paged_decode_attention_step,
+        [((S, 32, 64), BF16), ((S, 8, 64), BF16), ((S, 8, 64), BF16),
+         _pool(8, 64), _BT, _CL]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
+    # the CPU backend means "interpret" to every kernel module; this test
+    # compiles for a chip that is described, so it steers them here
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    fn, shapes = CASES[case]
+    chip = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{case}: no Mosaic kernel in the compiled program"
+
+
+def test_flash_dispatch_compiles_over_a_four_chip_mesh(v5e, monkeypatch):
+    """The train step's attention under ``mesh: {fsdp: 4}``. The SPMD
+    partitioner refuses a bare Mosaic kernel in a program over four devices
+    ("cannot be automatically partitioned"); the dispatcher must hand it
+    over inside a shard_map."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.comm.mesh import (BATCH_AXES, build_topology,
+                                         set_topology)
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.ops import attention
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    topo = set_topology(build_topology(MeshConfig(data=1, fsdp=4),
+                                       devices=list(v5e)))
+    rows = NamedSharding(topo.mesh, P(BATCH_AXES))
+    args = [jax.ShapeDtypeStruct((4, 4096, H, D), BF16, sharding=rows)] * 3
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attention.dot_product_attention(
+            q, k, v, causal=True).astype(F32) ** 2), argnums=(0, 1, 2))
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_v2_engine_refuses_to_span_chips_at_tp1(v5e):
+    """At tensor_parallel=1 the engine's mesh takes every visible device,
+    but its kernels run outside shard_map there and a Mosaic kernel cannot
+    be partitioned: on a four-chip host the first compile would fail with
+    the partitioner's message. Refused by name at build instead."""
+    from deepspeed_tpu.comm.mesh import build_topology
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    model = LlamaForCausalLM(LlamaConfig.tiny())
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    four = build_topology(MeshConfig(data=4), devices=list(v5e))
+    with pytest.raises(NotImplementedError, match="one replica per chip"):
+        InferenceEngineV2(model=model, model_parameters=params,
+                          mesh_topology=four)

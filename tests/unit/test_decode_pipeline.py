@@ -253,18 +253,53 @@ def test_pipeline_stats_and_monitor_fields(warm_engine):
 
 
 # --------------------------------------------------------------------------- #
-# persistent compile cache (utils/compile_cache.py via config_v2.CompileConfig)
+# persistent compile cache (utils/compile_cache.py, config_v2.CompileConfig)
 # --------------------------------------------------------------------------- #
 
-def test_compile_config_env_knob(monkeypatch):
+def _point_jax_at(monkeypatch, directory):
+    """What a driver does before the process starts: place the cache through
+    JAX_COMPILATION_CACHE_DIR. jax reads the variable at import, so a test
+    that sets it late mirrors it into the config itself."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", directory)
+    jax.config.update("jax_compilation_cache_dir", directory)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def restore_cache_config():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prior_dir = jax.config.jax_compilation_cache_dir
+    prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    cc.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", prior_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", prior_min)
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path,
+                                           restore_cache_config):
     from deepspeed_tpu.inference.v2.config_v2 import CompileConfig
-    monkeypatch.delenv("DSTPU_COMPILE_CACHE", raising=False)
-    assert CompileConfig().resolve_cache_dir() == ""
-    monkeypatch.setenv("DSTPU_COMPILE_CACHE", "/tmp/xyz")
-    assert CompileConfig().resolve_cache_dir() == "/tmp/xyz"
-    # explicit config beats the env, and "" explicitly disables
-    assert CompileConfig(cache_dir="/a").resolve_cache_dir() == "/a"
-    assert CompileConfig(cache_dir="").resolve_cache_dir() == ""
+    from deepspeed_tpu.utils.compile_cache import (host_fingerprint,
+                                                   setup_compile_cache)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    # unset: the fixed path under the checkout, host-keyed on the CPU
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert setup_compile_cache() == os.path.join(
+        repo, ".jax_cache", f"cpu-{host_fingerprint()}")
+    # set: that directory untouched by code — no sub-directory, no override
+    placed = str(tmp_path / "placed")
+    _point_jax_at(monkeypatch, placed)
+    assert setup_compile_cache() == placed
+    assert jax.config.jax_compilation_cache_dir == placed
+    # set too late for jax to have read it: refused, not silently cold
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "late"))
+    with pytest.raises(RuntimeError, match="before jax is imported"):
+        setup_compile_cache()
+    # no config field can move the cache
+    with pytest.raises(TypeError):
+        CompileConfig(cache_dir=placed)
     # non-pow2 buckets normalize to the grid (same rounding as warmup())
     assert CompileConfig(warmup_buckets=[3, 4, 6]).warmup_buckets == [4, 8]
     with pytest.raises(ValueError):
@@ -273,19 +308,14 @@ def test_compile_config_env_knob(monkeypatch):
         CompileConfig(warmup_decode_steps=[0])
 
 
-def test_second_engine_hits_persistent_cache(tmp_path):
+def test_second_engine_hits_persistent_cache(monkeypatch, tmp_path,
+                                             restore_cache_config):
     """Engine #1 (warmup on, fresh cache dir) populates the persistent cache;
     engine #2 with the same config must reload every program — no new cache
     entries written (file count is the compile witness XLA gives us)."""
-    cc = pytest.importorskip("jax.experimental.compilation_cache"
-                             ".compilation_cache")
-    if not hasattr(cc, "reset_cache"):
-        pytest.skip("jax too old to re-point the compilation cache")
     cache_root = str(tmp_path / "ccache")
-    cfg = {"cache_dir": cache_root, "min_compile_time_secs": 0.0,
-           "warmup": True, "warmup_buckets": [1]}
-    prior_dir = jax.config.jax_compilation_cache_dir
-    prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    cfg = {"min_compile_time_secs": 0.0, "warmup": True,
+           "warmup_buckets": [1]}
 
     def count_entries():
         # executables only: jax's lru_cache backend also touches "-atime"
@@ -295,29 +325,23 @@ def test_second_engine_hits_persistent_cache(tmp_path):
                     if os.path.isfile(p) and not p.endswith("-atime")])
 
     # model init once, OUTSIDE the cached window: its programs compile before
-    # the first engine re-points the cache, so a per-engine init would write
-    # its entries only on the second pass and fake a miss
+    # the cache is re-pointed, so a per-engine init would write its entries
+    # only on the second pass and fake a miss
     mp = _model_and_params()
-    try:
-        cc.reset_cache()                 # drop the conftest cache handle
-        e1 = _build_engine(compile_cfg=cfg, model_params=mp)
-        e1.put([0], [PROMPTS[0]])
-        e1.decode_pipeline([0]).run(2)
-        jax.effects_barrier()
-        n1 = count_entries()
-        assert n1 > 0, "warmup wrote nothing to the persistent cache"
-        del e1
-        e2 = _build_engine(compile_cfg=cfg, model_params=mp)
-        e2.put([0], [PROMPTS[0]])
-        e2.decode_pipeline([0]).run(2)
-        jax.effects_barrier()
-        assert count_entries() == n1, \
-            "second engine construction recompiled instead of hitting the cache"
-    finally:
-        cc.reset_cache()
-        jax.config.update("jax_compilation_cache_dir", prior_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          prior_min)
+    _point_jax_at(monkeypatch, cache_root)
+    e1 = _build_engine(compile_cfg=cfg, model_params=mp)
+    e1.put([0], [PROMPTS[0]])
+    e1.decode_pipeline([0]).run(2)
+    jax.effects_barrier()
+    n1 = count_entries()
+    assert n1 > 0, "warmup wrote nothing to the persistent cache"
+    del e1
+    e2 = _build_engine(compile_cfg=cfg, model_params=mp)
+    e2.put([0], [PROMPTS[0]])
+    e2.decode_pipeline([0]).run(2)
+    jax.effects_barrier()
+    assert count_entries() == n1, \
+        "second engine construction recompiled instead of hitting the cache"
 
 
 def test_pipeline_traced_run_byte_identical_with_serve_spans(warm_engine):

@@ -85,3 +85,38 @@ def test_segment_ids_fallback_path():
     out = flash_attention(q, k, v, segment_ids=seg)
     ref = reference_attention(q, k, v, segment_ids=seg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+
+
+def test_dispatch_runs_the_kernel_per_shard_under_an_engine_mesh(
+        eight_devices, monkeypatch):
+    """The SPMD partitioner cannot split a Mosaic kernel, so a program over
+    more than one device must call it inside a shard_map (on a TPU anything
+    else fails to lower; the interpreter hides that). Under the ambient mesh
+    the dispatcher shards the batch over the data axes and the heads over
+    'tensor', and results and gradients stay those of plain attention."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.comm.mesh import (BATCH_AXES, TENSOR_AXIS,
+                                         build_topology, set_topology)
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.ops import attention
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attention, "FLASH_MIN_SEQ", 128)
+    topo = set_topology(build_topology(MeshConfig(data=2, fsdp=2, tensor=2)))
+    rows = NamedSharding(topo.mesh, P(BATCH_AXES, None, TENSOR_AXIS, None))
+    q, k, v = (jax.device_put(t, rows)
+               for t in make_qkv(B=4, T=128, H=4, D=32))
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v, causal=True) ** 2)
+
+    assert "shard_map" in str(jax.make_jaxpr(
+        lambda q, k, v: attention.dot_product_attention(q, k, v, causal=True)
+    )(q, k, v))
+    got = jax.jit(jax.value_and_grad(loss(attention.dot_product_attention),
+                                     argnums=(0, 1, 2)))(q, k, v)
+    want = jax.value_and_grad(loss(reference_attention),
+                              argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)

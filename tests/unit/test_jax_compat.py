@@ -1,11 +1,4 @@
-"""Unit tests for the jax version shims (utils/jax_compat.py).
-
-PR 1 shipped the shims battle-tested but untested: alias presence
-(``jax.shard_map``, ``pltpu.CompilerParams``), the ``check_vma``→``check_rep``
-kwarg mapping, the ``axis_names`` emulation, and the donation strip that works
-around jaxlib 0.4.x CPU heap corruption. Assertions that only make sense on
-one side of the version fence are gated on ``_old_jax``.
-"""
+"""Unit tests for the two import points left in utils/jax_compat.py."""
 
 import jax
 import jax.numpy as jnp
@@ -13,91 +6,22 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deepspeed_tpu.utils import jax_compat
-from deepspeed_tpu.utils.jax_compat import _old_jax, import_pltpu, shard_map
+from deepspeed_tpu.utils.jax_compat import import_pltpu, shard_map
 
 
-def _one_device_mesh(axis="x"):
-    return Mesh(np.asarray(jax.devices()[:1]), (axis,))
-
-
-def test_apply_is_idempotent():
-    before = jax.shard_map
-    jax_compat.apply()
-    jax_compat.apply()
-    assert jax.shard_map is before
-
-
-def test_shard_map_alias_present():
-    # the whole tree spells the modern name; conftest ran apply()
-    assert hasattr(jax, "shard_map") and callable(jax.shard_map)
-
-
-def test_compat_shard_map_executes():
-    mesh = _one_device_mesh()
-    f = shard_map(lambda x: x * 2, mesh=mesh, in_specs=P(), out_specs=P())
+@pytest.mark.parametrize("kwargs", [
+    {}, {"check_vma": False}, {"axis_names": {"x"}},
+], ids=["plain", "check_vma", "axis_names"])
+def test_shard_map_passes_the_modern_signature_through(kwargs):
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("x",))
+    f = shard_map(lambda x: x * 2, mesh=mesh, in_specs=P(), out_specs=P(),
+                  **kwargs)
     np.testing.assert_allclose(np.asarray(f(jnp.arange(4.0))),
                                2 * np.arange(4.0))
 
 
-def test_compat_shard_map_accepts_check_vma():
-    # new jax takes check_vma natively; old jax only works if the shim maps
-    # it onto check_rep — either way the modern spelling must run
-    mesh = _one_device_mesh()
-    f = shard_map(lambda x: x + 1, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_vma=False)
-    np.testing.assert_allclose(np.asarray(f(jnp.zeros(3))), np.ones(3))
-
-
-def test_compat_shard_map_accepts_axis_names():
-    # modern surface: map over the named axes only; the old-jax emulation
-    # maps over every axis with check_rep dropped — results must agree
-    mesh = _one_device_mesh()
-    f = shard_map(lambda x: x - 1, mesh=mesh, in_specs=P(), out_specs=P(),
-                  axis_names={"x"})
-    np.testing.assert_allclose(np.asarray(f(jnp.ones(3))), np.zeros(3))
-
-
-def test_pltpu_compiler_params_alias():
-    pltpu = pytest.importorskip("jax.experimental.pallas.tpu",
-                                reason="pallas not importable on this platform")
+def test_import_pltpu_is_the_pallas_tpu_module():
+    from jax.experimental.pallas import tpu as pltpu
     got = import_pltpu()
     assert got is pltpu
     assert hasattr(got, "CompilerParams")
-    if hasattr(got, "TPUCompilerParams"):
-        assert got.CompilerParams is got.TPUCompilerParams
-
-
-def test_donation_stripped_on_old_jax_cpu():
-    if not _old_jax(jax):
-        pytest.skip("donation strip only applies to jax < 0.5")
-    # the wrapped jit must advertise itself (idempotence guard) ...
-    assert getattr(jax.jit, "_dstpu_nodonate", False)
-    # ... and a donated argument must survive the call on the CPU backend
-    # (jaxlib 0.4.x heap-corrupts on donated buffers; donation is stripped)
-    f = jax.jit(lambda x: x + 1, donate_argnums=(0,))
-    x = jnp.arange(8.0)
-    y = f(x)
-    assert not x.is_deleted()
-    np.testing.assert_allclose(np.asarray(y), np.arange(8.0) + 1)
-    np.testing.assert_allclose(np.asarray(x), np.arange(8.0))  # still readable
-
-
-def test_donation_preserved_shape_dtype_semantics():
-    # stripping donation must never change results: run the same program
-    # through the wrapped jit with and without donate_argnums
-    f_plain = jax.jit(lambda x: 2 * x)
-    f_donate = jax.jit(lambda x: 2 * x, donate_argnums=(0,))
-    a = jnp.arange(6.0)
-    np.testing.assert_allclose(np.asarray(f_plain(a)),
-                               np.asarray(f_donate(jnp.arange(6.0))))
-
-
-def test_lazy_jit_exposes_lower():
-    if not _old_jax(jax):
-        pytest.skip("lazy donation jit only exists on jax < 0.5")
-    # attribute access (e.g. .lower for AOT probes) must materialize the jit
-    f = jax.jit(lambda x: x * 3, donate_argnums=(0,))
-    lowered = f.lower(jnp.zeros(2))
-    compiled = lowered.compile()
-    np.testing.assert_allclose(np.asarray(compiled(jnp.ones(2))), 3 * np.ones(2))

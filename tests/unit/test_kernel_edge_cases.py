@@ -3,8 +3,8 @@
 Parity: reference ``tests/unit/inference/v2`` (34 files of per-kernel
 shape/dtype sweeps) and ``tests/unit/ops`` — the classes of input the fast
 paths are most likely to get wrong. Runs on the Pallas interpreter (CPU);
-the real-TPU lowering of the same kernels is exercised every bench run
-(bench.py kernel smoke grid).
+the TPU lowering of the main-path kernels is compiled by
+tests/unit/test_chip_compile.py and run by chip_smoke.py's kernels phase.
 """
 
 import numpy as np

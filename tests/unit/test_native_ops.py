@@ -30,6 +30,25 @@ def _round_trip(handle, tmp_path, nbytes, offset=0):
     np.testing.assert_array_equal(src, dst)
 
 
+@pytest.mark.parametrize("changed", ["host", "source"])
+def test_native_library_keyed_by_source_and_host(monkeypatch, tmp_path,
+                                                 changed):
+    """The build directory travels with a copied working tree and the build
+    uses -march=native: a library from another host or other source must
+    have another name, so it is never the one loaded here."""
+    from deepspeed_tpu.ops.native import builder
+    here = builder._lib_path()
+    if changed == "host":
+        monkeypatch.setattr(builder, "host_fingerprint",
+                            lambda: "0123456789ab")
+    else:
+        other = tmp_path / "ds_native.cpp"
+        with open(builder._SRC, "rb") as f:
+            other.write_bytes(f.read() + b"\n// edited\n")
+        monkeypatch.setattr(builder, "_SRC", str(other))
+    assert builder._lib_path() != here
+
+
 class TestAsyncIOHandle:
 
     @pytest.mark.parametrize("nbytes", [17, 4096, 1 << 20, (1 << 20) + 13])

@@ -1,8 +1,8 @@
 """Serving-loop regression guard (VERDICT r2 #9).
 
-The real serving numbers are policed per-round by bench.py on hardware, but
-only at two config points; a scheduler/engine regression that, say, doubles
-the host work per pass would still pass the functional suite. This smoke
+No benchmark polices serving speed on hardware yet (ROADMAP S0), and a
+scheduler/engine regression that, say, doubles the host work per pass would
+still pass the functional suite. This smoke
 asserts the per-pass rate of the two hot loops on the virtual CPU mesh stays
 within a GENEROUS bound (>2x headroom over measured-at-commit rates, so env
 noise doesn't flake it while a structural regression trips it).
