@@ -766,22 +766,18 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
 
     Gates: per-step loss streams BYTE-IDENTICAL across all scheduled depths
     (the schedule moves collectives, never math); zero compiles during the
-    timed runs; depth 0 shows zero span-measured overlap while depth >= 1
-    shows structurally nonzero overlap (gather windows under other waves'
-    residency windows, from the train/zero3 stamps). The implicit
-    (XLA-scheduled) path is compared to fp32 tolerance only — its combiner
-    reduces grads in a different order (~1 ulp drift).
+    timed runs. The implicit (XLA-scheduled) path is compared to fp32
+    tolerance only — its combiner reduces grads in a different order (~1 ulp
+    drift). How much collective time the schedule hides is device work and is
+    read from a device trace (the waves carry ``zero3/gather/w<k>`` scopes;
+    ``chipbench`` reports ``collective_hidden_share``), not measured here.
 
     The steps/sec ratio is REPORTED against a 1.15x bar but only GATED on a
     real accelerator: a forced-host CPU mesh executes thunks serially, so
-    scheduled overlap cannot convert to wall-clock there (the spans still
-    prove the placement; same honesty pattern as the BENCH_r09 nvme leg)."""
+    scheduled overlap cannot convert to wall-clock there."""
     import jax
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
-    from deepspeed_tpu.monitor import tracer
-    from deepspeed_tpu.monitor.trace import install_from_env
-    from deepspeed_tpu.runtime.zero import prefetch
 
     batch, seq = 8, 32
     n_embd, n_layer = (64, 4) if smoke else (192, 6)
@@ -790,12 +786,6 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
     rng = np.random.default_rng(0)
     batches = [{"input_ids": rng.integers(0, LM_VOCAB, size=(batch, seq))
                 .astype(np.int32)} for _ in range(4)]
-
-    # $DSTPU_TRACE must win the export dir BEFORE we force-enable: an
-    # already-enabled tracer makes install_from_env a no-op
-    install_from_env()
-    was_enabled = tracer.enabled
-    tracer.configure(enabled=True)   # arm the plan's trace taps at build
 
     def build(depth):
         model = GPT2LMHead(cfg_m)
@@ -827,10 +817,9 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
         gc.enable()
         return losses, wall
 
-    streams, rates, fracs, out = {}, {}, {}, {}
+    streams, rates, out = {}, {}, {}
     compiles_during_timed = 0
     for depth in (0, 1, 2):
-        prefetch.clear_stamps()
         engine = build(depth)
         assert engine._zero3_plan is not None, "zero3 schedule did not arm"
         losses, _ = run(engine, steps, start=0)        # includes compiles
@@ -843,8 +832,6 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
         engine.drain_metrics()
         compiles_during_timed += engine.compiles - c0
         rates[depth] = steps / float(np.median(walls))
-        ev = dict((name, val) for name, val, _ in engine.zero3_stats.events(1))
-        fracs[depth] = float(ev.get("train/zero3/overlap_frac", 0.0))
         if depth == 0:
             out["waves_per_step"] = engine._zero3_plan.n_waves
             out["gather_mb_per_step"] = round(
@@ -859,16 +846,9 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
     implicit.destroy()
     del implicit
     gc.collect()
-    # keep tracing on when $DSTPU_TRACE armed an export dir (initialize()
-    # arms it AFTER was_enabled was captured): the atexit exporter skips a
-    # disabled tracer and bench_smoke's trace_check needs these lanes
-    tracer.enabled = was_enabled or bool(tracer.trace_dir)
-
     base = [np.frombuffer(b, np.float32)[0] for b in streams[0]]
     byte_equal = streams[0] == streams[1] == streams[2]
     implicit_close = bool(np.allclose(imp_losses, base, rtol=1e-5))
-    spans = sum(c for name, (c, _) in tracer.summary().items()
-                if str(name).startswith("train/zero3"))
     speedup = rates[2] / rates[0] if rates[0] > 0 else 0.0
     bar = 1.15
     out.update({
@@ -880,9 +860,6 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
         "compiles_during_timed_runs": compiles_during_timed,
         "steps_per_sec": {f"depth{d}": round(r, 3)
                           for d, r in rates.items()},
-        "overlap_frac": {f"depth{d}": round(f, 4)
-                         for d, f in fracs.items()},
-        "zero3_spans_recorded": spans,
         "speedup_d2_vs_d0": round(speedup, 3),
         "speedup_bar": bar,
         "wall_clock_meaningful": bool(on_tpu),
@@ -890,13 +867,10 @@ def run_zero3_overlap_leg(on_tpu: bool, steps: int, reps: int, smoke: bool):
     if not on_tpu:
         out["caveat"] = (
             "forced-host CPU mesh: XLA:CPU executes thunks serially, so the "
-            "scheduled overlap is visible in span placement (overlap_frac) "
-            "but cannot convert to wall-clock; the 1.15x bar applies on "
-            "hardware with async collectives")
-    overlap_ok = fracs[0] == 0.0 and fracs[1] > 0.0 and fracs[2] > 0.0
+            "scheduled overlap cannot convert to wall-clock; the 1.15x bar "
+            "applies on hardware with async collectives")
     out["ok"] = bool(byte_equal and implicit_close
-                     and compiles_during_timed == 0 and overlap_ok
-                     and spans > 0
+                     and compiles_during_timed == 0
                      and (speedup >= bar or not on_tpu))
     return out
 

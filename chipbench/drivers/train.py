@@ -5,7 +5,8 @@ A step is complete when its loss has been fetched. The loop keeps one step
 in flight: it dispatches step ``k + 1`` and then fetches the loss of step
 ``k``. The window opens at one fetch and closes at the first fetch
 ``--seconds`` or more later, so the rate is whole steps over exactly the
-time they took.
+time they took. ``--trace 2``: after the window has closed the loop goes on
+for the cell's ``trace_seconds`` under the program's capture.
 
 Correctness, before the window: ``engine.eval_loss`` on the first batch,
 with the labels the plain reference itself predicts (its greedy token at
@@ -116,6 +117,25 @@ def run(ctx: Context) -> Outcome:
         if now - t_start >= ctx.seconds:
             break
     t_end = ends[-1]
+    if ctx.capture is not None:
+        # the window is closed and its numbers come from t_start, ends and
+        # losses above; the same loop goes on, now under the capture
+        capture, captured = ctx.capture, []
+        capture.prime()
+        capture.start()
+        t_c = last = time.perf_counter()
+        while last - t_c < capture.seconds:
+            with annotate("harness dispatch"):
+                nxt = engine.train_batch(data_iter=feed)
+            with annotate("fetch loss"):
+                float(pending)
+            pending = nxt
+            now = time.perf_counter()
+            captured.append(1e3 * (now - last))
+            last = now
+        capture.stop()
+        ctx.log(f"--trace 2: {len(captured)} steps under the capture, median "
+                f"{stats.median(captured):.2f} ms")
     float(pending)                          # the step in flight, not counted
     if tracer is not None and tracer.path is None:
         tracer.stop()

@@ -192,6 +192,43 @@ class TraceWindow:
             self._thread = None
 
 
+class CaptureWindow(TraceWindow):
+    """``--trace 2``: a few seconds of the same traffic AFTER the measured
+    window has closed, under the program's own capture control
+    (``tracer.capture_start`` / ``capture_stop``: the profiler and the
+    program's spans over one interval, on one clock). Until :meth:`prime`
+    nothing of it has run: the run is a ``--trace 0`` run up to there."""
+
+    def __init__(self, directory: str, seconds: float):
+        super().__init__(directory, seconds)
+        self.capture = None            # what capture_stop returned
+
+    def prime(self) -> None:
+        """Start and stop the profiler once and throw that trace away, so
+        that the cost of its first start falls into no number."""
+        shutil.rmtree(self.directory, ignore_errors=True)
+        primer = TraceWindow(os.path.join(self.directory, "primer"), 0.0)
+        primer.start()
+        primer.stop()
+        shutil.rmtree(primer.directory, ignore_errors=True)
+
+    def start(self) -> None:
+        from deepspeed_tpu.monitor.trace import tracer
+        tracer.capture_start(self.directory)
+        self.running = True
+
+    def stop(self) -> None:
+        from deepspeed_tpu.monitor.trace import tracer
+        if not self.running:
+            return
+        self.running = False
+        self.capture = tracer.capture_stop()
+        self.path = self.capture.trace_path
+
+    def discard(self) -> None:
+        shutil.rmtree(self.directory, ignore_errors=True)
+
+
 @dataclass
 class Context:
     """What a driver is given."""
@@ -206,6 +243,7 @@ class Context:
     compiles: CompileCounter
     t_process: float                       # time.time() at process start
     tracer: Optional[TraceWindow] = None   # set in a --trace 1 run
+    capture: Optional[CaptureWindow] = None   # set in a --trace 2 run
     on_chip: bool = True                   # False only in the CPU rehearsal
 
     def log(self, msg: str) -> None:
@@ -244,10 +282,49 @@ def memory_peak_bytes(devices) -> int:
     return max(peaks)
 
 
-def result_line(ctx: Context, out: Outcome, trace: bool) -> Dict[str, Any]:
+def load_view(ctx: Context, out: Outcome, trace: int,
+              values: Dict[str, float]):
+    """The reduced trace of a traced run and the view its readers are given.
+    In a ``--trace 2`` run the view also holds what ``capture_stop`` returned
+    (``capture``: the program's records on the trace's clock, its counters'
+    deltas, the trace's path) and the names the program gave its device work
+    (``op_names``), and the program's spans join the harness's annotations
+    on the host line, so that an idle gap goes to either."""
+    from chipbench.reduce import xplane
+    traced = ctx.capture if trace == 2 else ctx.tracer
+    if traced.path is None:
+        raise BenchError("the driver made no trace")
+    ctx.log(f"reducing {traced.path} "
+            f"({os.path.getsize(traced.path) / 2**20:.1f} MiB)")
+    tr = xplane.load(traced.path, ANNOTATIONS)
+    view = {"trace": tr, "counters": out.counters, "values": values,
+            "peaks": ctx.peaks, "config": ctx.config, "traffic": ctx.traffic,
+            "cell": ctx.cell, "chips": len(ctx.devices)}
+    if trace == 2:
+        from chipbench.reduce import hlo_names
+        capture = ctx.capture.capture
+        tr.host.extend(xplane.Event(r[1], r[2], r[3] - r[2])
+                       for r in capture.records if r[0] == "X")
+        tr.host.sort(key=lambda e: e.start_ns)
+        view["capture"] = capture
+        view["op_names"] = hlo_names.load(traced.path)
+        ctx.log(f"capture: host clock to trace clock skew "
+                f"{capture.skew_ns * 1e-3:.1f} us over "
+                f"{(capture.stop_ns - capture.start_ns) * 1e-9:.3f} s, "
+                f"anchors known to {capture.anchor_uncertainty_ns * 1e-3:.1f}"
+                f" us; {len(capture.records)} records of the program; "
+                f"counters {capture.counters}; HLO of "
+                f"{len(view['op_names'])} programs in the trace")
+    return tr, view
+
+
+def result_line(ctx: Context, out: Outcome, trace: int) -> Dict[str, Any]:
     """The one JSON object the run ends with. ``--trace 0`` carries the
     cell's end-to-end metrics, ``--trace 1`` its per-layer metrics, read by
-    their readers from the driver's counters and the reduced trace."""
+    their readers from the driver's counters and the reduced trace.
+    ``--trace 2`` carries both: end-to-end metrics and counters from the
+    measured window, which no profiler disturbed, and device-trace and span
+    metrics from the capture made after it."""
     reg, name = ctx.registry, ctx.cell["name"]
     d0 = ctx.devices[0]
     device: Dict[str, Any] = {
@@ -261,18 +338,17 @@ def result_line(ctx: Context, out: Outcome, trace: bool) -> Dict[str, Any]:
                             "device": device}
     values = dict(out.end_to_end)
     values["setup_s"] = out.window_start - ctx.t_process
-    if not trace:
+    if trace != 1:
         for m in reg.metrics_of(name, "end_to_end"):
             if m["name"] not in values:
                 raise BenchError(f"the driver gave no {m['name']!r}")
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
+    if not trace:
         return line
 
     from chipbench.reduce import xplane
-    ctx.log(f"reducing {ctx.tracer.path} "
-            f"({os.path.getsize(ctx.tracer.path) / 2**20:.1f} MiB)")
-    tr = xplane.load(ctx.tracer.path, ANNOTATIONS)
+    tr, view = load_view(ctx, out, trace, values)
     t0, t1 = xplane.window(tr)
     busy = xplane.busy_seconds(tr)
     device["busy_s"] = sum(busy.values()) / len(busy)
@@ -281,27 +357,26 @@ def result_line(ctx: Context, out: Outcome, trace: bool) -> Dict[str, Any]:
     for ev in tr.host:
         n, sec = spans.get(ev.name, (0, 0.0))
         spans[ev.name] = (n + 1, sec + ev.dur_ns * 1e-9)
+    most = sorted(spans.items(), key=lambda kv: -kv[1][1])[:16]
     ctx.log(f"trace: window {device['window_s']:.3f} s, busy "
-            f"{device['busy_s']:.3f} s; the harness's spans in it "
-            f"{ {k: (n, round(s, 3)) for k, (n, s) in spans.items()} }")
+            f"{device['busy_s']:.3f} s; host spans in it (count, seconds) "
+            f"{ {k: (n, round(s, 3)) for k, (n, s) in most} }")
     rows = [r for r in xplane.module_table(tr) if r["mean_ms"] >= 0.05]
     for row in rows[:8]:
         ctx.log(f"trace: {row['module']}: {row['calls']} runs, mean "
                 f"{row['mean_ms']:.3f} ms, total {row['total_ms']:.1f} ms")
-    view = {"trace": tr, "counters": out.counters, "values": values,
-            "peaks": ctx.peaks, "config": ctx.config, "traffic": ctx.traffic,
-            "cell": ctx.cell, "chips": len(ctx.devices)}
     for m in reg.metrics_of(name, "per_layer"):
         spec = reg.layer_metric(m["name"])
         value = reg.reader(spec["reader"])(view, **spec.get("args", {}))
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-    line["breakdown"] = {"device_ops": xplane.top_ops(tr, 10),
+    line["breakdown"] = {"device_ops": xplane.top_ops(tr, 10,
+                                                      view.get("op_names")),
                          "idle_gaps": xplane.idle_gaps(tr, 10)}
     return line
 
 
-def run_cell(workload: str, seed: int, seconds: float, trace: bool,
+def run_cell(workload: str, seed: int, seconds: float, trace: int,
              t_process: float, registry: Optional[Registry] = None) -> int:
     reg = registry or Registry()
     try:
@@ -315,14 +390,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     # every program is persisted, however fast it compiled: the second run
     # in a checkout must find all of them
     cache_dir = setup_compile_cache(min_compile_time_secs=0.0)
+    trace = int(trace)
     ctx = Context(registry=reg, cell=cell, config=reg.config(cell["config"]),
                   traffic=reg.traffic(cell["traffic"]), seed=seed,
                   seconds=seconds, devices=devices, peaks=peaks,
                   compiles=CompileCounter(), t_process=t_process)
     if trace:
-        ctx.tracer = TraceWindow(
+        window = CaptureWindow if trace == 2 else TraceWindow
+        traced = window(
             os.path.join(reg.root, "chipbench_out", "trace", workload),
             float(cell.get("trace_seconds", 2.0)))
+        if trace == 2:
+            ctx.capture = traced
+        else:
+            ctx.tracer = traced
     ctx.log(f"cell {workload}: config {cell['config']}, traffic "
             f"{cell['traffic']}, driver {cell['driver']}, seed {seed}, "
             f"{seconds} s, trace {int(trace)}; device {devices[0].device_kind}"
@@ -333,6 +414,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     except BenchError as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return 2
+    finally:
+        if ctx.capture is not None:
+            ctx.capture.discard()       # reduced, or of no use
     ctx.log(f"compiles in this process: {len(ctx.compiles.ended)} "
             f"({ctx.compiles.seconds:.1f} s in the backend)")
     print(json.dumps(line), flush=True)

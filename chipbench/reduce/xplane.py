@@ -310,9 +310,12 @@ def module_ms(trace: Trace, min_mean_ms: float = 0.0) -> Optional[float]:
     return table[0]["mean_ms"] if table else None
 
 
-def top_ops(trace: Trace, n: int = 10) -> List[List]:
+def top_ops(trace: Trace, n: int = 10,
+            op_names: Optional[Dict[str, Dict[str, str]]] = None) -> List[List]:
     """The ``n`` operations with most self time, named
-    ``<program>/<instruction> <opcode>``; seconds summed over chips."""
+    ``<program>/<instruction> <opcode>``; seconds summed over chips. With
+    ``op_names`` (``hlo_names.load`` of a ``--trace 2`` capture) the name
+    ends in ``@`` and the scopes the program gave the operation."""
     total: Dict[str, float] = {}
     for dev in trace.devices.values():
         mods = dev.modules
@@ -324,6 +327,11 @@ def top_ops(trace: Trace, n: int = 10) -> List[List]:
             prog = module_name(mods[k].name)[0] if inside else "?"
             code = "mosaic" if is_mosaic(ev.name) else opcode(ev.name)
             key = f"{prog}/{instruction(ev.name)} {code}"[:120]
+            if op_names is not None and inside:
+                named = op_names.get(mods[k].name, {}).get(
+                    instruction(ev.name).lstrip("%"))
+                if named:
+                    key += " @" + "/".join(named.split("/")[-3:-1])
             total[key] = total.get(key, 0.0) + t * 1e-9
     rows = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[k, v] for k, v in rows]
@@ -331,22 +339,25 @@ def top_ops(trace: Trace, n: int = 10) -> List[List]:
 
 def idle_gaps(trace: Trace, n: int = 10) -> List[List]:
     """Idle time of the first chip by what the host was doing: a gap of a
-    millisecond or more between operations goes to the benchmark's
-    annotation that overlaps most of it (at least half), every other gap to
-    ``unattributed`` by length. Seconds, largest first."""
+    millisecond or more between operations goes to the host span (the
+    benchmark's annotations; in a ``--trace 2`` run the program's spans too)
+    that overlaps most of it (at least half; of spans that overlap it alike,
+    as nested ones do, the shortest), every other gap to ``unattributed`` by
+    length. Seconds, largest first."""
     first = trace.devices[min(trace.devices)]
     spans = union(first.ops)
     total: Dict[str, float] = {}
     for (_, end), (start, _) in zip(spans, spans[1:]):
         gap = start - end
-        best, best_ns = None, 0.0
+        best, best_ns, best_dur = None, 0.0, 0.0
         if gap >= 1e6:
             for ev in trace.host:
                 if ev.start_ns >= start:
                     break
                 over = min(start, ev.end_ns) - max(end, ev.start_ns)
-                if over > best_ns:
-                    best, best_ns = ev.name, over
+                if over > best_ns or (over == best_ns and over > 0.0
+                                      and ev.dur_ns < best_dur):
+                    best, best_ns, best_dur = ev.name, over, ev.dur_ns
         if best is None or best_ns < 0.5 * gap:
             best = ("unattributed (<0.1 ms)" if gap < 1e5 else
                     "unattributed (0.1-1 ms)" if gap < 1e6 else

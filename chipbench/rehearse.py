@@ -1,6 +1,6 @@
 """Rehearse a cell on the CPU, where there is no chip (costs no chip time).
 
-    python -m chipbench.rehearse --workload <cell> [--seconds 3] [--trace 0]
+    python -m chipbench.rehearse --workload <cell> [--seconds 3] [--trace 0|2]
 
 Runs the cell's own driver, reference check and traffic generator at a tiny
 size: the configuration's widths shrunk (head_dim stays 128 so the kernel
@@ -9,12 +9,19 @@ virtual CPU devices for a four-chip cell. It finds wrong arguments, shapes
 and control flow. It prints counts only — requests, tokens, steps, whether
 the check passed — and never a time, a rate or a device metric's name: a
 CPU run says nothing about them.
+
+``--trace 2`` rehearses the run that measures and then traces: the driver's
+extra segment under the program's capture, the reduction and every reader of
+the cell's per-layer metrics. Its last line has a result line's form with the
+values withheld: each metric says only whether its reader found something to
+read (a CPU trace has no device plane, so device readers do not).
 """
 
 from __future__ import annotations
 
 import argparse
 import copy
+import json
 import os
 import sys
 import time
@@ -81,9 +88,11 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=3.0)
+    ap.add_argument("--trace", type=int, choices=(0, 2), default=0)
     args = ap.parse_args(argv)
 
-    from chipbench.harness import CompileCounter, Context, Registry
+    from chipbench.harness import (CaptureWindow, CompileCounter, Context,
+                                   Registry)
     reg = Registry()
     cell = reg.cell(args.workload)
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -99,12 +108,47 @@ def main(argv=None) -> int:
                   seed=args.seed, seconds=args.seconds,
                   devices=jax.devices(), peaks={}, compiles=CompileCounter(),
                   t_process=_T_PROCESS, on_chip=False)
+    if args.trace == 2:
+        ctx.capture = CaptureWindow(
+            os.path.join(reg.root, "chipbench_out", "rehearsal",
+                         args.workload), min(1.0, args.seconds))
     out = reg.driver(cell["driver"])(ctx)
     print(f"rehearsal of {args.workload} on {len(jax.devices())} CPU "
           f"device(s), tiny widths: check and outputs correct "
           f"{out.correct}; attempted {out.attempted}, failed {out.failed}; "
           f"counts {({k: v for k, v in out.counters.items() if k in COUNTS})}")
+    if args.trace == 2:
+        try:
+            print(json.dumps(rehearsal_line(ctx, out)), flush=True)
+        finally:
+            ctx.capture.discard()
     return 0 if out.correct else 1
+
+
+def rehearsal_line(ctx, out) -> dict:
+    """The form of a ``--trace 2`` result line, values withheld."""
+    from chipbench.harness import BenchError, load_view
+    reg, name = ctx.registry, ctx.cell["name"]
+    values = dict(out.end_to_end, setup_s=0.0)
+    _, view = load_view(ctx, out, 2, values)
+    metrics = {}
+    for m in reg.metrics_of(name, "end_to_end"):
+        if m["name"] not in values:
+            raise BenchError(f"the driver gave no {m['name']!r}")
+        metrics[m["name"]] = {"unit": m["unit"], "read": True}
+    for m in reg.metrics_of(name, "per_layer"):
+        spec = reg.layer_metric(m["name"])
+        try:
+            value = reg.reader(spec["reader"])(view, **spec.get("args", {}))
+        except (ValueError, KeyError):
+            value = None        # no device plane, no peaks table: no chip
+        metrics[m["name"]] = {"unit": m["unit"], "read": value is not None}
+    d0 = ctx.devices[0]
+    return {"rehearsal": True, "correct": bool(out.correct),
+            "attempted": int(out.attempted), "failed": int(out.failed),
+            "metrics": metrics,
+            "device": {"platform": d0.platform, "kind": d0.device_kind,
+                       "count": len(ctx.devices)}}
 
 
 if __name__ == "__main__":

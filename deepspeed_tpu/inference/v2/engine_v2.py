@@ -44,6 +44,7 @@ from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
 from deepspeed_tpu.monitor.trace import install_from_env as _trace_from_env
 from deepspeed_tpu.monitor.trace import tracer as _tracer
 from deepspeed_tpu.utils.caching import LRUCache, next_pow2
+from deepspeed_tpu.utils.compile_cache import backend_compiles as _backend_compiles
 from deepspeed_tpu.utils import locksan as _locksan
 from deepspeed_tpu.utils.fault_injection import maybe_fail as _maybe_fail
 from deepspeed_tpu.utils.logging import log_dist
@@ -79,8 +80,23 @@ def fetch_to_host(arr) -> np.ndarray:
     return out
 
 
+def _program(fn, name: str, **jit_kwargs):
+    """``jax.jit(fn)`` under a name of its own. Every builder's inner function
+    is called ``fwd``; under its name the program is ``jit_<name>`` in a
+    device trace, the compile log and the cache key (docs/OBSERVABILITY.md,
+    "Names on the device's work"). A split-K rung above 1 is part of the
+    name (``_sk<r>``)."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
+def _rung(sp: int) -> str:
+    return "" if int(sp) <= 1 else f"_sk{int(sp)}"
+
+
 @functools.partial(jax.jit, static_argnums=(3, 4))
-def _dev_sample(arr, rows, key, do_sample: bool, top_k: int, temperature=1.0):
+def serve_sample_rows(arr, rows, key, do_sample: bool, top_k: int,
+                      temperature=1.0):
     """Gather rows + greedy / temperature / top-k sampling, ONE device call.
     arr [P, V] (or [V] with rows=None semantics handled by caller reshaping);
     rows [n] int32."""
@@ -95,6 +111,15 @@ def _dev_sample(arr, rows, key, do_sample: bool, top_k: int, temperature=1.0):
 
 
 class InferenceEngineV2:
+
+    @property
+    def backend_compiles(self) -> int:
+        """Programs the PROCESS compiled or loaded from the persistent cache
+        since this engine was built (jax's monitoring events, through
+        ``utils/compile_cache.py``): module-level jits and eager helper
+        operations included, which ``compiles`` — a count of this engine's
+        own program builds — cannot see."""
+        return _backend_compiles() - self._backend_compiles_base
 
     def __init__(self,
                  model: Any = None,
@@ -114,6 +139,7 @@ class InferenceEngineV2:
         # cache). Warmup pre-builds the serving grid; a serving loop whose
         # batch sizes stay in-grid must never increment this again.
         self.compiles = 0
+        self._backend_compiles_base = _backend_compiles()
         tp = cfg.tensor_parallel
         if mesh_topology is not None:
             self.topology = set_topology(mesh_topology)
@@ -235,7 +261,7 @@ class InferenceEngineV2:
                 "ragged engine (shard-local slope schedules would be wrong); "
                 "run tp=1 or serve through init_inference")
         fwd = build_ragged_forward(self.spec, mesh=self.topology.mesh, tp=tp)
-        self._pass = jax.jit(fwd, donate_argnums=(1,))
+        self._pass = _program(fwd, "serve_paged_pass", donate_argnums=(1,))
         self.compiles += 1
         # flash-decoding split ladder (config.attention; docs/SERVING.md
         # "Attention kernels"): one ragged-pass program per pow2 rung.
@@ -250,7 +276,8 @@ class InferenceEngineV2:
         for r in self.attn_split_ladder[1:]:
             fwd_r = build_ragged_forward(self.spec, mesh=self.topology.mesh,
                                          tp=tp, n_splits=r)
-            self._pass_rungs[r] = jax.jit(fwd_r, donate_argnums=(1,))
+            self._pass_rungs[r] = _program(
+                fwd_r, "serve_paged_pass" + _rung(r), donate_argnums=(1,))
             self.compiles += 1
         # bench/test knob: pin the dispatched rung (None = admission-driven)
         self.attn_rung_override: Optional[int] = None
@@ -520,7 +547,7 @@ class InferenceEngineV2:
             # referenced): host-rematerialized sources appear whenever a
             # preempt-offloaded sequence is restored (serving/kv_offload.py
             # parks the victim's last logits row on host), and a count-shaped
-            # [n, V] upload would compile a fresh _dev_sample per distinct
+            # [n, V] upload would compile a fresh serve_sample_rows per distinct
             # restore count — in the middle of the steady state the
             # zero-compile gate polices. pow2 shapes land in the warmed grid.
             pad = next_pow2(len(host_rows)) - len(host_rows)
@@ -536,11 +563,11 @@ class InferenceEngineV2:
             # pad the row set to its bucket (utils.caching.next_pow2): a
             # serving loop calls this with a DIFFERENT number of live
             # sequences every time a sequence retires, and each distinct
-            # length would recompile _dev_sample (seconds each). Extra rows
+            # length would recompile serve_sample_rows (seconds each). Extra rows
             # resample row 0 and are sliced off.
             n_real = len(rows)
             rows = rows + [rows[0]] * (next_pow2(n_real) - n_real)
-            out = _dev_sample(arr, np.asarray(rows, np.int32), sub,
+            out = serve_sample_rows(arr, np.asarray(rows, np.int32), sub,
                               bool(do_sample), int(top_k),
                               float(temperature))
             parts.append(out)                 # padded; real rows are [:n_real]
@@ -642,7 +669,8 @@ class InferenceEngineV2:
                                     lora_targets=self._lora_targets(rb),
                                     n_splits=sp)
             self.compiles += 1
-            return jax.jit(fwd, donate_argnums=(1,))
+            return _program(fwd, "serve_decode_step" + _rung(sp),
+                            donate_argnums=(1,))
 
         return self._step_progs.get_or_create(
             (bucket, bool(do_sample), int(top_k), int(rb), sp), _build)
@@ -755,7 +783,8 @@ class InferenceEngineV2:
                                     lora_targets=self._lora_targets(rb),
                                     n_splits=sp)
             self.compiles += 1
-            return jax.jit(fwd, donate_argnums=(1,))
+            return _program(fwd, "serve_verify_step" + _rung(sp),
+                            donate_argnums=(1,))
 
         return self._verify_progs.get_or_create(
             (bucket, int(k), int(rb), sp), _build)
@@ -812,7 +841,7 @@ class InferenceEngineV2:
         decode-step program for every bucket (greedy — the serving default;
         sampled variants compile on first use), fused multistep programs for
         each ``burst_steps`` length across the grid, and the module-level
-        bootstrap sampler ``_dev_sample`` over the logits-source shapes the
+        bootstrap sampler ``serve_sample_rows`` over the logits-source shapes the
         serving loops read (chunk/decode pass outputs, per-bucket fused
         outputs, and pow2-padded host-rematerialized blocks — restore paths
         re-upload through the same bucket grid). Also warms the KV page
@@ -928,14 +957,14 @@ class InferenceEngineV2:
         # the greedy bootstrap sampler over every logits-source shape a
         # serving loop can hand it: without this, the FIRST pipeline run /
         # burst after startup pays a small-but-real compile that the engine
-        # counter cannot witness (_dev_sample is a module-level jit)
+        # counter cannot witness (serve_sample_rows is a module-level jit)
         sm = self.config.state_manager
         V = self.spec.vocab_size
         src_rows = {sm.num_chunk_slots, sm.max_ragged_sequence_count} | set(grid)
         for b in grid:
             rows = np.zeros((b,), np.int32)
             for nr in src_rows:
-                jax.block_until_ready(_dev_sample(
+                jax.block_until_ready(serve_sample_rows(
                     jnp.zeros((nr, V), jnp.float32), rows, self._rng_key,
                     False, 0, 1.0))
         built = self.compiles - before
@@ -958,7 +987,8 @@ class InferenceEngineV2:
             window_ring_ok=self.scheduler.ring_covers(n_steps + 1),
             n_splits=int(sp))
         self.compiles += 1
-        return jax.jit(fwd, donate_argnums=(1,))
+        return _program(fwd, "serve_decode_multistep" + _rung(sp),
+                        donate_argnums=(1,))
 
     def _scratch_step_args(self, bucket: int, max_blocks: int):
         """All-pad-row inputs for a fused decode program: every row is the
@@ -1050,17 +1080,19 @@ class InferenceEngineV2:
         if self._pass_prefill is None:
             from deepspeed_tpu.inference.v2.ragged_model import (
                 build_prefill_forward)
-            self._pass_prefill = jax.jit(
+            self._pass_prefill = _program(
                 build_prefill_forward(self.spec, mesh=self.topology.mesh,
                                       tp=self.config.tensor_parallel),
-                donate_argnums=(1,))
+                "serve_prefill_packed", donate_argnums=(1,))
             self.compiles += 1
         return self._pass_prefill
 
-    def _run_pass(self) -> None:
+    def _run_pass(self):
+        """Schedule and dispatch one pass; returns its batch (None when
+        nothing was pending) so that a caller can say what the pass held."""
         batch = self.scheduler.schedule_pass()
         if batch is None:
-            return
+            return None
         arrays = batch.device_arrays()
         # each jitted pass receives only the keys it reads (the two paths are
         # separate jit functions; shipping the other path's descriptors is
@@ -1092,6 +1124,7 @@ class InferenceEngineV2:
             else:
                 self._last_ref[uid] = (decode_logits,
                                        batch.decode_uids.index(uid))
+        return batch
 
     def query(self, uid: int, max_request_tokens: int) -> Tuple[int, int]:
         return self.scheduler.query(uid, max_request_tokens)
@@ -1129,19 +1162,19 @@ class InferenceEngineV2:
         if self._page_progs is None:
 
             @jax.jit
-            def _gather(kv, blocks):
+            def serve_kv_page_gather(kv, blocks):
                 # page-major on the way out: host slices [i] are contiguous
                 return jax.tree_util.tree_map(
                     lambda a: jnp.moveaxis(jnp.take(a, blocks, axis=1),
                                            1, 0), kv)
 
             @functools.partial(jax.jit, donate_argnums=(0,))
-            def _scatter(kv, pages, blocks):
+            def serve_kv_page_scatter(kv, pages, blocks):
                 return jax.tree_util.tree_map(
                     lambda a, p: a.at[:, blocks].set(jnp.moveaxis(p, 0, 1)),
                     kv, pages)
 
-            self._page_progs = (_gather, _scatter)
+            self._page_progs = (serve_kv_page_gather, serve_kv_page_scatter)
         return self._page_progs
 
     @property

@@ -154,8 +154,15 @@ class DecodePipeline:
         # while a request is in flight — the registry's refcount gate): empty
         # at rb=0, so adapter-free engines dispatch the identical program
         lora_args = e._lora_operands(uids, db.bucket, rb)
+        tb = perf() if _tracer.enabled else 0.0
         ids, _ = e._sample_device_padded(uids, self.do_sample,
                                          self.temperature, self.top_k)
+        if tb:
+            # the sampler that opens a slice: eager helpers keyed by how the
+            # rows split over logits arrays, so a compile/backend span here
+            # names a helper that was not warm
+            _tracer.add("serve/decode/bootstrap", tb, perf(),
+                        lane="serve/decode", rows=S)
         assert ids.shape[0] == db.bucket
         if hasattr(ids, "copy_to_host_async"):
             ids.copy_to_host_async()

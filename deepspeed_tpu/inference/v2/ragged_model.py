@@ -361,23 +361,25 @@ def _rope_flat(x: jax.Array, positions: jax.Array, theta: float,
     return _partial_rope(x[None], positions[None], theta, rotary_dim)[0]
 
 
+@jax.named_scope("moe_ffn")
 def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype) -> jax.Array:
     """Sort-based token dispatch + grouped GEMM (parity: reference moe_scatter ->
     CUTLASS moe_gemm -> moe_gather, inference/v2/kernels). x: [T, hid]."""
     T, hid = x.shape
     E = w["router"].shape[-1]
-    logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)   # [T, E]
-    gates, ids = jax.lax.top_k(logits, top_k)                          # [T, K]
-    gates = jax.nn.softmax(gates, axis=-1)
+    with jax.named_scope("router"):
+        logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)  # [T, E]
+        gates, ids = jax.lax.top_k(logits, top_k)                      # [T, K]
+        gates = jax.nn.softmax(gates, axis=-1)
 
-    tok_idx = jnp.repeat(jnp.arange(T), top_k)                         # [T*K]
-    expert_ids = ids.reshape(-1)
-    order = jnp.argsort(expert_ids)
-    src = tok_idx[order]
-    xs = x[src]                                                        # [T*K, hid]
-    group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
-
-    row_e = expert_ids[order]
+    with jax.named_scope("sort"):
+        tok_idx = jnp.repeat(jnp.arange(T), top_k)                     # [T*K]
+        expert_ids = ids.reshape(-1)
+        order = jnp.argsort(expert_ids)
+        src = tok_idx[order]
+        xs = x[src]                                                    # [T*K, hid]
+        group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
+        row_e = expert_ids[order]
 
     def gg(lhs, rhs):
         if isinstance(rhs, dict) and "w8" in rhs:
@@ -392,16 +394,18 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype) -> jax.Array:
             return (raw * rhs["scale"][row_e, 0, :]).astype(lhs.dtype)
         return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes)
 
-    if "w_gate" in w:
-        h = jax.nn.silu(gg(xs, w["w_gate"])) * gg(xs, w["w_up"])
-    else:
-        h = jax.nn.gelu(gg(xs, w["w_up"]))
-    ys = gg(h, w["w_down"])                                            # [T*K, hid]
-    scale = gates.reshape(-1)[order].astype(ys.dtype)
-    # scatter-free combine: invert the sort permutation and sum the K
-    # choices (parallel/moe.py dropless_moe — TPU scatter-add serializes)
-    inv = jnp.argsort(order)
-    out = (ys * scale[:, None])[inv].reshape(T, top_k, hid).sum(axis=1)
+    with jax.named_scope("experts"):
+        if "w_gate" in w:
+            h = jax.nn.silu(gg(xs, w["w_gate"])) * gg(xs, w["w_up"])
+        else:
+            h = jax.nn.gelu(gg(xs, w["w_up"]))
+        ys = gg(h, w["w_down"])                                        # [T*K, hid]
+    with jax.named_scope("combine"):
+        scale = gates.reshape(-1)[order].astype(ys.dtype)
+        # scatter-free combine: invert the sort permutation and sum the K
+        # choices (parallel/moe.py dropless_moe — TPU scatter-add serializes)
+        inv = jnp.argsort(order)
+        out = (ys * scale[:, None])[inv].reshape(T, top_k, hid).sum(axis=1)
     return out.astype(dtype)
 
 
@@ -615,22 +619,25 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     """
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
-    h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype, spec.norm_plus_one)
-    q = _lora_mm(h1, w["wq"], lora, "q").reshape(-1, H, D)
-    k = _lora_mm(h1, w["wk"], lora, "k").reshape(-1, Hkv, D)
-    v = _lora_mm(h1, w["wv"], lora, "v").reshape(-1, Hkv, D)
-    if "bq" in w:
-        q = q + w["bq"].reshape(H, D)
-        k = k + w["bk"].reshape(Hkv, D)
-        v = v + w["bv"].reshape(Hkv, D)
-    if spec.rope_theta is not None:
-        q = _rope_flat(q, positions, spec.rope_theta, spec.rotary_dim)
-        k = _rope_flat(k, positions, spec.rope_theta, spec.rotary_dim)
+    # the two halves carry scopes: a device trace tells the layer's
+    # attention (projections, rope, KV write, kernel) from its FFN
+    with jax.named_scope("attn"):
+        h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype, spec.norm_plus_one)
+        q = _lora_mm(h1, w["wq"], lora, "q").reshape(-1, H, D)
+        k = _lora_mm(h1, w["wk"], lora, "k").reshape(-1, Hkv, D)
+        v = _lora_mm(h1, w["wv"], lora, "v").reshape(-1, Hkv, D)
+        if "bq" in w:
+            q = q + w["bq"].reshape(H, D)
+            k = k + w["bk"].reshape(Hkv, D)
+            v = v + w["bv"].reshape(Hkv, D)
+        if spec.rope_theta is not None:
+            q = _rope_flat(q, positions, spec.rope_theta, spec.rotary_dim)
+            k = _rope_flat(k, positions, spec.rope_theta, spec.rotary_dim)
 
-    attn_raw, *state = attend(q, k, v)
-    attn_out = _lora_mm(attn_raw.reshape(-1, H * D), w["wo"], lora, "o")
-    if "bo" in w:
-        attn_out = attn_out + w["bo"]
+        attn_raw, *state = attend(q, k, v)
+        attn_out = _lora_mm(attn_raw.reshape(-1, H * D), w["wo"], lora, "o")
+        if "bo" in w:
+            attn_out = attn_out + w["bo"]
 
     if spec.parallel_block:
         mlp_in = (_norm(x, w["ln2"], spec.norm, spec.eps, dtype,
@@ -641,22 +648,23 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
         mlp_in = _norm(x, w["ln2"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)
 
-    if spec.moe is not None:
-        mlp_out = _moe_ffn(mlp_in, w["moe"], spec.moe["top_k"], dtype)
-    else:
-        m = w["mlp"]
-        if spec.activation in ("swiglu", "geglu"):
-            gate_act = jax.nn.silu if spec.activation == "swiglu" else jax.nn.gelu
-            hmid = gate_act(_mm(mlp_in, m["w_gate"])) * _mm(mlp_in, m["w_up"])
+    with jax.named_scope("ffn"):
+        if spec.moe is not None:
+            mlp_out = _moe_ffn(mlp_in, w["moe"], spec.moe["top_k"], dtype)
         else:
-            act = _plain_act(spec.activation)
-            hmid = _mm(mlp_in, m["w_up"])
-            if "b_up" in m:
-                hmid = hmid + m["b_up"]
-            hmid = act(hmid)
-        mlp_out = _mm(hmid, m["w_down"])
-        if "b_down" in m:
-            mlp_out = mlp_out + m["b_down"]
+            m = w["mlp"]
+            if spec.activation in ("swiglu", "geglu"):
+                gate_act = jax.nn.silu if spec.activation == "swiglu" else jax.nn.gelu
+                hmid = gate_act(_mm(mlp_in, m["w_gate"])) * _mm(mlp_in, m["w_up"])
+            else:
+                act = _plain_act(spec.activation)
+                hmid = _mm(mlp_in, m["w_up"])
+                if "b_up" in m:
+                    hmid = hmid + m["b_up"]
+                hmid = act(hmid)
+            mlp_out = _mm(hmid, m["w_down"])
+            if "b_down" in m:
+                mlp_out = mlp_out + m["b_down"]
 
     if spec.parallel_block:
         x = x + attn_out + mlp_out

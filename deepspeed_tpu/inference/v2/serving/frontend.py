@@ -326,6 +326,9 @@ class ServingFrontend:
         self._thread: Optional[threading.Thread] = None
         self._loop_exc: Optional[BaseException] = None
         self._closed = False
+        # where the engine thread's running serve/loop phase began (_mark);
+        # 0.0 while tracing is off
+        self._loop_t = 0.0
         # fenced = declared down by a health monitor: the loop (even a
         # wedged one that wakes later) must emit nothing further — every
         # in-flight stream now belongs to the replica it migrated to
@@ -652,9 +655,16 @@ class ServingFrontend:
                 if not self.step():
                     if self._fenced:
                         break                 # failover owns the queue now
+                    tr = _tracer.enabled
+                    t0 = time.perf_counter() if tr else 0.0
                     try:                      # idle: block on control traffic
                         msg = self._ctl.get(timeout=self.config.idle_wait_s)
                     except queue.Empty:
+                        msg = None
+                    if tr:
+                        _tracer.add("serve/loop/idle", t0, time.perf_counter(),
+                                    lane="serve/loop")
+                    if msg is None:
                         continue
                     if self._fenced:
                         self._ctl.put(msg)    # failover's scrape owns it
@@ -682,14 +692,52 @@ class ServingFrontend:
         maybe_fail(self._fault_site)
         if self._fenced:
             return False
+        # under tracing the iteration's phases tile it on the serve/loop
+        # lane: each closes (``_mark``) where the next begins, so none
+        # overlap and nothing between them is dark
+        self._loop_t = time.perf_counter() if _tracer.enabled else 0.0
         self._drain_control()
         self._sweep_cancels()
         worked = self._execute_handoffs()
+        if _tracer.enabled:
+            self._mark("serve/loop/control")
         worked = self._admission_round() or worked
+        if _tracer.enabled:
+            self._mark("serve/loop/admission")
         if self._pipe.uids:
             self._decode_slice()
             worked = True
+            if _tracer.enabled:
+                self._mark("serve/loop/decode_slice")
         return worked
+
+    def _mark(self, name: str, **args) -> None:
+        """Close the engine thread's running phase under ``name`` (a span on
+        the ``serve/loop`` lane from where the last phase ended to now).
+        Callers test ``_tracer.enabled`` first. A cursor of 0.0 means tracing
+        came on in the middle of this iteration: nothing is recorded for the
+        phase it came on in."""
+        now = time.perf_counter()
+        if self._loop_t:
+            _tracer.add(name, self._loop_t, now, lane="serve/loop", **args)
+        self._loop_t = now
+
+    def _pass(self) -> None:
+        """One engine pass over pending prompt chunks; under tracing a
+        ``serve/prefill/pass`` phase (the host's share: scheduling and
+        dispatch — the device runs the pass after the span has ended, and
+        every live decode row waits for it there), with what came before it
+        in this round closed as admission."""
+        if not _tracer.enabled:
+            self.engine._run_pass()
+            return
+        self._mark("serve/loop/admission")
+        batch = self.engine._run_pass()
+        if batch is None:
+            return
+        self._mark("serve/prefill/pass", slots=len(batch.slot_uid),
+                   tokens=int(batch.chunk_ntok.sum()),
+                   kind="packed" if batch.pure_prefill else "paged")
 
     def _handle(self, msg) -> None:
         kind, payload = msg
@@ -1040,7 +1088,7 @@ class ServingFrontend:
         t0 = time.perf_counter()
         tokens = sum(len(r.prompt) for r in reqs)
         while e.scheduler.has_pending():
-            e._run_pass()
+            self._pass()
             if self._fenced:
                 return       # fenced mid-prefill: failover owns every handle
             for req in reqs:
@@ -1185,7 +1233,7 @@ class ServingFrontend:
             req._resume_tokens = None
             e = self.engine
             while e.scheduler.has_pending():
-                e._run_pass()
+                self._pass()
                 if self._fenced or req.cancelled:
                     break
             if self._fenced:

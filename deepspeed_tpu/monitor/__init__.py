@@ -9,15 +9,14 @@ from deepspeed_tpu.monitor.export import (PrometheusExporter, TelemetryPump,
 from deepspeed_tpu.monitor.monitor import (CsvMonitor, Monitor, MonitorMaster,
                                            TensorBoardMonitor, WandbMonitor)
 from deepspeed_tpu.monitor.serving import PipelineStats
-from deepspeed_tpu.monitor.trace import Tracer, tracer
+from deepspeed_tpu.monitor.trace import Capture, Tracer, tracer
 from deepspeed_tpu.monitor.training import (CheckpointStats,
                                             OffloadPipelineStats,
                                             RolloutStats,
-                                            TrainPipelineStats,
-                                            Zero3CommStats)
+                                            TrainPipelineStats)
 
 __all__ = ["Monitor", "MonitorMaster", "TensorBoardMonitor", "WandbMonitor",
            "CsvMonitor", "PrometheusExporter", "TelemetryPump",
            "sanitize_metric_name", "PipelineStats", "TrainPipelineStats",
-           "OffloadPipelineStats", "CheckpointStats", "Zero3CommStats",
-           "RolloutStats", "Tracer", "tracer"]
+           "OffloadPipelineStats", "CheckpointStats", "RolloutStats",
+           "Capture", "Tracer", "tracer"]

@@ -61,16 +61,29 @@ nesting within a track is always well-formed.
 Enable via ``DSTPU_TRACE=<dir>`` (arms in ``deepspeed_tpu.initialize`` and
 the v2 inference engine) or ``config.monitor.trace`` — docs/OBSERVABILITY.md
 walks the taxonomy, Perfetto workflow, and overhead numbers.
+
+**Captures** put these spans and the device's work on one clock.
+``tracer.capture_start(dir)`` turns the rings on, starts ``jax.profiler`` and
+writes an anchor ``TraceAnnotation`` whose ``perf_counter`` time is known;
+``tracer.capture_stop()`` writes a second anchor, stops the profiler, puts
+``enabled`` back and returns a :class:`Capture`: the ``.xplane.pb``, the ring
+records of the interval with their endpoints on the trace's host clock
+(offset from the first anchor, drift from the second), and what the
+always-on counters (:meth:`Tracer.bump`) gained. Device work is never timed
+with host stamps: it is named (``jax.named_scope``, program names) and read
+from the device trace; host work is a span here.
 """
 
 from __future__ import annotations
 
 import atexit
+import glob
 import json
 import os
 import re
 import threading
 import time
+from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from deepspeed_tpu.utils.logging import logger
@@ -95,6 +108,11 @@ _REQ_LANE_RE = re.compile(r"^serve/req/u\d+$")
 
 # record kinds (Chrome trace phase at export: span -> B/E pair)
 _SPAN, _INSTANT, _COUNTER = "X", "i", "C"
+
+#: the two ``TraceAnnotation`` events a capture writes into the profiler's
+#: trace; their ``perf_counter`` times are known, so they map one clock onto
+#: the other
+CAPTURE_ANCHOR = "dstpu/capture/anchor"
 
 
 #: dead threads' rings retained for export/crash dumps (a finished prefetch
@@ -154,7 +172,7 @@ class Span:
     """Context manager recording one interval on exit; ``.seconds`` is valid
     after exit (call sites may feed it to their stats counters)."""
 
-    __slots__ = ("_tracer", "name", "lane", "args", "t0", "t1")
+    __slots__ = ("_tracer", "name", "lane", "args", "t0", "t1", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, lane: Optional[str],
                  args: Optional[dict]):
@@ -164,13 +182,22 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self.t1 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        annotation = self._tracer._annotation
+        if annotation is not None:
+            # a capture runs: the span shows in the profiler's own viewer too
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         self._tracer._record((_SPAN, self.name, self.t0, self.t1, self.lane,
                               self.args))
         return False
@@ -178,6 +205,38 @@ class Span:
     @property
     def seconds(self) -> float:
         return self.t1 - self.t0
+
+
+@dataclass
+class Capture:
+    """What :meth:`Tracer.capture_stop` returns. Times ending in ``_ns`` are
+    on the host clock of the profiler's trace (``ProfileData`` event times)."""
+    trace_path: str
+    start_ns: float                 # the first anchor
+    stop_ns: float                  # the second anchor
+    #: ``(kind, name, t0_ns, t1_ns, lane, args, thread_name)`` of every ring
+    #: record that overlaps the interval (not clipped to it)
+    records: List[tuple] = field(default_factory=list)
+    #: what each always-on counter (:meth:`Tracer.bump`) gained
+    counters: Dict[str, float] = field(default_factory=dict)
+    #: ``trace_ns = start_ns + (perf_s - perf_start) * 1e9 * drift``
+    perf_start: float = 0.0
+    drift: float = 1.0
+    #: how far a mapping by the first anchor alone would have put the second
+    #: anchor from where the trace has it
+    skew_ns: float = 0.0
+    #: half the ``perf_counter`` bracket around each anchor's entry
+    anchor_uncertainty_ns: float = 0.0
+
+    def to_ns(self, perf_s: float) -> float:
+        return self.start_ns + (perf_s - self.perf_start) * 1e9 * self.drift
+
+    def spans(self, *names: str) -> List[Tuple[str, float, float]]:
+        """``(name, t0_ns, t1_ns)`` of the spans called one of ``names``,
+        clipped to the captured interval, in start order."""
+        out = [(r[1], max(r[2], self.start_ns), min(r[3], self.stop_ns))
+               for r in self.records if r[0] == _SPAN and r[1] in names]
+        return sorted((s for s in out if s[2] > s[1]), key=lambda s: s[1])
 
 
 class Tracer:
@@ -196,6 +255,14 @@ class Tracer:
         # one simultaneous (perf_counter, unix) pair: trace_merge.py maps
         # every file's perf-based timestamps onto one wall-clock axis with it
         self._clock_sync = (time.perf_counter(), time.time())
+        #: always-on cumulative counters (:meth:`bump`); a capture returns
+        #: what they gained over its interval
+        self.totals: Dict[str, float] = {}
+        self._totals_lock = make_lock("monitor.trace.totals")
+        self._capture_lock = make_lock("monitor.trace.capture")
+        self._capture: Optional[dict] = None      # state of a running capture
+        self._captures = 0
+        self._annotation = None     # jax.profiler.TraceAnnotation in a capture
 
     # ------------------------------------------------------------------ #
     # configuration
@@ -236,6 +303,7 @@ class Tracer:
         self.req_lane_window = DEFAULT_REQ_LANE_WINDOW
         self._crash_path = None
         self._clock_sync = (time.perf_counter(), time.time())
+        self._annotation = None
 
     # ------------------------------------------------------------------ #
     # recording
@@ -299,6 +367,103 @@ class Tracer:
         now = time.perf_counter()
         self._ring().add((_COUNTER, name, now, now, lane,
                           {"value": float(value)}))
+
+    def bump(self, name: str, value: float = 1.0) -> None:
+        """Add to an always-on cumulative counter (compiles, cache loads):
+        counted whether or not tracing is on, for sites that run rarely. A
+        capture reports what each gained over its interval."""
+        with self._totals_lock:
+            self.totals[name] = self.totals.get(name, 0.0) + value
+
+    # ------------------------------------------------------------------ #
+    # captures: the rings and the profiler over one interval, on one clock
+    # ------------------------------------------------------------------ #
+
+    def _anchor(self) -> Tuple[float, float]:
+        """Write one anchor annotation; its ``perf_counter`` time (the middle
+        of the bracket around its entry) and half the bracket's width."""
+        ann = self._annotation(CAPTURE_ANCHOR)
+        a = time.perf_counter()
+        ann.__enter__()
+        b = time.perf_counter()
+        ann.__exit__(None, None, None)
+        return 0.5 * (a + b), 0.5 * (b - a)  # jaxlint: disable=JL001 -- brackets a host annotation, no device work
+
+    def capture_start(self, directory: str) -> None:
+        """Start a capture: rings on, ``jax.profiler`` tracing into
+        ``directory/capture_<n>`` (python tracer off, host tracer level 2),
+        first anchor written. May be called from a helper thread, any number
+        of times in a process, one capture at a time."""
+        import jax
+        with self._capture_lock:
+            if self._capture is not None:
+                raise RuntimeError("a capture is already running")
+            # a directory of its own: the profiler names its output by the
+            # second, so two captures in one second would share a file
+            self._captures += 1
+            directory = f"{directory}{os.sep}capture_{self._captures:03d}"
+            os.makedirs(directory, exist_ok=True)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0     # spans, not python frames
+            options.host_tracer_level = 2
+            jax.profiler.start_trace(directory, profiler_options=options)
+            self._annotation = jax.profiler.TraceAnnotation
+            with self._totals_lock:
+                totals = dict(self.totals)
+            state = {"directory": directory, "was_enabled": self.enabled,
+                     "totals": totals}
+            self.enabled = True
+            state["perf_start"], state["bracket"] = self._anchor()
+            self._capture = state
+
+    def capture_stop(self) -> Capture:
+        """Stop the capture: second anchor, profiler stopped, ``enabled`` put
+        back to what it was, and the :class:`Capture` returned."""
+        import jax
+        with self._capture_lock:
+            state = self._capture
+            if state is None:
+                raise RuntimeError("no capture is running")
+            perf_stop, bracket = self._anchor()
+            self.enabled = state["was_enabled"]
+            self._annotation = None
+            self._capture = None
+            jax.profiler.stop_trace()
+            with self._totals_lock:
+                counters = {k: v - state["totals"].get(k, 0.0)
+                            for k, v in self.totals.items()}
+        new = _xplanes(state["directory"])
+        if len(new) != 1:
+            raise RuntimeError(f"expected one trace under "
+                               f"{state['directory']}, found {new}")
+        anchors = _anchor_times_ns(new[0])
+        if len(anchors) < 2:
+            raise RuntimeError(f"{new[0]} holds {len(anchors)} of the "
+                               f"capture's two anchors")
+        perf_start = state["perf_start"]
+        start_ns, stop_ns = anchors[0], anchors[-1]
+        span_ns = (perf_stop - perf_start) * 1e9
+        cap = Capture(trace_path=new[0], start_ns=start_ns, stop_ns=stop_ns,
+                      counters=counters, perf_start=perf_start,
+                      drift=(stop_ns - start_ns) / span_ns if span_ns else 1.0,
+                      skew_ns=(stop_ns - start_ns) - span_ns,
+                      anchor_uncertainty_ns=1e9 * max(bracket,
+                                                      state["bracket"]))
+        with self._reg_lock:
+            rings = list(self._rings)
+        for ring in rings:
+            for kind, name, t0, t1, lane, args in ring.snapshot():
+                if t1 >= perf_start and t0 <= perf_stop:
+                    cap.records.append((kind, name, cap.to_ns(t0),
+                                        cap.to_ns(t1), lane, args,
+                                        ring.thread_name))
+        logger.info(
+            f"capture: {1e-9 * (stop_ns - start_ns):.3f} s, "
+            f"{len(cap.records)} records; host clock to trace clock: skew "
+            f"{cap.skew_ns * 1e-3:.1f} us over the interval (drift "
+            f"{cap.drift - 1.0:+.2e}), anchors known to "
+            f"{cap.anchor_uncertainty_ns * 1e-3:.1f} us; {new[0]}")
+        return cap
 
     # ------------------------------------------------------------------ #
     # aggregation (the stats classes' view of the same measurements)
@@ -550,6 +715,24 @@ class Tracer:
         except Exception as e:  # pragma: no cover - depends on dying disk
             logger.warning(f"trace export at exit failed: "
                            f"{type(e).__name__}: {e}")
+
+
+def _xplanes(directory: str) -> List[str]:
+    return glob.glob(os.path.join(directory, "plugins", "profile", "*",
+                                  "*.xplane.pb"))
+
+
+def _anchor_times_ns(path: str) -> List[float]:
+    """Start times of the capture's anchors on the trace's host plane."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out.extend(ev.start_ns for ev in line.events
+                       if ev.name == CAPTURE_ANCHOR)
+    return sorted(out)
 
 
 #: the process-wide tracer every instrumentation site records through

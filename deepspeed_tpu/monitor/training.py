@@ -247,82 +247,6 @@ class OffloadPipelineStats:
 
 
 @dataclass
-class Zero3CommStats:
-    """Collective-schedule counters for the explicit ZeRO-3 prefetch path
-    (``runtime/zero/prefetch.py``; docs/TRAINING.md "ZeRO-3 collective
-    schedule"). Aggregated from the SAME ``jax.debug.callback`` stamps that
-    become the ``train/zero3/{gather,free,reduce_scatter}`` tracer spans
-    (PR 7 stats-equals-spans discipline) — one ``record_step`` per drained
-    training-step segment.
-
-    Phase semantics (per training step):
-
-    - ``fwd_gather``: summed wall time of the forward bucketed all-gathers
-      (wave w's stamp pair ``gather_start`` -> ``gather_end``; the start
-      stamp sits on the tie barrier's output, so the window opens exactly
-      when the schedule *allows* the gather, ``depth`` waves early).
-    - ``bwd_gather``: the reverse-order backward re-gathers, tied to each
-      wave's incoming cotangent.
-    - ``reduce_scatter``: grad reduction windows (wave backward's activation
-      cotangent ready -> sharded param grads ready). Logical name — on
-      XLA:CPU the op lowers to a true ``reduce-scatter`` via the bucketed
-      gather's transpose; the implicit path would have been all-reduce+slice.
-    - ``overlap``: gather wall time intersected with OTHER waves' residency
-      windows (``gather_end`` -> ``free``, i.e. compute on already-gathered
-      waves). A serial gather-then-compute schedule (depth 0) measures ~0;
-      lookahead opens it. ``overlap_frac`` = overlap / total gather time.
-    - ``gather_bytes_per_step``: static plan bytes (fwd + bwd re-gather) —
-      what the schedule moves, for bytes/s math against the wall numbers.
-    """
-
-    steps: int = 0
-    waves: int = 0
-    fwd_gather_ms: float = 0.0
-    bwd_gather_ms: float = 0.0
-    reduce_scatter_ms: float = 0.0
-    overlap_ms: float = 0.0
-    overlap_frac_sum: float = 0.0
-    gather_bytes: int = 0
-
-    def record_step(self, *, fwd_gather_s: float, bwd_gather_s: float,
-                    reduce_scatter_s: float, overlap_s: float,
-                    overlap_frac: float, gather_bytes: int,
-                    n_waves: int) -> None:
-        self.steps += 1
-        self.waves += int(n_waves)
-        self.fwd_gather_ms += 1e3 * fwd_gather_s
-        self.bwd_gather_ms += 1e3 * bwd_gather_s
-        self.reduce_scatter_ms += 1e3 * reduce_scatter_s
-        self.overlap_ms += 1e3 * overlap_s
-        self.overlap_frac_sum += overlap_frac
-        self.gather_bytes = int(gather_bytes)
-
-    def reset(self) -> None:
-        self.steps = 0
-        self.waves = 0
-        self.fwd_gather_ms = 0.0
-        self.bwd_gather_ms = 0.0
-        self.reduce_scatter_ms = 0.0
-        self.overlap_ms = 0.0
-        self.overlap_frac_sum = 0.0
-        self.gather_bytes = 0
-
-    def events(self, step: int = 0) -> List[Event]:
-        n = max(1, self.steps)
-        return [
-            ("train/zero3/steps", float(self.steps), step),
-            ("train/zero3/waves_per_step", self.waves / n, step),
-            ("train/zero3/fwd_gather_ms_per_step", self.fwd_gather_ms / n, step),
-            ("train/zero3/bwd_gather_ms_per_step", self.bwd_gather_ms / n, step),
-            ("train/zero3/reduce_scatter_ms_per_step",
-             self.reduce_scatter_ms / n, step),
-            ("train/zero3/overlap_ms_per_step", self.overlap_ms / n, step),
-            ("train/zero3/overlap_frac", self.overlap_frac_sum / n, step),
-            ("train/zero3/gather_bytes_per_step", float(self.gather_bytes), step),
-        ]
-
-
-@dataclass
 class RolloutStats:
     """Colocated-rollout loop counters (``runtime/colocated.py``;
     docs/TRAINING.md "Colocated rollout"). Aggregated from the SAME

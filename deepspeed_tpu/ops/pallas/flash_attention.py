@@ -105,7 +105,7 @@ def _fwd(q, k, v, scale: float, causal: bool,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=bq, block_k=bk, nk=nk, H=H)
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -129,7 +129,10 @@ def _fwd(q, k, v, scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_backend.interpret(),
-    )(q, k, v)
+    )
+    # the scope is the kernel's name in a device trace (op_name metadata)
+    with jax.named_scope("flash_fwd"):
+        o, lse = call(q, k, v)
     return o, lse
 
 
@@ -242,7 +245,7 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kernel = functools.partial(_fwd_kernel_packed, scale=scale,
                                block_q=bq, block_k=bk, nk=nk, window=window)
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(H, nq, nk),
         in_specs=[
@@ -269,7 +272,9 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_backend.interpret(),
-    )(seg, seg, qT, kT, vT)
+    )
+    with jax.named_scope("flash_fwd_packed"):
+        o, lse = call(seg, seg, qT, kT, vT)
     out = jnp.swapaxes(o[0], 0, 1)[:R]
     if with_lse:
         return out, jnp.swapaxes(lse[0, :, :, 0], 0, 1)[:R]
@@ -379,7 +384,7 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
     delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
                        o.astype(jnp.float32))[..., None]
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nk=nk),
         grid=(B, H, nq, nk),
@@ -397,9 +402,11 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_backend.interpret(),
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope("flash_bwd_dq"):
+        dq = dq_call(q, k, v, do, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, nq=nq),
         grid=(B, H, nk, nq),
@@ -426,7 +433,9 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=_backend.interpret(),
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = dkv_call(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

@@ -937,14 +937,16 @@ def paged_decode_attention_sidebuf(q: jax.Array,
                                    lambda s, c, bt, cl, jj, ll: (s, 0, 0)),
             scratch_shapes=scratch,
         )
-        return pl.pallas_call(
+        call = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=_backend.interpret(),
-        )(*operands)
+        )
+        with jax.named_scope("paged_decode_sidebuf_batched"):
+            return call(*operands)
 
     kernel = functools.partial(
         _decode_kernel_sidebuf_quant if quant else _decode_kernel_sidebuf,
@@ -978,14 +980,16 @@ def paged_decode_attention_sidebuf(q: jax.Array,
                                lambda s, c, bt, cl, jj, ll: (s, 0, 0)),
         scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_backend.interpret(),
-    )(*operands)
+    )
+    with jax.named_scope("paged_decode_sidebuf"):
+        return call(*operands)
 
 
 def _decode_kernel_smalld(bt_ref, cl_ref, q_ref, kv_ref, o_ref,
@@ -1075,15 +1079,17 @@ def _paged_decode_smalld(q, kv_pages, block_tables, ctx_lens, scale,
             pltpu.VMEM((H, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_backend.interpret(),
-    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      q, kv_pages)
+    )
+    with jax.named_scope("paged_decode_smalld"):
+        return call(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+                    q, kv_pages)
 
 
 def paged_decode_attention(q: jax.Array,
@@ -1174,7 +1180,7 @@ def paged_decode_attention(q: jax.Array,
     )
     assert (bs * Hkv) % 8 == 0, \
         f"page rows {Hkv}*{bs} must align to the 8-sublane tile"
-    res = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
@@ -1183,7 +1189,9 @@ def paged_decode_attention(q: jax.Array,
             # across sequences), so iteration order must stay sequential
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_backend.interpret(),
-    )(*operands)
+    )
+    with jax.named_scope("paged_decode"):
+        res = call(*operands)
     if with_lse:
         return res[0], res[1][:, :, 0]
     return res
@@ -1343,7 +1351,7 @@ def paged_decode_attention_step(q: jax.Array,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    res = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
@@ -1351,7 +1359,9 @@ def paged_decode_attention_step(q: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_backend.interpret(),
-    )(*operands)
+    )
+    with jax.named_scope("paged_decode_step"):
+        res = call(*operands)
     out, kvf = res[0], res[1]
     # the write happens HERE, after the kernel: a canonical in-place scatter
     # on the aliased-through pool (see _decode_step_kernel docstring)
@@ -1602,14 +1612,16 @@ def paged_chunk_attention_batched(q: jax.Array,
             pltpu.VMEM((Hkv * bq * G, 128), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NC, Cs, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_backend.interpret(),
-    )(*operands)
+    )
+    with jax.named_scope("paged_chunk"):
+        return call(*operands)
 
 
 # --------------------------------------------------------------------------- #

@@ -121,6 +121,7 @@ def _scales_logical(kv_scales: jax.Array, NB: int, h_kv: int, bs: int):
 # XLA-composed fallback: scan over chunks, splits batched
 # --------------------------------------------------------------------- #
 
+@jax.named_scope("paged_decode_xla")
 def paged_decode_attention_xla(q: jax.Array,
                                kv_pages: jax.Array,
                                block_tables: jax.Array,
@@ -226,6 +227,7 @@ def paged_decode_attention_xla(q: jax.Array,
     return out
 
 
+@jax.named_scope("paged_chunk_xla")
 def paged_chunk_attention_xla(q: jax.Array,
                               kv_pages: jax.Array,
                               block_tables: jax.Array,
@@ -573,7 +575,7 @@ def paged_decode_attention_splitk_pallas(q: jax.Array,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
-    out_p, lse_p = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
@@ -582,7 +584,9 @@ def paged_decode_attention_splitk_pallas(q: jax.Array,
             # across virtual rows), so iteration order stays sequential
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_backend.interpret(),
-    )(*operands)
+    )
+    with jax.named_scope(f"paged_decode_splitk_sp{SP}"):
+        out_p, lse_p = call(*operands)
     out, lse = merge_splitk_partials(out_p.reshape(S, SP, H, D),
                                      lse_p[:, :, 0].reshape(S, SP, H))
     out = out.astype(q.dtype)

@@ -242,14 +242,13 @@ class DeepSpeedTPUEngine:
         # -- monitor (parity: MonitorMaster wiring, engine.py:249) ---------
         from deepspeed_tpu.monitor import (CheckpointStats, MonitorMaster,
                                            OffloadPipelineStats,
-                                           TrainPipelineStats, Zero3CommStats)
+                                           TrainPipelineStats)
         self.monitor = MonitorMaster(self.config)
         self.train_stats = TrainPipelineStats()
         self.offload_stats = OffloadPipelineStats()
         self.ckpt_stats = CheckpointStats()
         # ZeRO-3 collective schedule (runtime/zero/prefetch.py): built lazily
         # once params exist, armed around every trace of the fused step
-        self.zero3_stats = Zero3CommStats()
         self._zero3_plan = None
         # span tracing (docs/OBSERVABILITY.md): config-reachable alongside
         # the DSTPU_TRACE env path initialize() arms
@@ -415,7 +414,9 @@ class DeepSpeedTPUEngine:
         fp16 = self.config.fp16
         dynamic = fp16.enabled
 
-        def build(params_in):
+        # the program's name in a device trace and the compile log:
+        # jit_train_state_build
+        def train_state_build(params_in):
             master = tree_cast(params_in, jnp.float32)
             opt = self.optimizer.init(master)
             scaler = make_loss_scale_state(dynamic, fp16.loss_scale,
@@ -433,10 +434,13 @@ class DeepSpeedTPUEngine:
         donate = (0,) if self.config.donate_model_parameters else ()
         with topo.mesh:
             if init_params is not None:
-                self.state = jax.jit(lambda: build(init_params()),
+                def train_state_build_lazy():
+                    return train_state_build(init_params())
+                self.state = jax.jit(train_state_build_lazy,
                                      out_shardings=shardings)()
             else:
-                self.state = jax.jit(build, out_shardings=shardings,
+                self.state = jax.jit(train_state_build,
+                                     out_shardings=shardings,
                                      donate_argnums=donate)(model_parameters)
         self._state_shardings = shardings
         self._scaler_dynamic = bool(dynamic and fp16.loss_scale == 0)
@@ -473,12 +477,6 @@ class DeepSpeedTPUEngine:
                 "leaves (all under stage3_param_persistence_threshold?): "
                 "staying on the implicit ZeRO-3 path", z.stage3_prefetch_depth)
             return
-        import dataclasses as _dc
-        if _tracer.enabled:
-            # bake the taps into the plan BEFORE the step traces: the stamps
-            # feeding train/zero3/* spans + Zero3CommStats are debug callbacks
-            # compiled into the step, not host instrumentation
-            plan = _dc.replace(plan, trace_armed=True)
         self._zero3_plan = plan
         logger.info(
             "zero3 collective schedule: %d waves over %d layers, depth=%d, "
@@ -993,26 +991,19 @@ class DeepSpeedTPUEngine:
         fp16 = self.config.fp16
 
         def step_fn(state, batch):
-            # stash the device step counter for the ZeRO-3 schedule taps
-            # traced inside this step (stamps carry it so drain() segments
-            # by execution, not host callback arrival order); trace-scoped —
-            # the finally clears the tracer before it goes stale
-            from deepspeed_tpu.runtime.zero import prefetch as zero3_prefetch
-            zero3_prefetch.set_step_operand(state["step"])
-            try:
-                params = self._current_params(state)
-                scale = state["scaler"]["scale"] if fp16.enabled else jnp.float32(1.0)
-                grads, losses = self._accumulate_grads(params, scale, batch)
-                new_state, metrics = self._apply_grads(state, grads)
-                metrics["loss"] = jnp.mean(losses)
-            finally:
-                zero3_prefetch.set_step_operand(None)
+            params = self._current_params(state)
+            scale = state["scaler"]["scale"] if fp16.enabled else jnp.float32(1.0)
+            grads, losses = self._accumulate_grads(params, scale, batch)
+            new_state, metrics = self._apply_grads(state, grads)
+            metrics["loss"] = jnp.mean(losses)
             return new_state, metrics
 
         return step_fn
 
+    @jax.named_scope("optimizer")
     def _apply_grads(self, state, grads):
-        """Clip, check overflow, optimizer update on the fp32 master, cast back."""
+        """Clip, check overflow, optimizer update on the fp32 master, cast
+        back. Its operations carry the scope ``optimizer`` in a device trace."""
         cfg = self.config
         fp16 = cfg.fp16
         clip = cfg.gradient_clipping
@@ -1330,12 +1321,6 @@ class DeepSpeedTPUEngine:
             if queue_depth:
                 _tracer.counter("train/prefetch/queue_depth", queue_depth,
                                 lane="train/step")
-        if self._zero3_plan is not None and self._zero3_plan.trace_armed:
-            # stamps stream in from the step's debug callbacks as it executes;
-            # drain whatever segments have completed (the in-flight step's
-            # partial segment stays queued for the next drain)
-            from deepspeed_tpu.runtime.zero import prefetch as zero3_prefetch
-            zero3_prefetch.drain(_tracer, self.zero3_stats, self._zero3_plan)
         return metrics["loss"]
 
     def train_steps(self, n_steps: int, data_iter=None) -> np.ndarray:
@@ -1416,10 +1401,6 @@ class DeepSpeedTPUEngine:
         exit, and ``destroy()``; call it manually before reading monitor
         output mid-run."""
         self._drain_metric_queue(0)
-        if self._zero3_plan is not None and self._zero3_plan.trace_armed:
-            from deepspeed_tpu.runtime.zero import prefetch as zero3_prefetch
-            zero3_prefetch.drain(_tracer, self.zero3_stats, self._zero3_plan,
-                                 barrier=True)
 
     def _drain_metric_queue(self, leave: int):
         while len(self._pending_metrics) > leave:
@@ -1457,8 +1438,6 @@ class DeepSpeedTPUEngine:
                         self.offload_stats.events(samples))
                 if self.ckpt_stats.saves:
                     self.monitor.write_events(self.ckpt_stats.events(samples))
-                if self.zero3_stats.steps:
-                    self.monitor.write_events(self.zero3_stats.events(samples))
         if printing:
             loss = float(vals["loss"]) if "loss" in vals else float("nan")
             lr = float(vals["lr"])
@@ -1539,18 +1518,12 @@ class DeepSpeedTPUEngine:
         gas = self.gas_
 
         def micro(state, buf, mb):
-            # step operand for the ZeRO-3 schedule taps (see _build_fused_step)
-            from deepspeed_tpu.runtime.zero import prefetch as zero3_prefetch
-            zero3_prefetch.set_step_operand(state["step"])
-            try:
-                params = self._current_params(state)
-                scale = state["scaler"]["scale"] if fp16.enabled else jnp.float32(1.0)
-                loss, grads = self._grad_fn(params, mb, scale)
-                grads = tree_cast(grads, accum_dtype)
-                grads = self._constrain_grads(grads)
-                buf = jax.tree_util.tree_map(jnp.add, buf, grads)
-            finally:
-                zero3_prefetch.set_step_operand(None)
+            params = self._current_params(state)
+            scale = state["scaler"]["scale"] if fp16.enabled else jnp.float32(1.0)
+            loss, grads = self._grad_fn(params, mb, scale)
+            grads = tree_cast(grads, accum_dtype)
+            grads = self._constrain_grads(grads)
+            buf = jax.tree_util.tree_map(jnp.add, buf, grads)
             return loss, buf
 
         def apply(state, buf):
@@ -1809,5 +1782,7 @@ class DeepSpeedTPUEngine:
         from deepspeed_tpu.runtime.zero import prefetch as zero3_prefetch
         zero3_prefetch.configure(self._zero3_plan)
         if self._eval_step is None:
-            self._eval_step = jax.jit(self._loss_of)
+            def train_eval_loss(params, batch):
+                return self._loss_of(params, batch)
+            self._eval_step = jax.jit(train_eval_loss)
         return float(self._eval_step(params, mb))

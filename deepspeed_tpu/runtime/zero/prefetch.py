@@ -44,22 +44,14 @@ movement and the transpose reduce-scatter sums the same partials in the same
 participant order, so per-step loss streams are byte-identical across depth
 0/1/2 and any bucket size (the train_bench ``--zero3-overlap`` gate).
 
-Observability (PR 7 stats-equals-spans discipline): when tracing is armed at
-compile time, each gather / free / reduce-scatter emits a
-``jax.debug.callback`` stamp. Static tags are bound with ``functools.partial``
-and the operands are a 1-element **explicitly replicated** probe slice plus a
-replicated step counter — passing python values as callback operands
-deadlocks under the forced-host 8-device mesh, and an unconstrained probe
-fires per-shard. The step counter (armed by the engine's step builders via
-:func:`set_step_operand`; ``-1`` for step-less traces like eval forwards)
-keys :func:`drain`'s segmentation: ``jax.debug.callback`` is unordered and
-``ordered=True`` is rejected on multi-device meshes, so stamps of consecutive
-steps may interleave on the host — grouping by the device-side step id keeps
-segment boundaries exact regardless of arrival order (stamps sharing a step
-id — the micro facade's per-microbatch executions, fp16 overflow-skipped
-steps, eval passes — still fall back to per-key arrival order). The host
-drains the ledger into ``train/zero3/{gather,free,reduce_scatter}`` tracer
-spans and the same segments feed ``monitor.training.Zero3CommStats``.
+Observability: the schedule's collectives are DEVICE work, so they are named
+and read from the device trace, never timed with host stamps. Each wave's
+forward gather runs under ``jax.named_scope("zero3/gather/w<k>")``, its
+backward re-gather under ``zero3/gather_bwd/w<k>`` and the gather's transpose
+under ``zero3/reduce_scatter/w<k>``; the scope is part of every operation's
+``op_name`` in the compiled program, which a profiler trace carries
+(docs/OBSERVABILITY.md "Names on the device's work"). Exposed and hidden
+collective time come from that trace (``chipbench/reduce/xplane.py``).
 
 Known lowering honesty: spans and stats name the *logical* collective. On the
 forced-host CPU backend the bucketed gather lowers to a real ``all-gather``
@@ -73,20 +65,18 @@ import contextlib
 import dataclasses
 import functools
 import threading
-import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.comm.mesh import FSDP_AXES
 from deepspeed_tpu.runtime.zero.partition import gathered_spec, sharded_axes_of
 
 __all__ = [
     "Zero3Wave", "Zero3Plan", "build_plan", "configure", "current_plan",
-    "set_step_operand", "scheduled_layer_walk", "drain", "stamps_per_step",
-    "clear_stamps", "layer_stack_names",
+    "scheduled_layer_walk", "layer_stack_names",
 ]
 
 
@@ -149,7 +139,6 @@ class Zero3Plan:
     # leaves NOT gathered (replicated / persistence-threshold / tp-only):
     # schedule leaves them alone; recorded for the residency/bench story.
     persistent_bytes: int
-    trace_armed: bool = False        # baked at first trace; taps emitted iff True
 
     @property
     def n_waves(self) -> int:
@@ -246,7 +235,6 @@ class _PrefetchState(threading.local):
     def __init__(self):
         super().__init__()
         self.plan: Optional[Zero3Plan] = None
-        self.step = None         # traced step scalar while a step fn traces
 
 
 _STATE = _PrefetchState()
@@ -271,8 +259,8 @@ def cleared():
     train->serve reshard program (``runtime/colocated.py``) is the
     motivating case — would otherwise trace under a plan scheduled for a
     different program's model walk. The reshard touches no model layers, so
-    the taps would not fire today; the guard makes that a guarantee instead
-    of a coincidence (the same hygiene rule engine.py documents at its
+    the schedule would not apply today; the guard makes that a guarantee
+    instead of a coincidence (the same hygiene rule engine.py documents at its
     per-step ``configure`` call)."""
     prev = _STATE.plan
     _STATE.plan = None
@@ -280,73 +268,6 @@ def cleared():
         yield
     finally:
         _STATE.plan = prev
-
-
-def set_step_operand(step) -> None:
-    """Stash the device step counter for the duration of a step fn's trace.
-
-    The engine's step builders call this with ``state["step"]`` (a tracer of
-    the enclosing jit) on entry and ``None`` in a ``finally`` — the taps pick
-    it up as an extra callback operand so every stamp carries the step it
-    belongs to. The stash is trace-scoped: leaving it set after the trace
-    would leak a dead tracer into the next traced walk (eval, another
-    engine), hence the mandatory clear."""
-    _STATE.step = step
-
-
-# --------------------------------------------------------------------------- #
-# Stamp ledger (host side of the in-jit taps)
-# --------------------------------------------------------------------------- #
-
-# (wave_index, kind, step, perf_counter); step is the device step counter the
-# stamp executed under (-1 for step-less traces). Kinds, in per-wave program
-# order:
-#   fwd:  "gather_start" "gather_end" "free"
-#   bwd:  "bwd_gather_start" "bwd_gather_end" "rs_start" "rs_end"
-_LEDGER: List[Tuple[int, str, int, float]] = []
-_LEDGER_LOCK = threading.Lock()
-
-_FWD_KINDS = ("gather_start", "gather_end", "free")
-_BWD_KINDS = ("bwd_gather_start", "bwd_gather_end", "rs_start", "rs_end")
-
-
-def stamps_per_step(plan: Zero3Plan, with_backward: bool = True) -> int:
-    per = len(_FWD_KINDS) + (len(_BWD_KINDS) if with_backward else 0)
-    return per * plan.n_waves
-
-
-def clear_stamps() -> None:
-    with _LEDGER_LOCK:
-        _LEDGER.clear()
-
-
-def _record(wave: int, kind: str, _probe, step) -> None:
-    # Host callback target. Static tags arrive partial-bound; the jax
-    # operands are the replicated probe establishing the device-timeline
-    # dependency and the replicated step counter keying segmentation.
-    with _LEDGER_LOCK:
-        _LEDGER.append((wave, kind, int(step), time.perf_counter()))
-
-
-def _tap(tree, mesh, wave: int, kind: str):
-    """Stamp the moment `tree` becomes available on the device timeline.
-
-    The probe is a 1-element slice explicitly constrained replicated: the
-    callback then fires exactly once per execution (not per shard) and its
-    host timestamp tracks the producing op's completion. The stashed step
-    operand rides along (replicated too) so drain() can segment stamps by
-    execution without trusting host arrival order. Returns `tree` unchanged
-    — taps are read-only and never alter math.
-    """
-    leaf = jax.tree_util.tree_leaves(tree)[0]
-    probe = jax.lax.with_sharding_constraint(
-        jnp.ravel(leaf)[:1], NamedSharding(mesh, P()))
-    step = _STATE.step
-    step = jax.lax.with_sharding_constraint(
-        jnp.asarray(jnp.int32(-1) if step is None else step, jnp.int32),
-        NamedSharding(mesh, P()))
-    jax.debug.callback(functools.partial(_record, wave, kind), probe, step)
-    return tree
 
 
 # --------------------------------------------------------------------------- #
@@ -425,8 +346,9 @@ def _fused_allgather(*locals_, plans: Sequence[_LeafPlan],
 
 
 def _gather_wave(plan: Zero3Plan, wave: Zero3Wave, ptrees: Dict[str, Any],
-                 tie, mesh, *, bucket_limit: int, tap_prefix: Optional[str]):
-    """Gather a wave's sharded leaves (bucketed, differentiable, tie-pinned).
+                 tie, mesh, *, bucket_limit: int, scope: str):
+    """Gather a wave's sharded leaves (bucketed, differentiable, tie-pinned)
+    under ``jax.named_scope(scope)``: the wave's name in a device trace.
 
     Returns per-layer param dicts with gathered leaves substituted. The
     transpose of each bucket's all_gather is a psum_scatter over the same
@@ -443,8 +365,6 @@ def _gather_wave(plan: Zero3Plan, wave: Zero3Wave, ptrees: Dict[str, Any],
         leaves[i] = node
 
     leaves = list(_tie_barrier(leaves, tie))
-    if tap_prefix is not None:
-        leaves[0] = _tap(leaves[0], mesh, wave.index, tap_prefix + "_start")
 
     gathered: List[Any] = [None] * len(leaves)
     for bucket in _bucketize(wave.leaves, bucket_limit):
@@ -460,12 +380,10 @@ def _gather_wave(plan: Zero3Plan, wave: Zero3Wave, ptrees: Dict[str, Any],
             in_specs=tuple(lp.spec for lp in plans),
             out_specs=tuple(lp.out_spec for lp in plans),
             check_vma=False)
-        outs = fn(*[leaves[i] for i in bucket])
+        with jax.named_scope(scope):
+            outs = fn(*[leaves[i] for i in bucket])
         for i, g in zip(bucket, outs):
             gathered[i] = g
-
-    if tap_prefix is not None:
-        gathered[0] = _tap(gathered[0], mesh, wave.index, tap_prefix + "_end")
 
     out = {name: ptrees[name] for name in wave.layers}
     for lp, g in zip(wave.leaves, gathered):
@@ -487,31 +405,26 @@ def _make_gather_fn(plan: Zero3Plan, wave: Zero3Wave, mesh):
     over ``reduce_bucket_size`` buckets), so grads arriving on the gathered
     buffers leave this node already reduced + scattered to the param
     sharding — pipelined into the backward at this wave's position."""
-    taps = plan.trace_armed
-
     @jax.custom_vjp
     def gather_fn(ptrees, tie):
         return _gather_wave(plan, wave, ptrees, tie, mesh,
                             bucket_limit=plan.allgather_bucket_size,
-                            tap_prefix="gather" if taps else None)
+                            scope=f"zero3/gather/w{wave.index}")
 
     def gather_fwd(ptrees, tie):
         return gather_fn(ptrees, tie), (ptrees, tie)
 
     def gather_bwd(res, ct):
         ptrees, tie = res
-        if taps:
-            ct = _tap(ct, mesh, wave.index, "rs_start")
         # transpose of the bucketed gather = bucketed psum_scatter: jax.vjp
-        # of a fresh (untapped) gather gives it over reduce_bucket_size
-        # buckets; the unused primal all-gather is dead code XLA removes.
+        # of a fresh gather gives it over reduce_bucket_size buckets; the
+        # unused primal all-gather is dead code XLA removes.
+        scope = f"zero3/reduce_scatter/w{wave.index}"
         _, vjp_fn = jax.vjp(
             lambda pt: _gather_wave(plan, wave, pt, tie, mesh,
                                     bucket_limit=plan.reduce_bucket_size,
-                                    tap_prefix=None), ptrees)
+                                    scope=scope), ptrees)
         (gp,) = vjp_fn(ct)
-        if taps:
-            gp = _tap(gp, mesh, wave.index, "rs_end")
         return gp, jnp.zeros_like(tie)
 
     gather_fn.defvjp(gather_fwd, gather_bwd)
@@ -527,7 +440,6 @@ def _make_compute_fn(plan: Zero3Plan, wave: Zero3Wave, mesh,
     backward window), recomputes the wave (wave-granular remat), and routes
     the param grads out through the ``gathered`` input's cotangent — i.e.
     into the gather node's transpose reduce-scatter."""
-    taps = plan.trace_armed
 
     def run(gathered, x):
         for name in wave.layers:
@@ -539,20 +451,15 @@ def _make_compute_fn(plan: Zero3Plan, wave: Zero3Wave, mesh,
         return run(gathered, x)
 
     def compute_fwd(gathered, ptrees, x):
-        y = run(gathered, x)
-        if taps:
-            # y's readiness marks the gathered buffers' last forward use:
-            # nothing downstream references them (residuals are sharded)
-            y = _tap(y, mesh, wave.index, "free")
-        return y, (ptrees, x)
+        # y's readiness marks the gathered buffers' last forward use:
+        # nothing downstream references them (residuals are sharded)
+        return run(gathered, x), (ptrees, x)
 
     def compute_bwd(res, ct):
         ptrees, x = res
-        # no tap on ct here: _gather_wave already stamps bwd_gather_start on
-        # the tie-barriered sharded leaf, the same device-timeline moment
         regathered = _gather_wave(plan, wave, ptrees, ct, mesh,
                                   bucket_limit=plan.reduce_bucket_size,
-                                  tap_prefix="bwd_gather" if taps else None)
+                                  scope=f"zero3/gather_bwd/w{wave.index}")
         _, vjp_fn = jax.vjp(run, regathered, x)
         g_gathered, gx = vjp_fn(ct)
         # param grads leave via g_gathered (the gather node reduce-scatters
@@ -665,153 +572,3 @@ def scheduled_layer_walk(layers: Sequence[Any], carry, *,
         cf = _make_compute_fn(plan, wave, mesh, layer_call)
         carry = cf(gathered, {n: ptrees[n] for n in wave.layers}, carry)
     return carry
-
-
-# --------------------------------------------------------------------------- #
-# Drain: stamps -> tracer spans + Zero3CommStats segments
-# --------------------------------------------------------------------------- #
-
-def drain(tracer=None, stats=None, plan: Optional[Zero3Plan] = None, *,
-          barrier: bool = False) -> int:
-    """Convert accumulated stamps into tracer spans and stats records.
-
-    ``jax.debug.callback`` is unordered (and ``ordered=True`` is rejected on
-    multi-device meshes), so stamps of consecutive executions may interleave
-    on the host. Segmentation therefore groups by the device-side step
-    counter each stamp carries — exact regardless of arrival order. Stamps
-    sharing a step id (the micro facade runs every microbatch at one step
-    value, fp16 overflow skips the increment, step-less traces all stamp -1)
-    split on repeated (wave, kind) keys: each tap fires exactly once per
-    execution, so a repeat marks the next same-step execution, relying only
-    on per-key arrival order. A segment with backward stamps is a training
-    step; one without is an eval/fwd pass (recorded only as spans). Returns
-    the number of complete segments drained; partial segments (executions
-    still in flight) stay queued. ``barrier=True`` waits for all in-flight
-    debug callbacks first (the final drain: blocking on the step's outputs
-    does NOT flush its callbacks).
-    """
-    plan = plan or current_plan()
-    if plan is None:
-        return 0
-    if barrier:
-        jax.effects_barrier()
-    with _LEDGER_LOCK:
-        stamps = list(_LEDGER)
-    if not stamps:
-        return 0
-
-    groups: Dict[int, List[Dict[Tuple[int, str], float]]] = {}
-    seg_of: List[Tuple[int, int]] = []       # stamp index -> (step, seg#)
-    first_at: Dict[Tuple[int, int], int] = {}  # (step, seg#) -> arrival index
-    for i, (wave, kind, step, t) in enumerate(stamps):
-        segs = groups.setdefault(step, [{}])
-        if (wave, kind) in segs[-1]:
-            segs.append({})
-        segs[-1][(wave, kind)] = t
-        sid = (step, len(segs) - 1)
-        seg_of.append(sid)
-        first_at.setdefault(sid, i)
-    # a segment is drained once provably complete — a full training pass
-    # (every wave's rs_end) or, certifiable only after an effects barrier, a
-    # full forward-only pass — or once a later same-step execution closed it
-    # (duplicate key): whatever stamps it got is all it will ever get
-    n = plan.n_waves
-    emit: List[Tuple[int, int]] = []
-    for step, segs in groups.items():
-        for si, per in enumerate(segs):
-            closed = si < len(segs) - 1
-            full_train = all((w, "rs_end") in per for w in range(n))
-            full_fwd = (all((w, "free") in per for w in range(n))
-                        and all(k in _FWD_KINDS for _, k in per))
-            if closed or full_train or (barrier and full_fwd):
-                emit.append((step, si))
-    if not emit:
-        return 0
-    emitted = set(emit)
-    keep = [s for s, sid in zip(stamps, seg_of) if sid not in emitted]
-    with _LEDGER_LOCK:
-        # requeue unconsumed stamps ahead of any that arrived since snapshot
-        del _LEDGER[:len(stamps)]
-        _LEDGER[:0] = keep
-
-    emit.sort(key=lambda sid: first_at[sid])
-    for step, si in emit:
-        _emit_segment(groups[step][si], plan, tracer, stats)
-    return len(emit)
-
-
-def _emit_segment(per: Dict[Tuple[int, str], float], plan: Zero3Plan,
-                  tracer, stats) -> None:
-    n = plan.n_waves
-    fwd_gather = bwd_gather = rs = overlap = 0.0
-    spans_gather: List[Tuple[float, float]] = []
-    spans_free: List[Tuple[float, float]] = []
-    has_bwd = any((w, "rs_end") in per for w in range(n))
-    emit: Dict[str, List[Tuple[float, float, str, Dict[str, Any]]]] = {}
-    for w in range(n):
-        gs, ge = per.get((w, "gather_start")), per.get((w, "gather_end"))
-        fr = per.get((w, "free"))
-        wave_bytes = plan.waves[w].gather_bytes
-        if gs is not None and ge is not None:
-            fwd_gather += ge - gs
-            spans_gather.append((gs, ge))
-            emit.setdefault("train/zero3/gather", []).append(
-                (gs, ge, f"train/zero3/gather/w{w}",
-                 dict(wave=w, phase="fwd", bytes=wave_bytes)))
-        if ge is not None and fr is not None:
-            # residency window of the gathered buffers: gather done -> last use
-            spans_free.append((ge, fr))
-            emit.setdefault("train/zero3/free", []).append(
-                (ge, fr, f"train/zero3/free/w{w}",
-                 dict(wave=w, bytes=wave_bytes)))
-        bs = per.get((w, "bwd_gather_start"))
-        be = per.get((w, "bwd_gather_end"))
-        if bs is not None and be is not None:
-            bwd_gather += be - bs
-            spans_gather.append((bs, be))
-            emit.setdefault("train/zero3/gather", []).append(
-                (bs, be, f"train/zero3/gather/w{w}.bwd",
-                 dict(wave=w, phase="bwd", bytes=wave_bytes)))
-        r0, r1 = per.get((w, "rs_start")), per.get((w, "rs_end"))
-        if r0 is not None and r1 is not None:
-            rs += r1 - r0
-            emit.setdefault("train/zero3/reduce_scatter", []).append(
-                (r0, r1, f"train/zero3/reduce_scatter/w{w}",
-                 dict(wave=w, bytes=wave_bytes)))
-    if tracer is not None and tracer.enabled:
-        # spans on one lane CAN overlap (depth+1 residency windows live at
-        # once — that's the schedule working); Chrome-trace B/E pairs on one
-        # track must nest, so pack each lane's spans greedily onto
-        # overlap-free slot sub-lanes. Slot 0 keeps the bare lane name; the
-        # number of slots a lane needs IS the concurrency it exhibited
-        # (free: depth+1 rows = the double-buffer bound, made visible).
-        for base, items in emit.items():
-            slot_ends: List[float] = []
-            for t0, t1, name, args in sorted(items, key=lambda s: s[:2]):
-                for k, end in enumerate(slot_ends):
-                    if t0 >= end:
-                        slot = k
-                        break
-                else:
-                    slot = len(slot_ends)
-                    slot_ends.append(t1)
-                slot_ends[slot] = t1
-                tracer.add(name, t0, t1,
-                           lane=base if slot == 0 else f"{base}/{slot}",
-                           **args)
-    # overlap: gather windows intersected with OTHER waves' residency/compute
-    # windows (a gather under its own wave's compute is not prefetch)
-    gather_total = 0.0
-    for i, (gs, ge) in enumerate(spans_gather):
-        gather_total += ge - gs
-        for j, (cs, cf) in enumerate(spans_free):
-            lo, hi = max(gs, cs), min(ge, cf)
-            if hi > lo:
-                overlap += hi - lo
-    frac = (overlap / gather_total) if gather_total > 0 else 0.0
-    if stats is not None and has_bwd:
-        stats.record_step(fwd_gather_s=fwd_gather, bwd_gather_s=bwd_gather,
-                          reduce_scatter_s=rs, overlap_s=overlap,
-                          overlap_frac=frac,
-                          gather_bytes=plan.gather_bytes_per_step,
-                          n_waves=n)

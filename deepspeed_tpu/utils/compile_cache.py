@@ -9,6 +9,10 @@ benchmarks and the serving engine:
 - unset: ``<checkout>/.jax_cache``, a fixed path, because the path is part of
   the cache's key and a directory that moves never hits.
 
+The same call installs the process's compile listener
+(:func:`install_compile_listener`): an always-on count, from jax's monitoring
+events, of programs that were not ready when they were called.
+
 A TPU executable does not depend on the host that compiled it. A CPU
 executable is compiled ahead of time for the build host's CPU features, and
 loading one on a host without them is a SIGILL (XLA's cpu_aot_loader warns
@@ -23,7 +27,10 @@ from __future__ import annotations
 import hashlib
 import os
 import platform
+import time
 from typing import Optional
+
+from deepspeed_tpu.monitor.trace import tracer as _tracer
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -51,6 +58,53 @@ def host_fingerprint() -> str:
     return hashlib.sha1(raw.encode()).hexdigest()[:12]
 
 
+#: jax's monitoring events, and the always-on counters they feed
+#: (``tracer.totals``; a capture reports what each gained)
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_listening = False
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event == _BACKEND_COMPILE:
+        # wraps the cache lookup too: a program loaded from the persistent
+        # cache counts, it was not ready either
+        _tracer.bump("compile/backend_compiles")
+        _tracer.bump("compile/backend_compile_s", secs)
+        if _tracer.enabled:
+            # jax calls listeners on the thread that waited, so the span
+            # lands inside the span of the step that recompiled
+            now = time.perf_counter()
+            _tracer.add("compile/backend", now - secs, now)  # jaxlint: disable=JL001 -- jax measured secs around the blocking compile
+    elif event == _CACHE_RETRIEVAL:
+        _tracer.bump("compile/cache_load_s", secs)
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT:
+        _tracer.bump("compile/cache_loads")
+
+
+def install_compile_listener() -> None:
+    """Count backend compiles and persistent-cache loads for the life of the
+    process (idempotent). jax offers no way to take one listener off, so
+    there is one, module-level, and it writes to the tracer's counters."""
+    global _listening
+    if _listening:
+        return
+    from jax import monitoring
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+    _listening = True
+
+
+def backend_compiles() -> int:
+    """Programs compiled or loaded from the cache since the listener was
+    installed — module-level jits and eager operations included."""
+    return int(_tracer.totals.get("compile/backend_compiles", 0))
+
+
 def setup_compile_cache(min_compile_time_secs: Optional[float] = None) -> str:
     """Turn the persistent compilation cache on and return its directory.
 
@@ -62,6 +116,7 @@ def setup_compile_cache(min_compile_time_secs: Optional[float] = None) -> str:
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    install_compile_listener()
     configured = jax.config.jax_compilation_cache_dir
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
