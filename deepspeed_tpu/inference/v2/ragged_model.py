@@ -21,9 +21,15 @@ Every builder routes attention through ONE ``AttentionKernelSpec``
 (``inference/v2/attention.py``): kernel variants key on the pool dtype at
 the call (``kv_scales=None`` = bf16/f32 pages), window/alibi/TP bind once.
 
-MoE layers use sort-based grouped GEMM (``jax.lax.ragged_dot`` when available) —
-the TPU analog of the reference's CUTLASS ``moe_gemm`` + moe_scatter/gather
+MoE layers use sort-based grouped GEMM (``jax.lax.ragged_dot``) — the TPU
+analog of the reference's CUTLASS ``moe_gemm`` + moe_scatter/gather
 (``inference/v2/kernels/cutlass_ops``, ``ragged_ops/moe_{scatter,gather}``).
+The expert stacks ``[L, E, K, N]`` are NOT scanned with the other layer
+weights: the grouped GEMM is a custom call, a scan's per-layer slice cannot
+fuse into it, and the compiler copied each layer's stacks to a temporary
+first. The layer body closes over the whole stacks and ``_moe_ffn`` addresses
+layer ``l`` as groups ``[l*E, (l+1)*E)`` of the ``[L*E, K, N]`` view, every
+other group empty (``_split_expert_stacks``; docs/SERVING.md "MoE layers").
 """
 
 from __future__ import annotations
@@ -361,10 +367,39 @@ def _rope_flat(x: jax.Array, positions: jax.Array, theta: float,
     return _partial_rope(x[None], positions[None], theta, rotary_dim)[0]
 
 
+def _split_expert_stacks(layers: Dict) -> Tuple[Dict, Dict]:
+    """``weights["layers"]`` -> (the tree a layer scan slices, the expert
+    stacks ``[L, E, K, N]`` the layer body closes over whole).
+
+    A scan hands its body one layer's slice of every stacked leaf. XLA fuses
+    that slice into a dense dot, but the grouped GEMM is a custom call that
+    takes no fused operand: sliced, each layer's three expert stacks were
+    first COPIED to a temporary (57% of the Mixtral decode step's device
+    time). So the bf16 stacks stay out of the scanned tree and ``_moe_ffn``
+    addresses layer ``l``'s experts inside them. int8 stacks (``{"w8",
+    "scale"}``) stay scanned: their slice fuses with the dequantizing
+    convert the path needs anyway. The weight tree itself is untouched.
+    """
+    moe = layers.get("moe")
+    if not isinstance(moe, dict):
+        return layers, {}
+    stacks = {k: moe[k] for k in _QUANT_MLP_KEYS
+              if k in moe and not isinstance(moe[k], dict)}
+    rest = {k: v for k, v in moe.items() if k not in stacks}
+    return {**layers, "moe": rest}, stacks
+
+
 @jax.named_scope("moe_ffn")
-def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype) -> jax.Array:
+def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0) -> jax.Array:
     """Sort-based token dispatch + grouped GEMM (parity: reference moe_scatter ->
-    CUTLASS moe_gemm -> moe_gather, inference/v2/kernels). x: [T, hid]."""
+    CUTLASS moe_gemm -> moe_gather, inference/v2/kernels). x: [T, hid].
+
+    An expert matrix in ``w`` is one layer's ``[E, K, N]`` or the whole
+    ``[L, E, K, N]`` stack with ``l`` the layer to use (see
+    ``_split_expert_stacks``): the stack is viewed as ``L*E`` groups and the
+    layer's group sizes sit at offset ``l*E`` among zeros, so the kernel
+    reads the layer's experts where they lie and visits no other group.
+    """
     T, hid = x.shape
     E = w["router"].shape[-1]
     with jax.named_scope("router"):
@@ -376,10 +411,14 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype) -> jax.Array:
         tok_idx = jnp.repeat(jnp.arange(T), top_k)                     # [T*K]
         expert_ids = ids.reshape(-1)
         order = jnp.argsort(expert_ids)
-        src = tok_idx[order]
-        xs = x[src]                                                    # [T*K, hid]
+        # XLA:TPU runs its grouped-GEMM kernel only on a row count that is a
+        # multiple of 8; any other count lowers to a dense product over
+        # EVERY group of the rhs, all other layers' experts included. Rows
+        # past the last group belong to no expert and are dropped from ys.
+        rows = jnp.pad(order, (0, -order.shape[0] % 8))
+        xs = x[tok_idx[rows]]                                  # [T*K + pad, hid]
         group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
-        row_e = expert_ids[order]
+        row_e = expert_ids[rows]
 
     def gg(lhs, rhs):
         if isinstance(rhs, dict) and "w8" in rhs:
@@ -392,14 +431,19 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype) -> jax.Array:
                                      group_sizes,
                                      preferred_element_type=jnp.float32)
             return (raw * rhs["scale"][row_e, 0, :]).astype(lhs.dtype)
-        return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes)
+        groups = rhs.reshape((-1,) + rhs.shape[-2:])       # [L*E, K, N]
+        sizes = group_sizes
+        if groups.shape[0] != E:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros(groups.shape[0], jnp.int32), group_sizes, (l * E,))
+        return jax.lax.ragged_dot(lhs, groups.astype(lhs.dtype), sizes)
 
     with jax.named_scope("experts"):
         if "w_gate" in w:
             h = jax.nn.silu(gg(xs, w["w_gate"])) * gg(xs, w["w_up"])
         else:
             h = jax.nn.gelu(gg(xs, w["w_up"]))
-        ys = gg(h, w["w_down"])                                        # [T*K, hid]
+        ys = gg(h, w["w_down"])[:order.shape[0]]                       # [T*K, hid]
     with jax.named_scope("combine"):
         scale = gates.reshape(-1)[order].astype(ys.dtype)
         # scatter-free combine: invert the sort permutation and sum the K
@@ -607,7 +651,7 @@ def _quantize_weight_tree(weights: Dict, q) -> Dict:
 
 
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
-                       lora=None):
+                       lora=None, experts=None, l=0):
     """Shared per-layer transformer body for BOTH the ragged forward (put
     passes) and the fused multistep decode — one implementation so the two
     paths cannot diverge.  ``attend(q, k, v) -> (attn_raw [N, H, D],
@@ -615,6 +659,8 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     ``state`` is the caller's carried cache state (pools, or pools + scale
     pools for int8 KV). ``lora`` (``_lora_split`` output, or None) adds each
     row's grouped adapter delta to the targeted attention projections.
+    ``experts`` are the whole expert stacks ``_split_expert_stacks`` kept out
+    of the scanned ``w``, and ``l`` this layer's index in them.
     Returns ``(x_out, state_tuple)``.
     """
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
@@ -650,7 +696,8 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
 
     with jax.named_scope("ffn"):
         if spec.moe is not None:
-            mlp_out = _moe_ffn(mlp_in, w["moe"], spec.moe["top_k"], dtype)
+            mlp_out = _moe_ffn(mlp_in, {**w["moe"], **(experts or {})},
+                               spec.moe["top_k"], dtype, l)
         else:
             m = w["mlp"]
             if spec.activation in ("swiglu", "geglu"):
@@ -885,6 +932,7 @@ def build_ragged_forward(spec: RaggedModelSpec,
         positions = jnp.concatenate([b["chunk_positions"], b["decode_positions"]])
 
         x = _embed_in(spec, weights, tokens, positions)
+        layers, experts = _split_expert_stacks(weights["layers"])
 
         def layer_fn(carry, scanned):
             x, kvp, sc = carry
@@ -910,12 +958,13 @@ def build_ragged_forward(spec: RaggedModelSpec,
                 return (jnp.concatenate([out_c.reshape(CT, H, D), out_d],
                                         axis=0), kvp_, sc_)
 
-            x, (kvp, sc) = _transformer_layer(spec, w, x, positions, attend)
+            x, (kvp, sc) = _transformer_layer(spec, w, x, positions, attend,
+                                              experts=experts, l=l)
             return (x, kvp, sc), None
 
         (x, kvp, sc), _ = jax.lax.scan(
             layer_fn, (x, kvp0, sc0),
-            (weights["layers"], jnp.arange(L, dtype=jnp.int32)))
+            (layers, jnp.arange(L, dtype=jnp.int32)))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
@@ -969,6 +1018,7 @@ def build_prefill_forward(spec: RaggedModelSpec,
         seg = b["row_seg"]
 
         x = _embed_in(spec, weights, tokens, positions)
+        layers, experts = _split_expert_stacks(weights["layers"])
 
         def layer_fn(carry, scanned):
             x, kvp, sc = carry
@@ -991,12 +1041,13 @@ def build_prefill_forward(spec: RaggedModelSpec,
                     sc_ = sc
                 return out, kvp_, sc_
 
-            x, (kvp, sc) = _transformer_layer(spec, w, x, positions, attend)
+            x, (kvp, sc) = _transformer_layer(spec, w, x, positions, attend,
+                                              experts=experts, l=l)
             return (x, kvp, sc), None
 
         (x, kvp, sc), _ = jax.lax.scan(
             layer_fn, (x, kvp0, sc0),
-            (weights["layers"], jnp.arange(L, dtype=jnp.int32)))
+            (layers, jnp.arange(L, dtype=jnp.int32)))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
@@ -1083,6 +1134,7 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
         side_dtype = jnp.float32 if kvq else dtype
         side_k0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
         side_v0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
+        layers, experts = _split_expert_stacks(weights["layers"])
 
         def one_pass(x_ids, pos, j, sk_all, sv_all):
             x = _embed_in(spec, weights, x_ids, pos)
@@ -1123,13 +1175,13 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
                         kv_scales=sc4 if kvq else None)
                     return out, sk_new, sv_new
 
-                x, (sk_all, sv_all) = _transformer_layer(spec, w, x, pos,
-                                                         attend)
+                x, (sk_all, sv_all) = _transformer_layer(
+                    spec, w, x, pos, attend, experts=experts, l=l)
                 return (x, sk_all, sv_all), None
 
             (x, sk_new, sv_new), _ = jax.lax.scan(
                 layer_fn, (x, sk_all, sv_all),
-                (weights["layers"], jnp.arange(L, dtype=jnp.int32)))
+                (layers, jnp.arange(L, dtype=jnp.int32)))
             x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                       spec.norm_plus_one)
             return _unembed(spec, weights, x), sk_new, sv_new
@@ -1447,6 +1499,7 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
         dest = (page * bs + positions % bs).reshape(-1)
 
         x = _embed_in(spec, weights, tokens.reshape(-1), pos_flat)
+        layers, experts = _split_expert_stacks(weights["layers"])
 
         def layer_fn(carry, scanned):
             x, kvp, sc = carry
@@ -1480,10 +1533,10 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
                 return out.reshape(S * K1, H, D), kvp_, sc_
 
             x, (kvp, sc) = _transformer_layer(spec, w, x, pos_flat, attend,
-                                              lora=lora)
+                                              lora=lora, experts=experts, l=l)
             return (x, kvp, sc), None
 
-        xs = (weights["layers"], jnp.arange(L, dtype=jnp.int32))
+        xs = (layers, jnp.arange(L, dtype=jnp.int32))
         if lora_ops is not None:
             xs = xs + (lora_ops,)
         (x, kvp, sc), _ = jax.lax.scan(layer_fn, (x, kvp0, sc0), xs)
@@ -1545,6 +1598,7 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
         else:
             assert not lora_args, "lora operands on a non-LoRA program"
             lora_ops = None
+        layers, experts = _split_expert_stacks(weights["layers"])
 
         def one_pass(x_ids, pos, ctx, kvp, sc):
             # kvp flat [L*NB*2*Hkv*bs, D]. The attention + page-write is one
@@ -1584,10 +1638,11 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
                     return (out, kv5.reshape(L * NB * 2 * Hkv * bs, D), sc)
 
                 x, (kvp, sc) = _transformer_layer(spec, w, x, pos, attend,
-                                                  lora=lora)
+                                                  lora=lora, experts=experts,
+                                                  l=l)
                 return (x, kvp, sc), None
 
-            xs = (weights["layers"], jnp.arange(L, dtype=jnp.int32))
+            xs = (layers, jnp.arange(L, dtype=jnp.int32))
             if lora_ops is not None:
                 xs = xs + (lora_ops,)
             (x, kvp, sc), _ = jax.lax.scan(layer_fn, (x, kvp, sc), xs)

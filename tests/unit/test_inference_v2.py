@@ -254,6 +254,20 @@ def llama_setup():
     return model, params
 
 
+def _force_paged(eng):
+    """Strip the pure_prefill marking so the engine routes every pass
+    through build_ragged_forward (the paged path)."""
+    orig = eng.scheduler.schedule_pass
+
+    def no_fast():
+        b = orig()
+        if b is not None:
+            b.pure_prefill = False
+        return b
+
+    eng.scheduler.schedule_pass = no_fast
+
+
 class TestEngineV2:
 
     def _v1_greedy(self, model, params, prompts, n):
@@ -289,17 +303,7 @@ class TestEngineV2:
                 config=RaggedInferenceEngineConfig.load(dict(V2_CONFIG)),
                 model_parameters=params)
             if force_paged:
-                # force the paged path: strip the pure_prefill marking so the
-                # engine routes every pass through build_ragged_forward
-                orig = eng.scheduler.schedule_pass
-
-                def no_fast():
-                    b = orig()
-                    if b is not None:
-                        b.pure_prefill = False
-                    return b
-
-                eng.scheduler.schedule_pass = no_fast
+                _force_paged(eng)
             logits = eng.put([1, 2, 3], prompts)
             pools = (np.asarray(eng.kv.kv),)
             eng.flush([1, 2, 3])
@@ -351,17 +355,43 @@ class TestEngineV2:
         eng.flush([0, 1])
         assert eng.free_blocks > free_before
 
-    def test_mixtral_moe_path(self):
+    @pytest.mark.parametrize("path", ["generate", "paged_chunks", "bursts",
+                                      "bursts_sidebuf", "verify"])
+    def test_mixtral_moe_path(self, path):
+        """Greedy tokens of a tiny Mixtral equal the dense v1 engine's through
+        every program that scans the layers: packed prefill + decode step
+        (``generate``), the paged pass over a prompt chunked across passes,
+        ``decode_steps`` bursts (general loop; side-buffer loop at head_dim
+        128) and the speculative verify step. All five address a layer's
+        experts inside the whole stacks (``_split_expert_stacks``)."""
         from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
-        cfg = MixtralConfig.tiny(dtype=jnp.float32)
+        kw, conf = {}, {k: dict(v) if isinstance(v, dict) else v
+                        for k, v in V2_CONFIG.items()}
+        if path == "bursts_sidebuf":
+            kw = dict(hidden_size=256, num_attention_heads=2,
+                      num_key_value_heads=2)
+            conf["kv_cache"] = {"block_size": 64, "num_blocks": 8}
+        elif path == "paged_chunks":
+            conf["state_manager"]["prefill_chunk_size"] = 4
+        elif path == "verify":
+            conf["spec_decode"] = {"enabled": True, "k": 3}
+        cfg = MixtralConfig.tiny(dtype=jnp.float32, num_hidden_layers=3, **kw)
         model = MixtralForCausalLM(cfg)
         params = model.init(jax.random.PRNGKey(0),
                             {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
-        ref = self._v1_greedy(model, params, PROMPTS[:2], 4)
+        n = 6
+        ref = self._v1_greedy(model, params, PROMPTS[:2], n)
         eng = InferenceEngineV2(model=model,
-                                config=RaggedInferenceEngineConfig.load(dict(V2_CONFIG)),
+                                config=RaggedInferenceEngineConfig.load(conf),
                                 model_parameters=params)
-        out = eng.generate(PROMPTS[:2], max_new_tokens=4)
+        if path.startswith("bursts"):
+            eng.put([1, 2], [np.asarray(p, np.int32) for p in PROMPTS[:2]])
+            ids = eng.decode_steps([1, 2], n)
+            out = [p + ids[i].tolist() for i, p in enumerate(PROMPTS[:2])]
+        else:
+            if path == "paged_chunks":
+                _force_paged(eng)
+            out = eng.generate(PROMPTS[:2], max_new_tokens=n)
         assert out == ref
 
     def test_gemma_flags_match_v1(self):
@@ -662,6 +692,67 @@ def test_kv_quant_multistep_matches_per_token(eight_devices, window):
         e2.put([1, 2], [np.asarray([nxt[0]], np.int32),
                         np.asarray([nxt[1]], np.int32)])
     assert np.array_equal(ids_ms, np.stack(step_ids, 1))
+
+
+def _moe_layers(L, dtype, E=4, hid=32, inter=48, seed=0):
+    """Stacked MoE weights ``[L, ...]`` whose router never picks expert 2
+    for a token with ``x[0] == 1`` (an empty group in every layer): its
+    logit is 0, the others' are 9 + noise."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    router = jax.random.normal(ks[0], (L, hid, E), jnp.float32)
+    router = router.at[:, :, 2].set(0.0)
+    router = router.at[:, 0, :].add(jnp.asarray([9., 9., 0., 9.]))
+    moe = {"router": router,
+           "w_gate": jax.random.normal(ks[1], (L, E, hid, inter), dtype) * .2,
+           "w_up": jax.random.normal(ks[2], (L, E, hid, inter), dtype) * .2,
+           "w_down": jax.random.normal(ks[3], (L, E, inter, hid), dtype) * .2}
+    return {"moe": moe}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("L,l", [(1, 0), (3, 0), (3, 1), (3, 2)])
+@pytest.mark.parametrize("stacks", ["plain", "w8"])
+def test_moe_ffn_addresses_the_layer_inside_the_stack(stacks, L, l, dtype):
+    """``_moe_ffn`` on the whole ``[L, E, K, N]`` stacks at layer ``l`` is
+    bitwise ``_moe_ffn`` on that layer's slice — the same matmuls on the
+    same operands, addressed in place — with an expert that gets no row, a
+    row count (5 tokens top-2 = 10) that is padded to 16, and ``l`` traced
+    as the layer scans trace it. int8 stacks (``w8`` dicts) stay in the
+    scanned tree and take the slice."""
+    from deepspeed_tpu.inference.v2.ragged_model import (
+        _moe_ffn, _split_expert_stacks, quantize_weights_int8)
+    layers = _moe_layers(L, dtype)
+    if stacks == "w8":
+        layers = quantize_weights_int8({"layers": layers})["layers"]
+    x = jax.random.normal(jax.random.PRNGKey(7), (5, 32), dtype)
+    x = x.at[:, 0].set(1.0)
+    scanned, experts = _split_expert_stacks(layers)
+    assert set(experts) == (set() if stacks == "w8"
+                            else {"w_gate", "w_up", "w_down"})
+    assert (jax.tree.structure({**scanned["moe"], **experts})
+            == jax.tree.structure(layers["moe"]))
+
+    def at(tree, i):
+        return jax.tree.map(lambda a: a[i], tree)
+
+    sliced = at(layers["moe"], l)
+    ids = jax.lax.top_k(x.astype(jnp.float32) @ sliced["router"], 2)[1]
+    assert 2 not in np.asarray(ids)                  # the empty group
+    # both sides as a layer scan traces them (a jitted body, ``l`` traced):
+    # every leaf sliced, against the expert stacks whole
+    want = jax.jit(lambda li: _moe_ffn(
+        x, at(layers["moe"], li), 2, dtype))(jnp.int32(l))
+    got = jax.jit(lambda li: _moe_ffn(
+        x, {**at(scanned["moe"], li), **experts}, 2, dtype, li))(jnp.int32(l))
+    assert got.dtype == want.dtype and np.isfinite(
+        np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    if L > 1:       # another layer's experts give another answer
+        other = _moe_ffn(x, at(layers["moe"], (l + 1) % L), 2, dtype)
+        assert not np.array_equal(np.asarray(other, np.float32),
+                                  np.asarray(want, np.float32))
 
 
 def test_int8_weights_quantize_moe_experts(eight_devices):
