@@ -17,7 +17,7 @@ composing a new pool layout meant touching every site. Now:
   (:meth:`validate_engine_build`): the engine asks it instead of scattering
   refusals, so what composes (int8 x prefix cache, int8 x spec decode,
   int8 x page fabric) and what does not (int8 x tensor parallel,
-  spec x sliding window) is decided — and tested — in one place
+  spec x a page ring, i.e. a window in every layer) is decided — and tested — in one place
   (tests/unit/test_kv_quant_stack.py pins the surviving refusal messages).
 
 int8 write semantics (the invariant the byte gates rest on): quantize-on-
@@ -141,16 +141,23 @@ class AttentionKernelSpec:
             # everything else composes: sliding window / ALiBi mask inside
             # each split, int8 dequant per gathered page, spec verify rides
             # the chunk dispatcher, small head dims take the XLA scan
+        # the two window refusals are the page ring's, and the ring engages
+        # only where EVERY layer is windowed (``spec.window``, one kind). A
+        # model that mixes windowed and full layers (``spec.layer_kinds``)
+        # keeps whole-context pages in every layer, has no ring, and
+        # composes with both
         if cfg.prefix_cache.enabled and spec.window is not None:
             raise NotImplementedError(
                 "prefix_cache with a sliding-window model is not wired: "
-                "the page ring overwrites pages in place, which would rot "
+                f"every layer is windowed ({spec.window} tokens), so the "
+                "page ring overwrites pages in place, which would rot "
                 "cached content under a live sharer")
         if cfg.spec_decode.enabled and spec.window is not None:
             raise NotImplementedError(
                 "spec_decode with a sliding-window model is not wired "
                 "(the page ring aliases the verify step's k+1-ahead "
-                "write span)")
+                f"write span): every layer is windowed ({spec.window} "
+                "tokens), so the ring is on")
 
     # ------------------------------------------------------------------ #
     # trace-time dispatch (called inside jitted programs)

@@ -240,8 +240,18 @@ class InferenceEngineV2:
         self.scheduler = DynamicSplitFuseScheduler(sm, self.kv, self.allocator,
                                                    prefix_cache=self.prefix_cache)
         # sliding-window serving (Mistral/Qwen2): the scheduler ring-reuses
-        # each sequence's pages beyond the window so KV stays bounded
+        # each sequence's pages beyond the window so KV stays bounded. The
+        # ring engages only where EVERY layer is windowed: a model of mixed
+        # kinds (spec.layer_kinds; afmoe) reports window None here and all
+        # its layers hold whole-context pages (one page kind), the windowed
+        # ones reading only their last ``window`` tokens
         self.scheduler.window = self.spec.window
+        # [(window, how many layers have it)], for kv_window_dead_tokens()
+        from deepspeed_tpu.inference.v2.ragged_model import layer_runs
+        windows = [rs.window for rs, _, n in layer_runs(self.spec)
+                   for _ in range(n) if rs.window is not None]
+        self._windowed_layers = [(w, windows.count(w))
+                                 for w in sorted(set(windows))]
         if cfg.spec_decode.enabled:
             # (window refusal raised by validate_engine_build above; int8
             # pools compose — build_verify_step quantizes-on-write and the
@@ -349,8 +359,17 @@ class InferenceEngineV2:
         # serving runs don't pass through deepspeed_tpu.initialize — arm the
         # span tracer from $DSTPU_TRACE here (no-op when unset/armed)
         _trace_from_env()
+        from deepspeed_tpu.inference.v2.ragged_model import (
+            describe_layer_kinds)
+        ring = self.scheduler.ring_pages
         log_dist(f"engine_v2: family={family} tp={tp} blocks={nb}+scratch "
-                 f"block_size={kv_cfg.block_size} budget={sm.max_ragged_batch_size}",
+                 f"block_size={kv_cfg.block_size} budget={sm.max_ragged_batch_size}"
+                 f"; {describe_layer_kinds(self.spec)}; page ring "
+                 f"{'off' if ring is None else f'{ring} pages a sequence'}; "
+                 f"attention rungs {list(self.attn_split_ladder)}"
+                 + (" (full layers; windowed layers stay on rung 1)"
+                    if self.spec.layer_kinds is not None
+                    and len(self.attn_split_ladder) > 1 else ""),
                  ranks=[0])
         if cfg.compile.warmup:
             self.warmup(buckets=cfg.compile.warmup_buckets,
@@ -1343,6 +1362,28 @@ class InferenceEngineV2:
     def put_page(self, page: np.ndarray, block: int) -> None:
         """Scatter one host page back into pool slot ``block``."""
         self.put_pages(page[None], [block])
+
+    def kv_window_dead_tokens(self) -> Tuple[int, int]:
+        """``(dead, resident)`` over the live sequences, in tokens x layers:
+        ``resident`` counts the tokens whose K/V each layer's pages hold,
+        ``dead`` those of them that a windowed layer holds below
+        ``ctx - window`` — no query will read them again. It is what one
+        page kind costs a model that mixes windowed and full layers
+        (``spec.layer_kinds``): every layer keeps whole-context pages. Under
+        the page ring (every layer windowed) a sequence holds at most the
+        ring, and what is dead is the ring's slack. A gauge for a sender to
+        sample between requests; it reads the scheduler's table and takes no
+        lock."""
+        ring = self.scheduler.ring_pages
+        cap = None if ring is None else ring * self.kv.config.block_size
+        dead = resident = 0
+        for seq in list(self.scheduler.seqs.values()):
+            held = seq.seen_tokens if cap is None else min(seq.seen_tokens,
+                                                           cap)
+            resident += self.spec.num_layers * held
+            for window, n in self._windowed_layers:
+                dead += n * max(0, held - window)
+        return dead, resident
 
     def serving_frontend(self, config=None, uid_base: int = 1 << 20):
         """The persistent SLO-aware serving frontend over this engine

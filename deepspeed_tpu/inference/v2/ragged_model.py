@@ -34,8 +34,8 @@ other group empty (``_split_expert_stacks``; docs/SERVING.md "MoE layers").
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from dataclasses import dataclass, field, replace
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +56,18 @@ def _kv_unpack(kp):
     if isinstance(kp, tuple):
         return kp
     return kp, None
+
+
+class LayerKind(NamedTuple):
+    """What may differ from one layer of a model to the next."""
+    window: Optional[int]   # sliding-window span in tokens; None = full
+    rope: bool              # rotates q/k by position (else no positions)
+    moe: bool               # routed experts (else the dense MLP)
+
+    def describe(self) -> str:
+        attn = "full" if self.window is None else f"window {self.window}"
+        return (f"{attn}, {'rotary' if self.rope else 'no positions'}, "
+                f"{'MoE' if self.moe else 'dense'} FFN")
 
 
 @dataclass
@@ -82,16 +94,56 @@ class RaggedModelSpec:
     embed_scale_by_sqrt_dim: bool = False  # gemma: x *= sqrt(hidden) after embed
     norm_plus_one: bool = False    # gemma: RMSNorm scales by (1 + weight)
     eps: float = 1e-5
-    moe: Optional[Dict[str, int]] = None    # {"num_experts": E, "top_k": k}
+    # {"num_experts": E, "top_k": k}: top-k of the router logits, softmax
+    # over the chosen (Mixtral). With "score_func": "sigmoid" the scores are
+    # sigmoid(logits), chosen with the layer's "expert_bias" added, weighed
+    # without it, over their sum if "route_norm", times "route_scale" (afmoe)
+    moe: Optional[Dict[str, Any]] = None
     # mistral/qwen2 sliding-window span (tokens); None = full attention.
     # Reference parity: inference/v2/model_implementations/mistral.
     window: Optional[int] = None
+    # one kind per layer, for a model whose layers differ in attention
+    # (window or full, rotary or none) or FFN (dense or MoE). None: every
+    # layer is of the one kind the scalar fields give. Where kinds differ,
+    # ``window`` is None (no page ring: every layer holds whole-context
+    # pages), ``rope_theta`` and ``moe`` describe the layers that have them,
+    # and ``weights["layers"]`` is a tuple of stacked trees, one per run of
+    # equal kinds (:func:`layer_runs`)
+    layer_kinds: Optional[Tuple[LayerKind, ...]] = None
     # BLOOM lineage: per-head linear position bias applied inside the paged
     # kernels (reference csrc/transformer/inference/csrc/softmax.cu) and a
     # LayerNorm right after the embedding
     alibi: bool = False
     embed_norm: bool = False
     dtype: Any = jnp.bfloat16
+
+
+def layer_runs(spec: RaggedModelSpec
+               ) -> List[Tuple[RaggedModelSpec, int, int]]:
+    """Maximal runs of layers of one kind, as ``(the spec that run's layers
+    are built with, its first layer, how many)``. A model of one kind is one
+    run under its own spec."""
+    if spec.layer_kinds is None:
+        return [(spec, 0, spec.num_layers)]
+    runs: List[List[Any]] = []
+    for l, kind in enumerate(spec.layer_kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, l, 1])
+    return [(replace(spec, layer_kinds=None, window=kind.window,
+                     rope_theta=spec.rope_theta if kind.rope else None,
+                     moe=spec.moe if kind.moe else None), l0, n)
+            for kind, l0, n in runs]
+
+
+def describe_layer_kinds(spec: RaggedModelSpec) -> str:
+    """One line for the engine's set-up log."""
+    return "; ".join(
+        f"layers {l0}-{l0 + n - 1}: "
+        + LayerKind(rs.window, rs.rope_theta is not None,
+                    rs.moe is not None).describe()
+        for rs, l0, n in layer_runs(spec))
 
 
 # --------------------------------------------------------------------------- #
@@ -240,10 +292,15 @@ def adapt_decoder(params: Dict, config,
     if getattr(config, "attn_scale", None) is not None:
         unsupported.append("attn_scale")
     if unsupported:
+        # attn_scale is a kernel limit; the local layers are not (the spec
+        # carries a kind per layer) but this adapter does not map them
         raise ValueError(
-            f"config features {unsupported} are not supported by the ragged "
-            "(paged) attention path — serve through deepspeed_tpu."
-            "init_inference (v1 dense engine) instead")
+            f"config features {unsupported} are not served by the ragged "
+            "(paged) attention path: the paged kernels take no score scale "
+            "other than 1/sqrt(head_dim), and this adapter does not map "
+            "'local' attention_layers onto the spec's per-layer kinds — "
+            "serve through deepspeed_tpu.init_inference (v1 dense engine) "
+            "instead")
     spec = RaggedModelSpec(
         family=config.family,
         num_layers=config.num_hidden_layers,
@@ -279,6 +336,83 @@ def adapt_decoder(params: Dict, config,
     return spec, weights
 
 
+def adapt_afmoe(params: Dict, config,
+                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
+    """models/afmoe.py param tree (AfmoeForCausalLM; Arcee Trinity).
+
+    Everything that sets the family apart is read from the config and the
+    tree: one :class:`LayerKind` per layer from ``layer_types`` and
+    ``num_dense_layers``; q/k norm (``q_norm``/``k_norm``), the output gate
+    (``wg``) and the sandwich norms (``ln1_post``/``ln2_post``) by their
+    presence in a layer's weights; the router by ``spec.moe``."""
+    window = config.sliding_window
+    if max_context is not None and max_context <= window:
+        window = None           # as adapt_llama: no position sees past it
+    kinds = tuple(
+        LayerKind(window if t == "sliding_attention" else None,
+                  t == "sliding_attention", config.is_moe_layer(i))
+        for i, t in enumerate(config.layer_types))
+    spec = RaggedModelSpec(
+        family="afmoe",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=config.num_attention_heads,
+        num_kv_heads=config.num_key_value_heads,
+        head_dim=config.head_dim,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
+        embed_scale_by_sqrt_dim=config.mup_enabled, eps=config.rms_norm_eps,
+        moe={"num_experts": config.num_experts,
+             "top_k": config.num_experts_per_tok,
+             "score_func": config.score_func,
+             "route_norm": config.route_norm,
+             "route_scale": config.route_scale},
+        layer_kinds=kinds, dtype=config.dtype)
+    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
+        spec = layer_runs(spec)[0][0]
+
+    def swiglu(p):
+        return {"w_gate": p["gate_proj"]["kernel"],
+                "w_up": p["up_proj"]["kernel"],
+                "w_down": p["down_proj"]["kernel"]}
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        attn = lp["self_attn"]
+        out = {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln1_post": {"scale": lp["post_attention_layernorm"]["weight"]},
+            "ln2": {"scale": lp["pre_mlp_layernorm"]["weight"]},
+            "ln2_post": {"scale": lp["post_mlp_layernorm"]["weight"]},
+            "wq": attn["q_proj"]["kernel"], "wk": attn["k_proj"]["kernel"],
+            "wv": attn["v_proj"]["kernel"], "wo": attn["o_proj"]["kernel"],
+            "wg": attn["gate_proj"]["kernel"],
+            "q_norm": attn["q_norm"]["weight"],
+            "k_norm": attn["k_norm"]["weight"],
+        }
+        mlp = lp["mlp"]
+        if config.is_moe_layer(i):
+            out["moe"] = {"router": mlp["router"]["kernel"],
+                          "expert_bias": mlp["expert_bias"],
+                          "w_gate": mlp["w_gate"], "w_up": mlp["w_up"],
+                          "w_down": mlp["w_down"]}
+            if "shared_experts" in mlp:
+                out["moe"]["shared"] = swiglu(mlp["shared_experts"])
+        else:
+            out["mlp"] = swiglu(mlp)
+        return out
+
+    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
+                   for _, l0, n in layer_runs(spec))
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": stacks if spec.layer_kinds is not None else stacks[0],
+        "final_norm": {"scale": params["norm"]["weight"]},
+        "lm_head": params["lm_head"]["kernel"],
+    }
+    return spec, weights
+
+
 ADAPTERS: Dict[str, Callable] = {
     # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
     # LlamaConfig features the adapter reads)
@@ -296,15 +430,20 @@ ADAPTERS: Dict[str, Callable] = {
     "gptj": adapt_decoder,
     "gpt_bigcode": adapt_decoder,
     "bloom": adapt_decoder,   # ALiBi carried by the paged kernels
+    # layers of several kinds in one model (window+rotary / full without
+    # positions; dense / MoE), gated attention, sigmoid router, shared expert
+    "afmoe": adapt_afmoe,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
 #: serve these through the v1 dense engine instead
 _UNSUPPORTED = {
-    # gpt_neo alternates GLOBAL and LOCAL attention layers; the ragged spec
-    # carries one window for all layers, so it stays on the v1 dense engine
-    # (bloom's ALiBi is supported — the kernels bias scores per head)
-    "gpt_neo": "per-layer alternating local-window attention",
+    # gpt_neo scores attention WITHOUT the 1/sqrt(head_dim) factor
+    # (attn_scale=1.0), which the paged kernels do not take. Its alternating
+    # global/local layers are no longer what blocks it: the spec carries a
+    # kind per layer (``layer_kinds``); adapt_decoder does not map
+    # ``attention_layers`` onto them yet
+    "gpt_neo": "unscaled attention scores (attn_scale)",
 }
 
 
@@ -389,8 +528,70 @@ def _split_expert_stacks(layers: Dict) -> Tuple[Dict, Dict]:
     return {**layers, "moe": rest}, stacks
 
 
+def _swiglu(x, m):
+    return _mm(jax.nn.silu(_mm(x, m["w_gate"])) * _mm(x, m["w_up"]),
+               m["w_down"])
+
+
+def _scan_layers(spec: "RaggedModelSpec", layers, make_body, carry,
+                 extra_xs: Tuple = ()):
+    """The layer loop of every serving program: one ``lax.scan`` per run of
+    layers of one kind (:func:`layer_runs`), each over that run's stacked
+    weights. ``make_body(run_spec, experts, l0)`` returns the scan body for a
+    run — built with the run's own spec, so window, rotation and FFN are
+    static arguments of its kernels — and the body is handed ``(weights of
+    the layer, its index l in the whole model[, extra_xs rows of it])``: KV
+    pages are addressed by ``l``, the run's expert stacks by ``l - l0``. A
+    model of one kind is one scan over all its layers, as it always was."""
+    stacks = layers if isinstance(layers, tuple) else (layers,)
+    runs = layer_runs(spec)
+    assert len(stacks) == len(runs), (len(stacks), len(runs))
+    for (run_spec, l0, n), stack in zip(runs, stacks):
+        scanned, experts = _split_expert_stacks(stack)
+        xs = (scanned, jnp.arange(l0, l0 + n, dtype=jnp.int32)) + tuple(
+            x[l0:l0 + n] for x in extra_xs)
+        carry, _ = jax.lax.scan(make_body(run_spec, experts, l0), carry, xs)
+    return carry
+
+
+def _kind_splits(spec: "RaggedModelSpec", run_spec: "RaggedModelSpec",
+                 n_splits: int) -> int:
+    """The split-K rung a run's attention takes. In a model of mixed kinds
+    the rung is chosen for the full layers (their context is what grows);
+    a windowed run beside them reads at most its window and stays on the
+    chunk-serial kernels."""
+    if spec.layer_kinds is not None and run_spec.window is not None:
+        return 1
+    return n_splits
+
+
+def moe_route(x: jax.Array, w: Dict, top_k: int,
+              routing: Optional[Dict[str, Any]] = None
+              ) -> Tuple[jax.Array, jax.Array]:
+    """The router of :func:`_moe_ffn` by itself: each token's ``top_k``
+    expert ids ``[T, K]`` and their weights ``[T, K]`` in float32, from
+    ``x`` ``[T, hid]``, ``w["router"]`` and, if there, ``w["expert_bias"]``."""
+    routing = routing or {}
+    logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)  # [T, E]
+    if routing.get("score_func") == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = scores
+        if "expert_bias" in w:
+            biased = scores + w["expert_bias"].astype(jnp.float32)
+        ids = jax.lax.top_k(biased, top_k)[1]                      # [T, K]
+        gates = jnp.take_along_axis(scores, ids, axis=-1)
+        if routing.get("route_norm"):
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+        gates = gates * routing.get("route_scale", 1.0)
+    else:
+        gates, ids = jax.lax.top_k(logits, top_k)                  # [T, K]
+        gates = jax.nn.softmax(gates, axis=-1)
+    return gates, ids
+
+
 @jax.named_scope("moe_ffn")
-def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0) -> jax.Array:
+def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
+             routing: Optional[Dict[str, Any]] = None) -> jax.Array:
     """Sort-based token dispatch + grouped GEMM (parity: reference moe_scatter ->
     CUTLASS moe_gemm -> moe_gather, inference/v2/kernels). x: [T, hid].
 
@@ -399,13 +600,18 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0) -> jax.Array:
     ``_split_expert_stacks``): the stack is viewed as ``L*E`` groups and the
     layer's group sizes sit at offset ``l*E`` among zeros, so the kernel
     reads the layer's experts where they lie and visits no other group.
+
+    ``routing`` is ``spec.moe``: without a ``score_func`` the router is
+    Mixtral's (top-k of the logits, softmax over the chosen); ``"sigmoid"``
+    scores every expert by itself, chooses with ``w["expert_bias"]`` added
+    and weighs without it (the bias balances load, it is not a weight), over
+    the chosen scores' sum if ``route_norm``, times ``route_scale``. A
+    ``w["shared"]`` expert sees every token, unweighted.
     """
     T, hid = x.shape
     E = w["router"].shape[-1]
     with jax.named_scope("router"):
-        logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)  # [T, E]
-        gates, ids = jax.lax.top_k(logits, top_k)                      # [T, K]
-        gates = jax.nn.softmax(gates, axis=-1)
+        gates, ids = moe_route(x, w, top_k, routing)
 
     with jax.named_scope("sort"):
         tok_idx = jnp.repeat(jnp.arange(T), top_k)                     # [T*K]
@@ -450,6 +656,9 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0) -> jax.Array:
         # choices (parallel/moe.py dropless_moe — TPU scatter-add serializes)
         inv = jnp.argsort(order)
         out = (ys * scale[:, None])[inv].reshape(T, top_k, hid).sum(axis=1)
+    if "shared" in w:
+        with jax.named_scope("shared"):
+            out = out + _swiglu(x, w["shared"])
     return out.astype(dtype)
 
 
@@ -627,7 +836,15 @@ def quantize_weights_int8(weights: Dict) -> Dict:
 
 
 def _quantize_weight_tree(weights: Dict, q) -> Dict:
-    layers = weights["layers"]
+    runs = weights["layers"]
+    for layers in runs if isinstance(runs, tuple) else (runs,):
+        _quantize_layer_stack(layers, q)
+    if "lm_head" in weights and not isinstance(weights["lm_head"], dict):
+        weights["lm_head"] = q(weights["lm_head"])
+    return weights
+
+
+def _quantize_layer_stack(layers: Dict, q) -> None:
     for key in _QUANT_KEYS:
         if key in layers and not isinstance(layers[key], dict):
             layers[key] = q(layers[key])
@@ -645,9 +862,6 @@ def _quantize_weight_tree(weights: Dict, q) -> Dict:
         for key in _QUANT_MLP_KEYS:
             if key in moe and not isinstance(moe[key], dict):
                 moe[key] = q(moe[key])
-    if "lm_head" in weights and not isinstance(weights["lm_head"], dict):
-        weights["lm_head"] = q(weights["lm_head"])
-    return weights
 
 
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
@@ -676,14 +890,28 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
             q = q + w["bq"].reshape(H, D)
             k = k + w["bk"].reshape(Hkv, D)
             v = v + w["bv"].reshape(Hkv, D)
+        if "q_norm" in w:       # RMSNorm over each head's values (afmoe)
+            q = _norm(q, {"scale": w["q_norm"]}, "rms", spec.eps, dtype)
+            k = _norm(k, {"scale": w["k_norm"]}, "rms", spec.eps, dtype)
         if spec.rope_theta is not None:
             q = _rope_flat(q, positions, spec.rope_theta, spec.rotary_dim)
             k = _rope_flat(k, positions, spec.rope_theta, spec.rotary_dim)
 
-        attn_raw, *state = attend(q, k, v)
-        attn_out = _lora_mm(attn_raw.reshape(-1, H * D), w["wo"], lora, "o")
+        # KV page write + kernel, by the layer's kind of attention
+        with jax.named_scope("attn_full" if spec.window is None
+                             else "attn_window"):
+            attn_raw, *state = attend(q, k, v)
+        attn_raw = attn_raw.reshape(-1, H * D)
+        if "wg" in w:           # output gate from the normed input (afmoe)
+            with jax.named_scope("gate"):
+                gate = jax.nn.sigmoid(_mm(h1, w["wg"]).astype(jnp.float32))
+                attn_raw = (attn_raw * gate).astype(dtype)
+        attn_out = _lora_mm(attn_raw, w["wo"], lora, "o")
         if "bo" in w:
             attn_out = attn_out + w["bo"]
+        if "ln1_post" in w:     # sandwich norm: the branch's output, normed
+            attn_out = _norm(attn_out, w["ln1_post"], spec.norm, spec.eps,
+                             dtype, spec.norm_plus_one)
 
     if spec.parallel_block:
         mlp_in = (_norm(x, w["ln2"], spec.norm, spec.eps, dtype,
@@ -697,7 +925,7 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     with jax.named_scope("ffn"):
         if spec.moe is not None:
             mlp_out = _moe_ffn(mlp_in, {**w["moe"], **(experts or {})},
-                               spec.moe["top_k"], dtype, l)
+                               spec.moe["top_k"], dtype, l, routing=spec.moe)
         else:
             m = w["mlp"]
             if spec.activation in ("swiglu", "geglu"):
@@ -712,6 +940,9 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
             mlp_out = _mm(hmid, m["w_down"])
             if "b_down" in m:
                 mlp_out = mlp_out + m["b_down"]
+        if "ln2_post" in w:
+            mlp_out = _norm(mlp_out, w["ln2_post"], spec.norm, spec.eps,
+                            dtype, spec.norm_plus_one)
 
     if spec.parallel_block:
         x = x + attn_out + mlp_out
@@ -915,8 +1146,6 @@ def build_ragged_forward(spec: RaggedModelSpec,
     hid = spec.hidden_size
     dtype = spec.dtype
 
-    ak = AttentionKernelSpec(spec, mesh=mesh, tp=tp, n_splits=n_splits)
-
     def fwd(weights, kv_pages, b):
         kv_pages, kv_sc = _kv_unpack(kv_pages)
         kvq = kv_sc is not None
@@ -932,39 +1161,43 @@ def build_ragged_forward(spec: RaggedModelSpec,
         positions = jnp.concatenate([b["chunk_positions"], b["decode_positions"]])
 
         x = _embed_in(spec, weights, tokens, positions)
-        layers, experts = _split_expert_stacks(weights["layers"])
 
-        def layer_fn(carry, scanned):
-            x, kvp, sc = carry
-            w, l = scanned
+        def make_body(rs, experts, l0):
+            ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp,
+                                     n_splits=_kind_splits(spec, rs, n_splits))
 
-            def attend(q, k, v):
-                dest = _layer_dest(b["kv_dest"], l, NB, bs, L)
-                if kvq:
-                    kvp_, sc_ = _kv_page_write_quant(kvp, sc, k, v, dest,
-                                                     Hkv, bs)
-                    scales = sc_.reshape(L * NB, r8, 128)
-                else:
-                    kvp_ = _kv_page_write(kvp, k, v, dest, Hkv, bs)
-                    sc_, scales = sc, None
-                kv_l = kvp_.reshape(L * NB, 2, Hkv, bs, D)
-                out_c = ak.chunk(q[:CT].reshape(NC, Cs, H, D), kv_l,
-                                 b["chunk_block_tables"] + l * NB,
-                                 b["chunk_q0"], b["chunk_ctx_lens"],
-                                 kv_scales=scales)
-                out_d = ak.decode(q[CT:], kv_l,
-                                  b["decode_block_tables"] + l * NB,
-                                  b["decode_ctx_lens"], kv_scales=scales)
-                return (jnp.concatenate([out_c.reshape(CT, H, D), out_d],
-                                        axis=0), kvp_, sc_)
+            def layer_fn(carry, scanned):
+                x, kvp, sc = carry
+                w, l = scanned
 
-            x, (kvp, sc) = _transformer_layer(spec, w, x, positions, attend,
-                                              experts=experts, l=l)
-            return (x, kvp, sc), None
+                def attend(q, k, v):
+                    dest = _layer_dest(b["kv_dest"], l, NB, bs, L)
+                    if kvq:
+                        kvp_, sc_ = _kv_page_write_quant(kvp, sc, k, v, dest,
+                                                         Hkv, bs)
+                        scales = sc_.reshape(L * NB, r8, 128)
+                    else:
+                        kvp_ = _kv_page_write(kvp, k, v, dest, Hkv, bs)
+                        sc_, scales = sc, None
+                    kv_l = kvp_.reshape(L * NB, 2, Hkv, bs, D)
+                    out_c = ak.chunk(q[:CT].reshape(NC, Cs, H, D), kv_l,
+                                     b["chunk_block_tables"] + l * NB,
+                                     b["chunk_q0"], b["chunk_ctx_lens"],
+                                     kv_scales=scales)
+                    out_d = ak.decode(q[CT:], kv_l,
+                                      b["decode_block_tables"] + l * NB,
+                                      b["decode_ctx_lens"], kv_scales=scales)
+                    return (jnp.concatenate([out_c.reshape(CT, H, D), out_d],
+                                            axis=0), kvp_, sc_)
 
-        (x, kvp, sc), _ = jax.lax.scan(
-            layer_fn, (x, kvp0, sc0),
-            (layers, jnp.arange(L, dtype=jnp.int32)))
+                x, (kvp, sc) = _transformer_layer(rs, w, x, positions, attend,
+                                                  experts=experts, l=l - l0)
+                return (x, kvp, sc), None
+
+            return layer_fn
+
+        x, kvp, sc = _scan_layers(spec, weights["layers"], make_body,
+                                  (x, kvp0, sc0))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
@@ -1000,8 +1233,6 @@ def build_prefill_forward(spec: RaggedModelSpec,
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
 
-    ak = AttentionKernelSpec(spec, mesh=mesh, tp=tp)
-
     def fwd(weights, kv_pages, b):
         NC = b["chunk_ntok"].shape[0]
         CT = b["chunk_tokens"].shape[0]
@@ -1018,36 +1249,40 @@ def build_prefill_forward(spec: RaggedModelSpec,
         seg = b["row_seg"]
 
         x = _embed_in(spec, weights, tokens, positions)
-        layers, experts = _split_expert_stacks(weights["layers"])
 
-        def layer_fn(carry, scanned):
-            x, kvp, sc = carry
-            w, l = scanned
+        def make_body(rs, experts, l0):
+            ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp)
 
-            def attend(q, k, v):
-                # attention reads the PACKED in-flight rows (full precision);
-                # only the page write quantizes — the fast path's packed-vs-
-                # paged variance already makes equality gates force the paged
-                # path, int8 or not (docs/SERVING.md "Quantized KV")
-                out = ak.packed(q, k, v, seg)
-                if kvq:
-                    kvp_, sc_ = _kv_page_write_pages_quant(
-                        kvp, sc, k, v, l, b["page_ids"],
-                        b["page_rows"], b["page_fill"], NB, bs, L, Hkv)
-                else:
-                    kvp_ = _kv_page_write_pages(
-                        kvp, k, v, l, b["page_ids"], b["page_rows"],
-                        b["page_fill"], NB, bs, L, Hkv)
-                    sc_ = sc
-                return out, kvp_, sc_
+            def layer_fn(carry, scanned):
+                x, kvp, sc = carry
+                w, l = scanned
 
-            x, (kvp, sc) = _transformer_layer(spec, w, x, positions, attend,
-                                              experts=experts, l=l)
-            return (x, kvp, sc), None
+                def attend(q, k, v):
+                    # attention reads the PACKED in-flight rows (full
+                    # precision); only the page write quantizes — the fast
+                    # path's packed-vs-paged variance already makes equality
+                    # gates force the paged path, int8 or not
+                    # (docs/SERVING.md "Quantized KV")
+                    out = ak.packed(q, k, v, seg)
+                    if kvq:
+                        kvp_, sc_ = _kv_page_write_pages_quant(
+                            kvp, sc, k, v, l, b["page_ids"],
+                            b["page_rows"], b["page_fill"], NB, bs, L, Hkv)
+                    else:
+                        kvp_ = _kv_page_write_pages(
+                            kvp, k, v, l, b["page_ids"], b["page_rows"],
+                            b["page_fill"], NB, bs, L, Hkv)
+                        sc_ = sc
+                    return out, kvp_, sc_
 
-        (x, kvp, sc), _ = jax.lax.scan(
-            layer_fn, (x, kvp0, sc0),
-            (layers, jnp.arange(L, dtype=jnp.int32)))
+                x, (kvp, sc) = _transformer_layer(rs, w, x, positions, attend,
+                                                  experts=experts, l=l - l0)
+                return (x, kvp, sc), None
+
+            return layer_fn
+
+        x, kvp, sc = _scan_layers(spec, weights["layers"], make_body,
+                                  (x, kvp0, sc0))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
@@ -1104,8 +1339,6 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
     Cb = n_steps
     while (Cb * Hkv) % 8 != 0:
         Cb += 1
-    scale = 1.0 / (D ** 0.5)
-    ak = AttentionKernelSpec(spec, mesh=None, tp=1, n_splits=n_splits)
 
     def fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
             key, temperature=1.0):
@@ -1134,54 +1367,62 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
         side_dtype = jnp.float32 if kvq else dtype
         side_k0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
         side_v0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
-        layers, experts = _split_expert_stacks(weights["layers"])
 
         def one_pass(x_ids, pos, j, sk_all, sv_all):
             x = _embed_in(spec, weights, x_ids, pos)
 
-            def layer_fn(carry, scanned):
-                # side buffers ride the CARRY with in-place dynamic updates —
-                # as scan xs/ys they are repacked (a full side-buffer copy
-                # per step, measured slower than the scatter they replace)
-                x, sk_all, sv_all = carry
-                w, l = scanned
+            def make_body(rs, experts, l0):
+                ak = AttentionKernelSpec(
+                    rs, mesh=None, tp=1,
+                    n_splits=_kind_splits(spec, rs, n_splits))
 
-                def attend(q, k, v):
-                    if kvq:
-                        # int8 pools: the slab holds the rows' POOL values
-                        # (quantize-then-dequantize), so the in-chunk tokens
-                        # are attended at the same values every later
-                        # pool read — and the spec verify's write-then-
-                        # attend — dequantizes; the chunk-end flush
-                        # re-quantizes to the identical int8 bytes
-                        # (kv_write_dequant is value-idempotent)
-                        k = kv_write_dequant(k)
-                        v = kv_write_dequant(v)
-                    # step j's rows are the contiguous flat span
-                    # [j*Hkv, (j+1)*Hkv)
-                    sk_new = jax.lax.dynamic_update_slice(
-                        sk_all, k[None].astype(sk_all.dtype),
-                        (l, 0, j * Hkv, 0))
-                    sv_new = jax.lax.dynamic_update_slice(
-                        sv_all, v[None].astype(sv_all.dtype),
-                        (l, 0, j * Hkv, 0))
-                    # the WHOLE [L, S, Cb, Hkv, D] stack goes to the kernel,
-                    # which BlockSpec-indexes layer l — a dynamic_slice here
-                    # would materialise the layer's slab per call (measured
-                    # ~150 us/layer of pure copy traffic)
-                    out = ak.sidebuf(
-                        q, kvp5, block_tables + l * NB, prefix,
-                        sk_new, sv_new, j, layer_idx=l,
-                        kv_scales=sc4 if kvq else None)
-                    return out, sk_new, sv_new
+                def layer_fn(carry, scanned):
+                    # side buffers ride the CARRY with in-place dynamic
+                    # updates — as scan xs/ys they are repacked (a full
+                    # side-buffer copy per step, measured slower than the
+                    # scatter they replace)
+                    x, sk_all, sv_all = carry
+                    w, l = scanned
 
-                x, (sk_all, sv_all) = _transformer_layer(
-                    spec, w, x, pos, attend, experts=experts, l=l)
-                return (x, sk_all, sv_all), None
+                    def attend(q, k, v):
+                        if kvq:
+                            # int8 pools: the slab holds the rows' POOL
+                            # values (quantize-then-dequantize), so the
+                            # in-chunk tokens are attended at the same
+                            # values every later pool read — and the spec
+                            # verify's write-then-attend — dequantizes; the
+                            # chunk-end flush re-quantizes to the identical
+                            # int8 bytes (kv_write_dequant is
+                            # value-idempotent)
+                            k = kv_write_dequant(k)
+                            v = kv_write_dequant(v)
+                        # step j's rows are the contiguous flat span
+                        # [j*Hkv, (j+1)*Hkv)
+                        sk_new = jax.lax.dynamic_update_slice(
+                            sk_all, k[None].astype(sk_all.dtype),
+                            (l, 0, j * Hkv, 0))
+                        sv_new = jax.lax.dynamic_update_slice(
+                            sv_all, v[None].astype(sv_all.dtype),
+                            (l, 0, j * Hkv, 0))
+                        # the WHOLE [L, S, Cb, Hkv, D] stack goes to the
+                        # kernel, which BlockSpec-indexes layer l — a
+                        # dynamic_slice here would materialise the layer's
+                        # slab per call (measured ~150 us/layer of pure copy
+                        # traffic)
+                        out = ak.sidebuf(
+                            q, kvp5, block_tables + l * NB, prefix,
+                            sk_new, sv_new, j, layer_idx=l,
+                            kv_scales=sc4 if kvq else None)
+                        return out, sk_new, sv_new
 
-            (x, sk_new, sv_new), _ = jax.lax.scan(
-                layer_fn, (x, sk_all, sv_all),
-                (layers, jnp.arange(L, dtype=jnp.int32)))
+                    x, (sk_all, sv_all) = _transformer_layer(
+                        rs, w, x, pos, attend, experts=experts, l=l - l0)
+                    return (x, sk_all, sv_all), None
+
+                return layer_fn
+
+            x, sk_new, sv_new = _scan_layers(spec, weights["layers"],
+                                             make_body, (x, sk_all, sv_all))
             x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                       spec.norm_plus_one)
             return _unembed(spec, weights, x), sk_new, sv_new
@@ -1463,8 +1704,6 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
     dtype = spec.dtype
     K1 = k + 1
 
-    ak = AttentionKernelSpec(spec, mesh=mesh, tp=tp, n_splits=n_splits)
-
     def fwd(weights, kv_pages, ids, draft, n_draft, positions0,
             block_tables, ctx0, *lora_args):
         kv_pages, kv_sc = _kv_unpack(kv_pages)
@@ -1499,47 +1738,53 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
         dest = (page * bs + positions % bs).reshape(-1)
 
         x = _embed_in(spec, weights, tokens.reshape(-1), pos_flat)
-        layers, experts = _split_expert_stacks(weights["layers"])
 
-        def layer_fn(carry, scanned):
-            x, kvp, sc = carry
-            if lora_ops is not None:
-                w, l, lora_l = scanned
-                lora = _lora_split(spec, lora_targets, lora_l)
-            else:
-                w, l = scanned
-                lora = None
+        def make_body(rs, experts, l0):
+            ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp,
+                                     n_splits=_kind_splits(spec, rs, n_splits))
 
-            def attend(q, k_, v):
-                # write-then-attend (the ragged pass's discipline): all K+1
-                # rows' K/V scatter into the pool — quantize-on-write for
-                # int8 pools, the same fused append the decode step runs —
-                # then the chunk kernel reads pages causally (dequantizing
-                # in-flight), row j's own token included: every in-pass
-                # token is attended at its POOL value, exactly what
-                # sequential decode attends (docs/SERVING.md "Quantized KV")
-                dl = _layer_dest(dest, l, NB, bs, L)
-                if kvq:
-                    kvp_, sc_ = _kv_page_write_quant(kvp, sc, k_, v, dl,
-                                                     Hkv, bs)
-                    scales = sc_.reshape(L * NB, r8, 128)
+            def layer_fn(carry, scanned):
+                x, kvp, sc = carry
+                if lora_ops is not None:
+                    w, l, lora_l = scanned
+                    lora = _lora_split(spec, lora_targets, lora_l)
                 else:
-                    kvp_ = _kv_page_write(kvp, k_, v, dl, Hkv, bs)
-                    sc_, scales = sc, None
-                kv_l = kvp_.reshape(L * NB, 2, Hkv, bs, D)
-                out = ak.chunk(q.reshape(S, K1, H, D), kv_l,
-                               block_tables + l * NB, positions0,
-                               ctx0 + (K1 - 1), kv_scales=scales)
-                return out.reshape(S * K1, H, D), kvp_, sc_
+                    w, l = scanned
+                    lora = None
 
-            x, (kvp, sc) = _transformer_layer(spec, w, x, pos_flat, attend,
-                                              lora=lora, experts=experts, l=l)
-            return (x, kvp, sc), None
+                def attend(q, k_, v):
+                    # write-then-attend (the ragged pass's discipline): all
+                    # K+1 rows' K/V scatter into the pool — quantize-on-write
+                    # for int8 pools, the same fused append the decode step
+                    # runs — then the chunk kernel reads pages causally
+                    # (dequantizing in-flight), row j's own token included:
+                    # every in-pass token is attended at its POOL value,
+                    # exactly what sequential decode attends
+                    # (docs/SERVING.md "Quantized KV")
+                    dl = _layer_dest(dest, l, NB, bs, L)
+                    if kvq:
+                        kvp_, sc_ = _kv_page_write_quant(kvp, sc, k_, v, dl,
+                                                         Hkv, bs)
+                        scales = sc_.reshape(L * NB, r8, 128)
+                    else:
+                        kvp_ = _kv_page_write(kvp, k_, v, dl, Hkv, bs)
+                        sc_, scales = sc, None
+                    kv_l = kvp_.reshape(L * NB, 2, Hkv, bs, D)
+                    out = ak.chunk(q.reshape(S, K1, H, D), kv_l,
+                                   block_tables + l * NB, positions0,
+                                   ctx0 + (K1 - 1), kv_scales=scales)
+                    return out.reshape(S * K1, H, D), kvp_, sc_
 
-        xs = (layers, jnp.arange(L, dtype=jnp.int32))
-        if lora_ops is not None:
-            xs = xs + (lora_ops,)
-        (x, kvp, sc), _ = jax.lax.scan(layer_fn, (x, kvp0, sc0), xs)
+                x, (kvp, sc) = _transformer_layer(
+                    rs, w, x, pos_flat, attend, lora=lora, experts=experts,
+                    l=l - l0)
+                return (x, kvp, sc), None
+
+            return layer_fn
+
+        x, kvp, sc = _scan_layers(
+            spec, weights["layers"], make_body, (x, kvp0, sc0),
+            extra_xs=() if lora_ops is None else (lora_ops,))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
@@ -1579,8 +1824,6 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
 
-    ak = AttentionKernelSpec(spec, mesh=mesh, tp=tp, n_splits=n_splits)
-
     def fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
             key, temperature=1.0, *lora_args):
         kv_pages, kv_sc = _kv_unpack(kv_pages)
@@ -1598,7 +1841,6 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
         else:
             assert not lora_args, "lora operands on a non-LoRA program"
             lora_ops = None
-        layers, experts = _split_expert_stacks(weights["layers"])
 
         def one_pass(x_ids, pos, ctx, kvp, sc):
             # kvp flat [L*NB*2*Hkv*bs, D]. The attention + page-write is one
@@ -1608,44 +1850,52 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
             # for why a pre-kernel scatter forces XLA to clone the pool).
             x = _embed_in(spec, weights, x_ids, pos)
 
-            def layer_fn(carry, scanned):
-                x, kvp, sc = carry
-                if lora_ops is not None:
-                    w, l, lora_l = scanned
-                    lora = _lora_split(spec, lora_targets, lora_l)
-                else:
-                    w, l = scanned
-                    lora = None
+            def make_body(rs, experts, l0):
+                ak = AttentionKernelSpec(
+                    rs, mesh=mesh, tp=tp,
+                    n_splits=_kind_splits(spec, rs, n_splits))
 
-                def attend(q, k, v):
-                    if kvq:
-                        # the current token is attended from registers:
-                        # hand the kernel its POOL value (the in-kernel
-                        # re-quantization for the page write is
-                        # value-idempotent) so this path agrees with the
-                        # write-then-attend paths on the attended VALUES
-                        k = kv_write_dequant(k)
-                        v = kv_write_dequant(v)
-                        out, kv5, sc4 = ak.decode_step(
+                def layer_fn(carry, scanned):
+                    x, kvp, sc = carry
+                    if lora_ops is not None:
+                        w, l, lora_l = scanned
+                        lora = _lora_split(spec, lora_targets, lora_l)
+                    else:
+                        w, l = scanned
+                        lora = None
+
+                    def attend(q, k, v):
+                        if kvq:
+                            # the current token is attended from registers:
+                            # hand the kernel its POOL value (the in-kernel
+                            # re-quantization for the page write is
+                            # value-idempotent) so this path agrees with the
+                            # write-then-attend paths on the attended VALUES
+                            k = kv_write_dequant(k)
+                            v = kv_write_dequant(v)
+                            out, kv5, sc4 = ak.decode_step(
+                                q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
+                                block_tables + l * NB, ctx,
+                                kv_scales=sc.reshape(L * NB, r8, 128))
+                            return (out,
+                                    kv5.reshape(L * NB * 2 * Hkv * bs, D),
+                                    sc4.reshape(L * NB * r8 * 128))
+                        out, kv5 = ak.decode_step(
                             q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
-                            block_tables + l * NB, ctx,
-                            kv_scales=sc.reshape(L * NB, r8, 128))
+                            block_tables + l * NB, ctx)
                         return (out, kv5.reshape(L * NB * 2 * Hkv * bs, D),
-                                sc4.reshape(L * NB * r8 * 128))
-                    out, kv5 = ak.decode_step(
-                        q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
-                        block_tables + l * NB, ctx)
-                    return (out, kv5.reshape(L * NB * 2 * Hkv * bs, D), sc)
+                                sc)
 
-                x, (kvp, sc) = _transformer_layer(spec, w, x, pos, attend,
-                                                  lora=lora, experts=experts,
-                                                  l=l)
-                return (x, kvp, sc), None
+                    x, (kvp, sc) = _transformer_layer(
+                        rs, w, x, pos, attend, lora=lora, experts=experts,
+                        l=l - l0)
+                    return (x, kvp, sc), None
 
-            xs = (layers, jnp.arange(L, dtype=jnp.int32))
-            if lora_ops is not None:
-                xs = xs + (lora_ops,)
-            (x, kvp, sc), _ = jax.lax.scan(layer_fn, (x, kvp, sc), xs)
+                return layer_fn
+
+            x, kvp, sc = _scan_layers(
+                spec, weights["layers"], make_body, (x, kvp, sc),
+                extra_xs=() if lora_ops is None else (lora_ops,))
             x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                       spec.norm_plus_one)
             logits = _unembed(spec, weights, x)

@@ -1,8 +1,10 @@
 """Model zoo: the BASELINE config ladder families (gpt2, llama/mistral, mixtral,
-gpt-neox) plus the inference-container families (opt, falcon, phi, bert) —
+gpt-neox) plus the inference-container families (opt, falcon, phi, bert) and
+afmoe (Arcee Trinity: layers of several kinds in one model) —
 matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
+from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
 from deepspeed_tpu.models.bert import BertConfig, BertForMaskedLM
 from deepspeed_tpu.models.decoder import (DecoderConfig, DecoderLM,
                                           init_decoder_cache)

@@ -73,7 +73,7 @@ DECODER_TP_RULES: List[Tuple[str, str]] = [
 # kernels carry a leading [L] (and MoE an [E]) dim, which COLUMN (last dim) / ROW
 # (second-to-last) already handle; embeddings/norms/router replicate (no rule)
 RAGGED_STACKED_TP_RULES: List[Tuple[str, str]] = [
-    (r".*/(wq|wk|wv|bq|bk|bv)", COLUMN),
+    (r".*/(wq|wk|wv|wg|bq|bk|bv)", COLUMN),   # wg: afmoe's output gate
     (r".*/wo", ROW),
     (r".*/(w_gate|w_up|b_up)", COLUMN),
     (r".*/w_down", ROW),

@@ -139,8 +139,40 @@ def _moe_text():
     def moe_layer(x, w):
         return _moe_ffn(x, w, 2, jnp.float32)
 
-    return {"moe": jax.jit(moe_layer).lower(jnp.ones((16, 64)), w).as_text(
+    out = {"moe": jax.jit(moe_layer).lower(jnp.ones((16, 64)), w).as_text(
         debug_info=True)}
+    # afmoe's router (sigmoid scores, selection bias) and shared expert
+    shared = {k: jnp.ones(v.shape[1:]) for k, v in w.items() if k != "router"}
+    w2 = dict(w, expert_bias=jnp.zeros((4,)), shared=shared)
+
+    def afmoe_layer(x, w):
+        return _moe_ffn(x, w, 2, jnp.float32, routing={
+            "score_func": "sigmoid", "route_norm": True, "route_scale": 2.0})
+
+    out["afmoe_moe"] = jax.jit(afmoe_layer).lower(
+        jnp.ones((16, 64)), w2).as_text(debug_info=True)
+    return out
+
+
+def _afmoe_text():
+    """A model of mixed layer kinds: its ragged pass carries both kinds of
+    attention scope and the gate."""
+    from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
+    cfg = AfmoeConfig.tiny(dtype=jnp.float32)
+    model = AfmoeForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    e = InferenceEngineV2(model=model, model_parameters=params, config={
+        "dtype": "float32", "kv_cache": {"block_size": 8, "num_blocks": 16},
+        "state_manager": {"max_context": 64, "max_tracked_sequences": 2,
+                          "max_ragged_sequence_count": 2,
+                          "max_ragged_batch_size": 2 + 16,
+                          "prefill_chunk_size": 8}})
+    paged = e._pass = e._pass_rungs[1] = _Recorder(e._pass)
+    prompt = np.arange(1, 21, dtype=np.int32)
+    e.put([1], [prompt[:12]])
+    e.put([1], [prompt[12:]])
+    return {"afmoe_paged_pass": paged.text()}
 
 
 @pytest.fixture(scope="module")
@@ -148,7 +180,8 @@ def texts():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     out = {}
-    for build in (_serving_texts, _train_texts, _zero3_text, _moe_text):
+    for build in (_serving_texts, _train_texts, _zero3_text, _moe_text,
+                  _afmoe_text):
         out.update(build())
     return out
 
@@ -191,6 +224,13 @@ CASES = [
     ("moe", "scope", "moe_ffn/sort"),
     ("moe", "scope", "moe_ffn/experts"),
     ("moe", "scope", "moe_ffn/combine"),
+    ("afmoe_moe", "scope", "moe_ffn/router"),
+    ("afmoe_moe", "scope", "moe_ffn/shared"),
+    ("paged_pass", "scope", "attn/attn_full"),      # tiny llama: no window
+    ("afmoe_paged_pass", "scope", "attn/attn_window"),
+    ("afmoe_paged_pass", "scope", "attn/attn_full"),
+    ("afmoe_paged_pass", "scope", "attn/gate"),
+    ("afmoe_paged_pass", "scope", "moe_ffn/shared"),
 ]
 
 
