@@ -43,7 +43,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    _scale_tile_rows, kv_quantize_rows, kv_write_dequant)
+    _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
 
 
 def _kv_unpack(kp):
@@ -1318,15 +1318,21 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
         one sequence's [C, Hkv, D] slab into VMEM instead of the round-4
         schedule's per-layer-per-step jnp re-read of the whole [C, S, Hkv,
         D] buffer + lse merge;
-      - ONE page-granular read-modify-write flushes the side buffers into
-        the pools at chunk end (~n_span pages per sequence per layer,
-        amortized over the C steps).
+      - at chunk end ONE kernel writes the side buffers' rows into the
+        pools (``paged_kv_row_write``, scope ``kv_flush``): per sequence the
+        aligned groups of slots its C tokens fall in, for every layer —
+        row-granular, so the single decode step (C = 1, what the serving
+        pipeline runs) pays for one token's rows. The whole-page
+        read-modify-write this replaced moved two pages per sequence per
+        layer whatever C was: a quarter of a 32-row Mistral-7B step on a
+        v5e, where nothing amortized it (PERF.md, PR 28).
 
     Used when tp == 1 and head_dim % 128 == 0 (the fused kernel's
     alignment); other configs take the general loop below. ``window`` is
     admitted (the kernel windows both pieces by the moving query position);
-    the page-ring flush stays correct because the flush only touches pages
-    holding positions >= prefix, which the ring never recycles mid-chunk.
+    on the page ring the write stays correct because it only touches slots
+    holding positions >= prefix, whose pages the ring never recycles
+    mid-chunk.
     """
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     G = H // Hkv
@@ -1346,7 +1352,6 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
         kvq = kv_sc is not None
         S = ids0.shape[0]
         L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
-        MB = block_tables.shape[1]
         kvp5 = kv_pages.reshape(L * NB, 2, Hkv, bs, D)
         # scales are stored in kernel tile layout AT REST — the view below
         # is a bitcast, so the frozen-pool scans never pay a conversion
@@ -1443,70 +1448,16 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
             step, (ids0, positions0, side_k0, side_v0, init_logits),
             jnp.arange(C))
 
-        # ---- chunk-end flush: side buffers -> pool, page-granular RMW ---- #
+        # ---- chunk-end flush: the side buffers' rows -> the pool ---- #
         # the kernels READ the pool inside the scan; the barrier ties the
-        # flush's pool operand to the scan result so XLA orders the in-place
-        # scatter after the reads instead of cloning the (GB-scale) pool
-        kvp5b, sc4b, _ = jax.lax.optimization_barrier(
-            (kvp5, sc4, final_logits))
-        n_span = -(-C // bs) + 1
-        t_idx = jnp.arange(n_span)
-        lp = prefix[:, None] // bs + t_idx[None, :]             # [S, n_span]
-        phys = jnp.take_along_axis(block_tables,
-                                   jnp.minimum(lp, MB - 1),
-                                   axis=1)                      # [S, n_span]
-        page_valid = (lp * bs < prefix[:, None] + C) & (lp < MB)
-        # token slot k of span page t: global pos g = lp*bs + k, side row
-        # j = g - prefix (valid iff 0 <= j < C)
-        g_pos = lp[:, :, None] * bs + jnp.arange(bs)[None, None, :]
-        j_rel = g_pos - prefix[:, None, None]                   # [S, n_span, bs]
-        tok_valid = (j_rel >= 0) & (j_rel < C)
-        j_clamp = jnp.clip(j_rel, 0, C - 1)
-        s_idx = jnp.arange(S)[:, None, None]
-        phys_l = (phys[None] + (jnp.arange(L) * NB)[:, None, None])
-        phys_l = jnp.where(page_valid[None], phys_l, L * NB)    # OOB -> drop
-        idx = jnp.minimum(phys_l, L * NB - 1)
-
-        # side [L, S, Cb*Hkv, D] flat rows -> combined new values
-        # [L, S, n_span, 2, Hkv, bs, D]
-        def span_of(side):
-            rows = j_clamp[..., None] * Hkv + jnp.arange(Hkv)  # [S,nsp,bs,Hkv]
-            newv = side[:, s_idx[..., None], rows]  # [L,S,n_span,bs,Hkv,D]
-            return jnp.moveaxis(newv, 4, 3)         # [...,Hkv,bs,D]
-
-        newv = jnp.stack([span_of(sk_all), span_of(sv_all)], axis=3)
-        old = kvp5b[idx]                            # [L,S,n_span,2,Hkv,bs,D]
-        tv = tok_valid[None, :, :, None, None, :, None]
-        if kvq:
-            # int8 pool: quantize the flushed rows; the RMW keeps the old
-            # page values AND old scales where the span page's slots predate
-            # the chunk. Scales combine in the at-rest TILE layout (flat
-            # per-page order kv*Hkv*bs + h*bs + t, zero-padded to R8*128).
-            newq, news = kv_quantize_rows(newv)     # [L,S,n_span,2,Hkv,bs]
-            comb = jnp.where(tv, newq, old)
-            olds = sc4b[idx]                        # [L,S,n_span,R8,128]
-            n_sp = news.shape[2]
-            pad = r8 * 128 - 2 * Hkv * bs
-            newt = news.reshape(L, S, n_sp, 2 * Hkv * bs)
-            tvf = jnp.broadcast_to(tok_valid[:, :, None, :],
-                                   (S, n_sp, 2 * Hkv, bs)
-                                   ).reshape(S, n_sp, 2 * Hkv * bs)
-            if pad:
-                newt = jnp.pad(newt, ((0, 0),) * 3 + ((0, pad),))
-                tvf = jnp.pad(tvf, ((0, 0),) * 2 + ((0, pad),))
-            combs = jnp.where(tvf.reshape(1, S, n_sp, r8, 128),
-                              newt.reshape(L, S, n_sp, r8, 128), olds)
-            kvf = kvp5b.at[phys_l.reshape(-1)].set(
-                comb.reshape(-1, 2, Hkv, bs, D), mode="drop")
-            scf = sc4b.at[phys_l.reshape(-1)].set(
-                combs.reshape(-1, r8, 128), mode="drop")
-            new_kv = (kvf.reshape(L, NB, 2, Hkv, bs, D),
-                      scf.reshape(L, NB, r8, 128))
-        else:
-            comb = jnp.where(tv, newv.astype(kvp5b.dtype), old)
-            kvf = kvp5b.at[phys_l.reshape(-1)].set(
-                comb.reshape(-1, 2, Hkv, bs, D), mode="drop")
-            new_kv = kvf.reshape(L, NB, 2, Hkv, bs, D)
+        # write's pool operand to the scan result so XLA orders the in-place
+        # write after the reads instead of cloning the (GB-scale) pool
+        kv_pages, kv_sc, _ = jax.lax.optimization_barrier(
+            (kv_pages, kv_sc, final_logits))
+        with jax.named_scope("kv_flush"):
+            new_kv = paged_kv_row_write(kv_pages, sk_all, sv_all,
+                                        block_tables, prefix, C,
+                                        kv_scales=kv_sc)
         return (out_ids, final_logits, new_kv)
 
     return fwd
@@ -1614,7 +1565,9 @@ def build_decode_step(spec: RaggedModelSpec, mesh=None, tp: int = 1,
 
     The forward body is exactly ``build_multistep_decode(n_steps=1)`` — the
     same one-pass math the fused bursts run, so a pipelined token stream is
-    bit-identical to a ``decode_steps`` burst under greedy decoding. On top
+    bit-identical to a ``decode_steps`` burst under greedy decoding; on the
+    side-buffer schedule the step's K/V write is that schedule's row write
+    (``kv_flush``) with one row per sequence, head and layer. On top
     of it this wrapper re-derives the step's sampled next token from the
     returned logits (same key fold as the scan's step 0, so XLA CSEs it with
     the scan-internal sample) and RETURNS it, which the multistep builders

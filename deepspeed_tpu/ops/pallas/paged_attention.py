@@ -992,6 +992,163 @@ def paged_decode_attention_sidebuf(q: jax.Array,
         return call(*operands)
 
 
+def _row_group(pos0, g, n_rows, group, n_slots):
+    """The g-th aligned group of ``group`` slots that ``n_rows`` tokens from
+    position ``pos0`` on fall in, held at the last one they (and the block
+    table's ``n_slots``) reach: a grid step past it revisits that block."""
+    last = jnp.minimum(pos0 + n_rows - 1, n_slots - 1)
+    return jnp.minimum(pos0 // group + g, last // group)
+
+
+def _kv_row_write_kernel(bt_ref, pre_ref, *refs, n_rows, h_kv, group,
+                         block_size, n_slots, layers, quant):
+    """One grid step = (a tile of layers, one sequence, one group of slots):
+    the group's K and V rows of every layer of the tile come in, the side
+    rows that fall in it replace theirs, the block goes back. Every step
+    merges ALL its sequence's rows that hit its block — and, for an int8
+    pool, all that hit its page's scale tile — so a block revisited by the
+    next step (same index: neither fetched again nor written between) ends
+    up the same."""
+    del bt_ref
+    if quant:
+        sk_ref, sv_ref, ssk_ref, ssv_ref, kv_in, sc_in, kv_out, sc_out = refs
+    else:
+        sk_ref, sv_ref, kv_in, kv_out = refs
+    s, g = pl.program_id(1), pl.program_id(2)
+    pos0 = pre_ref[s]
+    base = _row_group(pos0, g, n_rows, group, n_slots) * group
+    page0 = base // block_size * block_size
+    D = kv_out.shape[-1]
+    slot = base + jax.lax.broadcasted_iota(jnp.int32, (group, D), 0)
+    if quant:
+        r8 = sc_out.shape[2]
+        tile = (jax.lax.broadcasted_iota(jnp.int32, (r8, 128), 0) * 128
+                + jax.lax.broadcasted_iota(jnp.int32, (r8, 128), 1))
+    # positions past the block table are written nowhere
+    pos = [jnp.where(pos0 + j < n_slots, pos0 + j, -1) for j in range(n_rows)]
+
+    def layer(l, carry):
+        for kv, side in ((0, sk_ref), (1, sv_ref)):
+            for h in range(h_kv):
+                cur = kv_in[l, 0, kv, h].astype(jnp.float32)
+                for j in range(n_rows):
+                    cur = jnp.where(slot == pos[j],
+                                    side[l, 0, pl.ds(j * h_kv + h, 1), :], cur)
+                kv_out[l, 0, kv, h] = cur.astype(kv_out.dtype)
+        if quant:
+            cur = sc_in[l, 0]
+            for kv, side in ((0, ssk_ref), (1, ssv_ref)):
+                for h in range(h_kv):
+                    for j in range(n_rows):
+                        t = pos[j] - page0
+                        idx = jnp.where((t >= 0) & (t < block_size),
+                                        (kv * h_kv + h) * block_size + t, -1)
+                        cur = jnp.where(
+                            tile == idx,
+                            side[l, 0, pl.ds(j * h_kv + h, 1), :], cur)
+            sc_out[l, 0] = cur
+        return carry
+
+    jax.lax.fori_loop(0, layers, layer, 0)
+
+
+def paged_kv_row_write(kv_pages: jax.Array, side_k: jax.Array,
+                       side_v: jax.Array, block_tables: jax.Array,
+                       prefix: jax.Array, n_rows: int,
+                       kv_scales: Optional[jax.Array] = None):
+    """Write ``n_rows`` new tokens per sequence into EVERY layer's pages, in
+    place: token ``j`` of sequence ``s`` (position ``prefix[s] + j``) goes to
+    slot ``pos % bs`` of page ``block_tables[s, pos // bs]``, its ``H_kv`` K
+    rows and ``H_kv`` V rows taken from rows ``j*H_kv + h`` of the side
+    buffers. The cost follows the rows written, not the pages they fall in.
+
+    kv_pages:     [L, NB, 2, H_kv, bs, D] — ALIASED: the returned pool
+                  reuses the input buffer
+    side_k/v:     [L, S, >= n_rows*H_kv, D]
+    block_tables: [S, MB] int32     prefix: [S] int32
+    kv_scales:    [L, NB, R8, 128] f32 scale tiles of an int8 pool (also
+                  aliased): the rows quantize per token-head
+                  (:func:`kv_quantize_rows`) and their scales land at the
+                  tile offset ``kv*H_kv*bs + h*bs + slot``.
+
+    Mosaic refuses a DMA of one row into a page (a slice of the slot
+    dimension must be aligned to the pool's tiling), so the unit is the
+    aligned group of slots one tile holds (16 of a bf16 pool): the group is
+    read, the new rows replace theirs, the group is written back. One block
+    carries that group for K and V of as many layers as fit a MiB, so a
+    step moves ``S`` blocks per tile of layers — at Mistral-7B widths 32 x
+    1 MiB for 32 rows x 16 layers, where whole pages were 1,024 x 512 KiB
+    three times over. Positions past the block table are written nowhere.
+    Two sequences whose tables hold the same page (the engine's pad rows,
+    all at its scratch page) leave either's rows there.
+
+    Returns the pool, or ``(pool, kv_scales)``."""
+    L, NB, two, Hkv, bs, D = kv_pages.shape
+    S, MB = block_tables.shape
+    assert two == 2 and side_k.shape[:2] == (L, S)
+    quant = kv_scales is not None
+    item = jnp.dtype(kv_pages.dtype).itemsize
+    G = min(32 // item, bs)                     # the slots of one tile
+    assert bs % G == 0
+    n_groups = (n_rows + 2 * G - 2) // G        # groups n_rows can straddle
+    rows_side = side_k.shape[2]
+    # layers a block: as many as keep the pool's block and a step's side
+    # rows (float32; values and, for an int8 pool, scales) under a MiB each
+    layer_bytes = max(2 * Hkv * G * D * item,
+                      (4 if quant else 2) * rows_side * max(D, 128) * 4)
+    LT = max(t for t in range(1, L + 1)
+             if L % t == 0 and (t == 1 or t * layer_bytes <= 1 << 20))
+
+    def block(s, g, bt, pre):
+        """(page, group of slots in it) of grid step (s, g)."""
+        gg = _row_group(pre[s], g, n_rows, G, MB * bs)
+        return bt[s, gg * G // bs], gg % (bs // G)
+
+    def side_spec(width):
+        return pl.BlockSpec((LT, 1, rows_side, width),
+                            lambda lt, s, g, bt, pre: (lt, s, 0, 0))
+
+    # single rows are read out of the side buffers: 32-bit rows, which a
+    # kernel may slice anywhere (the values are exact in float32)
+    sides = [side_k.astype(jnp.float32), side_v.astype(jnp.float32)]
+    pools = [kv_pages]
+    def pool_map(lt, s, g, bt, pre):
+        page, slots = block(s, g, bt, pre)
+        return (lt, page, 0, 0, slots, 0)
+
+    pool_specs = [pl.BlockSpec((LT, 1, 2, Hkv, G, D), pool_map)]
+    if quant:
+        kq, ks = kv_quantize_rows(side_k)
+        vq, vs = kv_quantize_rows(side_v)
+        lanes = side_k.shape[:3] + (128,)
+        sides = [kq.astype(jnp.float32), vq.astype(jnp.float32),
+                 jnp.broadcast_to(ks[..., None], lanes),
+                 jnp.broadcast_to(vs[..., None], lanes)]
+        pools.append(kv_scales)
+        pool_specs.append(pl.BlockSpec(
+            (LT, 1) + kv_scales.shape[2:],
+            lambda lt, s, g, bt, pre: (lt, block(s, g, bt, pre)[0], 0, 0)))
+    first_pool = 2 + len(sides)                 # after the two prefetched
+    call = pl.pallas_call(
+        functools.partial(_kv_row_write_kernel, n_rows=n_rows, h_kv=Hkv,
+                          group=G, block_size=bs, n_slots=MB * bs,
+                          layers=LT, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(L // LT, S, n_groups),
+            in_specs=[side_spec(x.shape[-1]) for x in sides] + pool_specs,
+            out_specs=pool_specs),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools],
+        input_output_aliases={first_pool + i: i for i in range(len(pools))},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3),
+        interpret=_backend.interpret(),
+    )
+    with jax.named_scope("paged_kv_row_write"):
+        out = call(block_tables.astype(jnp.int32), prefix.astype(jnp.int32),
+                   *sides, *pools)
+    return tuple(out) if quant else out[0]
+
+
 def _decode_kernel_smalld(bt_ref, cl_ref, q_ref, kv_ref, o_ref,
                           acc_sc, m_sc, l_sc, *, scale, block_size,
                           max_blocks, h_kv, groups, window=None,

@@ -16,7 +16,9 @@ Python exception) at these four shapes — every 64/80/96-wide-head family
 would have killed a server at its first decode compile.
 """
 
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
 
@@ -236,3 +238,72 @@ def test_moe_layer_scan_reads_expert_stacks_in_place(T, v5e):
               if f"= bf16[{E},{K},{N}]" in ln or f"= bf16[{E},{N},{K}]" in ln]
     assert not copies, f"a layer's expert stack is materialised: {copies}"
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_decode_step_writes_kv_rows_in_place(pool, v5e, monkeypatch):
+    """The serving decode step at Mistral-7B width (16 layers, 32 rows,
+    pages of 128 tokens, a pool of 792 pages). Its K/V write used to gather
+    two whole pages per sequence per layer (``[L, S, 2, 2, Hkv, bs, D]``,
+    512 MiB), merge them with the new rows and scatter them back — three
+    passes, a quarter of the step on the chip. Now: a kernel writes the
+    rows; no instruction produces a value of that size, nothing copies the
+    pool, the pool (and an int8 pool's scale tiles) is the output's
+    buffer, and what the program keeps besides its arguments is small."""
+    from deepspeed_tpu.inference.v2.ragged_model import (RaggedModelSpec,
+                                                         build_decode_step)
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    L, rows, pages, hid, ffn, vocab = 16, 32, 792, 4096, 14336, 32000
+    chip = SingleDeviceSharding(v5e[0])
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    spec = RaggedModelSpec(family="llama", num_layers=L, hidden_size=hid,
+                           num_heads=H, num_kv_heads=HKV, head_dim=D,
+                           vocab_size=vocab, window=WINDOW, dtype=BF16)
+    weights = {
+        "embed": arr(BF16, vocab, hid), "lm_head": arr(BF16, hid, vocab),
+        "final_norm": {"scale": arr(BF16, hid)},
+        "layers": {"ln1": {"scale": arr(BF16, L, hid)},
+                   "ln2": {"scale": arr(BF16, L, hid)},
+                   "wq": arr(BF16, L, hid, H * D),
+                   "wk": arr(BF16, L, hid, HKV * D),
+                   "wv": arr(BF16, L, hid, HKV * D),
+                   "wo": arr(BF16, L, H * D, hid),
+                   "mlp": {"w_gate": arr(BF16, L, hid, ffn),
+                           "w_up": arr(BF16, L, hid, ffn),
+                           "w_down": arr(BF16, L, ffn, hid)}}}
+    kv_dtype = BF16 if pool == "bf16" else I8
+    kv = arr(kv_dtype, L, pages, 2, HKV, BS, D)
+    if pool == "int8":
+        kv = (kv, arr(F32, L, *kv_scale_tiles_shape(pages, HKV, BS)))
+    compiled = jax.jit(
+        build_decode_step(spec, window_ring_ok=True), donate_argnums=(1,)
+    ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
+            arr(I32, rows, MB), arr(I32, rows),
+            arr(jnp.uint32, 2)).compile()
+    text = compiled.as_text()
+    assert "paged_kv_row_write" in text, "the row writer is not in the program"
+    pool_elems = L * pages * 2 * HKV * BS * D
+    span_elems = L * rows * 2 * 2 * HKV * BS * D
+    produced = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* (\S+?)\(")
+    big = []
+    for line in text.splitlines():
+        m = produced.match(line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        n = math.prod(dims)
+        if dims[-2:] == [BS, D] and (
+                n == span_elems
+                or (n == pool_elems and m.group(2).startswith("copy"))):
+            big.append(line.strip()[:100])
+    assert not big, f"whole pages or a copy of the pool: {big}"
+    n_pools = 2 if pool == "int8" else 1
+    header = text.splitlines()[0]
+    assert len(re.findall(r"\{\d+\}: \(1[23], \{\}", header)) == n_pools, \
+        header[:200]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_elems * jnp.dtype(kv_dtype).itemsize
+    assert mem.temp_size_in_bytes < 64 << 20

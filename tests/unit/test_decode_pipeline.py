@@ -433,3 +433,81 @@ def test_generate_zero_new_compiles_in_grid(warm_engine):
     c0 = e.compiles
     e.generate(PROMPTS, max_new_tokens=5)
     assert e.compiles == c0
+
+
+# --------------------------------------------------------------------------- #
+# the decode step's K/V write: pool bytes, against the per-step-write loop
+# --------------------------------------------------------------------------- #
+
+def _one_layer_engine(kvq: bool):
+    """head_dim 128 (the side-buffer schedule's gate), ONE layer: a layer's
+    K/V rows depend on no attention output, so the side-buffer schedule and
+    the per-step-write loop must leave the same BYTES in the pool."""
+    cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256,
+                      num_hidden_layers=1, num_attention_heads=2,
+                      num_key_value_heads=2, max_position_embeddings=512,
+                      dtype=jnp.float32)
+    model = LlamaForCausalLM(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(2),
+                                 {"input_ids": jnp.zeros((1, 8), jnp.int32)}
+                                 )["params"]
+    econf = {"dtype": jnp.float32,
+             "state_manager": {"max_tracked_sequences": 4,
+                               "max_ragged_sequence_count": 4,
+                               "max_ragged_batch_size": 160,
+                               "prefill_chunk_size": 80,
+                               "max_context": 256},
+             "kv_cache": {"block_size": 64}}
+    if kvq:
+        econf["kv_quant"] = {"enabled": True}
+    return InferenceEngineV2(model=model, model_parameters=params,
+                             config=econf)
+
+
+def _pool_bytes(engine):
+    kv = engine.kv.kv
+    leaves = kv if isinstance(kv, tuple) else (kv,)
+    # the scratch page is the pad rows' and belongs to no sequence
+    return [np.asarray(x)[:, :engine.scratch_block] for x in leaves]
+
+
+@pytest.mark.parametrize("kvq", [False, True], ids=["f32_pool", "int8_pool"])
+def test_step_and_burst_leave_the_per_step_loops_bytes_in_the_pool(
+        kvq, monkeypatch):
+    """Three live rows in a bucket of four (one pad row), one of them at
+    slot 63 of its page so the next step opens a new page: two pipelined
+    single steps, then a burst of 8. The pool (and an int8 pool's scale
+    tiles) must hold what the per-step-write loop — a row scatter after
+    every step's kernel — leaves there."""
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(0, 128, size=(n,)).astype(np.int32)
+               for n in (63, 17, 70)]
+    uids = [0, 1, 2]
+
+    def serve(engine):
+        engine.put(uids, list(prompts))
+        pipe = engine.decode_pipeline(uids)
+        first = [pipe.run(1) for _ in range(2)]
+        pipe.retire(uids)
+        burst = engine.decode_steps(uids, 8)
+        return np.concatenate(first + [burst], axis=1), _pool_bytes(engine)
+
+    from deepspeed_tpu.inference.v2 import ragged_model
+    flushes = []
+    flush = ragged_model.paged_kv_row_write
+    monkeypatch.setattr(
+        ragged_model, "paged_kv_row_write",
+        lambda *a, **kw: (flushes.append(a[5]), flush(*a, **kw))[1])
+    got_ids, got = serve(_one_layer_engine(kvq))
+    assert set(flushes) == {1, 8}           # at each program's tracing
+    traced = len(flushes)
+    # a side-buffer budget of nothing sends every program to the general loop
+    monkeypatch.setenv("DSTPU_SIDEBUF_MAX_MB", "0")
+    want_ids, want = serve(_one_layer_engine(kvq))
+    assert len(flushes) == traced
+    np.testing.assert_array_equal(got_ids, want_ids)
+    assert len(got) == (2 if kvq else 1)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+    assert np.count_nonzero(got[0]) > 0

@@ -72,6 +72,35 @@ def _serving_texts():
             "paged_pass": paged.text()}
 
 
+def _wide_head_text():
+    """Heads 128 wide: the decode step takes the side-buffer schedule, whose
+    K/V write comes once per program, after the layers."""
+    cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256,
+                      num_hidden_layers=2, num_attention_heads=2,
+                      num_key_value_heads=2, max_position_embeddings=128,
+                      dtype=jnp.float32)
+    model = LlamaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
+    e = InferenceEngineV2(model=model, model_parameters=params, config={
+        "dtype": jnp.float32,
+        "state_manager": {"max_tracked_sequences": 2,
+                          "max_ragged_sequence_count": 2,
+                          "max_ragged_batch_size": 32, "max_context": 64},
+        "kv_cache": {"block_size": 8}})
+    built = e._decode_step_prog
+    steps = []
+
+    def decode_step_prog(*a, **k):
+        steps.append(_Recorder(built(*a, **k)))
+        return steps[-1]
+
+    e._decode_step_prog = decode_step_prog
+    e.put([1], [np.arange(1, 13, dtype=np.int32)])
+    e.decode_pipeline([1]).run(2)
+    return {"decode_step_d128": steps[0].text()}
+
+
 def _train_texts():
     # steered in the test: on the CPU the model would take the dense
     # reference attention; the flash kernel (interpreted) is what a chip runs
@@ -180,8 +209,8 @@ def texts():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     out = {}
-    for build in (_serving_texts, _train_texts, _zero3_text, _moe_text,
-                  _afmoe_text):
+    for build in (_serving_texts, _wide_head_text, _train_texts, _zero3_text,
+                  _moe_text, _afmoe_text):
         out.update(build())
     return out
 
@@ -198,6 +227,9 @@ CASES = [
     ("decode_step", "scope", "attn"),
     ("decode_step", "scope", "ffn"),
     ("decode_step", "scope", "paged_decode_smalld"),   # the tiny head size's
+    ("decode_step_d128", "scope", "paged_decode_sidebuf"),
+    ("decode_step_d128", "scope", "kv_flush"),
+    ("decode_step_d128", "scope", "kv_flush/paged_kv_row_write"),
     ("prefill_packed", "program", "jit_serve_prefill_packed"),
     ("prefill_packed", "scope", "flash_fwd_packed"),
     ("prefill_packed", "scope", "attn"),

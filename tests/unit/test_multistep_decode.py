@@ -176,3 +176,116 @@ def test_sidebuf_multistep_matches_dense_model(eight_devices):
             nxt = int(np.argmax(np.asarray(lg[0, -1])))
             assert nxt == ids2[i][step], (u, step)
             cur = np.concatenate([cur, [nxt]])
+
+
+# --------------------------------------------------------------------------- #
+# the chunk-end K/V write: rows, not pages
+# --------------------------------------------------------------------------- #
+
+_L, _NB, _HKV, _BS, _D, _MB = 2, 8, 2, 128, 128, 6
+_SCRATCH = _NB - 1
+
+
+def _flush_case(case, C):
+    """(block_tables [S, MB], prefix [S], rows whose side values are equal)."""
+    bt = np.stack([np.arange(_MB), np.arange(_MB)[::-1]]).astype(np.int32)
+    if case == "page_edge":
+        # the step at slot 127 of a page, the next in slot 0 of a new one
+        return bt, np.array([_BS - 1, 3 * _BS - 1], np.int32), ()
+    if case == "pad_rows":
+        # a bucket of 4 with 2 live rows: the pad rows point wholly at the
+        # scratch page, at position 0, and carry the same token
+        pad = np.full((2, _MB), _SCRATCH, np.int32)
+        return (np.concatenate([bt, pad]),
+                np.array([5, _BS + 9, 0, 0], np.int32), (2, 3))
+    if case == "ring":
+        # a windowed sequence: logical pages wrap onto a ring of 3 physical
+        ring = np.stack([np.arange(_MB) % 3, 3 + np.arange(_MB) % 3])
+        return (ring.astype(np.int32),
+                np.array([3 * _BS - 3, 5 * _BS - C], np.int32), ())
+    if case == "past_table":
+        # positions beyond the block table drop, the others land
+        return bt, np.array([_MB * _BS - 3, _MB * _BS], np.int32), ()
+    raise AssertionError(case)
+
+
+def _reference_row_write(pool, scales, side_k, side_v, bt, prefix, C):
+    """Row by row, in numpy: step j of sequence s goes to slot (prefix + j)
+    % bs of page bt[s, (prefix + j) // bs] in every layer, K and V, head by
+    head; nothing else changes. int8 pools take each row's quantized values
+    and its scale at the tile offset kv*Hkv*bs + h*bs + slot."""
+    from deepspeed_tpu.ops.pallas.paged_attention import kv_quantize_rows
+    pool = pool.copy()
+    sides = [np.asarray(side_k), np.asarray(side_v)]
+    if scales is not None:
+        scales = scales.copy()
+        flat = scales.reshape(_L, _NB, -1)
+        # jitted, as every writer of the pool is: XLA divides by the
+        # constant 127 its own way there, one ulp off the eager result
+        quant = [jax.jit(kv_quantize_rows)(jnp.asarray(x)) for x in sides]
+        sides = [np.asarray(q) for q, _ in quant]
+        side_scales = [np.asarray(s) for _, s in quant]
+    for l in range(_L):
+        for s in range(bt.shape[0]):
+            for j in range(C):
+                pos = int(prefix[s]) + j
+                if pos // _BS >= bt.shape[1]:
+                    continue
+                page, slot = bt[s, pos // _BS], pos % _BS
+                for kv in range(2):
+                    for h in range(_HKV):
+                        pool[l, page, kv, h, slot] = \
+                            sides[kv][l, s, j * _HKV + h].astype(pool.dtype)
+                        if scales is not None:
+                            flat[l, page, (kv * _HKV + h) * _BS + slot] = \
+                                side_scales[kv][l, s, j * _HKV + h]
+    return pool, scales
+
+
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("C", [1, 8])
+@pytest.mark.parametrize("case", ["page_edge", "pad_rows", "ring",
+                                  "past_table"])
+def test_kv_flush_writes_rows_like_a_row_by_row_reference(case, C,
+                                                          pool_dtype):
+    """Pool bytes after a decode step (C = 1) and after a burst (C = 8),
+    twice in a row so the second write continues where the first stopped,
+    equal a row-by-row reference write: same values, same dtype, every
+    other byte of the pool (and of an int8 pool's scale tiles) untouched."""
+    import ml_dtypes
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _scale_tile_rows, paged_kv_row_write)
+    kvq = pool_dtype == "int8"
+    rng = np.random.RandomState(7)
+    bt, prefix, twins = _flush_case(case, C)
+    S = bt.shape[0]
+    shape = (_L, _NB, 2, _HKV, _BS, _D)
+    if kvq:
+        pool = rng.randint(-127, 128, size=shape).astype(np.int8)
+        r8 = _scale_tile_rows(_HKV, _BS)
+        scales = rng.rand(_L, _NB, r8, 128).astype(np.float32)
+        side_dtype = np.float32
+    else:
+        pool = rng.randn(*shape).astype(ml_dtypes.bfloat16)
+        scales, side_dtype = None, ml_dtypes.bfloat16
+    flush = jax.jit(paged_kv_row_write, static_argnums=(5,))
+    for _ in range(2):
+        side = [rng.randn(_L, S, C * _HKV, _D).astype(side_dtype)
+                for _ in range(2)]
+        for x in side:
+            for s in twins[1:]:
+                x[:, s] = x[:, twins[0]]
+        want, want_sc = _reference_row_write(pool, scales, *side, bt, prefix,
+                                             C)
+        got = flush(jnp.asarray(pool), jnp.asarray(side[0]),
+                    jnp.asarray(side[1]), jnp.asarray(bt),
+                    jnp.asarray(prefix), C,
+                    None if scales is None else jnp.asarray(scales))
+        if kvq:
+            got, got_sc = got
+            np.testing.assert_array_equal(np.asarray(got_sc), want_sc)
+            scales = want_sc
+        assert got.dtype == pool.dtype and got.shape == shape
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert not np.array_equal(want, pool) or case == "past_table"
+        pool, prefix = want, prefix + C

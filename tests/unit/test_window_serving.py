@@ -164,3 +164,68 @@ def test_window_one_chunk_boundary_finalizes():
             o_r, _ = paged_decode_attention_step_reference(
                 q, kn, vn, kv, bts, cls_, window=W)
             assert float(jnp.max(jnp.abs(o - o_r))) < 2e-2, (W, ctx)
+
+
+@pytest.mark.parametrize("burst", [1, 8])
+def test_windowed_flush_on_the_ring_leaves_the_per_step_loops_bytes(
+        burst, monkeypatch, eight_devices):
+    """A windowed model whose heads are 128 wide serves through the
+    side-buffer schedule (``ring_covers`` holds for 2 and for 9 tokens): two
+    sequences past their window, so every write lands on a ring page that
+    once held an older logical page. After 24 tokens — single pipelined
+    steps, or bursts of 8 — the pool holds, byte for byte, what the
+    per-step-write loop leaves there. One layer: its K/V rows depend on no
+    attention output, so the two schedules must agree exactly."""
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256,
+                      num_hidden_layers=1, num_attention_heads=2,
+                      num_key_value_heads=2, max_position_embeddings=256,
+                      sliding_window=16, dtype=jnp.float32)
+    model = LlamaForCausalLM(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(3),
+                                 {"input_ids": jnp.zeros((1, 8), jnp.int32)}
+                                 )["params"]
+    rng = np.random.RandomState(6)
+    prompts = [rng.randint(0, 128, size=(n,)).astype(np.int32)
+               for n in (40, 33)]
+
+    def serve():
+        engine = InferenceEngineV2(
+            model=model, model_parameters=params,
+            config={"state_manager": {"max_tracked_sequences": 2,
+                                      "max_ragged_sequence_count": 2,
+                                      "max_ragged_batch_size": 40,
+                                      "prefill_chunk_size": 8,
+                                      "max_context": 96},
+                    "kv_cache": {"block_size": 8}, "dtype": jnp.float32})
+        assert engine.spec.window == 16 and engine.spec.head_dim == 128
+        assert engine.scheduler.ring_covers(burst + 1)
+        engine.put([1, 2], list(prompts))
+        if burst == 1:
+            ids = engine.decode_pipeline([1, 2]).run(24)
+        else:
+            ids = np.concatenate([engine.decode_steps([1, 2], burst)
+                                  for _ in range(24 // burst)], axis=1)
+        for u in (1, 2):       # the ring wrapped: fewer pages than logical
+            seq = engine.scheduler.seqs[u]
+            assert len(set(seq.blocks)) <= engine.scheduler.ring_pages
+            assert len(set(seq.blocks)) < -(-seq.seen_tokens // 8)
+        return ids, np.asarray(engine.kv.kv)[:, :engine.scratch_block]
+
+    from deepspeed_tpu.inference.v2 import ragged_model
+    flushes = []
+    flush = ragged_model.paged_kv_row_write
+    monkeypatch.setattr(
+        ragged_model, "paged_kv_row_write",
+        lambda *a, **kw: (flushes.append(a[5]), flush(*a, **kw))[1])
+    got_ids, got = serve()
+    assert set(flushes) == {burst}
+    traced = len(flushes)
+    # a side-buffer budget of nothing sends every program to the general loop
+    monkeypatch.setenv("DSTPU_SIDEBUF_MAX_MB", "0")
+    want_ids, want = serve()
+    assert len(flushes) == traced
+    np.testing.assert_array_equal(got_ids, want_ids)
+    np.testing.assert_array_equal(got, want)
+    assert np.count_nonzero(got) > 0
