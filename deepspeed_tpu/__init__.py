@@ -67,7 +67,7 @@ def initialize(args=None,
     from deepspeed_tpu.utils import fault_injection
 
     # arm the deterministic fault plan, if any (no-op unless $DSTPU_FAULTS is
-    # set) — the kill-and-resume bench drives subprocess workers through this
+    # set) — how a subprocess worker is handed its faults
     fault_injection.install_from_env()
     # arm span tracing from $DSTPU_TRACE (no-op unless set; config.monitor.
     # trace reaches the same tracer through the engine) — docs/OBSERVABILITY.md

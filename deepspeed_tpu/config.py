@@ -239,8 +239,8 @@ class OffloadOptimizerConfig(ConfigModel):
     # "Offloaded optimizer pipeline"): while group g runs its host kernel,
     # group g+1's grad D2H is in flight and group g-1's updated master is
     # already uploading/casting back. False restores the fully serial
-    # fetch-all / step-all / upload-all step (identical math — the bench's
-    # byte-equality baseline).
+    # fetch-all / step-all / upload-all step (identical math — the reference
+    # of tests/unit/test_offload.py's byte-equality test).
     overlap_step: bool = True
     # Worker threads for the host optimizer kernel (leaves are chunked and
     # stepped concurrently; both the native OpenMP kernels via ctypes and
@@ -459,7 +459,7 @@ class TraceConfig(ConfigModel):
     pipelines plus a crash flight recorder. No direct reference analog — the
     reference leans on torch.profiler; here the async pipelines carry their
     own zero-sync span instrumentation. Also armable without config via the
-    ``DSTPU_TRACE=<dir>`` env var (subprocess benches)."""
+    ``DSTPU_TRACE=<dir>`` env var (subprocess workers)."""
 
     enabled: bool = False
     # where trace_{pid}.json / trace_crash.json land; nonempty implies enabled

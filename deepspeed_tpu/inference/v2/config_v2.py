@@ -183,14 +183,14 @@ class SpecDecodeConfig:
     matching — no second model), verifies them in ONE ragged forward
     (``ragged_model.build_verify_step``), and emits the accepted prefix plus
     one greedy bonus token. Greedy speculation is exactness-preserving:
-    token streams are byte-identical to the spec-off pipeline, gated by
-    ``serving_bench.py --spec``.
+    token streams are byte-identical to the spec-off pipeline
+    (``tests/unit/test_spec_decode.py::test_spec_stream_matches_plain_pipeline``).
 
     ``k``: max draft tokens verified per step — the top rung of the
     (bucket, k) warmup grid. Prefer ``k + 1`` a POWER OF TWO (3, 7, 15):
     the chunk kernel's q-block must divide k+1, and an odd k+1 collapses
-    it to 1-row blocks with (k+1)x the grid steps (measured ~2x slower on
-    the bench box — a misaligned k warns below). ``min_match`` /
+    it to 1-row blocks with (k+1)x the grid steps (a misaligned k warns
+    below). ``min_match`` /
     ``max_ngram``: the proposer matches the longest history suffix of
     length in [min_match, max_ngram] and proposes its continuation; no
     match proposes nothing and the step degenerates to plain decode for
@@ -348,20 +348,11 @@ class ServingConfig:
     ``max_queue`` bounds the pending queue (beyond = immediate shed);
     ``idle_wait_s`` is the engine thread's block interval when idle.
 
-    ``attribution``: record the per-request phase ledger
-    (``RequestHandle.timeline()`` — queued/admission/prefill/handoff_wait/
-    decode/preempted/restore/migration stints from the same perf stamps the
-    trace spans carry) and bucket SLO misses by dominant phase
-    (``serve/slo/*``; docs/OBSERVABILITY.md "SLO-miss attribution"). A few
-    list appends per phase TRANSITION — nothing per token; ``False``
-    disables both (the A/B lever ``serving_bench.py --trace-overhead``
-    gates).
-
     ``spec``: serve greedy requests through the engine's speculative
     pipeline when ``spec_decode.enabled`` (default). ``False`` pins this
     frontend to the plain ``DecodePipeline`` — a per-frontend A/B lever
     (draft-miss overhead vs k-token amortization), and the discipline the
-    byte-equality bench gates use: spec-on and spec-off greedy streams
+    byte-equality tests use: spec-on and spec-off greedy streams
     agree only up to cross-kernel float noise (~1e-4/token argmax flips on
     a random-init model — docs/SERVING.md "Quantized KV" gate taxonomy),
     so a replay gated bit-exactly against a plain reference serves plain.
@@ -370,7 +361,7 @@ class ServingConfig:
     here = LoRA adapter name, the multi-tenant identity of docs/SERVING.md
     "Multi-tenant LoRA"). Per-request ``priority=`` stays the override, but
     a submit that names an adapter WITHOUT naming a class defaults to the
-    tenant's mapped class instead of ``"standard"`` — mixed benches stop
+    tenant's mapped class instead of ``"standard"`` — a mixed queue stops
     misclassifying traffic whose class lives in workload config rather
     than on each request. Every value must name a configured class.
     """
@@ -384,7 +375,6 @@ class ServingConfig:
     shed_factor: float = 1.0
     max_queue: int = 1024
     idle_wait_s: float = 0.02
-    attribution: bool = True
 
     def __post_init__(self):
         self.classes = [PriorityClassConfig(**c) if isinstance(c, dict) else c
@@ -482,7 +472,7 @@ class RouterConfig:
       SGLang-RadixAttention trick at cluster scope, read from a shared
       chain-hash index fed by per-replica insert/evict deltas), scored
       against load: ``score = cached_tokens - balance * outstanding``.
-    - ``"round_robin"``: placement ignores caches — the bench baseline.
+    - ``"round_robin"``: placement ignores caches — the baseline.
 
     ``balance`` is the stickiness/balance tradeoff knob: how many cached
     prompt tokens one outstanding request on a replica outweighs. ``0`` is

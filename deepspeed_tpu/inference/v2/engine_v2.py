@@ -289,7 +289,7 @@ class InferenceEngineV2:
             self._pass_rungs[r] = _program(
                 fwd_r, "serve_paged_pass" + _rung(r), donate_argnums=(1,))
             self.compiles += 1
-        # bench/test knob: pin the dispatched rung (None = admission-driven)
+        # test knob: pin the dispatched rung (None = admission-driven)
         self.attn_rung_override: Optional[int] = None
         self._pass_prefill = None  # built on the first pure-prefill pass
         self._rng = np.random.RandomState(cfg.seed)
@@ -741,7 +741,7 @@ class InferenceEngineV2:
         tokens per split, clamped to the warmed ladder — short-context
         batches stay on the split=1 chunk-serial program (the merge pass is
         pure overhead there) and the long-context tail climbs the ladder as
-        it grows. ``attn_rung_override`` pins the choice (bench A/B legs on
+        it grows. ``attn_rung_override`` pins the choice (A/B runs on
         one warmed engine). Records the selection through the shared perf
         stamps: one ``perf_counter`` pair feeds both the
         ``serve/attn/select`` trace span and ``attn_stats`` (the

@@ -19,8 +19,8 @@ State machine per adapter (docs/SERVING.md "Multi-tenant LoRA"):
 Refcounts gate eviction exactly like KV pages: an adapter bound to any
 in-flight request can never be evicted, so a decode batch's gather is
 always backed. Fault-in under pool pressure evicts idle adapters LRU;
-``maybe_fail("serve.lora_fault")`` sits inside the fault-in so the chaos
-bench can cancel mid-fault (rollback: allocated pages freed, binding
+``maybe_fail("serve.lora_fault")`` sits inside the fault-in so a chaos
+test can cancel mid-fault (rollback: allocated pages freed, binding
 undone, refcounts at baseline).
 
 Each fault-in/evict takes ONE pair of ``perf_counter`` stamps feeding both
@@ -65,7 +65,7 @@ class _Adapter:
 class LoraAdapterRegistry:
     """Adapter lifecycle over one :class:`LoraPagePool`.
 
-    ONE mutator thread by design (the frontend's engine thread / the bench
+    ONE mutator thread by design (the frontend's engine thread / a test's
     driver — the same discipline as the scheduler), but the cheap metadata
     readers (``names``/``rank``/``is_resident``/``can_admit``/``binding``)
     are called from CLIENT threads (``frontend.submit`` validation) and the
@@ -172,10 +172,10 @@ class LoraAdapterRegistry:
         whole lifetime, so a drained adapter just drops back to
         REGISTERED and its next fault-in re-uploads from the master
         instead of the pinned snapshot. Settles the pool to its quiescent
-        baseline (``swap.outstanding == 0``) for leak accounting —
-        benchmarks snapshot their pool baselines after this, otherwise
-        whichever adapters HAPPEN to sit evicted at snapshot time read as
-        leaked buffers (the serving_bench --lora baseline flake)."""
+        baseline (``swap.outstanding == 0``) for leak accounting — a
+        caller snapshots its pool baseline after this, otherwise whichever
+        adapters HAPPEN to sit evicted at snapshot time read as leaked
+        buffers."""
         with self._meta:
             evicted = [ad for ad in self._adapters.values()
                        if ad.state == EVICTED]
@@ -313,8 +313,8 @@ class LoraAdapterRegistry:
             self.evict(victim.name)
         ids = self.pool.alloc(ad.rank)
         try:
-            # chaos site: cancel-while-faulting (serving_bench --lora and
-            # tests pin that the rollback restores refcounts + free pages)
+            # chaos site: cancel-while-faulting (tests/unit/test_lora_serving.py
+            # pins that the rollback restores refcounts + free pages)
             _maybe_fail("serve.lora_fault")
             if ad.state == EVICTED:
                 rows = np.stack([self.swap.view(buf, (self.pool.elements,),

@@ -1,8 +1,8 @@
 """Async double-buffered decode pipeline — the v2 steady-state serving loop.
 
-Why this exists: BENCH_r06 showed the prefix cache cutting prefill tokens 83%
-while wall clock moved ~5% — steady-state serving cost had become per-step
-HOST work, not device compute. The per-token loop paid, per generated token:
+Why this exists: with the prefix cache removing most prefill tokens, what
+was left of steady-state serving cost was per-step HOST work, not device
+compute. The per-token loop paid, per generated token:
 a device dispatch, a BLOCKING logits/token fetch, scheduler bookkeeping, a
 full ragged descriptor build, and another dispatch — all serialised. This
 pipeline restructures that into two overlapped stages (the TPU-jit analog of

@@ -67,8 +67,8 @@ def chain_hash(parent_chain: int, key: Tuple[int, ...]) -> int:
 
 @dataclass
 class PrefixCacheStats:
-    """Counters surfaced through ``monitor/`` (``events()``) and the serving
-    bench. ``tokens_saved`` counts prompt tokens whose prefill was skipped."""
+    """Counters surfaced through ``monitor/`` (``events()``).
+    ``tokens_saved`` counts prompt tokens whose prefill was skipped."""
     lookups: int = 0
     hits: int = 0                 # lookups that matched at least one block
     misses: int = 0

@@ -1494,9 +1494,10 @@ def build_multistep_decode(spec: RaggedModelSpec, n_steps: int,
     schedule does not need); above this budget the general loop is used
     (default from DSTPU_SIDEBUF_MAX_MB, 6144 MB — ADVICE r4's OOM guard.
     6 GB not 2: an MHA-12 serving leg's buffers are 2.3 GB and the general
-    loop is 4x slower there — measured bench regression when the gate was
-    2 GB — while v5e HBM comfortably holds 6 GB transient beside a
-    sub-1B serving model; larger models use the env knob).
+    loop is 4x slower there — a regression seen on the chip before PR 22,
+    of which no record survives, when the gate was 2 GB — while v5e HBM
+    comfortably holds 6 GB transient beside a sub-1B serving model; larger
+    models use the env knob).
 
     Returns ``fwd(weights, kv_pages, ids0 [S], positions0 [S],
     block_tables [S, MB], ctx0 [S], key) -> (out_ids [n_steps, S],
@@ -1621,7 +1622,7 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
     softmax — is identical to what ``build_decode_step`` computes one token
     at a time, so for any row whose consumed prefix matches the greedy
     stream the logits are BIT-EQUAL to sequential decode (the exactness
-    induction the byte-identical bench gate rests on; pinned by
+    induction the byte-identical stream rests on; pinned by
     tests/unit/test_spec_decode.py).
 
     The greedy accept mask is computed ON DEVICE: draft token j+1 is

@@ -44,8 +44,8 @@ class DynamicSplitFuseScheduler:
         # sliding-window page ring (ring reuse overwrites pages in place, so
         # a cached page's content would rot under a live sharer).
         self.prefix_cache = prefix_cache
-        # prompt tokens actually prefilled (post-cache); the shared-prefix
-        # bench leg reads this to report computed-prefill savings
+        # prompt tokens actually prefilled (post-cache): what a cache hit or
+        # a cache-aware route saved is read off this counter
         self.prefill_tokens_completed = 0
         self.seqs: Dict[int, DSSequenceDescriptor] = {}
         bs = cache.config.block_size

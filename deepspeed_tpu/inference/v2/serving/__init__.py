@@ -1,7 +1,7 @@
 """The SLO-aware serving stack — PAPER.md layer 6 (MII/FastGen) over
 ``InferenceEngineV2``, from one frontend to an N-replica cluster.
 
-Seven modules:
+Six modules:
 
 - ``frontend.py`` — ``ServingFrontend``: persistent engine thread driving
   iteration-level continuous batching over ``engine.decode_pipeline``;
@@ -15,8 +15,6 @@ Seven modules:
   round-trip through pinned host buffers (vLLM swap-out, not
   drop-and-recompute), byte-identical on restore; the same bucketed page
   path is the cluster's cross-engine KV fabric.
-- ``loadgen.py`` — Poisson open-loop load generator (seed-deterministic,
-  shared-prefix mixture components) + goodput-under-SLO scoring.
 - ``cluster.py`` — ``ServingCluster``: N data-parallel replicas (uniform
   page fabric, replica-labelled monitor surfaces) + ``PrefillWorker``
   (dedicated SplitFuse prefill under disaggregation).
@@ -44,10 +42,5 @@ from deepspeed_tpu.inference.v2.serving.health import (DOWN, DRAINING,
                                                        SUSPECT,
                                                        HealthMonitor)
 from deepspeed_tpu.inference.v2.serving.kv_offload import KVOffloadManager
-from deepspeed_tpu.inference.v2.serving.loadgen import (Arrival,
-                                                        PoissonLoadGen,
-                                                        WorkloadComponent,
-                                                        goodput_report,
-                                                        replay, slo_met)
 from deepspeed_tpu.inference.v2.serving.router import (ClusterPrefixIndex,
                                                        ServingRouter)

@@ -144,7 +144,7 @@ class ServingCluster:
                        f"{[r.name for r in self.replicas]}")
 
     def start(self) -> "ServingCluster":
-        """Start every replica frontend (idempotent per frontend — a bench
+        """Start every replica frontend (idempotent per frontend — a caller
         or test may warm frontends before handing the cluster to a
         router)."""
         for r in self.frontends:

@@ -18,8 +18,8 @@ Tokens drain one step late (PR 3's overlap discipline); the per-step
 queues — no device fetch, no formatting — so serving adds zero host syncs to
 the gated hot path. Admission and retirement move the live set between pow2
 buckets the engine pre-compiled (``engine.warmup()``), so steady-state
-admission adds ZERO compiles after warmup (gated by
-``serving_bench.py --frontend``).
+admission adds ZERO compiles after warmup
+(``tests/unit/test_kv_quant_stack.py::test_int8_frontend_preempt_cycle_compiles_nothing``).
 
 Under KV-pool pressure the admission plan PREEMPTS low-priority victims by
 offloading their private KV tail to pinned host buffers
@@ -67,7 +67,7 @@ _TERMINAL = (FINISHED, CANCELLED, SHED)
 #: process-lifetime flow-id mint. trace_id CANNOT be the uid: uid bases
 #: restart with every cluster/frontend lifetime while tracer rings (and
 #: the exporter's flow synthesizer) span the whole process, so uid reuse
-#: across successive clusters — every bench rep, any in-process serving
+#: across successive clusters — every repeat of a test, any in-process serving
 #: restart — would merge unrelated requests' hops into one bogus chain.
 #: The pid prefix keeps ids distinct across the subprocess workers whose
 #: files ``trace_merge.py`` stitches into one timeline.
@@ -84,10 +84,11 @@ def _mint_trace_id() -> int:
 def attribution_epsilon(client_s: float) -> float:
     """The ONE tolerance for "this request's ledger sums to its
     client-measured latency": max(5 ms, 1%). Shared by the
-    ``serve/slo/attr_consistent`` stat (``_finalize``) and the bench
-    attribution gates (``serving_bench._attribution_gate``) so the two can
-    never quietly measure different things (docs/OBSERVABILITY.md
-    "SLO-miss attribution")."""
+    ``serve/slo/attr_consistent`` stat (``_finalize``) and the tests that
+    hold the ledger to the client's clock
+    (``tests/unit/test_serving_health.py::test_cluster_scenario_under_lock_sanitizer``)
+    so the two can never quietly measure different things
+    (docs/OBSERVABILITY.md "SLO-miss attribution")."""
     return max(0.005, 0.01 * client_s)
 
 
@@ -154,15 +155,13 @@ class RequestHandle:
         #: the phase ledger: (phase, t0, t1) stints built from the SAME
         #: perf stamps the serve/req trace spans record — where this
         #: request's time went, summing to the client-measured latency for
-        #: finished requests. ``None`` when attribution is disabled
-        #: (``ServingConfig.attribution``).
-        self._ledger: Optional[List[tuple]] = []
+        #: finished requests
+        self._ledger: List[tuple] = []
 
     # -- phase attribution (docs/OBSERVABILITY.md "SLO-miss attribution") -- #
 
     def _ledger_add(self, phase: str, t0: float, t1: float) -> None:
-        if self._ledger is not None:
-            self._ledger.append((phase, t0, t1))
+        self._ledger.append((phase, t0, t1))
 
     def timeline(self) -> List[tuple]:
         """The per-request phase ledger: ``(phase, t0, t1)`` stints in
@@ -172,8 +171,8 @@ class RequestHandle:
         ``preempted``, ``restore``, ``migration``. For a finished request
         the stints tile ``arrival_t .. last-emission`` with no gaps, so
         their durations sum to the client-measured latency
-        (TTFT + Σ TBT). Empty when attribution is disabled."""
-        return list(self._ledger or ())
+        (TTFT + Σ TBT)."""
+        return list(self._ledger)
 
     def attribution(self) -> Dict[str, object]:
         """Phase attribution summary derived from :meth:`timeline`:
@@ -182,7 +181,7 @@ class RequestHandle:
         ledger total, and the client-measured latency (arrival to last
         emission; ``None`` before any token)."""
         phases: Dict[str, float] = {}
-        for phase, t0, t1 in (self._ledger or ()):
+        for phase, t0, t1 in self._ledger:
             phases[phase] = phases.get(phase, 0.0) + max(0.0, t1 - t0)
         total = sum(phases.values())
         client = (self._last_emit_t - self.arrival_t
@@ -268,9 +267,6 @@ class ServingFrontend:
                 "restore) or 'none'")
         self.engine = engine
         self.config = cfg
-        # phase-ledger recording (RequestHandle.timeline / serve/slo/*);
-        # off = handles carry no ledger and misses go unattributed
-        self._attribution = bool(getattr(cfg, "attribution", True))
         self.stats = FrontendStats([c.name for c in cfg.classes])
         # KV-pool gauges (monitor/serving.py): pool dtype + bytes/token are
         # static facts of the engine build; the capacity doubling an int8
@@ -377,8 +373,6 @@ class ServingFrontend:
         req = RequestHandle(next(self._uid_iter), prompt, cls,
                             int(max_new_tokens), eos_token_id,
                             time.perf_counter(), adapter=adapter)
-        if not self._attribution:
-            req._ledger = None
         with self._inflight_lock:
             self._inflight += 1
         self._ctl.put(("submit", req))
@@ -683,7 +677,7 @@ class ServingFrontend:
     def step(self) -> bool:
         """ONE frontend iteration: control drain -> cancellation sweep ->
         handoff imports -> admission plan -> prefill -> one decode slice.
-        Public so tests and deterministic bench phases can drive the loop
+        Public so tests and deterministic callers can drive the loop
         synchronously (no thread); returns False when the iteration found
         no work (idle)."""
         # chaos site (raise = crash this loop, stall = wedge it); the fence
@@ -867,9 +861,8 @@ class ServingFrontend:
                 consistent = (client is not None
                               and abs(attr["total_s"] - client)
                               <= attribution_epsilon(client))
-                self.stats.record_slo_miss(
-                    req.cls.name, attr["dominant"] or "unattributed",
-                    consistent)
+                self.stats.record_slo_miss(req.cls.name, attr["dominant"],
+                                           consistent)
         elif status == SHED:
             self.stats.record_shed(req.cls.name)
             if _tracer.enabled:

@@ -42,7 +42,8 @@ shed, never a hung stream. The dead replica's chain-hash entries leave the
 resets the engine (flush stranded sequences, drop stranded offload
 records), rebuilds a frontend in a FRESH uid space, re-warms the pow2
 program grids OFF the routing hot path (zero new compiles on an
-already-warm engine — gated by ``serving_bench.py --chaos``), re-registers
+already-warm engine — ``tests/unit/test_serving_health.py`` holds it, in
+``test_cluster_scenario_under_lock_sanitizer``), re-registers
 the prefix-index delta feed (replaying the engine's surviving radix tree),
 and only then returns the replica to routing.
 
@@ -177,8 +178,8 @@ class HealthMonitor:
             return all(r.state == HEALTHY for r in self._recs.values())
 
     def wait_all_healthy(self, timeout: float) -> bool:
-        """Poll until every replica is back in rotation (benches wait for
-        self-healing to complete before scoring baselines)."""
+        """Poll until every replica is back in rotation (a caller waits for
+        self-healing to complete before it reads pool baselines)."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             self.poll()

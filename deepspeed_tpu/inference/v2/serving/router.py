@@ -35,8 +35,9 @@ Three mechanisms (``config_v2.RouterConfig``):
   logits row, the exact record preempt-offload parks) into
   ``engine.import_kv`` on the decode engine (fresh pool ids, byte-exact
   content, ``_last_logits`` re-seeded like a preemption restore). Decode
-  replicas then never run a prefill pass, eliminating prefill interference
-  on decode TBT — the gate ``serving_bench.py --router`` measures.
+  replicas then never run a prefill pass, which takes prefill interference
+  off decode TBT (not measured on the chip: no cell runs a router yet,
+  ``PERF.md`` §7).
 
 Observability: ``serve/router/*`` counters (``monitor/serving.RouterStats``
 — placement, cache hits, rebalances, handoff traffic, per-class CLUSTER
@@ -374,8 +375,6 @@ class ServingRouter:
                 # routable at all — reject before any prefill burns on it
                 req = RequestHandle(next(self._uids), prompt, cls,
                                     int(max_new_tokens), eos_token_id, t0)
-                if not getattr(self._serving_cfg, "attribution", True):
-                    req._ledger = None
                 with self._lock:
                     self.stats.router_sheds[cls.name] += 1
                 self._finalize_external(req, "shed")
@@ -592,8 +591,6 @@ class ServingRouter:
                 f"prefill pool holds {target.engine.allocator.total_blocks}")
         req = RequestHandle(next(self._uids), prompt, cls, max_new_tokens,
                             eos_token_id, arrival_t)
-        if not getattr(self._serving_cfg, "attribution", True):
-            req._ledger = None
         req._router_counted = True     # in _inflight until handoff or final
         with self._lock:
             self._inflight += 1

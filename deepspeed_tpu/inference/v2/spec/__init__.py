@@ -19,8 +19,8 @@ subsystem makes each step pay for up to ``k + 1`` tokens instead:
   the refcounted allocator (``scheduler.rollback_reserved``).
 
 Greedy speculation is exactness-preserving: streams are byte-identical to
-the spec-off pipeline (``serving_bench.py --spec`` gates it), programs live
-on the warmed (bucket, k) grid so speculation adds zero timed compiles, and
+the spec-off pipeline (``tests/unit/test_spec_decode.py`` holds it), programs
+live on the warmed (bucket, k) grid so speculation adds zero timed compiles, and
 ``monitor/serving.SpecDecodeStats`` + ``serve/spec/*`` trace lanes make the
 acceptance economics observable.
 """

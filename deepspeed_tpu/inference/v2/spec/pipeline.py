@@ -21,8 +21,8 @@ HBM stream (the reason decode is slow) over k+1 emitted tokens.
 
 Correctness: greedy speculation is exactness-preserving — the emitted
 stream is BYTE-IDENTICAL to the spec-off pipeline (ragged_model.
-build_verify_step's induction; gated end-to-end by ``serving_bench.py
---spec``). Rejection never touches prefix-cache-shared pages: stale
+build_verify_step's induction). Rejection never touches prefix-cache-shared
+pages: stale
 rejected-token KV sits past the advanced context inside pages the sequence
 owns (ctx-bounded readers never see it; the next write overwrites it), and
 run-end ``scheduler.rollback_reserved`` frees whole reserved-but-unused

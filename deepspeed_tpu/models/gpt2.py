@@ -2,7 +2,7 @@
 
 The reference has no in-repo GPT-2 (it trains HF/Megatron models through the
 engine); this model zoo exists so the framework is runnable end-to-end standalone,
-like the reference's ``tests/unit/simple_model.py`` fixtures but production-shaped.
+like the reference's ``simple_model.py`` test fixtures but production-shaped.
 Design: pre-LN transformer, learned positions, causal attention routed through
 ``deepspeed_tpu.ops.attention`` (jnp today, Pallas flash-attention when available).
 
@@ -57,7 +57,8 @@ class GPT2Config:
 
     @classmethod
     def tiny(cls, **kw):
-        """Test-sized config (fixture-model analog of tests/unit/simple_model.py)."""
+        """Test-sized config (analog of the reference's ``simple_model.py``
+        fixtures)."""
         defaults = dict(vocab_size=256, n_positions=128, n_embd=64, n_layer=2, n_head=4)
         defaults.update(kw)
         return cls(**defaults)

@@ -118,7 +118,8 @@ class LlamaConfig:
 
     @classmethod
     def tiny(cls, **kw):
-        """Fixture-sized config (analog of tests/unit/simple_model.py fixtures)."""
+        """Fixture-sized config (analog of the reference's ``simple_model.py``
+        fixtures)."""
         defaults = dict(vocab_size=256, hidden_size=64, intermediate_size=128,
                         num_hidden_layers=2, num_attention_heads=4,
                         num_key_value_heads=2, max_position_embeddings=128)

@@ -31,7 +31,7 @@ Phase semantics (per step):
   host loop — not the device or the transfer — is eating the pipeline.
 
 ``fetch_bytes`` counts exactly what crossed device->host per step; the
-steady-state bench asserts it equals one int32 row per bucket slot
+decode-pipeline tests assert it equals one int32 row per bucket slot
 (4 * bucket bytes), the invariant that keeps decode transfer-bound work off
 the per-token critical path.
 """
@@ -58,9 +58,10 @@ class PipelineStats:
     bubble_ms: float = 0.0
     fetch_bytes: int = 0
     last_fetch_bytes: int = 0        # bytes of the most recent per-step drain
-    #: per-step wall times (ms) of the MOST RECENT run only — the bench reads
-    #: p50/p99 per-token latency from here; DecodePipeline.run clears it at
-    #: run start (the scalar fields above stay cumulative)
+    #: per-step wall times (ms) of the MOST RECENT run only — p50/p99
+    #: per-token latency on the host's clock is read from here;
+    #: DecodePipeline.run clears it at run start (the scalar fields above stay
+    #: cumulative)
     step_wall_ms: List[float] = field(default_factory=list)
 
     def record_step(self, dispatch_s: float, drain_s: float, build_s: float,
@@ -433,7 +434,7 @@ class FrontendStats:
                          float(np.percentile(xs, 95)), step),
                     ]
         # serve/slo/*: SLO-miss attribution rollup (snapshot the dicts —
-        # the engine thread inserts first-seen phase keys while a bench
+        # the engine thread inserts first-seen phase keys while another
         # thread reads)
         slo_base = "serve/slo" if self.replica is None \
             else f"serve/slo/{self.replica}"
@@ -518,7 +519,7 @@ class HealthStats:
     def events(self, step: int = 0) -> List[Event]:
         """``serve/health/*`` monitor events (docs/SERVING.md glossary).
         Snapshots the dicts/deque first: a monitor backend reads on a
-        bench/user thread while the health thread inserts first-seen
+        user thread while the health thread inserts first-seen
         transition keys — iterating the live dict would race."""
         import numpy as np
         transitions = dict(self.transitions)

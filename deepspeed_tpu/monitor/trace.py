@@ -28,7 +28,7 @@ Design constraints (the regimes PRs 3-6 gated must survive tracing ON):
   the final steps' timeline, dumped to ``trace_crash.json`` by the
   fault-injection kill/raise hooks and fatal engine teardown (and the normal
   rings export from an atexit hook), so a preempted or wedged run leaves a
-  readable timeline (pairs with ``train_bench.py --preempt``).
+  readable timeline.
 - **true no-op when disabled**: ``add()`` is a two-instruction early return
   and ``span()`` hands back a shared no-op context manager; hot-path call
   sites additionally guard on ``tracer.enabled`` so disabled runs don't even
@@ -335,8 +335,8 @@ class Tracer:
         lock-free appends. A thread's FIRST record otherwise acquires the
         registry lock at whatever call site it happens to land on — callers
         that record under their own locks use this to keep the registry
-        acquisition outside them (lock-order hygiene; the locksan bench gate
-        demands every observed acquisition order be statically explained)."""
+        acquisition outside them (lock-order hygiene; the locksan scenario tests
+        demand every observed acquisition order be statically explained)."""
         if self.enabled:
             self._ring()
 
@@ -487,7 +487,7 @@ class Tracer:
 
     def iter_records(self) -> Iterator[tuple]:
         """Snapshot every retained raw record ``(kind, name, t0, t1, lane,
-        args)`` across all rings — benches and tests assert on request flow
+        args)`` across all rings — tests assert on request flow
         chains (spans sharing a ``trace_id`` arg) without exporting."""
         with self._reg_lock:
             rings = list(self._rings)
@@ -742,7 +742,7 @@ tracer = Tracer()
 def install_from_env() -> Tracer:
     """Arm the tracer from ``$DSTPU_TRACE`` (a directory; no-op when unset).
     Called by ``deepspeed_tpu.initialize`` and the v2 inference engine so
-    subprocess benches trace without touching user code; idempotent — an
+    subprocess workers trace without touching user code; idempotent — an
     already-configured tracer wins."""
     if tracer.enabled:
         return tracer

@@ -39,8 +39,8 @@ class _HostKernelBase:
     @property
     def backend(self) -> str:
         """Which implementation actually runs: 'openmp' (C++ ds_native) or
-        'numpy' (fallback) — recorded in the bench artifact so offload
-        numbers are attributable."""
+        'numpy' (fallback) — so an offload number can say which kernel
+        made it."""
         return "openmp" if self._lib is not None else "numpy"
 
 

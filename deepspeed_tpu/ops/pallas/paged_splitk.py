@@ -30,9 +30,9 @@ Two implementations, one ladder:
 - **XLA fallback** (``paged_decode_attention_xla``): one ``lax.scan`` over
   a sequence's page chunks with the split axis BATCHED — split=1 runs NC
   sequential scan steps (the chunk-serial anatomy), split=S runs ceil(NC/S)
-  steps with S-fold fatter gathers/dots per step. The sequential-depth
-  reduction is real on any backend (measured on the CPU bench box —
-  ``serving_bench.py --long-context``), and this path carries the cases the
+  steps with S-fold fatter gathers/dots per step. The sequential depth
+  falls on any backend (what that is worth on the chip is not measured:
+  ``PERF.md`` §7), and this path carries the cases the
   manual-DMA kernel cannot (small head dims, per-sequence traced window
   starts).
 

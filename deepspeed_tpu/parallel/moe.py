@@ -163,7 +163,7 @@ def dropless_moe(tokens: jax.Array, gate_logits: jax.Array, k: int,
     if E == 1 and k == 1:
         # degenerate single-expert routing: every token goes to expert 0
         # with weight 1 — skip the sort/gather/scatter machinery entirely
-        # (this also makes the bench's dense_equiv leg a TRUE dense
+        # (this also makes a one-expert, top-1 run a TRUE dense
         # attention+FFN ceiling rather than dispatch-included)
         out = grouped_ffn(tokens, jnp.asarray([N], jnp.int32))
         return out, jnp.float32(1.0)

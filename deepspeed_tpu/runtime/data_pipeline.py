@@ -1,12 +1,12 @@
 """Prefetch-to-device training input pipeline.
 
-Why this exists: BENCH_r07 rebuilt the *serving* decode path as an async
-pipeline, but the training hot path still paid the same host-bound tax per
-step — ``DeepSpeedTPUDataLoader.__iter__`` collates batches item-by-item on
-the caller's thread, ``train_batch`` blocks on a synchronous
-``_shard_global_batch`` device_put, and the metric fetch serialised every
-step. This module is the t5x-style answer (prefetch-to-device iterators) for
-the DeepSpeed-shaped engine: a producer thread pulls host batches from any
+Why this exists: ``inference/v2/pipeline.py`` made the *serving* decode
+path an async pipeline, but the training hot path still paid the same
+host-bound tax per step — ``DeepSpeedTPUDataLoader.__iter__`` collates
+batches item-by-item on the caller's thread, ``train_batch`` blocks on a
+synchronous ``_shard_global_batch`` device_put, and the metric fetch
+serialised every step. This module is the t5x-style answer
+(prefetch-to-device iterators) for the DeepSpeed-shaped engine: a producer thread pulls host batches from any
 loader, applies the host-side staging work (curriculum-seqlen truncation,
 progressive-layer-drop injection, the [tb] -> [gas, mb*dp] reshape and
 sharded ``device_put``) OFF the critical path, and parks the next N
@@ -20,7 +20,8 @@ The staging helpers (`as_host_tree`, `truncate_to_seqlen`, `inject_pld`) are
 module functions so the engine's synchronous fallback path (``prefetch=0``,
 or an explicit ``train_batch(batch)``) runs the EXACT same code the producer
 thread runs — the pipelined and sync loops must produce bit-identical loss
-streams (gated by ``benchmarks/train_bench.py``).
+streams
+(``tests/unit/test_data_pipeline.py::test_train_steps_pipelined_matches_sync_loop``).
 
 This module is deliberately NOT a jaxlint JL007 hot-path module: host-side
 ``np.asarray`` conversions live here so ``runtime/engine.py`` (which IS

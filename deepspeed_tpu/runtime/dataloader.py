@@ -7,8 +7,8 @@ train_batch_size) as numpy trees; the engine shards them over (data, fsdp) at
 device_put. Per-host input pipelines (one feeder per process) arrive with the
 multi-host launcher.
 
-Determinism contract (pinned by tests/unit/test_data_pipeline.py and relied
-on by ``benchmarks/train_bench.py``'s loss-equality gates): the shuffle order
+Determinism contract (pinned by tests/unit/test_data_pipeline.py, whose
+loss-equality tests rely on it): the shuffle order
 is a pure function of ``(seed, epoch)`` — two loaders with the same seed and
 epoch yield identical batch streams, and ``RepeatingLoader``'s epoch
 auto-bump reshuffles reproducibly. The async step loop builds on this:

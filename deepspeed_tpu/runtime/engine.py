@@ -683,7 +683,8 @@ class DeepSpeedTPUEngine:
         dedicated worker thread, with the NVMe swapper double-buffering
         underneath. Serial (``overlap_step: false`` — the pre-PR baseline):
         one blocking drain of all groups, a serial kernel pass, uploads built
-        at the end. Identical math either way (the bench gates on it)."""
+        at the end. Identical math either way
+        (tests/unit/test_offload.py holds the byte equality)."""
         perf = time.perf_counter
         lr = float(fetch_to_host(metrics["lr"]))
         meta_groups = self._offload_group_meta
@@ -1219,8 +1220,8 @@ class DeepSpeedTPUEngine:
         one runs. ``wall_clock_breakdown`` restores the fully synchronous
         reference loop."""
         from deepspeed_tpu.runtime.data_pipeline import StagedBatch
-        # mid-run preemption point: the --preempt bench kills here, modelling
-        # a spot-VM SIGTERM landing between (or inside) steps
+        # mid-run preemption point: a ``step.kill`` fault plan kills here,
+        # modelling a spot-VM SIGTERM landing between (or inside) steps
         fault_injection.maybe_fail("step.kill")
         perf = time.perf_counter
         t0 = perf()
@@ -1759,7 +1760,7 @@ class DeepSpeedTPUEngine:
         the executable-cache sizes of the fused/micro/apply/eval steps. A
         steady-state loop whose batch shapes are stable must never increment
         this after warmup (curriculum buckets each cost exactly one); the
-        train bench gates on it."""
+        training tests assert it."""
         n = 0
         for fn in (self._fused_step, self._micro_step, self._apply_step,
                    self._eval_step, getattr(self, "_offload_merge", None)):

@@ -359,7 +359,7 @@ class HostOffloadOptimizer:
         if not self.nvme:
             # frozen COPIES, not the live arrays: host Adam mutates master/
             # moments in place, and callers hand these leaves to background
-            # checkpoint writers (or bench snapshot/restore) that must not
+            # checkpoint writers (or a snapshot/restore) that must not
             # observe the next step's values
             return ({k: np.array(v, np.float32) for k, v in self.master.items()},
                     {sk: {k: np.array(v, np.float32)

@@ -42,7 +42,8 @@ implicit path bit-for-bit untouched.
 Scheduling changes placement, never math: gather bucketing is pure data
 movement and the transpose reduce-scatter sums the same partials in the same
 participant order, so per-step loss streams are byte-identical across depth
-0/1/2 and any bucket size (the train_bench ``--zero3-overlap`` gate).
+0/1/2 and any bucket size
+(``tests/unit/test_zero3_prefetch.py::test_depth_changes_placement_never_math``).
 
 Observability: the schedule's collectives are DEVICE work, so they are named
 and read from the device trace, never timed with host stamps. Each wave's
@@ -137,7 +138,7 @@ class Zero3Plan:
     allgather_bucket_size: int
     reduce_bucket_size: int
     # leaves NOT gathered (replicated / persistence-threshold / tp-only):
-    # schedule leaves them alone; recorded for the residency/bench story.
+    # schedule leaves them alone; recorded for the residency story.
     persistent_bytes: int
 
     @property

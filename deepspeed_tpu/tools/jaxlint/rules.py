@@ -560,7 +560,8 @@ class HotPathHostFetch(Rule):
     fetches one int32 token row; a stray ``np.asarray(logits)`` / ``.item()``
     / ``jax.device_get(...)`` in that path silently re-serialises the host on
     the device (and, through a remote runtime, re-adds an RTT per token) —
-    the exact regression class BENCH_r06 measured. Inert unless the config
+    the regression class ``inference/v2/pipeline.py`` was built to remove.
+    Inert unless the config
     lists ``hot_paths`` substrings (``.jaxlint.json``), so only modules that
     opted into hot-path discipline are policed; the intentional drain carries
     an inline ``# jaxlint: disable=JL007``.

@@ -701,7 +701,7 @@ class Program:
 def static_lock_graph(paths: Iterable[str], config=None) \
         -> Set[Tuple[str, str]]:
     """The static lock-acquisition edge set for the given tree — what the
-    bench legs compare locksan's observed edges against (static must be a
+    scenario tests compare locksan's observed edges against (static must be a
     superset)."""
     from deepspeed_tpu.tools.threadlint.config import (ThreadLintConfig,
                                                        find_config)

@@ -1,7 +1,7 @@
 """Where this program keeps JAX's persistent compilation cache.
 
 One rule, one helper, called by the test harness, ``chip_smoke.py``, the
-benchmarks and the serving engine:
+benchmark (``chipbench/``) and the serving engine:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads that directory from
   the environment, and this code sets no other — no sub-directory, no

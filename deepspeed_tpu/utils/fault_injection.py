@@ -19,10 +19,10 @@ deterministically, so a failing run replays bit-for-bit:
   any thread, including a checkpoint writer thread mid-write).
 
 Nothing is installed by default and ``maybe_fail`` is a two-instruction
-no-op when inactive, so production hot paths pay nothing. Benches and the
-kill-and-resume leg of ``train_bench.py --preempt`` install a plan in a
-subprocess via the ``DSTPU_FAULTS`` env var (see :func:`parse_plan` for the
-grammar), e.g.::
+no-op when inactive, so production hot paths pay nothing. A subprocess is
+given its plan through the ``DSTPU_FAULTS`` env var (see :func:`parse_plan`
+for the grammar; ``tests/unit/test_rolling_checkpoint.py`` kills and resumes
+a run that way), e.g.::
 
     DSTPU_FAULTS="step.kill:at=8:action=kill"
     DSTPU_FAULTS="ckpt.writer:at=3:action=kill;aio.read:every=5:action=errno:errno=5"
@@ -41,8 +41,9 @@ Known sites (grep for ``maybe_fail``/``maybe_rc`` to audit):
 ``agent.run``       ``DSElasticAgent`` before each (re)start attempt
 ==================  =========================================================
 
-Serving-side sites (ISSUE 12 — the chaos surface ``serving_bench.py
---chaos`` replays against; docs/SERVING.md "Failure semantics"):
+Serving-side sites (ISSUE 12 — the chaos surface
+``tests/unit/test_serving_health.py`` replays against; docs/SERVING.md
+"Failure semantics"):
 
 ========================  ===================================================
 ``serve.engine_step``     top of ``ServingFrontend.step()`` — ``raise``
@@ -205,7 +206,7 @@ def parse_plan(plan: str, seed: int = 0) -> FaultInjector:
 
 def install_from_env() -> Optional[FaultInjector]:
     """Install a plan from ``DSTPU_FAULTS`` (no-op when unset). Called by
-    ``deepspeed_tpu.initialize`` so subprocess benches arm faults without
+    ``deepspeed_tpu.initialize`` so subprocess workers arm faults without
     touching user code; idempotent — an already-installed injector wins."""
     if _active is not None:
         return _active

@@ -5,7 +5,7 @@ through ``utils/threads.make_lock``/``make_rlock`` becomes a
 :class:`SanLock` proxy that records, per thread, the stack of held lock
 NAMES and grows a global acquisition graph: taking ``B`` while holding
 ``A`` adds the edge ``A -> B``. At ``report()`` time (engine destroy, the
-crash flight-recorder dump, or the bench legs' final gate) the graph is
+crash flight-recorder dump, or a test's final assertion) the graph is
 checked for cycles — a cycle is a potential deadlock two threads can
 interleave into even if this run never did.
 
@@ -14,13 +14,14 @@ Two more signals ride along:
 - **held-lock blocking**: the policed ``fetch_to_host`` drain points (and
   anything else that calls :func:`note_blocking`) record when a blocking
   call runs with locks held — the runtime twin of threadlint rule TL002.
-- **static cross-check**: ``scripts/bench_smoke.sh`` runs the chaos and
-  router smoke legs under the sanitizer and asserts the OBSERVED edges are
-  a subset of the static lock graph threadlint computed — an observed edge
-  the analyzer cannot see means the model (or an annotation) is wrong.
+- **static cross-check**: ``tests/unit/test_serving_health.py`` runs the
+  failover/rejoin, disaggregated and cache-aware cluster scenarios under the
+  sanitizer and asserts the OBSERVED edges are a subset of the static lock
+  graph threadlint computed — an observed edge the analyzer cannot see
+  means the model (or an annotation) is wrong.
 
 Everything here is process-global on purpose: lock ordering is a
-whole-process property. ``reset()`` clears the tables between bench legs.
+whole-process property. ``reset()`` clears the tables between scenarios.
 """
 
 from __future__ import annotations
@@ -50,7 +51,7 @@ def enabled() -> bool:
 
 
 def arm() -> None:
-    """Force the sanitizer on (tests/benches); clears recorded state."""
+    """Force the sanitizer on (tests); clears recorded state."""
     global _armed
     _armed = True
     reset()
@@ -240,5 +241,5 @@ def report() -> dict:
 
 def check_static(static_edges: Set[Tuple[str, str]]) -> Set[Tuple[str, str]]:
     """Observed edges the static analyzer did NOT predict (empty = the
-    static graph is a superset, the bench gate's requirement)."""
+    static graph is a superset, which the scenario tests require)."""
     return edges() - set(static_edges)

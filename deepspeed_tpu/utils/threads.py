@@ -14,7 +14,7 @@ through the factories here with a dotted name::
 - Under ``DSTPU_LOCKSAN=1`` it returns an order-recording
   :class:`~deepspeed_tpu.utils.locksan.SanLock` proxy carrying the same
   name, so the runtime acquisition graph and the static one share a
-  namespace and the bench can assert ``static edges >= observed edges``.
+  namespace and a test can assert ``static edges >= observed edges``.
 
 Names are lockdep-style CLASSES, not instances: every per-key lock minted by
 ``utils/caching.py`` shares one name, exactly how lockdep groups locks by

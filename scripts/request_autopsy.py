@@ -17,7 +17,7 @@ attribution")::
 With no ``--trace-id``, the WORST chain (largest submit-to-last-hop
 window) is picked — on an SLO-investigation that is usually the request
 you want. ``--list`` prints every chain's window instead. ``--smoke``
-(wired into ``scripts/bench_smoke.sh``) asserts at least one multi-hop
+(run by ``tests/unit/test_trace.py``) asserts at least one multi-hop
 chain exists in the traces and renders the worst one; exit 1 otherwise.
 
 Timestamps are clock-aligned across files via the exporters' ``clockSync``

@@ -37,8 +37,8 @@ crosses lanes (router placement -> prefill -> decode stints / migration).
 ``--expect-crash`` asserts a parseable ``trace_crash.json`` (the flight
 recorder's dump) exists in the directory and contains at least one span.
 
-Exit 0 on success; 1 with a per-file error listing otherwise. Invoked
-non-fatally from ``scripts/bench_smoke.sh`` after the traced bench legs
+Exit 0 on success; 1 with a per-file error listing otherwise. The serving,
+router and health tests run it over the timelines they emit
 (docs/OBSERVABILITY.md).
 """
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Clock-align and merge per-process trace files into one timeline.
 
-Subprocess bench workers (and any multi-process run) each export their own
+Subprocess workers (and any multi-process run) each export their own
 ``trace_<pid>.json`` with timestamps from their OWN ``time.perf_counter()``
 epoch — loading two of them into Perfetto shows two unrelated time axes.
 Each exporter embeds a ``clockSync`` anchor (one simultaneous

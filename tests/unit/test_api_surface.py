@@ -6,19 +6,37 @@ an import found in DeepSpeed tutorials/user code.
 """
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
 
 
-def test_root_names():
-    import deepspeed_tpu as ds
-    for name in ("initialize", "init_inference", "add_config_arguments",
-                 "zero", "pipe", "moe", "module_inject", "checkpoint",
-                 "monitor", "profiling", "runtime", "accelerator", "sequence",
-                 "DeepSpeedEngine", "PipelineModule", "OnDevice",
-                 "init_distributed", "checkpointing", "comm", "ops", "utils"):
-        assert hasattr(ds, name), name
+@pytest.mark.parametrize("module, names, exact", [
+    ("deepspeed_tpu",
+     ("initialize", "init_inference", "add_config_arguments",
+      "zero", "pipe", "moe", "module_inject", "checkpoint",
+      "monitor", "profiling", "runtime", "accelerator", "sequence",
+      "DeepSpeedEngine", "PipelineModule", "OnDevice",
+      "init_distributed", "checkpointing", "comm", "ops", "utils"), False),
+    # the serving package exports these and nothing else: a load generator
+    # is the benchmark's (chipbench/traffic/), not the library's
+    ("deepspeed_tpu.inference.v2.serving",
+     ("AdmissionController", "CostModel", "PrefillWorker", "Replica",
+      "ServingCluster", "RequestHandle", "ServingFrontend", "DOWN",
+      "DRAINING", "HEALTHY", "REJOINING", "SUSPECT", "HealthMonitor",
+      "KVOffloadManager", "ClusterPrefixIndex", "ServingRouter"), True),
+], ids=["root", "serving"])
+def test_exported_names(module, names, exact):
+    import importlib
+    import types
+    mod = importlib.import_module(module)
+    for name in names:
+        assert hasattr(mod, name), name
+    if exact:
+        public = {n for n, v in vars(mod).items() if not n.startswith("_")
+                  and not isinstance(v, types.ModuleType)}
+        assert public == set(names)
 
 
 def test_reference_import_lines():

@@ -174,6 +174,7 @@ def test_swap_zero_compiles_byte_identical_generation(tmp_path):
 
     serve.generate([prompt], max_new_tokens=8)        # warm the ladders
     c0, b0 = serve.compiles, bridge.compiles
+    kv_free0 = serve.allocator.free_blocks
 
     for i in range(3):                                # >=3 consecutive swaps
         eng.train_batch(_batch(8, seed=200 + i))
@@ -196,6 +197,7 @@ def test_swap_zero_compiles_byte_identical_generation(tmp_path):
                 "kv_cache": {"block_size": 16, "num_blocks": 16}})
     assert out == fresh.generate([prompt], max_new_tokens=8)
     assert _leaves_byte_equal(serve.weights, fresh.weights)
+    assert serve.allocator.free_blocks == kv_free0     # KV pool at baseline
 
 
 def test_swap_refused_with_live_sequences_and_bad_trees():
@@ -331,7 +333,7 @@ def test_frontend_swap_quiesces_inflight_decode():
 
 
 # --------------------------------------------------------------------------- #
-# LoRA swap-pool drain (satellite: the serving_bench baseline flake)
+# LoRA swap-pool drain
 # --------------------------------------------------------------------------- #
 
 def test_lora_drain_swap_settles_pool_byte_safely():
@@ -389,11 +391,29 @@ def test_rollout_loop_interleaves_train_and_generate():
     loop = RolloutLoop(eng, fe, prompt_fn=prompt_fn, collate_fn=collate,
                        steps_per_round=1, max_new_tokens=4,
                        request_timeout=60.0)
+    rollouts, generate = [], loop._generate
+
+    def recording_generate(rnd):
+        rollouts.append(generate(rnd))
+        return rollouts[-1]
+
+    loop._generate = recording_generate
     try:
         losses = loop.run(3)
     finally:
         loop.close()
         fe.close()
+    # the same policy updates with a serving engine rebuilt from the host
+    # copy of the weights at every update: the rollouts agree token for token
+    eng2 = _train_engine(model, params, steps=0)
+    for rnd in range(3):
+        fresh = _serve_engine(model, jax.tree_util.tree_map(
+            np.asarray, eng2.rollout_source_params()))
+        prompts = prompt_fn(rnd)
+        full = fresh.generate(prompts, max_new_tokens=4)
+        naive = [(p, list(f[len(p):])) for p, f in zip(prompts, full)]
+        assert naive == [(p, list(t)) for p, t in rollouts[rnd]]
+        eng2.train_batch(collate(naive))
     assert len(losses) == 3 and all(np.isfinite(l).all() for l in losses)
     assert eng.global_steps == 3
     assert serve.weight_version == 4                   # align + 3 rounds

@@ -407,6 +407,9 @@ def test_train_steps_pipelined_matches_sync_loop():
     assert e_pipe.global_steps == 6
     assert e_pipe._prefetch_loader is not None
     assert e_pipe.train_stats.prefetched_steps >= 5  # first may stage inline
+    c0 = e_pipe.compiles             # warm: staging never builds a program
+    e_pipe.train_steps(3)
+    assert e_pipe.compiles == c0
     e_pipe.destroy()
     assert e_pipe._prefetch_loader is None
     e_sync.destroy()

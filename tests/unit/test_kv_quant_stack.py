@@ -62,8 +62,9 @@ def _engine(model, params, kvq=True, prefix_cache=False, spec_k=0,
 
 def _force_paged(engine):
     """Hold the kernel path constant (packed-vs-paged prefill variance is
-    per-path, pre-existing, and orthogonal — see serving_bench
-    run_shared_prefix): every pass through the paged forward."""
+    per-path, pre-existing, and orthogonal — a cache hit always continues
+    through the paged path, a cold prompt takes the packed one): every pass
+    through the paged forward."""
     orig = engine.scheduler.schedule_pass
 
     def no_fast_path():
@@ -273,6 +274,55 @@ def test_int8_offload_restore_stream_identical(eight_devices):
     eng.flush([11])
     assert head + [int(t) for t in tail_out[0]] == ref
     assert eng.free_blocks == free0
+
+
+def test_int8_frontend_preempt_cycle_compiles_nothing(eight_devices):
+    """The same round trip driven by the product's own loop on a warmed
+    engine: two batch requests decode on a pool too small for a third; an
+    interactive arrival makes admission offload one of them (packed
+    value+scale pages) and restore it later. All three streams equal direct
+    plain-pipeline runs, nothing compiles after warm-up (the page-op grid is
+    part of it), and pool and host buffers end where they began."""
+    model, params = _params()
+    eng = _engine(model, params, kvq=True, num_blocks=8,
+                  compile={"warmup": True})
+    _force_paged(eng)
+    try:
+        rng = np.random.RandomState(7)
+        free0 = eng.free_blocks
+        fe = eng.serving_frontend(config={"classes": [
+            {"name": "interactive", "priority": 2,
+             "ttft_slo_ms": 1e6, "tbt_slo_ms": 1e6},
+            {"name": "batch", "priority": 0,
+             "ttft_slo_ms": 1e6, "tbt_slo_ms": 1e6}],
+            "decode_slice": 4, "spec": False, "preemption": "offload"})
+        c0 = eng.compiles
+        lows = [fe.submit(rng.randint(0, 256, size=(150,)).astype(np.int32),
+                          priority="batch", max_new_tokens=60)
+                for _ in range(2)]
+        for _ in range(80):              # a victim must be DECODING
+            fe.step()
+            if any(len(h.tokens) >= 4 for h in lows):
+                break
+        hi = fe.submit(rng.randint(0, 256, size=(128,)).astype(np.int32),
+                       priority="interactive", max_new_tokens=8)
+        hs = lows + [hi]
+        for _ in range(900):
+            if all(h.finished for h in hs):
+                break
+            fe.step()
+        assert all(h.status == "finished" for h in hs)
+        assert fe.stats.preemptions >= 1 and fe.stats.restores >= 1
+        assert fe.stats.offload_bytes > 0
+        assert eng.compiles == c0
+        fe.close()
+        assert fe.offload.pool.outstanding == 0
+        for i, h in enumerate(hs):
+            assert _serve(eng, 300 + i, h.prompt, len(h.tokens)) == h.tokens
+        assert eng.compiles == c0
+        assert eng.free_blocks == free0
+    finally:
+        _unforce_paged(eng)
 
 
 def test_int8_cross_engine_handoff_and_salvage(eight_devices):

@@ -333,6 +333,53 @@ def test_frontend_lora_streams_and_tenant_classes(warm_engine):
     fe.close()
 
 
+def test_frontend_tenant_churn_past_pool_capacity(warm_engine, tmp_path):
+    """More registered adapters (9 pages) than the pool holds (6): a mixed
+    queue makes admission fault cold adapters in and evict idle ones while
+    one ragged batch mixes tenants. Every stream equals its per-adapter
+    direct run, nothing compiles, the faults leave ``serve/lora`` spans, and
+    KV pool, adapter pool and pinned buffers end at their baselines."""
+    from deepspeed_tpu.monitor.trace import tracer
+    e = warm_engine
+    load_lora_adapter(e, "t-c", _adapter_state(e, 4, seed=9))
+    tracer.reset()
+    tracer.configure(trace_dir=str(tmp_path), enabled=True)
+    try:
+        rng = np.random.RandomState(5)
+        binds = ["t-a", "t-c", "t-b", None, "t-c", "t-a", "t-b", "t-c"]
+        prompts = [_prompt(rng, int(rng.randint(7, 18))) for _ in binds]
+        N = 6
+        refs = [_serve_direct(e, 970 + i, p, N, adapter=a)
+                for i, (p, a) in enumerate(zip(prompts, binds))]
+        kv_free0 = e.allocator.free_blocks
+        c0 = e.compiles
+        st = e.lora.stats.adapters
+        faults0 = sum(c.faults for c in st.values())
+        evict0 = sum(c.evictions for c in st.values())
+        fe = e.serving_frontend(config=_serving_cfg())
+        hs = [fe.submit(p, max_new_tokens=N, adapter=a)
+              for p, a in zip(prompts, binds)]
+        assert _step_until(fe, lambda: all(h.finished for h in hs), n=2000)
+        for h, ref in zip(hs, refs):
+            assert h.status == "finished" and h.result(5) == ref
+        fe.close()
+        assert e.compiles == c0
+        assert sum(c.faults for c in st.values()) > faults0
+        assert sum(c.evictions for c in st.values()) > evict0
+        assert "serve/lora/fault" in tracer.summary()
+        # an adapter that sits evicted holds pinned buffers until settled
+        e.lora.drain_swap()
+        reg = e.lora
+        resident = sum(reg.rank(n) for n in reg.names if reg.is_resident(n))
+        assert reg.pool.free_pages + resident == reg.pool.num_pages
+        assert all(reg.refcount(n) == 0 for n in reg.names)
+        assert reg.swap.outstanding == 0
+        assert e.allocator.free_blocks == kv_free0
+    finally:
+        tracer.reset()
+        e.lora.unregister("t-c")
+
+
 def test_frontend_lora_refusals(warm_engine, model_params):
     e = warm_engine
     fe = e.serving_frontend(config=_serving_cfg())

@@ -375,6 +375,9 @@ def test_overlap_step_matches_serial_engine_bytes(tmp_path):
         np.testing.assert_array_equal(m_s[k], m_p[k])
         np.testing.assert_array_equal(m_s[k], m_n[k])
     for e in (eng_s, eng_p, eng_n):
+        c0 = e.compiles              # warm: neither orchestration compiles
+        e.train_batch(batches[0])
+        assert e.compiles == c0
         e.destroy()
 
 

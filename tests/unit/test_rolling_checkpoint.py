@@ -3,7 +3,10 @@ backpressure, retention, shutdown flush, and resume-from-newest-complete.
 """
 
 import csv
+import json
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -356,3 +359,180 @@ def test_failed_enqueue_hands_the_backpressure_permit_back(tmp_path):
     rc.save()
     rc.flush()
     eng.destroy()
+
+
+# --------------------------------------------------------------------------- #
+# a real death and a resume onto another device count (docs/ELASTICITY.md):
+# subprocess workers, so the kill (os._exit through DSTPU_FAULTS) runs no
+# atexit and no finally
+# --------------------------------------------------------------------------- #
+
+#: the final global batch does not depend on the world size, so a resume on
+#: M != N devices trains on the same global batch at every step
+_ELASTIC = {"enabled": True, "max_train_batch_size": 32,
+            "micro_batch_sizes": [4, 8], "min_gpus": 1, "max_gpus": 8,
+            "version": 0.2}
+_EVERY, _KILL_STEP, _TOTAL, _PREFIX = 3, 8, 12, "rolling_step"
+_WORLD, _RESUME_WORLD = 4, 2
+
+
+def _elastic_worker(save_dir, out, total_steps, resume_universal="",
+                    load_dir="", resume_tag=""):
+    """One training run in THIS process: data parallel over however many
+    devices XLA_FLAGS forced, rolling checkpoints on a cadence, optionally
+    resumed from a universal checkpoint (the other-world path) or a regular
+    tag (the verified-load control). Writes a JSON report to ``out``."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.checkpoint.universal import load_universal_into_engine
+    from deepspeed_tpu.elasticity import compute_elastic_config
+    from deepspeed_tpu.utils.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
+    world = jax.device_count()
+    final_batch, _valid, micro = compute_elastic_config(
+        {"elasticity": _ELASTIC}, world_size=world, return_microbatch=True)
+
+    def model(params, b):
+        h = jnp.tanh(jnp.mean(b["x"], axis=1) @ params["w1"])
+        return jnp.mean((h @ params["w2"] - b["y"]) ** 2)
+
+    rng = np.random.default_rng(0)
+    params = {"w1": rng.standard_normal((32, 16)).astype(np.float32) * 0.05,
+              "w2": rng.standard_normal((16, 8)).astype(np.float32) * 0.05}
+    cfg = {"train_batch_size": final_batch,
+           "train_micro_batch_size_per_gpu": micro,
+           "mesh": {"data": -1}, "steps_per_print": 0,
+           "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+           "checkpoint": {"engine": "async", "writers": 2,
+                          "verify_load": True,
+                          "rolling": {"every_n_steps": _EVERY,
+                                      "save_dir": save_dir,
+                                      "keep_last": 8, "max_pending": 2}}}
+    engine, *_ = deepspeed_tpu.initialize(model=model, model_parameters=params,
+                                          config=cfg)
+    if resume_universal:
+        load_universal_into_engine(engine, resume_universal)
+    elif resume_tag:
+        engine.load_checkpoint(load_dir, tag=resume_tag, verify=True)
+    start = engine.global_steps
+
+    def batch(step):             # keyed by the step alone: every world size
+        g = np.random.default_rng(10_000 + step)   # and resume sees the same
+        return {"x": g.standard_normal((final_batch, 4, 32)).astype(np.float32),
+                "y": g.standard_normal((final_batch, 8)).astype(np.float32)}
+
+    losses, warm = {}, None
+    for step in range(start, total_steps):
+        losses[str(step + 1)] = float(engine.train_batch(batch(step)))
+        if step == start:        # the first (re)started step may compile
+            warm = engine.compiles
+    report = {"world": world, "global_batch": final_batch,
+              "start_step": start, "losses": losses,
+              "compiles_after_warmup":
+                  0 if warm is None else engine.compiles - warm}
+    engine.destroy()             # flushes rolling commits, closes the writers
+    with open(out, "w") as f:
+        json.dump(report, f)
+
+
+def _spawn_elastic_worker(devices, save_dir, out, faults="", **resume):
+    env = dict(os.environ)
+    env.pop("DSTPU_FAULTS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    if faults:
+        env["DSTPU_FAULTS"] = faults
+        env["DSTPU_TRACE"] = str(save_dir) + "_trace"
+    args = dict(save_dir=str(save_dir), out=str(out), total_steps=_TOTAL,
+                **{k: str(v) for k, v in resume.items()})
+    p = subprocess.run([sys.executable, os.path.abspath(__file__),
+                        json.dumps(args)], env=env, capture_output=True,
+                       text=True, timeout=600)
+    report = None
+    if os.path.exists(out):
+        with open(out) as f:
+            report = json.load(f)
+    return p, report
+
+
+@pytest.fixture(scope="module")
+def uninterrupted_run(tmp_path_factory):
+    """The whole run at the original world size, never killed."""
+    td = tmp_path_factory.mktemp("elastic_ref")
+    p, report = _spawn_elastic_worker(_WORLD, td / "ck", td / "ref.json")
+    assert p.returncode == 0, p.stderr[-2000:]
+    return report
+
+
+@pytest.mark.parametrize("death", ["between_steps", "inside_checkpoint_write"])
+def test_killed_run_resumes_on_another_device_count(tmp_path, death,
+                                                    uninterrupted_run):
+    """A run on 4 devices dies (a) between steps, at a step that is not a
+    cadence point, or (b) inside a rolling tag's file write; the newest
+    COMPLETE tag — for (b) the one before the torn tag, which stays on
+    disk and is detected — resumes on 2 devices through the universal
+    format. The resumed loss stream is byte-identical to a verified load of
+    the same tag on 2 devices, stays within float tolerance of the
+    uninterrupted 4-device run (reduction order differs across device
+    counts), the global batch is the same on both worlds, and nothing
+    compiles after the first resumed step. The killed process leaves the
+    tracer's flight-recorder dump behind."""
+    from deepspeed_tpu.checkpoint.universal import ds_to_universal
+    from deepspeed_tpu.utils.fault_injection import KILL_EXIT_CODE
+    # the stall paces every step 250 ms (the kill is listed first and wins at
+    # its hit): these tiny steps outrun the background committer, and a kill
+    # before the previous cadence tag committed leaves nothing to resume
+    pace = "step.kill:every=1:action=stall:delay_s=0.25"
+    plan = {"between_steps": f"step.kill:at={_KILL_STEP}:action=kill;{pace}",
+            # hit 3 = the second cadence save's first file
+            "inside_checkpoint_write":
+                f"ckpt.writer:at=3:action=kill;{pace}"}[death]
+    save_dir = tmp_path / "killed"
+    p, report = _spawn_elastic_worker(_WORLD, save_dir, tmp_path / "a.json",
+                                      faults=plan)
+    assert p.returncode == KILL_EXIT_CODE, p.stderr[-2000:]
+    assert report is None                      # it never reached its end
+    # os._exit runs no atexit: the flight recorder's dump, written before
+    # the kill, is the only timeline such a death leaves
+    with open(tmp_path / "killed_trace" / "trace_crash.json") as f:
+        dump = json.load(f)
+    assert any(ev.get("name", "").startswith("train/")
+               for ev in dump["traceEvents"])
+
+    tag = find_resume_tag(str(save_dir))
+    assert tag is not None and tag.startswith(_PREFIX)
+    assert tag_problem(str(save_dir), tag) is None
+    k = int(tag[len(_PREFIX):])
+    assert 0 < k < _KILL_STEP and k % _EVERY == 0
+    if death == "inside_checkpoint_write":
+        torn = f"{_PREFIX}{2 * _EVERY}"
+        assert (save_dir / torn).is_dir()      # still on disk, not chosen
+        assert tag_problem(str(save_dir), torn) is not None
+        assert k == _EVERY
+
+    uni = ds_to_universal(str(save_dir), str(tmp_path / "uni"), tag=tag)
+    pb, b = _spawn_elastic_worker(_RESUME_WORLD, tmp_path / "b_ck",
+                                  tmp_path / "b.json", resume_universal=uni)
+    pc, c = _spawn_elastic_worker(_RESUME_WORLD, tmp_path / "c_ck",
+                                  tmp_path / "c.json", load_dir=save_dir,
+                                  resume_tag=tag)
+    assert pb.returncode == 0 and pc.returncode == 0, \
+        (pb.stderr + pc.stderr)[-2000:]
+    ref = uninterrupted_run
+    assert b["world"] == _RESUME_WORLD and ref["world"] == _WORLD
+    assert b["global_batch"] == ref["global_batch"]
+    assert b["start_step"] == k and c["start_step"] == k
+    assert b["losses"] == c["losses"] and len(b["losses"]) == _TOTAL - k
+    assert b["compiles_after_warmup"] == 0 == c["compiles_after_warmup"]
+    steps = sorted(b["losses"], key=int)
+    np.testing.assert_allclose([b["losses"][s] for s in steps],
+                               [ref["losses"][s] for s in steps],
+                               rtol=5e-4, atol=1e-6)
+
+
+if __name__ == "__main__":
+    _elastic_worker(**json.loads(sys.argv[1]))

@@ -4,6 +4,7 @@ salvage, self-healing rejoin, the prefix-index listener lifecycle, and the
 bounded-retry disaggregated handoff. docs/SERVING.md "Failure semantics"
 describes the design under test."""
 
+import functools
 import threading
 import time
 
@@ -70,7 +71,7 @@ def _build_engine(model_params, num_blocks=24, prefix_cache=False,
 
 
 def _force_paged(engine):
-    """Hold the kernel path constant (the serving_bench discipline): a
+    """Hold the kernel path constant: a
     migration re-prefill is a from-zero prefill, which would take the
     PACKED fast path while the uninterrupted reference decoded through the
     paged kernels — the two carry a benign per-path numeric variance that
@@ -693,16 +694,7 @@ def test_health_spans_pass_trace_check(model_params, tmp_path):
             st.liveness_downs + st.stall_downs
         assert names["serve/health/migrate"][0] == st.migrations
         assert names["serve/health/rejoin"][0] == st.rejoins
-        path = tracer.export()
-        import subprocess
-        import sys
-        r = subprocess.run(
-            [sys.executable, "scripts/trace_check.py", path,
-             "--require", "serve/health"],
-            capture_output=True, text=True,
-            cwd=str(__import__("pathlib").Path(__file__).
-                    resolve().parents[2]))
-        assert r.returncode == 0, r.stdout + r.stderr
+        _trace_check(tracer.export(), "--require", "serve/health")
     finally:
         tracer.reset()
 
@@ -779,3 +771,223 @@ def test_monitor_reads_never_wait_out_a_blocking_failover():
     assert not t.is_alive()
     assert mon.state("r0") == DRAINING
     assert mon.handled_replicas() == ["r0"]
+
+
+# --------------------------------------------------------------------------- #
+# whole cluster scenarios, every platform-independent gate at once, with the
+# lock-order sanitizer armed and cross-checked against threadlint's graph
+# --------------------------------------------------------------------------- #
+
+def _trace_check(path, *args):
+    """Run the real ``scripts/trace_check.py`` over ``path``."""
+    import pathlib
+    import subprocess
+    import sys
+    r = subprocess.run(
+        [sys.executable, "scripts/trace_check.py", str(path), *args],
+        capture_output=True, text=True,
+        cwd=str(pathlib.Path(__file__).resolve().parents[2]))
+    assert r.returncode == 0, r.stdout + r.stderr
+
+
+@functools.lru_cache(maxsize=None)
+def _static_lock_edges():
+    """threadlint's static lock graph over the package, under the repo's
+    own ``.threadlint.json`` (found from the package upward;
+    docs/THREADLINT.md)."""
+    import pathlib
+    from deepspeed_tpu.tools.threadlint.model import static_lock_graph
+    root = pathlib.Path(__file__).resolve().parents[2]
+    return frozenset(static_lock_graph([str(root / "deepspeed_tpu")]))
+
+
+def _assert_ledgers_tile(handles):
+    """Every finished request's phase stints sum to the latency its client
+    measured (TTFT + sum of gaps), within the tolerance ``serve/slo/
+    attr_consistent`` itself applies — across handoffs and migrations."""
+    from deepspeed_tpu.inference.v2.serving.frontend import \
+        attribution_epsilon
+    checked = 0
+    for h in handles:
+        attr = h.attribution()
+        if h.status != "finished" or attr["client_s"] is None:
+            continue
+        checked += 1
+        assert abs(attr["total_s"] - attr["client_s"]) \
+            <= attribution_epsilon(attr["client_s"]), (h.uid, h.migrated,
+                                                       attr)
+    assert checked
+
+
+def _assert_migrated_chains(handles, tracer):
+    """A migrated request keeps one story: a ``migration`` stint on its
+    ledger, and spans sharing its trace_id on at least two lanes, one of
+    them the health lane's migrate span."""
+    lanes = {}
+    for kind, name, _t0, _t1, lane, args in tracer.iter_records():
+        if kind == "X" and args and "trace_id" in args:
+            lanes.setdefault(args["trace_id"], set()).add((lane, name))
+    migrated = [h for h in handles if h.status == "finished" and h.migrated]
+    assert migrated
+    for h in migrated:
+        assert any(p == "migration" for p, _, _ in h.timeline()), h.uid
+        recs = lanes.get(h.trace_id, set())
+        assert len({lane for lane, _ in recs}) >= 2, (h.uid, recs)
+        assert any(n == "serve/health/migrate" for _, n in recs), h.uid
+
+
+def _stream_until_first_token(handles):
+    for h in handles:
+        for _t in h:
+            break
+
+
+@pytest.mark.parametrize("scenario", ["failover_rejoin",
+                                      "disaggregated_handoff",
+                                      "cache_aware_routing"])
+def test_cluster_scenario_under_lock_sanitizer(model_params, tmp_path,
+                                               scenario):
+    """The three cluster scenarios, whole, on warmed replicas with every
+    lock a recording proxy and request flow tracing on. What must hold on
+    any platform: every stream byte-identical to an uninterrupted direct
+    run (kernel path held constant), zero compiles on every replica —
+    a rejoin's re-warm included —, every allocator back at its baseline,
+    every finished request's ledger summing to its client's latency, no
+    cycle in the observed lock order and no observed edge that threadlint's
+    static graph does not predict.
+
+    - ``failover_rejoin``: one replica's loop is killed by an injected
+      raise and, once it has healed, the other's is stalled past the down
+      deadline; both are detected, their streams migrate, both rejoin on
+      their own and end healthy; the raise leaves the flight-recorder dump
+      and every migrated request keeps one flow chain across lanes.
+    - ``disaggregated_handoff``: a prefill and a decode replica; every
+      request's KV crosses the page fabric once.
+    - ``cache_aware_routing``: two serving replicas with radix caches
+      behind the cache-aware policy; requests sharing a prefix follow it.
+    """
+    from deepspeed_tpu.monitor.trace import tracer
+    from deepspeed_tpu.utils import locksan
+    locksan.arm()                    # before any lock below is built
+    tracer.reset()
+    tracer.configure(trace_dir=str(tmp_path), enabled=True)
+    rt = None
+    try:
+        cache = scenario == "cache_aware_routing"
+        engines = [_build_engine(model_params, prefix_cache=cache,
+                                 warmup=True) for _ in range(2)]
+        for e in engines:
+            _force_paged(e)
+        rng = _rng()
+        if cache:
+            shared = [_prompt(rng, 48), _prompt(rng, 48)]
+            prompts = [np.concatenate([shared[i % 2], _prompt(rng, 6)])
+                       for i in range(8)]
+        else:
+            prompts = [_prompt(rng, n) for n in (24, 40, 9, 32, 24, 16, 40,
+                                                 12)]
+        gens = [24, 16, 32, 24, 32, 24, 16, 24]
+        refs = [_direct_stream(engines[1], p, g)
+                for p, g in zip(prompts, gens)]
+        if cache:                    # the references must not pre-warm a tree
+            for e in engines:
+                while e.prefix_cache.cached_blocks \
+                        and e.prefix_cache.evict(e.prefix_cache.cached_blocks):
+                    pass
+        frees = [e.free_blocks for e in engines]
+
+        if scenario == "failover_rejoin":
+            # deadlines that a warm step stays under even with every core
+            # of the box taken by other test workers (a spurious down on
+            # both replicas at once sheds); the injected stall outlasts them
+            _, rt = _router(engines, health=dict(
+                _HEALTH, auto_rejoin=True, suspect_after_s=1.0,
+                down_after_s=3.0))
+            # the whole mix once through each replica before the monitor
+            # starts: what a first multi-row pass builds lazily would read
+            # as a stall
+            rt.cluster.start()
+            for r in rt.cluster.frontends:
+                for p, g in zip(prompts, gens):
+                    r.frontend.submit(p, priority="lo", max_new_tokens=g)
+                assert r.frontend.drain(timeout=120)
+        else:
+            roles, cfg = ((["prefill", "decode"],
+                           {"topology": "disaggregated"})
+                          if scenario == "disaggregated_handoff" else
+                          (["serve", "serve"],
+                           {"policy": "cache_aware", "balance": 16.0}))
+            rt = ServingRouter(ServingCluster(engines, serving=_SERVING,
+                                              roles=roles), cfg)
+        rt.start()
+        compiles = [e.compiles for e in engines]
+
+        def submit(lo, hi):
+            return [rt.submit(prompts[i], priority="hi" if i % 2 else "lo",
+                              max_new_tokens=gens[i]) for i in range(lo, hi)]
+
+        if scenario == "failover_rejoin":
+            try:
+                hs = submit(0, 4)
+                _stream_until_first_token(hs[:2])
+                fi.install(fi.parse_plan(
+                    "serve.engine_step.r0:at=2:action=raise"))
+                assert rt.drain(timeout=120)
+                assert rt.health.wait_all_healthy(60.0)
+                more = submit(4, 8)
+                _stream_until_first_token(more[:2])
+                fi.install(fi.parse_plan(
+                    "serve.engine_step.r1:at=2:action=stall:delay_s=4.5"))
+                assert rt.drain(timeout=120)
+                assert rt.health.wait_all_healthy(60.0)
+                hs += more
+            finally:
+                fi.clear()
+            st = rt.health.stats
+            assert st.liveness_downs >= 1 and st.stall_downs >= 1
+            assert st.migrations >= 1 and st.rejoins >= 2
+            _assert_migrated_chains(hs, tracer)
+            # the timeline itself: lanes present, each request's hops bound
+            # into one flow, and the injected raise's flight-recorder dump
+            tracer.export()
+            _trace_check(tmp_path, "--require", "serve", "serve/req",
+                         "serve/router", "serve/health", "--require-flows",
+                         "serve/req", "--expect-crash")
+        elif scenario == "disaggregated_handoff":
+            hs = submit(0, 8)
+            assert rt.drain(timeout=120)
+            assert rt.stats.handoffs == len(hs)
+            assert rt.stats.handoff_bytes > 0
+        else:
+            prefilled = sum(e.scheduler.prefill_tokens_completed
+                            for e in engines)
+            hs = submit(0, 2)        # one request plants each prefix
+            assert rt.drain(timeout=120)
+            hs += submit(2, 8)       # the rest follow it to the warm tree
+            assert rt.drain(timeout=120)
+            assert rt.stats.cache_hit_blocks > 0
+            prefilled = sum(e.scheduler.prefill_tokens_completed
+                            for e in engines) - prefilled
+            assert prefilled < sum(len(p) for p in prompts) - 5 * 32
+
+        for h, ref in zip(hs, refs):
+            assert h.status == "finished" and h.tokens == ref, \
+                [(x.uid, x.status, x.migrated, len(x.tokens)) for x in hs]
+        _assert_ledgers_tile(hs)
+        rt.close()
+        rt = None
+        assert [e.compiles for e in engines] == compiles
+        if cache:                    # cached pages are the tree's, not leaks
+            for e in engines:
+                assert e.free_blocks + e.prefix_cache.cached_blocks \
+                    == e.allocator.total_blocks
+        else:
+            assert [e.free_blocks for e in engines] == frees
+        assert locksan.edges()       # the proxies were in the path
+        assert locksan.report()["cycles"] == []
+        assert locksan.check_static(_static_lock_edges()) == set()
+    finally:
+        if rt is not None:
+            rt.close()
+        tracer.reset()
+        locksan.disarm()

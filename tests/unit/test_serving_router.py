@@ -17,9 +17,7 @@ from deepspeed_tpu.inference.v2.prefix_cache import (RadixPrefixCache,
 from deepspeed_tpu.inference.v2.ragged.blocked_allocator import \
     BlockedAllocator
 from deepspeed_tpu.inference.v2.serving import (ClusterPrefixIndex,
-                                                PoissonLoadGen,
-                                                ServingCluster, ServingRouter,
-                                                WorkloadComponent)
+                                                ServingCluster, ServingRouter)
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.monitor.serving import (FrontendStats, RouterStats,
                                            SpecDecodeStats)
@@ -686,80 +684,3 @@ def test_router_route_spans(model_params, tmp_path):
         assert r.returncode == 0, r.stdout + r.stderr
     finally:
         tracer.reset()
-
-
-# --------------------------------------------------------------------------- #
-# loadgen: shared-prefix components + target-independent determinism
-# --------------------------------------------------------------------------- #
-
-def test_loadgen_shared_prefix_components_deterministic():
-    mix = [WorkloadComponent("hi", 2.0, [4, 8], [4], prefix_len=12),
-           WorkloadComponent("lo", 1.0, [8], [8], prefix_len=12),
-           WorkloadComponent("hi", 1.0, [6], [4])]
-    a1 = PoissonLoadGen(rate=50.0, mix=mix, vocab=128, seed=9).arrivals(n=30)
-    a2 = PoissonLoadGen(rate=50.0, mix=mix, vocab=128, seed=9).arrivals(n=30)
-    assert [x.t for x in a1] == [x.t for x in a2]
-    assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a1, a2))
-    # requests within a prefix component share its prefix; components
-    # differ from each other
-    by_len = {}
-    for x in a1:
-        by_len.setdefault(len(x.prompt), []).append(x.prompt)
-    with_prefix = [ps for n, ps in by_len.items() if n >= 12 + 4]
-    prefixes = set()
-    for ps in with_prefix:
-        for p in ps:
-            prefixes.add(tuple(int(t) for t in p[:12]))
-    assert len(prefixes) >= 2                # two distinct component prefixes
-
-
-def test_loadgen_prefix_free_mix_stream_unchanged():
-    """prefix_len=0 components draw nothing extra: the stream for a given
-    seed is byte-identical to the pre-prefix generator (the PR 8 bench
-    seeds replay unchanged)."""
-    mix = [WorkloadComponent("hi", 3.0, [8, 16], [4]),
-           WorkloadComponent("lo", 1.0, [32], [8, 16])]
-    a = PoissonLoadGen(rate=50.0, mix=mix, vocab=128, seed=7).arrivals(n=10)
-    # pinned against the PR 8 generator's output for this seed
-    assert [round(x.t, 6) for x in a[:3]] == \
-        [round(t, 6) for t in _legacy_arrival_times(7, 50.0, mix, 128, 3)]
-
-
-def _legacy_arrival_times(seed, rate, mix, vocab, n):
-    """The PR 8 arrival loop, verbatim (no prefix draws)."""
-    rng = np.random.RandomState(seed)
-    w = np.asarray([c.weight for c in mix], np.float64)
-    w = w / w.sum()
-    out, t = [], 0.0
-    while len(out) < n:
-        t += float(rng.exponential(1.0 / rate))
-        comp = mix[int(rng.choice(len(mix), p=w))]
-        plen = int(comp.prompt_lens[int(rng.randint(len(comp.prompt_lens)))])
-        rng.randint(len(comp.gen_lens))
-        rng.randint(0, vocab, size=(plen,))
-        out.append(t)
-    return out
-
-
-def test_loadgen_replay_target_independent():
-    """The same seed drives the identical per-request (class, prompt,
-    arrival, budget) stream whoever consumes it — scoring a router and a
-    single frontend compares the exact same workload."""
-    from deepspeed_tpu.inference.v2.serving import replay
-
-    class StubTarget:
-        def __init__(self):
-            self.seen = []
-
-        def submit(self, prompt, priority, max_new_tokens):
-            self.seen.append((priority, tuple(int(t) for t in prompt),
-                              max_new_tokens))
-            return object()
-
-    mix = [WorkloadComponent("hi", 1.0, [4], [4], prefix_len=8)]
-    t1, t2 = StubTarget(), StubTarget()
-    for t in (t1, t2):
-        arrivals = PoissonLoadGen(rate=200.0, mix=mix, vocab=64,
-                                  seed=3).arrivals(n=12)
-        replay(t, arrivals, speed=1e6)
-    assert t1.seen == t2.seen

@@ -62,11 +62,15 @@ def stream_bytes(losses):
 
 def test_depth_changes_placement_never_math(eight_devices):
     """Byte-identical per-step loss streams across prefetch depths: the
-    schedule moves collectives, the math is untouched (the train_bench
-    --zero3-overlap gate, unit-sized)."""
+    schedule moves collectives, the math is untouched, and a warm schedule
+    compiles nothing."""
     base = stream_bytes(run_losses(make_engine(0)))
     for depth in (1, 2):
-        assert stream_bytes(run_losses(make_engine(depth))) == base
+        engine = make_engine(depth)
+        assert stream_bytes(run_losses(engine)) == base
+        c0 = engine.compiles         # warm: the schedule is one program
+        run_losses(engine, steps=2)
+        assert engine.compiles == c0
     # the implicit (XLA-scheduled) path uses a different grad-reduction
     # order: equal to fp32 tolerance, NOT guaranteed byte-equal
     implicit = run_losses(make_engine(None))
