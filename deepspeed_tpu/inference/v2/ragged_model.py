@@ -883,9 +883,20 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     # attention (projections, rope, KV write, kernel) from its FFN
     with jax.named_scope("attn"):
         h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype, spec.norm_plus_one)
-        q = _lora_mm(h1, w["wq"], lora, "q").reshape(-1, H, D)
-        k = _lora_mm(h1, w["wk"], lora, "k").reshape(-1, Hkv, D)
-        v = _lora_mm(h1, w["wv"], lora, "v").reshape(-1, Hkv, D)
+        # The projections' [N, out] results stay values of their own. Left
+        # to fold the reshape to heads (and the rotation after it) into the
+        # dot's output layout, the TPU compiler asks for a transposed weight:
+        # it then reads layer l of wq, wk and wv out of the stack into
+        # on-chip memory and copies each transposed there before a dot runs
+        # (2.3 ms of a 12 ms Mistral-7B decode step on a v5e; 1.15 fused).
+        # Behind the barrier the dot's fusion takes the stack and l, as wo's
+        # and the FFN's do.
+        q, k, v = jax.lax.optimization_barrier((
+            _lora_mm(h1, w["wq"], lora, "q"), _lora_mm(h1, w["wk"], lora, "k"),
+            _lora_mm(h1, w["wv"], lora, "v")))
+        q = q.reshape(-1, H, D)
+        k = k.reshape(-1, Hkv, D)
+        v = v.reshape(-1, Hkv, D)
         if "bq" in w:
             q = q + w["bq"].reshape(H, D)
             k = k + w["bk"].reshape(Hkv, D)

@@ -240,6 +240,36 @@ def test_moe_layer_scan_reads_expert_stacks_in_place(T, v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def _on(chip):
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    return arr
+
+
+HID, FFN, VOCAB = 4096, 14336, 32000
+
+
+def _mistral_7b(arr, L, weight=None):
+    """Spec and stacked weight tree (shapes only) of Mistral-7B at ``L``
+    layers; ``weight(L, K, N)`` makes a layer-stacked matrix (bf16 unless
+    given)."""
+    from deepspeed_tpu.inference.v2.ragged_model import RaggedModelSpec
+    w = weight or (lambda *shape: arr(BF16, *shape))
+    spec = RaggedModelSpec(family="llama", num_layers=L, hidden_size=HID,
+                           num_heads=H, num_kv_heads=HKV, head_dim=D,
+                           vocab_size=VOCAB, window=WINDOW, dtype=BF16)
+    weights = {
+        "embed": arr(BF16, VOCAB, HID), "lm_head": arr(BF16, HID, VOCAB),
+        "final_norm": {"scale": arr(BF16, HID)},
+        "layers": {"ln1": {"scale": arr(BF16, L, HID)},
+                   "ln2": {"scale": arr(BF16, L, HID)},
+                   "wq": w(L, HID, H * D), "wk": w(L, HID, HKV * D),
+                   "wv": w(L, HID, HKV * D), "wo": w(L, H * D, HID),
+                   "mlp": {"w_gate": w(L, HID, FFN), "w_up": w(L, HID, FFN),
+                           "w_down": w(L, FFN, HID)}}}
+    return spec, weights
+
+
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
 def test_decode_step_writes_kv_rows_in_place(pool, v5e, monkeypatch):
     """The serving decode step at Mistral-7B width (16 layers, 32 rows,
@@ -250,30 +280,12 @@ def test_decode_step_writes_kv_rows_in_place(pool, v5e, monkeypatch):
     rows; no instruction produces a value of that size, nothing copies the
     pool, the pool (and an int8 pool's scale tiles) is the output's
     buffer, and what the program keeps besides its arguments is small."""
-    from deepspeed_tpu.inference.v2.ragged_model import (RaggedModelSpec,
-                                                         build_decode_step)
+    from deepspeed_tpu.inference.v2.ragged_model import build_decode_step
     monkeypatch.setattr(_backend, "interpret", lambda: False)
-    L, rows, pages, hid, ffn, vocab = 16, 32, 792, 4096, 14336, 32000
+    L, rows, pages = 16, 32, 792
     chip = SingleDeviceSharding(v5e[0])
-
-    def arr(dtype, *shape):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    spec = RaggedModelSpec(family="llama", num_layers=L, hidden_size=hid,
-                           num_heads=H, num_kv_heads=HKV, head_dim=D,
-                           vocab_size=vocab, window=WINDOW, dtype=BF16)
-    weights = {
-        "embed": arr(BF16, vocab, hid), "lm_head": arr(BF16, hid, vocab),
-        "final_norm": {"scale": arr(BF16, hid)},
-        "layers": {"ln1": {"scale": arr(BF16, L, hid)},
-                   "ln2": {"scale": arr(BF16, L, hid)},
-                   "wq": arr(BF16, L, hid, H * D),
-                   "wk": arr(BF16, L, hid, HKV * D),
-                   "wv": arr(BF16, L, hid, HKV * D),
-                   "wo": arr(BF16, L, H * D, hid),
-                   "mlp": {"w_gate": arr(BF16, L, hid, ffn),
-                           "w_up": arr(BF16, L, hid, ffn),
-                           "w_down": arr(BF16, L, ffn, hid)}}}
+    arr = _on(chip)
+    spec, weights = _mistral_7b(arr, L)
     kv_dtype = BF16 if pool == "bf16" else I8
     kv = arr(kv_dtype, L, pages, 2, HKV, BS, D)
     if pool == "int8":
@@ -307,3 +319,143 @@ def test_decode_step_writes_kv_rows_in_place(pool, v5e, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_elems * jnp.dtype(kv_dtype).itemsize
     assert mem.temp_size_in_bytes < 64 << 20
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \((.*)\) -> .* \{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (?:\w+\[([\d,]*)\]\S*|\(.*?\)) ([\w-]+)\(")
+_CALLS = re.compile(r"calls=%([^,\s}]+)")
+
+
+def _executed(text):
+    """``(instructions, parameters of each computation)`` of a compiled
+    program's text, the instructions being those that run by themselves:
+    ``(name, dims, opcode, line)`` of every computation that no fusion
+    calls (the entry, loop bodies and conditions). What sits inside a
+    fusion is a step of that fusion's own pipeline, not a value in memory."""
+    params, lines, name = {}, {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            params[name], lines[name] = m.group(2), []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            lines[name].append(line)
+    fused = {m.group(1) for body in lines.values() for line in body
+             if " fusion(" in line for m in [_CALLS.search(line)] if m}
+    out = []
+    for name, body in lines.items():
+        if name in fused:
+            continue
+        for line in body:
+            m = _INSTRUCTION.match(line)
+            if m:
+                dims = tuple(int(d) for d in (m.group(2) or "").split(",")
+                             if d)
+                out.append((m.group(1), dims, m.group(3), line))
+    return out, params
+
+
+_QKV = ((HID, H * D), (HID, HKV * D))                 # wq; wk and wv
+_FREE = ("parameter", "get-tuple-element", "bitcast")
+
+
+def _layer_matrices(instructions, whole_layers_only):
+    """Instructions that produce one layer's ``wq``, ``wk`` or ``wv`` as a
+    value of its own: ``[1, K, N]`` as the scan slices it or, unless
+    ``whole_layers_only``, ``[K, N]`` and either transposed. (A pass over
+    1,024 rows has activations of ``[1024, 4096]``; there only the sliced
+    form tells a weight.)"""
+    found = []
+    for name, dims, op, _ in instructions:
+        sliced = len(dims) == 3 and dims[0] == 1
+        if op in _FREE or (whole_layers_only and not sliced):
+            continue
+        core = dims[1:] if sliced else dims
+        if core in _QKV or core[::-1] in _QKV:
+            found.append(f"{name} = {list(dims)} {op}")
+    return found
+
+
+def _stacks_read_in_place(instructions, params, L):
+    """``(K, N)`` of every layer-stacked matrix ``[L, K, N]`` that is an
+    operand of a fusion around a dot (``kind=kOutput``), sorted."""
+    stacks = []
+    for _, _, op, line in instructions:
+        if op == "fusion" and "kind=kOutput" in line:
+            stacks += [(int(k), int(n)) for k, n in re.findall(
+                rf"(?:bf16|s8)\[{L},(\d+),(\d+)\]",
+                params[_CALLS.search(line).group(1)])]
+    return sorted(stacks)
+
+
+@pytest.mark.parametrize("rows,tree", [(8, "bf16"), (16, "bf16"),
+                                       (32, "bf16"), (8, "int8")])
+def test_decode_step_reads_qkv_weights_in_place(rows, tree, v5e, monkeypatch):
+    """The decode step at Mistral-7B width, 16 layers, in each bucket the
+    benchmark's cells run. The q, k and v projections used to read layer
+    ``l`` of their stacked weights into on-chip memory as a value of its own
+    (``%constant_dynamic-slice_fusion.6/.7/.8``, ``[1, 4096, 4096]`` and two
+    ``[1, 4096, 1024]``: the matrix's one HBM read, overlapped by nothing)
+    and then transpose it there (three ``copy``), because the reshape to
+    heads was folded into the dot's output layout: 6 such instructions a
+    layer in every bucket, 1.4 ms of an 11.9 ms step on the chip. Now every
+    stacked matrix of the layer is an operand of its dot's own fusion, as
+    ``wo`` and the FFN's always were, and an int8 tree's dequantizing
+    convert sits in that fusion too."""
+    from deepspeed_tpu.inference.v2.ragged_model import build_decode_step
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    L, pages = 16, 792
+    arr = _on(SingleDeviceSharding(v5e[0]))
+
+    def int8(L, K, N):
+        return {"w8": arr(I8, L, K, N), "scale": arr(F32, L, 1, N)}
+
+    spec, weights = _mistral_7b(arr, L, int8 if tree == "int8" else None)
+    compiled = jax.jit(
+        build_decode_step(spec, window_ring_ok=True), donate_argnums=(1,)
+    ).lower(weights, arr(BF16, L, pages, 2, HKV, BS, D), arr(I32, rows),
+            arr(I32, rows), arr(I32, rows, MB), arr(I32, rows),
+            arr(jnp.uint32, 2)).compile()
+    instructions, params = _executed(compiled.as_text())
+    staged = _layer_matrices(instructions, whole_layers_only=False)
+    assert not staged, f"a layer's projection matrix is materialised: {staged}"
+    assert _stacks_read_in_place(instructions, params, L) == sorted(
+        [(HID, H * D), (HID, HKV * D), (HID, HKV * D), (H * D, HID),
+         (HID, FFN), (HID, FFN), (FFN, HID)])
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("program", ["serve_prefill_packed",
+                                     "serve_paged_pass"])
+def test_prefill_programs_stage_no_projection_weights(program, v5e,
+                                                      monkeypatch):
+    """The two prefill programs of the same engine (4 slots of 256 tokens,
+    32 decode rows) run the same layer body. Before the projections kept
+    their 2-D results the packed pass staged and transposed all three
+    matrices (6 ``[1, 4096, *]`` temporaries a layer) and the paged pass two
+    of them (4); neither may gain one back."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2.ragged_model import (
+        build_prefill_forward, build_ragged_forward)
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    L, pages, slots, slot, rows = 16, 792, 4, 256, 32
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights = _mistral_7b(arr, L)
+    # the arrays a pass is handed, as the scheduler sizes them
+    host = RaggedBatch(num_slots=slots, slot_size=slot, max_sequences=rows,
+                       max_blocks=MB).device_arrays()
+    pages_written = slots * slot // BS + slots
+    batch = {k: arr(I32, pages_written) if v is None else arr(I32, *v.shape)
+             for k, v in host.items()}
+    build = {"serve_prefill_packed": build_prefill_forward,
+             "serve_paged_pass": build_ragged_forward}[program]
+    compiled = jax.jit(build(spec), donate_argnums=(1,)).lower(
+        weights, arr(BF16, L, pages, 2, HKV, BS, D), batch).compile()
+    instructions, params = _executed(compiled.as_text())
+    staged = _layer_matrices(instructions, whole_layers_only=True)
+    assert not staged, f"a layer's projection matrix is materialised: {staged}"
+    assert _stacks_read_in_place(instructions, params, L).count(
+        (HID, HKV * D)) == 2
