@@ -146,6 +146,33 @@ class AttentionKernelSpec:
         # model that mixes windowed and full layers (``spec.layer_kinds``)
         # keeps whole-context pages in every layer, has no ring, and
         # composes with both
+        # a model with state-space (Mamba) layers keeps ONE recurrent state a
+        # sequence, which every token overwrites: whatever hands a sequence
+        # pages of an earlier position, or moves its pages without its
+        # state, would need a snapshot of the state at a block boundary,
+        # which no program writes (docs/SERVING.md "State-space layers")
+        if getattr(spec, "mamba", None) is not None:
+            from deepspeed_tpu.inference.v2.scheduler import (
+                STATE_SNAPSHOT_MSG)
+            refused = {
+                "prefix_cache.enabled (a cached prefix's pages carry no "
+                "state for the layers that do not attend)":
+                    cfg.prefix_cache.enabled,
+                "spec_decode.enabled (rejected drafts have already advanced "
+                "the state; rolling back needs the state before them)":
+                    cfg.spec_decode.enabled,
+            }   # (serving.preemption: offload is the frontend's to refuse)
+            for what, on in refused.items():
+                if on:
+                    raise NotImplementedError(
+                        STATE_SNAPSHOT_MSG.format(what=what))
+            if cfg.lora.enabled or tp > 1:
+                raise NotImplementedError(
+                    "multi-tenant LoRA and tensor_parallel > 1 are not wired "
+                    "for a model with state-space (Mamba) layers: the fused "
+                    "decode programs hand the rows' state slots where the "
+                    "adapter operands go, and the state kernels run outside "
+                    "any shard_map")
         if cfg.prefix_cache.enabled and spec.window is not None:
             raise NotImplementedError(
                 "prefix_cache with a sliding-window model is not wired: "

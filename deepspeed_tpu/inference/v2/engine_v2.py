@@ -110,6 +110,13 @@ def serve_sample_rows(arr, rows, key, do_sample: bool, top_k: int,
     return jax.random.categorical(key, z, axis=-1)
 
 
+@jax.jit
+def serve_place_rows(ids, part, at):
+    """``ids`` [B] int32 with ``part`` [p] written at positions ``at`` [p]
+    (``at == B``: an entry of the part's padding, dropped)."""
+    return ids.at[at].set(part.astype(jnp.int32), mode="drop")
+
+
 class InferenceEngineV2:
 
     @property
@@ -216,8 +223,12 @@ class InferenceEngineV2:
         # bucket never touches a live sequence's KV). Outside the allocator
         # on purpose — free/total accounting and the prefix cache never see
         # it, and it can never be handed to a sequence.
+        # pages exist for the layers that attend; a Mamba layer holds a state
+        # slot per sequence instead (ragged/state_pool.py)
+        from deepspeed_tpu.inference.v2.ragged_model import (
+            num_page_layers, num_state_layers)
         kv_cfg = KVCacheConfig(
-            num_layers=self.spec.num_layers,
+            num_layers=max(1, num_page_layers(self.spec)),
             num_kv_heads=self.spec.num_kv_heads,
             head_dim=self.spec.head_dim,
             block_size=cfg.kv_cache.block_size,
@@ -239,6 +250,21 @@ class InferenceEngineV2:
                 cow_fn=self.kv.copy_page)
         self.scheduler = DynamicSplitFuseScheduler(sm, self.kv, self.allocator,
                                                    prefix_cache=self.prefix_cache)
+        # the recurrent-state pools of a model with state-space layers: one
+        # slot per tracked sequence (+ the dump slot), riding with the pages
+        # as ONE donated pytree through every program
+        self.state_config = None
+        if self.spec.mamba is not None:
+            from deepspeed_tpu.inference.v2.ragged.state_pool import (
+                StatefulKV, StatePoolConfig, StateSlotAllocator)
+            m = self.spec.mamba
+            self.state_config = StatePoolConfig(
+                num_layers=num_state_layers(self.spec),
+                num_slots=sm.max_tracked_sequences, d_inner=m["d_inner"],
+                d_state=m["d_state"], d_conv=m["d_conv"])
+            self.scheduler.state_slots = StateSlotAllocator(
+                sm.max_tracked_sequences)
+            self.kv.kv = StatefulKV(self.kv.kv, *self.state_config.zeros())
         # sliding-window serving (Mistral/Qwen2): the scheduler ring-reuses
         # each sequence's pages beyond the window so KV stays bounded. The
         # ring engages only where EVERY layer is windowed: a model of mixed
@@ -362,8 +388,15 @@ class InferenceEngineV2:
         from deepspeed_tpu.inference.v2.ragged_model import (
             describe_layer_kinds)
         ring = self.scheduler.ring_pages
+        state = "" if self.state_config is None else (
+            f"; state pool {self.state_config.num_slots}+dump slots x "
+            f"{self.state_config.num_layers} layers = "
+            f"{self.state_config.total_bytes() / 2**20:.1f} MiB "
+            f"({self.state_config.bytes_per_slot() / 2**20:.2f} MiB a "
+            "sequence)")
         log_dist(f"engine_v2: family={family} tp={tp} blocks={nb}+scratch "
-                 f"block_size={kv_cfg.block_size} budget={sm.max_ragged_batch_size}"
+                 f"block_size={kv_cfg.block_size} x {kv_cfg.num_layers} page "
+                 f"layers budget={sm.max_ragged_batch_size}{state}"
                  f"; {describe_layer_kinds(self.spec)}; page ring "
                  f"{'off' if ring is None else f'{ring} pages a sequence'}; "
                  f"attention rungs {list(self.attn_split_ladder)}"
@@ -548,8 +581,6 @@ class InferenceEngineV2:
         of recompiling per count (seconds each)."""
         if not uids:
             return jnp.zeros((1,), jnp.int32), 0
-        order = np.empty(len(uids), np.int64)
-        parts = []
         by_array: Dict[int, Tuple[Any, list]] = {}
         host_rows, host_idx = [], []
         for i, uid in enumerate(uids):
@@ -572,33 +603,41 @@ class InferenceEngineV2:
             pad = next_pow2(len(host_rows)) - len(host_rows)
             arr = jnp.asarray(np.stack(host_rows + [host_rows[0]] * pad))
             by_array[id(arr)] = (arr, [(i, j) for j, i in enumerate(host_idx)])
-        n_done = 0
+        # each source array's rows are sampled at a power-of-two count and
+        # PLACED at their positions in one bucket-sized row: every program
+        # here is keyed by (bucket, part size), both powers of two, so the
+        # set is finite and warmup() builds all of it. (Concatenating the
+        # parts and gathering them into order was keyed by HOW the live rows
+        # split over logits arrays — a new eager program for every new split,
+        # 6-9 of them inside a 45 s window of a 128-row replica; PERF.md,
+        # PR 31.) Entries past the live rows stay token 0: pad rows, which
+        # run against the scratch page.
+        n = len(uids)
+        bucket = next_pow2(n)
+        ids = self._zero_row(bucket)
         for arr, pairs in by_array.values():
             rows = [r for _, r in pairs]
             if do_sample:
                 self._rng_key, sub = jax.random.split(self._rng_key)
             else:
                 sub = self._rng_key
-            # pad the row set to its bucket (utils.caching.next_pow2): a
-            # serving loop calls this with a DIFFERENT number of live
-            # sequences every time a sequence retires, and each distinct
-            # length would recompile serve_sample_rows (seconds each). Extra rows
-            # resample row 0 and are sliced off.
+            # extra rows resample row 0 and are placed nowhere
             n_real = len(rows)
             rows = rows + [rows[0]] * (next_pow2(n_real) - n_real)
             out = serve_sample_rows(arr, np.asarray(rows, np.int32), sub,
-                              bool(do_sample), int(top_k),
-                              float(temperature))
-            parts.append(out)                 # padded; real rows are [:n_real]
-            for j, (i, _) in enumerate(pairs):
-                order[i] = n_done + j
-            n_done += len(out)                # padded offsets
-        flat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        # pad the reorder gather to the bucket size too (same reasoning)
-        n = len(uids)
-        order_pad = np.concatenate([order,
-                                    np.zeros(next_pow2(n) - n, np.int64)])
-        return flat[jnp.asarray(order_pad, jnp.int32)].astype(jnp.int32), n
+                                    bool(do_sample), int(top_k),
+                                    float(temperature))
+            at = np.full((len(rows),), bucket, np.int32)
+            at[:n_real] = [i for i, _ in pairs]
+            ids = serve_place_rows(ids, out, at)
+        return ids, n
+
+    def _zero_row(self, bucket: int):
+        """A token row of zeros committed to the engine's mesh (what
+        ``serve_place_rows`` fills; see ``_scratch_step_args`` for why
+        committed)."""
+        return jax.device_put(np.zeros((bucket,), np.int32),
+                              self.topology.replicated())
 
     def decode_steps(self, uids: Sequence[int], n_steps: int,
                      do_sample: bool = False, temperature: float = 1.0,
@@ -644,7 +683,8 @@ class InferenceEngineV2:
         self._rng_key, sub = jax.random.split(self._rng_key)
         out_ids, final_logits, new_kv = fn(
             self.weights, self.kv.kv, ids0, db.positions, db.block_tables,
-            db.ctx_lens, sub, jnp.float32(temperature))
+            db.ctx_lens, sub, jnp.float32(temperature),
+            *self._state_operands(db))
         self.kv.update(new_kv)
         for i, u in enumerate(uids):
             self.scheduler.advance(u, n_steps)
@@ -722,6 +762,30 @@ class InferenceEngineV2:
             return ()
         pt = self.lora.page_table(uids, bucket, rb)
         return (self.lora.pool.pool, jnp.asarray(pt))
+
+    def _state_operands(self, db) -> tuple:
+        """The trailing operand of a fused decode program of a model with
+        state-space layers: the rows' state slots (run-invariant, like the
+        block tables). Empty for any other model, so callers splat it."""
+        if db.state_slots is None:
+            return ()
+        return (jnp.asarray(db.state_slots),)
+
+    def state_slots(self) -> Tuple[int, int, int]:
+        """``(live, peak, total)`` slots of the recurrent-state pool — the
+        gauge beside the page gauges (``allocator.free_blocks``); all zero
+        for a model with no state-space layers. It reads the scheduler's
+        free list and takes no lock."""
+        a = self.scheduler.state_slots
+        return (0, 0, 0) if a is None else (a.live, a.peak, a.total)
+
+    def sequence_state(self, uid: int) -> np.ndarray:
+        """A tracked sequence's recurrent state ``h`` ``[Lm, N, E]``
+        (float32) fetched to the host, for a check that compares it."""
+        slot = self.scheduler.seqs[int(uid)].state_slot
+        if slot < 0:
+            raise ValueError("this model has no state-space layers")
+        return fetch_to_host(self.kv.kv.ssm[:, slot])
 
     @property
     def attn_split_ladder(self) -> List[int]:
@@ -966,7 +1030,9 @@ class InferenceEngineV2:
         # timed steady state must not compile — warm both ops per bucket
         # over the scratch page (content round-trips to itself; int8 pools
         # round-trip their packed values+scale-tile payload the same way)
-        for b in self.page_buckets:
+        # (a model with state-space layers moves no pages to the host: what
+        # needs that is refused, see validate_engine_build)
+        for b in self.page_buckets if self.state_config is None else ():
             pages = self.fetch_pages([self.scratch_block] * b)
             self.put_pages(pages, [self.scratch_block] * b)
         # the adapter-pool movers over their own rank-sized bucket grid — a
@@ -980,12 +1046,21 @@ class InferenceEngineV2:
         sm = self.config.state_manager
         V = self.spec.vocab_size
         src_rows = {sm.num_chunk_slots, sm.max_ragged_sequence_count} | set(grid)
-        for b in grid:
-            rows = np.zeros((b,), np.int32)
-            for nr in src_rows:
-                jax.block_until_ready(serve_sample_rows(
-                    jnp.zeros((nr, V), jnp.float32), rows, self._rng_key,
-                    False, 0, 1.0))
+        # (the logits source committed to the mesh, as a program's output is:
+        # see _scratch_step_args)
+        for nr in src_rows:
+            logits = jax.device_put(jnp.zeros((nr, V), jnp.float32),
+                                    self.topology.replicated())
+            for b in grid:
+                part = serve_sample_rows(
+                    logits, np.zeros((b,), np.int32), self._rng_key,
+                    False, 0, 1.0)
+                # ... and its placement into every bucket's row that can
+                # hold it
+                for to in (g for g in grid if g >= b):
+                    jax.block_until_ready(serve_place_rows(
+                        self._zero_row(to), part,
+                        np.full((b,), to, np.int32)))
         built = self.compiles - before
         log_dist(f"engine_v2: warmup built {built} programs "
                  f"(buckets={grid}, burst_steps={list(burst_steps)})",
@@ -1011,12 +1086,24 @@ class InferenceEngineV2:
 
     def _scratch_step_args(self, bucket: int, max_blocks: int):
         """All-pad-row inputs for a fused decode program: every row is the
-        inert scratch-page fake sequence DecodeBatch pads with."""
-        ids = jnp.zeros((bucket,), jnp.int32)
+        inert scratch-page fake sequence DecodeBatch pads with. ``ids`` is
+        committed to the engine's mesh, as the token row live traffic hands
+        a step always is (it is the sampler's or the step before's output):
+        an argument's sharding is part of what jit compiles for, so with an
+        uncommitted row the warm-up built a second executable beside the one
+        traffic runs, and every bucket's first live use compiled its program
+        anew — 20-27 s each for a 28-layer model, in the middle of serving
+        (PERF.md, PR 31)."""
+        ids = jax.device_put(jnp.zeros((bucket,), jnp.int32),
+                             self.topology.replicated())
         pos = np.zeros((bucket,), np.int32)
         bt = np.full((bucket, max_blocks), self.scratch_block, np.int32)
         ctx = np.ones((bucket,), np.int32)
-        return ids, pos, bt, ctx, self._rng_key, jnp.float32(1.0)
+        args = ids, pos, bt, ctx, self._rng_key, jnp.float32(1.0)
+        if self.state_config is not None:   # every row at the dump slot
+            args += (jnp.full((bucket,), self.scheduler._dump_slot,
+                              jnp.int32),)
+        return args
 
     def _scratch_lora_args(self, bucket: int, rb: int) -> tuple:
         """All-zero-page LoRA operands for warming a rank-bucketed program
@@ -1053,7 +1140,8 @@ class InferenceEngineV2:
 
         def scratch_batch():
             b = RaggedBatch(num_slots=NC, slot_size=Cs, max_sequences=S,
-                            max_blocks=MB)
+                            max_blocks=MB,
+                            dump_slot=self.scheduler._dump_slot)
             b.kv_dest = np.full((NC * Cs + S,), self.kv.oob_sentinel, np.int32)
             PW = NC * Cs // bs + NC
             b.page_ids = np.full((PW,), self.kv.config.num_blocks, np.int32)
@@ -1071,7 +1159,7 @@ class InferenceEngineV2:
         arrays = b.device_arrays()
         for pass_fn in self._pass_rungs.values():
             _, _, new_kv = pass_fn(self.weights, self.kv.kv,
-                                   {k: arrays[k] for k in PAGED_PASS_KEYS})
+                                   self._pass_arrays(arrays, PAGED_PASS_KEYS))
             # direct rebind (not .update()) so JL003 sees the donated pool's
             # reference replaced before the next pass reads it
             self.kv.kv = new_kv
@@ -1089,9 +1177,19 @@ class InferenceEngineV2:
         arrays = b.device_arrays()
         logits, _, new_kv = self._ensure_prefill_pass()(
             self.weights, self.kv.kv,
-            {k: arrays[k] for k in PREFILL_PASS_KEYS})
+            self._pass_arrays(arrays, PREFILL_PASS_KEYS))
         self.kv.update(new_kv)
         jax.block_until_ready(logits)
+
+    def _pass_arrays(self, arrays: Dict[str, Any], keys) -> Dict[str, Any]:
+        """The descriptors one pass program reads (the two paths are separate
+        jit programs; the other's would be dead upload weight), with the
+        rows' state slots for a model with state-space layers."""
+        if self.state_config is not None:
+            from deepspeed_tpu.inference.v2.ragged_model import (
+                STATE_PASS_KEYS)
+            keys = keys + STATE_PASS_KEYS
+        return {k: arrays[k] for k in keys}
 
     def _ensure_prefill_pass(self):
         """Build (once) the packed pure-prefill fast-path program — shared by
@@ -1124,12 +1222,12 @@ class InferenceEngineV2:
         # has no per-head position bias; the paged kernels do)
         if batch.pure_prefill and not self.spec.alibi:
             pass_fn = self._ensure_prefill_pass()
-            arrays = {k: arrays[k] for k in PREFILL_PASS_KEYS}
+            arrays = self._pass_arrays(arrays, PREFILL_PASS_KEYS)
         else:
             # rung-keyed paged pass: the decode rows ride this step's
             # split rung (rung 1 is self._pass — byte-identical)
             pass_fn = self._pass_rungs.get(self._attn_rung(), self._pass)
-            arrays = {k: arrays[k] for k in PAGED_PASS_KEYS}
+            arrays = self._pass_arrays(arrays, PAGED_PASS_KEYS)
         chunk_logits, decode_logits, new_kv = pass_fn(
             self.weights, self.kv.kv, arrays)
         self.kv.update(new_kv)
@@ -1319,6 +1417,12 @@ class InferenceEngineV2:
         flush returns this sequence's pages to the LOCAL radix tree — the
         prefill replica stays warm for the next matching prompt."""
         uid = int(uid)
+        if self.state_config is not None:
+            from deepspeed_tpu.inference.v2.scheduler import (
+                STATE_SNAPSHOT_MSG)
+            raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
+                what="export_kv (handing a sequence's pages to another "
+                "engine)"))
         seq = self.scheduler.seqs.get(uid)
         if seq is None:
             raise KeyError(f"sequence {uid} is not tracked")
@@ -1342,6 +1446,12 @@ class InferenceEngineV2:
         The sequence is then in steady decode state: ``decode_pipeline`` can
         admit it directly. Returns the allocated block ids."""
         uid = int(uid)
+        if self.state_config is not None:
+            from deepspeed_tpu.inference.v2.scheduler import (
+                STATE_SNAPSHOT_MSG)
+            raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
+                what="import_kv (adopting a sequence whose pages were "
+                "computed on another engine)"))
         page_shape, page_dtype = self.page_payload_spec
         pages = np.asarray(pages, page_dtype)
         if tuple(pages.shape[1:]) != page_shape:
@@ -1380,7 +1490,7 @@ class InferenceEngineV2:
         for seq in list(self.scheduler.seqs.values()):
             held = seq.seen_tokens if cap is None else min(seq.seen_tokens,
                                                            cap)
-            resident += self.spec.num_layers * held
+            resident += self.kv.config.num_layers * held
             for window, n in self._windowed_layers:
                 dead += n * max(0, held - window)
         return dead, resident

@@ -154,6 +154,9 @@ class DecodePipeline:
         # while a request is in flight — the registry's refcount gate): empty
         # at rb=0, so adapter-free engines dispatch the identical program
         lora_args = e._lora_operands(uids, db.bucket, rb)
+        # so are the rows' recurrent-state slots (a model with state-space
+        # layers; it refuses LoRA, so the two never share the tail)
+        lora_args += e._state_operands(db)
         tb = perf() if _tracer.enabled else 0.0
         ids, _ = e._sample_device_padded(uids, self.do_sample,
                                          self.temperature, self.top_k)

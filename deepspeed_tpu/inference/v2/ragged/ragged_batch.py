@@ -78,6 +78,19 @@ class RaggedBatch:
     page_rows: np.ndarray = None              # [PW] int32
     page_fill: np.ndarray = None              # [PW] int32
 
+    # recurrent-state slots (ragged/state_pool.py; models with state-space
+    # layers). Empty chunk slots and inactive decode rows hold ``dump_slot``.
+    # chunk_state_mode says where a chunk slot's state comes from: 0 = zero
+    # (the slot holds its sequence's position 0), 1 = the pool (the sequence
+    # has context from an earlier pass), 2 = the chunk slot before it (the
+    # same sequence's previous ``slot_size`` tokens in this pass). A slot
+    # followed by a mode-2 slot writes nothing back; the sequence's last
+    # slot of the pass does.
+    dump_slot: int = 0
+    chunk_state_slot: np.ndarray = None       # [NC] int32
+    chunk_state_mode: np.ndarray = None       # [NC] int32
+    decode_state_slot: np.ndarray = None      # [S] int32
+
     def __post_init__(self):
         NC, Cs = self.num_slots, self.slot_size
         S, MB = self.max_sequences, self.max_blocks
@@ -105,6 +118,12 @@ class RaggedBatch:
             self.kv_dest = np.zeros((NC * Cs + S,), np.int32)
         if self.row_seg is None:
             self.row_seg = np.full((NC * Cs,), -1, np.int32)
+        if self.chunk_state_slot is None:
+            self.chunk_state_slot = np.full((NC,), self.dump_slot, np.int32)
+        if self.chunk_state_mode is None:
+            self.chunk_state_mode = np.zeros((NC,), np.int32)
+        if self.decode_state_slot is None:
+            self.decode_state_slot = np.full((S,), self.dump_slot, np.int32)
         # page_ids/page_rows/page_fill stay None here: their static size
         # (NC*Cs/bs + NC) needs the cache block size, so the scheduler
         # allocates them (schedule_pass)
@@ -135,6 +154,9 @@ class RaggedBatch:
             "page_ids": self.page_ids,
             "page_rows": self.page_rows,
             "page_fill": self.page_fill,
+            "chunk_state_slot": self.chunk_state_slot,
+            "chunk_state_mode": self.chunk_state_mode,
+            "decode_state_slot": self.decode_state_slot,
         }
 
 
@@ -163,6 +185,9 @@ class DecodeBatch:
     positions: np.ndarray       # [bucket] int32; pad rows 0
     block_tables: np.ndarray    # [bucket, MB] int32; pad rows all-scratch
     ctx_lens: np.ndarray        # [bucket] int32; pad rows 1
+    # [bucket] int32 recurrent-state slots, pad rows the dump slot; None for
+    # a model with no state-space layers (run-invariant, like block tables)
+    state_slots: "np.ndarray | None" = None
 
     @property
     def live(self) -> int:

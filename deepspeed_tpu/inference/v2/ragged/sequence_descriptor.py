@@ -45,6 +45,9 @@ class DSSequenceDescriptor:
     # stamp trails the cache's current version frees the pages instead of
     # filing old-weight KV into a post-swap tree (runtime/colocated.py)
     weight_version: int = 0
+    # slot of the recurrent-state pool (ragged/state_pool.py) this sequence
+    # holds from admission to flush; -1 for a model with no such layers
+    state_slot: int = -1
 
     @property
     def cur_allocated_blocks(self) -> int:

@@ -1,0 +1,113 @@
+"""Per-sequence recurrent state beside the paged KV cache.
+
+A state-space (Mamba) layer keeps, for every tracked sequence, a state of
+fixed size whatever the sequence's length: the recurrence's ``h`` (``[N, E]``
+float32) and the causal convolution's tail (the last ``K - 1`` inputs,
+``(K - 1) * E`` values of the model's dtype, held in float32). Pages do not
+fit that: their lifetime follows tokens, the allocator frees and shares them
+block by block, and a state can be neither shared nor rolled back. So there
+is a second kind of per-sequence device state with a lifetime of its own:
+
+- a *slot* is taken when the scheduler first tracks a sequence and given
+  back at ``flush``; there are as many slots as tracked sequences
+  (``max_tracked_sequences``), so taking one cannot fail once admission has
+  passed;
+- a slot is zeroed when taken, not when freed: the first pass that runs a
+  sequence's position 0 starts its state from zero instead of reading the
+  slot (``RaggedBatch.chunk_state_mode``), so what a freed slot still holds
+  is never read;
+- the pools carry one slot more than the allocator hands out, the *dump*
+  slot: padding rows of a bucket and empty chunk slots read and write it, as
+  the KV pool's scratch page takes theirs.
+
+Device arrays (``Lm`` state-space layers, ``NS`` slots)::
+
+    ssm  [Lm, NS + 1, N, E]               float32
+    conv [Lm, NS + 1, (K - 1) * 8, E / 8] float32: tap j of a slot is its rows
+                                          8j..8j+7, channel e at
+                                          [e // (E/8), e % (E/8)]
+
+``E`` lies on the lanes in both (``[.., E, N]`` with ``N = 16`` would pad
+every 16 values to a 128-lane tile), and both are laid out so that one slot
+of one layer is a whole number of device tiles (8 x 128 of 32 bits): a
+kernel then moves a slot as one block, where it lies
+(``ops/pallas/ssm.py::ssm_decode_step``). The tails hold the model's dtype's
+values (what ``in_proj`` gave, exact in float32); as ``[.., K - 1, E]`` of
+that dtype their 3 rows would pad to 16, and as flat rows ``[Lm * (NS + 1),
+(K - 1) * E]`` only XLA's row scatter could update them, which costs by the
+row (3.3 ms of a 19 ms decode step at 128 rows x 26 layers; and laid out
+``[Lm, NS + 1, ..]`` that flat view was a copy of the pool in every layer of
+every step; both from the chip and the compiled step, PR 31). They travel
+with the pages as one donated pytree (:class:`StatefulKV`) through every
+serving program, which updates them by slot, in place.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, List, NamedTuple
+
+import jax.numpy as jnp
+
+
+class StatefulKV(NamedTuple):
+    """What a serving program of a model with state-space layers is handed
+    (and hands back) in place of the bare page pool."""
+    pages: Any          # the KV pool, or its (int8 values, scales) tuple
+    ssm: Any            # [Lm, NS + 1, N, E] float32
+    conv: Any           # [Lm, NS + 1, (K - 1) * 8, E / 8] float32
+
+
+@dataclass
+class StatePoolConfig:
+    num_layers: int             # state-space layers (Lm)
+    num_slots: int              # NS, the dump slot not counted
+    d_inner: int                # E
+    d_state: int                # N
+    d_conv: int                 # K
+
+    def __post_init__(self):
+        if self.d_inner % 8:
+            raise ValueError(f"d_inner {self.d_inner} is not a multiple of 8")
+
+    def bytes_per_slot(self) -> int:
+        """One sequence's state over all layers."""
+        return self.num_layers * 4 * self.d_inner * (self.d_state
+                                                     + self.d_conv - 1)
+
+    def total_bytes(self) -> int:
+        return (self.num_slots + 1) * self.bytes_per_slot()
+
+    def zeros(self):
+        """``(ssm, conv)``, freshly allocated."""
+        L, S = self.num_layers, self.num_slots + 1
+        return (jnp.zeros((L, S, self.d_state, self.d_inner), jnp.float32),
+                jnp.zeros((L, S, (self.d_conv - 1) * 8, self.d_inner // 8),
+                          jnp.float32))
+
+
+class StateSlotAllocator:
+    """Free list over the ``num_slots`` state slots, with the gauges the
+    engine reports (``engine.state_slots()``)."""
+
+    def __init__(self, num_slots: int):
+        self.total = int(num_slots)
+        self._free: List[int] = list(range(self.total - 1, -1, -1))
+        self.peak = 0
+
+    @property
+    def live(self) -> int:
+        return self.total - len(self._free)
+
+    def take(self) -> int:
+        if not self._free:
+            raise RuntimeError(
+                f"all {self.total} state slots are taken: one per tracked "
+                "sequence (max_tracked_sequences)")
+        slot = self._free.pop()
+        self.peak = max(self.peak, self.live)
+        return slot
+
+    def free(self, slot: int) -> None:
+        assert 0 <= slot < self.total and slot not in self._free, slot
+        self._free.append(int(slot))

@@ -42,8 +42,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
+from deepspeed_tpu.inference.v2.ragged.state_pool import StatefulKV
 from deepspeed_tpu.ops.pallas.paged_attention import (
     _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
+from deepspeed_tpu.ops.pallas.ssm import ssm_chunk_scan, ssm_decode_step
 
 
 def _kv_unpack(kp):
@@ -58,15 +60,46 @@ def _kv_unpack(kp):
     return kp, None
 
 
+def _state_unpack(kp):
+    """A program's pool argument -> (the KV pool as :func:`_kv_unpack`
+    takes it, the recurrent-state pools ``(ssm, conv)`` or None). A model
+    with state-space layers is handed a :class:`StatefulKV`; any other the
+    bare pool, and its programs carry no state argument at all."""
+    if isinstance(kp, StatefulKV):
+        return kp.pages, (kp.ssm, kp.conv)
+    return kp, None
+
+
+def _state_pack(new_kv, state):
+    return new_kv if state is None else StatefulKV(new_kv, *state)
+
+
 class LayerKind(NamedTuple):
     """What may differ from one layer of a model to the next."""
     window: Optional[int]   # sliding-window span in tokens; None = full
     rope: bool              # rotates q/k by position (else no positions)
     moe: bool               # routed experts (else the dense MLP)
+    mamba = False           # an attention layer (else: MambaKind)
 
     def describe(self) -> str:
         attn = "full" if self.window is None else f"window {self.window}"
         return (f"{attn}, {'rotary' if self.rope else 'no positions'}, "
+                f"{'MoE' if self.moe else 'dense'} FFN")
+
+
+class MambaKind(NamedTuple):
+    """The kind of a layer whose mixer is a Mamba state-space block, not
+    attention: it holds no pages and has neither window nor positions, and
+    keeps a fixed-size state per sequence (ragged/state_pool.py). A kind of
+    its own beside :class:`LayerKind`, which stays the three values an
+    attention layer is told by."""
+    moe: bool = False       # routed experts (else the dense MLP)
+    window = None
+    rope = False
+    mamba = True
+
+    def describe(self) -> str:
+        return ("Mamba state-space mixer (no pages), "
                 f"{'MoE' if self.moe else 'dense'} FFN")
 
 
@@ -109,7 +142,12 @@ class RaggedModelSpec:
     # pages), ``rope_theta`` and ``moe`` describe the layers that have them,
     # and ``weights["layers"]`` is a tuple of stacked trees, one per run of
     # equal kinds (:func:`layer_runs`)
-    layer_kinds: Optional[Tuple[LayerKind, ...]] = None
+    layer_kinds: Optional[Tuple[Any, ...]] = None   # LayerKind | MambaKind
+    # widths of the Mamba mixer {"d_inner": E, "d_state": N, "dt_rank": R,
+    # "d_conv": K} of a model that has such layers (``layer_kinds`` says
+    # which); on a run's spec (:func:`layer_runs`) it is set for a run of
+    # Mamba layers and None for a run of attention layers
+    mamba: Optional[Dict[str, int]] = None
     # BLOOM lineage: per-head linear position bias applied inside the paged
     # kernels (reference csrc/transformer/inference/csrc/softmax.cu) and a
     # LayerNorm right after the embedding
@@ -133,7 +171,8 @@ def layer_runs(spec: RaggedModelSpec
             runs.append([kind, l, 1])
     return [(replace(spec, layer_kinds=None, window=kind.window,
                      rope_theta=spec.rope_theta if kind.rope else None,
-                     moe=spec.moe if kind.moe else None), l0, n)
+                     moe=spec.moe if kind.moe else None,
+                     mamba=spec.mamba if kind.mamba else None), l0, n)
             for kind, l0, n in runs]
 
 
@@ -141,9 +180,37 @@ def describe_layer_kinds(spec: RaggedModelSpec) -> str:
     """One line for the engine's set-up log."""
     return "; ".join(
         f"layers {l0}-{l0 + n - 1}: "
-        + LayerKind(rs.window, rs.rope_theta is not None,
-                    rs.moe is not None).describe()
+        + (MambaKind(rs.moe is not None) if rs.mamba is not None
+           else LayerKind(rs.window, rs.rope_theta is not None,
+                          rs.moe is not None)).describe()
         for rs, l0, n in layer_runs(spec))
+
+
+def num_state_layers(spec: RaggedModelSpec) -> int:
+    """Layers that hold a recurrent state per sequence (Mamba mixers)."""
+    return sum(n for rs, _, n in layer_runs(spec) if rs.mamba is not None)
+
+
+def num_page_layers(spec: RaggedModelSpec) -> int:
+    """Layers that hold KV pages — THE layer count of the page pool, of a
+    page's bytes and of everything counted in tokens x layers. Not
+    ``spec.num_layers`` where some layers carry no attention."""
+    return spec.num_layers - num_state_layers(spec)
+
+
+def _pool_bases(spec: RaggedModelSpec) -> List[int]:
+    """For each run of :func:`layer_runs`, its first layer's index in the
+    pool its layers address: a layer's rank among the layers of its sort
+    (pages for attention, state for Mamba). A model whose layers all hold
+    pages addresses them by the layer's index in the model, as ever."""
+    runs = layer_runs(spec)
+    if spec.mamba is None:
+        return [l0 for _, l0, _ in runs]
+    bases, seen = [], {True: 0, False: 0}
+    for rs, _, n in runs:
+        bases.append(seen[rs.mamba is not None])
+        seen[rs.mamba is not None] += n
+    return bases
 
 
 # --------------------------------------------------------------------------- #
@@ -413,6 +480,81 @@ def adapt_afmoe(params: Dict, config,
     return spec, weights
 
 
+def adapt_jamba(params: Dict, config,
+                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
+    """models/jamba.py param tree (JambaForCausalLM; AI21 Jamba).
+
+    One kind per layer from the config's ``layer_types`` (what
+    ``attn_layer_period``/``attn_layer_offset`` build): :class:`MambaKind` or
+    an attention :class:`LayerKind` without window or positions, every FFN
+    dense. A run of Mamba layers stacks
+    the mixer's matrices under their own names (``in_proj`` ... ``out_proj``)
+    where a run of attention layers has ``wq``..``wo``; ``A_log`` is stored
+    transposed, ``[N, E]``, the state's layout (ops/pallas/ssm.py)."""
+    from deepspeed_tpu.models.jamba import MAMBA
+    kinds = tuple(MambaKind() if t == MAMBA else LayerKind(None, False, False)
+                  for t in config.layer_types)
+    spec = RaggedModelSpec(
+        family="jamba",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=config.num_attention_heads,
+        num_kv_heads=config.num_key_value_heads,
+        head_dim=config.head_dim,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=None,
+        tied_lm_head=True, eps=config.rms_norm_eps,
+        layer_kinds=kinds, dtype=config.dtype,
+        mamba={"d_inner": config.mamba_d_inner,
+               "d_state": config.mamba_d_state,
+               "dt_rank": config.mamba_dt_rank,
+               "d_conv": config.mamba_d_conv} if any(
+                   k.mamba for k in kinds) else None)
+    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
+        spec = layer_runs(spec)[0][0]
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        ff = lp["feed_forward"]
+        out = {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln2": {"scale": lp["pre_ff_layernorm"]["weight"]},
+            "mlp": {"w_gate": ff["gate_proj"]["kernel"],
+                    "w_up": ff["up_proj"]["kernel"],
+                    "w_down": ff["down_proj"]["kernel"]},
+        }
+        if kinds[i].mamba:
+            m = lp["mamba"]
+            out["mamba"] = {
+                "in_proj": m["in_proj"]["kernel"],
+                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, E]
+                "conv_b": m["conv_bias"],
+                "x_proj": m["x_proj"]["kernel"],
+                "dt_norm": m["dt_layernorm"]["weight"],
+                "b_norm": m["b_layernorm"]["weight"],
+                "c_norm": m["c_layernorm"]["weight"],
+                "dt_proj": m["dt_proj"]["kernel"],
+                "dt_bias": m["dt_bias"],
+                "A_log": jnp.transpose(m["A_log"]),              # [N, E]
+                "D": m["D"],
+                "out_proj": m["out_proj"]["kernel"],
+            }
+        else:
+            attn = lp["self_attn"]
+            out.update(wq=attn["q_proj"]["kernel"], wk=attn["k_proj"]["kernel"],
+                       wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"])
+        return out
+
+    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
+                   for _, l0, n in layer_runs(spec))
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": stacks if spec.layer_kinds is not None else stacks[0],
+        "final_norm": {"scale": params["final_layernorm"]["weight"]},
+    }
+    return spec, weights
+
+
 ADAPTERS: Dict[str, Callable] = {
     # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
     # LlamaConfig features the adapter reads)
@@ -433,6 +575,9 @@ ADAPTERS: Dict[str, Callable] = {
     # layers of several kinds in one model (window+rotary / full without
     # positions; dense / MoE), gated attention, sigmoid router, shared expert
     "afmoe": adapt_afmoe,
+    # Mamba state-space layers beside a few attention layers: a state pool
+    # beside the pages (ragged/state_pool.py)
+    "jamba": adapt_jamba,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
@@ -540,17 +685,21 @@ def _scan_layers(spec: "RaggedModelSpec", layers, make_body, carry,
     weights. ``make_body(run_spec, experts, l0)`` returns the scan body for a
     run — built with the run's own spec, so window, rotation and FFN are
     static arguments of its kernels — and the body is handed ``(weights of
-    the layer, its index l in the whole model[, extra_xs rows of it])``: KV
-    pages are addressed by ``l``, the run's expert stacks by ``l - l0``. A
-    model of one kind is one scan over all its layers, as it always was."""
+    the layer, its index l[, extra_xs rows of it])``: KV pages are addressed
+    by ``l``, the run's expert stacks by ``l - l0``. ``l`` is the layer's
+    index in the model, except in a model with Mamba layers
+    (:func:`_pool_bases`): there an attention layer's ``l`` is its rank among
+    the attention layers (the page pool has that many layers) and a Mamba
+    layer's its rank among the Mamba layers (the state pools'). A model of
+    one kind is one scan over all its layers, as it always was."""
     stacks = layers if isinstance(layers, tuple) else (layers,)
     runs = layer_runs(spec)
     assert len(stacks) == len(runs), (len(stacks), len(runs))
-    for (run_spec, l0, n), stack in zip(runs, stacks):
+    for (run_spec, l0, n), base, stack in zip(runs, _pool_bases(spec), stacks):
         scanned, experts = _split_expert_stacks(stack)
-        xs = (scanned, jnp.arange(l0, l0 + n, dtype=jnp.int32)) + tuple(
+        xs = (scanned, jnp.arange(base, base + n, dtype=jnp.int32)) + tuple(
             x[l0:l0 + n] for x in extra_xs)
-        carry, _ = jax.lax.scan(make_body(run_spec, experts, l0), carry, xs)
+        carry, _ = jax.lax.scan(make_body(run_spec, experts, base), carry, xs)
     return carry
 
 
@@ -864,6 +1013,128 @@ def _quantize_layer_stack(layers: Dict, q) -> None:
                 moe[key] = q(moe[key])
 
 
+class _StateRows(NamedTuple):
+    """Whose recurrent state each row of a program reads and writes: the
+    pass's chunk slots first (``NC`` slots of equal size; None where the
+    program has no prompt rows), then one row per decode sequence (None
+    where it has none). Slots, modes and token counts as
+    ``RaggedBatch.chunk_state_*`` / ``chunk_ntok`` / ``decode_state_slot``."""
+    chunk_slot: Any = None      # [NC] int32
+    chunk_mode: Any = None      # [NC] int32: 0 zero, 1 the pool, 2 the slot before
+    chunk_ntok: Any = None      # [NC] int32
+    decode_slot: Any = None     # [S] int32
+
+
+def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
+    """The Mamba-1 mixer (Jamba's: RMSNorm on dt, B and C) on the normed
+    rows ``u`` ``[T, hid]`` of one layer, reading and updating its rows'
+    states in the pools ``state = (ssm [Lm, NS+1, N, E], conv [Lm, NS+1,
+    (K-1)*8, E/8])`` (float32; ragged/state_pool.py) at layer ``l`` of them. Returns ``(out [T, hid],
+    ssm, conv)``.
+
+    Prompt rows run the chunked scan slot by slot (scope ``ssm/scan``): a
+    chunk slot starts from zero, from the pool or from the slot before it
+    (``rows.chunk_mode``), rows past its token count leave the state alone
+    (their ``dt`` is zeroed), and only a sequence's last slot of the pass
+    writes the pool. Decode rows are segments of one token (``ssm/step``).
+    The convolution reads its ``K - 1`` predecessors from the rows before, the
+    slot before or the pool's tail. ``dt``, ``exp(dt A)``, ``h`` and ``y``
+    are float32, the matrices and the tail the model's dtype."""
+    m, mw = spec.mamba, w["mamba"]
+    E, N, R, K = m["d_inner"], m["d_state"], m["dt_rank"], m["d_conv"]
+    dtype = spec.dtype
+    ssm, conv = state
+    NS1 = ssm.shape[1]
+    dump = NS1 - 1
+    # all layers' slots in one list (merging the leading dimensions is a
+    # view); a slot's tile rows are its K - 1 taps x E channels in order,
+    # so the rows GATHERED from it reshape to [n, K - 1, E] (a small copy —
+    # reshaping the pool itself so would lay it out anew, in every layer)
+    conv2 = conv.reshape((-1,) + conv.shape[2:])
+    taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, E)
+    f32 = jnp.float32
+    conv_w, conv_b = mw["conv_w"].astype(f32), mw["conv_b"].astype(f32)
+
+    with jax.named_scope("in_proj"):
+        az = _mm(u, mw["in_proj"])
+    a, z = az[:, :E], az[:, E:]
+
+    def conv_act(ext):          # [.., K + n - 1, E] inputs -> [.., n, E]
+        n = ext.shape[-2] - (K - 1)
+        acc = conv_b + sum(ext[..., j:j + n, :].astype(f32) * conv_w[j]
+                           for j in range(K))
+        return jax.nn.silu(acc).astype(dtype)
+
+    parts, CT = [], 0
+    with jax.named_scope("conv"):
+        if rows.chunk_slot is not None:
+            NC = rows.chunk_slot.shape[0]
+            CT = a.shape[0] - (0 if rows.decode_slot is None
+                               else rows.decode_slot.shape[0])
+            Cs = CT // NC
+            mode = rows.chunk_mode
+            a_c = a[:CT].reshape(NC, Cs, E)
+            pool_rows = l * NS1 + rows.chunk_slot
+            # a sequence's last slot of the pass writes back; the others
+            # (and empty slots) write the dump slot
+            last = jnp.concatenate([mode[1:] != 2, jnp.ones((1,), bool)])
+            store_rows = l * NS1 + jnp.where(last, rows.chunk_slot, dump)
+            tail = jnp.where(
+                (mode == 2)[:, None, None],
+                jnp.roll(a_c[:, Cs - (K - 1):], 1, axis=0),
+                jnp.where((mode == 1)[:, None, None], taps(pool_rows), 0))
+            ext = jnp.concatenate([tail.astype(dtype), a_c], axis=1)
+            parts.append(conv_act(ext).reshape(CT, E))
+            new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice(
+                e, (n, 0), (K - 1, E)))(ext, rows.chunk_ntok)
+            conv2 = conv2.at[store_rows].set(
+                new_tail.astype(conv.dtype).reshape((NC,) + conv.shape[2:]))
+        if rows.decode_slot is not None:
+            # the rows' tails are read here; their shift by one token rides
+            # with the recurrence kernel below
+            drows = l * NS1 + rows.decode_slot
+            ext = jnp.concatenate([taps(drows).astype(dtype),
+                                   a[CT:, None]], axis=1)        # [S, K, E]
+            parts.append(conv_act(ext)[:, 0])
+    conv = conv2.reshape(conv.shape)
+    c = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+    rbc = _mm(c, mw["x_proj"])
+    r = _norm(rbc[:, :R], {"scale": mw["dt_norm"]}, "rms", spec.eps, dtype)
+    Bm = _norm(rbc[:, R:R + N], {"scale": mw["b_norm"]}, "rms", spec.eps,
+               dtype).astype(f32)
+    Cm = _norm(rbc[:, R + N:], {"scale": mw["c_norm"]}, "rms", spec.eps,
+               dtype).astype(f32)
+    dt = jax.nn.softplus(_mm(r, mw["dt_proj"]).astype(f32)
+                         + mw["dt_bias"].astype(f32))
+    A = -jnp.exp(mw["A_log"].astype(f32))                       # [N, E]
+    cf = c.astype(f32)
+
+    ys = []
+    if rows.chunk_slot is not None:
+        with jax.named_scope("scan"):
+            live = (jnp.arange(Cs)[None, :]
+                    < rows.chunk_ntok[:, None]).reshape(CT, 1)
+            flat = ssm.reshape(-1, N, E)
+            h0 = jnp.where((mode == 1)[:, None, None], flat[pool_rows], 0.0)
+            y, hT = ssm_chunk_scan(jnp.where(live, dt[:CT], 0.0), cf[:CT],
+                                   Bm[:CT], Cm[:CT], A, h0,
+                                   (mode == 2).astype(jnp.int32))
+            ssm = flat.at[store_rows].set(hT).reshape(ssm.shape)
+            ys.append(y)
+    if rows.decode_slot is not None:
+        with jax.named_scope("step"):
+            y, ssm, conv = ssm_decode_step(
+                ssm, conv, l, rows.decode_slot, dt[CT:], cf[CT:], Bm[CT:],
+                Cm[CT:], A, a[CT:])
+            ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    y = (y + mw["D"].astype(f32) * cf) * jax.nn.silu(z.astype(f32))
+    with jax.named_scope("out_proj"):
+        out = _mm(y.astype(dtype), mw["out_proj"])
+    return out, ssm, conv
+
+
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                        lora=None, experts=None, l=0):
     """Shared per-layer transformer body for BOTH the ragged forward (put
@@ -879,50 +1150,59 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     """
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
-    # the two halves carry scopes: a device trace tells the layer's
-    # attention (projections, rope, KV write, kernel) from its FFN
-    with jax.named_scope("attn"):
-        h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype, spec.norm_plus_one)
-        # The projections' [N, out] results stay values of their own. Left
-        # to fold the reshape to heads (and the rotation after it) into the
-        # dot's output layout, the TPU compiler asks for a transposed weight:
-        # it then reads layer l of wq, wk and wv out of the stack into
-        # on-chip memory and copies each transposed there before a dot runs
-        # (2.3 ms of a 12 ms Mistral-7B decode step on a v5e; 1.15 fused).
-        # Behind the barrier the dot's fusion takes the stack and l, as wo's
-        # and the FFN's do.
-        q, k, v = jax.lax.optimization_barrier((
-            _lora_mm(h1, w["wq"], lora, "q"), _lora_mm(h1, w["wk"], lora, "k"),
-            _lora_mm(h1, w["wv"], lora, "v")))
-        q = q.reshape(-1, H, D)
-        k = k.reshape(-1, Hkv, D)
-        v = v.reshape(-1, Hkv, D)
-        if "bq" in w:
-            q = q + w["bq"].reshape(H, D)
-            k = k + w["bk"].reshape(Hkv, D)
-            v = v + w["bv"].reshape(Hkv, D)
-        if "q_norm" in w:       # RMSNorm over each head's values (afmoe)
-            q = _norm(q, {"scale": w["q_norm"]}, "rms", spec.eps, dtype)
-            k = _norm(k, {"scale": w["k_norm"]}, "rms", spec.eps, dtype)
-        if spec.rope_theta is not None:
-            q = _rope_flat(q, positions, spec.rope_theta, spec.rotary_dim)
-            k = _rope_flat(k, positions, spec.rope_theta, spec.rotary_dim)
+    if spec.mamba is not None:
+        # a layer whose mixer is no attention: ``attend(normed rows) ->
+        # (mixer output [N, hid], *state)`` runs :func:`_mamba_mixer` with
+        # the caller's rows and carried state pools
+        with jax.named_scope("ssm"):
+            h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
+                       spec.norm_plus_one)
+            attn_out, *state = attend(h1)
+    else:
+        # the two halves carry scopes: a device trace tells the layer's
+        # attention (projections, rope, KV write, kernel) from its FFN
+        with jax.named_scope("attn"):
+            h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype, spec.norm_plus_one)
+            # The projections' [N, out] results stay values of their own. Left
+            # to fold the reshape to heads (and the rotation after it) into the
+            # dot's output layout, the TPU compiler asks for a transposed weight:
+            # it then reads layer l of wq, wk and wv out of the stack into
+            # on-chip memory and copies each transposed there before a dot runs
+            # (2.3 ms of a 12 ms Mistral-7B decode step on a v5e; 1.15 fused).
+            # Behind the barrier the dot's fusion takes the stack and l, as wo's
+            # and the FFN's do.
+            q, k, v = jax.lax.optimization_barrier((
+                _lora_mm(h1, w["wq"], lora, "q"), _lora_mm(h1, w["wk"], lora, "k"),
+                _lora_mm(h1, w["wv"], lora, "v")))
+            q = q.reshape(-1, H, D)
+            k = k.reshape(-1, Hkv, D)
+            v = v.reshape(-1, Hkv, D)
+            if "bq" in w:
+                q = q + w["bq"].reshape(H, D)
+                k = k + w["bk"].reshape(Hkv, D)
+                v = v + w["bv"].reshape(Hkv, D)
+            if "q_norm" in w:       # RMSNorm over each head's values (afmoe)
+                q = _norm(q, {"scale": w["q_norm"]}, "rms", spec.eps, dtype)
+                k = _norm(k, {"scale": w["k_norm"]}, "rms", spec.eps, dtype)
+            if spec.rope_theta is not None:
+                q = _rope_flat(q, positions, spec.rope_theta, spec.rotary_dim)
+                k = _rope_flat(k, positions, spec.rope_theta, spec.rotary_dim)
 
-        # KV page write + kernel, by the layer's kind of attention
-        with jax.named_scope("attn_full" if spec.window is None
-                             else "attn_window"):
-            attn_raw, *state = attend(q, k, v)
-        attn_raw = attn_raw.reshape(-1, H * D)
-        if "wg" in w:           # output gate from the normed input (afmoe)
-            with jax.named_scope("gate"):
-                gate = jax.nn.sigmoid(_mm(h1, w["wg"]).astype(jnp.float32))
-                attn_raw = (attn_raw * gate).astype(dtype)
-        attn_out = _lora_mm(attn_raw, w["wo"], lora, "o")
-        if "bo" in w:
-            attn_out = attn_out + w["bo"]
-        if "ln1_post" in w:     # sandwich norm: the branch's output, normed
-            attn_out = _norm(attn_out, w["ln1_post"], spec.norm, spec.eps,
-                             dtype, spec.norm_plus_one)
+            # KV page write + kernel, by the layer's kind of attention
+            with jax.named_scope("attn_full" if spec.window is None
+                                 else "attn_window"):
+                attn_raw, *state = attend(q, k, v)
+            attn_raw = attn_raw.reshape(-1, H * D)
+            if "wg" in w:           # output gate from the normed input (afmoe)
+                with jax.named_scope("gate"):
+                    gate = jax.nn.sigmoid(_mm(h1, w["wg"]).astype(jnp.float32))
+                    attn_raw = (attn_raw * gate).astype(dtype)
+            attn_out = _lora_mm(attn_raw, w["wo"], lora, "o")
+            if "bo" in w:
+                attn_out = attn_out + w["bo"]
+            if "ln1_post" in w:     # sandwich norm: the branch's output, normed
+                attn_out = _norm(attn_out, w["ln1_post"], spec.norm, spec.eps,
+                                 dtype, spec.norm_plus_one)
 
     if spec.parallel_block:
         mlp_in = (_norm(x, w["ln2"], spec.norm, spec.eps, dtype,
@@ -1137,6 +1417,25 @@ PAGED_PASS_KEYS = (
 PREFILL_PASS_KEYS = (
     "chunk_tokens", "chunk_positions", "chunk_ntok", "decode_tokens",
     "row_seg", "page_ids", "page_rows", "page_fill")
+#: and, for a model with state-space layers, each pass's rows' state slots
+STATE_PASS_KEYS = ("chunk_state_slot", "chunk_state_mode",
+                   "decode_state_slot")
+
+
+def _mamba_body(rs: RaggedModelSpec, positions, rows: _StateRows):
+    """The scan body of a run of Mamba layers, for every serving program:
+    the carry is ``(x, *the program's KV carry, (ssm, conv))``; the KV part
+    passes through untouched and ``l`` is the layer's rank among the Mamba
+    layers (:func:`_pool_bases`)."""
+    def layer_fn(carry, scanned):
+        x, *cache, st = carry
+        w, l = scanned[:2]
+        x, st = _transformer_layer(
+            rs, w, x, positions,
+            lambda u: _mamba_mixer(rs, w, u, st, l, rows))
+        return (x, *cache, st), None
+
+    return layer_fn
 
 
 def build_ragged_forward(spec: RaggedModelSpec,
@@ -1158,12 +1457,16 @@ def build_ragged_forward(spec: RaggedModelSpec,
     dtype = spec.dtype
 
     def fwd(weights, kv_pages, b):
+        kv_pages, st0 = _state_unpack(kv_pages)
         kv_pages, kv_sc = _kv_unpack(kv_pages)
         kvq = kv_sc is not None
         NC = b["chunk_ntok"].shape[0]
         CT = b["chunk_tokens"].shape[0]
         Cs = CT // NC
         S = b["decode_tokens"].shape[0]
+        rows = None if st0 is None else _StateRows(
+            b["chunk_state_slot"], b["chunk_state_mode"], b["chunk_ntok"],
+            b["decode_state_slot"])
         L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
         kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)  # flat (bitcast);
         r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
@@ -1174,11 +1477,13 @@ def build_ragged_forward(spec: RaggedModelSpec,
         x = _embed_in(spec, weights, tokens, positions)
 
         def make_body(rs, experts, l0):
+            if rs.mamba is not None:
+                return _mamba_body(rs, positions, rows)
             ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp,
                                      n_splits=_kind_splits(spec, rs, n_splits))
 
             def layer_fn(carry, scanned):
-                x, kvp, sc = carry
+                x, kvp, sc, st = carry
                 w, l = scanned
 
                 def attend(q, k, v):
@@ -1203,15 +1508,16 @@ def build_ragged_forward(spec: RaggedModelSpec,
 
                 x, (kvp, sc) = _transformer_layer(rs, w, x, positions, attend,
                                                   experts=experts, l=l - l0)
-                return (x, kvp, sc), None
+                return (x, kvp, sc, st), None
 
             return layer_fn
 
-        x, kvp, sc = _scan_layers(spec, weights["layers"], make_body,
-                                  (x, kvp0, sc0))
+        x, kvp, sc, st = _scan_layers(spec, weights["layers"], make_body,
+                                      (x, kvp0, sc0, st0))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
+        new_kv = _state_pack(new_kv, st)
 
         x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                   spec.norm_plus_one)
@@ -1249,8 +1555,13 @@ def build_prefill_forward(spec: RaggedModelSpec,
         CT = b["chunk_tokens"].shape[0]
         Cs = CT // NC
         S = b["decode_tokens"].shape[0]
+        kv_pages, st0 = _state_unpack(kv_pages)
         kv_pages, kv_sc = _kv_unpack(kv_pages)
         kvq = kv_sc is not None
+        # a pass from position 0 only: every chunk slot's state starts from
+        # zero or from the slot before it, and there are no decode rows
+        rows = None if st0 is None else _StateRows(
+            b["chunk_state_slot"], b["chunk_state_mode"], b["chunk_ntok"])
         L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
         kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)
         r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
@@ -1262,10 +1573,12 @@ def build_prefill_forward(spec: RaggedModelSpec,
         x = _embed_in(spec, weights, tokens, positions)
 
         def make_body(rs, experts, l0):
+            if rs.mamba is not None:
+                return _mamba_body(rs, positions, rows)
             ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp)
 
             def layer_fn(carry, scanned):
-                x, kvp, sc = carry
+                x, kvp, sc, st = carry
                 w, l = scanned
 
                 def attend(q, k, v):
@@ -1288,15 +1601,16 @@ def build_prefill_forward(spec: RaggedModelSpec,
 
                 x, (kvp, sc) = _transformer_layer(rs, w, x, positions, attend,
                                                   experts=experts, l=l - l0)
-                return (x, kvp, sc), None
+                return (x, kvp, sc, st), None
 
             return layer_fn
 
-        x, kvp, sc = _scan_layers(spec, weights["layers"], make_body,
-                                  (x, kvp0, sc0))
+        x, kvp, sc, st = _scan_layers(spec, weights["layers"], make_body,
+                                      (x, kvp0, sc0, st0))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
+        new_kv = _state_pack(new_kv, st)
 
         x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                   spec.norm_plus_one)
@@ -1358,9 +1672,13 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
         Cb += 1
 
     def fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
-            key, temperature=1.0):
+            key, temperature=1.0, state_slots=None):
+        kv_pages, st0 = _state_unpack(kv_pages)
         kv_pages, kv_sc = _kv_unpack(kv_pages)
         kvq = kv_sc is not None
+        assert (st0 is None) == (state_slots is None), \
+            "state pools and the rows' state slots come together"
+        rows = _StateRows(decode_slot=state_slots)
         S = ids0.shape[0]
         L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
         kvp5 = kv_pages.reshape(L * NB, 2, Hkv, bs, D)
@@ -1384,10 +1702,12 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
         side_k0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
         side_v0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
 
-        def one_pass(x_ids, pos, j, sk_all, sv_all):
+        def one_pass(x_ids, pos, j, sk_all, sv_all, st):
             x = _embed_in(spec, weights, x_ids, pos)
 
             def make_body(rs, experts, l0):
+                if rs.mamba is not None:
+                    return _mamba_body(rs, pos, rows)
                 ak = AttentionKernelSpec(
                     rs, mesh=None, tp=1,
                     n_splits=_kind_splits(spec, rs, n_splits))
@@ -1397,7 +1717,7 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
                     # updates — as scan xs/ys they are repacked (a full
                     # side-buffer copy per step, measured slower than the
                     # scatter they replace)
-                    x, sk_all, sv_all = carry
+                    x, sk_all, sv_all, st = carry
                     w, l = scanned
 
                     def attend(q, k, v):
@@ -1433,30 +1753,31 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
 
                     x, (sk_all, sv_all) = _transformer_layer(
                         rs, w, x, pos, attend, experts=experts, l=l - l0)
-                    return (x, sk_all, sv_all), None
+                    return (x, sk_all, sv_all, st), None
 
                 return layer_fn
 
-            x, sk_new, sv_new = _scan_layers(spec, weights["layers"],
-                                             make_body, (x, sk_all, sv_all))
+            x, sk_new, sv_new, st = _scan_layers(
+                spec, weights["layers"], make_body, (x, sk_all, sv_all, st))
             x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                       spec.norm_plus_one)
-            return _unembed(spec, weights, x), sk_new, sv_new
+            return _unembed(spec, weights, x), sk_new, sv_new, st
 
         def sample(logits, step_key):
             return _sample_logits(logits, step_key, do_sample, top_k,
                                   temperature)
 
         def step(carry, j):
-            ids, pos, sk_all, sv_all, _ = carry
-            logits, sk_all, sv_all = one_pass(ids, pos, j, sk_all, sv_all)
+            ids, pos, sk_all, sv_all, st, _ = carry
+            logits, sk_all, sv_all, st = one_pass(ids, pos, j, sk_all,
+                                                  sv_all, st)
             nxt = sample(logits, jax.random.fold_in(key, j))
-            return (nxt, pos + 1, sk_all, sv_all, logits), ids
+            return (nxt, pos + 1, sk_all, sv_all, st, logits), ids
 
         V = weights["embed"].shape[0]
         init_logits = jnp.zeros((S, V), jnp.float32)
-        (_, _, sk_all, sv_all, final_logits), out_ids = jax.lax.scan(
-            step, (ids0, positions0, side_k0, side_v0, init_logits),
+        (_, _, sk_all, sv_all, st, final_logits), out_ids = jax.lax.scan(
+            step, (ids0, positions0, side_k0, side_v0, st0, init_logits),
             jnp.arange(C))
 
         # ---- chunk-end flush: the side buffers' rows -> the pool ---- #
@@ -1469,7 +1790,7 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
             new_kv = paged_kv_row_write(kv_pages, sk_all, sv_all,
                                         block_tables, prefix, C,
                                         kv_scales=kv_sc)
-        return (out_ids, final_logits, new_kv)
+        return (out_ids, final_logits, _state_pack(new_kv, st))
 
     return fwd
 
@@ -1539,7 +1860,7 @@ def build_multistep_decode(spec: RaggedModelSpec, n_steps: int,
 
     def fwd(weights, kv_pages, ids0, *rest, **kw):
         S = ids0.shape[0]
-        L = _kv_unpack(kv_pages)[0].shape[0]
+        L = _kv_unpack(_state_unpack(kv_pages)[0])[0].shape[0]
         side_bytes = (2 * L * S * n_steps * spec.num_kv_heads
                       * spec.head_dim * esize)
         impl = sidebuf if side_bytes <= budget else general
@@ -1665,6 +1986,11 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
     ``final_logits`` predict ``next_ids``'s successor source row (the
     engine's continuation refs).
     """
+    if spec.mamba is not None:
+        from deepspeed_tpu.inference.v2.scheduler import STATE_SNAPSHOT_MSG
+        raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
+            what="the speculative verify step (rejected drafts have already "
+            "advanced the state; rolling back needs the state before them)"))
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
     K1 = k + 1
@@ -1790,10 +2116,17 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
     dtype = spec.dtype
 
     def fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
-            key, temperature=1.0, *lora_args):
+            key, temperature=1.0, *lora_args, state_slots=None):
+        kv_pages, st0 = _state_unpack(kv_pages)
         kv_pages, kv_sc = _kv_unpack(kv_pages)
         kvq = kv_sc is not None
         assert not (kvq and tp > 1), "int8 KV pages + TP not wired"
+        rows = None
+        if st0 is not None:
+            # (LoRA is refused beside state-space layers)
+            assert state_slots is not None and not lora_args, \
+                "state pools and the rows' state slots come together"
+            rows = _StateRows(decode_slot=state_slots)
         S = ids0.shape[0]
         L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
         r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
@@ -1807,7 +2140,7 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
             assert not lora_args, "lora operands on a non-LoRA program"
             lora_ops = None
 
-        def one_pass(x_ids, pos, ctx, kvp, sc):
+        def one_pass(x_ids, pos, ctx, kvp, sc, st):
             # kvp flat [L*NB*2*Hkv*bs, D]. The attention + page-write is one
             # fused unit (paged_decode_attention_step): pool aliased through
             # the kernel, new rows scattered in place after — the pool flows
@@ -1816,12 +2149,14 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
             x = _embed_in(spec, weights, x_ids, pos)
 
             def make_body(rs, experts, l0):
+                if rs.mamba is not None:
+                    return _mamba_body(rs, pos, rows)
                 ak = AttentionKernelSpec(
                     rs, mesh=mesh, tp=tp,
                     n_splits=_kind_splits(spec, rs, n_splits))
 
                 def layer_fn(carry, scanned):
-                    x, kvp, sc = carry
+                    x, kvp, sc, st = carry
                     if lora_ops is not None:
                         w, l, lora_l = scanned
                         lora = _lora_split(spec, lora_targets, lora_l)
@@ -1854,38 +2189,47 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
                     x, (kvp, sc) = _transformer_layer(
                         rs, w, x, pos, attend, lora=lora, experts=experts,
                         l=l - l0)
-                    return (x, kvp, sc), None
+                    return (x, kvp, sc, st), None
 
                 return layer_fn
 
-            x, kvp, sc = _scan_layers(
-                spec, weights["layers"], make_body, (x, kvp, sc),
+            x, kvp, sc, st = _scan_layers(
+                spec, weights["layers"], make_body, (x, kvp, sc, st),
                 extra_xs=() if lora_ops is None else (lora_ops,))
             x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                       spec.norm_plus_one)
             logits = _unembed(spec, weights, x)
-            return logits, kvp, sc
+            return logits, kvp, sc, st
 
         def sample(logits, step_key):
             return _sample_logits(logits, step_key, do_sample, top_k,
                                   temperature)
 
         def step(carry, j):
-            ids, pos, ctx, kvp, sc, _ = carry
-            logits, kvp, sc = one_pass(ids, pos, ctx, kvp, sc)
+            ids, pos, ctx, kvp, sc, st, _ = carry
+            logits, kvp, sc, st = one_pass(ids, pos, ctx, kvp, sc, st)
             nxt = sample(logits, jax.random.fold_in(key, j))
-            return (nxt, pos + 1, ctx + 1, kvp, sc, logits), ids
+            return (nxt, pos + 1, ctx + 1, kvp, sc, st, logits), ids
 
         V = weights["embed"].shape[0]
         init_logits = jnp.zeros((ids0.shape[0], V), jnp.float32)
         kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)
         sc0 = kv_sc.reshape(L * NB * r8 * 128) if kvq else None
-        (_, _, _, kvp, sc, final_logits), out_ids = jax.lax.scan(
-            step, (ids0, positions0, ctx0, kvp0, sc0, init_logits),
+        (_, _, _, kvp, sc, st, final_logits), out_ids = jax.lax.scan(
+            step, (ids0, positions0, ctx0, kvp0, sc0, st0, init_logits),
             jnp.arange(n_steps))
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
-        return (out_ids, final_logits, new_kv)
+        return (out_ids, final_logits, _state_pack(new_kv, st))
 
-    return fwd
+    if spec.mamba is None:
+        return fwd
+
+    def fwd_state(weights, kv_pages, ids0, positions0, block_tables, ctx0,
+                  key, temperature=1.0, state_slots=None):
+        """The side-buffer form's signature: the rows' state slots by name."""
+        return fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
+                   key, temperature, state_slots=state_slots)
+
+    return fwd_state

@@ -24,6 +24,17 @@ from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
+from deepspeed_tpu.inference.v2.ragged.state_pool import StateSlotAllocator
+from deepspeed_tpu.monitor.trace import tracer as _tracer
+
+#: what every feature that needs a copy of a sequence's recurrent state at
+#: some earlier position is refused with (docs/SERVING.md "State-space layers")
+STATE_SNAPSHOT_MSG = (
+    "{what} is not wired for a model with state-space (Mamba) layers: a "
+    "layer's recurrent state is one fixed-size value per sequence that every "
+    "token overwrites, so pages of an earlier position have no state to go "
+    "with them — it takes a snapshot of the state at a block boundary, which "
+    "no program writes")
 
 if TYPE_CHECKING:  # avoid an import cycle at runtime
     from deepspeed_tpu.inference.v2.prefix_cache import RadixPrefixCache
@@ -61,6 +72,14 @@ class DynamicSplitFuseScheduler:
         # engine when speculative decoding is on: the n-gram proposer drafts
         # from each sequence's prompt history, spec/proposer.py)
         self.record_history_always = False
+        # recurrent-state slots (ragged/state_pool.py), set by the engine for
+        # a model with state-space layers: one per tracked sequence, taken
+        # here at admission, freed at flush
+        self.state_slots: "Optional[StateSlotAllocator]" = None
+
+    @property
+    def _dump_slot(self) -> int:
+        return 0 if self.state_slots is None else self.state_slots.total
 
     @property
     def _pass_take_cap(self) -> int:
@@ -113,6 +132,9 @@ class DynamicSplitFuseScheduler:
                 raise RuntimeError(
                     f"max_tracked_sequences={self.config.max_tracked_sequences} exceeded")
             seq = self.seqs[uid] = DSSequenceDescriptor(uid=uid)
+            if self.state_slots is not None:
+                seq.state_slot = self.state_slots.take()
+                _tracer.bump("serve/state_slots/taken")
             if self.prefix_cache is not None:
                 seq.weight_version = self.prefix_cache.weight_version
         if self._cache_active or self.record_history_always:
@@ -141,6 +163,11 @@ class DynamicSplitFuseScheduler:
         next matching prompt — instead of the free list; eviction reclaims
         them under pool pressure."""
         seq = self.seqs.pop(uid, None)
+        if seq is not None and seq.state_slot >= 0:
+            # what the slot holds stays there: the next sequence to take it
+            # starts from zero (RaggedBatch.chunk_state_mode 0)
+            self.state_slots.free(seq.state_slot)
+            _tracer.bump("serve/state_slots/freed")
         if seq is None or not seq.blocks:
             return
         # ring reuse repeats physical ids in the logical list — settle each once
@@ -283,6 +310,10 @@ class DynamicSplitFuseScheduler:
                 "cross-engine KV adoption with a sliding-window page ring "
                 "is not wired (the logical block list aliases physical "
                 "pages)")
+        if self.state_slots is not None:
+            raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
+                what="adopting a sequence whose pages were computed elsewhere"
+                " (import_kv)"))
         tokens = np.asarray(tokens, np.int32)
         if uid in self.seqs:
             raise ValueError(f"sequence {uid} is already tracked")
@@ -362,9 +393,14 @@ class DynamicSplitFuseScheduler:
             pos[i] = seq.seen_tokens
         # pad rows: pos 0 -> ctx 1, attending exactly one (scratch) token
         ctx = pos + 1
+        slots = None
+        if self.state_slots is not None:
+            slots = np.full((bucket,), self._dump_slot, np.int32)
+            slots[:len(uids)] = [self.seqs[u].state_slot for u in uids]
         from deepspeed_tpu.inference.v2.ragged.ragged_batch import DecodeBatch
         return DecodeBatch(uids=[int(u) for u in uids], bucket=bucket,
-                           positions=pos, block_tables=bt, ctx_lens=ctx)
+                           positions=pos, block_tables=bt, ctx_lens=ctx,
+                           state_slots=slots)
 
     def advance(self, uid: int, n_tokens: int) -> None:
         """Record ``n_tokens`` device-generated tokens (their KV was written
@@ -439,7 +475,7 @@ class DynamicSplitFuseScheduler:
         S, MB = cfg.max_ragged_sequence_count, self.max_blocks
         bs = self.cache.config.block_size
         batch = RaggedBatch(num_slots=NC, slot_size=Cs, max_sequences=S,
-                            max_blocks=MB)
+                            max_blocks=MB, dump_slot=self._dump_slot)
         kv_dest = np.full((NC * Cs + S,), self.cache.oob_sentinel, np.int32)
 
         # decode rows: sequences holding exactly one pending token
@@ -454,6 +490,8 @@ class DynamicSplitFuseScheduler:
             batch.decode_positions[row] = pos
             batch.decode_block_tables[row] = seq.block_table(MB)
             batch.decode_ctx_lens[row] = pos + 1
+            if seq.state_slot >= 0:
+                batch.decode_state_slot[row] = seq.state_slot
             kv_dest[NC * Cs + row] = self.cache.flat_write_index(
                 seq.blocks[pos // bs], pos % bs)
             seq.in_flight_tokens = 1
@@ -520,6 +558,10 @@ class DynamicSplitFuseScheduler:
                 batch.chunk_block_tables[sl] = seq.block_table(MB)
                 batch.chunk_q0[sl] = q0
                 batch.chunk_ctx_lens[sl] = q0 + n
+                if seq.state_slot >= 0:
+                    batch.chunk_state_slot[sl] = seq.state_slot
+                    batch.chunk_state_mode[sl] = (2 if taken else
+                                                  1 if q0 else 0)
                 batch.row_seg[r0:r0 + n] = len(batch.chunk_uids) - 1
                 kv_dest[r0:r0 + n] = self.cache.flat_write_index(
                     blocks[positions // bs], positions % bs)

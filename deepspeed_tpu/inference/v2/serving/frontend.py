@@ -257,6 +257,13 @@ class ServingFrontend:
                 "preemption with a sliding-window page ring is not wired "
                 "(the logical block list aliases physical pages) — run "
                 "preemption='none'")
+        if cfg.preemption == "offload" \
+                and engine.scheduler.state_slots is not None:
+            from deepspeed_tpu.inference.v2.scheduler import (
+                STATE_SNAPSHOT_MSG)
+            raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
+                what="preemption='offload' (pages go to the host, the state "
+                "would not; run 'recompute' or 'none')"))
         if cfg.preemption == "recompute" and getattr(engine, "lora", None) \
                 is not None:
             raise NotImplementedError(

@@ -9,6 +9,7 @@ from deepspeed_tpu.models.bert import BertConfig, BertForMaskedLM
 from deepspeed_tpu.models.decoder import (DecoderConfig, DecoderLM,
                                           init_decoder_cache)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, init_cache
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.diffusion import (DiffusionConfig,

@@ -170,13 +170,23 @@ def _colscale_pages(mat, tile_ref, n_pages, nsub, off):
     return jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
 
 
+#: the most pages a chunk holds, whatever the bytes allow: the body issues,
+#: awaits and zeroes a chunk's page copies one by one, unrolled at six places,
+#: so its trace and its code grow with P. By bytes alone one KV head of 128
+#: would take 63 pages a chunk (8k tokens: a 2k context computes 8k), and a
+#: 28-layer decode step then traced for 13 s a bucket on the chip's host
+#: (PERF.md, PR 31). 8 KV heads take 7 and 4 take 15: under the cap.
+MAX_PAGES_PER_CHUNK = 32
+
+
 def _pick_pages_per_chunk(bs: int, h_kv: int, d: int, esize: int,
                           max_blocks: int, reserve_bytes: int = 0,
                           scale_tile_rows: int = 0, flash_heads: int = 0,
                           out_bytes: int = 0) -> int:
     """Largest P with the 2-slot combined-KV slabs within ~8 MB of VMEM
-    (~16 MB on v5e; q blocks and score tiles are small). Fatter chunks
-    amortise the per-grid-step fixed cost, the dominant decode overhead.
+    (~16 MB on v5e; q blocks and score tiles are small), up to
+    ``MAX_PAGES_PER_CHUNK``. Fatter chunks amortise the per-grid-step fixed
+    cost, the dominant decode overhead.
 
     ``reserve_bytes``: VMEM the caller holds besides the page slabs (the
     sidebuf kernel's side slabs). ``flash_heads``: H of the f32 flash
@@ -195,7 +205,7 @@ def _pick_pages_per_chunk(bs: int, h_kv: int, d: int, esize: int,
     per_page = 2 * 2 * bs * h_kv * d * esize     # 2 slots x (K + V)
     if scale_tile_rows:
         per_page += 2 * scale_tile_rows * 128 * 4  # 2 slots x scale tile
-    return max(1, min(max_blocks, budget // per_page))
+    return max(1, min(max_blocks, budget // per_page, MAX_PAGES_PER_CHUNK))
 
 
 def _alibi_slope(head, H: int):

@@ -1,0 +1,277 @@
+"""Selective state-space (Mamba-1) recurrence kernels for the serving path.
+
+The recurrence of one layer, per token ``t`` and channel ``e`` (``N`` state
+values a channel)::
+
+    h_t[n, e] = exp(dt_t[e] * A[n, e]) * h_{t-1}[n, e] + dt_t[e] * x_t[e] * B_t[n]
+    y_t[e]    = sum_n h_t[n, e] * C_t[n]
+
+all in float32. It is vector-unit work with no matrix product in it, and what
+bounds it is the state: ``[N, E]`` float32 per sequence and layer (320 KiB at
+``E`` 5120, ``N`` 16). The layout puts ``E`` on the lanes and ``N`` on the
+sublanes — ``[.., N, E]`` tiles with no padding; ``[.., E, N]`` would pad 16
+lanes to 128, eight times the bytes — and ``B_t``/``C_t`` reach the kernels
+already spread over 128 lanes (``[T, N, 128]``), so that a kernel only
+repeats whole registers.
+
+- :func:`ssm_decode_step`: one token per row, each row with a state of its own
+  somewhere in the pool ``[Lm, slots, N, E]``. The pool is aliased through the
+  call; a grid step reads one row's state block where it lies (the layer and
+  the slot come from prefetched scalars), updates it and writes it back: one
+  read and one write of each state, nothing else of the pool touched. The
+  row's convolution tail rides along: its block of the tail pool
+  ``[Lm, slots, (K-1)*8, E/8]`` comes in, drops its oldest tap, takes the
+  row's new input as its newest and goes back. Left to XLA, that update was
+  a row scatter of 30 KiB rows, which costs by the row: 3.3 ms of a 19 ms
+  decode step at 128 rows x 26 layers (my chip run, PR 31), against nothing
+  here (the blocks move under the state's).
+- :func:`ssm_chunk_scan`: a pass's packed prompt rows, ``G`` chunk slots of
+  ``Cs`` rows. The state of a block of channels stays in on-chip memory
+  across the token blocks; at a slot's first block it is loaded from ``h0``
+  unless the slot continues the one before it (``cont``); after a slot's last
+  block it is written to ``hT``. Rows with ``dt = 0`` leave the state as it is
+  (``exp(0) = 1``, nothing added), which is how a chunk shorter than its slot
+  is padded.
+
+Each has a plain-XLA form (``*_xla``) for shapes the kernel refuses (``E`` not
+a multiple of 128, a slot size not a multiple of 8) and as what the tests
+hold the kernels to. On the CPU the kernels run through the Pallas
+interpreter (``_backend.interpret()``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from deepspeed_tpu.ops.pallas import _backend
+from deepspeed_tpu.utils.jax_compat import import_pltpu
+
+pltpu = import_pltpu()
+
+LANES = 128
+
+
+def lane_spread(v: jax.Array) -> jax.Array:
+    """``[T, N]`` -> ``[T, N, 128]`` float32, each value on all 128 lanes."""
+    return jnp.broadcast_to(v.astype(jnp.float32)[..., None],
+                            v.shape + (LANES,))
+
+
+def _channel_block(E: int) -> int:
+    for eb in (512, 256, 128):
+        if E % eb == 0:
+            return eb
+    return 0
+
+
+# --------------------------------------------------------------------------- #
+# one token per row, state in the pool
+# --------------------------------------------------------------------------- #
+
+TAP_ROWS = 8      # sublane rows one tap of a convolution tail takes
+
+
+def _decode_kernel(l_ref, slot_ref, dt_ref, x_ref, b_ref, c_ref, a_ref,
+                   new_ref, h_ref, t_ref, y_ref, ho_ref, to_ref):
+    del l_ref, slot_ref               # read by the index maps
+    dt = dt_ref[0]                                            # [1, E]
+    reps = a_ref.shape[1] // LANES
+    h = (jnp.exp(dt * a_ref[...]) * h_ref[0, 0]
+         + (dt * x_ref[0]) * pltpu.repeat(b_ref[0], reps, axis=1))
+    ho_ref[0, 0] = h
+    y_ref[0] = jnp.sum(h * pltpu.repeat(c_ref[0], reps, axis=1), axis=0,
+                       keepdims=True)
+    # the tail: taps 1.. move down one, the row's new input is the newest
+    kept = t_ref.shape[2] - TAP_ROWS
+    if kept:
+        to_ref[0, 0, :kept] = t_ref[0, 0, TAP_ROWS:]
+    to_ref[0, 0, kept:] = new_ref[0]
+
+
+def ssm_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
+                    dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
+                    A: jax.Array, new: jax.Array):
+    """One recurrence step for ``S`` rows whose states lie in ``pool``, and
+    the shift of their convolution tails in ``tails``.
+
+    pool:  [Lm, NS, N, E] float32 — ALIASED: the returned pool reuses it
+    tails: [Lm, NS, (K-1)*8, E/8] float32 — ALIASED: tap ``j`` of a slot is
+           its rows ``8j..8j+7``, channel ``e`` at ``[e // (E/8), e % (E/8)]``
+    l:     int32 scalar, the layer of the pools this call updates
+    slots: [S] int32, each row's slot (rows that share a slot — the engine's
+           padding rows, all at its dump slot — leave any one's state there)
+    dt, x: [S, E] float32     B, C: [S, N] float32     A: [N, E] float32
+    new:   [S, E] the convolution's input at this token: each row's tail
+           drops its oldest tap and takes this as its newest
+
+    Returns ``(y [S, E] float32, pool, tails)``."""
+    Lm, NS, N, E = pool.shape
+    S = slots.shape[0]
+    TR, E8 = tails.shape[2:]
+    if E8 % LANES or N % 8:
+        return ssm_decode_step_xla(pool, tails, l, slots, dt, x, B, C, A, new)
+    row = lambda i, l_ref, s_ref: (i, 0, 0)
+    slot = lambda i, l_ref, s_ref: (l_ref[0], s_ref[i], 0, 0)
+    state = pl.BlockSpec((1, 1, N, E), slot)
+    tail = pl.BlockSpec((1, 1, TR, E8), slot)
+    call = pl.pallas_call(
+        _decode_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(S,),
+            in_specs=[pl.BlockSpec((1, 1, E), row), pl.BlockSpec((1, 1, E), row),
+                      pl.BlockSpec((1, N, LANES), row),
+                      pl.BlockSpec((1, N, LANES), row),
+                      pl.BlockSpec((N, E), lambda i, l_ref, s_ref: (0, 0)),
+                      pl.BlockSpec((1, TAP_ROWS, E8), row), state, tail],
+            out_specs=[pl.BlockSpec((1, 1, E), row), state, tail]),
+        out_shape=[jax.ShapeDtypeStruct((S, 1, E), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                   jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_backend.interpret(),
+    )
+    with jax.named_scope("ssm_decode_step"):
+        y, pool, tails = call(
+            jnp.asarray(l, jnp.int32).reshape(1), slots.astype(jnp.int32),
+            dt.astype(jnp.float32)[:, None], x.astype(jnp.float32)[:, None],
+            lane_spread(B), lane_spread(C), A.astype(jnp.float32),
+            new.astype(tails.dtype).reshape(S, TAP_ROWS, E8), pool, tails)
+    return y[:, 0], pool, tails
+
+
+def ssm_decode_step_xla(pool, tails, l, slots, dt, x, B, C, A, new):
+    """:func:`ssm_decode_step` in plain XLA: gather the rows' states and
+    tails, update, scatter them back (in place where the pools are a donated
+    carry)."""
+    Lm, NS, N, E = pool.shape
+    TR, E8 = tails.shape[2:]
+    with jax.named_scope("ssm_decode_step_xla"):
+        flat = pool.reshape(Lm * NS, N, E)
+        rows = l * NS + slots
+        dt = dt.astype(jnp.float32)
+        h = (jnp.exp(dt[:, None, :] * A[None]) * flat[rows]
+             + (dt * x)[:, None, :] * B.astype(jnp.float32)[:, :, None])
+        y = jnp.sum(h * C.astype(jnp.float32)[:, :, None], axis=1)
+        tflat = tails.reshape(Lm * NS, TR, E8)
+        shifted = jnp.concatenate(
+            [tflat[rows][:, TAP_ROWS:],
+             new.astype(tails.dtype).reshape(-1, TAP_ROWS, E8)], axis=1)
+        return (y, flat.at[rows].set(h).reshape(pool.shape),
+                tflat.at[rows].set(shifted).reshape(tails.shape))
+
+
+# --------------------------------------------------------------------------- #
+# a pass's packed prompt rows, state on chip across a slot
+# --------------------------------------------------------------------------- #
+
+def _scan_kernel(cont_ref, dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
+                 y_ref, ht_ref, h_sc, *, blocks_per_slot: int, rows: int):
+    tb, e = pl.program_id(0), pl.program_id(1)
+    g = tb // blocks_per_slot
+
+    @pl.when(jnp.logical_and(tb % blocks_per_slot == 0, cont_ref[g] == 0))
+    def _():
+        h_sc[e] = h0_ref[0]
+
+    A = a_ref[...]                                            # [N, Eb]
+    reps = A.shape[1] // LANES
+
+    def body(i, h):
+        t8 = pl.multiple_of(i * 8, 8)
+        dt8 = dt_ref[pl.ds(t8, 8), :]                         # [8, Eb]
+        dx8 = dt8 * x_ref[pl.ds(t8, 8), :]
+        b8 = b_ref[pl.ds(t8, 8)]                              # [8, N, 128]
+        c8 = c_ref[pl.ds(t8, 8)]
+        ys = []
+        for k in range(8):
+            h = (jnp.exp(dt8[k:k + 1] * A) * h
+                 + dx8[k:k + 1] * pltpu.repeat(b8[k], reps, axis=1))
+            ys.append(jnp.sum(h * pltpu.repeat(c8[k], reps, axis=1), axis=0,
+                              keepdims=True))
+        y_ref[pl.ds(t8, 8), :] = jnp.concatenate(ys, axis=0)
+        return h
+
+    h = jax.lax.fori_loop(0, rows // 8, body, h_sc[e])
+    h_sc[e] = h
+    ht_ref[0] = h
+
+
+def ssm_chunk_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
+                   A: jax.Array, h0: jax.Array, cont: jax.Array):
+    """The recurrence over ``G`` chunk slots of ``Cs`` packed rows each.
+
+    dt, x: [G*Cs, E] float32 (``dt`` zero on rows that hold no token)
+    B, C:  [G*Cs, N] float32     A: [N, E] float32
+    h0:    [G, N, E] float32, the state a slot starts from
+    cont:  [G] int32, non-zero where a slot takes over the state the slot
+           before it ended with (same sequence, next ``Cs`` tokens) and
+           ignores ``h0``; slot 0 never does
+
+    Returns ``(y [G*Cs, E] float32, hT [G, N, E] float32)``: ``hT[g]`` is the
+    state after slot ``g``'s last row."""
+    G, N, E = h0.shape
+    T = dt.shape[0]
+    Cs = T // G
+    Eb = _channel_block(E)
+    if not Eb or Cs % 8 or N % 8:
+        return ssm_chunk_scan_xla(dt, x, B, C, A, h0, cont)
+    TB = next(t for t in (128, 64, 32, 16, 8) if Cs % t == 0)
+    bps = Cs // TB
+    cont = cont.astype(jnp.int32).at[0].set(0)
+    tok = lambda tb, e, c: (tb, e)
+    spread = lambda tb, e, c: (tb, 0, 0)
+    slot = lambda tb, e, c: (tb // bps, 0, e)
+    call = pl.pallas_call(
+        functools.partial(_scan_kernel, blocks_per_slot=bps, rows=TB),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(T // TB, E // Eb),
+            in_specs=[pl.BlockSpec((TB, Eb), tok), pl.BlockSpec((TB, Eb), tok),
+                      pl.BlockSpec((TB, N, LANES), spread),
+                      pl.BlockSpec((TB, N, LANES), spread),
+                      pl.BlockSpec((N, Eb), lambda tb, e, c: (0, e)),
+                      pl.BlockSpec((1, N, Eb), slot)],
+            out_specs=[pl.BlockSpec((TB, Eb), tok),
+                       pl.BlockSpec((1, N, Eb), slot)],
+            scratch_shapes=[pltpu.VMEM((E // Eb, N, Eb), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((T, E), jnp.float32),
+                   jax.ShapeDtypeStruct((G, N, E), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_backend.interpret(),
+    )
+    with jax.named_scope("ssm_chunk_scan"):
+        y, hT = call(cont, dt.astype(jnp.float32), x.astype(jnp.float32),
+                     lane_spread(B), lane_spread(C), A.astype(jnp.float32),
+                     h0.astype(jnp.float32))
+    return y, hT
+
+
+def ssm_chunk_scan_xla(dt, x, B, C, A, h0, cont):
+    """:func:`ssm_chunk_scan` in plain XLA: token by token, slot after slot."""
+    G, N, E = h0.shape
+    Cs = dt.shape[0] // G
+    f32 = lambda v: v.astype(jnp.float32).reshape((G, Cs) + v.shape[1:])
+    dt, x, B, C = f32(dt), f32(x), f32(B), f32(C)
+
+    def step(h, row):
+        dt_t, x_t, b_t, c_t = row
+        h = (jnp.exp(dt_t[None, :] * A) * h
+             + (dt_t * x_t)[None, :] * b_t[:, None])
+        return h, jnp.sum(h * c_t[:, None], axis=0)
+
+    with jax.named_scope("ssm_chunk_scan_xla"):
+        ys, hs = [], []
+        h = jnp.zeros((N, E), jnp.float32)
+        for g in range(G):
+            start = h0[g].astype(jnp.float32)
+            h = jnp.where(cont[g] != 0, h, start) if g else start
+            h, y = jax.lax.scan(step, h, (dt[g], x[g], B[g], C[g]))
+            ys.append(y)
+            hs.append(h)
+        return jnp.concatenate(ys), jnp.stack(hs)

@@ -459,3 +459,95 @@ def test_prefill_programs_stage_no_projection_weights(program, v5e,
     assert not staged, f"a layer's projection matrix is materialised: {staged}"
     assert _stacks_read_in_place(instructions, params, L).count(
         (HID, HKV * D)) == 2
+
+
+def _jamba2_3b(arr):
+    """Spec and stacked weight trees (shapes only) of AI21-Jamba2-3B, all 28
+    layers, as ``adapt_jamba`` stacks them, and its pools for 160 tracked
+    sequences and ``pages`` pages."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
+                                                              StatePoolConfig)
+    from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
+    cfg = JambaConfig.jamba2_3b(dtype=BF16)
+    model = JambaForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_jamba(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    pool = StatePoolConfig(num_layers=rm.num_state_layers(spec),
+                           num_slots=160, d_inner=5120, d_state=16, d_conv=4)
+    ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
+    kv = StatefulKV(arr(BF16, rm.num_page_layers(spec), 2049, 2, 1, BS, D),
+                    arr(ssm_shape.dtype, *ssm_shape.shape),
+                    arr(conv_shape.dtype, *conv_shape.shape))
+    return spec, weights, kv
+
+
+def _state_pool_values(text, kv):
+    """``(opcode, line)`` of every instruction outside a fusion whose value
+    has a state pool's shape (as it is, or as the flat rows a scatter takes)
+    and that is not free (a parameter, a tuple element, a bitcast) nor the
+    in-place update itself."""
+    ssm, conv = kv.ssm.shape, kv.conv.shape
+    pools = {ssm, (ssm[0] * ssm[1],) + ssm[2:], conv,
+             (conv[0] * conv[1],) + conv[2:],
+             (conv[0] * conv[1], 3, ssm[3]), (conv[0], conv[1], 3, ssm[3])}
+    instructions, _ = _executed(text)
+    return [(op, line.strip()[:120]) for _, dims, op, line in instructions
+            if dims in pools and op not in _FREE + ("fusion", "custom-call",
+                                                    "while", "tuple")]
+
+
+@pytest.mark.parametrize("program", ["serve_decode_step",
+                                     "serve_prefill_packed"])
+def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
+                                                        monkeypatch):
+    """AI21-Jamba2-3B at its published widths, all 28 layers: the 128-row
+    decode step and the packed prefill pass (4 slots of 256). Both state
+    kernels are in the program; the pools (1.33 GiB of ``h``, 0.25 GiB of
+    convolution tails) are the outputs' buffers; and no instruction copies a
+    pool or lays it out anew — a first layout of the tails, ``[Lm, NS + 1,
+    (K-1) E]``, had the flat view a row scatter needs copied in every layer
+    (127 MiB of temporaries, twice a layer, against 4 now), and a reshape of
+    the tile-exact pool to ``[.., K - 1, E]`` would do the same."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _jamba2_3b(arr)
+    assert [n for _, _, n in rm.layer_runs(spec)] == [7, 1, 13, 1, 6]
+    if program == "serve_decode_step":
+        rows = 128
+        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
+                                   arr(I32, rows, 96), arr(I32, rows),
+                                   arr(jnp.uint32, 2), arr(F32),
+                                   arr(I32, rows)).compile()
+        kernel = "ssm_decode_step"
+    else:
+        host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=128,
+                           max_blocks=96).device_arrays()
+        batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
+                 else arr(I32, *host[k].shape)
+                 for k in rm.PREFILL_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernel = "ssm_chunk_scan"
+    text = compiled.as_text()
+    assert kernel in text, "the state kernel is not in the program"
+    moved = _state_pool_values(text, kv)
+    assert not moved, f"a state pool is copied or laid out anew: {moved}"
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 << 20

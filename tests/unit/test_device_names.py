@@ -204,13 +204,46 @@ def _afmoe_text():
     return {"afmoe_paged_pass": paged.text()}
 
 
+def _jamba_texts():
+    """A model with Mamba layers: its passes carry the mixer's scopes, the
+    chunked scan over prompt rows, and its decode step the one-token
+    recurrence."""
+    from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
+    cfg = JambaConfig.tiny(hidden_size=512, num_attention_heads=4,
+                           mamba_dt_rank=16, dtype=jnp.float32)
+    model = JambaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    e = InferenceEngineV2(model=model, model_parameters=params, config={
+        "dtype": "float32", "kv_cache": {"block_size": 8, "num_blocks": 16},
+        "state_manager": {"max_context": 64, "max_tracked_sequences": 2,
+                          "max_ragged_sequence_count": 2,
+                          "max_ragged_batch_size": 2 + 16,
+                          "prefill_chunk_size": 8}})
+    paged = e._pass = e._pass_rungs[1] = _Recorder(e._pass)
+    built = e._decode_step_prog
+    steps = []
+
+    def decode_step_prog(*a, **k):
+        steps.append(_Recorder(built(*a, **k)))
+        return steps[-1]
+
+    e._decode_step_prog = decode_step_prog
+    prompt = np.arange(1, 21, dtype=np.int32)
+    e.put([1], [prompt[:12]])
+    e.put([1], [prompt[12:]])
+    e.decode_pipeline([1]).run(2)
+    return {"jamba_paged_pass": paged.text(),
+            "jamba_decode_step": steps[0].text()}
+
+
 @pytest.fixture(scope="module")
 def texts():
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     out = {}
     for build in (_serving_texts, _wide_head_text, _train_texts, _zero3_text,
-                  _moe_text, _afmoe_text):
+                  _moe_text, _afmoe_text, _jamba_texts):
         out.update(build())
     return out
 
@@ -263,6 +296,19 @@ CASES = [
     ("afmoe_paged_pass", "scope", "attn/attn_full"),
     ("afmoe_paged_pass", "scope", "attn/gate"),
     ("afmoe_paged_pass", "scope", "moe_ffn/shared"),
+    ("jamba_paged_pass", "scope", "ssm"),
+    ("jamba_paged_pass", "scope", "ssm/in_proj"),
+    ("jamba_paged_pass", "scope", "ssm/conv"),
+    ("jamba_paged_pass", "scope", "ssm/scan"),
+    ("jamba_paged_pass", "scope", "ssm/scan/ssm_chunk_scan"),
+    ("jamba_paged_pass", "scope", "ssm/step"),      # a pass's decode rows
+    ("jamba_paged_pass", "scope", "ssm/out_proj"),
+    ("jamba_paged_pass", "scope", "attn/attn_full"),
+    ("jamba_decode_step", "program", "jit_serve_decode_step"),
+    ("jamba_decode_step", "scope", "ssm/step"),
+    ("jamba_decode_step", "scope", "ssm/step/ssm_decode_step"),
+    ("jamba_decode_step", "scope", "ssm/conv"),
+    ("jamba_decode_step", "scope", "kv_flush"),
 ]
 
 

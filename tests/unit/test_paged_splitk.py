@@ -359,6 +359,14 @@ class TestVmemBudget:
                                      scale_tile_rows=r8) == 4
         assert _pick_pages_per_chunk(bs, hkv, d, 1, 64) == 5
 
+    def test_one_kv_head_is_capped_by_pages_not_bytes(self):
+        # 128 KiB a page: the bytes alone would allow 63 pages a chunk
+        assert _pick_pages_per_chunk(128, 1, 128, 2, 96, flash_heads=20) \
+            == pa.MAX_PAGES_PER_CHUNK == 32
+        # ... and the widths the other cells serve stay where bytes put them
+        assert _pick_pages_per_chunk(128, 8, 128, 2, 40, flash_heads=32) == 7
+        assert _pick_pages_per_chunk(128, 4, 128, 2, 208, flash_heads=32) == 15
+
     def test_floor_is_one_page(self, monkeypatch):
         monkeypatch.setenv("DSTPU_PAGED_VMEM_BUDGET", "1")
         assert _pick_pages_per_chunk(64, 2, 128, 4, 64, flash_heads=8,
