@@ -381,6 +381,15 @@ class ActivationCheckpointingConfig(ConfigModel):
     On TPU this maps to ``jax.checkpoint`` policies: ``partition_activations`` ->
     sharded remat saveables; ``cpu_checkpointing`` -> host offload of residuals
     (XLA memory_kind pinned_host); contiguous buffers are an XLA concern.
+
+    With none of ``partition_activations``, ``cpu_checkpointing`` and
+    ``number_checkpoints`` set — and no ``remat_policy`` named by the model —
+    nobody has said what a checkpointed layer keeps, and the train engine
+    chooses the most the chip has room for (docs/TRAINING.md, "Activation
+    checkpointing: what a layer keeps"). Setting any of the three keeps the
+    engine out of it; full recompute is the model's ``remat_policy="none"``.
+    The explicit ZeRO-3 schedule (``stage3_prefetch_depth``) recomputes by
+    wave whatever is set here.
     """
 
     partition_activations: bool = False

@@ -375,6 +375,12 @@ class Tracer:
         with self._totals_lock:
             self.totals[name] = self.totals.get(name, 0.0) + value
 
+    def note(self, name: str, value: float) -> None:
+        """Set an always-on value that is chosen, not counted (what an
+        engine's checkpointed layers keep): the newest choice stands."""
+        with self._totals_lock:
+            self.totals[name] = float(value)
+
     # ------------------------------------------------------------------ #
     # captures: the rings and the profiler over one interval, on one clock
     # ------------------------------------------------------------------ #

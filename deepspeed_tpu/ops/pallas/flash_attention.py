@@ -21,6 +21,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.utils.jax_compat import import_pltpu
@@ -452,6 +453,12 @@ def _flash(q, k, v, scale, causal, block_q, block_k):
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     o, lse = _fwd(q, k, v, scale, causal, block_q, block_k)
+    # named for jax.checkpoint: a policy that saves these two names
+    # (activation_checkpointing's "flash_residuals_saveable") keeps what the
+    # backward kernels read of the forward, and the forward kernel is not
+    # run a second time. q, k and v are the caller's to keep or recompute.
+    o = checkpoint_name(o, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, o, lse)
 
 

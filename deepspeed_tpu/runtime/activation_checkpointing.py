@@ -25,21 +25,59 @@ host). Under XLA the same capability is a **remat policy** on ``jax.checkpoint``
 
 Models call ``apply_remat(BlockClass, config, static_argnums=...)`` at build time;
 user code may also use the reference-shaped ``checkpoint(fn, *args)``.
+
+**When nobody names a policy.** A model built with ``remat=True`` and no
+``remat_policy``, under a config whose ``activation_checkpointing`` block sets
+nothing, used to keep only each layer's input and run the layer's forward a
+second time in the backward. The train engine now picks, once, the first rung
+of :data:`LADDER` that the chip has room for (:func:`choose_rung`): the device's
+memory limit against the train state it holds at rest, the gradient
+accumulator, and what each rung keeps a layer — read from a trace of one layer
+(:func:`kept_bytes`), no compile. The step the engine compiles is the guard:
+a program whose ``memory_analysis()`` exceeds the limit, or that the TPU
+compiler refuses for memory, is compiled again one rung lower
+(``engine._compile_fitted``). Full recompute stays spelled
+``remat_policy="none"``; a named
+policy, any of ``partition_activations`` / ``cpu_checkpointing`` /
+``number_checkpoints``, the explicit ZeRO-3 schedule (its waves recompute to
+free gathered parameters) and a device that reports no memory limit (the CPU
+backend) keep exactly what they had.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Callable, Dict, Optional, Sequence
+import dataclasses
+import math
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 from jax import checkpoint_policies as _cp
+from jax.extend.core import Literal as _Literal
 
 from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.utils.tree import tree_size_bytes
 
 # --------------------------------------------------------------------------- #
 # Policy registry
 # --------------------------------------------------------------------------- #
+
+def narrow_dots_saveable(prim, *avals, **params) -> bool:
+    """The products with no batch dimension whose output is no wider than
+    what they contract over: in a transformer layer q, k, v and the attention
+    output projection, and not the MLP's expansion to the intermediate size
+    (whose outputs are most of what ``dots_with_no_batch_dims_saveable``
+    keeps)."""
+    if not _cp.dots_with_no_batch_dims_saveable(prim, *avals, **params) \
+            or "dimension_numbers" not in params:
+        return False
+    (lhs_contract, rhs_contract), _ = params["dimension_numbers"]
+    lhs, rhs = avals[:2]
+    contracted = math.prod(lhs.shape[d] for d in lhs_contract)
+    produced = math.prod(n for d, n in enumerate(rhs.shape)
+                         if d not in rhs_contract)
+    return produced <= contracted
+
 
 #: Name -> zero-arg factory returning a jax.checkpoint policy (or None = full remat).
 #: Mirrors the reference's knob set (checkpointing.py:1070 configure) plus the
@@ -50,6 +88,7 @@ POLICIES: Dict[str, Callable[[], Optional[Callable]]] = {
     "everything_saveable": lambda: _cp.everything_saveable,
     "dots_saveable": lambda: _cp.dots_saveable,
     "dots_with_no_batch_dims_saveable": lambda: _cp.dots_with_no_batch_dims_saveable,
+    "narrow_dots_saveable": lambda: narrow_dots_saveable,
     # host-offload variants (parity: cpu_checkpointing, checkpointing.py:546-560)
     "offload_dots": lambda: _cp.offload_dot_with_no_batch_dims(
         offload_src="device", offload_dst="pinned_host"),
@@ -57,6 +96,12 @@ POLICIES: Dict[str, Callable[[], Optional[Callable]]] = {
     # models via checkpoint_name "attn_out") — backward skips recomputing the
     # attention kernel, costing only B*T*C per layer of extra residency.
     "attn_out_saveable": lambda: _cp.save_only_these_names("attn_out"),
+    # what the flash kernel's backward reads of its forward: the output and
+    # the log-sum-exp, named in its forward rule (ops/pallas/flash_attention.py).
+    # A custom_vjp is not a dot, so no dot policy keeps them, and "attn_out"
+    # (the models' tag, on the reshaped output) does not cover the log-sum-exp
+    "flash_residuals_saveable": lambda: _cp.save_only_these_names(
+        "flash_out", "flash_lse"),
     "offload_attn_out": lambda: _cp.save_and_offload_only_these_names(
         names_which_can_be_saved=[], names_which_can_be_offloaded=["attn_out"],
         offload_src="device", offload_dst="pinned_host"),
@@ -103,6 +148,9 @@ class _CheckpointingState:
         self.synchronize = False
         self.profile = False
         self.policy: Optional[Callable] = None
+        # set around one trace by an engine (keeping / probing below)
+        self.kept: Optional[Callable] = None
+        self.probe: Optional["LayerProbe"] = None
 
 
 _STATE = _CheckpointingState()
@@ -167,8 +215,180 @@ def reset() -> None:
     _STATE = _CheckpointingState()
 
 
+def names_what_is_kept(cfg) -> bool:
+    """Does this ``activation_checkpointing`` block say what a checkpointed
+    layer keeps (a policy through ``partition_activations`` or
+    ``cpu_checkpointing``, the chunking through ``number_checkpoints``)? An
+    engine then leaves the choice alone."""
+    return bool(getattr(cfg, "partition_activations", False)
+                or getattr(cfg, "cpu_checkpointing", False)
+                or getattr(cfg, "number_checkpoints", None))
+
+
 def current_policy() -> Optional[Callable]:
-    return _STATE.policy if _STATE.configured else None
+    """The policy for a caller that names none: the configured block's, else
+    what an engine is keeping around this trace (:func:`keeping`), else None
+    (full recompute)."""
+    if _STATE.configured and names_what_is_kept(_STATE):
+        return _STATE.policy
+    return _STATE.kept
+
+
+def policy_for(name_or_policy) -> Optional[Callable]:
+    """A caller's own policy (a registry name — ``"none"`` spells full
+    recompute — or a callable) if it names one, else :func:`current_policy`."""
+    if name_or_policy is not None:
+        return resolve_policy(name_or_policy)
+    return current_policy()
+
+
+# --------------------------------------------------------------------------- #
+# What a checkpointed layer keeps when nobody names a policy
+# --------------------------------------------------------------------------- #
+
+def _attention_kept():
+    return _cp.save_from_both_policies(
+        POLICIES["attn_out_saveable"](), POLICIES["flash_residuals_saveable"]())
+
+
+#: (what is kept, factory of the policy), from most kept to least. The
+#: backward of rung 0 re-runs only the norms, the rotary embedding and the
+#: MLP's elementwise product; of the last, the whole layer.
+LADDER: Tuple[Tuple[str, Callable[[], Optional[Callable]]], ...] = (
+    ("dots and attention", lambda: _cp.save_from_both_policies(
+        POLICIES["dots_with_no_batch_dims_saveable"](), _attention_kept())),
+    ("narrow dots and attention", lambda: _cp.save_from_both_policies(
+        POLICIES["narrow_dots_saveable"](), _attention_kept())),
+    ("attention", _attention_kept),
+    ("layer inputs", POLICIES["none"]),
+)
+
+#: The share of the device's limit :func:`choose_rung` leaves free: the
+#: estimate knows neither how the compiler lays the kept tensors out nor the
+#: step's other temporaries (a layer's working set, gathered parameters, the
+#: head's logits). A share, so that it scales with the chip; the compiled
+#: step's ``memory_analysis()`` is the guard behind it.
+MARGIN_SHARE = 0.125
+
+
+def choose_rung(limit: int, resident: int, kept: Sequence[int], layers: int,
+                *, alive: int = 1, other: int = 0) -> int:
+    """The first rung of :data:`LADDER` a device has room for.
+
+    ``limit``: the device's memory limit in bytes (0: it reports none, and
+    the last rung is returned); ``resident``: bytes of train state it holds
+    at rest; ``kept[r]``: bytes rung ``r`` keeps of one layer on this device;
+    ``layers``: the layers a device runs; ``alive``: micro-batches whose
+    activations are alive at once; ``other``: temporaries the caller can
+    name (the gradient accumulator). A pure function of its arguments: the
+    same configuration gets the same rung in every run."""
+    last = len(kept) - 1
+    if limit <= 0:
+        return last
+    room = int(limit * (1.0 - MARGIN_SHARE)) - resident - other
+    for rung in range(last):
+        if kept[rung] * layers * alive <= room:
+            return rung
+    return last
+
+
+def kept_bytes(layer: Callable, policy: Optional[Callable], *args) -> int:
+    """Bytes the forward of ``jax.checkpoint(layer, policy=policy)`` keeps
+    for its backward beyond its own arguments and constants: the sum over
+    what ``jax.ad_checkpoint.print_saved_residuals`` lists as an output or a
+    named value. Traced from ``args`` (arrays, tracers or shapes); nothing
+    is compiled."""
+    fn = jax.checkpoint(layer, policy=policy)
+    jaxpr = jax.make_jaxpr(lambda *a: jax.linearize(fn, *a)[1])(*args).jaxpr
+    given = set(jaxpr.invars) | set(jaxpr.constvars)
+    return tree_size_bytes([v.aval for v in jaxpr.outvars
+                            if not isinstance(v, _Literal) and v not in given])
+
+
+class LayerProbe:
+    """What one trace of a model tells of its checkpointed layer walks:
+    for each walk, the bytes each rung keeps of one layer (its input
+    included, which every rung keeps) and the number of layers."""
+
+    def __init__(self):
+        self.walks: List[Tuple[Tuple[int, ...], int]] = []
+
+    @property
+    def layers(self) -> int:
+        return sum(n for _, n in self.walks)
+
+    def kept_per_layer(self) -> Tuple[int, ...]:
+        """Bytes each rung keeps of a layer (the mean over all walks)."""
+        return tuple(-(-sum(per_layer[r] * n for per_layer, n in self.walks)
+                       // self.layers) for r in range(len(LADDER)))
+
+    def measure(self, module, carry, call_layer, n_layers: int) -> None:
+        # a pure function of (variables, carry) for layer 0: the bound
+        # module's own variables and rng streams, applied unbound (this
+        # trace is thrown away, so drawing from the streams costs nothing)
+        unbound, variables = module.unbind()
+        rngs = {name: module.make_rng(name) for name in module.scope.rngs}
+
+        def layer(variables, carry):
+            return unbound.apply(variables, carry, rngs=rngs,
+                                 method=lambda m, c: call_layer(m, c, 0))
+
+        given = tree_size_bytes(carry)
+        self.walks.append((tuple(
+            given + kept_bytes(layer, build(), variables, carry)
+            for _, build in LADDER), n_layers))
+
+
+@contextlib.contextmanager
+def probing():
+    """Around one (abstract) trace of a model: every checkpointed walk that
+    names no policy reports to the yielded :class:`LayerProbe`."""
+    probe, prior = LayerProbe(), _STATE.probe
+    _STATE.probe = probe
+    try:
+        yield probe
+    finally:
+        _STATE.probe = prior
+
+
+@contextlib.contextmanager
+def keeping(rung: Optional[int]):
+    """Around one trace of a step: walks that name no policy keep what
+    ``LADDER[rung]`` keeps (None: nothing changes)."""
+    prior = _STATE.kept
+    _STATE.kept = None if rung is None else LADDER[rung][1]()
+    try:
+        yield
+    finally:
+        _STATE.kept = prior
+
+
+@dataclasses.dataclass(frozen=True)
+class RematPlan:
+    """What an engine chose for its checkpointed layers, and against what."""
+    rung: int
+    kept_per_layer: Tuple[int, ...]    # bytes a device keeps of a layer, by rung
+    layers: int
+    limit_bytes: int
+    resident_bytes: int
+    other_bytes: int
+
+    @property
+    def what(self) -> str:
+        return LADDER[self.rung][0]
+
+    @property
+    def kept_bytes(self) -> int:
+        return self.kept_per_layer[self.rung] * self.layers
+
+    def describe(self) -> str:
+        gib = 2.0 ** 30
+        return (f"rung {self.rung} ({self.what}): keeps "
+                f"{self.kept_bytes / gib:.3f} GiB a device over {self.layers} "
+                f"layers ({self.kept_per_layer[self.rung]} B a layer) "
+                f"beside {self.resident_bytes / gib:.2f} GiB of state and "
+                f"{self.other_bytes / gib:.2f} GiB of gradients, of a limit "
+                f"of {self.limit_bytes / gib:.2f} GiB")
 
 
 # --------------------------------------------------------------------------- #
@@ -184,14 +404,14 @@ def checkpoint(function: Callable, *args, policy=None, static_argnums=(), **kwar
     through unchanged, so dropout is deterministic across the recompute without
     the reference's fork/restore of device RNG states (:122).
     """
-    pol = resolve_policy(policy) if policy is not None else current_policy()
+    pol = policy_for(policy)
     fn = jax.checkpoint(function, policy=pol, static_argnums=static_argnums)
     return fn(*args, **kwargs)
 
 
 def checkpoint_wrapper(function: Callable, policy=None, static_argnums=()):
     """Return a remat-wrapped callable (decorator form)."""
-    pol = resolve_policy(policy) if policy is not None else current_policy()
+    pol = policy_for(policy)
     return jax.checkpoint(function, policy=pol, static_argnums=static_argnums)
 
 
@@ -205,7 +425,7 @@ def apply_remat(block_cls, remat: bool = True, policy=None, static_argnums=()):
     if not remat:
         return block_cls
     import flax.linen as nn
-    pol = resolve_policy(policy) if policy is not None else current_policy()
+    pol = policy_for(policy)
     return nn.remat(block_cls, policy=pol, static_argnums=static_argnums)
 
 
@@ -234,6 +454,9 @@ def apply_checkpointed_layers(module, carry, call_layer, n_layers: int,
     reachable through ``module`` (setup-defined submodule lists), the flax lifted
     -transform contract. Model builders use this so the
     ``activation_checkpointing`` config block uniformly drives every family.
+    With ``policy`` None the walk keeps what :func:`current_policy` says: the
+    block's policy, else what an engine is :func:`keeping` around this trace,
+    else nothing (full recompute; as a policy name, ``"none"``).
 
     When the engine arms a ZeRO-3 collective schedule
     (``zero_optimization.stage3_prefetch_depth``; ``runtime/zero/prefetch.py``)
@@ -261,7 +484,9 @@ def apply_checkpointed_layers(module, carry, call_layer, n_layers: int,
             carry = call_layer(module, carry, i)
         return carry
     import flax.linen as nn
-    pol = resolve_policy(policy) if policy is not None else current_policy()
+    if policy is None and _STATE.probe is not None:
+        _STATE.probe.measure(module, carry, call_layer, n_layers)
+    pol = policy_for(policy)
 
     def chunk(mdl, carry, s, e):
         for i in range(s, e):

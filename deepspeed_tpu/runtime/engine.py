@@ -30,6 +30,7 @@ device->host fetch routes through :func:`fetch_to_host`.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from collections import deque
@@ -90,6 +91,46 @@ def fetch_to_host(tree):
     _tracer.add("train/drain/fetch_to_host", t0, time.perf_counter(),
                 lane="train/drain")
     return out
+
+
+def _bytes_a_device(tree, shardings=None, dtype=None) -> int:
+    """Bytes one device holds of a tree of arrays or shapes, each under its
+    own sharding (or the matching leaf of ``shardings``), as ``dtype`` if
+    given."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    over = [getattr(x, "sharding", None) for x in leaves] \
+        if shardings is None else jax.tree_util.tree_leaves(shardings)
+    return sum(
+        int(np.prod(sh.shard_shape(x.shape) if sh is not None else x.shape))
+        * jnp.dtype(dtype or x.dtype).itemsize for x, sh in zip(leaves, over))
+
+
+class _FittedStep:
+    """The fused step of an engine that chose what its checkpointed layers
+    keep (``engine.remat_plan``): compiled ahead of its first call for each
+    batch shape, so that the compiled program's ``memory_analysis()`` is
+    held against the device's limit before anything runs (the engine's
+    ``_compile_fitted``), and called as compiled — one compile a shape, as
+    ``jax.jit`` would make."""
+
+    def __init__(self, engine: "DeepSpeedTPUEngine"):
+        self._engine = engine
+        self._compiled: Dict[Any, Callable] = {}
+
+    def _cache_size(self) -> int:
+        return len(self._compiled)
+
+    def lower(self, state, batch):
+        return self._engine._jit_fused_step().lower(state, batch)
+
+    def __call__(self, state, batch):
+        leaves, treedef = jax.tree_util.tree_flatten(batch)
+        key = (treedef, tuple((x.shape, x.dtype) for x in leaves))
+        step = self._compiled.get(key)
+        if step is None:
+            step = self._compiled[key] = self._engine._compile_fitted(
+                state, batch)
+        return step(state, batch)
 
 
 def _extract_apply_fn(model: Any) -> Callable:
@@ -250,6 +291,9 @@ class DeepSpeedTPUEngine:
         # ZeRO-3 collective schedule (runtime/zero/prefetch.py): built lazily
         # once params exist, armed around every trace of the fused step
         self._zero3_plan = None
+        # what the checkpointed layer walk keeps, chosen at the first fused
+        # step (_plan_remat); None: nothing chosen, the walk is as configured
+        self.remat_plan = None
         # span tracing (docs/OBSERVABILITY.md): config-reachable alongside
         # the DSTPU_TRACE env path initialize() arms
         tc = self.config.monitor.trace
@@ -989,17 +1033,121 @@ class DeepSpeedTPUEngine:
         return grads, losses
 
     def _build_fused_step(self):
+        from deepspeed_tpu.runtime import activation_checkpointing
         fp16 = self.config.fp16
 
         def step_fn(state, batch):
             params = self._current_params(state)
             scale = state["scaler"]["scale"] if fp16.enabled else jnp.float32(1.0)
-            grads, losses = self._accumulate_grads(params, scale, batch)
+            # read when traced: the rung the engine holds now
+            plan = self.remat_plan
+            with activation_checkpointing.keeping(
+                    None if plan is None else plan.rung):
+                grads, losses = self._accumulate_grads(params, scale, batch)
             new_state, metrics = self._apply_grads(state, grads)
             metrics["loss"] = jnp.mean(losses)
             return new_state, metrics
 
         return step_fn
+
+    def _jit_fused_step(self):
+        return jax.jit(self._build_fused_step(), donate_argnums=(0,),
+                       compiler_options=self._compiler_options())
+
+    # ------------------------------------------------------------------ #
+    # what the checkpointed layers keep (runtime/activation_checkpointing.py)
+    # ------------------------------------------------------------------ #
+
+    def _plan_remat(self, batch_tree):
+        """Choose what the model's checkpointed layer walk keeps, from what
+        can be observed and is the same in every run: the device's memory
+        limit, the bytes of train state and of gradient accumulator a device
+        holds, and what each rung keeps of a layer, from one abstract trace
+        of the loss on a micro-batch of ``batch_tree`` ([gas, rows, ...]).
+        None — and nothing changes — where the device reports no limit (the
+        CPU backend), the ``activation_checkpointing`` block says what is
+        kept, the explicit ZeRO-3 schedule is armed (its waves recompute to
+        free gathered parameters), or no walk of the model asked (no
+        ``remat``, or a ``remat_policy`` named)."""
+        from deepspeed_tpu.accelerator import get_accelerator
+        from deepspeed_tpu.runtime import activation_checkpointing as ac
+        if self._zero3_plan is not None or ac.names_what_is_kept(
+                self.config.activation_checkpointing):
+            return None
+        limit = get_accelerator().total_memory()
+        if limit <= 0:
+            return None
+        micro = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), batch_tree)
+        with ac.probing() as probe:
+            jax.eval_shape(
+                lambda state, mb: self._loss_of(self._current_params(state), mb),
+                self.state, micro)
+        if not probe.layers:
+            return None
+        params = jax.eval_shape(self._current_params, self.state)
+        grads = _bytes_a_device(
+            params, self.partitioner._to_sharding(
+                self.partitioner.grad_spec(params, self._tp_specs)),
+            self.config.grad_accum_dtype)
+        resident = _bytes_a_device(self.state)
+        # the micro-batch's rows are spread over the data axes; one
+        # micro-batch is alive at a time (the accumulation is a scan)
+        world = self.topology.dp_world_size
+        kept = tuple(-(-k // world) for k in probe.kept_per_layer())
+        rung = ac.choose_rung(limit, resident, kept, probe.layers, other=grads)
+        return ac.RematPlan(rung=rung, kept_per_layer=kept, layers=probe.layers,
+                            limit_bytes=limit, resident_bytes=resident,
+                            other_bytes=grads)
+
+    def _make_fused_step(self, batch_tree):
+        self.remat_plan = self._plan_remat(batch_tree)
+        if self.remat_plan is None:
+            return self._jit_fused_step()
+        return _FittedStep(self)
+
+    def _compile_fitted(self, state, batch):
+        """Compile the fused step at the plan's rung; while the compiled
+        program needs more than the device's limit — by its
+        ``memory_analysis()``, or because the TPU compiler refuses it
+        (RESOURCE_EXHAUSTED) — one rung lower. The last rung (full
+        recompute) is what ran before there was a choice: it is returned
+        whatever it needs, and what the compiler raises of it is raised."""
+        from deepspeed_tpu.runtime import activation_checkpointing as ac
+        while True:
+            plan = self.remat_plan
+            last = plan.rung == len(ac.LADDER) - 1
+            try:
+                compiled = self._jit_fused_step().lower(state, batch).compile()
+            except jax.errors.JaxRuntimeError as e:
+                if last or "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                needs = "is refused by the compiler: out of memory"
+            else:
+                mem = compiled.memory_analysis()
+                need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+                        + mem.generated_code_size_in_bytes)
+                if last or need <= plan.limit_bytes:
+                    log_dist(f"activation checkpointing: {plan.describe()}; "
+                             f"the compiled step needs {need / 2**30:.2f} GiB",
+                             ranks=[0])
+                    for name, value in (
+                            ("rung", plan.rung),
+                            ("kept_bytes", plan.kept_bytes),
+                            ("kept_bytes_per_layer",
+                             plan.kept_per_layer[plan.rung]),
+                            ("limit_bytes", plan.limit_bytes),
+                            ("resident_bytes", plan.resident_bytes),
+                            ("step_bytes", need)):
+                        _tracer.note(f"train/remat/{name}", value)
+                    return compiled
+                needs = (f"needs {need / 2**30:.2f} GiB of a limit of "
+                         f"{plan.limit_bytes / 2**30:.2f}")
+            logger.warning(
+                "activation checkpointing: the step at rung %d (%s) %s; "
+                "compiling again one rung lower", plan.rung, plan.what, needs)
+            self.remat_plan = dataclasses.replace(plan, rung=plan.rung + 1)
 
     @jax.named_scope("optimizer")
     def _apply_grads(self, state, grads):
@@ -1269,9 +1417,6 @@ class DeepSpeedTPUEngine:
         # never a plan left armed by a previous scheduled engine
         from deepspeed_tpu.runtime.zero import prefetch as zero3_prefetch
         zero3_prefetch.configure(self._zero3_plan)
-        if self._fused_step is None and self._offload is None:
-            self._fused_step = jax.jit(self._build_fused_step(), donate_argnums=(0,),
-                                       compiler_options=self._compiler_options())
         fp_cfg = self.config.flops_profiler
         if fp_cfg.enabled and self.global_steps + 1 == fp_cfg.profile_step:
             raw = batch.raw if prefetched else batch
@@ -1286,6 +1431,8 @@ class DeepSpeedTPUEngine:
         if self._offload is not None:
             metrics = self._offload_train_step(staged.tree)
         else:
+            if self._fused_step is None:
+                self._fused_step = self._make_fused_step(staged.tree)
             self.state, metrics = self._fused_step(self.state, staged.tree)
         t3 = perf()
         # Only force a device sync for exact timings when the user asked for a

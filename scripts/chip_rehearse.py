@@ -87,17 +87,23 @@ def described_devices():
 
 @contextlib.contextmanager
 def steered_to_tpu():
-    """The CPU backend means "interpret" and "dense attention" to the code
-    under rehearsal; the programs compiled here are for a TPU."""
+    """The CPU backend means "interpret", "dense attention" and "no memory
+    limit" to the code under rehearsal; the programs compiled here are for
+    a TPU v5e."""
+    from deepspeed_tpu.accelerator import get_accelerator
     from deepspeed_tpu.ops import attention
     from deepspeed_tpu.ops.pallas import _backend
-    saved = _backend.interpret, attention._use_pallas
+    accelerator = type(get_accelerator())
+    saved = (_backend.interpret, attention._use_pallas,
+             accelerator.total_memory)
     _backend.interpret = lambda: False
     attention._use_pallas = lambda: True
+    accelerator.total_memory = lambda self, device_index=None: V5E_HBM_LIMIT
     try:
         yield
     finally:
-        _backend.interpret, attention._use_pallas = saved
+        (_backend.interpret, attention._use_pallas,
+         accelerator.total_memory) = saved
 
 
 def report(name: str, compiled, t0: float) -> None:
@@ -278,15 +284,24 @@ def compile_train_step(devices, layers: int, fsdp: int, prefetch_depth,
         assert armed == (prefetch_depth is not None), \
             f"explicit ZeRO-3 schedule armed = {armed}"
         zero3_prefetch.configure(engine._zero3_plan)
-        step = jax.jit(engine._build_fused_step(), donate_argnums=(0,),
-                       compiler_options=engine._compiler_options("tpu"))
+        # the engine asks the default backend which options it may pass
+        options = engine._compiler_options("tpu")
+        engine._compiler_options = lambda backend=None: options
         sharded = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(
                 (engine.gas_, x.shape[0] // engine.gas_) + x.shape[1:],
                 x.dtype,
                 sharding=NamedSharding(topo.mesh, P(None, BATCH_AXES))), batch)
         t0 = time.time()
-        compiled = step.lower(engine.state, sharded).compile()
+        # as train_batch does at its first step: choose what the checkpointed
+        # layers keep, then compile (and, with a choice, hold the compiled
+        # step's memory against the limit)
+        step = engine._make_fused_step(sharded)
+        compiled = step.lower(engine.state, sharded).compile() \
+            if engine.remat_plan is None \
+            else engine._compile_fitted(engine.state, sharded)
+    if engine.remat_plan is not None:
+        log(f"{label}: {engine.remat_plan.describe()}")
     report(label, compiled, t0)
 
 

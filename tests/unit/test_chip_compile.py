@@ -551,3 +551,137 @@ def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < 256 << 20
+
+
+# --------------------------------------------------------------------------- #
+# the train step: what its checkpointed layers keep (runtime/
+# activation_checkpointing.py), chosen by the engine for a described v5e
+# --------------------------------------------------------------------------- #
+
+#: ``memory_stats()["bytes_limit"]`` of a v5e chip (chip runs of PR 22); a
+#: described device reports none
+V5E_HBM_LIMIT = int(15.75 * 2 ** 30)
+
+
+class _ShapesOnly:
+    """``jax.jit`` for the engine's state build: the shapes it would make,
+    under its out_shardings (a described device cannot hold an array)."""
+
+    def __init__(self, fn, out_shardings=None, **_):
+        self.fn, self.out_shardings = fn, out_shardings
+
+    def __call__(self, *args):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            jax.eval_shape(self.fn, *args), self.out_shardings)
+
+
+def _train_step(v5e, monkeypatch, layers, fsdp=1, rows=1, remat_policy=None):
+    """The fused step of ``chipbench/configs/mistral7b-train-d2.json``'s
+    engine (``layers`` deep, ``rows`` sequences of 4096 a chip a step, ZeRO-3
+    over ``fsdp`` chips), built and compiled as ``train_batch`` builds it at
+    its first step. Returns (engine, compiled)."""
+    import json
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    import deepspeed_tpu
+    from deepspeed_tpu.accelerator import get_accelerator
+    from deepspeed_tpu.comm.mesh import BATCH_AXES, build_topology
+    from deepspeed_tpu.config import MeshConfig
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from deepspeed_tpu.ops import attention
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    monkeypatch.setattr(attention, "_use_pallas", lambda: True)
+    monkeypatch.setattr(type(get_accelerator()), "total_memory",
+                        lambda self, device_index=None: V5E_HBM_LIMIT)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "mistral7b-train-d2.json")) as f:
+        config = dict(json.load(f)["train"])
+    config.update(train_batch_size=rows * fsdp,
+                  train_micro_batch_size_per_gpu=rows,
+                  mesh={"data": 1, "fsdp": fsdp})
+    topo = build_topology(MeshConfig(data=1, fsdp=fsdp),
+                          devices=list(v5e[:fsdp]))
+    model = LlamaForCausalLM(LlamaConfig.mistral_7b(
+        num_hidden_layers=layers, dtype=BF16, remat=True,
+        remat_policy=remat_policy))
+    engine, *_ = deepspeed_tpu.initialize(model=model, config=config,
+                                          mesh_topology=topo)
+    batch = {"input_ids": np.zeros((rows * fsdp, 4096), np.int32)}
+    with monkeypatch.context() as m:
+        m.setattr(jax, "jit", _ShapesOnly)
+        engine._ensure_state(batch)
+    # the engine asks the default backend which options it may pass
+    options = engine._compiler_options("tpu")
+    monkeypatch.setattr(engine, "_compiler_options",
+                        lambda backend=None: options)
+    staged = {"input_ids": jax.ShapeDtypeStruct(
+        (1, rows * fsdp, 4096), I32,
+        sharding=NamedSharding(topo.mesh, P(None, BATCH_AXES)))}
+    step = engine._make_fused_step(staged)
+    if engine.remat_plan is None:
+        return engine, step.lower(engine.state, staged).compile()
+    return engine, engine._compile_fitted(engine.state, staged)
+
+
+def _device_bytes(compiled) -> int:
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
+def _all_gathers(text) -> int:
+    return text.count(" all-gather(") + text.count(" all-gather-start(")
+
+
+def test_train_step_keeps_the_flash_residuals_and_fits(v5e, monkeypatch):
+    """Cell ``mistral7b-train.seq4k`` at its real shapes: with room for
+    every product and the flash forward's output and log-sum-exp, a layer's
+    backward runs the forward kernel no second time — 3 Mosaic calls a
+    layer (forward, dq, dkv), where full recompute makes 4."""
+    engine, compiled = _train_step(v5e, monkeypatch, layers=2)
+    plan = engine.remat_plan
+    assert plan.rung == 0 and plan.limit_bytes == V5E_HBM_LIMIT
+    # a layer: its input, q, k, v, o_proj's output, gate and up, the
+    # attention output twice (as the kernel and as the model lay it out)
+    # and the log-sum-exp
+    assert plan.kept_per_layer[0] == 2 * 4096 * (
+        4 * 4096 + 2 * 1024 + 2 * 14336 + 4096) + 4 * 32 * 4096
+    assert compiled.as_text().count("tpu_custom_call") == 6
+    assert _device_bytes(compiled) <= V5E_HBM_LIMIT
+
+
+def test_train_step_over_four_chips_gathers_no_weights_for_a_second_forward(
+        v5e, monkeypatch):
+    """The same walk under ``mesh: {fsdp: 4}`` (cell ``mistral7b-zero3x4
+    .seq4k``'s, 2 layers deep): the kernel sits in a shard_map there and its
+    names still reach the policy; and the forward that is not re-run gathers
+    no layer's weights a third time."""
+    engine, kept = _train_step(v5e, monkeypatch, layers=2, fsdp=4)
+    assert engine.remat_plan.rung == 0
+    named, full = _train_step(v5e, monkeypatch, layers=2, fsdp=4,
+                              remat_policy="none")
+    assert named.remat_plan is None
+    kept, full = kept.as_text(), full.as_text()
+    assert (kept.count("tpu_custom_call"),
+            full.count("tpu_custom_call")) == (6, 8)
+    assert _all_gathers(kept) < _all_gathers(full)
+
+
+@pytest.mark.parametrize("estimate", ["as_made", "too_hopeful"])
+def test_train_step_without_room_for_every_product_keeps_fewer(
+        estimate, v5e, monkeypatch):
+    """The guard's case: six sequences a step on the same chip. What rung 0
+    keeps of them does not fit beside the state and the gradients. The
+    engine's estimate says so and the rung it gives compiles and fits; and
+    were the estimate too hopeful (here: made to say rung 0), the compiler
+    refuses that step and the engine compiles one rung lower, which fits."""
+    from deepspeed_tpu.runtime import activation_checkpointing as ac
+    if estimate == "too_hopeful":
+        monkeypatch.setattr(ac, "choose_rung", lambda *a, **kw: 0)
+    engine, compiled = _train_step(v5e, monkeypatch, layers=2, rows=6)
+    assert 0 < engine.remat_plan.rung < len(ac.LADDER) - 1
+    assert compiled.as_text().count("tpu_custom_call") == 6
+    assert _device_bytes(compiled) <= V5E_HBM_LIMIT
