@@ -37,6 +37,7 @@ import functools
 from typing import Any, Optional
 
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_packed
+from deepspeed_tpu.ops.pallas.mla_attention import mla_paged_attention
 from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_chunk_attention_batched, paged_decode_attention,
     paged_decode_attention_sidebuf, paged_decode_attention_step)
@@ -48,6 +49,13 @@ _QUANT_TP_MSG = "int8 KV pages + TP not wired"
 _SPLIT_TP_MSG = ("attention.decode_splits > 1 with tensor_parallel > 1 is "
                  "not wired (the split-K LSE merge would land outside the "
                  "shard_map body)")
+
+
+#: one reason for every refusal beside latent pages (``spec.mla``)
+LATENT_PAGES_MSG = ("{what} is not wired for a model with latent attention "
+                    "(MLA): its pages hold one latent row a token a layer, "
+                    "not keys and values per head (docs/SERVING.md \"Latent "
+                    "pages\")")
 
 
 class AttentionKernelSpec:
@@ -98,6 +106,16 @@ class AttentionKernelSpec:
                 alibi=spec.alibi)
         self._packed = functools.partial(flash_attention_packed,
                                          window=spec.window)
+        mla = getattr(spec, "mla", None)
+        if mla is not None:
+            # latent pages (ragged_mla.py): one kernel for every program
+            # that reads the pool; the scale is the expanded form's, of the
+            # whole q/k width
+            self._latent = functools.partial(
+                mla_paged_attention, heads=spec.num_heads,
+                v_dim=mla["kv_lora_rank"],
+                softmax_scale=(mla["qk_nope_head_dim"]
+                               + mla["qk_rope_head_dim"]) ** -0.5)
 
     # ------------------------------------------------------------------ #
     # build-time capability surface
@@ -114,6 +132,30 @@ class AttentionKernelSpec:
         decode, preempt-offload and the cross-engine page fabric (the PR
         that collapsed those three former refusals into this table)."""
         tp = cfg.tensor_parallel
+        # latent pages (multi-head latent attention): one row a token a
+        # layer with no head axis. What reads or moves pages by the K/V
+        # pair's shape, or shards them by heads, cannot carry them yet
+        if getattr(spec, "mla", None) is not None:
+            attn = getattr(cfg, "attention", None)
+            refused = {
+                "kv_quant.enabled (int8 pages keep one scale a token-head "
+                "and need head_dim % 128 == 0; a latent row has no heads "
+                "and its parts, the latent and the rotary key, would need "
+                "a scale each)": cfg.kv_quant.enabled,
+                "tensor_parallel > 1 (the pages have no head axis to shard "
+                "and the latent kernel runs outside any shard_map)": tp > 1,
+                "attention.decode_splits > 1 (the split-K rungs are the K/V "
+                "kernels')": attn is not None and attn.decode_splits > 1,
+                "lora.enabled (adapters target q/k/v/o projections this "
+                "attention does not have)": cfg.lora.enabled,
+                "quantization.weight_bits (the latent projections are read "
+                "as plain arrays)":
+                    cfg.quantization.weight_bits in (4, 8),
+            }
+            for what, on in refused.items():
+                if on:
+                    raise NotImplementedError(LATENT_PAGES_MSG.format(
+                        what=what))
         if tp > 1 and (spec.num_heads % tp or spec.num_kv_heads % tp):
             raise ValueError(
                 f"tensor_parallel={tp} does not divide num_heads="
@@ -271,6 +313,16 @@ class AttentionKernelSpec:
         kw = {} if kv_scales is None else dict(kv_scales=kv_scales)
         return self._sidebuf(q, kv_l, block_tables, prefix_lens,
                              side_k, side_v, j, layer_idx=layer_idx, **kw)
+
+    def latent(self, q, pages, block_tables, q_pos0, ctx_lens, side=None,
+               side_j=None, layer_idx=None):
+        """Absorbed-form attention over latent pages
+        (``ops/pallas/mla_attention.py``): ``q`` ``[N, rows, W]`` already in
+        the rows' space, ``pages`` ``[L*NB, bs, W]`` with block tables
+        pre-offset by ``l*NB``; decode rows may bring the fused schedule's
+        side slab. tp == 1 only (refused at build)."""
+        return self._latent(q, pages, block_tables, q_pos0, ctx_lens,
+                            side=side, side_j=side_j, layer_idx=layer_idx)
 
     def packed(self, q, k, v, seg):
         """Packed segment-masked prefill flash (no paged reads — the

@@ -226,7 +226,7 @@ class InferenceEngineV2:
         # pages exist for the layers that attend; a Mamba layer holds a state
         # slot per sequence instead (ragged/state_pool.py)
         from deepspeed_tpu.inference.v2.ragged_model import (
-            num_page_layers, num_state_layers)
+            latent_width, num_page_layers, num_state_layers)
         kv_cfg = KVCacheConfig(
             num_layers=max(1, num_page_layers(self.spec)),
             num_kv_heads=self.spec.num_kv_heads,
@@ -234,7 +234,10 @@ class InferenceEngineV2:
             block_size=cfg.kv_cache.block_size,
             num_blocks=nb + 1,
             dtype=cfg.dtype,
-            quantized=cfg.kv_quant.enabled)
+            quantized=cfg.kv_quant.enabled,
+            # latent attention: one row a token a layer, no K/V pair
+            latent_dim=None if self.spec.mla is None
+            else latent_width(self.spec))
         self.scratch_block = nb
         self.kv = BlockedKVCache(kv_cfg, self.topology)
         self.allocator = BlockedAllocator(nb)
@@ -388,15 +391,27 @@ class InferenceEngineV2:
         from deepspeed_tpu.inference.v2.ragged_model import (
             describe_layer_kinds)
         ring = self.scheduler.ring_pages
+        if self.spec.mla is not None:
+            # always-on values (tracer.totals; docs/OBSERVABILITY.md): what a
+            # token costs the latent pool a layer, and how many of the
+            # router's experts this engine holds
+            _tracer.note("serve/latent/bytes_per_token",
+                        kv_cfg.bytes_per_block()
+                        / (kv_cfg.num_layers * kv_cfg.block_size))
+        if self.spec.moe is not None and "held" in self.spec.moe:
+            _tracer.note("serve/moe/held_experts", self.spec.moe["held"][1])
         state = "" if self.state_config is None else (
             f"; state pool {self.state_config.num_slots}+dump slots x "
             f"{self.state_config.num_layers} layers = "
             f"{self.state_config.total_bytes() / 2**20:.1f} MiB "
             f"({self.state_config.bytes_per_slot() / 2**20:.2f} MiB a "
             "sequence)")
+        latent = "" if kv_cfg.latent_dim is None else (
+            f" of latent rows ({kv_cfg.latent_dim} values a token, no K/V "
+            "pair)")
         log_dist(f"engine_v2: family={family} tp={tp} blocks={nb}+scratch "
                  f"block_size={kv_cfg.block_size} x {kv_cfg.num_layers} page "
-                 f"layers budget={sm.max_ragged_batch_size}{state}"
+                 f"layers{latent} budget={sm.max_ragged_batch_size}{state}"
                  f"; {describe_layer_kinds(self.spec)}; page ring "
                  f"{'off' if ring is None else f'{ring} pages a sequence'}; "
                  f"attention rungs {list(self.attn_split_ladder)}"
@@ -1277,13 +1292,10 @@ class InferenceEngineV2:
         AND its scale tile together (the scale-tile fabric invariant every
         page mover keeps; docs/SERVING.md "Quantized KV")."""
         if self._page_progs is None:
-
             @jax.jit
             def serve_kv_page_gather(kv, blocks):
-                # page-major on the way out: host slices [i] are contiguous
                 return jax.tree_util.tree_map(
-                    lambda a: jnp.moveaxis(jnp.take(a, blocks, axis=1),
-                                           1, 0), kv)
+                    lambda a: _gather_pages(a, blocks), kv)
 
             @functools.partial(jax.jit, donate_argnums=(0,))
             def serve_kv_page_scatter(kv, pages, blocks):
@@ -1308,8 +1320,7 @@ class InferenceEngineV2:
             return (cfg.bytes_per_block(),), np.uint8
         # jnp.dtype, not a numpy-name round trip: bf16 pools carry the
         # ml_dtypes bfloat16 numpy extension dtype
-        return ((cfg.num_layers, 2, cfg.num_kv_heads, cfg.block_size,
-                 cfg.head_dim), jnp.dtype(cfg.dtype))
+        return cfg.page_shape, jnp.dtype(cfg.dtype)
 
     def _pack_pages(self, vals: np.ndarray, scales: np.ndarray) -> np.ndarray:
         """(int8 values [n, L, 2, Hkv, bs, D], f32 scale tiles
@@ -1653,6 +1664,19 @@ class InferenceEngineV2:
                 self.flush([u])     # retired mid-run: recycle KV blocks now
         self.flush(pipe.uids)
         return outs
+
+
+def _gather_pages(a, blocks):
+    """Pages ``blocks`` of pool leaf ``a`` (page axis 1), page-major on the
+    way out: host slices ``[i]`` are contiguous. A loop of one dynamic slice
+    a page, for every layout: as ONE gather of whole latent pages (rows 640
+    wide) the TPU compiler staged the whole pool — 4.3 GiB of temporaries at
+    700 pages, of which the engine's warm-up died on the chip (PR 33) —
+    while the loop holds next to nothing for latent and K/V pools alike and
+    compiles in a tenth of a second whatever the count
+    (``tests/unit/test_chip_compile.py``)."""
+    return jax.lax.map(lambda b: jax.lax.dynamic_index_in_dim(
+        a, b, axis=1, keepdims=False), blocks)
 
 
 def _guess_family(model) -> str:

@@ -48,6 +48,12 @@ class KVCacheConfig:
     # the pools become (int8 values, f32 scales) pytrees; every consumer
     # dequantizes in-kernel
     quantized: bool = False
+    # latent pages (multi-head latent attention): the values of ONE row a
+    # token a layer holds — the compressed latent and the shared rotary key,
+    # padded to whole lane tiles (``ragged_model.latent_width``). The pool is
+    # then [L, NB, bs, latent_dim]: no head axis, no K/V pair, and
+    # ``num_kv_heads``/``head_dim`` describe nothing in it
+    latent_dim: Optional[int] = None
 
     @property
     def max_tokens(self) -> int:
@@ -67,18 +73,35 @@ class KVCacheConfig:
             values = 2 * self.num_kv_heads * self.block_size * self.head_dim
             return self.num_layers * (values + r8 * lanes * 4)
         itemsize = jnp.dtype(self.dtype).itemsize
+        if self.latent_dim is not None:
+            return (self.num_layers * self.block_size * self.latent_dim
+                    * itemsize)
         return (2 * self.num_layers * self.block_size * self.num_kv_heads
                 * self.head_dim * itemsize)
+
+    @property
+    def page_shape(self) -> Tuple[int, ...]:
+        """One page of all layers, as the pool holds it less the page axis."""
+        if self.latent_dim is not None:
+            return (self.num_layers, self.block_size, self.latent_dim)
+        return (self.num_layers, 2, self.num_kv_heads, self.block_size,
+                self.head_dim)
 
     @classmethod
     def from_memory_budget(cls, num_layers: int, num_kv_heads: int, head_dim: int,
                            budget_bytes: int, block_size: int = 128,
-                           dtype: Any = jnp.bfloat16) -> "KVCacheConfig":
+                           dtype: Any = jnp.bfloat16,
+                           latent_dim: Optional[int] = None
+                           ) -> "KVCacheConfig":
         """Size the pool from an HBM budget (parity: the reference sizes its pool
-        from free GPU memory after model load, ``engine_v2.py`` memory config)."""
-        probe = cls(num_layers, num_kv_heads, head_dim, block_size, 1, dtype)
+        from free GPU memory after model load, ``engine_v2.py`` memory config).
+        With ``latent_dim`` the pages are latent rows and the two head
+        arguments count for nothing."""
+        probe = cls(num_layers, num_kv_heads, head_dim, block_size, 1, dtype,
+                    latent_dim=latent_dim)
         nb = max(1, budget_bytes // probe.bytes_per_block())
-        return cls(num_layers, num_kv_heads, head_dim, block_size, int(nb), dtype)
+        return cls(num_layers, num_kv_heads, head_dim, block_size, int(nb),
+                   dtype, latent_dim=latent_dim)
 
 
 class BlockedKVCache:
@@ -86,19 +109,25 @@ class BlockedKVCache:
     V = index 1 — one page per sequence-chunk holds BOTH, because the
     decode kernel is per-DMA-copy bound; see ops/pallas/paged_attention.py)
     and its sharding. With ``config.quantized`` the pool is an (int8
-    values, f32 per-token-head scales [L, NB, 2, Hkv, bs]) tuple."""
+    values, f32 per-token-head scales [L, NB, 2, Hkv, bs]) tuple. With
+    ``config.latent_dim`` (latent attention) it is [L, NB, bs, latent_dim]:
+    one row a token a layer, the page axis still at 1, so whatever moves
+    whole pages by that axis (``copy_page``, the engine's page gather and
+    scatter) carries it unchanged."""
 
     def __init__(self, config: KVCacheConfig, topology: Optional[MeshTopology] = None):
         self.config = config
         self.topology = topology
         self._copy_prog = None      # COW page-copy program (copy_page)
-        shape = (config.num_layers, config.num_blocks, 2,
-                 config.num_kv_heads, config.block_size, config.head_dim)
+        shape = (config.num_layers, config.num_blocks) + config.page_shape[1:]
         sharding = None
         if topology is not None:
             tp = topology.tp_world_size
-            spec = [None] * 6
-            if tp > 1 and config.num_kv_heads % tp == 0:
+            spec = [None] * len(shape)
+            if config.latent_dim is not None:
+                assert tp == 1 and not config.quantized, \
+                    "latent pages are neither sharded nor quantized"
+            elif tp > 1 and config.num_kv_heads % tp == 0:
                 spec[3] = TENSOR_AXIS
             sharding = NamedSharding(topology.mesh, P(*spec))
         if config.quantized:

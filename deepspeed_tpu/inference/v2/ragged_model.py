@@ -130,8 +130,16 @@ class RaggedModelSpec:
     # {"num_experts": E, "top_k": k}: top-k of the router logits, softmax
     # over the chosen (Mixtral). With "score_func": "sigmoid" the scores are
     # sigmoid(logits), chosen with the layer's "expert_bias" added, weighed
-    # without it, over their sum if "route_norm", times "route_scale" (afmoe)
+    # without it, over their sum if "route_norm", times "route_scale" (afmoe,
+    # joyai). "held": (first, count) — the expert stacks hold only experts
+    # first..first+count-1 of the E the router scores (one chip's share of an
+    # expert-parallel deployment); absent: all E
     moe: Optional[Dict[str, Any]] = None
+    # multi-head latent attention: {"q_lora_rank", "kv_lora_rank",
+    # "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"}. The pages then
+    # hold one latent row a token a layer (no head axis, no K/V pair:
+    # ragged/kv_cache.py) and the programs are ragged_mla.py's
+    mla: Optional[Dict[str, int]] = None
     # mistral/qwen2 sliding-window span (tokens); None = full attention.
     # Reference parity: inference/v2/model_implementations/mistral.
     window: Optional[int] = None
@@ -555,6 +563,97 @@ def adapt_jamba(params: Dict, config,
     return spec, weights
 
 
+def adapt_joyai(params: Dict, config,
+                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
+    """models/joyai.py param tree (JoyaiForCausalLM; JoyAI-LLM-Flash).
+
+    Latent attention (``spec.mla``): ``kv_b_proj`` is stored split by what
+    it makes and head-major, ``w_uk`` ``[H, R, nope]`` (keys) and ``w_uv``
+    ``[H, R, v]`` (values): the layout the decode step's per-head products
+    read in place (``[R, H, .]`` was copied transposed in every layer). Each
+    is used by the expanded form (latent -> keys/values) and by the absorbed
+    form (queries -> latent space, latent output -> values) alike. One leading run of dense layers, then MoE layers whose stacks
+    hold ``config.held`` of the router's ``n_routed_experts``. The
+    multi-token-prediction module (``layers_<num_hidden_layers>`` and on in
+    a converted checkpoint) feeds no logit and is not loaded."""
+    del max_context
+    H = config.num_attention_heads
+    R, dn, dr, dv = (config.kv_lora_rank, config.qk_nope_head_dim,
+                     config.qk_rope_head_dim, config.v_head_dim)
+    skipped = sorted(k for k in params if k.startswith("layers_")
+                     and int(k[len("layers_"):]) >= config.num_hidden_layers)
+    if skipped:
+        from deepspeed_tpu.utils.logging import log_dist
+        log_dist(f"adapt_joyai: {skipped} (the multi-token-prediction "
+                 "module) not loaded", ranks=[0])
+    kinds = tuple(LayerKind(None, True, config.is_moe_layer(i))
+                  for i in range(config.num_hidden_layers))
+    first, count = config.held
+    moe = {"num_experts": config.n_routed_experts,
+           "top_k": config.num_experts_per_tok, "score_func": "sigmoid",
+           "route_norm": config.norm_topk_prob,
+           "route_scale": config.routed_scaling_factor}
+    if count != config.n_routed_experts:
+        moe["held"] = (first, count)
+    spec = RaggedModelSpec(
+        family="joyai",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=H, num_kv_heads=H, head_dim=dv,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
+        eps=config.rms_norm_eps, moe=moe, layer_kinds=kinds,
+        mla={"q_lora_rank": config.q_lora_rank, "kv_lora_rank": R,
+             "qk_nope_head_dim": dn, "qk_rope_head_dim": dr,
+             "v_head_dim": dv},
+        dtype=config.dtype)
+    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
+        spec = layer_runs(spec)[0][0]
+
+    def swiglu(p):
+        return {"w_gate": p["gate_proj"]["kernel"],
+                "w_up": p["up_proj"]["kernel"],
+                "w_down": p["down_proj"]["kernel"]}
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        attn = lp["self_attn"]
+        kvb = jnp.transpose(
+            attn["kv_b_proj"]["kernel"].reshape(R, H, dn + dv), (1, 0, 2))
+        out = {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
+            "wqa": attn["q_a_proj"]["kernel"],
+            "q_a_norm": attn["q_a_layernorm"]["weight"],
+            "wqb": attn["q_b_proj"]["kernel"],
+            "wkva": attn["kv_a_proj_with_mqa"]["kernel"],
+            "kv_a_norm": attn["kv_a_layernorm"]["weight"],
+            "w_uk": kvb[..., :dn], "w_uv": kvb[..., dn:],
+            "wo": attn["o_proj"]["kernel"],
+        }
+        mlp = lp["mlp"]
+        if config.is_moe_layer(i):
+            out["moe"] = {"router": mlp["gate"]["kernel"],
+                          "expert_bias": mlp["e_score_correction_bias"],
+                          "w_gate": mlp["w_gate"], "w_up": mlp["w_up"],
+                          "w_down": mlp["w_down"]}
+            if "shared_experts" in mlp:
+                out["moe"]["shared"] = swiglu(mlp["shared_experts"])
+        else:
+            out["mlp"] = swiglu(mlp)
+        return out
+
+    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
+                   for _, l0, n in layer_runs(spec))
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": stacks if spec.layer_kinds is not None else stacks[0],
+        "final_norm": {"scale": params["norm"]["weight"]},
+        "lm_head": params["lm_head"]["kernel"],
+    }
+    return spec, weights
+
+
 ADAPTERS: Dict[str, Callable] = {
     # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
     # LlamaConfig features the adapter reads)
@@ -578,6 +677,10 @@ ADAPTERS: Dict[str, Callable] = {
     # Mamba state-space layers beside a few attention layers: a state pool
     # beside the pages (ragged/state_pool.py)
     "jamba": adapt_jamba,
+    # latent attention (MLA): pages of one latent row a token, no head axis
+    # (ragged_mla.py); a sigmoid router over experts of which this chip may
+    # hold a share
+    "joyai": adapt_joyai,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
@@ -759,12 +862,24 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     """
     T, hid = x.shape
     E = w["router"].shape[-1]
+    held = (routing or {}).get("held")
     with jax.named_scope("router"):
         gates, ids = moe_route(x, w, top_k, routing)
 
     with jax.named_scope("sort"):
         tok_idx = jnp.repeat(jnp.arange(T), top_k)                     # [T*K]
         expert_ids = ids.reshape(-1)
+        if held is not None:
+            # this chip's share: the router chose among all E and weighed
+            # over all top_k chosen; only the assignments that land on the
+            # held experts are computed here. Those sort to the front, by
+            # held expert; the others follow them, belong to no group (the
+            # grouped GEMM visits no row past its groups) and weigh nothing
+            first, E = held
+            local = expert_ids - first
+            on = (local >= 0) & (local < E)
+            expert_ids = jnp.where(on, local, E)
+            gates = jnp.where(on.reshape(gates.shape), gates, 0.0)
         order = jnp.argsort(expert_ids)
         # XLA:TPU runs its grouped-GEMM kernel only on a row count that is a
         # multiple of 8; any other count lowers to a dense product over
@@ -774,6 +889,8 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
         xs = x[tok_idx[rows]]                                  # [T*K + pad, hid]
         group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
         row_e = expert_ids[rows]
+        if held is not None:
+            row_e = jnp.minimum(row_e, E - 1)
 
     def gg(lhs, rhs):
         if isinstance(rhs, dict) and "w8" in rhs:
@@ -799,6 +916,8 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
         else:
             h = jax.nn.gelu(gg(xs, w["w_up"]))
         ys = gg(h, w["w_down"])[:order.shape[0]]                       # [T*K, hid]
+        if held is not None:    # rows of no group hold nothing defined
+            ys = jnp.where((expert_ids[order] < E)[:, None], ys, 0)
     with jax.named_scope("combine"):
         scale = gates.reshape(-1)[order].astype(ys.dtype)
         # scatter-free combine: invert the sort permutation and sum the K
@@ -1135,6 +1254,46 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     return out, ssm, conv
 
 
+def latent_width(spec: "RaggedModelSpec") -> int:
+    """Values of one latent row in the pool (``spec.mla``): the latent and
+    the rotary key, padded to whole lane tiles."""
+    from deepspeed_tpu.ops.pallas.mla_attention import latent_row_width
+    return latent_row_width(spec.mla["kv_lora_rank"],
+                            spec.mla["qk_rope_head_dim"])
+
+
+def _mla_project(spec: "RaggedModelSpec", w, h1, positions):
+    """The projections of latent attention on the normed rows ``h1``:
+    ``(q_nope [N, H, nope], q_rope [N, H, rope] rotated, the rows' latent
+    rows [N, W])`` — a row is what the pool holds of a token: ``c_kv`` after
+    its norm, the shared rotary key after rotation, zeros up to ``W``."""
+    m, H = spec.mla, spec.num_heads
+    R, dn, dr = m["kv_lora_rank"], m["qk_nope_head_dim"], m["qk_rope_head_dim"]
+    dtype = spec.dtype
+    with jax.named_scope("q_proj"):
+        cq = _norm(_mm(h1, w["wqa"]), {"scale": w["q_a_norm"]}, "rms",
+                   spec.eps, dtype)
+        # the [N, H * (nope + rope)] result stays a value of its own, as
+        # the q/k/v results below do (PR 30): left to fold the reshape to
+        # heads and the rotation into the dot's output layout, the TPU
+        # compiler staged layer l of wqb (18 MiB) and copied it transposed in
+        # every layer, 1.6 ms of a 22.7 ms decode step (PR 33)
+        q = jax.lax.optimization_barrier(_mm(cq, w["wqb"])).reshape(
+            -1, H, dn + dr)
+        q_rope = _rope_flat(q[..., dn:], positions, spec.rope_theta, None)
+    with jax.named_scope("kv_latent"):
+        kva = _mm(h1, w["wkva"])
+        ckv = _norm(kva[:, :R], {"scale": w["kv_a_norm"]}, "rms", spec.eps,
+                    dtype)
+        k_rope = _rope_flat(kva[:, None, R:], positions, spec.rope_theta,
+                            None)[:, 0]
+        lat = jnp.concatenate(
+            [ckv, k_rope,
+             jnp.zeros((ckv.shape[0], latent_width(spec) - R - dr), dtype)],
+            axis=-1)
+    return q[..., :dn], q_rope, lat
+
+
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                        lora=None, experts=None, l=0):
     """Shared per-layer transformer body for BOTH the ragged forward (put
@@ -1158,6 +1317,16 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
             h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)
             attn_out, *state = attend(h1)
+    elif spec.mla is not None:
+        # latent attention: ``attend(q_nope [N, H, nope], q_rope [N, H,
+        # rope], latent rows [N, W]) -> (attention output [N, H * v],
+        # *state)`` writes the rows into the latent pages and attends in the
+        # form its program uses (expanded or absorbed: ragged_mla.py)
+        with jax.named_scope("attn"), jax.named_scope("mla"):
+            h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
+                       spec.norm_plus_one)
+            attn_raw, *state = attend(*_mla_project(spec, w, h1, positions))
+            attn_out = _mm(attn_raw, w["wo"])
     else:
         # the two halves carry scopes: a device trace tells the layer's
         # attention (projections, rope, KV write, kernel) from its FFN
@@ -1452,6 +1621,9 @@ def build_ragged_forward(spec: RaggedModelSpec,
     When ``tp > 1`` the paged attention kernels run under shard_map on the
     'tensor' axis (heads sharded); everything else partitions via XLA SPMD.
     """
+    if spec.mla is not None:
+        from deepspeed_tpu.inference.v2.ragged_mla import build_paged_pass
+        return build_paged_pass(spec)
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     hid = spec.hidden_size
     dtype = spec.dtype
@@ -1547,6 +1719,9 @@ def build_prefill_forward(spec: RaggedModelSpec,
     32x128-token prompts: paged-chunk path 13 ms/layer attention vs ~1 ms
     packed — wave throughput 8k -> 30k+ tok/s.
     """
+    if spec.mla is not None:
+        from deepspeed_tpu.inference.v2.ragged_mla import build_packed_prefill
+        return build_packed_prefill(spec)
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
 
@@ -1837,6 +2012,11 @@ def build_multistep_decode(spec: RaggedModelSpec, n_steps: int,
     *consumed* by step j (ids0 first), and ``final_logits`` predict the token
     after the last generated one (so the serving loop can continue seamlessly).
     """
+    if spec.mla is not None:
+        # latent pages: the side-buffer schedule always (its gates are the
+        # K/V kernels'; tp > 1 and LoRA are refused at build)
+        from deepspeed_tpu.inference.v2.ragged_mla import build_multistep
+        return build_multistep(spec, n_steps, do_sample, top_k)
     general = _build_multistep_general(spec, n_steps, mesh=mesh, tp=tp,
                                        do_sample=do_sample, top_k=top_k,
                                        lora_targets=lora_targets,
@@ -1991,6 +2171,9 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
         raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
             what="the speculative verify step (rejected drafts have already "
             "advanced the state; rolling back needs the state before them)"))
+    if spec.mla is not None:
+        from deepspeed_tpu.inference.v2.ragged_mla import build_verify
+        return build_verify(spec, k)
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
     K1 = k + 1
@@ -2083,20 +2266,27 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
         x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
                   spec.norm_plus_one)
         logits = _unembed(spec, weights, x).reshape(S, K1, -1)
-        # greedy accept: the SAME argmax _sample_logits greedy runs, so an
-        # accepted token is exactly the token sequential decode would emit
-        pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # [S, K1]
-        match = (pred[:, :k] == draft) if k else jnp.zeros((S, 0), bool)
-        match = match & (jnp.arange(k, dtype=jnp.int32)[None]
-                         < n_draft[:, None])
-        accept = jnp.cumprod(match.astype(jnp.int32), axis=1).sum(axis=1)
-        next_ids = jnp.take_along_axis(pred, accept[:, None], axis=1)[:, 0]
-        final_logits = jnp.take_along_axis(
-            logits, accept[:, None, None], axis=1)[:, 0]           # [S, V]
-        accept_row = jnp.stack([accept, next_ids]).astype(jnp.int32)
-        return accept_row, next_ids, final_logits, new_kv
+        return _greedy_accept(logits, draft, n_draft) + (new_kv,)
 
     return fwd
+
+
+def _greedy_accept(logits, draft, n_draft):
+    """The verify step's accept rule on its logits ``[S, k + 1, V]``:
+    ``(accept_row [2, S], next_ids [S], final_logits [S, V])``. The SAME
+    argmax ``_sample_logits`` greedy runs, so an accepted token is exactly
+    the token sequential decode would emit."""
+    S, k = draft.shape
+    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)           # [S, K1]
+    match = (pred[:, :k] == draft) if k else jnp.zeros((S, 0), bool)
+    match = match & (jnp.arange(k, dtype=jnp.int32)[None]
+                     < n_draft[:, None])
+    accept = jnp.cumprod(match.astype(jnp.int32), axis=1).sum(axis=1)
+    next_ids = jnp.take_along_axis(pred, accept[:, None], axis=1)[:, 0]
+    final_logits = jnp.take_along_axis(
+        logits, accept[:, None, None], axis=1)[:, 0]               # [S, V]
+    accept_row = jnp.stack([accept, next_ids]).astype(jnp.int32)
+    return accept_row, next_ids, final_logits
 
 
 def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,

@@ -209,10 +209,12 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
                            window: Optional[int] = None):
     """Packed ragged-prefill flash attention (inference fast path; fwd only).
 
-    q [R, H, D]; k/v [R, Hkv, D] (GQA kv repeated in here); segment_ids [R]
+    q [R, H, D]; k [R, Hkv, D], v [R, Hkv, Dv] (GQA kv repeated in here; Dv
+    may differ from D — latent attention's expanded form has q/k of 192 and
+    v of 128); segment_ids [R]
     int32 — rows attend only same-segment rows at <= their own row index.
     Padding rows should carry segment -1 (they then attend only other padding,
-    and their output is never read). Returns [R, H, D] (plus lse [R, H] fp32
+    and their output is never read). Returns [R, H, Dv] (plus lse [R, H] fp32
     when ``with_lse`` — the hook for merging with paged prior-context
     attention).
 
@@ -223,7 +225,7 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
     32x128 rows, v5e-1).
     """
     R, H, D = q.shape
-    Hkv = k.shape[1]
+    Hkv, Dv = k.shape[1], v.shape[2]
     assert H % Hkv == 0
     rep = H // Hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / (D ** 0.5)
@@ -255,18 +257,18 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, bq, D), lambda h, iq, ik: (0, h, iq, 0)),
             # GQA: kv head = q head // rep, no materialised repeat
             pl.BlockSpec((1, 1, bk, D), lambda h, iq, ik: (0, h // rep, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda h, iq, ik: (0, h // rep, ik, 0)),
+            pl.BlockSpec((1, 1, bk, Dv), lambda h, iq, ik: (0, h // rep, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda h, iq, ik: (0, h, iq, 0)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda h, iq, ik: (0, h, iq, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda h, iq, ik: (0, h, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, H, Rp, D), q.dtype),
+            jax.ShapeDtypeStruct((1, H, Rp, Dv), q.dtype),
             jax.ShapeDtypeStruct((1, H, Rp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],

@@ -30,6 +30,8 @@ from jax.sharding import SingleDeviceSharding
 from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
                                                       flash_attention_packed)
+from deepspeed_tpu.ops.pallas.mla_attention import (mla_paged_attention,
+                                                    mla_row_write)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     kv_scale_tiles_shape, paged_chunk_attention_batched,
     paged_decode_attention, paged_decode_attention_sidebuf,
@@ -88,7 +90,39 @@ def _decode_smalld(h, h_kv, d):
             [((S, h, d), BF16), _pool(h_kv, d), _BT, _CL])
 
 
+# latent attention at JoyAI-LLM-Flash's widths: rows of 512 + 64 values in 640
+# (whole lane tiles), 32 query heads, 80 pages a sequence, 40 layers of pages
+_LAT = dict(heads=32, v_dim=512, softmax_scale=192 ** -0.5)
+_LAT_POOL = ((40 * 64, BS, 640), BF16)
+_LAT_Q = ((32, 32, 640), BF16)
+_LAT_BT, _LAT_CL = ((32, 80), I32), ((32,), I32)
+
+
 CASES = {
+    # one query token a sequence, its 32 heads the rows of both products
+    "mla_decode": (lambda *a: mla_paged_attention(*a, **_LAT),
+                   [_LAT_Q, _LAT_POOL, _LAT_BT, _LAT_CL, _LAT_CL]),
+    # the fused decode schedule: frozen pages + a side slab of 8 rows, the
+    # layer's slab picked by a traced index
+    "mla_decode_side": (
+        lambda q, p, bt, q0, cl, side, j, l: mla_paged_attention(
+            q, p, bt, q0, cl, side=side, side_j=j, layer_idx=l, **_LAT),
+        [_LAT_Q, _LAT_POOL, _LAT_BT, _LAT_CL, _LAT_CL,
+         ((40, 32, 8, 640), BF16), ((), I32), ((), I32)]),
+    # 4 chunk slots of 256 tokens: 8,192 query rows a slot in blocks of 512
+    "mla_chunk": (lambda *a: mla_paged_attention(*a, **_LAT),
+                  [((4, 256 * 32, 640), BF16), _LAT_POOL, ((4, 80), I32),
+                   ((4,), I32), ((4,), I32)]),
+    "mla_row_write": (
+        lambda p, side, bt, pre: mla_row_write(p, side, bt, pre, 1),
+        [((40, 64, BS, 640), BF16), ((40, 32, 8, 640), BF16), _LAT_BT,
+         _LAT_CL]),
+    # the expanded form's packed flash: q/k of 192 (128 + the 64 rotary
+    # values), v of 128
+    "packed_prefill_qk192_v128": (
+        lambda q, k, v, seg: flash_attention_packed(q, k, v, seg),
+        [((_PACKED, H, 192), BF16), ((_PACKED, H, 192), BF16),
+         ((_PACKED, H, D), BF16), ((_PACKED,), I32)]),
     "flash_fwd": (lambda q, k, v: flash_attention(q, k, v, causal=True),
                   [_TRAIN] * 3),
     "flash_bwd": (jax.grad(_flash_sq, argnums=(0, 1, 2)), [_TRAIN] * 3),
@@ -551,6 +585,116 @@ def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < 256 << 20
+
+
+def _joyai_flash(arr, pages=700):
+    """Spec and stacked weight trees (shapes only) of JoyAI-LLM-Flash, all 40
+    layers at published widths with experts 0-15 of 256 held, as
+    ``adapt_joyai`` stacks them, and its pool of ``pages`` latent pages."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM
+    cfg = JoyaiConfig.joyai_llm_flash(dtype=BF16, experts_held=(0, 16))
+    model = JoyaiForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_joyai(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    return spec, weights, arr(BF16, 40, pages + 1, BS, rm.latent_width(spec))
+
+
+@pytest.mark.parametrize("program", ["serve_decode_step",
+                                     "serve_prefill_packed",
+                                     "serve_paged_pass"])
+def test_joyai_programs_keep_the_latent_pool_and_the_weights_in_place(
+        program, v5e, monkeypatch):
+    """JoyAI-LLM-Flash as the benchmark's configuration runs it: all 40
+    layers at published widths, 16 of 256 experts held (8.90 GiB of weights)
+    and 700 latent pages (4.28 GiB): the 32-row decode step, the packed
+    prefill pass (4 slots of 256) and the paged pass. The latent kernels are
+    in the program; the pool is the output's buffer and no instruction
+    copies it; and the up-projections ``w_uk``/``w_uv`` (4 MiB each a layer)
+    are read where they lie — stored ``[R, H, d]`` they were copied
+    transposed in every layer of the decode step — and no layer's matrix is
+    staged before its dot."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _joyai_flash(arr)
+    assert [n for _, _, n in rm.layer_runs(spec)] == [1, 39]
+    assert kv.shape == (40, 701, 128, 640)
+    host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=32,
+                       max_blocks=80).device_arrays()
+    if program == "serve_decode_step":
+        rows = 32
+        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
+                                   arr(I32, rows, 80), arr(I32, rows),
+                                   arr(jnp.uint32, 2), arr(F32)).compile()
+        kernels = ("mla_decode", "mla_row_write")
+    elif program == "serve_prefill_packed":
+        batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
+                 else arr(I32, *host[k].shape) for k in rm.PREFILL_PASS_KEYS}
+        compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels = ("flash_fwd_packed",)
+    else:
+        batch = {k: arr(I32, *host[k].shape) for k in rm.PAGED_PASS_KEYS}
+        compiled = jax.jit(rm.build_ragged_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels = ("mla_decode", "mla_chunk")
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert kernel in text, f"{kernel} is not in the program"
+    pool = math.prod(kv.shape) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 256 << 20
+    instructions, _ = _executed(text)
+    pools = {kv.shape, (40 * 701,) + kv.shape[2:],
+             (40 * 701 * 128, 640)}
+    moved = [line.strip()[:120] for _, dims, op, line in instructions
+             if dims in pools and op not in _FREE + (
+                 "fusion", "custom-call", "while", "tuple", "scatter")]
+    assert not moved, f"the latent pool is copied or laid out anew: {moved}"
+    ups = [line.strip()[:120] for _, dims, op, line in instructions
+           if op in ("copy", "transpose")
+           and sorted(dims) in (sorted((32, 512, 128)),)]
+    assert not ups, f"w_uk / w_uv are copied in every layer: {ups}"
+    # nor is a layer's q_b_proj (18 MiB) staged and copied transposed before
+    # its dot: 1.6 ms of the decode step on the chip until its result went
+    # behind a barrier, as the dense q/k/v results do (PR 30)
+    assert "constant_dynamic-slice_fusion" not in text
+
+
+@pytest.mark.parametrize("pages", [1, 8, 64])
+@pytest.mark.parametrize("page", [(BS, 640), (2, 8, BS, 128)],
+                         ids=["latent", "kv"])
+def test_page_gather_stages_no_pool(page, pages, v5e):
+    """The engine's page gather (preempt-offload, ``export_kv``, and every
+    engine's warm-up) over the benchmark's pools — latent rows, 700 pages of
+    6.25 MiB over 40 layers, and keys and values per head, 791 pages of 8
+    MiB over 16: as one gather of whole latent pages the compiled program
+    held 4.3 GiB of temporaries — the whole pool staged — and the warm-up
+    ran out of memory on the chip; a page at a time it holds next to none,
+    whichever the layout."""
+    from deepspeed_tpu.inference.v2.engine_v2 import _gather_pages
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    layers, blocks = (40, 701) if len(page) == 2 else (16, 792)
+    pool = arr(BF16, layers, blocks, *page)
+    compiled = jax.jit(_gather_pages).lower(pool, arr(I32, pages)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == pages * layers * math.prod(page) * 2
+    assert mem.temp_size_in_bytes < 16 << 20
 
 
 # --------------------------------------------------------------------------- #
