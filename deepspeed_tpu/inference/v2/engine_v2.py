@@ -90,6 +90,14 @@ def _program(fn, name: str, **jit_kwargs):
     return jax.jit(fn, **jit_kwargs)
 
 
+def _moe_kernel_counts() -> Dict[str, int]:
+    """``serve/moe/grouped_kernel/<pallas|xla>`` of ``tracer.totals`` by
+    kernel: MoE layer runs traced so far in this process."""
+    prefix = "serve/moe/grouped_kernel/"
+    return {k[len(prefix):]: int(v) for k, v in dict(_tracer.totals).items()
+            if k.startswith(prefix)}
+
+
 def _rung(sp: int) -> str:
     return "" if int(sp) <= 1 else f"_sk{int(sp)}"
 
@@ -960,6 +968,7 @@ class InferenceEngineV2:
         comparison legs sharing the engine — adds zero timed compiles).
         """
         before = self.compiles
+        kernels_before = _moe_kernel_counts()
         grid = sorted({next_pow2(int(b)) for b in buckets}) \
             if buckets is not None else self.decode_buckets
         if spec_ks is None:
@@ -1077,8 +1086,14 @@ class InferenceEngineV2:
                         self._zero_row(to), part,
                         np.full((b,), to, np.int32)))
         built = self.compiles - before
+        # which grouped-GEMM kernel the MoE layer runs of those programs took
+        # (ragged_model.moe_grouped_kernel; always-on counters in
+        # tracer.totals, counted as a program is traced)
+        took = {k: n - kernels_before.get(k, 0)
+                for k, n in _moe_kernel_counts().items()}
+        moe = f"; MoE layer runs by grouped kernel: {took}" if took else ""
         log_dist(f"engine_v2: warmup built {built} programs "
-                 f"(buckets={grid}, burst_steps={list(burst_steps)})",
+                 f"(buckets={grid}, burst_steps={list(burst_steps)}){moe}",
                  ranks=[0])
         return built
 

@@ -21,15 +21,17 @@ Every builder routes attention through ONE ``AttentionKernelSpec``
 (``inference/v2/attention.py``): kernel variants key on the pool dtype at
 the call (``kv_scales=None`` = bf16/f32 pages), window/alibi/TP bind once.
 
-MoE layers use sort-based grouped GEMM (``jax.lax.ragged_dot``) — the TPU
+MoE layers use sort-based grouped GEMM (``ops/pallas/grouped_matmul.py`` for
+bfloat16 stacks of small expert matrices, ``jax.lax.ragged_dot`` otherwise:
+``moe_grouped_kernel``) — the TPU
 analog of the reference's CUTLASS ``moe_gemm`` + moe_scatter/gather
 (``inference/v2/kernels/cutlass_ops``, ``ragged_ops/moe_{scatter,gather}``).
 The expert stacks ``[L, E, K, N]`` are NOT scanned with the other layer
 weights: the grouped GEMM is a custom call, a scan's per-layer slice cannot
 fuse into it, and the compiler copied each layer's stacks to a temporary
 first. The layer body closes over the whole stacks and ``_moe_ffn`` addresses
-layer ``l`` as groups ``[l*E, (l+1)*E)`` of the ``[L*E, K, N]`` view, every
-other group empty (``_split_expert_stacks``; docs/SERVING.md "MoE layers").
+layer ``l`` as groups ``[l*E, (l+1)*E)`` of the ``[L*E, K, N]`` view, no
+other group read (``_split_expert_stacks``; docs/SERVING.md "MoE layers").
 """
 
 from __future__ import annotations
@@ -43,6 +45,9 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
 from deepspeed_tpu.inference.v2.ragged.state_pool import StatefulKV
+from deepspeed_tpu.monitor.trace import tracer as _tracer
+from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                     plan_visits, row_tile)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
 from deepspeed_tpu.ops.pallas.ssm import ssm_chunk_scan, ssm_decode_step
@@ -817,6 +822,33 @@ def _kind_splits(spec: "RaggedModelSpec", run_spec: "RaggedModelSpec",
     return n_splits
 
 
+#: the shape rule of :func:`moe_grouped_kernel`, fixed from the chip table in
+#: PERF.md (PR 34; ``scripts/moe_grouped_table.py`` measures it again)
+GROUPED_PALLAS_MATRIX_BYTES = 8 << 20
+
+
+def moe_grouped_kernel(stack, dtype) -> str:
+    """Which kernel an MoE layer's grouped products take, from what is static
+    at trace time: ``"pallas"`` (``ops/pallas/grouped_matmul.py``) for
+    bfloat16 stacks of small matrices — many small experts, read at 700 GB/s
+    where XLA's kernel reads them at 320-430; ``"xla"``
+    (``jax.lax.ragged_dot``) for everything else: int8 stacks (``{"w8",
+    "scale"}``), float32, matrices past ``GROUPED_PALLAS_MATRIX_BYTES``
+    (Mixtral's 112 MiB: XLA's kernel is at 80% of the HBM rate there),
+    widths that are not whole 128-lane tiles. The row count is no part of it:
+    at a decode step's 256 assignments and at a prefill pass's 8,192 the
+    table reads alike. ``stack`` is one of the layer's expert stacks
+    ``[.., K, N]``, ``dtype`` the activations'."""
+    if isinstance(stack, dict):
+        return "xla"
+    K, N = stack.shape[-2:]
+    if not (stack.dtype == dtype == jnp.bfloat16) or K % 128 or N % 128:
+        return "xla"
+    if K * N * 2 > GROUPED_PALLAS_MATRIX_BYTES:
+        return "xla"
+    return "pallas"
+
+
 def moe_route(x: jax.Array, w: Dict, top_k: int,
               routing: Optional[Dict[str, Any]] = None
               ) -> Tuple[jax.Array, jax.Array]:
@@ -850,8 +882,9 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     An expert matrix in ``w`` is one layer's ``[E, K, N]`` or the whole
     ``[L, E, K, N]`` stack with ``l`` the layer to use (see
     ``_split_expert_stacks``): the stack is viewed as ``L*E`` groups and the
-    layer's group sizes sit at offset ``l*E`` among zeros, so the kernel
-    reads the layer's experts where they lie and visits no other group.
+    kernel (:func:`moe_grouped_kernel` says which of two) reads the layer's
+    experts where they lie — group ``g`` is matrix ``l*E + g`` — and no
+    other.
 
     ``routing`` is ``spec.moe``: without a ``score_func`` the router is
     Mixtral's (top-k of the logits, softmax over the chosen); ``"sigmoid"``
@@ -881,13 +914,19 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
             expert_ids = jnp.where(on, local, E)
             gates = jnp.where(on.reshape(gates.shape), gates, 0.0)
         order = jnp.argsort(expert_ids)
-        # XLA:TPU runs its grouped-GEMM kernel only on a row count that is a
-        # multiple of 8; any other count lowers to a dense product over
-        # EVERY group of the rhs, all other layers' experts included. Rows
-        # past the last group belong to no expert and are dropped from ys.
-        rows = jnp.pad(order, (0, -order.shape[0] % 8))
+        kernel = moe_grouped_kernel(w["w_up"], x.dtype)
+        _tracer.bump(f"serve/moe/grouped_kernel/{kernel}")
+        # the Pallas kernel walks whole row tiles. XLA:TPU runs its own
+        # grouped-GEMM kernel only on a row count that is a multiple of 8;
+        # any other count lowers to a dense product over EVERY group of the
+        # rhs, all other layers' experts included. Rows past the last group
+        # belong to no expert and are dropped from ys.
+        tm = row_tile(order.shape[0]) if kernel == "pallas" else 8
+        rows = jnp.pad(order, (0, -order.shape[0] % tm))
         xs = x[tok_idx[rows]]                                  # [T*K + pad, hid]
         group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
+        if kernel == "pallas":      # one plan for the layer's three products
+            visits = plan_visits(group_sizes, rows.shape[0], tm)
         row_e = expert_ids[rows]
         if held is not None:
             row_e = jnp.minimum(row_e, E - 1)
@@ -904,6 +943,13 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
                                      preferred_element_type=jnp.float32)
             return (raw * rhs["scale"][row_e, 0, :]).astype(lhs.dtype)
         groups = rhs.reshape((-1,) + rhs.shape[-2:])       # [L*E, K, N]
+        if kernel == "pallas":
+            # each touched expert read once where it lies: group g of this
+            # layer is matrix l*E + g, and an empty group is never fetched
+            return grouped_matmul(lhs, groups, visits,
+                                  l if groups.shape[0] != E else 0)
+        # XLA's kernel takes its sizes over every group of the rhs: the
+        # layer's at offset l*E among zeros
         sizes = group_sizes
         if groups.shape[0] != E:
             sizes = jax.lax.dynamic_update_slice(

@@ -274,6 +274,63 @@ def test_moe_layer_scan_reads_expert_stacks_in_place(T, v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("T", [1, 32])
+@pytest.mark.parametrize("model", ["trinity", "joyai_held"])
+def test_moe_layer_scan_of_small_experts_takes_the_pallas_grouped_matmul(
+        model, T, v5e, monkeypatch):
+    """The same layer scan at Trinity-Mini's expert shapes (128 experts of
+    2048 x 1024, top-8 of a sigmoid router) and at JoyAI's held share (16 of
+    256 experts of 2048 x 768 behind the full router): bfloat16 matrices of 4
+    and 3 MiB take ``ops/pallas/grouped_matmul.py`` — three Mosaic calls in
+    the scan body (gate, up, down) under the scope ``moe_ffn/experts``, no
+    ``ragged-dot`` custom call and so no ``ragged-dot-metadata`` kernel, and
+    the stacks reach the calls whole: nothing copies or slices a layer's
+    experts. Mixtral's 112 MiB matrices stay on XLA's kernel: the case above
+    keeps holding."""
+    from deepspeed_tpu.inference.v2.ragged_model import (_moe_ffn,
+                                                         _split_expert_stacks)
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    L, K = 4, 2048
+    E, held, N, routed = ((128, None, 1024, 128) if model == "trinity"
+                          else (16, (0, 16), 768, 256))
+    routing = {"score_func": "sigmoid", "route_norm": True,
+               "route_scale": 2.826}
+    if held:
+        routing["held"] = held
+    chip = SingleDeviceSharding(v5e[0])
+
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=chip)
+
+    layers = {"moe": {"router": bf16(L, K, routed),
+                      "expert_bias": bf16(L, routed),
+                      "w_gate": bf16(L, E, K, N), "w_up": bf16(L, E, K, N),
+                      "w_down": bf16(L, E, N, K)}}
+
+    def fwd(layers, x):
+        scanned, experts = _split_expert_stacks(layers)
+
+        def layer_fn(x, wl):
+            w, l = wl
+            return x + _moe_ffn(x, {**w["moe"], **experts}, 8, BF16, l,
+                                routing=routing), None
+
+        return jax.lax.scan(layer_fn, x,
+                            (scanned, jnp.arange(L, dtype=I32)))[0]
+
+    compiled = jax.jit(fwd).lower(layers, bf16(T, K)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3 and all(
+        "moe_ffn/experts/moe_grouped_matmul" in ln for ln in calls), calls
+    assert "ragged-dot" not in text
+    stack = re.compile(rf"= bf16\[(\d+,)*({K},{N}|{N},{K})\]\S* "
+                       r"(copy|dynamic-slice|dynamic_slice|fusion)\(")
+    staged = [ln.strip()[:120] for ln in text.splitlines() if stack.search(ln)]
+    assert not staged, f"a layer's expert stack is materialised: {staged}"
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def _on(chip):
     def arr(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
