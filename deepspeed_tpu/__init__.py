@@ -73,6 +73,11 @@ def initialize(args=None,
     # trace reaches the same tracer through the engine) — docs/OBSERVABILITY.md
     from deepspeed_tpu.monitor import trace as _trace
     _trace.install_from_env()
+    # the process's account of what jax traces, lowers and compiles, by the
+    # stage of set-up (idempotent; the serving engine installs it through
+    # setup_compile_cache) — docs/OBSERVABILITY.md, "Set-up and compiles"
+    from deepspeed_tpu.utils.compile_cache import install_compile_listener
+    install_compile_listener()
 
     config = DeepSpeedTPUConfig.load(config if config is not None else config_params)
     comm.init_distributed()
@@ -87,22 +92,24 @@ def initialize(args=None,
             "tensor_parallel": {"tp_size": config.hybrid_engine.inference_tp_size},
             "max_out_tokens": config.hybrid_engine.max_out_tokens,
         }
-    engine = engine_cls(
-        args=args,
-        model=model,
-        optimizer=optimizer,
-        model_parameters=model_parameters,
-        training_data=training_data,
-        lr_scheduler=lr_scheduler,
-        mesh_topology=mesh_topology,
-        collate_fn=collate_fn,
-        config=config,
-        rngs=rngs,
-        tp_rules=tp_rules,
-        model_family=model_family,
-        param_specs=param_specs,
-        **engine_kwargs,
-    )
+    # set-up's first stage (tracer.stage): net of a state build inside it
+    with _trace.tracer.stage("engine_init"):
+        engine = engine_cls(
+            args=args,
+            model=model,
+            optimizer=optimizer,
+            model_parameters=model_parameters,
+            training_data=training_data,
+            lr_scheduler=lr_scheduler,
+            mesh_topology=mesh_topology,
+            collate_fn=collate_fn,
+            config=config,
+            rngs=rngs,
+            tp_rules=tp_rules,
+            model_family=model_family,
+            param_specs=param_specs,
+            **engine_kwargs,
+        )
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
 
 

@@ -50,6 +50,7 @@ from deepspeed_tpu.utils.fault_injection import maybe_fail as _maybe_fail
 from deepspeed_tpu.utils.logging import log_dist
 
 
+import contextlib
 import functools
 import time as _time
 
@@ -142,6 +143,16 @@ class InferenceEngineV2:
                  model_parameters: Any = None,
                  family: Optional[str] = None,
                  mesh_topology: Optional[MeshTopology] = None):
+        # serving runs don't pass through deepspeed_tpu.initialize — arm the
+        # span tracer from $DSTPU_TRACE here (no-op when unset/armed), before
+        # the first stage, so that a traced start's timeline holds all of it
+        _trace_from_env()
+        # the first stage of set-up (tracer.stage; docs/OBSERVABILITY.md,
+        # "Set-up and compiles"): net of the warm-up it may run
+        with _tracer.stage("engine_init"):
+            self._init(model, config, model_parameters, family, mesh_topology)
+
+    def _init(self, model, config, model_parameters, family, mesh_topology):
         self.config = RaggedInferenceEngineConfig.load(config)
         cfg = self.config
         # persistent XLA compile cache: configured FIRST so every program this
@@ -155,6 +166,7 @@ class InferenceEngineV2:
         # batch sizes stay in-grid must never increment this again.
         self.compiles = 0
         self._backend_compiles_base = _backend_compiles()
+        self._setup_logged = False
         tp = cfg.tensor_parallel
         if mesh_topology is not None:
             self.topology = set_topology(mesh_topology)
@@ -194,22 +206,27 @@ class InferenceEngineV2:
         if model_parameters is None:
             raise ValueError("InferenceEngineV2 needs model_parameters")
         from deepspeed_tpu.utils.tree import tree_cast
-        params = tree_cast(model_parameters, cfg.dtype)
-        self.spec, weights = adapt_model(family, params, model_config,
-                                         max_context=cfg.state_manager.max_context)
-        self.spec.dtype = cfg.dtype
-        if cfg.quantization.weight_bits in (4, 8):
-            if tp > 1:
-                raise NotImplementedError(
-                    "weight-only int4/int8 with tensor_parallel > 1 is not "
-                    "wired yet (the AutoTP rule walker shards plain arrays); "
-                    "run quantized at tp=1 or bf16 under tp")
-            from deepspeed_tpu.inference.v2.ragged_model import (
-                quantize_weights_int4, quantize_weights_int8)
-            weights = (quantize_weights_int8(weights)
-                       if cfg.quantization.weight_bits == 8
-                       else quantize_weights_int4(weights))
-        self.weights = self._shard_weights(weights)
+        with _tracer.stage("shard_weights"):
+            params = tree_cast(model_parameters, cfg.dtype)
+            self.spec, weights = adapt_model(
+                family, params, model_config,
+                max_context=cfg.state_manager.max_context)
+            self.spec.dtype = cfg.dtype
+            if cfg.quantization.weight_bits in (4, 8):
+                if tp > 1:
+                    raise NotImplementedError(
+                        "weight-only int4/int8 with tensor_parallel > 1 is "
+                        "not wired yet (the AutoTP rule walker shards plain "
+                        "arrays); run quantized at tp=1 or bf16 under tp")
+                from deepspeed_tpu.inference.v2.ragged_model import (
+                    quantize_weights_int4, quantize_weights_int8)
+                weights = (quantize_weights_int8(weights)
+                           if cfg.quantization.weight_bits == 8
+                           else quantize_weights_int4(weights))
+            # blocked on: the stage holds the copy to the device, not only
+            # its dispatch
+            self.weights = jax.block_until_ready(
+                self._shard_weights(weights))
 
         # KV cache + allocator + scheduler
         sm = cfg.state_manager
@@ -247,7 +264,9 @@ class InferenceEngineV2:
             latent_dim=None if self.spec.mla is None
             else latent_width(self.spec))
         self.scratch_block = nb
-        self.kv = BlockedKVCache(kv_cfg, self.topology)
+        with _tracer.stage("kv_alloc"):
+            self.kv = BlockedKVCache(kv_cfg, self.topology)
+            jax.block_until_ready(self.kv.kv)
         self.allocator = BlockedAllocator(nb)
         self.prefix_cache = None
         if cfg.prefix_cache.enabled:
@@ -275,7 +294,9 @@ class InferenceEngineV2:
                 d_state=m["d_state"], d_conv=m["d_conv"])
             self.scheduler.state_slots = StateSlotAllocator(
                 sm.max_tracked_sequences)
-            self.kv.kv = StatefulKV(self.kv.kv, *self.state_config.zeros())
+            with _tracer.stage("kv_alloc"):
+                self.kv.kv = jax.block_until_ready(StatefulKV(
+                    self.kv.kv, *self.state_config.zeros()))
         # sliding-window serving (Mistral/Qwen2): the scheduler ring-reuses
         # each sequence's pages beyond the window so KV stays bounded. The
         # ring engages only where EVERY layer is windowed: a model of mixed
@@ -393,9 +414,6 @@ class InferenceEngineV2:
                              compile_hook=_count_compile),
                 swap_buffers=cfg.lora.swap_buffers,
                 max_rank=cfg.lora.max_rank)
-        # serving runs don't pass through deepspeed_tpu.initialize — arm the
-        # span tracer from $DSTPU_TRACE here (no-op when unset/armed)
-        _trace_from_env()
         from deepspeed_tpu.inference.v2.ragged_model import (
             describe_layer_kinds)
         ring = self.scheduler.ring_pages
@@ -958,8 +976,15 @@ class InferenceEngineV2:
         Explicit ``buckets`` are rounded up to powers of two (the live path
         always rounds, so a non-pow2 bucket would be dead weight).
 
-        Returns the number of ENGINE programs built (``self.compiles``; the
-        bootstrap-sampler warms are module-level jits outside the counter).
+        Returns the number of ENGINE programs built (what ``self.compiles``
+        gained: this engine's own builds, so the bootstrap sampler's
+        module-level jits and eager helpers are not in it). The process-wide
+        count of what warm-up traced, lowered and compiled or loaded is
+        ``compile/warmup/programs`` in ``tracer.totals``, beside
+        ``setup/warmup_s`` and a ``setup/warmup/<family>_s`` for each family
+        of the grid that this configuration has; the line logged at the end
+        (``compile_cache.setup_summary``) gives both with the costliest
+        programs by name.
 
         ``spec_ks``: draft lengths to warm the speculative verify grid for
         — one ``build_verify_step`` program per (bucket, k). ``None``
@@ -967,6 +992,23 @@ class InferenceEngineV2:
         (so a spec-serving engine's steady state — including the spec-off
         comparison legs sharing the engine — adds zero timed compiles).
         """
+        # set-up's second stage (tracer.stage), a child a family of the grid
+        with _tracer.stage("warmup"):
+            built = self._warmup(buckets, burst_steps, spec_ks)
+        if not self._setup_logged:      # once: a rejoin warms again
+            self._setup_logged = True
+            from deepspeed_tpu.utils.compile_cache import setup_summary
+            log_dist(f"engine_v2: {setup_summary()}", ranks=[0])
+        return built
+
+    def _warmup(self, buckets, burst_steps, spec_ks) -> int:
+        stage = _tracer.stage
+
+        def family(name, members):
+            """The stage of one family of the grid: none where this
+            configuration has no member of it."""
+            return stage(name) if members else contextlib.nullcontext()
+
         before = self.compiles
         kernels_before = _moe_kernel_counts()
         grid = sorted({next_pow2(int(b)) for b in buckets}) \
@@ -1000,55 +1042,61 @@ class InferenceEngineV2:
             self._verify_progs.maxsize,
             (len(lora_rungs) + 1) * len(spec_ks) * len(grid)
             * len(attn_rungs) + 2)
-        self._warm_passes()
+        with stage("passes"):
+            self._warm_passes()
         mb = self.scheduler.max_blocks
-        for sp in attn_rungs:
-            for b in grid:
-                prog = self._decode_step_prog(b, False, 0, sp=sp)
-                args = self._scratch_step_args(b, mb)
-                nxt, _logits, new_kv = prog(self.weights, self.kv.kv, *args)
-                self.kv.update(new_kv)
-                jax.block_until_ready(nxt)
+        with stage("decode_grid"):
+            for sp in attn_rungs:
+                for b in grid:
+                    prog = self._decode_step_prog(b, False, 0, sp=sp)
+                    args = self._scratch_step_args(b, mb)
+                    nxt, _logits, new_kv = prog(self.weights, self.kv.kv,
+                                                *args)
+                    self.kv.update(new_kv)
+                    jax.block_until_ready(nxt)
         # the LoRA (bucket, rank-bucket) grid: every rung runs once over
         # all-pad rows with an all-zero-page table (exact-zero deltas — the
         # same traced shapes live mixed-tenant batches use)
-        for rb in lora_rungs:
-            for sp in attn_rungs:
-                for b in grid:
-                    prog = self._decode_step_prog(b, False, 0, rb, sp=sp)
-                    args = self._scratch_step_args(b, mb)
-                    lops = self._scratch_lora_args(b, rb)
-                    nxt, _logits, new_kv = prog(self.weights, self.kv.kv,
-                                                *args, *lops)
-                    self.kv.update(new_kv)
-                    jax.block_until_ready(nxt)
-        for n_steps in burst_steps:
-            for sp in attn_rungs:
-                for b in grid:
-                    fn = self._multistep.get_or_create(
-                        (n_steps, b, False, 0, sp),
-                        lambda n=n_steps, s=sp: self._build_multistep(
-                            n, False, 0, s))
-                    args = self._scratch_step_args(b, mb)
-                    out_ids, _logits, new_kv = fn(self.weights, self.kv.kv,
-                                                  *args)
-                    self.kv.update(new_kv)
-                    jax.block_until_ready(out_ids)
+        with family("lora_grid", lora_rungs):
+            for rb in lora_rungs:
+                for sp in attn_rungs:
+                    for b in grid:
+                        prog = self._decode_step_prog(b, False, 0, rb, sp=sp)
+                        args = self._scratch_step_args(b, mb)
+                        lops = self._scratch_lora_args(b, rb)
+                        nxt, _logits, new_kv = prog(self.weights, self.kv.kv,
+                                                    *args, *lops)
+                        self.kv.update(new_kv)
+                        jax.block_until_ready(nxt)
+        with family("multistep", burst_steps):
+            for n_steps in burst_steps:
+                for sp in attn_rungs:
+                    for b in grid:
+                        fn = self._multistep.get_or_create(
+                            (n_steps, b, False, 0, sp),
+                            lambda n=n_steps, s=sp: self._build_multistep(
+                                n, False, 0, s))
+                        args = self._scratch_step_args(b, mb)
+                        out_ids, _logits, new_kv = fn(self.weights,
+                                                      self.kv.kv, *args)
+                        self.kv.update(new_kv)
+                        jax.block_until_ready(out_ids)
         # the speculative (bucket, k) verify grid: every program runs once
         # over all-scratch rows with zero proposed drafts (accept masks and
         # page writes exercise the same traced shapes live traffic uses)
-        for k in spec_ks:
-            for b in grid:
-                for rb in [0] + lora_rungs:
-                    for sp in attn_rungs:
-                        prog = self._verify_prog(b, k, rb, sp=sp)
-                        args = self._scratch_verify_args(b, k, mb)
-                        lops = self._scratch_lora_args(b, rb)
-                        _acc, nxt, _fl, new_kv = prog(self.weights,
-                                                      self.kv.kv,
-                                                      *args, *lops)
-                        self.kv.update(new_kv)
-                        jax.block_until_ready(nxt)
+        with family("verify_grid", spec_ks):
+            for k in spec_ks:
+                for b in grid:
+                    for rb in [0] + lora_rungs:
+                        for sp in attn_rungs:
+                            prog = self._verify_prog(b, k, rb, sp=sp)
+                            args = self._scratch_verify_args(b, k, mb)
+                            lops = self._scratch_lora_args(b, rb)
+                            _acc, nxt, _fl, new_kv = prog(self.weights,
+                                                          self.kv.kv,
+                                                          *args, *lops)
+                            self.kv.update(new_kv)
+                            jax.block_until_ready(nxt)
         # the KV page round-trip pair (preempt-offload / page fabric) over
         # its whole bucket grid: rare path, but a preemption DURING the
         # timed steady state must not compile — warm both ops per bucket
@@ -1056,13 +1104,15 @@ class InferenceEngineV2:
         # round-trip their packed values+scale-tile payload the same way)
         # (a model with state-space layers moves no pages to the host: what
         # needs that is refused, see validate_engine_build)
-        for b in self.page_buckets if self.state_config is None else ():
-            pages = self.fetch_pages([self.scratch_block] * b)
-            self.put_pages(pages, [self.scratch_block] * b)
-        # the adapter-pool movers over their own rank-sized bucket grid — a
-        # mid-steady-state adapter fault/evict must never compile either
-        if self.lora is not None:
-            self.lora.pool.warm(self.config.lora.max_rank)
+        with family("page_movers", self.state_config is None
+                    or self.lora is not None):
+            for b in self.page_buckets if self.state_config is None else ():
+                pages = self.fetch_pages([self.scratch_block] * b)
+                self.put_pages(pages, [self.scratch_block] * b)
+            # the adapter-pool movers over their own rank-sized bucket grid —
+            # a mid-steady-state adapter fault/evict must never compile either
+            if self.lora is not None:
+                self.lora.pool.warm(self.config.lora.max_rank)
         # the greedy bootstrap sampler over every logits-source shape a
         # serving loop can hand it: without this, the FIRST pipeline run /
         # burst after startup pays a small-but-real compile that the engine
@@ -1072,19 +1122,20 @@ class InferenceEngineV2:
         src_rows = {sm.num_chunk_slots, sm.max_ragged_sequence_count} | set(grid)
         # (the logits source committed to the mesh, as a program's output is:
         # see _scratch_step_args)
-        for nr in src_rows:
-            logits = jax.device_put(jnp.zeros((nr, V), jnp.float32),
-                                    self.topology.replicated())
-            for b in grid:
-                part = serve_sample_rows(
-                    logits, np.zeros((b,), np.int32), self._rng_key,
-                    False, 0, 1.0)
-                # ... and its placement into every bucket's row that can
-                # hold it
-                for to in (g for g in grid if g >= b):
-                    jax.block_until_ready(serve_place_rows(
-                        self._zero_row(to), part,
-                        np.full((b,), to, np.int32)))
+        with stage("sampler"):
+            for nr in src_rows:
+                logits = jax.device_put(jnp.zeros((nr, V), jnp.float32),
+                                        self.topology.replicated())
+                for b in grid:
+                    part = serve_sample_rows(
+                        logits, np.zeros((b,), np.int32), self._rng_key,
+                        False, 0, 1.0)
+                    # ... and its placement into every bucket's row that can
+                    # hold it
+                    for to in (g for g in grid if g >= b):
+                        jax.block_until_ready(serve_place_rows(
+                            self._zero_row(to), part,
+                            np.full((b,), to, np.int32)))
         built = self.compiles - before
         # which grouped-GEMM kernel the MoE layer runs of those programs took
         # (ragged_model.moe_grouped_kernel; always-on counters in
@@ -1549,7 +1600,10 @@ class InferenceEngineV2:
         (``MonitorMaster.write_events`` shape): prefix-cache stats when the
         cache is on, and the decode pipeline's per-step timing/transfer
         breakdown (dispatch / host-build / fetch-drain / bubble, fetch bytes)
-        once any ``DecodePipeline`` has run."""
+        once any ``DecodePipeline`` has run; and the process's ``setup/*``
+        and ``compile/*`` totals (docs/OBSERVABILITY.md, "Set-up and
+        compiles")."""
+        monitor.write_events(_tracer.setup_events(step))
         if self.prefix_cache is not None:
             monitor.write_events(self.prefix_cache.stats.events(step))
         if self.pipeline_stats.steps:

@@ -49,6 +49,12 @@ Two recording APIs, matching two call-site shapes:
 ``instant(name)`` marks a point event (faults, admissions); ``counter(name,
 value)`` records a Perfetto counter track sample (queue depths).
 
+``stage(name)`` is the one always-on interval: a stage of SET-UP, whose wall
+time goes to ``totals["setup/<name>_s"]`` whether or not tracing is on (and
+to a span on lane ``setup`` when it is), and which names the phase that
+``utils/compile_cache.py`` charges jax's traces, lowerings and compiles to
+(docs/OBSERVABILITY.md, "Set-up and compiles").
+
 Tracks: by default a span lands on its recording THREAD's track (threads in
 this tree are descriptively named: ``dstpu-prefetch``, ``dstpu-hostopt_*``,
 ``dstpu-offload-upload``, ``ckpt-writer_*``, ``dstpu-ckpt-commit``). A
@@ -60,7 +66,7 @@ nesting within a track is always well-formed.
 
 Enable via ``DSTPU_TRACE=<dir>`` (arms in ``deepspeed_tpu.initialize`` and
 the v2 inference engine) or ``config.monitor.trace`` — docs/OBSERVABILITY.md
-walks the taxonomy, Perfetto workflow, and overhead numbers.
+walks the taxonomy, the Perfetto workflow and what tracing costs.
 
 **Captures** put these spans and the device's work on one clock.
 ``tracer.capture_start(dir)`` turns the rings on, starts ``jax.profiler`` and
@@ -108,6 +114,12 @@ _REQ_LANE_RE = re.compile(r"^serve/req/u\d+$")
 
 # record kinds (Chrome trace phase at export: span -> B/E pair)
 _SPAN, _INSTANT, _COUNTER = "X", "i", "C"
+
+#: the top-level stages of set-up and the compile phase each belongs to
+#: (:meth:`Tracer.stage`, :meth:`Tracer.phase`)
+STAGE_PHASE = {"engine_init": "build", "state_build": "build",
+               "warmup": "warmup", "remat_fit": "warmup",
+               "first_step": "warmup"}
 
 #: the two ``TraceAnnotation`` events a capture writes into the profiler's
 #: trace; their ``perf_counter`` times are known, so they map one clock onto
@@ -207,6 +219,57 @@ class Span:
         return self.t1 - self.t0
 
 
+class Stage:
+    """One open stage of set-up (:meth:`Tracer.stage`); ``.seconds`` is what
+    it was charged, valid after exit."""
+
+    __slots__ = ("_tracer", "name", "path", "phase", "top", "t0", "carved",
+                 "seconds")
+
+    def __init__(self, tracer: "Tracer", name: str):
+        self._tracer = tracer
+        self.name = name
+        self.path = name
+        self.phase = "build"
+        self.top = True
+        self.t0 = 0.0
+        self.carved = 0.0      # seconds of top-level stages opened inside
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Stage":
+        tr = self._tracer
+        with tr._totals_lock:
+            stack = tr._stages
+            self.top = self.name in STAGE_PHASE or not stack
+            if self.top:
+                self.phase = STAGE_PHASE.get(self.name, "build")
+            else:
+                self.path = f"{stack[-1].path}/{self.name}"
+                self.phase = stack[-1].phase
+            stack.append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        tr = self._tracer
+        with tr._totals_lock:
+            stack = tr._stages
+            if self in stack:       # not after a reset() in between
+                del stack[stack.index(self):]
+            self.seconds = max(0.0, (t1 - self.t0) - self.carved)  # jaxlint: disable=JL001 -- a stage's body blocks on the device work it started
+            if self.top:
+                for outer in stack:
+                    outer.carved += self.seconds
+                if self.name in ("warmup", "first_step"):
+                    tr._warmed = True
+            key = f"setup/{self.path}_s"
+            tr.totals[key] = tr.totals.get(key, 0.0) + self.seconds
+        if tr.enabled:
+            tr.add("setup/" + self.path, self.t0, t1, lane="setup")
+        return False
+
+
 @dataclass
 class Capture:
     """What :meth:`Tracer.capture_stop` returns. Times ending in ``_ns`` are
@@ -259,6 +322,11 @@ class Tracer:
         #: what they gained over its interval
         self.totals: Dict[str, float] = {}
         self._totals_lock = make_lock("monitor.trace.totals")
+        #: the open stages of set-up, outermost first (:meth:`stage`), and
+        #: whether a ``warmup`` or ``first_step`` stage has closed yet; both
+        #: under the totals lock
+        self._stages: List[Stage] = []
+        self._warmed = False
         self._capture_lock = make_lock("monitor.trace.capture")
         self._capture: Optional[dict] = None      # state of a running capture
         self._captures = 0
@@ -304,6 +372,9 @@ class Tracer:
         self._crash_path = None
         self._clock_sync = (time.perf_counter(), time.time())
         self._annotation = None
+        with self._totals_lock:
+            self._stages = []
+            self._warmed = False
 
     # ------------------------------------------------------------------ #
     # recording
@@ -380,6 +451,54 @@ class Tracer:
         engine's checkpointed layers keep): the newest choice stands."""
         with self._totals_lock:
             self.totals[name] = float(value)
+
+    # ------------------------------------------------------------------ #
+    # set-up: where a process's start goes, by stage
+    # ------------------------------------------------------------------ #
+
+    def stage(self, name: str) -> Stage:
+        """Context manager naming the stage of set-up the program is in.
+        ALWAYS ON: on exit ``setup/<name>_s`` in :attr:`totals` gains the
+        stage's wall time (a stage blocks on the device work it started
+        before it closes); under ``enabled`` the interval is also a span on
+        lane ``setup``. It closes on an exception like on a return.
+
+        A name of :data:`STAGE_PHASE` is a top-level stage: opened inside
+        another stage it is no child of it, and every stage open around it
+        is charged NET of it (an engine's ``engine_init`` net of the
+        ``warmup`` its constructor runs; a ``first_step`` net of the
+        ``state_build`` and ``remat_fit`` inside it), so the top-level
+        stages add up to wall time. Any other name is a child of the
+        innermost open stage — ``setup/<parent>/<name>_s``, inside the
+        parent's seconds — or, with none open, a top-level stage of phase
+        ``build``.
+
+        The stack is process-wide, under the totals lock: set-up runs on one
+        thread, and what another thread compiles meanwhile (a warm-up that
+        waits for a worker) is charged to the stage that is open
+        (:meth:`phase`). Two threads that open stages at once would nest
+        into each other."""
+        return Stage(self, name)
+
+    def phase(self) -> str:
+        """The compile phase of this moment (``utils/compile_cache.py``
+        charges what jax traces, lowers and compiles to it): ``build`` or
+        ``warmup`` by the innermost open stage; with none open ``before``
+        until a ``warmup`` or ``first_step`` stage has closed once in the
+        process (the caller's own programs), ``traffic`` from then on."""
+        with self._totals_lock:
+            if self._stages:
+                return self._stages[-1].phase
+            return "traffic" if self._warmed else "before"
+
+    def setup_events(self, step: int = 0) -> List[Tuple[str, float, int]]:
+        """The ``setup/*`` and ``compile/*`` totals as ``(name, value,
+        step)`` monitor events: the engines' monitor writes carry them to
+        ``MonitorMaster``'s sinks and the ``/metrics`` exporter."""
+        with self._totals_lock:
+            return [(name, float(value), step)
+                    for name, value in sorted(self.totals.items())
+                    if name.startswith(("setup/", "compile/"))]
 
     # ------------------------------------------------------------------ #
     # captures: the rings and the profiler over one interval, on one clock

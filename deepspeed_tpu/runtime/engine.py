@@ -128,8 +128,10 @@ class _FittedStep:
         key = (treedef, tuple((x.shape, x.dtype) for x in leaves))
         step = self._compiled.get(key)
         if step is None:
-            step = self._compiled[key] = self._engine._compile_fitted(
-                state, batch)
+            # a stage of set-up, a child ``rung<k>`` for each rung it lowers
+            with _tracer.stage("remat_fit"):
+                step = self._compiled[key] = self._engine._compile_fitted(
+                    state, batch)
         return step(state, batch)
 
 
@@ -352,9 +354,10 @@ class DeepSpeedTPUEngine:
         if self.progressive_layer_drop is not None:
             self._rng, self._pld_base_key = jax.random.split(self._rng)
         if model_parameters is not None:
-            self._init_state(model_parameters)
+            self._build_state(model_parameters)
 
         # -- jitted steps (built lazily, after state exists) ---------------
+        self._first_step_done = False   # train_batch's one branch (set-up)
         self._fused_step = None
         self._micro_step = None
         self._apply_step = None
@@ -372,6 +375,15 @@ class DeepSpeedTPUEngine:
     # ------------------------------------------------------------------ #
     # state init
     # ------------------------------------------------------------------ #
+
+    def _build_state(self, model_parameters: Any,
+                     init_params: Optional[Callable[[], Any]] = None):
+        """:meth:`_init_state` as a stage of set-up (``tracer.stage``): run
+        and blocked on, so ``setup/state_build_s`` holds the build and not
+        only its dispatch."""
+        with _tracer.stage("state_build"):
+            self._init_state(model_parameters, init_params)
+            jax.block_until_ready(self.state)
 
     def _init_state(self, model_parameters: Any,
                     init_params: Optional[Callable[[], Any]] = None):
@@ -1101,7 +1113,8 @@ class DeepSpeedTPUEngine:
                             other_bytes=grads)
 
     def _make_fused_step(self, batch_tree):
-        self.remat_plan = self._plan_remat(batch_tree)
+        with _tracer.stage("remat_fit"), _tracer.stage("plan"):
+            self.remat_plan = self._plan_remat(batch_tree)
         if self.remat_plan is None:
             return self._jit_fused_step()
         return _FittedStep(self)
@@ -1118,7 +1131,9 @@ class DeepSpeedTPUEngine:
             plan = self.remat_plan
             last = plan.rung == len(ac.LADDER) - 1
             try:
-                compiled = self._jit_fused_step().lower(state, batch).compile()
+                with _tracer.stage(f"rung{plan.rung}"):
+                    compiled = self._jit_fused_step().lower(
+                        state, batch).compile()
             except jax.errors.JaxRuntimeError as e:
                 if last or "RESOURCE_EXHAUSTED" not in str(e):
                     raise
@@ -1223,7 +1238,7 @@ class DeepSpeedTPUEngine:
         def init_params():
             return self.module.init(init_rng, micro)["params"]
 
-        self._init_state(jax.eval_shape(init_params), init_params)
+        self._build_state(jax.eval_shape(init_params), init_params)
 
     def _inject_pld(self, batch, leading: int, step: Optional[int] = None,
                     micro: Optional[int] = None):
@@ -1367,6 +1382,8 @@ class DeepSpeedTPUEngine:
         and ``_after_step`` drains the PREVIOUS step's metrics while this
         one runs. ``wall_clock_breakdown`` restores the fully synchronous
         reference loop."""
+        if not self._first_step_done:
+            return self._first_train_batch(batch, data_iter)
         from deepspeed_tpu.runtime.data_pipeline import StagedBatch
         # mid-run preemption point: a ``step.kill`` fault plan kills here,
         # modelling a spot-VM SIGTERM landing between (or inside) steps
@@ -1470,6 +1487,22 @@ class DeepSpeedTPUEngine:
                 _tracer.counter("train/prefetch/queue_depth", queue_depth,
                                 lane="train/step")
         return metrics["loss"]
+
+    def _first_train_batch(self, batch, data_iter):
+        """The first step as the last stage of set-up (``tracer.stage``): it
+        builds the state if the engine was given none and compiles the step,
+        and the stage closes when its loss has been fetched, so
+        ``setup/first_step_s`` (net of the ``state_build`` and ``remat_fit``
+        inside it) is over before the second step is dispatched. Then the one
+        line that says where set-up went."""
+        self._first_step_done = True
+        with _tracer.stage("first_step"):
+            # (not self.train_batch: a subclass wraps it)
+            loss = DeepSpeedTPUEngine.train_batch(self, batch, data_iter)
+            jax.block_until_ready(loss)
+        from deepspeed_tpu.utils.compile_cache import setup_summary
+        log_dist(f"engine: {setup_summary()}", ranks=[0])
+        return loss
 
     def train_steps(self, n_steps: int, data_iter=None) -> np.ndarray:
         """Run ``n_steps`` fused steps back-to-back, metrics one step in
@@ -1581,6 +1614,7 @@ class DeepSpeedTPUEngine:
             self.monitor.write_events(events)
             if printing:
                 self.monitor.write_events(self.train_stats.events(samples))
+                self.monitor.write_events(_tracer.setup_events(samples))
                 if self._offload is not None and self.offload_stats.steps:
                     self.monitor.write_events(
                         self.offload_stats.events(samples))
