@@ -48,7 +48,8 @@ class GPT2Config:
     sequence_parallel: bool = False
     # rows per chunk in the fused projection+CE loss (llama.py
     # chunked_causal_lm_loss). The head GEMM's M dim is chunk*(T-1): larger
-    # chunks raise MXU efficiency, smaller bound the [chunk, T, V] transient.
+    # chunks raise MXU efficiency, smaller bound the [chunk, T, V] transient
+    # (logits and, under differentiation, their gradient: one iteration's).
     lm_loss_chunk: int = 4
 
     @classmethod

@@ -22,6 +22,7 @@ Two call paths:
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 
 from jax.ad_checkpoint import checkpoint_name
 
+from deepspeed_tpu.monitor.trace import tracer as _tracer
 from deepspeed_tpu.ops.attention import dot_product_attention, reference_attention
 from deepspeed_tpu.runtime.activation_checkpointing import apply_checkpointed_layers
 
@@ -62,9 +64,12 @@ class LlamaConfig:
     # when head counts can't divide the seq axis. Mutually exclusive with
     # sequence_parallel.
     context_parallel: bool = False
-    # rows per chunk in the fused projection+CE loss (chunked_causal_lm_loss):
-    # larger chunks raise head-GEMM MXU efficiency, smaller bound the
-    # [chunk, T, V] fp32 transient
+    # rows of the batch per chunk in the fused projection+CE loss
+    # (chunked_causal_lm_loss): the rows of logits alive at once. Larger
+    # chunks raise head-GEMM MXU efficiency, smaller bound the transient of
+    # one iteration ([chunk, T, V] fp32 logits and, under differentiation,
+    # their gradient in the product's dtype); what the forward pass keeps
+    # (dh, dw) does not depend on it
     lm_loss_chunk: int = 4
     dtype: Any = jnp.float32
     remat: bool = False
@@ -370,6 +375,93 @@ def causal_lm_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
     return jnp.mean(lse - picked)
 
 
+def _head_chunk(h, y, w, bias, transpose):
+    """One chunk of the loss head: ``h`` [chunk, T-1, C] and ``w`` ([C, V] if
+    ``transpose`` else [V, C]) in the product's dtype, ``bias`` float32 or
+    None. Returns the sum of the chunk's next-token NLL, its float32 logits
+    and their log-sum-exp."""
+    logits = jax.lax.dot_general(
+        h, w, (((2,), (0 if transpose else 1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if bias is not None:
+        logits = logits + bias
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
+    return jnp.sum(lse - picked), logits, lse
+
+
+def _head_chunk_grads(h, y, w, bias, transpose, inv_n):
+    """:func:`_head_chunk`'s NLL with the gradient of the MEAN loss
+    (``inv_n``: 1 / all rows of the loss), formed while the chunk's logits
+    are alive: ``dlogits = (softmax - onehot) * inv_n`` in float32, rounded
+    once to the product's dtype for the two transposed products. Returns
+    ``(nll, dw, dbias)`` in float32, for the caller to sum over chunks, and
+    ``dh`` in ``h``'s dtype."""
+    # two products read ``h``: it stays a value of its own (as it was while
+    # it was a residual), or the TPU compiler fuses the final norm into both
+    # as their producer and each runs a quarter slower
+    h = jax.lax.optimization_barrier(h)
+    nll, logits, lse = _head_chunk(h, y, w, bias, transpose)
+    hot = jax.lax.broadcasted_iota(y.dtype, logits.shape, 2) == y[..., None]
+    dlogits = (jnp.exp(logits - lse[..., None]) - hot) * inv_n
+    dbias = None if bias is None else jnp.sum(dlogits, axis=(0, 1))
+    # ... and so does ``dlogits``, written once in the product's dtype: fused
+    # into the transposed products, each reads the float32 logits and runs
+    # the exponential again (the ``dw`` product 11.4 ms for 6.8, PR 36)
+    dlogits = jax.lax.optimization_barrier(dlogits.astype(h.dtype))
+    dh = jax.lax.dot_general(
+        dlogits, w, (((2,), (1 if transpose else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dw = jax.lax.dot_general(*((h, dlogits) if transpose else (dlogits, h)),
+                             (((0, 1), (0, 1)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return (nll, dw, dbias), dh.astype(h.dtype)
+
+
+def _head_chunks(x, labels, chunk):
+    B, T, C = x.shape
+    return (x[:, :-1, :].reshape(B // chunk, chunk, T - 1, C),
+            labels[:, 1:].reshape(B // chunk, chunk, T - 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _loss_head(x, w, bias, labels, chunk, transpose):
+    """No gradient asked (evaluation, an abstract trace): the plain scan."""
+    def body(acc, inp):
+        return acc + _head_chunk(*inp, w, bias, transpose)[0], None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0.0),
+                            _head_chunks(x, labels, chunk))
+    return total / (x.shape[0] * (x.shape[1] - 1))
+
+
+def _loss_head_fwd(x, w, bias, labels, chunk, transpose):
+    B, T, _ = x.shape
+    xs, ys = _head_chunks(x, labels, chunk)
+    n = B * (T - 1)
+    # noted when traced: the rows whose gradient is formed with their loss
+    _tracer.note("train/loss_head/fused", B)
+
+    def body(sums, inp):
+        new, dh = _head_chunk_grads(*inp, w, bias, transpose, 1.0 / n)
+        return jax.tree_util.tree_map(jnp.add, sums, new), dh
+
+    zeros = (jnp.float32(0.0), jnp.zeros(w.shape, jnp.float32),
+             None if bias is None else jnp.zeros(bias.shape, jnp.float32))
+    (total, dw, dbias), dh = jax.lax.scan(body, zeros, (xs, ys))
+    return total / n, (dh.reshape(B, T - 1, -1), dw.astype(w.dtype), dbias)
+
+
+def _loss_head_bwd(chunk, transpose, saved, g):
+    # the cotangent (the loss scale, a caller's 1/k) multiplies in float32
+    dh, dw, dbias = jax.tree_util.tree_map(
+        lambda d: (d.astype(jnp.float32) * g).astype(d.dtype), saved)
+    return jnp.pad(dh, ((0, 0), (0, 1), (0, 0))), dw, dbias, None
+
+
+_loss_head.defvjp(_loss_head_fwd, _loss_head_bwd)
+
+
 def chunked_causal_lm_loss(x: jax.Array, vocab_weight: jax.Array,
                            labels: jax.Array, batch_chunk: int = 4,
                            transpose: bool = False,
@@ -378,38 +470,42 @@ def chunked_causal_lm_loss(x: jax.Array, vocab_weight: jax.Array,
 
     ``x`` [B, T, C] final hidden states; ``vocab_weight`` [V, C] (embedding
     layout; pass ``transpose=True`` for a [C, V] lm_head kernel). The [B, T, V]
-    logits tensor never materialises: each chunk's logits live only inside a
-    rematerialised scan body (~chunk*T*V fp32 transient), which is what lets
-    large-vocab models run at memory-bound batch sizes — the role of the
-    reference's fused logits kernels (inference/v2 logits_gather + vocab-
-    parallel loss in Megatron-style training).
+    logits tensor never materialises: a scan walks ``batch_chunk`` rows of
+    the batch at a time, and a chunk's logits (~chunk*T*V fp32 transient)
+    live only inside its iteration, which is what lets large-vocab models
+    run at memory-bound batch sizes — the role of the reference's fused
+    logits kernels (inference/v2 logits_gather + vocab-parallel loss in
+    Megatron-style training).
+
+    Under differentiation (a ``jax.custom_vjp``) the same iteration that
+    forms a chunk's logits also forms the loss's gradient with respect to
+    them, ``(softmax - onehot) / N``, and pushes it through the two
+    transposed products. What the forward pass keeps for the backward pass
+    is then ``dh`` ([B, T-1, C] in the product's dtype) and ``dw`` (the
+    head's gradient, float32 across chunks, kept in the weight's dtype;
+    ``dbias`` beside it) — neither logits nor a second run of the vocabulary
+    product — and the backward rule only multiplies them by the loss's
+    scalar cotangent (the loss scale). The loss is the last thing the
+    forward pass computes and the first the backward pass differentiates, so
+    nothing is held longer than autodiff would hold it. Its operations carry
+    the scope ``loss_head`` in a device trace, and ``train/loss_head/fused``
+    in ``tracer.totals`` (rows of the batch, noted when a gradient is
+    traced) says the fused rule engaged.
     """
-    B, T, C = x.shape
+    B = x.shape[0]
     chunk = max(1, min(batch_chunk, B))
     while B % chunk:
         chunk -= 1
-    xs = x[:, :-1, :].reshape(B // chunk, chunk, T - 1, C)
-    ys = labels[:, 1:].reshape(B // chunk, chunk, T - 1)
-    w = vocab_weight if transpose else vocab_weight.T  # [C, V]
-
     # bf16 models project in bf16 with fp32 MXU accumulation (the v5e runs
     # fp32 matmuls at a fraction of bf16 rate; accumulation stays exact).
-    # fp32 models keep the fp32 path bit-for-bit.
+    # fp32 models keep the fp32 path bit-for-bit; fp16 models take it too,
+    # so the loss scale multiplies float32 gradients before the cast back.
     mm_dtype = jnp.bfloat16 if x.dtype == jnp.bfloat16 else jnp.float32
-
-    def body(acc, inp):
-        h, y = inp
-        logits = jax.lax.dot_general(
-            h.astype(mm_dtype), w.astype(mm_dtype),
-            (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        if head_bias is not None:
-            logits = logits + head_bias.astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
-        return acc + jnp.sum(lse - picked), None
-
-    total, _ = jax.lax.scan(jax.checkpoint(body), jnp.float32(0.0), (xs, ys))
-    return total / (B * (T - 1))
+    with jax.named_scope("loss_head"):
+        return _loss_head(
+            x.astype(mm_dtype), vocab_weight.astype(mm_dtype),
+            None if head_bias is None else head_bias.astype(jnp.float32),
+            labels, chunk, transpose)
 
 
 def decode_layers(model, input_ids, cache, cache_index, positions):

@@ -1130,6 +1130,9 @@ class DeepSpeedTPUEngine:
         while True:
             plan = self.remat_plan
             last = plan.rung == len(ac.LADDER) - 1
+            # models/llama.py notes its rows when THIS step's gradient is
+            # traced; a step with another loss leaves the 0
+            _tracer.note("train/loss_head/fused", 0)
             try:
                 with _tracer.stage(f"rung{plan.rung}"):
                     compiled = self._jit_fused_step().lower(
@@ -1144,9 +1147,13 @@ class DeepSpeedTPUEngine:
                         - mem.alias_size_in_bytes + mem.temp_size_in_bytes
                         + mem.generated_code_size_in_bytes)
                 if last or need <= plan.limit_bytes:
+                    fused = _tracer.totals["train/loss_head/fused"]
                     log_dist(f"activation checkpointing: {plan.describe()}; "
-                             f"the compiled step needs {need / 2**30:.2f} GiB",
-                             ranks=[0])
+                             f"the compiled step needs {need / 2**30:.2f} GiB"
+                             + ("" if not fused else
+                                f"; the loss head forms its gradient with its "
+                                f"value over {fused:.0f} rows a micro-batch "
+                                f"(train/loss_head/fused)"), ranks=[0])
                     for name, value in (
                             ("rung", plan.rung),
                             ("kept_bytes", plan.kept_bytes),
