@@ -72,6 +72,12 @@ class AttentionKernelSpec:
         self.mesh = mesh
         self.tp = int(tp)
         self.n_splits = int(n_splits)
+        # the model's own softmax scale (granite) is the kernels' static
+        # argument; None or the neutral value leaves them their default,
+        # head_dim ** -0.5
+        attn_scale = getattr(spec, "attn_scale", None)
+        scale = {} if attn_scale in (None, spec.head_dim ** -0.5) else {
+            "softmax_scale": float(attn_scale)}
         if self.n_splits > 1:
             # flash-decoding rung: every paged caller routes through the
             # split-K dispatchers so decode, fused step, sidebuf and spec
@@ -82,30 +88,31 @@ class AttentionKernelSpec:
             ns = self.n_splits
             self._decode = functools.partial(
                 paged_decode_attention_splitk, window=spec.window,
-                alibi=spec.alibi, n_splits=ns)
+                alibi=spec.alibi, n_splits=ns, **scale)
             self._chunk = functools.partial(
                 paged_chunk_attention_splitk, window=spec.window,
-                alibi=spec.alibi, n_splits=ns)
+                alibi=spec.alibi, n_splits=ns, **scale)
             self._step = functools.partial(
                 paged_decode_attention_splitk_step, window=spec.window,
-                alibi=spec.alibi, n_splits=ns)
+                alibi=spec.alibi, n_splits=ns, **scale)
             self._sidebuf = functools.partial(
                 paged_sidebuf_attention_splitk, window=spec.window,
-                alibi=spec.alibi, n_splits=ns)
+                alibi=spec.alibi, n_splits=ns, **scale)
         else:
             self._decode = functools.partial(
-                paged_decode_attention, window=spec.window, alibi=spec.alibi)
+                paged_decode_attention, window=spec.window, alibi=spec.alibi,
+                **scale)
             self._chunk = functools.partial(
                 paged_chunk_attention_batched, window=spec.window,
-                alibi=spec.alibi)
+                alibi=spec.alibi, **scale)
             self._step = functools.partial(
                 paged_decode_attention_step, window=spec.window,
-                alibi=spec.alibi)
+                alibi=spec.alibi, **scale)
             self._sidebuf = functools.partial(
                 paged_decode_attention_sidebuf, window=spec.window,
-                alibi=spec.alibi)
+                alibi=spec.alibi, **scale)
         self._packed = functools.partial(flash_attention_packed,
-                                         window=spec.window)
+                                         window=spec.window, **scale)
         mla = getattr(spec, "mla", None)
         if mla is not None:
             # latent pages (ragged_mla.py): one kernel for every program

@@ -288,10 +288,14 @@ class InferenceEngineV2:
             from deepspeed_tpu.inference.v2.ragged.state_pool import (
                 StatefulKV, StatePoolConfig, StateSlotAllocator)
             m = self.spec.mamba
+            ssd = m.get("kind") == "mamba2"
             self.state_config = StatePoolConfig(
                 num_layers=num_state_layers(self.spec),
                 num_slots=sm.max_tracked_sequences, d_inner=m["d_inner"],
-                d_state=m["d_state"], d_conv=m["d_conv"])
+                d_state=m["d_state"], d_conv=m["d_conv"],
+                # Mamba-2 convolves x, B and C together
+                conv_dim=m["d_inner"] + 2 * m["n_groups"] * m["d_state"]
+                if ssd else None)
             self.scheduler.state_slots = StateSlotAllocator(
                 sm.max_tracked_sequences)
             with _tracer.stage("kv_alloc"):
@@ -426,8 +430,16 @@ class InferenceEngineV2:
                         / (kv_cfg.num_layers * kv_cfg.block_size))
         if self.spec.moe is not None and "held" in self.spec.moe:
             _tracer.note("serve/moe/held_experts", self.spec.moe["held"][1])
+        if self.state_config is not None:
+            # always-on values: what a tracked sequence costs the state pool
+            # over all its layers, and which recurrence fills it
+            _tracer.note("serve/state/bytes_per_sequence",
+                         self.state_config.bytes_per_slot())
+            recurrence = 2 if self.spec.mamba.get("kind") == "mamba2" else 1
+            _tracer.note("serve/state/kind", recurrence)
         state = "" if self.state_config is None else (
-            f"; state pool {self.state_config.num_slots}+dump slots x "
+            f"; Mamba-{recurrence} "
+            f"state pool {self.state_config.num_slots}+dump slots x "
             f"{self.state_config.num_layers} layers = "
             f"{self.state_config.total_bytes() / 2**20:.1f} MiB "
             f"({self.state_config.bytes_per_slot() / 2**20:.2f} MiB a "
@@ -822,7 +834,8 @@ class InferenceEngineV2:
 
     def sequence_state(self, uid: int) -> np.ndarray:
         """A tracked sequence's recurrent state ``h`` ``[Lm, N, E]``
-        (float32) fetched to the host, for a check that compares it."""
+        (float32; Mamba-2: channel ``h * P + p`` of head ``h`` on the last
+        axis) fetched to the host, for a check that compares it."""
         slot = self.scheduler.seqs[int(uid)].state_slot
         if slot < 0:
             raise ValueError("this model has no state-space layers")

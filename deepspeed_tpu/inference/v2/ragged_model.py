@@ -39,6 +39,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,7 +52,8 @@ from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
                                                      plan_visits, row_tile)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
-from deepspeed_tpu.ops.pallas.ssm import ssm_chunk_scan, ssm_decode_step
+from deepspeed_tpu.ops.pallas.ssm import (ssd_chunk_scan, ssd_decode_step,
+                                          ssm_chunk_scan, ssm_decode_step)
 
 
 def _kv_unpack(kp):
@@ -95,9 +98,11 @@ class LayerKind(NamedTuple):
 class MambaKind(NamedTuple):
     """The kind of a layer whose mixer is a Mamba state-space block, not
     attention: it holds no pages and has neither window nor positions, and
-    keeps a fixed-size state per sequence (ragged/state_pool.py). A kind of
-    its own beside :class:`LayerKind`, which stays the three values an
-    attention layer is told by."""
+    keeps a fixed-size state per sequence (ragged/state_pool.py). Which
+    recurrence (Mamba-1, Mamba-2) is the model's, not the layer's
+    (``RaggedModelSpec.mamba``). A kind of its own beside
+    :class:`LayerKind`, which stays the three values an attention layer is
+    told by."""
     moe: bool = False       # routed experts (else the dense MLP)
     window = None
     rope = False
@@ -156,11 +161,22 @@ class RaggedModelSpec:
     # and ``weights["layers"]`` is a tuple of stacked trees, one per run of
     # equal kinds (:func:`layer_runs`)
     layer_kinds: Optional[Tuple[Any, ...]] = None   # LayerKind | MambaKind
-    # widths of the Mamba mixer {"d_inner": E, "d_state": N, "dt_rank": R,
-    # "d_conv": K} of a model that has such layers (``layer_kinds`` says
-    # which); on a run's spec (:func:`layer_runs`) it is set for a run of
-    # Mamba layers and None for a run of attention layers
-    mamba: Optional[Dict[str, int]] = None
+    # widths of the Mamba mixer of a model that has such layers
+    # (``layer_kinds`` says which); on a run's spec (:func:`layer_runs`) it is
+    # set for a run of Mamba layers and None for a run of attention layers.
+    # Mamba-1: {"d_inner": E, "d_state": N, "dt_rank": R, "d_conv": K}.
+    # Mamba-2 (SSD), told by "kind": "mamba2": {"d_inner": E = H * P,
+    # "n_heads": H, "d_head": P, "n_groups": G (1: the kernels' one group),
+    # "d_state": N, "d_conv": K, "chunk": the product form's chunk size}
+    mamba: Optional[Dict[str, Any]] = None
+    # plain multipliers (granite): on the embedding's output, on each
+    # branch's output before it joins the residual stream, on the logits,
+    # and the softmax scale where it is not head_dim ** -0.5. None (or the
+    # neutral value) leaves the program as it is without them
+    embed_scale: Optional[float] = None
+    residual_scale: Optional[float] = None
+    logits_scale: Optional[float] = None
+    attn_scale: Optional[float] = None
     # BLOOM lineage: per-head linear position bias applied inside the paged
     # kernels (reference csrc/transformer/inference/csrc/softmax.cu) and a
     # LayerNorm right after the embedding
@@ -659,6 +675,101 @@ def adapt_joyai(params: Dict, config,
     return spec, weights
 
 
+def adapt_granite(params: Dict, config,
+                  max_context: Optional[int] = None
+                  ) -> Tuple[RaggedModelSpec, Dict]:
+    """models/granite.py param tree (GraniteForCausalLM; IBM Granite 4.0-H,
+    ``granitemoehybrid``).
+
+    One kind per layer from the config's ``layer_types``:
+    :class:`MambaKind` with routed experts, or an attention
+    :class:`LayerKind` without window or positions, with them too. The
+    mixer is Mamba-2 (``spec.mamba["kind"] == "mamba2"``): a run of Mamba
+    layers stacks ``in_proj`` (gate, convolution input, ``dt`` a head), the
+    convolution over x, B and C together, ``A_log``/``D``/``dt_bias`` a head,
+    the gated norm's gain and ``out_proj``. The router is the softmax one
+    (top-k of the logits, softmax over the chosen); the stacks hold
+    ``config.held`` of its ``num_local_experts``; the shared MLP rides as the
+    layer's ``shared`` expert. The four published multipliers are the spec's
+    plain floats."""
+    del max_context
+    from deepspeed_tpu.models.granite import MAMBA
+    kinds = tuple(MambaKind(True) if t == MAMBA
+                  else LayerKind(None, False, True)
+                  for t in config.layer_types)
+    first, count = config.held
+    moe = {"num_experts": config.num_local_experts,
+           "top_k": config.num_experts_per_tok}
+    if count != config.num_local_experts:
+        moe["held"] = (first, count)
+    spec = RaggedModelSpec(
+        family="granite",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=config.num_attention_heads,
+        num_kv_heads=config.num_key_value_heads,
+        head_dim=config.head_dim,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=None,
+        tied_lm_head=True, eps=config.rms_norm_eps, moe=moe,
+        layer_kinds=kinds, dtype=config.dtype,
+        embed_scale=float(config.embedding_multiplier),
+        residual_scale=float(config.residual_multiplier),
+        logits_scale=1.0 / float(config.logits_scaling),
+        attn_scale=float(config.attention_multiplier),
+        mamba={"kind": "mamba2", "d_inner": config.mamba_d_inner,
+               "n_heads": config.mamba_n_heads,
+               "d_head": config.mamba_d_head,
+               "n_groups": config.mamba_n_groups,
+               "d_state": config.mamba_d_state,
+               "d_conv": config.mamba_d_conv,
+               "chunk": config.mamba_chunk_size} if any(
+                   k.mamba for k in kinds) else None)
+    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
+        spec = layer_runs(spec)[0][0]
+
+    def swiglu(p):
+        return {"w_gate": p["gate_proj"]["kernel"],
+                "w_up": p["up_proj"]["kernel"],
+                "w_down": p["down_proj"]["kernel"]}
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        ff = lp["block_sparse_moe"]
+        out = {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
+            "moe": {"router": ff["router"]["kernel"],
+                    "w_gate": ff["w_gate"], "w_up": ff["w_up"],
+                    "w_down": ff["w_down"],
+                    "shared": swiglu(ff["shared_mlp"])},
+        }
+        if kinds[i].mamba:
+            m = lp["mamba"]
+            out["mamba"] = {
+                "in_proj": m["in_proj"]["kernel"],
+                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, W]
+                "conv_b": m["conv_bias"],
+                "dt_bias": m["dt_bias"], "A_log": m["A_log"], "D": m["D"],
+                "norm": m["norm"],
+                "out_proj": m["out_proj"]["kernel"],
+            }
+        else:
+            attn = lp["self_attn"]
+            out.update(wq=attn["q_proj"]["kernel"], wk=attn["k_proj"]["kernel"],
+                       wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"])
+        return out
+
+    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
+                   for _, l0, n in layer_runs(spec))
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": stacks if spec.layer_kinds is not None else stacks[0],
+        "final_norm": {"scale": params["norm"]["weight"]},
+    }
+    return spec, weights
+
+
 ADAPTERS: Dict[str, Callable] = {
     # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
     # LlamaConfig features the adapter reads)
@@ -686,6 +797,10 @@ ADAPTERS: Dict[str, Callable] = {
     # (ragged_mla.py); a sigmoid router over experts of which this chip may
     # hold a share
     "joyai": adapt_joyai,
+    # Mamba-2 (SSD) layers — a matrix state per head in the same pool —
+    # beside a few no-position GQA layers, every FFN routed experts (of which
+    # this chip may hold a share) plus a shared MLP; four plain multipliers
+    "granite": adapt_granite,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
@@ -1190,41 +1305,97 @@ class _StateRows(NamedTuple):
     decode_slot: Any = None     # [S] int32
 
 
-def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
-    """The Mamba-1 mixer (Jamba's: RMSNorm on dt, B and C) on the normed
-    rows ``u`` ``[T, hid]`` of one layer, reading and updating its rows'
-    states in the pools ``state = (ssm [Lm, NS+1, N, E], conv [Lm, NS+1,
-    (K-1)*8, E/8])`` (float32; ragged/state_pool.py) at layer ``l`` of them. Returns ``(out [T, hid],
-    ssm, conv)``.
+#: a state slot of this many bytes or more moves by one dynamic slice a row
+#: (:func:`_slots_take`), a smaller one by XLA's gather and scatter: of rows
+#: of 4 MiB XLA's gather first slices the WHOLE pool into column blocks (2.6
+#: GiB of copies a layer at 73 slots; compile, PR 39), of rows of 320 KiB it
+#: does not (cell 7, PR 31) — the rule lies between the two sizes seen
+_SLOT_SLICE_BYTES = 1 << 20
 
-    Prompt rows run the chunked scan slot by slot (scope ``ssm/scan``): a
-    chunk slot starts from zero, from the pool or from the slot before it
-    (``rows.chunk_mode``), rows past its token count leave the state alone
-    (their ``dt`` is zeroed), and only a sequence's last slot of the pass
-    writes the pool. Decode rows are segments of one token (``ssm/step``).
-    The convolution reads its ``K - 1`` predecessors from the rows before, the
-    slot before or the pool's tail. ``dt``, ``exp(dt A)``, ``h`` and ``y``
-    are float32, the matrices and the tail the model's dtype."""
+
+def _slots_take(flat, rows):
+    """``flat[rows]`` of a pool ``[slots, N, E]`` for a few ``rows``: one
+    dynamic slice a row (each a contiguous block of the pool) where a row is
+    large (``_SLOT_SLICE_BYTES``), else a gather."""
+    if flat[0].size * flat.dtype.itemsize < _SLOT_SLICE_BYTES:
+        return flat[rows]
+    return jnp.concatenate([
+        jax.lax.dynamic_slice_in_dim(flat, rows[i], 1) for i in
+        range(rows.shape[0])])
+
+
+def _slots_put(flat, rows, values):
+    """``flat.at[rows].set(values)``; where a row is large, one dynamic
+    update a row, in order (a row named twice keeps the later value): in
+    place on a carried pool."""
+    if flat[0].size * flat.dtype.itemsize < _SLOT_SLICE_BYTES:
+        return flat.at[rows].set(values)
+    for i in range(rows.shape[0]):
+        flat = jax.lax.dynamic_update_slice_in_dim(
+            flat, values[i:i + 1].astype(flat.dtype), rows[i], 0)
+    return flat
+
+
+def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
+    """The Mamba mixer on the normed rows ``u`` ``[T, hid]`` of one layer,
+    reading and updating its rows' states in the pools ``state = (ssm [Lm,
+    NS+1, N, E], conv [Lm, NS+1, (K-1)*8, W/8])`` (float32;
+    ragged/state_pool.py) at layer ``l`` of them. Returns ``(out [T, hid],
+    ssm, conv)``. ``spec.mamba`` says which recurrence
+    (``ops/pallas/ssm.py`` states both):
+
+    - Mamba-1 (Jamba's: RMSNorm on dt, B and C): ``in_proj`` gives the
+      convolution's input and the gate; ``x_proj`` makes ``dt``'s low-rank
+      input, ``B`` and ``C`` from the convolved rows; the state decays by the
+      channel and the state value;
+    - Mamba-2 (``"kind": "mamba2"``; granite's): ``in_proj`` gives the gate,
+      the convolution's input — x, B and C together, ``W = E + 2 N`` channels
+      — and ``dt`` a head; no ``x_proj``, ``dt_proj`` or inner norms; the
+      state decays by the head; the gate is followed by an RMSNorm over all
+      ``E`` (scope ``ssm/gate_norm``) before ``out_proj``.
+
+    What they share is the rows' bookkeeping. Prompt rows run the chunked
+    scan slot by slot (scope ``ssm/scan``): a chunk slot starts from zero,
+    from the pool or from the slot before it (``rows.chunk_mode``), rows past
+    its token count leave the state alone (their ``dt`` is zeroed), and only
+    a sequence's last slot of the pass writes the pool. Decode rows are
+    segments of one token (``ssm/step``). The convolution reads its ``K - 1``
+    predecessors from the rows before, the slot before or the pool's tail.
+    ``dt``, the decay, ``h`` and ``y`` are float32, the matrices and the tail
+    the model's dtype."""
     m, mw = spec.mamba, w["mamba"]
-    E, N, R, K = m["d_inner"], m["d_state"], m["dt_rank"], m["d_conv"]
+    ssd = m.get("kind") == "mamba2"
+    E, N, K = m["d_inner"], m["d_state"], m["d_conv"]
+    W = E + 2 * N if ssd else E          # channels the convolution runs over
     dtype = spec.dtype
     ssm, conv = state
     NS1 = ssm.shape[1]
     dump = NS1 - 1
     # all layers' slots in one list (merging the leading dimensions is a
-    # view); a slot's tile rows are its K - 1 taps x E channels in order,
-    # so the rows GATHERED from it reshape to [n, K - 1, E] (a small copy —
-    # reshaping the pool itself so would lay it out anew, in every layer)
+    # view); a slot's tile rows are its K - 1 taps x W channels in order
+    # (padded to whole tiles a tap where W is not), so the rows GATHERED
+    # from it reshape to [n, K - 1, W] (a small copy — reshaping the pool
+    # itself so would lay it out anew, in every layer)
     conv2 = conv.reshape((-1,) + conv.shape[2:])
-    taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, E)
+    Wp = 8 * conv.shape[3]
+    if Wp == W:
+        taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, W)
+        as_taps = lambda t: t.reshape((-1,) + conv.shape[2:])
+    else:
+        taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, Wp)[..., :W]
+        as_taps = lambda t: jnp.pad(
+            t, ((0, 0), (0, 0), (0, Wp - W))).reshape((-1,) + conv.shape[2:])
     f32 = jnp.float32
     conv_w, conv_b = mw["conv_w"].astype(f32), mw["conv_b"].astype(f32)
 
     with jax.named_scope("in_proj"):
         az = _mm(u, mw["in_proj"])
-    a, z = az[:, :E], az[:, E:]
+    if ssd:
+        z, a, dt_in = az[:, :E], az[:, E:E + W], az[:, E + W:]
+    else:
+        a, z = az[:, :E], az[:, E:]
 
-    def conv_act(ext):          # [.., K + n - 1, E] inputs -> [.., n, E]
+    def conv_act(ext):          # [.., K + n - 1, W] inputs -> [.., n, W]
         n = ext.shape[-2] - (K - 1)
         acc = conv_b + sum(ext[..., j:j + n, :].astype(f32) * conv_w[j]
                            for j in range(K))
@@ -1238,7 +1409,7 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
                                else rows.decode_slot.shape[0])
             Cs = CT // NC
             mode = rows.chunk_mode
-            a_c = a[:CT].reshape(NC, Cs, E)
+            a_c = a[:CT].reshape(NC, Cs, W)
             pool_rows = l * NS1 + rows.chunk_slot
             # a sequence's last slot of the pass writes back; the others
             # (and empty slots) write the dump slot
@@ -1249,30 +1420,44 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
                 jnp.roll(a_c[:, Cs - (K - 1):], 1, axis=0),
                 jnp.where((mode == 1)[:, None, None], taps(pool_rows), 0))
             ext = jnp.concatenate([tail.astype(dtype), a_c], axis=1)
-            parts.append(conv_act(ext).reshape(CT, E))
+            parts.append(conv_act(ext).reshape(CT, W))
             new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice(
-                e, (n, 0), (K - 1, E)))(ext, rows.chunk_ntok)
+                e, (n, 0), (K - 1, W)))(ext, rows.chunk_ntok)
             conv2 = conv2.at[store_rows].set(
-                new_tail.astype(conv.dtype).reshape((NC,) + conv.shape[2:]))
+                as_taps(new_tail.astype(conv.dtype)))
         if rows.decode_slot is not None:
             # the rows' tails are read here; their shift by one token rides
             # with the recurrence kernel below
             drows = l * NS1 + rows.decode_slot
             ext = jnp.concatenate([taps(drows).astype(dtype),
-                                   a[CT:, None]], axis=1)        # [S, K, E]
+                                   a[CT:, None]], axis=1)        # [S, K, W]
             parts.append(conv_act(ext)[:, 0])
     conv = conv2.reshape(conv.shape)
     c = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-    rbc = _mm(c, mw["x_proj"])
-    r = _norm(rbc[:, :R], {"scale": mw["dt_norm"]}, "rms", spec.eps, dtype)
-    Bm = _norm(rbc[:, R:R + N], {"scale": mw["b_norm"]}, "rms", spec.eps,
-               dtype).astype(f32)
-    Cm = _norm(rbc[:, R + N:], {"scale": mw["c_norm"]}, "rms", spec.eps,
-               dtype).astype(f32)
-    dt = jax.nn.softplus(_mm(r, mw["dt_proj"]).astype(f32)
-                         + mw["dt_bias"].astype(f32))
-    A = -jnp.exp(mw["A_log"].astype(f32))                       # [N, E]
+    if ssd:
+        # x, B and C are the convolved rows' three parts; a step size and a
+        # decay a head
+        Bm, Cm = c[:, E:E + N].astype(f32), c[:, E + N:].astype(f32)
+        c = c[:, :E]
+        dt = jax.nn.softplus(dt_in.astype(f32) + mw["dt_bias"].astype(f32))
+        A = -jnp.exp(mw["A_log"].astype(f32))                   # [H]
+        chunk_scan = functools.partial(ssd_chunk_scan,
+                                       chunk=m.get("chunk", 256))
+        decode_step = ssd_decode_step
+    else:
+        R = m["dt_rank"]
+        rbc = _mm(c, mw["x_proj"])
+        r = _norm(rbc[:, :R], {"scale": mw["dt_norm"]}, "rms", spec.eps,
+                  dtype)
+        Bm = _norm(rbc[:, R:R + N], {"scale": mw["b_norm"]}, "rms", spec.eps,
+                   dtype).astype(f32)
+        Cm = _norm(rbc[:, R + N:], {"scale": mw["c_norm"]}, "rms", spec.eps,
+                   dtype).astype(f32)
+        dt = jax.nn.softplus(_mm(r, mw["dt_proj"]).astype(f32)
+                             + mw["dt_bias"].astype(f32))
+        A = -jnp.exp(mw["A_log"].astype(f32))                   # [N, E]
+        chunk_scan, decode_step = ssm_chunk_scan, ssm_decode_step
     cf = c.astype(f32)
 
     ys = []
@@ -1281,20 +1466,29 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
             live = (jnp.arange(Cs)[None, :]
                     < rows.chunk_ntok[:, None]).reshape(CT, 1)
             flat = ssm.reshape(-1, N, E)
-            h0 = jnp.where((mode == 1)[:, None, None], flat[pool_rows], 0.0)
-            y, hT = ssm_chunk_scan(jnp.where(live, dt[:CT], 0.0), cf[:CT],
-                                   Bm[:CT], Cm[:CT], A, h0,
-                                   (mode == 2).astype(jnp.int32))
-            ssm = flat.at[store_rows].set(hT).reshape(ssm.shape)
+            h0 = jnp.where((mode == 1)[:, None, None],
+                           _slots_take(flat, pool_rows), 0.0)
+            y, hT = chunk_scan(jnp.where(live, dt[:CT], 0.0), cf[:CT],
+                               Bm[:CT], Cm[:CT], A, h0,
+                               (mode == 2).astype(jnp.int32))
+            ssm = _slots_put(flat, store_rows, hT).reshape(ssm.shape)
             ys.append(y)
     if rows.decode_slot is not None:
         with jax.named_scope("step"):
-            y, ssm, conv = ssm_decode_step(
+            y, ssm, conv = decode_step(
                 ssm, conv, l, rows.decode_slot, dt[CT:], cf[CT:], Bm[CT:],
                 Cm[CT:], A, a[CT:])
             ys.append(y)
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
-    y = (y + mw["D"].astype(f32) * cf) * jax.nn.silu(z.astype(f32))
+    if ssd:
+        y = (y + jnp.repeat(mw["D"].astype(f32), E // m["n_heads"]) * cf) \
+            * jax.nn.silu(z.astype(f32))
+        with jax.named_scope("gate_norm"):
+            # the gate first, then the norm, over all E (one group)
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                                  + spec.eps) * mw["norm"].astype(f32)
+    else:
+        y = (y + mw["D"].astype(f32) * cf) * jax.nn.silu(z.astype(f32))
     with jax.named_scope("out_proj"):
         out = _mm(y.astype(dtype), mw["out_proj"])
     return out, ssm, conv
@@ -1419,12 +1613,18 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                 attn_out = _norm(attn_out, w["ln1_post"], spec.norm, spec.eps,
                                  dtype, spec.norm_plus_one)
 
+    # a branch joins the residual stream times ``residual_scale`` (granite),
+    # in float32 so that the stream is rounded once
+    scaled = spec.residual_scale not in (None, 1.0)
+    join = (lambda x, out: (x.astype(jnp.float32) + spec.residual_scale
+                            * out.astype(jnp.float32)).astype(dtype)) \
+        if scaled else (lambda x, out: x + out)
     if spec.parallel_block:
         mlp_in = (_norm(x, w["ln2"], spec.norm, spec.eps, dtype,
                         spec.norm_plus_one)
                   if spec.parallel_dual_norm else h1)
     else:
-        x = x + attn_out
+        x = join(x, attn_out)
         mlp_in = _norm(x, w["ln2"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)
 
@@ -1451,9 +1651,9 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                             dtype, spec.norm_plus_one)
 
     if spec.parallel_block:
-        x = x + attn_out + mlp_out
+        x = join(x, attn_out + mlp_out) if scaled else x + attn_out + mlp_out
     else:
-        x = x + mlp_out
+        x = join(x, mlp_out)
     return x.astype(dtype), tuple(state)
 
 
@@ -1468,6 +1668,8 @@ def _embed_in(spec: "RaggedModelSpec", weights, tokens, positions):
                   spec.eps, spec.dtype, spec.norm_plus_one)
     if spec.embed_scale_by_sqrt_dim:
         x = x.astype(jnp.float32) * (spec.hidden_size ** 0.5)
+    if spec.embed_scale not in (None, 1.0):
+        x = x.astype(jnp.float32) * spec.embed_scale
     return x.astype(spec.dtype)
 
 
@@ -1479,6 +1681,8 @@ def _unembed(spec: "RaggedModelSpec", weights, xs):
         logits = _mm(xs, weights["lm_head"]).astype(jnp.float32)
     if spec.head_bias:
         logits = logits + weights["lm_head_bias"].astype(jnp.float32)
+    if spec.logits_scale not in (None, 1.0):
+        logits = logits * spec.logits_scale
     return logits
 
 
@@ -1637,17 +1841,20 @@ STATE_PASS_KEYS = ("chunk_state_slot", "chunk_state_mode",
                    "decode_state_slot")
 
 
-def _mamba_body(rs: RaggedModelSpec, positions, rows: _StateRows):
+def _mamba_body(rs: RaggedModelSpec, positions, rows: _StateRows,
+                experts=None, l0=0):
     """The scan body of a run of Mamba layers, for every serving program:
     the carry is ``(x, *the program's KV carry, (ssm, conv))``; the KV part
     passes through untouched and ``l`` is the layer's rank among the Mamba
-    layers (:func:`_pool_bases`)."""
+    layers (:func:`_pool_bases`), ``l - l0`` its place in the run's expert
+    stacks where its FFN routes experts (``MambaKind(moe=True)``)."""
     def layer_fn(carry, scanned):
         x, *cache, st = carry
         w, l = scanned[:2]
+        moe = {} if rs.moe is None else dict(experts=experts, l=l - l0)
         x, st = _transformer_layer(
             rs, w, x, positions,
-            lambda u: _mamba_mixer(rs, w, u, st, l, rows))
+            lambda u: _mamba_mixer(rs, w, u, st, l, rows), **moe)
         return (x, *cache, st), None
 
     return layer_fn
@@ -1696,7 +1903,7 @@ def build_ragged_forward(spec: RaggedModelSpec,
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
-                return _mamba_body(rs, positions, rows)
+                return _mamba_body(rs, positions, rows, experts, l0)
             ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp,
                                      n_splits=_kind_splits(spec, rs, n_splits))
 
@@ -1795,7 +2002,7 @@ def build_prefill_forward(spec: RaggedModelSpec,
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
-                return _mamba_body(rs, positions, rows)
+                return _mamba_body(rs, positions, rows, experts, l0)
             ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp)
 
             def layer_fn(carry, scanned):
@@ -1928,7 +2135,7 @@ def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
 
             def make_body(rs, experts, l0):
                 if rs.mamba is not None:
-                    return _mamba_body(rs, pos, rows)
+                    return _mamba_body(rs, pos, rows, experts, l0)
                 ak = AttentionKernelSpec(
                     rs, mesh=None, tp=1,
                     n_splits=_kind_splits(spec, rs, n_splits))
@@ -2386,7 +2593,7 @@ def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
 
             def make_body(rs, experts, l0):
                 if rs.mamba is not None:
-                    return _mamba_body(rs, pos, rows)
+                    return _mamba_body(rs, pos, rows, experts, l0)
                 ak = AttentionKernelSpec(
                     rs, mesh=mesh, tp=tp,
                     n_splits=_kind_splits(spec, rs, n_splits))

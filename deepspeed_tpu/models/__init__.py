@@ -1,7 +1,8 @@
 """Model zoo: the BASELINE config ladder families (gpt2, llama/mistral, mixtral,
 gpt-neox) plus the inference-container families (opt, falcon, phi, bert) and
 afmoe (Arcee Trinity: layers of several kinds in one model), jamba
-(state-space layers) and joyai (JoyAI-LLM-Flash: latent attention) —
+(state-space layers), joyai (JoyAI-LLM-Flash: latent attention) and granite
+(IBM Granite 4.0-H: Mamba-2 layers over routed experts) —
 matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
@@ -10,6 +11,7 @@ from deepspeed_tpu.models.bert import BertConfig, BertForMaskedLM
 from deepspeed_tpu.models.decoder import (DecoderConfig, DecoderLM,
                                           init_decoder_cache)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+from deepspeed_tpu.models.granite import GraniteConfig, GraniteForCausalLM
 from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
 from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, init_cache

@@ -1,7 +1,8 @@
-"""Selective state-space (Mamba-1) recurrence kernels for the serving path.
+"""State-space recurrence kernels for the serving path: the selective scan of
+Mamba-1 and the matrix-state recurrence of Mamba-2 (SSD).
 
-The recurrence of one layer, per token ``t`` and channel ``e`` (``N`` state
-values a channel)::
+**Mamba-1** (``ssm_*``). One layer, per token ``t`` and channel ``e`` (``N``
+state values a channel)::
 
     h_t[n, e] = exp(dt_t[e] * A[n, e]) * h_{t-1}[n, e] + dt_t[e] * x_t[e] * B_t[n]
     y_t[e]    = sum_n h_t[n, e] * C_t[n]
@@ -14,29 +15,52 @@ lanes to 128, eight times the bytes — and ``B_t``/``C_t`` reach the kernels
 already spread over 128 lanes (``[T, N, 128]``), so that a kernel only
 repeats whole registers.
 
-- :func:`ssm_decode_step`: one token per row, each row with a state of its own
-  somewhere in the pool ``[Lm, slots, N, E]``. The pool is aliased through the
-  call; a grid step reads one row's state block where it lies (the layer and
-  the slot come from prefetched scalars), updates it and writes it back: one
-  read and one write of each state, nothing else of the pool touched. The
-  row's convolution tail rides along: its block of the tail pool
-  ``[Lm, slots, (K-1)*8, E/8]`` comes in, drops its oldest tap, takes the
-  row's new input as its newest and goes back. Left to XLA, that update was
-  a row scatter of 30 KiB rows, which costs by the row: 3.3 ms of a 19 ms
-  decode step at 128 rows x 26 layers (my chip run, PR 31), against nothing
-  here (the blocks move under the state's).
-- :func:`ssm_chunk_scan`: a pass's packed prompt rows, ``G`` chunk slots of
-  ``Cs`` rows. The state of a block of channels stays in on-chip memory
-  across the token blocks; at a slot's first block it is loaded from ``h0``
-  unless the slot continues the one before it (``cont``); after a slot's last
-  block it is written to ``hT``. Rows with ``dt = 0`` leave the state as it is
-  (``exp(0) = 1``, nothing added), which is how a chunk shorter than its slot
-  is padded.
+**Mamba-2** (``ssd_*``). The state is a matrix per head, ``S[h]`` ``[P, N]``
+(``H`` heads of ``P`` channels, ``E = H * P``), the decay one scalar a head
+and ``B_t``/``C_t`` shared by the heads of a group (one group here)::
+
+    S_t[h] = exp(dt_t[h] * a[h]) * S_{t-1}[h] + dt_t[h] * x_t[h] (outer) B_t
+    y_t[h] = S_t[h] C_t
+
+It is the recurrence above with ``dt`` and ``A`` constant over a head's
+channels, so the state takes the SAME layout, ``[.., N, E]`` with channel
+``e = h * P + p`` on the lanes: 4 MiB a sequence a layer at ``N`` 128, ``E``
+8192 (128 heads of 64), no padding, two heads of 64 a 128-lane tile. What
+differs is how it is computed. A decode row is bound by its state's bytes
+(one read and one write of 4 MiB), so :func:`ssd_decode_step` moves it in
+blocks of channels with the decay made once a channel (not once a state
+value: no ``exp`` over ``[N, E]``). Prompt rows take the *product form*
+(:func:`ssd_chunk_scan`): over a chunk of ``Q`` tokens the intra-chunk part
+``(L o C B^T) X``, the carried part ``C S`` and the chunk's new state ``B^T
+(decay * X)`` are matrix products on the MXU, where Mamba-1's scan is a loop
+over tokens on the vector unit.
+
+- :func:`ssm_decode_step` / :func:`ssd_decode_step`: one token per row, each
+  row with a state of its own somewhere in the pool ``[Lm, slots, N, E]``.
+  The pool is aliased through the call; a grid step reads a row's state block
+  where it lies (the layer and the slot come from prefetched scalars),
+  updates it and writes it back: one read and one write of each state,
+  nothing else of the pool touched. The row's convolution tail rides along:
+  its block of the tail pool ``[Lm, slots, (K-1)*8, W/8]`` (``W`` the
+  convolved channels padded to whole tiles: ``E`` for Mamba-1, ``E + 2 G N``
+  for Mamba-2, where x, B and C are convolved together) comes in, drops its
+  oldest tap, takes the row's new input as its newest and goes back. Left to
+  XLA, that update was a row scatter of 30 KiB rows, which costs by the row:
+  3.3 ms of a 19 ms decode step at 128 rows x 26 layers (my chip run, PR 31),
+  against nothing here (the blocks move under the state's).
+- :func:`ssm_chunk_scan` / :func:`ssd_chunk_scan`: a pass's packed prompt
+  rows, ``G`` chunk slots of ``Cs`` rows. The state stays in on-chip memory
+  across a slot's token blocks; at a slot's first block it is loaded from
+  ``h0`` unless the slot continues the one before it (``cont``); after a
+  slot's last block it is written to ``hT``. Rows with ``dt = 0`` leave the
+  state as it is (``exp(0) = 1``, nothing added), which is how a chunk
+  shorter than its slot is padded.
 
 Each has a plain-XLA form (``*_xla``) for shapes the kernel refuses (``E`` not
 a multiple of 128, a slot size not a multiple of 8) and as what the tests
-hold the kernels to. On the CPU the kernels run through the Pallas
-interpreter (``_backend.interpret()``).
+hold the kernels to; the Mamba-2 forms are the Mamba-1 forms with ``dt`` and
+``a`` repeated over a head's channels, token by token. On the CPU the kernels
+run through the Pallas interpreter (``_backend.interpret()``).
 """
 
 from __future__ import annotations
@@ -275,3 +299,257 @@ def ssm_chunk_scan_xla(dt, x, B, C, A, h0, cont):
             ys.append(y)
             hs.append(h)
         return jnp.concatenate(ys), jnp.stack(hs)
+
+
+# --------------------------------------------------------------------------- #
+# Mamba-2 (SSD): a matrix state per head, in the same [N, E] layout
+# --------------------------------------------------------------------------- #
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _per_channel(v: jax.Array, P: int) -> jax.Array:
+    """``[.., H]`` -> ``[.., H * P]``: a head's value on each of its channels."""
+    return jnp.repeat(v.astype(jnp.float32), P, axis=-1)
+
+
+def _tail_rows(new: jax.Array, tails: jax.Array) -> jax.Array:
+    """A row's new convolution input ``[S, W]`` as the tail pool holds a tap:
+    ``[S, 8, W8]``, zero past ``W`` (the pool pads the convolved channels to
+    whole tiles)."""
+    W8 = tails.shape[-1]
+    new = new.astype(tails.dtype)
+    return jnp.pad(new, ((0, 0), (0, TAP_ROWS * W8 - new.shape[1]))).reshape(
+        -1, TAP_ROWS, W8)
+
+
+def _ssd_decode_kernel(l_ref, slot_ref, da_ref, dx_ref, b_ref, c_ref, new_ref,
+                       h_ref, t_ref, y_ref, ho_ref, to_ref):
+    del l_ref, slot_ref               # read by the index maps
+    reps = h_ref.shape[3] // LANES
+    h = (da_ref[0] * h_ref[0, 0]
+         + dx_ref[0] * pltpu.repeat(b_ref[0], reps, axis=1))
+    ho_ref[0, 0] = h.astype(ho_ref.dtype)
+    y_ref[0] = jnp.sum(h * pltpu.repeat(c_ref[0], reps, axis=1), axis=0,
+                       keepdims=True)
+
+    # the tail's block is the row's, whatever the channel block: shifted once
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        kept = t_ref.shape[2] - TAP_ROWS
+        if kept:
+            to_ref[0, 0, :kept] = t_ref[0, 0, TAP_ROWS:]
+        to_ref[0, 0, kept:] = new_ref[0]
+
+
+def _decode_block(E: int) -> int:
+    for eb in (2048, 1024, 512, 256, 128):
+        if E % eb == 0:
+            return eb
+    return 0
+
+
+def ssd_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
+                    dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
+                    a: jax.Array, new: jax.Array):
+    """One step of the Mamba-2 recurrence for ``S`` rows whose states lie in
+    ``pool``, and the shift of their convolution tails in ``tails``.
+
+    pool:  [Lm, NS, N, E] float32 — ALIASED; channel ``h * P + p`` of head
+           ``h`` on the lanes (the module's docstring)
+    tails: [Lm, NS, (K-1)*8, W8] float32 — ALIASED: tap ``j`` of a slot is
+           its rows ``8j..8j+7``, convolved channel ``w`` at ``[w // W8,
+           w % W8]``
+    l, slots: as :func:`ssm_decode_step`
+    dt:    [S, H] float32     x: [S, E]     B, C: [S, N]     a: [H] (negative)
+    new:   [S, W] the convolution's input at this token (x, B and C's)
+
+    Returns ``(y [S, E] float32, pool, tails)``. Per row the kernel reads and
+    writes the state once, in blocks of channels: 2 x 4 MiB at ``N`` 128,
+    ``E`` 8192, which is its floor."""
+    Lm, NS, N, E = pool.shape
+    S, H = dt.shape
+    TR, W8 = tails.shape[2:]
+    Eb = _decode_block(E)
+    if not Eb or W8 % LANES or N % 8:
+        return ssd_decode_step_xla(pool, tails, l, slots, dt, x, B, C, a, new)
+    P = E // H
+    with jax.named_scope("ssd_decode_step"):
+        dt = dt.astype(jnp.float32)
+        da = _per_channel(jnp.exp(dt * a.astype(jnp.float32)), P)
+        dx = _per_channel(dt, P) * x.astype(jnp.float32)
+        row = lambda i, e, l_ref, s_ref: (i, 0, 0)
+        chan = lambda i, e, l_ref, s_ref: (i, 0, e)
+        state = pl.BlockSpec(
+            (1, 1, N, Eb), lambda i, e, l_ref, s_ref: (l_ref[0], s_ref[i], 0, e))
+        tail = pl.BlockSpec(
+            (1, 1, TR, W8), lambda i, e, l_ref, s_ref: (l_ref[0], s_ref[i], 0, 0))
+        call = pl.pallas_call(
+            _ssd_decode_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(S, E // Eb),
+                in_specs=[pl.BlockSpec((1, 1, Eb), chan),
+                          pl.BlockSpec((1, 1, Eb), chan),
+                          pl.BlockSpec((1, N, LANES), row),
+                          pl.BlockSpec((1, N, LANES), row),
+                          pl.BlockSpec((1, TAP_ROWS, W8), row), state, tail],
+                out_specs=[pl.BlockSpec((1, 1, Eb), chan), state, tail]),
+            out_shape=[jax.ShapeDtypeStruct((S, 1, E), jnp.float32),
+                       jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+                       jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
+            input_output_aliases={7: 1, 8: 2},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=_backend.interpret(),
+        )
+        y, pool, tails = call(
+            jnp.asarray(l, jnp.int32).reshape(1), slots.astype(jnp.int32),
+            da[:, None], dx[:, None], lane_spread(B), lane_spread(C),
+            _tail_rows(new, tails), pool, tails)
+    return y[:, 0], pool, tails
+
+
+def ssd_decode_step_xla(pool, tails, l, slots, dt, x, B, C, a, new):
+    """:func:`ssd_decode_step` in plain XLA: the Mamba-1 form with ``dt`` and
+    ``a`` repeated over each head's channels."""
+    N, E = pool.shape[2:]
+    P = E // dt.shape[1]
+    with jax.named_scope("ssd_decode_step_xla"):
+        A = jnp.broadcast_to(_per_channel(a, P)[None, :], (N, E))
+        return ssm_decode_step_xla(pool, tails, l, slots, _per_channel(dt, P),
+                                   x.astype(jnp.float32), B, C, A,
+                                   _tail_rows(new, tails))
+
+
+def _ssd_scan_kernel(cont_ref, x_ref, bt_ref, c_ref, col_ref, row_ref, h0_ref,
+                     y_ref, ht_ref, h_sc, g_sc, *, blocks_per_slot: int,
+                     P: int):
+    tb, e = pl.program_id(0), pl.program_id(1)
+    g = tb // blocks_per_slot
+    f32 = jnp.float32
+    dot = lambda a, b: jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=f32)
+
+    @pl.when(jnp.logical_and(tb % blocks_per_slot == 0, cont_ref[g] == 0))
+    def _():
+        h_sc[e] = h0_ref[0]
+
+    Cm, Bt = c_ref[...], bt_ref[0]                       # [Q, N], [N, Q]
+
+    @pl.when(e == 0)        # C B^T: one group, every head's
+    def _():
+        g_sc[...] = dot(Cm, Bt)
+
+    Q, Eb = x_ref.shape
+    hpt = LANES // P                                     # heads a lane tile
+    G = g_sc[...]
+    col, row = col_ref[0, 0], row_ref[0, 0]              # [Q, Hb], [Hb, Q]
+    seen = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // P
+    S_all = h_sc[e]                                      # [N, Eb]
+    ys, states = [], []
+    for k in range(Eb // LANES):
+        X = x_ref[:, k * LANES:(k + 1) * LANES]          # dt * x  [Q, 128]
+        S = S_all[:, k * LANES:(k + 1) * LANES]          # [N, 128]
+        y = jnp.zeros((Q, LANES), f32)
+        carried = jnp.zeros((Q, LANES), f32)   # exp(cum_t), by the lane's head
+        to_end = jnp.zeros((Q, LANES), f32)    # exp(cum_Q - cum_t)
+        whole = jnp.zeros((1, LANES), f32)     # exp(cum_Q)
+        for i in range(hpt):
+            j = k * hpt + i
+            cj, rj = col[:, j:j + 1], row[j:j + 1, :]    # [Q, 1], [1, Q]
+            last = cj[Q - 1:Q, :]                        # [1, 1]
+            L = jnp.exp(jnp.where(seen, cj - rj, -jnp.inf))
+            on = lane_head == i
+            y = y + dot(L * G, jnp.where(on, X, 0.0))
+            carried = jnp.where(on, jnp.exp(cj), carried)
+            to_end = jnp.where(on, jnp.exp(last - cj), to_end)
+            whole = jnp.where(on, jnp.exp(last), whole)
+        ys.append(y + carried * dot(Cm, S))
+        states.append(whole * S + dot(Bt, X * to_end))
+    y_ref[...] = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+    S_new = states[0] if len(states) == 1 else jnp.concatenate(states, axis=1)
+    h_sc[e] = S_new
+    ht_ref[0] = S_new
+
+
+#: tokens of one product-form chunk: the model's ``mamba_chunk_size`` where it
+#: divides a chunk slot, else the largest of these that does
+SSD_CHUNKS = (256, 128, 64, 32, 16, 8)
+
+
+def ssd_chunk_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
+                   a: jax.Array, h0: jax.Array, cont: jax.Array,
+                   chunk: int = 256):
+    """The Mamba-2 recurrence over ``G`` chunk slots of ``Cs`` packed rows
+    each, in the product form (SSD) over chunks of ``Q`` tokens (``chunk``, or
+    the largest of :data:`SSD_CHUNKS` under it that divides ``Cs``).
+
+    dt:   [G*Cs, H] float32 (zero on rows that hold no token)
+    x:    [G*Cs, E]     B, C: [G*Cs, N]     a: [H] float32 (negative)
+    h0:   [G, N, E] float32, the state a slot starts from
+    cont: [G] int32, as :func:`ssm_chunk_scan`
+
+    Returns ``(y [G*Cs, E] float32, hT [G, N, E] float32)``.
+
+    Per chunk, with ``cum_t`` the running sum of ``dt_s a`` inside it (one a
+    head): ``y_t = sum_{s<=t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s +
+    exp(cum_t) C_t S`` and ``S' = exp(cum_Q) S + sum_s exp(cum_Q - cum_s) B_s
+    (outer) dt_s x_s``: three products a head pair on the MXU in float32
+    (``C B^T`` once a chunk: one group), the state carried across a slot's
+    chunks in on-chip memory. ``exp`` only ever sees differences ``<= 0``."""
+    G, N, E = h0.shape
+    T, H = dt.shape
+    Cs, P = T // G, E // H
+    Q = next((q for q in SSD_CHUNKS if q <= chunk and Cs % q == 0), 0)
+    Eb = next((eb for eb in (512, 256, 128) if E % eb == 0), 0)
+    if not (Q and Eb) or LANES % P or N % 8:
+        return ssd_chunk_scan_xla(dt, x, B, C, a, h0, cont)
+    Hb, nE, nC, bps = Eb // P, E // Eb, T // Q, Cs // Q
+    f32 = jnp.float32
+    with jax.named_scope("ssd_chunk_scan"):
+        dt = dt.astype(f32)
+        cum = jnp.cumsum((dt * a.astype(f32)).reshape(nC, Q, nE, Hb), axis=1)
+        col = jnp.transpose(cum, (0, 2, 1, 3))                # [nC, nE, Q, Hb]
+        row = jnp.transpose(cum, (0, 2, 3, 1))                # [nC, nE, Hb, Q]
+        dx = _per_channel(dt, P) * x.astype(f32)
+        Bt = jnp.transpose(B.astype(f32).reshape(nC, Q, N), (0, 2, 1))
+        cont = cont.astype(jnp.int32).at[0].set(0)
+        slot = lambda tb, e, c: (tb // bps, 0, e)
+        heads = lambda tb, e, c: (tb, e, 0, 0)
+        call = pl.pallas_call(
+            functools.partial(_ssd_scan_kernel, blocks_per_slot=bps, P=P),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(nC, nE),
+                in_specs=[pl.BlockSpec((Q, Eb), lambda tb, e, c: (tb, e)),
+                          pl.BlockSpec((1, N, Q), lambda tb, e, c: (tb, 0, 0)),
+                          pl.BlockSpec((Q, N), lambda tb, e, c: (tb, 0)),
+                          pl.BlockSpec((1, 1, Q, Hb), heads),
+                          pl.BlockSpec((1, 1, Hb, Q), heads),
+                          pl.BlockSpec((1, N, Eb), slot)],
+                out_specs=[pl.BlockSpec((Q, Eb), lambda tb, e, c: (tb, e)),
+                           pl.BlockSpec((1, N, Eb), slot)],
+                scratch_shapes=[pltpu.VMEM((nE, N, Eb), f32),
+                                pltpu.VMEM((Q, Q), f32)]),
+            out_shape=[jax.ShapeDtypeStruct((T, E), f32),
+                       jax.ShapeDtypeStruct((G, N, E), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=64 << 20),
+            interpret=_backend.interpret(),
+        )
+        y, hT = call(cont, dx, Bt, C.astype(f32), col, row, h0.astype(f32))
+    return y, hT
+
+
+def ssd_chunk_scan_xla(dt, x, B, C, a, h0, cont):
+    """:func:`ssd_chunk_scan` in plain XLA, in the RECURRENT form: token by
+    token, slot after slot (the Mamba-1 form with ``dt`` and ``a`` repeated
+    over each head's channels)."""
+    G, N, E = h0.shape
+    P = E // dt.shape[1]
+    with jax.named_scope("ssd_chunk_scan_xla"):
+        A = jnp.broadcast_to(_per_channel(a, P)[None, :], (N, E))
+        return ssm_chunk_scan_xla(_per_channel(dt, P), x, B, C, A, h0, cont)

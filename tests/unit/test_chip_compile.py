@@ -36,6 +36,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     kv_scale_tiles_shape, paged_chunk_attention_batched,
     paged_decode_attention, paged_decode_attention_sidebuf,
     paged_decode_attention_step)
+from deepspeed_tpu.ops.pallas.ssm import ssd_chunk_scan, ssd_decode_step
 from deepspeed_tpu.ops.pallas.paged_splitk import (
     paged_decode_attention_splitk_pallas, paged_sidebuf_attention_splitk)
 
@@ -172,6 +173,19 @@ CASES = {
         paged_decode_attention_step,
         [((S, 32, 64), BF16), ((S, 8, 64), BF16), ((S, 8, 64), BF16),
          _pool(8, 64), _BT, _CL]),
+    # the Mamba-2 (SSD) state kernels at granite-4.0-h-small's widths: 128
+    # heads of 64 over a state of 128, a pass of 4 chunk slots of 256 and a
+    # 64-row step over the pools of 9 layers x 73 slots
+    "ssd_chunk_scan_h128_p64_n128": (
+        ssd_chunk_scan,
+        [((1024, 128), F32), ((1024, 8192), F32), ((1024, 128), F32),
+         ((1024, 128), F32), ((128,), F32), ((4, 128, 8192), F32),
+         ((4,), I32)]),
+    "ssd_decode_step_h128_p64_n128": (
+        ssd_decode_step,
+        [((9, 73, 128, 8192), F32), ((9, 73, 24, 1152), F32), ((), I32),
+         ((64,), I32), ((64, 128), F32), ((64, 8192), F32), ((64, 128), F32),
+         ((64, 128), F32), ((128,), F32), ((64, 8448), BF16)]),
 }
 
 
@@ -886,3 +900,91 @@ def test_train_step_without_room_for_every_product_keeps_fewer(
     assert 0 < engine.remat_plan.rung < len(ac.LADDER) - 1
     assert compiled.as_text().count("tpu_custom_call") == 6
     assert _device_bytes(compiled) <= V5E_HBM_LIMIT
+
+
+def _granite_stage(arr):
+    """Spec, stacked weight trees (shapes only) and pools of granite-4.0-h-
+    small as the benchmark's configuration runs it: published layers 0-9 at
+    published widths, 36 of 72 experts held (9.24 GiB of weights), 2,656
+    pages and the state pool of 72 + 1 slots (2.63 GiB)."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
+                                                              StatePoolConfig)
+    from deepspeed_tpu.models.granite import (GraniteConfig,
+                                              GraniteForCausalLM)
+    cfg = GraniteConfig.granite_4_0_h_small(
+        num_hidden_layers=10, layer_types=tuple(
+            "attention" if i == 5 else "mamba" for i in range(10)),
+        experts_held=(0, 36), dtype=BF16)
+    model = GraniteForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_granite(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    pool = StatePoolConfig(num_layers=9, num_slots=72, d_inner=8192,
+                           d_state=128, d_conv=4, conv_dim=8448)
+    ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
+    kv = StatefulKV(arr(BF16, 1, 2657, 2, 8, BS, D),
+                    arr(F32, *ssm_shape.shape), arr(F32, *conv_shape.shape))
+    return spec, weights, kv
+
+
+@pytest.mark.parametrize("program", ["serve_decode_step",
+                                     "serve_prefill_packed",
+                                     "serve_paged_pass"])
+def test_granite_programs_update_the_state_pools_in_place(program, v5e,
+                                                          monkeypatch):
+    """granite-4.0-h-small's stage 0 at published widths: the 64-row decode
+    step, the packed prefill pass (4 slots of 256) and the paged pass. Both
+    SSD kernels are where they belong; the pools (2.60 GiB of states, 72 MiB
+    of tails, 1.30 GiB of pages) are the outputs' buffers; and the program's
+    temporaries stay small — XLA's gather of four 4 MiB states sliced the
+    WHOLE state pool into column blocks first (2.6 GiB of copies a layer,
+    and the pass did not fit the chip; compile, PR 39), so those rows move
+    one dynamic slice each."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _granite_stage(arr)
+    assert [n for _, _, n in rm.layer_runs(spec)] == [5, 1, 4]
+    rows, pages = 64, 80
+    host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=rows,
+                       max_blocks=pages).device_arrays()
+    if program == "serve_decode_step":
+        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
+                                   arr(I32, rows, pages), arr(I32, rows),
+                                   arr(jnp.uint32, 2), arr(F32),
+                                   arr(I32, rows)).compile()
+        kernels, limit = ("ssd_decode_step",), 64 << 20
+    elif program == "serve_prefill_packed":
+        batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
+                 else arr(I32, *host[k].shape)
+                 for k in rm.PREFILL_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels, limit = ("ssd_chunk_scan",), 384 << 20
+    else:
+        batch = {k: arr(I32, *host[k].shape)
+                 for k in rm.PAGED_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_ragged_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels, limit = ("ssd_chunk_scan", "ssd_decode_step"), 384 << 20
+    text = compiled.as_text()
+    for kernel in kernels + ("moe_grouped_matmul",):
+        assert kernel in text, f"{kernel} is not in the program"
+    assert "mini-gather" not in text
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < limit
