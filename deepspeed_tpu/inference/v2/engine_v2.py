@@ -437,6 +437,12 @@ class InferenceEngineV2:
                          self.state_config.bytes_per_slot())
             recurrence = 2 if self.spec.mamba.get("kind") == "mamba2" else 1
             _tracer.note("serve/state/kind", recurrence)
+        if any(k.block is not None for k in self.spec.layer_kinds or ()):
+            # one block a layer: how many layers are each block (what a
+            # reader divides a block's share of a step by)
+            whats = [k.what for k in self.spec.layer_kinds]
+            for what in sorted(set(whats)):
+                _tracer.note(f"serve/layers/blocks/{what}", whats.count(what))
         state = "" if self.state_config is None else (
             f"; Mamba-{recurrence} "
             f"state pool {self.state_config.num_slots}+dump slots x "

@@ -88,6 +88,7 @@ class LayerKind(NamedTuple):
     rope: bool              # rotates q/k by position (else no positions)
     moe: bool               # routed experts (else the dense MLP)
     mamba = False           # an attention layer (else: MambaKind)
+    block = None            # the pair: mixer, then FFN (else: BlockKind)
 
     def describe(self) -> str:
         attn = "full" if self.window is None else f"window {self.window}"
@@ -107,10 +108,52 @@ class MambaKind(NamedTuple):
     window = None
     rope = False
     mamba = True
+    block = None
 
     def describe(self) -> str:
         return ("Mamba state-space mixer (no pages), "
                 f"{'MoE' if self.moe else 'dense'} FFN")
+
+
+class BlockKind(NamedTuple):
+    """The kind of a layer that is ONE block, ``x + block(norm(x))``, where
+    the two kinds above are a mixer followed by an FFN (nemotron_h: a Mamba
+    mixer, OR attention, OR routed experts, and nothing else in the layer).
+    A layer of experts or of a dense MLP alone addresses neither pool: it
+    holds no pages and no state."""
+    what: str                       # "mamba" | "attention" | "moe" | "mlp"
+    window: Optional[int] = None    # of an attention block
+    rope: bool = False
+
+    @property
+    def mamba(self) -> bool:
+        return self.what == "mamba"
+
+    @property
+    def moe(self) -> bool:
+        return self.what == "moe"
+
+    @property
+    def block(self) -> str:         # which half of the pair the layer is
+        return "mixer" if self.what in ("mamba", "attention") else "ffn"
+
+    def describe(self) -> str:
+        if self.what == "mamba":
+            return "Mamba state-space mixer alone (no pages)"
+        if self.what == "attention":
+            attn = "full" if self.window is None else f"window {self.window}"
+            return (f"attention alone ({attn}, "
+                    f"{'rotary' if self.rope else 'no positions'})")
+        return ("routed experts" if self.moe else "dense MLP") \
+            + " alone (no pages, no state)"
+
+
+def _holds(kind) -> Optional[str]:
+    """The pool a layer of ``kind`` addresses: ``"state"`` (a Mamba mixer),
+    ``"pages"`` (attention) or None (an FFN alone)."""
+    if kind.mamba:
+        return "state"
+    return None if kind.block == "ffn" else "pages"
 
 
 @dataclass
@@ -124,7 +167,7 @@ class RaggedModelSpec:
     vocab_size: int
     norm: str = "rms"              # "rms" | "ln"
     # gated: "swiglu" (silu gate) | "geglu" (tanh-gelu gate, Gemma)
-    # plain: "gelu" (tanh) | "gelu_exact" (erf) | "silu" | "relu"
+    # plain: "gelu" (tanh) | "gelu_exact" (erf) | "silu" | "relu" | "relu2"
     activation: str = "swiglu"
     rope_theta: Optional[float] = 10000.0   # None -> no rotary
     rotary_dim: Optional[int] = None        # partial rotary (phi); None = full head
@@ -143,7 +186,9 @@ class RaggedModelSpec:
     # without it, over their sum if "route_norm", times "route_scale" (afmoe,
     # joyai). "held": (first, count) — the expert stacks hold only experts
     # first..first+count-1 of the E the router scores (one chip's share of an
-    # expert-parallel deployment); absent: all E
+    # expert-parallel deployment); absent: all E. "act": the plain activation
+    # of experts that are two matrices (no gate stack), as ``activation``
+    # names them; absent: "gelu"
     moe: Optional[Dict[str, Any]] = None
     # multi-head latent attention: {"q_lora_rank", "kv_lora_rank",
     # "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"}. The pages then
@@ -158,9 +203,14 @@ class RaggedModelSpec:
     # layer is of the one kind the scalar fields give. Where kinds differ,
     # ``window`` is None (no page ring: every layer holds whole-context
     # pages), ``rope_theta`` and ``moe`` describe the layers that have them,
-    # and ``weights["layers"]`` is a tuple of stacked trees, one per run of
-    # equal kinds (:func:`layer_runs`)
-    layer_kinds: Optional[Tuple[Any, ...]] = None   # LayerKind | MambaKind
+    # and ``weights["layers"]`` is a tuple with one entry per unit the layer
+    # loop scans (:func:`layer_units`): a run's stacked tree, or for a unit of
+    # several kinds that repeats a tuple of stacked trees, one a kind
+    layer_kinds: Optional[Tuple[Any, ...]] = None   # Layer-/Mamba-/BlockKind
+    # on a run's spec (:func:`layer_runs`) of layers that are one block
+    # (:class:`BlockKind`): "mixer" (no FFN follows) or "ffn" (no mixer
+    # before it). None: the pair every other layer is
+    block: Optional[str] = None
     # widths of the Mamba mixer of a model that has such layers
     # (``layer_kinds`` says which); on a run's spec (:func:`layer_runs`) it is
     # set for a run of Mamba layers and None for a run of attention layers.
@@ -185,6 +235,15 @@ class RaggedModelSpec:
     dtype: Any = jnp.bfloat16
 
 
+def _run_spec(spec: RaggedModelSpec, kind) -> RaggedModelSpec:
+    """The spec the layers of one ``kind`` are built with."""
+    return replace(spec, layer_kinds=None, window=kind.window,
+                   rope_theta=spec.rope_theta if kind.rope else None,
+                   moe=spec.moe if kind.moe else None,
+                   mamba=spec.mamba if kind.mamba else None,
+                   block=kind.block)
+
+
 def layer_runs(spec: RaggedModelSpec
                ) -> List[Tuple[RaggedModelSpec, int, int]]:
     """Maximal runs of layers of one kind, as ``(the spec that run's layers
@@ -198,48 +257,128 @@ def layer_runs(spec: RaggedModelSpec
             runs[-1][2] += 1
         else:
             runs.append([kind, l, 1])
-    return [(replace(spec, layer_kinds=None, window=kind.window,
-                     rope_theta=spec.rope_theta if kind.rope else None,
-                     moe=spec.moe if kind.moe else None,
-                     mamba=spec.mamba if kind.mamba else None), l0, n)
-            for kind, l0, n in runs]
+    return [(_run_spec(spec, kind), l0, n) for kind, l0, n in runs]
+
+
+def _unit_cuts(kinds: Tuple[Any, ...]) -> List[Tuple[int, int, int]]:
+    """``kinds`` cut into repeating units, as ``(first layer, period p,
+    repeats r)``: the fewest units, then the shortest periods. A run of two
+    or more layers of one kind is always a unit of its own (p 1); a stretch
+    of layers that each differ from their neighbours is cut into units of
+    p >= 2 kinds that repeat r >= 2 times, and single layers."""
+    cuts: List[Tuple[int, int, int]] = []
+    n, i = len(kinds), 0
+    while i < n:
+        j = i
+        while j + 1 < n and kinds[j + 1] == kinds[i]:
+            j += 1
+        if j > i:
+            cuts.append((i, 1, j - i + 1))
+            i = j + 1
+            continue
+        # the stretch of single layers from i on
+        j = i
+        while j + 1 < n and kinds[j + 1] != kinds[j] and (
+                j + 2 >= n or kinds[j + 2] != kinds[j + 1]):
+            j += 1
+        end = j + 1
+        best: Dict[int, Tuple[Tuple[int, int], List]] = {end: ((0, 0), [])}
+        for a in range(end - 1, i - 1, -1):
+            cost, rest = best[a + 1]
+            pick = ((cost[0] + 1, cost[1] + 1), [(a, 1, 1)] + rest)
+            for p in range(2, (end - a) // 2 + 1):
+                r = 1
+                while a + (r + 1) * p <= end and kinds[
+                        a + r * p:a + (r + 1) * p] == kinds[a:a + p]:
+                    r += 1
+                for reps in range(2, r + 1):
+                    cost, rest = best[a + reps * p]
+                    cand = (cost[0] + 1, cost[1] + p)
+                    if cand < pick[0]:
+                        pick = (cand, [(a, p, reps)] + rest)
+            best[a] = pick
+        cuts.extend(best[i][1])
+        i = end
+    return cuts
+
+
+def layer_units(spec: RaggedModelSpec
+                ) -> List[Tuple[Tuple[RaggedModelSpec, ...], int, int]]:
+    """The layers as the layer loop scans them: ``(the specs of a unit's p
+    layers, the unit's first layer, how many times it repeats)``. A unit of
+    one kind is a run of :func:`layer_runs` and is scanned as ever; where
+    every layer differs from the one before it (nemotron_h: ``M E M E M *
+    E M ..``) maximal runs would be one scan a layer, and a unit of p kinds
+    that repeats is ONE scan whose body runs the p layers in turn
+    (:func:`_unit_cuts`)."""
+    if spec.layer_kinds is None:
+        return [((spec,), 0, spec.num_layers)]
+    kinds = tuple(spec.layer_kinds)
+    return [(tuple(_run_spec(spec, k) for k in kinds[l0:l0 + p]), l0, r)
+            for l0, p, r in _unit_cuts(kinds)]
 
 
 def describe_layer_kinds(spec: RaggedModelSpec) -> str:
-    """One line for the engine's set-up log."""
+    """One line for the engine's set-up log: the layers as the layer loop
+    scans them (:func:`layer_units`)."""
+    kinds = spec.layer_kinds
+
+    def one(rs, l):
+        if kinds is not None:
+            return kinds[l].describe()
+        return (MambaKind(rs.moe is not None) if rs.mamba is not None
+                else LayerKind(rs.window, rs.rope_theta is not None,
+                               rs.moe is not None)).describe()
+
     return "; ".join(
-        f"layers {l0}-{l0 + n - 1}: "
-        + (MambaKind(rs.moe is not None) if rs.mamba is not None
-           else LayerKind(rs.window, rs.rope_theta is not None,
-                          rs.moe is not None)).describe()
-        for rs, l0, n in layer_runs(spec))
+        f"layers {l0}-{l0 + len(specs) * n - 1}: "
+        + (one(specs[0], l0) if len(specs) == 1 else
+           f"{n} x [" + " | ".join(one(rs, l0 + k)
+                                   for k, rs in enumerate(specs)) + "]")
+        for specs, l0, n in layer_units(spec))
+
+
+def _layer_holds(spec: RaggedModelSpec) -> List[Optional[str]]:
+    """For each layer, the pool it addresses (:func:`_holds`)."""
+    if spec.layer_kinds is None:
+        return ["state" if spec.mamba is not None else
+                None if spec.block == "ffn" else "pages"] * spec.num_layers
+    return [_holds(k) for k in spec.layer_kinds]
 
 
 def num_state_layers(spec: RaggedModelSpec) -> int:
     """Layers that hold a recurrent state per sequence (Mamba mixers)."""
-    return sum(n for rs, _, n in layer_runs(spec) if rs.mamba is not None)
+    return _layer_holds(spec).count("state")
 
 
 def num_page_layers(spec: RaggedModelSpec) -> int:
     """Layers that hold KV pages — THE layer count of the page pool, of a
-    page's bytes and of everything counted in tokens x layers. Not
-    ``spec.num_layers`` where some layers carry no attention."""
-    return spec.num_layers - num_state_layers(spec)
+    page's bytes and of everything counted in tokens x layers: the layers
+    that attend. Not ``spec.num_layers`` where some layers carry no
+    attention (a Mamba mixer holds a state, an FFN alone holds nothing)."""
+    return _layer_holds(spec).count("pages")
+
+
+def _pool_index(spec: RaggedModelSpec) -> List[int]:
+    """For each layer, its index in the pool it addresses: its rank among
+    the layers of its sort (pages for attention, state for Mamba; a layer
+    that addresses neither counts among its like, and nothing reads that).
+    A model whose layers all hold pages addresses them by the layer's index
+    in the model, as ever."""
+    holds = _layer_holds(spec)
+    seen: Dict[Optional[str], int] = {}
+    index = []
+    for h in holds:
+        index.append(seen.get(h, 0))
+        seen[h] = index[-1] + 1
+    return index
 
 
 def _pool_bases(spec: RaggedModelSpec) -> List[int]:
     """For each run of :func:`layer_runs`, its first layer's index in the
-    pool its layers address: a layer's rank among the layers of its sort
-    (pages for attention, state for Mamba). A model whose layers all hold
-    pages addresses them by the layer's index in the model, as ever."""
-    runs = layer_runs(spec)
-    if spec.mamba is None:
-        return [l0 for _, l0, _ in runs]
-    bases, seen = [], {True: 0, False: 0}
-    for rs, _, n in runs:
-        bases.append(seen[rs.mamba is not None])
-        seen[rs.mamba is not None] += n
-    return bases
+    pool its layers address (:func:`_pool_index`)."""
+    index = _pool_index(spec)
+    return [index[l0] for _, l0, _ in layer_runs(spec)]
 
 
 # --------------------------------------------------------------------------- #
@@ -248,6 +387,18 @@ def _pool_bases(spec: RaggedModelSpec) -> List[int]:
 
 def _stack(trees: List[Any]) -> Any:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
+def _stack_units(spec: RaggedModelSpec, layer: Callable[[int], Any]) -> Tuple:
+    """``weights["layers"]`` of a model of several kinds, from ``layer(i)``
+    (layer ``i``'s canonical weights): one entry per unit of
+    :func:`layer_units` — a run's layers stacked, or for a unit of p kinds a
+    tuple of p trees, tree k stacking layer k of each of its repeats."""
+    return tuple(
+        _stack([layer(l0 + i) for i in range(n)]) if len(specs) == 1 else
+        tuple(_stack([layer(l0 + i * len(specs) + k) for i in range(n)])
+              for k in range(len(specs)))
+        for specs, l0, n in layer_units(spec))
 
 
 def adapt_llama(params: Dict, config,
@@ -498,8 +649,7 @@ def adapt_afmoe(params: Dict, config,
             out["mlp"] = swiglu(mlp)
         return out
 
-    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
-                   for _, l0, n in layer_runs(spec))
+    stacks = _stack_units(spec, layer)
     weights = {
         "embed": params["embed_tokens"]["embedding"],
         "layers": stacks if spec.layer_kinds is not None else stacks[0],
@@ -574,8 +724,7 @@ def adapt_jamba(params: Dict, config,
                        wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"])
         return out
 
-    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
-                   for _, l0, n in layer_runs(spec))
+    stacks = _stack_units(spec, layer)
     weights = {
         "embed": params["embed_tokens"]["embedding"],
         "layers": stacks if spec.layer_kinds is not None else stacks[0],
@@ -664,8 +813,7 @@ def adapt_joyai(params: Dict, config,
             out["mlp"] = swiglu(mlp)
         return out
 
-    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
-                   for _, l0, n in layer_runs(spec))
+    stacks = _stack_units(spec, layer)
     weights = {
         "embed": params["embed_tokens"]["embedding"],
         "layers": stacks if spec.layer_kinds is not None else stacks[0],
@@ -760,12 +908,95 @@ def adapt_granite(params: Dict, config,
                        wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"])
         return out
 
-    stacks = tuple(_stack([layer(i) for i in range(l0, l0 + n)])
-                   for _, l0, n in layer_runs(spec))
+    stacks = _stack_units(spec, layer)
     weights = {
         "embed": params["embed_tokens"]["embedding"],
         "layers": stacks if spec.layer_kinds is not None else stacks[0],
         "final_norm": {"scale": params["norm"]["weight"]},
+    }
+    return spec, weights
+
+
+def adapt_nemotron_h(params: Dict, config,
+                     max_context: Optional[int] = None
+                     ) -> Tuple[RaggedModelSpec, Dict]:
+    """models/nemotron_h.py param tree (NemotronHForCausalLM; NVIDIA
+    Nemotron-H, ``nemotron_h``).
+
+    One :class:`BlockKind` per layer from ``hybrid_override_pattern``: each
+    layer is ONE block behind its one norm (``ln1``) — a Mamba-2 mixer
+    (``spec.mamba`` with ``n_groups`` pairs of B and C), attention without
+    window or positions, or routed experts. The experts are two stacks
+    (``w_up``, ``w_down``: no gate; the width zero-padded to whole lane
+    tiles, :func:`_pad_expert_width`) with ``relu2`` between them, and so is
+    the shared expert (unpadded: a dense product); the router is the sigmoid
+    one with its selection bias (``expert_bias``), weights normalised over
+    the chosen and scaled; the stacks hold ``config.held`` of its
+    ``n_routed_experts``."""
+    del max_context
+    from deepspeed_tpu.models import nemotron_h as zoo
+    what = {zoo.MAMBA: "mamba", zoo.MOE: "moe", zoo.ATTENTION: "attention"}
+    kinds = tuple(BlockKind(what[c]) for c in config.hybrid_override_pattern)
+    first, count = config.held
+    moe = {"num_experts": config.n_routed_experts,
+           "top_k": config.num_experts_per_tok, "score_func": "sigmoid",
+           "route_norm": bool(config.norm_topk_prob),
+           "route_scale": float(config.routed_scaling_factor),
+           "act": config.mlp_hidden_act}
+    if count != config.n_routed_experts:
+        moe["held"] = (first, count)
+    spec = RaggedModelSpec(
+        family="nemotron_h",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=config.num_attention_heads,
+        num_kv_heads=config.num_key_value_heads,
+        head_dim=config.head_dim,
+        vocab_size=config.vocab_size,
+        norm="rms", activation=config.mlp_hidden_act, rope_theta=None,
+        tied_lm_head=False, eps=config.norm_eps,
+        moe=moe if any(k.moe for k in kinds) else None,
+        layer_kinds=kinds, dtype=config.dtype,
+        mamba={"kind": "mamba2", "d_inner": config.mamba_d_inner,
+               "n_heads": config.mamba_num_heads,
+               "d_head": config.mamba_head_dim,
+               "n_groups": config.n_groups,
+               "d_state": config.ssm_state_size,
+               "d_conv": config.conv_kernel,
+               "chunk": config.chunk_size} if any(
+                   k.mamba for k in kinds) else None)
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        m = lp["mixer"]
+        out = {"ln1": {"scale": lp["norm"]["weight"]}}
+        if kinds[i].mamba:
+            out["mamba"] = {
+                "in_proj": m["in_proj"]["kernel"],
+                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, W]
+                "conv_b": m["conv_bias"],
+                "dt_bias": m["dt_bias"], "A_log": m["A_log"], "D": m["D"],
+                "norm": m["norm"],
+                "out_proj": m["out_proj"]["kernel"],
+            }
+        elif kinds[i].moe:
+            w_up, w_down = _pad_expert_width(m["w_up"], m["w_down"])
+            out["moe"] = {
+                "router": m["router"]["kernel"],
+                "expert_bias": m["e_score_correction_bias"],
+                "w_up": w_up, "w_down": w_down,
+                "shared": {"w_up": m["shared_up"]["kernel"],
+                           "w_down": m["shared_down"]["kernel"]}}
+        else:
+            out.update(wq=m["q_proj"]["kernel"], wk=m["k_proj"]["kernel"],
+                       wv=m["v_proj"]["kernel"], wo=m["o_proj"]["kernel"])
+        return out
+
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": _stack_units(spec, layer),
+        "final_norm": {"scale": params["norm_f"]["weight"]},
+        "lm_head": params["lm_head"]["kernel"],
     }
     return spec, weights
 
@@ -801,6 +1032,10 @@ ADAPTERS: Dict[str, Callable] = {
     # beside a few no-position GQA layers, every FFN routed experts (of which
     # this chip may hold a share) plus a shared MLP; four plain multipliers
     "granite": adapt_granite,
+    # one block a layer (Mamba-2 with groups of B and C, OR attention, OR
+    # two-matrix relu2 experts behind a sigmoid router): BlockKind, and the
+    # layer loop scans repeating units of the pattern (layer_units)
+    "nemotron_h": adapt_nemotron_h,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
@@ -850,6 +1085,7 @@ _PLAIN_ACTS = {
     "gelu_exact": lambda x: jax.nn.gelu(x, approximate=False),  # erf-exact
     "silu": jax.nn.silu,
     "relu": jax.nn.relu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),            # nemotron_h
 }
 
 
@@ -901,28 +1137,81 @@ def _swiglu(x, m):
                m["w_down"])
 
 
+def _ffn_body(rs: "RaggedModelSpec", experts=None, l0=0):
+    """The scan body of layers that are an FFN alone (``rs.block ==
+    "ffn"``), for every serving program: the carry's first value is ``x``,
+    the rest (pools, side buffers, states) passes through untouched; ``l -
+    l0`` is the layer's place in the run's expert stacks."""
+    def layer_fn(carry, scanned):
+        x, *rest = carry
+        w, l = scanned[:2]
+        x, _ = _transformer_layer(rs, w, x, None, None, experts=experts,
+                                  l=l - l0)
+        return (x, *rest), None
+
+    return layer_fn
+
+
 def _scan_layers(spec: "RaggedModelSpec", layers, make_body, carry,
                  extra_xs: Tuple = ()):
-    """The layer loop of every serving program: one ``lax.scan`` per run of
-    layers of one kind (:func:`layer_runs`), each over that run's stacked
-    weights. ``make_body(run_spec, experts, l0)`` returns the scan body for a
-    run — built with the run's own spec, so window, rotation and FFN are
-    static arguments of its kernels — and the body is handed ``(weights of
-    the layer, its index l[, extra_xs rows of it])``: KV pages are addressed
-    by ``l``, the run's expert stacks by ``l - l0``. ``l`` is the layer's
-    index in the model, except in a model with Mamba layers
-    (:func:`_pool_bases`): there an attention layer's ``l`` is its rank among
-    the attention layers (the page pool has that many layers) and a Mamba
-    layer's its rank among the Mamba layers (the state pools'). A model of
-    one kind is one scan over all its layers, as it always was."""
+    """The layer loop of every serving program: one ``lax.scan`` per unit of
+    :func:`layer_units`. A unit of one kind is a run of layers of that kind,
+    scanned over the run's stacked weights. ``make_body(run_spec, experts,
+    l0)`` returns the scan body for a run — built with the run's own spec,
+    so window, rotation and FFN are static arguments of its kernels — and the
+    body is handed ``(weights of the layer, its index l[, extra_xs rows of
+    it])``: KV pages are addressed by ``l``, the run's expert stacks by ``l -
+    l0``. ``l`` is the layer's index in the model, except in a model some of
+    whose layers hold no pages (:func:`_pool_index`): there an attention
+    layer's ``l`` is its rank among the attention layers (the page pool has
+    that many layers), a Mamba layer's its rank among the Mamba layers (the
+    state pools'), and a layer that is an FFN alone (:func:`_ffn_body`; no
+    ``make_body`` is asked for it) addresses no pool. A model of one kind is
+    one scan over all its layers, as it always was.
+
+    A unit of p > 1 kinds that repeats r times holds a tuple of p stacked
+    trees of r layers each; its body runs the p layers in turn, each under
+    its own kind's body, layer k of repeat i at pool index ``base_k + i *
+    (the unit's layers of k's sort)`` and at place ``i`` of its own expert
+    stacks."""
     stacks = layers if isinstance(layers, tuple) else (layers,)
-    runs = layer_runs(spec)
-    assert len(stacks) == len(runs), (len(stacks), len(runs))
-    for (run_spec, l0, n), base, stack in zip(runs, _pool_bases(spec), stacks):
-        scanned, experts = _split_expert_stacks(stack)
-        xs = (scanned, jnp.arange(base, base + n, dtype=jnp.int32)) + tuple(
-            x[l0:l0 + n] for x in extra_xs)
-        carry, _ = jax.lax.scan(make_body(run_spec, experts, base), carry, xs)
+    units = layer_units(spec)
+    assert len(stacks) == len(units), (len(stacks), len(units))
+    index = _pool_index(spec)
+
+    def body_of(rs, experts, l0):
+        return (_ffn_body if rs.block == "ffn" else make_body)(rs, experts,
+                                                               l0)
+
+    for (specs, l0, n), stack in zip(units, stacks):
+        p = len(specs)
+        if p == 1:
+            base = index[l0]
+            scanned, experts = _split_expert_stacks(stack)
+            xs = (scanned, jnp.arange(base, base + n, dtype=jnp.int32)) \
+                + tuple(x[l0:l0 + n] for x in extra_xs)
+            carry, _ = jax.lax.scan(body_of(specs[0], experts, base), carry,
+                                    xs)
+            continue
+        assert isinstance(stack, tuple) and len(stack) == p, (l0, p)
+        split = [_split_expert_stacks(s) for s in stack]
+        bases = [index[l0 + k] for k in range(p)]
+        strides = [index[l0 + p + k] - index[l0 + k] for k in range(p)]
+
+        def unit_fn(carry, xs):     # traced by the scan below, in this turn
+            ws, i, *extra = xs
+            for k, rs in enumerate(specs):
+                # the body is built here, inside the scan's trace: where its
+                # layers lie in their expert stacks (l - l0 = i) depends on i
+                l = bases[k] + i * strides[k]
+                carry, _ = body_of(rs, split[k][1], l - i)(
+                    carry, (ws[k], l) + tuple(x[k] for x in extra))
+            return carry, None
+
+        xs = (tuple(sc for sc, _ in split), jnp.arange(n, dtype=jnp.int32)) \
+            + tuple(x[l0:l0 + p * n].reshape((n, p) + x.shape[1:])
+                    for x in extra_xs)
+        carry, _ = jax.lax.scan(unit_fn, carry, xs)
     return carry
 
 
@@ -938,19 +1227,23 @@ def _kind_splits(spec: "RaggedModelSpec", run_spec: "RaggedModelSpec",
 
 
 #: the shape rule of :func:`moe_grouped_kernel`, fixed from the chip table in
-#: PERF.md (PR 34; ``scripts/moe_grouped_table.py`` measures it again)
-GROUPED_PALLAS_MATRIX_BYTES = 8 << 20
+#: PERF.md (PR 34; 8 MiB then, the largest matrix measured under Mixtral's.
+#: PR 42's rows put a 9.8 MiB matrix on the Pallas kernel at 720 GB/s against
+#: 82 on XLA's; ``scripts/moe_grouped_table.py`` measures it again)
+GROUPED_PALLAS_MATRIX_BYTES = 12 << 20
 
 
 def moe_grouped_kernel(stack, dtype) -> str:
     """Which kernel an MoE layer's grouped products take, from what is static
     at trace time: ``"pallas"`` (``ops/pallas/grouped_matmul.py``) for
     bfloat16 stacks of small matrices — many small experts, read at 700 GB/s
-    where XLA's kernel reads them at 320-430; ``"xla"``
-    (``jax.lax.ragged_dot``) for everything else: int8 stacks (``{"w8",
-    "scale"}``), float32, matrices past ``GROUPED_PALLAS_MATRIX_BYTES``
-    (Mixtral's 112 MiB: XLA's kernel is at 80% of the HBM rate there),
-    widths that are not whole 128-lane tiles. The row count is no part of it:
+    where XLA's kernel reads them at 320-430 (and a 10 MiB one at 82);
+    ``"xla"`` (``jax.lax.ragged_dot``) for everything else: int8 stacks
+    (``{"w8", "scale"}``), float32, matrices past
+    ``GROUPED_PALLAS_MATRIX_BYTES`` (Mixtral's 112 MiB: XLA's kernel is at
+    80% of the HBM rate there), widths that are not whole 128-lane tiles (a
+    family whose published width is not pads its stacks with zeros when it
+    adapts them: :func:`_pad_expert_width`). The row count is no part of it:
     at a decode step's 256 assignments and at a prefill pass's 8,192 the
     table reads alike. ``stack`` is one of the layer's expert stacks
     ``[.., K, N]``, ``dtype`` the activations'."""
@@ -962,6 +1255,24 @@ def moe_grouped_kernel(stack, dtype) -> str:
     if K * N * 2 > GROUPED_PALLAS_MATRIX_BYTES:
         return "xla"
     return "pallas"
+
+
+def _pad_expert_width(w_up: jax.Array, w_down: jax.Array):
+    """Two-matrix experts ``[E, hid, F]``, ``[E, F, hid]`` with ``F`` padded
+    with zeros to whole 128-lane tiles (nemotron_h: 1856 -> 1920, 3.4% more
+    bytes). The result is the same — ``act(0) = 0`` for every plain
+    activation here but gelu's, whose 0 it is too, and a zero row of
+    ``w_down`` adds nothing — and both grouped kernels need it: the chip
+    lays a ``[.., 2688, 1856]`` array out with 2688 on the lanes (no padding
+    that way), so a kernel that wants rows of 1856 is first handed a
+    transposed COPY of the whole stack (1.2 GiB a two-layer unit; compile,
+    PR 42), and XLA's ``ragged_dot`` reads the unpadded matrices at 87 GB/s
+    (chip table, PR 42)."""
+    pad = -w_up.shape[-1] % 128
+    if not pad:
+        return w_up, w_down
+    return (jnp.pad(w_up, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(w_down, ((0, 0), (0, pad), (0, 0))))
 
 
 def moe_route(x: jax.Array, w: Dict, top_k: int,
@@ -1006,11 +1317,15 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     scores every expert by itself, chooses with ``w["expert_bias"]`` added
     and weighs without it (the bias balances load, it is not a weight), over
     the chosen scores' sum if ``route_norm``, times ``route_scale``. A
-    ``w["shared"]`` expert sees every token, unweighted.
+    ``w["shared"]`` expert sees every token, unweighted. Experts with a
+    ``w_gate`` stack are SwiGLUs; without one they are two matrices with the
+    plain activation ``routing["act"]`` between them (``"relu2"``:
+    nemotron_h; absent: tanh-gelu), and so is a shared expert without one.
     """
     T, hid = x.shape
     E = w["router"].shape[-1]
     held = (routing or {}).get("held")
+    plain = _plain_act((routing or {}).get("act", "gelu"))
     with jax.named_scope("router"):
         gates, ids = moe_route(x, w, top_k, routing)
 
@@ -1074,8 +1389,8 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     with jax.named_scope("experts"):
         if "w_gate" in w:
             h = jax.nn.silu(gg(xs, w["w_gate"])) * gg(xs, w["w_up"])
-        else:
-            h = jax.nn.gelu(gg(xs, w["w_up"]))
+        else:       # two matrices an expert: ``routing["act"]`` between them
+            h = plain(gg(xs, w["w_up"]))
         ys = gg(h, w["w_down"])[:order.shape[0]]                       # [T*K, hid]
         if held is not None:    # rows of no group hold nothing defined
             ys = jnp.where((expert_ids[order] < E)[:, None], ys, 0)
@@ -1087,7 +1402,9 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
         out = (ys * scale[:, None])[inv].reshape(T, top_k, hid).sum(axis=1)
     if "shared" in w:
         with jax.named_scope("shared"):
-            out = out + _swiglu(x, w["shared"])
+            sh = w["shared"]
+            out = out + (_swiglu(x, sh) if "w_gate" in sh else
+                         _mm(plain(_mm(x, sh["w_up"])), sh["w_down"]))
     return out.astype(dtype)
 
 
@@ -1264,9 +1581,18 @@ def quantize_weights_int8(weights: Dict) -> Dict:
     return _quantize_weight_tree(weights, q)
 
 
+def _layer_stacks(layers):
+    """Every stacked tree of ``weights["layers"]``: the one tree, a run's, or
+    each of a repeating unit's (:func:`_stack_units`)."""
+    if isinstance(layers, tuple):
+        for part in layers:
+            yield from _layer_stacks(part)
+    else:
+        yield layers
+
+
 def _quantize_weight_tree(weights: Dict, q) -> Dict:
-    runs = weights["layers"]
-    for layers in runs if isinstance(runs, tuple) else (runs,):
+    for layers in _layer_stacks(weights["layers"]):
         _quantize_layer_stack(layers, q)
     if "lm_head" in weights and not isinstance(weights["lm_head"], dict):
         weights["lm_head"] = q(weights["lm_head"])
@@ -1348,11 +1674,13 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
       convolution's input and the gate; ``x_proj`` makes ``dt``'s low-rank
       input, ``B`` and ``C`` from the convolved rows; the state decays by the
       channel and the state value;
-    - Mamba-2 (``"kind": "mamba2"``; granite's): ``in_proj`` gives the gate,
-      the convolution's input — x, B and C together, ``W = E + 2 N`` channels
-      — and ``dt`` a head; no ``x_proj``, ``dt_proj`` or inner norms; the
-      state decays by the head; the gate is followed by an RMSNorm over all
-      ``E`` (scope ``ssm/gate_norm``) before ``out_proj``.
+    - Mamba-2 (``"kind": "mamba2"``; granite's, nemotron_h's): ``in_proj``
+      gives the gate, the convolution's input — x, B and C together, ``W = E
+      + 2 G N`` channels, ``G = n_groups`` pairs of B and C, each shared by
+      ``H / G`` heads — and ``dt`` a head; no ``x_proj``, ``dt_proj`` or
+      inner norms; the state decays by the head; the gate is followed by an
+      RMSNorm over each group's ``E / G`` channels (scope ``ssm/gate_norm``)
+      before ``out_proj``.
 
     What they share is the rows' bookkeeping. Prompt rows run the chunked
     scan slot by slot (scope ``ssm/scan``): a chunk slot starts from zero,
@@ -1366,7 +1694,8 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     m, mw = spec.mamba, w["mamba"]
     ssd = m.get("kind") == "mamba2"
     E, N, K = m["d_inner"], m["d_state"], m["d_conv"]
-    W = E + 2 * N if ssd else E          # channels the convolution runs over
+    G = m.get("n_groups", 1) if ssd else 1      # groups of heads sharing B, C
+    W = E + 2 * G * N if ssd else E      # channels the convolution runs over
     dtype = spec.dtype
     ssm, conv = state
     NS1 = ssm.shape[1]
@@ -1438,7 +1767,9 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     if ssd:
         # x, B and C are the convolved rows' three parts; a step size and a
         # decay a head
-        Bm, Cm = c[:, E:E + N].astype(f32), c[:, E + N:].astype(f32)
+        Bm, Cm = c[:, E:E + G * N].astype(f32), c[:, E + G * N:].astype(f32)
+        if G > 1:       # [T, G, N]: head h reads group h // (H / G)
+            Bm, Cm = Bm.reshape(-1, G, N), Cm.reshape(-1, G, N)
         c = c[:, :E]
         dt = jax.nn.softplus(dt_in.astype(f32) + mw["dt_bias"].astype(f32))
         A = -jnp.exp(mw["A_log"].astype(f32))                   # [H]
@@ -1484,9 +1815,13 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
         y = (y + jnp.repeat(mw["D"].astype(f32), E // m["n_heads"]) * cf) \
             * jax.nn.silu(z.astype(f32))
         with jax.named_scope("gate_norm"):
-            # the gate first, then the norm, over all E (one group)
+            # the gate first, then the norm: over all E (one group), or a
+            # group's E / G channels at a time
+            if G > 1:
+                y = y.reshape(-1, G, E // G)
             y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                                  + spec.eps) * mw["norm"].astype(f32)
+                                  + spec.eps)
+            y = y.reshape(-1, E) * mw["norm"].astype(f32)
     else:
         y = (y + mw["D"].astype(f32) * cf) * jax.nn.silu(z.astype(f32))
     with jax.named_scope("out_proj"):
@@ -1549,7 +1884,10 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     """
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
-    if spec.mamba is not None:
+    state = ()
+    if spec.block == "ffn":
+        pass        # the layer is its FFN alone: no mixer, ``attend`` unused
+    elif spec.mamba is not None:
         # a layer whose mixer is no attention: ``attend(normed rows) ->
         # (mixer output [N, hid], *state)`` runs :func:`_mamba_mixer` with
         # the caller's rows and carried state pools
@@ -1619,7 +1957,12 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     join = (lambda x, out: (x.astype(jnp.float32) + spec.residual_scale
                             * out.astype(jnp.float32)).astype(dtype)) \
         if scaled else (lambda x, out: x + out)
-    if spec.parallel_block:
+    if spec.block == "mixer":       # one block a layer: no FFN follows
+        return join(x, attn_out).astype(dtype), tuple(state)
+    if spec.block == "ffn":         # .. or none went before: its one norm
+        mlp_in = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
+                       spec.norm_plus_one)
+    elif spec.parallel_block:
         mlp_in = (_norm(x, w["ln2"], spec.norm, spec.eps, dtype,
                         spec.norm_plus_one)
                   if spec.parallel_dual_norm else h1)

@@ -1,8 +1,9 @@
 """Model zoo: the BASELINE config ladder families (gpt2, llama/mistral, mixtral,
 gpt-neox) plus the inference-container families (opt, falcon, phi, bert) and
 afmoe (Arcee Trinity: layers of several kinds in one model), jamba
-(state-space layers), joyai (JoyAI-LLM-Flash: latent attention) and granite
-(IBM Granite 4.0-H: Mamba-2 layers over routed experts) —
+(state-space layers), joyai (JoyAI-LLM-Flash: latent attention), granite
+(IBM Granite 4.0-H: Mamba-2 layers over routed experts) and nemotron_h
+(Nemotron 3 Nano: one block a layer — Mamba-2, experts or attention) —
 matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
@@ -16,6 +17,8 @@ from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
 from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, init_cache
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
+                                             NemotronHForCausalLM)
 from deepspeed_tpu.models.diffusion import (DiffusionConfig,
                                             DiffusionPipeline,
                                             init_diffusion_inference)

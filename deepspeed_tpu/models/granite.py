@@ -17,13 +17,15 @@ post_attention_layernorm(x)``; ``x += r * (moe(h2) + shared(h2))``; then
   ``q k^T * attention_multiplier`` (1/128 as published, not ``head_dim **
   -0.5``), grouped queries, ``o_proj``;
 - Mamba-2 on ``u``, ``H = mamba_n_heads`` heads of ``P = mamba_d_head``,
-  ``E = H P``, ``N = mamba_d_state``, one group: ``[z | xBC | dt] =
-  in_proj(u)`` (widths ``E``, ``E + 2N``, ``H``); ``xBC = silu(conv1d(xBC) +
+  ``E = H P``, ``N = mamba_d_state``, ``G = mamba_n_groups`` pairs of B and C
+  (published: one): ``[z | xBC | dt] = in_proj(u)`` (widths ``E``, ``E + 2 G
+  N``, ``H``); ``xBC = silu(conv1d(xBC) +
   b)`` depthwise and causal over ``mamba_d_conv`` taps; ``dt = softplus(dt +
   dt_bias)``; ``a = -exp(A_log)`` a head; ``S_t[h] = exp(dt_t[h] a[h])
   S_{t-1}[h] + dt_t[h] x_t[h] (outer) B_t``; ``y_t[h] = S_t[h] C_t + D[h]
   x_t[h]``; ``g = y * silu(z)``; ``n = g * rsqrt(mean(g^2) + eps) * norm``
-  (the gate first, then the norm, over all ``E``); ``out_proj(n)``. The
+  (the gate first, then the norm, over each group's ``E / G`` channels);
+  ``out_proj(n)``. The
   recurrence runs in float32 whatever ``dtype`` is;
 - MoE: router logits in float32, the ``num_experts_per_tok`` largest, softmax
   over those; experts are SwiGLUs of width ``intermediate_size``, stored as
@@ -107,9 +109,9 @@ class GraniteConfig:
                 self.layer_types) - {MAMBA, ATTENTION}:
             raise ValueError("layer_types needs one of 'mamba'/'attention' "
                              "for each of num_hidden_layers")
-        if self.mamba_n_groups != 1:
-            raise ValueError("mamba_n_groups != 1: the kernels take one "
-                             "group (the family publishes 1)")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_heads is not a multiple of "
+                             "mamba_n_groups")
         if self.mamba_n_heads * self.mamba_d_head \
                 != self.mamba_expand * self.hidden_size:
             raise ValueError("mamba_n_heads x mamba_d_head is not "
@@ -168,14 +170,21 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 def ssd_recurrence(dt, x, Bm, Cm, a, D):
     """The recurrence token by token, in float32, from a zero state: ``dt``
-    ``[T, H]``, ``x`` ``[T, H, P]``, ``Bm``, ``Cm`` ``[T, N]``, ``a``, ``D``
+    ``[T, H]``, ``x`` ``[T, H, P]``, ``Bm``, ``Cm`` ``[T, N]`` (one group) or
+    ``[T, G, N]`` (head ``h`` reads group ``h // (H / G)``), ``a``, ``D``
     ``[H]`` -> ``y`` ``[T, H, P]``."""
+    H = x.shape[1]
+    if Bm.ndim == 2:
+        Bm, Cm = Bm[:, None], Cm[:, None]
+    per_head = lambda v: jnp.repeat(v, H // v.shape[0], axis=0)     # [H, N]
+
     def step(S, row):
         dt_t, x_t, b_t, c_t = row
         S = jnp.exp(dt_t * a)[:, None, None] * S \
-            + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
-        return S, S @ c_t + D[:, None] * x_t
-    S0 = jnp.zeros(x.shape[1:] + Bm.shape[1:], jnp.float32)
+            + (dt_t[:, None] * x_t)[:, :, None] * per_head(b_t)[:, None, :]
+        return S, jnp.einsum("hpn,hn->hp", S, per_head(c_t)) \
+            + D[:, None] * x_t
+    S0 = jnp.zeros(x.shape[1:] + Bm.shape[2:], jnp.float32)
     return jax.lax.scan(step, S0, (dt, x, Bm, Cm))[1]
 
 
@@ -186,9 +195,10 @@ class GraniteMamba(nn.Module):
     def __call__(self, u):
         cfg = self.config
         B, T, _ = u.shape
-        H, P, N, K = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
-                      cfg.mamba_d_conv)
-        E, W = H * P, H * P + 2 * N
+        H, P, N, K, G = (cfg.mamba_n_heads, cfg.mamba_d_head,
+                         cfg.mamba_d_state, cfg.mamba_d_conv,
+                         cfg.mamba_n_groups)
+        E, W = H * P, H * P + 2 * G * N
         dense = lambda feats, name: nn.Dense(feats, use_bias=False,
                                              dtype=cfg.dtype, name=name)
         zxd = dense(E + W + H, "in_proj")(u)
@@ -208,12 +218,15 @@ class GraniteMamba(nn.Module):
         a_neg = -jnp.exp(self.param("A_log", _a_log_init, (H,), jnp.float32))
         D = self.param("D", nn.initializers.ones, (H,), jnp.float32)
         y = jax.vmap(ssd_recurrence, in_axes=(0, 0, 0, 0, None, None))(
-            dt, f32(c[..., :E]).reshape(B, T, H, P), f32(c[..., E:E + N]),
-            f32(c[..., E + N:]), a_neg, D).reshape(B, T, E)
-        g = y * nn.silu(f32(z))
+            dt, f32(c[..., :E]).reshape(B, T, H, P),
+            f32(c[..., E:E + G * N]).reshape(B, T, G, N),
+            f32(c[..., E + G * N:]).reshape(B, T, G, N), a_neg,
+            D).reshape(B, T, E)
+        g = (y * nn.silu(f32(z))).reshape(B, T, G, E // G)
         gain = self.param("norm", nn.initializers.ones, (E,), cfg.dtype)
-        n = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                              + cfg.rms_norm_eps) * f32(gain)
+        n = (g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                               + cfg.rms_norm_eps)).reshape(B, T, E) \
+            * f32(gain)
         return dense(cfg.hidden_size, "out_proj")(n.astype(cfg.dtype))
 
 

@@ -17,7 +17,9 @@ repeats whole registers.
 
 **Mamba-2** (``ssd_*``). The state is a matrix per head, ``S[h]`` ``[P, N]``
 (``H`` heads of ``P`` channels, ``E = H * P``), the decay one scalar a head
-and ``B_t``/``C_t`` shared by the heads of a group (one group here)::
+and ``B_t``/``C_t`` shared by the heads of a group (``G`` groups of ``H / G``
+heads: head ``h`` reads ``B_t[h // (H / G)]``; granite has one, nemotron_h
+eight)::
 
     S_t[h] = exp(dt_t[h] * a[h]) * S_{t-1}[h] + dt_t[h] * x_t[h] (outer) B_t
     y_t[h] = S_t[h] C_t
@@ -324,14 +326,43 @@ def _tail_rows(new: jax.Array, tails: jax.Array) -> jax.Array:
 
 
 def _ssd_decode_kernel(l_ref, slot_ref, da_ref, dx_ref, b_ref, c_ref, new_ref,
-                       h_ref, t_ref, y_ref, ho_ref, to_ref):
+                       h_ref, t_ref, y_ref, ho_ref, to_ref, *, groups=None):
     del l_ref, slot_ref               # read by the index maps
-    reps = h_ref.shape[3] // LANES
-    h = (da_ref[0] * h_ref[0, 0]
-         + dx_ref[0] * pltpu.repeat(b_ref[0], reps, axis=1))
-    ho_ref[0, 0] = h.astype(ho_ref.dtype)
-    y_ref[0] = jnp.sum(h * pltpu.repeat(c_ref[0], reps, axis=1), axis=0,
-                       keepdims=True)
+    if groups is None:
+        # one group: B and C arrive spread over 128 lanes, every channel's
+        reps = h_ref.shape[3] // LANES
+        h = (da_ref[0] * h_ref[0, 0]
+             + dx_ref[0] * pltpu.repeat(b_ref[0], reps, axis=1))
+        ho_ref[0, 0] = h.astype(ho_ref.dtype)
+        y_ref[0] = jnp.sum(h * pltpu.repeat(c_ref[0], reps, axis=1), axis=0,
+                           keepdims=True)
+    else:
+        # G groups: B and C arrive as they are, [G, N] with N on the lanes
+        # (spread over 128 lanes in HBM they were 64 MiB each at 128 rows x 8
+        # groups, a quarter of the step's traffic). A group's N values are
+        # turned down the sublanes here — the diagonal of its row repeated N
+        # times, summed along the lanes — and multiply their channels by
+        # broadcast
+        held, span = groups     # groups a channel block holds / blocks a group
+        N = h_ref.shape[2]
+        Eg = h_ref.shape[3] // held
+        first = pl.program_id(1) * held if span == 1 \
+            else pl.program_id(1) // span
+        diag = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+                == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1))
+
+        def column(ref, g):                                   # -> [N, 1]
+            row = ref[0, pl.ds(first + g, 1), :]              # [1, N]
+            return jnp.sum(jnp.where(diag, jnp.broadcast_to(row, (N, N)),
+                                     0.0), axis=1, keepdims=True)
+
+        for g in range(held):
+            lanes = slice(g * Eg, (g + 1) * Eg)
+            h = (da_ref[0, :, lanes] * h_ref[0, 0, :, lanes]
+                 + dx_ref[0, :, lanes] * column(b_ref, g))
+            ho_ref[0, 0, :, lanes] = h.astype(ho_ref.dtype)
+            y_ref[0, :, lanes] = jnp.sum(h * column(c_ref, g), axis=0,
+                                         keepdims=True)
 
     # the tail's block is the row's, whatever the channel block: shifted once
     @pl.when(pl.program_id(1) == 0)
@@ -349,6 +380,18 @@ def _decode_block(E: int) -> int:
     return 0
 
 
+def _group_blocks(Eb: int, Eg: int):
+    """A channel block of ``Eb`` against groups of ``Eg`` channels: ``(groups
+    a block holds, channel blocks a group spans)`` — one of them is 1 — or
+    None where neither divides the other or a group is not whole lane
+    tiles."""
+    if Eg % LANES:
+        return None
+    if Eb % Eg == 0:
+        return Eb // Eg, 1
+    return (1, Eg // Eb) if Eg % Eb == 0 else None
+
+
 def ssd_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
                     dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
                     a: jax.Array, new: jax.Array):
@@ -361,7 +404,12 @@ def ssd_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
            its rows ``8j..8j+7``, convolved channel ``w`` at ``[w // W8,
            w % W8]``
     l, slots: as :func:`ssm_decode_step`
-    dt:    [S, H] float32     x: [S, E]     B, C: [S, N]     a: [H] (negative)
+    dt:    [S, H] float32     x: [S, E]     a: [H] (negative)
+    B, C:  [S, N] (one group: handed to the kernel spread over 128 lanes),
+           or [S, G, N]: head ``h`` reads group ``h // (H / G)``, channels
+           ``g E / G .. (g + 1) E / G - 1`` group g (handed over as they
+           are: the kernel turns a group's N values down the sublanes
+           itself)
     new:   [S, W] the convolution's input at this token (x, B and C's)
 
     Returns ``(y [S, E] float32, pool, tails)``. Per row the kernel reads and
@@ -371,7 +419,9 @@ def ssd_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
     S, H = dt.shape
     TR, W8 = tails.shape[2:]
     Eb = _decode_block(E)
-    if not Eb or W8 % LANES or N % 8:
+    G = B.shape[1] if B.ndim == 3 else 1
+    gb = _group_blocks(Eb, E // G) if Eb and G > 1 else None
+    if not Eb or W8 % LANES or N % 8 or (G > 1 and gb is None):
         return ssd_decode_step_xla(pool, tails, l, slots, dt, x, B, C, a, new)
     P = E // H
     with jax.named_scope("ssd_decode_step"):
@@ -384,14 +434,19 @@ def ssd_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
             (1, 1, N, Eb), lambda i, e, l_ref, s_ref: (l_ref[0], s_ref[i], 0, e))
         tail = pl.BlockSpec(
             (1, 1, TR, W8), lambda i, e, l_ref, s_ref: (l_ref[0], s_ref[i], 0, 0))
+        if G == 1:
+            kernel, spread = _ssd_decode_kernel, lane_spread
+            group = pl.BlockSpec((1, N, LANES), row)
+        else:       # [S, G, N] whole a row: the kernel picks its block's
+            kernel = functools.partial(_ssd_decode_kernel, groups=gb)
+            spread = lambda v: v.astype(jnp.float32)
+            group = pl.BlockSpec((1, G, N), row)
         call = pl.pallas_call(
-            _ssd_decode_kernel,
+            kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(S, E // Eb),
                 in_specs=[pl.BlockSpec((1, 1, Eb), chan),
-                          pl.BlockSpec((1, 1, Eb), chan),
-                          pl.BlockSpec((1, N, LANES), row),
-                          pl.BlockSpec((1, N, LANES), row),
+                          pl.BlockSpec((1, 1, Eb), chan), group, group,
                           pl.BlockSpec((1, TAP_ROWS, W8), row), state, tail],
                 out_specs=[pl.BlockSpec((1, 1, Eb), chan), state, tail]),
             out_shape=[jax.ShapeDtypeStruct((S, 1, E), jnp.float32),
@@ -404,16 +459,40 @@ def ssd_decode_step(pool: jax.Array, tails: jax.Array, l, slots: jax.Array,
         )
         y, pool, tails = call(
             jnp.asarray(l, jnp.int32).reshape(1), slots.astype(jnp.int32),
-            da[:, None], dx[:, None], lane_spread(B), lane_spread(C),
+            da[:, None], dx[:, None], spread(B), spread(C),
             _tail_rows(new, tails), pool, tails)
     return y[:, 0], pool, tails
 
 
+def _per_group_channel(v: jax.Array, E: int) -> jax.Array:
+    """``[.., G, N]`` -> ``[.., N, E]``: a group's values on each of its
+    ``E / G`` channels."""
+    G = v.shape[-2]
+    return jnp.repeat(jnp.swapaxes(v.astype(jnp.float32), -1, -2), E // G,
+                      axis=-1)
+
+
 def ssd_decode_step_xla(pool, tails, l, slots, dt, x, B, C, a, new):
     """:func:`ssd_decode_step` in plain XLA: the Mamba-1 form with ``dt`` and
-    ``a`` repeated over each head's channels."""
-    N, E = pool.shape[2:]
+    ``a`` repeated over each head's channels (and, with more than one group,
+    ``B`` and ``C`` over each group's)."""
+    Lm, NS, N, E = pool.shape
     P = E // dt.shape[1]
+    if B.ndim == 3:
+        TR, W8 = tails.shape[2:]
+        with jax.named_scope("ssd_decode_step_xla"):
+            flat = pool.reshape(Lm * NS, N, E)
+            rows = l * NS + slots
+            dt_c = _per_channel(dt, P)
+            h = (jnp.exp(dt_c * _per_channel(a, P))[:, None, :] * flat[rows]
+                 + (dt_c * x.astype(jnp.float32))[:, None, :]
+                 * _per_group_channel(B, E))
+            y = jnp.sum(h * _per_group_channel(C, E), axis=1)
+            tflat = tails.reshape(Lm * NS, TR, W8)
+            shifted = jnp.concatenate(
+                [tflat[rows][:, TAP_ROWS:], _tail_rows(new, tails)], axis=1)
+            return (y, flat.at[rows].set(h).reshape(pool.shape),
+                    tflat.at[rows].set(shifted).reshape(tails.shape))
     with jax.named_scope("ssd_decode_step_xla"):
         A = jnp.broadcast_to(_per_channel(a, P)[None, :], (N, E))
         return ssm_decode_step_xla(pool, tails, l, slots, _per_channel(dt, P),
@@ -423,7 +502,7 @@ def ssd_decode_step_xla(pool, tails, l, slots, dt, x, B, C, a, new):
 
 def _ssd_scan_kernel(cont_ref, x_ref, bt_ref, c_ref, col_ref, row_ref, h0_ref,
                      y_ref, ht_ref, h_sc, g_sc, *, blocks_per_slot: int,
-                     P: int):
+                     P: int, blocks_per_group: int = 0):
     tb, e = pl.program_id(0), pl.program_id(1)
     g = tb // blocks_per_slot
     f32 = jnp.float32
@@ -435,9 +514,14 @@ def _ssd_scan_kernel(cont_ref, x_ref, bt_ref, c_ref, col_ref, row_ref, h0_ref,
     def _():
         h_sc[e] = h0_ref[0]
 
-    Cm, Bt = c_ref[...], bt_ref[0]                       # [Q, N], [N, Q]
+    if not blocks_per_group:
+        Cm, Bt = c_ref[...], bt_ref[0]                   # [Q, N], [N, Q]
+        first_of_group = e == 0     # C B^T: one group, every head's
+    else:       # the group of channel block e; its blocks follow one another
+        Cm, Bt = c_ref[0], bt_ref[0, 0]
+        first_of_group = e % blocks_per_group == 0
 
-    @pl.when(e == 0)        # C B^T: one group, every head's
+    @pl.when(first_of_group)
     def _():
         g_sc[...] = dot(Cm, Bt)
 
@@ -488,7 +572,9 @@ def ssd_chunk_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
     the largest of :data:`SSD_CHUNKS` under it that divides ``Cs``).
 
     dt:   [G*Cs, H] float32 (zero on rows that hold no token)
-    x:    [G*Cs, E]     B, C: [G*Cs, N]     a: [H] float32 (negative)
+    x:    [G*Cs, E]     a: [H] float32 (negative)
+    B, C: [G*Cs, N] (one group), or [G*Cs, Gr, N]: head ``h`` reads group
+          ``h // (H / Gr)``
     h0:   [G, N, E] float32, the state a slot starts from
     cont: [G] int32, as :func:`ssm_chunk_scan`
 
@@ -498,13 +584,16 @@ def ssd_chunk_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
     head): ``y_t = sum_{s<=t} exp(cum_t - cum_s) (C_t . B_s) dt_s x_s +
     exp(cum_t) C_t S`` and ``S' = exp(cum_Q) S + sum_s exp(cum_Q - cum_s) B_s
     (outer) dt_s x_s``: three products a head pair on the MXU in float32
-    (``C B^T`` once a chunk: one group), the state carried across a slot's
+    (``C B^T`` once a chunk and group), the state carried across a slot's
     chunks in on-chip memory. ``exp`` only ever sees differences ``<= 0``."""
     G, N, E = h0.shape
     T, H = dt.shape
     Cs, P = T // G, E // H
+    Gr = B.shape[1] if B.ndim == 3 else 1
     Q = next((q for q in SSD_CHUNKS if q <= chunk and Cs % q == 0), 0)
-    Eb = next((eb for eb in (512, 256, 128) if E % eb == 0), 0)
+    # a channel block lies inside one group
+    Eb = next((eb for eb in (512, 256, 128)
+               if E % eb == 0 and (E // Gr) % eb == 0), 0)
     if not (Q and Eb) or LANES % P or N % 8:
         return ssd_chunk_scan_xla(dt, x, B, C, a, h0, cont)
     Hb, nE, nC, bps = Eb // P, E // Eb, T // Q, Cs // Q
@@ -515,17 +604,34 @@ def ssd_chunk_scan(dt: jax.Array, x: jax.Array, B: jax.Array, C: jax.Array,
         col = jnp.transpose(cum, (0, 2, 1, 3))                # [nC, nE, Q, Hb]
         row = jnp.transpose(cum, (0, 2, 3, 1))                # [nC, nE, Hb, Q]
         dx = _per_channel(dt, P) * x.astype(f32)
-        Bt = jnp.transpose(B.astype(f32).reshape(nC, Q, N), (0, 2, 1))
+        if Gr == 1:
+            Bt = jnp.transpose(B.astype(f32).reshape(nC, Q, N), (0, 2, 1))
+        else:       # [nC, Gr, N, Q]
+            Bt = jnp.transpose(B.astype(f32).reshape(nC, Q, Gr, N),
+                               (0, 2, 3, 1))
         cont = cont.astype(jnp.int32).at[0].set(0)
         slot = lambda tb, e, c: (tb // bps, 0, e)
         heads = lambda tb, e, c: (tb, e, 0, 0)
+        if Gr == 1:
+            kernel = functools.partial(_ssd_scan_kernel, blocks_per_slot=bps,
+                                       P=P)
+            b_spec = pl.BlockSpec((1, N, Q), lambda tb, e, c: (tb, 0, 0))
+            c_spec = pl.BlockSpec((Q, N), lambda tb, e, c: (tb, 0))
+        else:       # channel block e's group of Bt, and of C as [Gr, T, N]
+            bpg = E // Gr // Eb
+            C = jnp.transpose(C, (1, 0, 2))
+            kernel = functools.partial(_ssd_scan_kernel, blocks_per_slot=bps,
+                                       P=P, blocks_per_group=bpg)
+            b_spec = pl.BlockSpec((1, 1, N, Q),
+                                  lambda tb, e, c: (tb, e // bpg, 0, 0))
+            c_spec = pl.BlockSpec((1, Q, N),
+                                  lambda tb, e, c: (e // bpg, tb, 0))
         call = pl.pallas_call(
-            functools.partial(_ssd_scan_kernel, blocks_per_slot=bps, P=P),
+            kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=(nC, nE),
                 in_specs=[pl.BlockSpec((Q, Eb), lambda tb, e, c: (tb, e)),
-                          pl.BlockSpec((1, N, Q), lambda tb, e, c: (tb, 0, 0)),
-                          pl.BlockSpec((Q, N), lambda tb, e, c: (tb, 0)),
+                          b_spec, c_spec,
                           pl.BlockSpec((1, 1, Q, Hb), heads),
                           pl.BlockSpec((1, 1, Hb, Q), heads),
                           pl.BlockSpec((1, N, Eb), slot)],
@@ -552,4 +658,16 @@ def ssd_chunk_scan_xla(dt, x, B, C, a, h0, cont):
     P = E // dt.shape[1]
     with jax.named_scope("ssd_chunk_scan_xla"):
         A = jnp.broadcast_to(_per_channel(a, P)[None, :], (N, E))
-        return ssm_chunk_scan_xla(_per_channel(dt, P), x, B, C, A, h0, cont)
+        if B.ndim == 2:
+            return ssm_chunk_scan_xla(_per_channel(dt, P), x, B, C, A, h0,
+                                      cont)
+        # a group's channels are a model of their own under its B and C
+        Gr = B.shape[1]
+        chan = lambda v: jnp.moveaxis(
+            v.reshape(v.shape[:-1] + (Gr, E // Gr)), -2, 0)
+        y, hT = jax.vmap(ssm_chunk_scan_xla,
+                         in_axes=(0, 0, 1, 1, 0, 0, None))(
+            chan(_per_channel(dt, P)), chan(x.astype(jnp.float32)), B, C,
+            chan(A), chan(h0), cont)
+        return (jnp.moveaxis(y, 0, -2).reshape(-1, E),
+                jnp.moveaxis(hT, 0, -2).reshape(h0.shape))

@@ -199,8 +199,10 @@ RUNS = {
     "three_kinds": ((LayerKind(8, True, False), LayerKind(8, True, True),
                      LayerKind(8, True, True), LayerKind(None, False, True)),
                     [1, 2, 1]),
+    # kinds that alternate are ONE scan over the repeating pair (a unit of
+    # layer_units), not a scan a layer
     "alternating": ((LayerKind(8, True, True), LayerKind(None, False, True))
-                    * 2, [1, 1, 1, 1]),
+                    * 2, [(2, 2)]),
 }
 
 
@@ -211,11 +213,19 @@ def test_layer_loop_scans_each_run_of_equal_kinds_once(case):
         family="t", num_layers=4, hidden_size=8, num_heads=1, num_kv_heads=1,
         head_dim=8, vocab_size=8, moe={"num_experts": 2, "top_k": 1},
         window=8 if case == "one_kind_windowed" else None, layer_kinds=kinds)
-    stacks = tuple({"a": jnp.ones((n,), jnp.float32)} for n in sizes)
+    stacks = tuple({"a": jnp.ones((n,), jnp.float32)} if isinstance(n, int)
+                   else tuple({"a": jnp.ones((n[1],), jnp.float32)}
+                              for _ in range(n[0])) for n in sizes)
     if kinds is None:
         stacks = stacks[0]
     n, seen, total = _count_scans(spec, stacks)
     assert n == len(sizes)                  # one scan a run, as before for one
+    if case == "alternating":
+        # the pair's body is traced once: each of its two layers under its
+        # own kind, and each at place i of its own stack in repeat i
+        assert [s[:3] for s in seen] == [tuple(k) for k in kinds[:2]]
+        assert total == 2 * (1 + 2)
+        return
     assert [s[3] for s in seen] == list(np.cumsum([0] + sizes[:-1]))
     # each layer of a run is handed its index in the run's own stacks
     assert total == sum(n * (n + 1) / 2 for n in sizes)

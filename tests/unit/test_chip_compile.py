@@ -988,3 +988,99 @@ def test_granite_programs_update_the_state_pools_in_place(program, v5e,
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < limit
+
+
+def _nemotron_stage(arr):
+    """Spec, stacked weight trees (shapes only) and pools of Nemotron 3 Nano
+    30B-A3B as the benchmark's configuration runs it: published layers 0-15
+    (``MEMEM*EMEMEM*EME``) at published widths, 64 of 128 experts held, half
+    the vocabulary (9.84 GiB of weights), 5,256 pages over the two attention
+    layers and the state pool of 144 + 1 slots over the seven Mamba layers
+    (2.05 GiB)."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
+                                                              StatePoolConfig)
+    from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
+                                                 NemotronHForCausalLM)
+    cfg = NemotronHConfig.nemotron_3_nano_30b_a3b(
+        num_hidden_layers=16, hybrid_override_pattern="MEMEM*EMEMEM*EME",
+        experts_held=(0, 64), vocab_size=65536, dtype=BF16)
+    model = NemotronHForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_nemotron_h(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    pool = StatePoolConfig(num_layers=7, num_slots=144, d_inner=4096,
+                           d_state=128, d_conv=4, conv_dim=6144)
+    ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
+    kv = StatefulKV(arr(BF16, 2, 5257, 2, 2, BS, D),
+                    arr(F32, *ssm_shape.shape), arr(F32, *conv_shape.shape))
+    return spec, weights, kv
+
+
+@pytest.mark.parametrize("program", ["serve_decode_step",
+                                     "serve_prefill_packed",
+                                     "serve_paged_pass"])
+def test_nemotron_programs_scan_units_and_update_the_pools_in_place(
+        program, v5e, monkeypatch):
+    """Nemotron 3 Nano's first 16 layers at published widths: the 128-row
+    decode step, the packed prefill pass (4 slots of 256) and the paged pass.
+    The 16 one-block layers are three scans (``M, E, (MEM*EME) x 2``), not
+    sixteen; both SSD kernels take 8 groups of B and C; the experts'
+    products — a width of 14.5 lane tiles, zero-padded to 15 when the weights
+    are adapted — are the Pallas grouped matmul's (XLA's ragged-dot kernel is
+    nowhere) and NO expert stack is copied (unpadded, the chip lays
+    ``[.., 2688, 1856]`` out with 2688 on the lanes and the kernel was handed
+    a transposed copy of every ``w_up`` stack: 4.9 GiB of temporaries, and
+    the step did not fit; compile, PR 42); the pools are the outputs' buffers
+    and the temporaries stay small."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _nemotron_stage(arr)
+    assert [(len(s), n) for s, _, n in rm.layer_units(spec)] == [
+        (1, 1), (1, 1), (7, 2)]
+    assert rm.num_page_layers(spec) == 2 and rm.num_state_layers(spec) == 7
+    rows, pages = 128, 96
+    host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=rows,
+                       max_blocks=pages).device_arrays()
+    if program == "serve_decode_step":
+        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
+                                   arr(I32, rows, pages), arr(I32, rows),
+                                   arr(jnp.uint32, 2), arr(F32),
+                                   arr(I32, rows)).compile()
+        kernels, limit = ("ssd_decode_step",), 512 << 20
+    elif program == "serve_prefill_packed":
+        batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
+                 else arr(I32, *host[k].shape)
+                 for k in rm.PREFILL_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels, limit = ("ssd_chunk_scan",), 512 << 20
+    else:
+        batch = {k: arr(I32, *host[k].shape)
+                 for k in rm.PAGED_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_ragged_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels, limit = ("ssd_chunk_scan", "ssd_decode_step"), 512 << 20
+    text = compiled.as_text()
+    for kernel in kernels + ("moe_grouped_matmul",):
+        assert kernel in text, f"{kernel} is not in the program"
+    assert "ragged-dot" not in text and "mini-gather" not in text
+    assert not re.search(r"bf16\[\d+,64,(2688,1920|1920,2688)\]\S* copy\(",
+                         text), "an expert stack is copied"
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < limit

@@ -541,9 +541,10 @@ def test_page_movers_are_refused(served):
         rm.build_verify_step(eng.spec, 3)
 
 
-def test_more_than_one_group_is_refused():
+def test_groups_that_do_not_divide_the_heads_are_refused():
+    GraniteConfig.tiny(mamba_n_groups=2)        # 4 heads in 2 groups: built
     with pytest.raises(ValueError, match="mamba_n_groups"):
-        GraniteConfig.tiny(mamba_n_groups=2)
+        GraniteConfig.tiny(mamba_n_groups=3)
     with pytest.raises(ValueError, match="experts_held"):
         GraniteConfig.tiny(experts_held=(6, 4))
 

@@ -1,7 +1,7 @@
 """The serving programs of the families the benchmark runs, as lowered text.
 
 Five families at toy widths (dense llama lineage, Mixtral, Trinity's afmoe,
-Jamba, JoyAI) x four programs (decode step, fused multistep, paged pass,
+Jamba, JoyAI; and granite, recorded later: ``LATER``) x four programs (decode step, fused multistep, paged pass,
 packed prefill), lowered for the CPU — where the Pallas kernels lower as
 their interpreted bodies, so the kernels' own text is held too — and hashed.
 ``data/serving_program_text.json`` holds the hashes of the commit before
@@ -14,7 +14,9 @@ file anew and says so::
         tests/unit/test_serving_program_text.py --write
 
 (``--write <file> <commit>`` with ``PYTHONPATH`` at a ``git archive`` of
-another commit records that commit's programs: how the file was made).
+another commit records that commit's programs: how the file was made;
+``--write <file> <commit> granite`` records that family alone from it and
+merges it into the file: how granite's four were, from 83a3dac).
 """
 
 import hashlib
@@ -29,6 +31,10 @@ import pytest
 GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
                       "serving_program_text.json")
 FAMILIES = ("llama", "mixtral", "afmoe", "jamba", "joyai")
+#: granite's four programs (Mamba-2 with ONE group of B and C), recorded from
+#: the commit before PR 42 gave the SSD kernels a group axis (83a3dac, the
+#: file's ``later``): one group lowers to the text it lowered to
+LATER = ("granite",)
 PROGRAMS = ("serve_decode_step", "serve_decode_multistep",
             "serve_paged_pass", "serve_prefill_packed")
 
@@ -61,6 +67,11 @@ def tiny(fam, model=None):
         from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM
         cfg = JoyaiConfig.tiny(dtype=f32)
         model, adapt = JoyaiForCausalLM(cfg), rm.adapt_joyai
+    elif fam == "granite":
+        from deepspeed_tpu.models.granite import (GraniteConfig,
+                                                  GraniteForCausalLM)
+        cfg = GraniteConfig.tiny(dtype=f32)
+        model, adapt = GraniteForCausalLM(cfg), rm.adapt_granite
     else:
         from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         cfg = LlamaConfig.tiny(dtype=f32)
@@ -78,8 +89,8 @@ def tiny(fam, model=None):
     m = spec.mamba
     pool = StatePoolConfig(
         rm.num_state_layers(spec), 4, m["d_inner"], m["d_state"],
-        m["d_conv"], **({"conv_dim": m["d_inner"] + 2 * m["d_state"]}
-                        if m.get("kind") == "mamba2" else {}))
+        m["d_conv"], **({"conv_dim": m["d_inner"] + 2 * m.get("n_groups", 1)
+                         * m["d_state"]} if m.get("kind") == "mamba2" else {}))
     return spec, weights, StatefulKV(pages, *pool.zeros())
 
 
@@ -123,14 +134,15 @@ def golden():
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
-@pytest.mark.parametrize("fam", FAMILIES)
+@pytest.mark.parametrize("fam", FAMILIES + LATER)
 def test_program_lowers_to_the_recorded_text(fam, program, golden):
     assert golden["jax"] == jax.__version__, (
         "another jax lowers to other text: write the file anew on the commit "
         "it records, with this jax")
     text = lowered(*tiny(fam), program)
+    commit = golden["later"][fam] if fam in LATER else golden["commit"]
     assert digest(text) == golden["programs"][f"{fam}.{program}"], (
-        f"{fam}'s {program} is not the text that {golden['commit']} lowers "
+        f"{fam}'s {program} is not the text that {commit} lowers "
         "to: if that is meant, write the file anew (module docstring)")
 
 
@@ -143,12 +155,23 @@ if __name__ == "__main__":
     commit = sys.argv[3] if len(sys.argv) > 3 else subprocess.run(
         ["git", "-C", where, "rev-parse", "--short", "HEAD"],
         capture_output=True, text=True).stdout.strip()
+    # ``--write <file> <commit> <family>..``: those families only, merged
+    # into ``<file>`` under ``later`` (a family recorded from a later commit
+    # than the file's own)
+    only = tuple(sys.argv[4:])
     hashes = {}
-    for fam_ in FAMILIES:
+    for fam_ in only or FAMILIES + LATER:
         model_ = tiny(fam_)
         for program_ in PROGRAMS:
             hashes[f"{fam_}.{program_}"] = digest(lowered(*model_, program_))
+    if only:
+        with open(out) as f:
+            record = json.load(f)
+        record["programs"].update(hashes)
+        record.setdefault("later", {}).update({f: commit for f in only})
+    else:
+        record = {"commit": commit, "jax": jax.__version__,
+                  "later": {f: commit for f in LATER}, "programs": hashes}
     with open(out, "w") as f:
-        json.dump({"commit": commit, "jax": jax.__version__,
-                   "programs": hashes}, f, indent=1)
+        json.dump(record, f, indent=1)
         f.write("\n")

@@ -189,3 +189,122 @@ def test_mamba1_pool_shapes_are_what_they_were():
     ssm_shape, conv_shape = jax.eval_shape(cfg.zeros)
     assert ssm_shape.shape == (26, 161, 16, 5120)
     assert conv_shape.shape == (26, 161, 24, 640)
+
+
+# --------------------------------------------------------------------------- #
+# more than one group of B and C (nemotron_h: 8; head h reads group h // (H/G))
+# --------------------------------------------------------------------------- #
+
+def draw_groups(seed, T, groups, heads=8):
+    """``heads`` heads of 64 in ``groups`` groups: ``B``, ``C`` ``[T, G, N]``
+    (E 512: with 2 groups a channel block of 256 lies in one group, with 8
+    a group is 64 channels and the kernels hand over to their XLA forms)."""
+    rng = np.random.default_rng(seed)
+    dt, x, _, _, a = draw(seed, T, heads=heads)
+    f = lambda: jnp.asarray(rng.standard_normal((T, groups, N)), F32)
+    return dt, x, f(), f(), a
+
+
+def per_token_loop(dt, x, B, C, a, h0=None):
+    """The recurrence written out in numpy, a token and a head at a time:
+    ``(y [T, E], the last state [N, E])``."""
+    dt, x, B, C, a = (np.asarray(v, np.float64) for v in (dt, x, B, C, a))
+    T, heads = dt.shape
+    G = B.shape[1]
+    S = np.zeros((heads, P, N)) if h0 is None else np.asarray(
+        h0, np.float64).T.reshape(heads, P, N)
+    ys = np.zeros((T, heads, P))
+    for t in range(T):
+        for h in range(heads):
+            g = h // (heads // G)
+            S[h] = np.exp(dt[t, h] * a[h]) * S[h] + dt[t, h] * np.outer(
+                x[t, h * P:(h + 1) * P], B[t, g])
+            ys[t, h] = S[h] @ C[t, g]
+    return ys.reshape(T, -1), S.reshape(heads * P, N).T
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+@pytest.mark.parametrize("groups", [2, 8])
+def test_grouped_chunk_scan_is_the_per_token_loop(groups, form):
+    """Two slots of 32, the second continuing the first: the product form
+    with a ``C B^T`` a group (2 groups: the kernel; 8 groups of 64 channels:
+    what it hands to the XLA form) and the XLA form itself against the loop
+    over tokens and heads, outputs and last state; and a head that read
+    another group's B and C would not pass."""
+    scan = ssm.ssd_chunk_scan if form == "kernel" else ssm.ssd_chunk_scan_xla
+    dt, x, B, C, a = draw_groups(11 + groups, 64, groups)
+    width = x.shape[1]
+    h0 = jnp.zeros((2, N, width), F32)
+    y, hT = scan(dt, x, B, C, a, h0, jnp.asarray([0, 1], jnp.int32))
+    want_y, want_h = per_token_loop(dt, x, B, C, a)
+    assert close(y, want_y) and close(hT[1], want_h)
+    wrong_y, _ = per_token_loop(dt, x, B[:, ::-1], C[:, ::-1], a)
+    assert not close(y, wrong_y, 1e-2)
+
+
+def test_grouped_chunk_scan_kernel_is_its_xla_form_from_a_state():
+    """16 heads in 2 groups (E 1,024: two channel blocks of 512, one a
+    group), a slot that starts from its own ``h0``."""
+    dt, x, B, C, a = draw_groups(21, 32, 2, heads=16)
+    h0 = jnp.asarray(np.random.default_rng(22).standard_normal(
+        (1, N, 1024)), F32)
+    cont = jnp.zeros((1,), jnp.int32)
+    got = ssm.ssd_chunk_scan(dt, x, B, C, a, h0, cont)
+    want = ssm.ssd_chunk_scan_xla(dt, x, B, C, a, h0, cont)
+    assert all(close(g, w) for g, w in zip(got, want))
+    loop_y, loop_h = per_token_loop(dt, x, B, C, a, h0[0])
+    assert close(got[0], loop_y) and close(got[1][0], loop_h)
+
+
+@pytest.mark.parametrize("groups, heads", [(2, 8), (8, 16), (2, 64)])
+def test_grouped_decode_step_is_its_xla_form_and_the_loop(groups, heads):
+    """The one-token kernel with the groups of a channel block side by side
+    (8 heads in 2 groups: one block of 512 holds both; 16 heads in 8: groups
+    of 128 channels, eight a block) or a group over several blocks (64 heads
+    in 2 groups: E 4,096 in blocks of 2,048), against its XLA form and one
+    step of the loop from the slot's state."""
+    width = heads * P
+    cfg = StatePoolConfig(num_layers=2, num_slots=3, d_inner=width, d_state=N,
+                          d_conv=4, conv_dim=width + 2 * groups * N)
+    rng = np.random.default_rng(30 + groups)
+    pool, tails = (jnp.asarray(rng.standard_normal(s.shape), F32)
+                   for s in jax.eval_shape(cfg.zeros))
+    rows = [2, 0, 3]
+    dt, x, B, C, a = draw_groups(31, 3, groups, heads=heads)
+    new = jnp.asarray(rng.standard_normal((3, cfg.conv_dim)), F32)
+    slots = jnp.asarray(rows, jnp.int32)
+    got = ssm.ssd_decode_step(pool, tails, 1, slots, dt, x, B, C, a, new)
+    want = ssm.ssd_decode_step_xla(pool, tails, 1, slots, dt, x, B, C, a, new)
+    assert all(close(g, w) for g, w in zip(got, want))
+    y, pool2, tails2 = got
+    for i, s in enumerate(rows):
+        loop_y, loop_h = per_token_loop(dt[i:i + 1], x[i:i + 1], B[i:i + 1],
+                                        C[i:i + 1], a, pool[1, s])
+        assert close(pool2[1, s], loop_h) and close(y[i], loop_y[0])
+        flat = np.asarray(tails2[1, s, 16:]).reshape(-1)
+        assert close(flat[:cfg.conv_dim], new[i])
+    assert close(pool2[0], pool[0], 0) and close(pool2[1, 1], pool[1, 1], 0)
+
+
+def test_one_group_given_with_a_group_axis_is_the_ungrouped_call():
+    """``[T, 1, N]`` and ``[T, N]`` are the same recurrence."""
+    dt, x, B, C, a = draw(40, 32)
+    h0 = jnp.zeros((1, N, E), F32)
+    cont = jnp.zeros((1,), jnp.int32)
+    flat = ssm.ssd_chunk_scan_xla(dt, x, B, C, a, h0, cont)
+    grouped = ssm.ssd_chunk_scan_xla(dt, x, B[:, None], C[:, None], a, h0,
+                                     cont)
+    assert all(close(g, w) for g, w in zip(grouped, flat))
+
+
+def test_nemotron_pool_bytes_a_sequence_a_layer():
+    """Nemotron 3 Nano's widths: 64 x 64 x 128 float32 of state (2 MiB) and
+    three taps over the 6,144 convolved channels (x and 8 groups of B and
+    C), whole tiles as they are."""
+    cfg = StatePoolConfig(num_layers=7, num_slots=144, d_inner=4096,
+                          d_state=128, d_conv=4, conv_dim=4096 + 2 * 8 * 128)
+    assert cfg.conv_width == 6144
+    assert cfg.bytes_per_slot() == 7 * (2 * 2**20 + 72 * 2**10) == 15196160
+    ssm_shape, conv_shape = jax.eval_shape(cfg.zeros)
+    assert ssm_shape.shape == (7, 145, 128, 4096)
+    assert conv_shape.shape == (7, 145, 24, 768)
