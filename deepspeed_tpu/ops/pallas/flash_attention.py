@@ -8,8 +8,8 @@ style): O(T) memory, statistics kept in VMEM scratch across the KV grid dimensio
 
 Supports: causal masking, packed-sequence ``segment_ids``, GQA (kv heads repeated in
 the wrapper), bf16/f32 inputs with f32 accumulation, and a custom VJP whose backward
-recomputes probabilities from the saved logsumexp — no [T, T] materialisation in
-either direction.
+is ONE call: each tile's probabilities are formed once from the saved logsumexp and
+give dq, dk and dv (two calls past ``FUSED_BWD_VMEM_BYTES``) — no [T, T] anywhere.
 
 Layouts: q, k, v are [B, T, H, D] publicly, [B, H, T, D] in-kernel; lse [B, H, T, 1].
 """
@@ -288,10 +288,106 @@ def flash_attention_packed(q: jax.Array, k: jax.Array, v: jax.Array,
 # backward
 # --------------------------------------------------------------------------- #
 
+# One call a layer gives all three gradients (``_bwd_fused_kernel``). It keeps
+# dq's float32 sum for a whole (batch, head) — ``T x D x 4`` bytes — in VMEM
+# beside the tile's temporaries; ``_fused_bwd_vmem_bytes`` counts both from the
+# static shapes, and the call is compiled under that count as its VMEM limit.
+# A call whose count is over this budget takes the two older calls instead
+# (``_bwd_dq_kernel`` and ``_bwd_dkv_kernel``: each forms every tile's scores
+# again, and holds one row block of dq at a time). Three quarters of a v5e's
+# 128 MiB of VMEM: the fused call won at every length that fits it, 65,536
+# rows of 128 among them (``scripts/flash_bwd_table.py``; PERF.md, PR 43).
+FUSED_BWD_VMEM_BYTES = 96 * 2**20
+
+
+def _fused_bwd_vmem_bytes(T, D, bq, bk, itemsize):
+    """An upper bound on the VMEM the fused backward call needs, from its
+    static shapes: dq's accumulator and its output block (double-buffered),
+    the score tile's four float32 temporaries and two casts, and the
+    pipelined row blocks. (The compiler takes the main shapes in half of
+    it: it re-uses the tile's temporaries.)"""
+    D = -(-D // 128) * 128                           # a row is whole lane tiles
+    acc = T * D * (4 + 2 * itemsize)
+    tile = bq * bk * (4 * 4 + 2 * itemsize)
+    blocks = (2 * (2 * bq + 4 * bk) * D * itemsize   # q, do; k, v, dk, dv
+              + 2 * 2 * bq * 128 * 4                 # lse, delta: a lane tile
+              + 2 * bk * D * 4)                      # dk's and dv's sums
+    return acc + tile + blocks
+
+
+def _tile_p_ds(q, k, v, do, lse, delta, iq, ik, *, scale, causal,
+               block_q, block_k):
+    """One tile's probabilities and score gradients, float32 ``[bq, bk]``:
+    ``S = q k^T``, the causal mask, ``P = exp(S - lse)`` (a row whose lse is
+    +inf reads 0), ``dP = do v^T``, ``dS = P (dP - delta)``."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        q_idx = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_idx = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(q_idx >= k_idx, s, NEG_INF)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * scale
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc,
+                      *, scale, causal, block_q, block_k, nq, nk):
+    """Grid ``(B, H, nk, nq)``, the key tile outer: dk and dv sum over the
+    inner axis as in ``_bwd_dkv_kernel``; dq's row block ``iq`` sums over the
+    outer one in ``dq_sc[T, D]``, in the order ``_bwd_dq_kernel`` sums it.
+    ``dq_ref`` is the whole ``[T, D]`` of a (batch, head): it stays in VMEM
+    over both inner axes, each row block is cast into it once, at its last
+    visit (``ik == nk - 1``), and the pipeline writes it out once."""
+    ik, iq = pl.program_id(2), pl.program_id(3)
+    rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+
+    @pl.when(iq == 0)
+    def _():
+        dk_sc[:] = jnp.zeros_like(dk_sc)
+        dv_sc[:] = jnp.zeros_like(dv_sc)
+
+    @pl.when(ik == 0)
+    def _():
+        dq_sc[rows, :] = jnp.zeros((block_q, dq_sc.shape[1]), dq_sc.dtype)
+
+    should_run = True
+    if causal:
+        should_run = ik * block_k <= iq * block_q + block_q - 1
+
+    @pl.when(should_run)
+    def _():
+        q = q_ref[0, 0, :, :]
+        k = k_ref[0, 0, :, :]
+        do = do_ref[0, 0, :, :]
+        p, ds = _tile_p_ds(q, k, v_ref[0, 0, :, :], do, lse_ref[0, 0, :, :],
+                           delta_ref[0, 0, :, :], iq, ik, scale=scale,
+                           causal=causal, block_q=block_q, block_k=block_k)
+        ds = ds.astype(q.dtype)
+        dv_sc[:] += jax.lax.dot_general(p.astype(do.dtype), do,
+                                        (((0,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        dk_sc[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+        dq_sc[rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(iq == nq - 1)
+    def _():
+        dk_ref[0, 0, :, :] = dk_sc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_sc[:].astype(dv_ref.dtype)
+
+    @pl.when(ik == nk - 1)
+    def _():
+        dq_ref[0, 0, rows, :] = dq_sc[rows, :].astype(dq_ref.dtype)
+
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_sc, *, scale, causal, block_q, block_k, nk):
-    h, iq, ik = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    iq, ik = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
     def _():
@@ -303,22 +399,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(should_run)
     def _():
-        q = q_ref[0, 0, :, :]
         k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]                # [bq, 1]
-        delta = delta_ref[0, 0, :, :]            # [bq, 1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_idx = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_idx = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_idx >= k_idx, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
+        _, ds = _tile_p_ds(q_ref[0, 0, :, :], k, v_ref[0, 0, :, :],
+                           do_ref[0, 0, :, :], lse_ref[0, 0, :, :],
+                           delta_ref[0, 0, :, :], iq, ik, scale=scale,
+                           causal=causal, block_q=block_q, block_k=block_k)
         dq_sc[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
                                         (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
@@ -331,7 +416,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_sc, dv_sc,
                     *, scale, causal, block_q, block_k, nq):
-    h, ik, iq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ik, iq = pl.program_id(2), pl.program_id(3)
 
     @pl.when(iq == 0)
     def _():
@@ -346,24 +431,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(should_run)
     def _():
         q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
         do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_idx = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_idx = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_idx >= k_idx, s, NEG_INF)
-        p = jnp.exp(s - lse)                                  # [bq, bk]
+        p, ds = _tile_p_ds(q, k_ref[0, 0, :, :], v_ref[0, 0, :, :], do,
+                           lse_ref[0, 0, :, :], delta_ref[0, 0, :, :], iq, ik,
+                           scale=scale, causal=causal, block_q=block_q,
+                           block_k=block_k)
         dv_sc[:] += jax.lax.dot_general(p.astype(do.dtype), do,
                                         (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale                         # [bq, bk]
         dk_sc[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
                                         (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
@@ -375,6 +450,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd(scale, causal, block_q, block_k, residuals, g):
+    # imported here: a line added above the forward kernels would re-key their
+    # Mosaic programs (a kernel's source locations are part of its key)
+    from deepspeed_tpu.monitor.trace import tracer as _tracer
     q, k, v, o, lse = residuals
     do = g
     B, H, T, D = q.shape
@@ -387,9 +465,64 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
     delta = jnp.einsum("bhtd,bhtd->bht", do.astype(jnp.float32),
                        o.astype(jnp.float32))[..., None]
 
+    params = dict(scale=scale, causal=causal, block_q=bq, block_k=bk)
+    semantics = ("parallel", "parallel", "parallel", "arbitrary")
+
+    # grid (B, H, nk, nq), the key tile outer: the fused call's and dkv's
+    def of_q(b, h, ik, iq):
+        if causal:
+            # a tile the mask skips asks for the rows of the first tile that
+            # runs, so nothing is copied in for it (its copies had no
+            # products to hide behind: 0.36 ms of the call's 2.88 at seq4k)
+            iq = jnp.maximum(iq, jnp.minimum(ik * bk // bq, nq - 1))
+        return (b, h, iq, 0)
+
+    def of_k(b, h, ik, iq):
+        return (b, h, ik, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, 1, bq, D), of_q),
+        pl.BlockSpec((1, 1, bk, D), of_k),
+        pl.BlockSpec((1, 1, bk, D), of_k),
+        pl.BlockSpec((1, 1, bq, D), of_q),
+        pl.BlockSpec((1, 1, bq, 1), of_q),
+        pl.BlockSpec((1, 1, bq, 1), of_q),
+    ]
+    dkv_specs = [pl.BlockSpec((1, 1, bk, D), of_k),
+                 pl.BlockSpec((1, 1, bk, D), of_k)]
+    dkv_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    dkv_sums = [pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32)]
+
+    # counted where the rule is traced: the engine's log line says which
+    vmem = _fused_bwd_vmem_bytes(T, D, bq, bk, q.dtype.itemsize)
+    if vmem <= FUSED_BWD_VMEM_BYTES:
+        _tracer.bump("train/flash/bwd_fused")
+        fused_call = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, nq=nq, nk=nk, **params),
+            grid=(B, H, nk, nq),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, 1, T, D),
+                                    lambda b, h, ik, iq: (b, h, 0, 0))]
+            + dkv_specs,
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((T, D), jnp.float32)] + dkv_sums,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary"),
+                vmem_limit_bytes=vmem),
+            interpret=_backend.interpret(),
+        )
+        # under the dkv call's name: the benchmark's readers know the
+        # backward by ``flash_bwd_dq|flash_bwd_dkv`` (PERF.md section 7)
+        with jax.named_scope("flash_bwd_dkv"):
+            dq, dk, dv = fused_call(q, k, v, do, lse, delta)
+        return dq, dk, dv
+
+    _tracer.bump("train/flash/bwd_split")
     dq_call = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, nk=nk),
+        functools.partial(_bwd_dq_kernel, nk=nk, **params),
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
@@ -402,39 +535,20 @@ def _bwd(scale, causal, block_q, block_k, residuals, g):
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=_backend.interpret(),
     )
     with jax.named_scope("flash_bwd_dq"):
         dq = dq_call(q, k, v, do, lse, delta)
 
     dkv_call = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, nq=nq),
+        functools.partial(_bwd_dkv_kernel, nq=nq, **params),
         grid=(B, H, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, iq: (b, h, ik, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        in_specs=in_specs,
+        out_specs=dkv_specs,
+        out_shape=dkv_shape,
+        scratch_shapes=dkv_sums,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=_backend.interpret(),
     )
     with jax.named_scope("flash_bwd_dkv"):

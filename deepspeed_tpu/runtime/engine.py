@@ -1131,8 +1131,12 @@ class DeepSpeedTPUEngine:
             plan = self.remat_plan
             last = plan.rung == len(ac.LADDER) - 1
             # models/llama.py notes its rows when THIS step's gradient is
-            # traced; a step with another loss leaves the 0
+            # traced; a step with another loss leaves the 0. The flash
+            # kernel counts its backward calls the same way, by the path
+            # each took (ops/pallas/flash_attention.py::_bwd)
             _tracer.note("train/loss_head/fused", 0)
+            _tracer.note("train/flash/bwd_fused", 0)
+            _tracer.note("train/flash/bwd_split", 0)
             try:
                 with _tracer.stage(f"rung{plan.rung}"):
                     compiled = self._jit_fused_step().lower(
@@ -1148,12 +1152,19 @@ class DeepSpeedTPUEngine:
                         + mem.generated_code_size_in_bytes)
                 if last or need <= plan.limit_bytes:
                     fused = _tracer.totals["train/loss_head/fused"]
+                    one = _tracer.totals["train/flash/bwd_fused"]
+                    two = _tracer.totals["train/flash/bwd_split"]
                     log_dist(f"activation checkpointing: {plan.describe()}; "
                              f"the compiled step needs {need / 2**30:.2f} GiB"
                              + ("" if not fused else
                                 f"; the loss head forms its gradient with its "
                                 f"value over {fused:.0f} rows a micro-batch "
-                                f"(train/loss_head/fused)"), ranks=[0])
+                                f"(train/loss_head/fused)")
+                             + ("" if not one + two else
+                                f"; the flash kernel's backward is one call "
+                                f"in {one:.0f} of the {one + two:.0f} traced "
+                                f"(train/flash/bwd_fused, the rest "
+                                f"train/flash/bwd_split)"), ranks=[0])
                     for name, value in (
                             ("rung", plan.rung),
                             ("kept_bytes", plan.kept_bytes),

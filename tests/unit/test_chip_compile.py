@@ -127,6 +127,17 @@ CASES = {
     "flash_fwd": (lambda q, k, v: flash_attention(q, k, v, causal=True),
                   [_TRAIN] * 3),
     "flash_bwd": (jax.grad(_flash_sq, argnums=(0, 1, 2)), [_TRAIN] * 3),
+    # the one fused backward call is compiled under its own count of VMEM
+    # (``_fused_bwd_vmem_bytes``): head size 64 (half a lane tile a row),
+    # float32 operands, and cross attention with no mask
+    "flash_bwd_d64": (jax.grad(_flash_sq, argnums=(0, 1, 2)),
+                      [((1, 4096, H, 64), BF16)] * 3),
+    "flash_bwd_f32": (jax.grad(_flash_sq, argnums=(0, 1, 2)),
+                      [((1, 2048, 4, D), F32)] * 3),
+    "flash_bwd_cross": (
+        jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v).astype(F32) ** 2), argnums=(0, 1, 2)),
+        [((1, 2048, 8, D), BF16)] + [((1, 4096, 8, D), BF16)] * 2),
     "packed_prefill": (
         lambda q, k, v, seg: flash_attention_packed(q, k, v, seg,
                                                     window=WINDOW),
@@ -201,6 +212,21 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{case}: no Mosaic kernel in the compiled program"
+
+
+@pytest.mark.parametrize("seq,calls", [(65536, 2), (131072, 3)])
+def test_flash_backward_is_one_call_up_to_its_vmem_budget(seq, calls, v5e,
+                                                          monkeypatch):
+    """dq's float32 sum of a whole (batch, head) lives in VMEM: at 65,536
+    rows of 128 the count is 90 MiB, inside ``FUSED_BWD_VMEM_BYTES``, and the
+    gradient is the forward call and ONE backward call; twice that is over
+    the budget and takes the two older calls."""
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((1, seq, 1, D), BF16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    text = jax.jit(jax.grad(_flash_sq, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == calls
 
 
 def test_flash_dispatch_compiles_over_a_four_chip_mesh(v5e, monkeypatch):
@@ -854,9 +880,22 @@ def _all_gathers(text) -> int:
 def test_train_step_keeps_the_flash_residuals_and_fits(v5e, monkeypatch):
     """Cell ``mistral7b-train.seq4k`` at its real shapes: with room for
     every product and the flash forward's output and log-sum-exp, a layer's
-    backward runs the forward kernel no second time — 3 Mosaic calls a
-    layer (forward, dq, dkv), where full recompute makes 4."""
+    backward runs the forward kernel no second time — 2 Mosaic calls a
+    layer (forward, and the one backward call that gives dq, dk and dv),
+    where full recompute makes 3. Every backward call the step traces took
+    the fused kernel, and the step needs no more of the chip than with the
+    two calls it replaced (dq's sum lives in VMEM, not in a new buffer)."""
+    from deepspeed_tpu.monitor.trace import tracer
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    with monkeypatch.context() as m:
+        m.setattr(fa, "FUSED_BWD_VMEM_BYTES", 0)
+        _, two_calls = _train_step(v5e, m, layers=2)
+    assert two_calls.as_text().count("tpu_custom_call") == 6
+    assert tracer.totals["train/flash/bwd_fused"] == 0
     engine, compiled = _train_step(v5e, monkeypatch, layers=2)
+    assert tracer.totals["train/flash/bwd_fused"] >= 1
+    assert tracer.totals["train/flash/bwd_split"] == 0
+    assert _device_bytes(compiled) <= _device_bytes(two_calls)
     plan = engine.remat_plan
     assert plan.rung == 0 and plan.limit_bytes == V5E_HBM_LIMIT
     # a layer: its input, q, k, v, o_proj's output, gate and up, the
@@ -864,7 +903,7 @@ def test_train_step_keeps_the_flash_residuals_and_fits(v5e, monkeypatch):
     # and the log-sum-exp
     assert plan.kept_per_layer[0] == 2 * 4096 * (
         4 * 4096 + 2 * 1024 + 2 * 14336 + 4096) + 4 * 32 * 4096
-    assert compiled.as_text().count("tpu_custom_call") == 6
+    assert compiled.as_text().count("tpu_custom_call") == 4
     assert _device_bytes(compiled) <= V5E_HBM_LIMIT
 
 
@@ -881,7 +920,7 @@ def test_train_step_over_four_chips_gathers_no_weights_for_a_second_forward(
     assert named.remat_plan is None
     kept, full = kept.as_text(), full.as_text()
     assert (kept.count("tpu_custom_call"),
-            full.count("tpu_custom_call")) == (6, 8)
+            full.count("tpu_custom_call")) == (4, 6)
     assert _all_gathers(kept) < _all_gathers(full)
 
 
@@ -898,7 +937,7 @@ def test_train_step_without_room_for_every_product_keeps_fewer(
         monkeypatch.setattr(ac, "choose_rung", lambda *a, **kw: 0)
     engine, compiled = _train_step(v5e, monkeypatch, layers=2, rows=6)
     assert 0 < engine.remat_plan.rung < len(ac.LADDER) - 1
-    assert compiled.as_text().count("tpu_custom_call") == 6
+    assert compiled.as_text().count("tpu_custom_call") == 4
     assert _device_bytes(compiled) <= V5E_HBM_LIMIT
 
 

@@ -272,8 +272,7 @@ CASES = [
     ("paged_pass", "scope", "attn"),
     ("train_step", "program", "jit_step_fn"),
     ("train_step", "scope", "flash_fwd"),
-    ("train_step", "scope", "flash_bwd_dq"),
-    ("train_step", "scope", "flash_bwd_dkv"),
+    ("train_step", "scope", "flash_bwd_dkv"),   # the one fused backward call
     ("train_step", "scope", "optimizer"),
     ("eval", "program", "jit_train_eval_loss"),
     ("eval", "scope", "flash_fwd"),
