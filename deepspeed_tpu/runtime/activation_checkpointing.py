@@ -150,6 +150,7 @@ class _CheckpointingState:
         self.policy: Optional[Callable] = None
         # set around one trace by an engine (keeping / probing below)
         self.kept: Optional[Callable] = None
+        self.grads_reduced = False
         self.probe: Optional["LayerProbe"] = None
 
 
@@ -301,8 +302,21 @@ def kept_bytes(layer: Callable, policy: Optional[Callable], *args) -> int:
     fn = jax.checkpoint(layer, policy=policy)
     jaxpr = jax.make_jaxpr(lambda *a: jax.linearize(fn, *a)[1])(*args).jaxpr
     given = set(jaxpr.invars) | set(jaxpr.constvars)
-    return tree_size_bytes([v.aval for v in jaxpr.outvars
-                            if not isinstance(v, _Literal) and v not in given])
+    # a jit the backward re-runs (``nn.silu`` inside a custom_vjp's forward
+    # rule) hands its argument back as a result: the same value under a
+    # second name, which the compiled step holds once
+    same = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "jit":
+            continue
+        inner = eqn.params["jaxpr"].jaxpr
+        for out, res in zip(eqn.outvars, inner.outvars):
+            if res in inner.invars:
+                arg = eqn.invars[inner.invars.index(res)]
+                if not isinstance(arg, _Literal):
+                    same[out] = same.get(arg, arg)
+    kept = {same.get(v, v) for v in jaxpr.outvars if not isinstance(v, _Literal)}
+    return tree_size_bytes([v.aval for v in kept - given])
 
 
 class LayerProbe:
@@ -352,15 +366,25 @@ def probing():
 
 
 @contextlib.contextmanager
-def keeping(rung: Optional[int]):
+def keeping(rung: Optional[int], grads_reduced: bool = False):
     """Around one trace of a step: walks that name no policy keep what
-    ``LADDER[rung]`` keeps (None: nothing changes)."""
-    prior = _STATE.kept
+    ``LADDER[rung]`` keeps (None: nothing changes), and a differentiation
+    rule that asks :func:`gradients_are_reduced` is told ``grads_reduced``:
+    whether the step sums its weight gradients across devices before the
+    update (so that no optimizer update rides in a layer's products)."""
+    prior = (_STATE.kept, _STATE.grads_reduced)
     _STATE.kept = None if rung is None else LADDER[rung][1]()
+    _STATE.grads_reduced = grads_reduced
     try:
         yield
     finally:
-        _STATE.kept = prior
+        _STATE.kept, _STATE.grads_reduced = prior
+
+
+def gradients_are_reduced() -> bool:
+    """What the engine said of the step it is tracing (:func:`keeping`);
+    False outside one."""
+    return _STATE.grads_reduced
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1054,7 +1054,8 @@ class DeepSpeedTPUEngine:
             # read when traced: the rung the engine holds now
             plan = self.remat_plan
             with activation_checkpointing.keeping(
-                    None if plan is None else plan.rung):
+                    None if plan is None else plan.rung,
+                    grads_reduced=self.topology.dp_world_size > 1):
                 grads, losses = self._accumulate_grads(params, scale, batch)
             new_state, metrics = self._apply_grads(state, grads)
             metrics["loss"] = jnp.mean(losses)
@@ -1713,6 +1714,7 @@ class DeepSpeedTPUEngine:
         return buf
 
     def _build_micro_steps(self):
+        from deepspeed_tpu.runtime import activation_checkpointing
         fp16 = self.config.fp16
         accum_dtype = self.config.grad_accum_dtype
         gas = self.gas_
@@ -1720,7 +1722,10 @@ class DeepSpeedTPUEngine:
         def micro(state, buf, mb):
             params = self._current_params(state)
             scale = state["scaler"]["scale"] if fp16.enabled else jnp.float32(1.0)
-            loss, grads = self._grad_fn(params, mb, scale)
+            # no rung is chosen for this path; a rule is told of the mesh
+            with activation_checkpointing.keeping(
+                    None, grads_reduced=self.topology.dp_world_size > 1):
+                loss, grads = self._grad_fn(params, mb, scale)
             grads = tree_cast(grads, accum_dtype)
             grads = self._constrain_grads(grads)
             buf = jax.tree_util.tree_map(jnp.add, buf, grads)

@@ -254,9 +254,10 @@ class AbstractJit:
 
 
 def compile_train_step(devices, layers: int, fsdp: int, prefetch_depth,
-                       label: str, global_batch: int = 0) -> None:
+                       label: str, global_batch: int = 0):
     """The engine's full fused train step — built by a real
-    ``DeepSpeedTPUEngine`` over a mesh of described devices."""
+    ``DeepSpeedTPUEngine`` over a mesh of described devices. Returns the
+    compiled step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     import deepspeed_tpu
     from deepspeed_tpu.comm.mesh import BATCH_AXES, build_topology
@@ -303,6 +304,7 @@ def compile_train_step(devices, layers: int, fsdp: int, prefetch_depth,
     if engine.remat_plan is not None:
         log(f"{label}: {engine.remat_plan.describe()}")
     report(label, compiled, t0)
+    return compiled
 
 
 def rehearse_compile() -> None:

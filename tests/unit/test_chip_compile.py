@@ -907,6 +907,41 @@ def test_train_step_keeps_the_flash_residuals_and_fits(v5e, monkeypatch):
     assert _device_bytes(compiled) <= V5E_HBM_LIMIT
 
 
+@pytest.mark.parametrize("fsdp", [1, 4])
+def test_train_step_feeds_no_product_through_an_exponential(fsdp, v5e,
+                                                            monkeypatch):
+    """Both training cells' steps, 2 layers deep. On one chip no
+    ``convolution`` takes as an operand a producer fusion that holds an
+    ``exponential``: with the MLP's gate as the plain ``act(gate) * up`` six
+    products a layer did (``silu(gate)`` re-formed tile by tile inside the
+    forward ``down_proj``, ``dx`` through ``gate_proj`` and ``up_proj`` and
+    the three ``dW`` with their AdamW updates: 12 at PR 43, 1.4-2.3 times
+    the MXU's time each by the compiler's own estimate);
+    ``models/llama.py::gated_activation`` hands ``act(gate) * up`` and
+    ``(dgate, dup)`` on through an ``optimization_barrier`` each, and the
+    compiler forms them as epilogues of the products that make their
+    inputs. A compiler that stops honouring the barriers, or a new producer
+    of the kind, trips this. Over four chips the engine tells the rule that
+    the gradients are reduced across devices and it stands down: the
+    producers are there as they were (which shows that the reader finds
+    them), and no product that carries an all-gather's pieces gains the
+    exponential as an epilogue — two a layer would, at 0.6 ms each on the
+    chip (PR 44)."""
+    from deepspeed_tpu.profiling.compiled_products import (fed_through,
+                                                           product_fusions)
+    _, compiled = _train_step(v5e, monkeypatch, layers=2, fsdp=fsdp)
+    fusions = product_fusions(compiled.as_text())
+    # a layer's 7 projections forward, 14 backward, and the head's 3
+    assert len(fusions) >= 2 * 21 + 3
+    fed = [f.name for f in fed_through(fusions, "exponential")]
+    if fsdp == 1:
+        assert fed == []
+    else:
+        assert len(fed) == 12
+        assert [f.name for f in fusions if "all-gather" in f.epilogue
+                and "exponential" in f.epilogue] == []
+
+
 def test_train_step_over_four_chips_gathers_no_weights_for_a_second_forward(
         v5e, monkeypatch):
     """The same walk under ``mesh: {fsdp: 4}`` (cell ``mistral7b-zero3x4
