@@ -2,7 +2,7 @@
 
 ``AttentionKernelSpec`` is the single dispatch surface every v2 device
 program routes its attention through — the ragged paged pass, the packed
-prefill fast path, the fused decode-step/multistep programs, and the
+prefill fast path, the fused decode step, and the
 speculative verify step (``ragged_model.py`` builders). Before it existed,
 each builder picked kernels per call site (window/alibi partials, TP
 shard_map wrapping, int8-scale keyword plumbing) and the engine carried one
@@ -312,9 +312,9 @@ class AttentionKernelSpec:
 
     def sidebuf(self, q, kv_l, block_tables, prefix_lens, side_k, side_v, j,
                 layer_idx, kv_scales: Optional[Any] = None):
-        """Frozen-prefix + side-slab decode attention (the scatter-free
-        multistep schedule). Only reachable at tp == 1 (the multistep
-        builder's side-buffer gate), so no TP wrap. For int8 pools the
+        """Frozen-prefix + side-slab decode attention (the decode step's
+        side-buffer form). Only reachable at tp == 1
+        (``ragged_model.side_buffer_fits``), so no TP wrap. For int8 pools the
         slab must hold ``kv_write_dequant``'d rows (module docstring)."""
         assert self.tp == 1, "side-buffer schedule is tp == 1 only"
         kw = {} if kv_scales is None else dict(kv_scales=kv_scales)

@@ -139,8 +139,7 @@ class CompileConfig:
 
     ``warmup``: pre-compile the serving program set at engine construction —
     the ragged paged pass, the prefill fast path, and the fused decode-step
-    program for every bucket in ``warmup_buckets`` (plus fused multistep
-    programs for each burst length in ``warmup_decode_steps``). Warmup runs
+    program for every bucket in ``warmup_buckets``. Warmup runs
     each program once over the engine's scratch KV page, so with a persistent
     cache a *second* engine start skips compilation entirely.
 
@@ -152,7 +151,6 @@ class CompileConfig:
     min_compile_time_secs: Optional[float] = None
     warmup: bool = False
     warmup_buckets: Optional[Any] = None     # list of ints
-    warmup_decode_steps: Any = ()            # list of fused-burst lengths
 
     def __post_init__(self):
         if self.warmup_buckets is not None:
@@ -166,10 +164,6 @@ class CompileConfig:
             from deepspeed_tpu.utils.caching import next_pow2
             self.warmup_buckets = sorted({next_pow2(b)
                                           for b in self.warmup_buckets})
-        if any(not isinstance(n, int) or n < 1
-               for n in self.warmup_decode_steps):
-            raise ValueError("compile.warmup_decode_steps must be ints >= 1, "
-                             f"got {self.warmup_decode_steps!r}")
 
 
 @dataclass
@@ -547,7 +541,7 @@ class AttentionConfig:
     ``decode_splits``: top rung of the pow2 split ladder. 1 (default) keeps
     the chunk-serial kernels exactly — split-K never dispatches. S > 1 makes
     every paged attention caller (ragged decode pass, fused decode
-    step/multistep, sidebuf, spec verify) route through the split-K
+    step, sidebuf, spec verify) route through the split-K
     dispatchers (``ops/pallas/paged_splitk.py``): each sequence's page range
     is cut into up to S grid-parallel splits emitting ``(acc, lse)``
     partials, merged by one logsumexp-weighted pass. The engine warms ONE

@@ -14,11 +14,11 @@ TPU-native structure per pass (one jitted call, static shapes):
     host: sample / collect last-token logits, advance descriptors
 
 The steady-state decode hot path does NOT run that per-pass loop: it runs
-bucketed fused decode programs (sampling on device, one int32 token row per
-step crossing to host) driven either as ``decode_steps`` bursts or through
-the async double-buffered ``DecodePipeline`` (``pipeline.py``); see
-docs/SERVING.md for the full picture (bucketing grids, the one-step-late
-drain, AOT warmup).
+the bucketed fused decode step (one program a token: sampling on device, one
+int32 token row per step crossing to host) chained by the async
+double-buffered ``DecodePipeline`` (``pipeline.py``); see docs/SERVING.md
+for the full picture (bucketing grids, the one-step-late drain, AOT
+warmup).
 
 KV pages are donated through the pass (XLA aliases them in HBM — the functional
 analog of the reference writing its blocked KV cache in place).
@@ -339,7 +339,7 @@ class InferenceEngineV2:
         # "Attention kernels"): one ragged-pass program per pow2 rung.
         # Rung 1 IS self._pass — the byte-identical chunk-serial program;
         # higher rungs rebuild the pass with split-K attention bound
-        # (ops/pallas/paged_splitk.py). The fused decode/multistep/verify
+        # (ops/pallas/paged_splitk.py). The fused decode-step and verify
         # grids grow the same rung axis through their cache keys, and
         # warmup() pre-builds every (grid point x rung) so the
         # admission-driven rung choice (_attn_rung) never compiles on the
@@ -361,15 +361,11 @@ class InferenceEngineV2:
         # Materialised to numpy lazily (put()) or sampled on device without
         # ever shipping the [S, V] tensor to host (sample_next()).
         self._last_ref: Dict[int, Tuple[Any, int]] = {}
-        # LRU-bounded compiled multistep programs: keyed by (n_steps, BUCKET,
-        # do_sample, top_k) where BUCKET = next_pow2(live rows) — serving with
-        # many batch sizes reuses ~log2 executables, and the LRU bound keeps a
-        # long-lived process from accumulating programs for retired burst
-        # lengths. Callers hold the returned program through the call, so
-        # eviction can never free an executable mid-flight (Python refs).
-        self._multistep: LRUCache = LRUCache(maxsize=8)
-        # compiled single-step fused decode programs (DecodePipeline), keyed
-        # by (bucket, do_sample, top_k); one per grid point
+        # compiled fused decode-step programs (DecodePipeline), LRU-bounded
+        # and keyed by (BUCKET, do_sample, top_k, rank bucket, split rung)
+        # where BUCKET = next_pow2(live rows): serving with many batch sizes
+        # reuses ~log2 executables. Callers hold the returned program through
+        # the call, so eviction can never free an executable mid-flight.
         self._step_progs: LRUCache = LRUCache(maxsize=16)
         # compiled verify-step programs (spec/pipeline.py), keyed by
         # (bucket, k) — the speculation grid warmup() pre-compiles
@@ -464,8 +460,7 @@ class InferenceEngineV2:
                     and len(self.attn_split_ladder) > 1 else ""),
                  ranks=[0])
         if cfg.compile.warmup:
-            self.warmup(buckets=cfg.compile.warmup_buckets,
-                        burst_steps=cfg.compile.warmup_decode_steps)
+            self.warmup(buckets=cfg.compile.warmup_buckets)
 
     # ------------------------------------------------------------------ #
 
@@ -494,7 +489,7 @@ class InferenceEngineV2:
         """Rebind ``self.weights`` to a new device tree in place — the
         train->serve sync point of the colocated rollout loop.
 
-        Every device program this engine builds (the pass/decode/multistep/
+        Every device program this engine builds (the pass, decode-step and
         verify grids, warmup() included) takes the weight tree as a RUNTIME
         operand (``prog(self.weights, self.kv.kv, ...)``), so a swap whose
         tree matches the old one leaf-for-leaf in structure, shape, dtype
@@ -697,64 +692,6 @@ class InferenceEngineV2:
         committed)."""
         return jax.device_put(np.zeros((bucket,), np.int32),
                               self.topology.replicated())
-
-    def decode_steps(self, uids: Sequence[int], n_steps: int,
-                     do_sample: bool = False, temperature: float = 1.0,
-                     top_k: int = 0, fetch: bool = True
-                     ) -> "np.ndarray | jax.Array":
-        """Generate ``n_steps`` tokens for every uid with ONE device program
-        (fused sample->forward->sample loop; see build_multistep_decode).
-        All uids must be in steady decode state (no pending tokens).  Returns
-        the generated ids [len(uids), n_steps]; the engine's last-logits refs
-        advance so normal put()/sample_next() calls can continue after.
-
-        ``fetch=False`` returns the DEVICE array, already shaped [S,
-        n_steps] like the fetched result (the transpose is a free layout op
-        on device — ADVICE r4: the old [n_steps, S] return was a silent-
-        corruption footgun when S == n_steps): the call then costs only a
-        dispatch, so back-to-back bursts chain on device instead of each
-        waiting for the previous burst's ids to reach the host.
-
-        The device program runs at ``next_pow2(len(uids))`` rows (pad rows
-        decode into the engine's scratch page): programs are keyed by the
-        bucket, so the live count drifting with admissions/retirements reuses
-        cached executables, and ``warmup()`` can pre-compile the whole grid.
-        Row-independent decode keeps real rows byte-identical under padding
-        (greedy); batch-sampled rows draw from a [bucket, V] noise block, so
-        SAMPLED streams depend on the bucket (not on which other rows are
-        pads) — a documented trade, not a bug."""
-        uids = [int(u) for u in uids]
-        S = len(uids)
-        assert not self.scheduler.has_pending(), \
-            "decode_steps requires a drained scheduler"
-        # bucketed descriptors: the program below is keyed by the BUCKET, so a
-        # serving loop admitting/retiring sequences reuses ~log2 executables
-        db = self.scheduler.decode_batch(uids, n_steps + 1, self.scratch_block)
-        sp = self._attn_rung()
-        fn = self._multistep.get_or_create(
-            (n_steps, db.bucket, bool(do_sample), int(top_k), sp),
-            lambda: self._build_multistep(n_steps, do_sample, top_k, sp))
-        # already bucket-padded: pad entries re-sample a real row's logits but
-        # run against the scratch page, so they cannot touch live KV
-        ids0, _ = self._sample_device_padded(uids, do_sample, temperature,
-                                             top_k)
-        assert ids0.shape[0] == db.bucket
-        self._rng_key, sub = jax.random.split(self._rng_key)
-        out_ids, final_logits, new_kv = fn(
-            self.weights, self.kv.kv, ids0, db.positions, db.block_tables,
-            db.ctx_lens, sub, jnp.float32(temperature),
-            *self._state_operands(db))
-        self.kv.update(new_kv)
-        for i, u in enumerate(uids):
-            self.scheduler.advance(u, n_steps)
-            self._last_ref[u] = (final_logits, i)
-            self._last_logits.pop(u, None)
-        if not fetch:
-            ids_t = out_ids.T           # device [bucket, n_steps]
-            # the pad-row slice compiles one tiny gather per (bucket, S) —
-            # only paid when the bucket is not exactly full
-            return ids_t if db.bucket == S else ids_t[:S]
-        return fetch_to_host(out_ids).T[:S]    # [S, n_steps]
 
     def _decode_step_prog(self, bucket: int, do_sample: bool, top_k: int,
                           rb: int = 0, sp: Optional[int] = None):
@@ -974,7 +911,6 @@ class InferenceEngineV2:
         return [1 << i for i in range(top.bit_length())]
 
     def warmup(self, buckets: Optional[Sequence[int]] = None,
-               burst_steps: Sequence[int] = (),
                spec_ks: Optional[Sequence[int]] = None) -> int:
         """Pre-compile the serving program set so in-grid traffic never
         observes an XLA compile (and, with a persistent compile cache
@@ -982,8 +918,7 @@ class InferenceEngineV2:
 
         Covers: the ragged paged pass, the prefill fast path, the fused
         decode-step program for every bucket (greedy — the serving default;
-        sampled variants compile on first use), fused multistep programs for
-        each ``burst_steps`` length across the grid, and the module-level
+        sampled variants compile on first use), and the module-level
         bootstrap sampler ``serve_sample_rows`` over the logits-source shapes the
         serving loops read (chunk/decode pass outputs, per-bucket fused
         outputs, and pow2-padded host-rematerialized blocks — restore paths
@@ -1011,16 +946,17 @@ class InferenceEngineV2:
         (so a spec-serving engine's steady state — including the spec-off
         comparison legs sharing the engine — adds zero timed compiles).
         """
+        from deepspeed_tpu.utils.compile_cache import (setup_summary,
+                                                       with_stack_room)
         # set-up's second stage (tracer.stage), a child a family of the grid
         with _tracer.stage("warmup"):
-            built = self._warmup(buckets, burst_steps, spec_ks)
+            built = with_stack_room(lambda: self._warmup(buckets, spec_ks))
         if not self._setup_logged:      # once: a rejoin warms again
             self._setup_logged = True
-            from deepspeed_tpu.utils.compile_cache import setup_summary
             log_dist(f"engine_v2: {setup_summary()}", ranks=[0])
         return built
 
-    def _warmup(self, buckets, burst_steps, spec_ks) -> int:
+    def _warmup(self, buckets, spec_ks) -> int:
         stage = _tracer.stage
 
         def family(name, members):
@@ -1054,9 +990,6 @@ class InferenceEngineV2:
         self._step_progs.maxsize = max(
             self._step_progs.maxsize,
             (len(lora_rungs) + 1) * len(grid) * len(attn_rungs) + 2)
-        self._multistep.maxsize = max(
-            self._multistep.maxsize,
-            len(burst_steps) * len(grid) * len(attn_rungs) + 2)
         self._verify_progs.maxsize = max(
             self._verify_progs.maxsize,
             (len(lora_rungs) + 1) * len(spec_ks) * len(grid)
@@ -1087,19 +1020,6 @@ class InferenceEngineV2:
                                                     *args, *lops)
                         self.kv.update(new_kv)
                         jax.block_until_ready(nxt)
-        with family("multistep", burst_steps):
-            for n_steps in burst_steps:
-                for sp in attn_rungs:
-                    for b in grid:
-                        fn = self._multistep.get_or_create(
-                            (n_steps, b, False, 0, sp),
-                            lambda n=n_steps, s=sp: self._build_multistep(
-                                n, False, 0, s))
-                        args = self._scratch_step_args(b, mb)
-                        out_ids, _logits, new_kv = fn(self.weights,
-                                                      self.kv.kv, *args)
-                        self.kv.update(new_kv)
-                        jax.block_until_ready(out_ids)
         # the speculative (bucket, k) verify grid: every program runs once
         # over all-scratch rows with zero proposed drafts (accept masks and
         # page writes exercise the same traced shapes live traffic uses)
@@ -1133,8 +1053,8 @@ class InferenceEngineV2:
             if self.lora is not None:
                 self.lora.pool.warm(self.config.lora.max_rank)
         # the greedy bootstrap sampler over every logits-source shape a
-        # serving loop can hand it: without this, the FIRST pipeline run /
-        # burst after startup pays a small-but-real compile that the engine
+        # serving loop can hand it: without this, the FIRST pipeline run
+        # after startup pays a small-but-real compile that the engine
         # counter cannot witness (serve_sample_rows is a module-level jit)
         sm = self.config.state_manager
         V = self.spec.vocab_size
@@ -1163,26 +1083,9 @@ class InferenceEngineV2:
                 for k, n in _moe_kernel_counts().items()}
         moe = f"; MoE layer runs by grouped kernel: {took}" if took else ""
         log_dist(f"engine_v2: warmup built {built} programs "
-                 f"(buckets={grid}, burst_steps={list(burst_steps)}){moe}",
+                 f"(buckets={grid}){moe}",
                  ranks=[0])
         return built
-
-    def _build_multistep(self, n_steps: int, do_sample: bool, top_k: int,
-                         sp: int = 1):
-        """Build (and count) one fused multistep program — the same builder
-        decode_steps uses, shared so warmup pre-compiles identical keys.
-        ``sp`` is the flash-decoding split rung the program attends at."""
-        from deepspeed_tpu.inference.v2.ragged_model import (
-            build_multistep_decode)
-        tp = self.topology.tp_world_size
-        fwd = build_multistep_decode(
-            self.spec, n_steps, mesh=self.topology.mesh,
-            tp=tp if tp > 1 else 1, do_sample=do_sample, top_k=top_k,
-            window_ring_ok=self.scheduler.ring_covers(n_steps + 1),
-            n_splits=int(sp))
-        self.compiles += 1
-        return _program(fwd, "serve_decode_multistep" + _rung(sp),
-                        donate_argnums=(1,))
 
     def _scratch_step_args(self, bucket: int, max_blocks: int):
         """All-pad-row inputs for a fused decode program: every row is the
@@ -1671,8 +1574,8 @@ class InferenceEngineV2:
         byte-identical to the old per-token ``sample_next``/``put`` loop,
         spec on or off (pinned by tests/unit/test_decode_pipeline.py and
         test_spec_decode.py); sampled streams are valid draws but consume
-        RNG per fused step, so they differ from the old loop's draws (the
-        documented ``decode_steps`` trade)."""
+        RNG per fused step, so they differ from the old loop's draws (and
+        depend on the bucket: ``DecodePipeline``'s docstring)."""
         # fresh uid namespace: never collide with caller-owned put() sequences
         uids: List[int] = []
         nxt = 0

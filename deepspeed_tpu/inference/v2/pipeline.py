@@ -53,8 +53,8 @@ class DecodePipeline:
     """Double-buffered decode over a fixed live set of sequences.
 
     All ``uids`` must be in steady decode state: known to the scheduler, no
-    pending host tokens, last-logits refs available (i.e. after ``put()`` /
-    ``decode_steps`` / a previous run). Drive it as::
+    pending host tokens, last-logits refs available (i.e. after ``put()`` or
+    a previous run). Drive it as::
 
         pipe = engine.decode_pipeline(uids)
         tokens = pipe.run(64)            # [len(uids), 64], greedy
@@ -62,10 +62,14 @@ class DecodePipeline:
         pipe.admit(new_uids)             # after engine.put() prefilled them
         tokens2 = pipe.run(64)
 
-    Greedy streams are byte-identical to ``decode_steps`` bursts and to the
-    per-token ``sample_next``/``put`` loop (same forward math; pinned by
-    tests/unit/test_decode_pipeline.py). Sampled streams are valid draws but
-    bucket-dependent (see ``decode_steps``' docstring).
+    Rows are padded to ``next_pow2(live)`` (``DecodeBatch``: programs are
+    keyed by the bucket, pad rows decode into the scratch page) and decode is
+    row-independent, so greedy streams are byte-identical under padding and
+    to the per-token ``sample_next``/``put`` loop (same forward math; pinned
+    by tests/unit/test_decode_pipeline.py). Batch-sampled rows draw from a
+    [bucket, V] noise block, so SAMPLED streams are valid draws that depend
+    on the bucket (not on which other rows are pads) — a documented trade,
+    not a bug.
     """
 
     def __init__(self, engine, uids: Sequence[int], do_sample: bool = False,
@@ -258,8 +262,8 @@ class DecodePipeline:
             self.uids = []
             raise
         # the final step's sampled row (token n_steps) stays on device,
-        # discarded — identical policy to decode_steps; continuation
-        # re-derives it from the final logits refs (greedy: same token)
+        # discarded; continuation re-derives it from the final logits refs
+        # (greedy: same token)
         for i, u in enumerate(uids):
             if live[i]:
                 e.scheduler.advance(u, n_steps)

@@ -162,8 +162,8 @@ class RaggedBatch:
 
 @dataclass
 class DecodeBatch:
-    """BUCKETED decode-only descriptor set for the fused decode programs
-    (``decode_steps`` bursts and the double-buffered ``DecodePipeline``).
+    """BUCKETED decode-only descriptor set for the fused decode step
+    (the double-buffered ``DecodePipeline`` and its speculative twin).
 
     Row count is padded to ``bucket = next_pow2(len(uids))`` so every device
     program downstream is keyed by the bucket, not the live count: admitting

@@ -25,7 +25,7 @@ class DSSequenceDescriptor:
     # every token the host has seen for this sequence, in order — the radix
     # tree is keyed on token blocks, so releasing KV pages to the cache needs
     # the ids that produced them. Device-generated tokens the host never saw
-    # (fused decode bursts) are NOT here; pages beyond the history are freed,
+    # (a pipeline run's) are NOT here; pages beyond the history are freed,
     # not cached. Buffered as a part-list so the per-decode-token append is
     # O(1) (a flat-array concatenate per token is O(n^2) over a generation);
     # ``history()`` flattens on demand.

@@ -24,16 +24,16 @@ Attention is one function in two forms that agree through that pool:
   once, as keys and as values: ``2 (W + kv_lora_rank)`` operations per
   (query, key, head) but ``W`` values read per key for all heads. Every
   program that reads the pool uses it: decode rows (ragged pass, fused
-  decode step, multistep), the verify step, and a paged chunk's rows — for
+  decode step), the verify step, and a paged chunk's rows — for
   the chunk's EARLIER context and, after its rows are written, for its own
   rows too (docs/SERVING.md "Latent pages" has the count against expanding
   the cached latents).
 
 New rows reach the pool as K/V rows do: a flat scatter in the ragged pass and
 the verify step, whole pages in the packed pass, and in the fused decode
-programs through a side buffer ``[L, S, C, W]`` that the kernel attends
-beside the frozen pages and one row write (scope ``kv_flush``) puts into the
-pool at the chunk's end.
+step through a side buffer ``[L, S, 8, W]`` that the kernel attends beside
+the frozen pages and one row write (scope ``kv_flush``) puts into the pool
+after the layers.
 """
 
 from __future__ import annotations
@@ -199,75 +199,59 @@ def build_packed_prefill(spec: RaggedModelSpec) -> Callable:
     return fwd
 
 
-def build_multistep(spec: RaggedModelSpec, n_steps: int, do_sample: bool,
-                    top_k: int) -> Callable:
-    """``_build_multistep_sidebuf`` over latent pages: the pool stays frozen
-    for the chunk of ``n_steps`` steps, each step's latent rows go to a side
-    buffer ``[L, S, Cb, W]`` the kernel attends beside the pages, and one row
-    write puts them into the pool at the end. ``n_steps`` 1 is the serving
-    pipeline's decode step."""
-    C = n_steps
-    Cb = -(-n_steps // 8) * 8       # the slab's rows, in whole sublane tiles
+def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
+                      top_k: int) -> Callable:
+    """``ragged_model.build_decode_step``'s side-buffer form over latent
+    pages: the pool stays frozen through the layers, each layer's latent row
+    goes to a side buffer ``[L, S, 8, W]`` (one sublane tile, row 0 the
+    step's) the kernel attends beside the pages, and one row write puts the
+    rows into the pool after the layers."""
 
-    def fwd(weights, pool, ids0, positions0, block_tables, ctx0, key,
+    def fwd(weights, pool, ids, positions, block_tables, ctx, key,
             temperature=1.0):
-        S = ids0.shape[0]
+        S = ids.shape[0]
         L, NB, bs, W = pool.shape
         pages = pool.reshape(L * NB, bs, W)
-        # ctx0 counts the first current token; the pages hold the prefix
-        prefix = jnp.maximum(ctx0 - 1, 0)
-        side0 = jnp.zeros((L, S, Cb, W), pool.dtype)
+        # ctx counts this step's token; the pages hold the prefix
+        prefix = jnp.maximum(ctx - 1, 0)
+        x = _embed_in(spec, weights, ids, positions)
 
-        def one_pass(x_ids, pos, j, side):
-            x = _embed_in(spec, weights, x_ids, pos)
+        def make_body(rs, experts, l0):
+            ak = AttentionKernelSpec(rs)
 
-            def make_body(rs, experts, l0):
-                ak = AttentionKernelSpec(rs)
+            def layer_fn(carry, scanned):
+                x, side = carry
+                w, l = scanned
 
-                def layer_fn(carry, scanned):
-                    x, side = carry
-                    w, l = scanned
+                def attend(q_nope, q_rope, lat):
+                    side_ = jax.lax.dynamic_update_slice(
+                        side, lat[None, :, None].astype(side.dtype),
+                        (l, 0, 0, 0))
+                    with jax.named_scope("absorb"):
+                        q = mla_absorb_q(rs, w, q_nope, q_rope, W)
+                    with jax.named_scope("decode"):
+                        o_lat = ak.latent(
+                            q, pages, block_tables + l * NB, prefix,
+                            prefix, side=side_, side_j=0, layer_idx=l)
+                    with jax.named_scope("absorb"):
+                        return mla_absorb_o(w, o_lat), side_
 
-                    def attend(q_nope, q_rope, lat):
-                        side_ = jax.lax.dynamic_update_slice(
-                            side, lat[None, :, None].astype(side.dtype),
-                            (l, 0, j, 0))
-                        with jax.named_scope("absorb"):
-                            q = mla_absorb_q(rs, w, q_nope, q_rope, W)
-                        with jax.named_scope("decode"):
-                            o_lat = ak.latent(
-                                q, pages, block_tables + l * NB, prefix,
-                                prefix, side=side_, side_j=j, layer_idx=l)
-                        with jax.named_scope("absorb"):
-                            return mla_absorb_o(w, o_lat), side_
+                x, (side,) = _transformer_layer(
+                    rs, w, x, positions, attend, experts=experts, l=l - l0)
+                return (x, side), None
 
-                    x, (side,) = _transformer_layer(
-                        rs, w, x, pos, attend, experts=experts, l=l - l0)
-                    return (x, side), None
+            return layer_fn
 
-                return layer_fn
-
-            x, side = _scan_layers(spec, weights["layers"], make_body,
-                                   (x, side))
-            return _unembed(spec, weights, _finish(spec, weights, x)), side
-
-        def step(carry, j):
-            ids, pos, side, _ = carry
-            logits, side = one_pass(ids, pos, j, side)
-            nxt = _sample_logits(logits, jax.random.fold_in(key, j),
-                                 do_sample, top_k, temperature)
-            return (nxt, pos + 1, side, logits), ids
-
-        V = weights["embed"].shape[0]
-        (_, _, side, final_logits), out_ids = jax.lax.scan(
-            step, (ids0, positions0, side0, jnp.zeros((S, V), jnp.float32)),
-            jnp.arange(C))
-        # the kernels READ the pool inside the scan; the barrier orders the
-        # in-place write after them instead of cloning the pool
-        pool, _ = jax.lax.optimization_barrier((pool, final_logits))
+        x, side = _scan_layers(spec, weights["layers"], make_body,
+                               (x, jnp.zeros((L, S, 8, W), pool.dtype)))
+        logits = _unembed(spec, weights, _finish(spec, weights, x))
+        # the kernels READ the pool inside the layers; the barrier orders
+        # the in-place write after them instead of cloning the pool
+        pool, _ = jax.lax.optimization_barrier((pool, logits))
         with jax.named_scope("kv_flush"):
-            new_pool = mla_row_write(pool, side, block_tables, prefix, C)
-        return out_ids, final_logits, new_pool
+            new_pool = mla_row_write(pool, side, block_tables, prefix, 1)
+        nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
+        return nxt, logits, new_pool
 
     return fwd
 

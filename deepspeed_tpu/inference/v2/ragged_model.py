@@ -15,7 +15,7 @@ slots | decode rows]. Each layer writes the pass's K/V into the paged cache
   - chunk slots -> ``AttentionKernelSpec.chunk`` (flash over pages for all
     slots in one kernel, causal by absolute position)
   - decode rows -> ``AttentionKernelSpec.decode`` (one token per sequence;
-    the fused multistep loop uses ``.decode_step``/``.sidebuf``)
+    the fused decode step uses ``.decode_step``/``.sidebuf``)
 
 Every builder routes attention through ONE ``AttentionKernelSpec``
 (``inference/v2/attention.py``): kernel variants key on the pool dtype at
@@ -40,6 +40,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -1872,7 +1873,7 @@ def _mla_project(spec: "RaggedModelSpec", w, h1, positions):
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                        lora=None, experts=None, l=0):
     """Shared per-layer transformer body for BOTH the ragged forward (put
-    passes) and the fused multistep decode — one implementation so the two
+    passes) and the fused decode step — one implementation so the two
     paths cannot diverge.  ``attend(q, k, v) -> (attn_raw [N, H, D],
     *state)`` performs the KV page write + attention for its pass shape;
     ``state`` is the caller's carried cache state (pools, or pools + scale
@@ -2394,271 +2395,37 @@ def build_prefill_forward(spec: RaggedModelSpec,
     return fwd
 
 
-def _build_multistep_sidebuf(spec: RaggedModelSpec, n_steps: int,
-                             do_sample: bool, top_k: int,
-                             n_splits: int = 1) -> Callable:
-    """Fused multistep decode WITHOUT per-step pool scatters.
-
-    The default multistep loop writes each step's K/V into the paged pools
-    with a [S*Hkv]-row scatter per layer per step; TPU scatter serializes
-    per row, and at S=256 those writes cost ~2.5 ms/step — more than the
-    dense compute (measured v5e-1, 0.55B GQA: dense-only 1.8 ms,
-    dense+scatter 4.3 ms, full 7.0 ms). Here the pools stay FROZEN for the
-    whole chunk:
-
-      - each layer's new K/V rows accumulate in a sequence-major side buffer
-        [L, S, C, Hkv, D] (one contiguous dynamic_update_slice per step);
-      - attention per step = ONE fused kernel over the frozen prefix pages
-        plus the side slab (``paged_decode_attention_sidebuf``): the side
-        rows fold into the same online-softmax state, so the kernel reads
-        one sequence's [C, Hkv, D] slab into VMEM instead of the round-4
-        schedule's per-layer-per-step jnp re-read of the whole [C, S, Hkv,
-        D] buffer + lse merge;
-      - at chunk end ONE kernel writes the side buffers' rows into the
-        pools (``paged_kv_row_write``, scope ``kv_flush``): per sequence the
-        aligned groups of slots its C tokens fall in, for every layer —
-        row-granular, so the single decode step (C = 1, what the serving
-        pipeline runs) pays for one token's rows. The whole-page
-        read-modify-write this replaced moved two pages per sequence per
-        layer whatever C was: a quarter of a 32-row Mistral-7B step on a
-        v5e, where nothing amortized it (PERF.md, PR 28).
-
-    Used when tp == 1 and head_dim % 128 == 0 (the fused kernel's
-    alignment); other configs take the general loop below. ``window`` is
-    admitted (the kernel windows both pieces by the moving query position);
-    on the page ring the write stays correct because it only touches slots
-    holding positions >= prefix, whose pages the ring never recycles
-    mid-chunk.
-    """
-    H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    G = H // Hkv
-    dtype = spec.dtype
-    # side-slab CAPACITY is n_steps padded so Cb*Hkv aligns to the 8-sublane
-    # tile (MQA Hkv=1 with arbitrary n_steps stays on the fast path; padded
-    # rows are never visible: the kernel masks cc > j and j < n_steps, and
-    # the flush only writes rows < n_steps)
-    C = n_steps
-    Cb = n_steps
-    while (Cb * Hkv) % 8 != 0:
-        Cb += 1
-
-    def fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
-            key, temperature=1.0, state_slots=None):
-        kv_pages, st0 = _state_unpack(kv_pages)
-        kv_pages, kv_sc = _kv_unpack(kv_pages)
-        kvq = kv_sc is not None
-        assert (st0 is None) == (state_slots is None), \
-            "state pools and the rows' state slots come together"
-        rows = _StateRows(decode_slot=state_slots)
-        S = ids0.shape[0]
-        L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
-        kvp5 = kv_pages.reshape(L * NB, 2, Hkv, bs, D)
-        # scales are stored in kernel tile layout AT REST — the view below
-        # is a bitcast, so the frozen-pool scans never pay a conversion
-        r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
-        sc4 = kv_sc.reshape(L * NB, r8, 128) if kvq else None
-        # engine contract: ctx0 counts tokens INCLUDING the first current
-        # token; the pages hold only the frozen prefix [0, ctx0 - 1) — the
-        # current token (and everything after) lives in the side buffers
-        prefix = jnp.maximum(ctx0 - 1, 0)
-        # side buffers live PRE-FLATTENED as [L, S, Cb*Hkv, D] rows
-        # (row cc*Hkv + h): with Hkv second-minor, the per-call reshape to
-        # kernel rows relayout-copies the WHOLE buffer at head counts whose
-        # (Hkv, D) tile pads (measured: 14 ms/step vs 2.9 at MHA-12 — the
-        # same padded-sublane trap the kv pool layout avoids, kv_cache.py).
-        # int8 pools: the slab holds kv_write_dequant'd POOL values, kept
-        # f32 so a bf16 slab round-trip cannot round them away from what
-        # every pool read (int8 * f32 scale, in f32) computes
-        side_dtype = jnp.float32 if kvq else dtype
-        side_k0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
-        side_v0 = jnp.zeros((L, S, Cb * Hkv, D), side_dtype)
-
-        def one_pass(x_ids, pos, j, sk_all, sv_all, st):
-            x = _embed_in(spec, weights, x_ids, pos)
-
-            def make_body(rs, experts, l0):
-                if rs.mamba is not None:
-                    return _mamba_body(rs, pos, rows, experts, l0)
-                ak = AttentionKernelSpec(
-                    rs, mesh=None, tp=1,
-                    n_splits=_kind_splits(spec, rs, n_splits))
-
-                def layer_fn(carry, scanned):
-                    # side buffers ride the CARRY with in-place dynamic
-                    # updates — as scan xs/ys they are repacked (a full
-                    # side-buffer copy per step, measured slower than the
-                    # scatter they replace)
-                    x, sk_all, sv_all, st = carry
-                    w, l = scanned
-
-                    def attend(q, k, v):
-                        if kvq:
-                            # int8 pools: the slab holds the rows' POOL
-                            # values (quantize-then-dequantize), so the
-                            # in-chunk tokens are attended at the same
-                            # values every later pool read — and the spec
-                            # verify's write-then-attend — dequantizes; the
-                            # chunk-end flush re-quantizes to the identical
-                            # int8 bytes (kv_write_dequant is
-                            # value-idempotent)
-                            k = kv_write_dequant(k)
-                            v = kv_write_dequant(v)
-                        # step j's rows are the contiguous flat span
-                        # [j*Hkv, (j+1)*Hkv)
-                        sk_new = jax.lax.dynamic_update_slice(
-                            sk_all, k[None].astype(sk_all.dtype),
-                            (l, 0, j * Hkv, 0))
-                        sv_new = jax.lax.dynamic_update_slice(
-                            sv_all, v[None].astype(sv_all.dtype),
-                            (l, 0, j * Hkv, 0))
-                        # the WHOLE [L, S, Cb, Hkv, D] stack goes to the
-                        # kernel, which BlockSpec-indexes layer l — a
-                        # dynamic_slice here would materialise the layer's
-                        # slab per call (measured ~150 us/layer of pure copy
-                        # traffic)
-                        out = ak.sidebuf(
-                            q, kvp5, block_tables + l * NB, prefix,
-                            sk_new, sv_new, j, layer_idx=l,
-                            kv_scales=sc4 if kvq else None)
-                        return out, sk_new, sv_new
-
-                    x, (sk_all, sv_all) = _transformer_layer(
-                        rs, w, x, pos, attend, experts=experts, l=l - l0)
-                    return (x, sk_all, sv_all, st), None
-
-                return layer_fn
-
-            x, sk_new, sv_new, st = _scan_layers(
-                spec, weights["layers"], make_body, (x, sk_all, sv_all, st))
-            x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
-                      spec.norm_plus_one)
-            return _unembed(spec, weights, x), sk_new, sv_new, st
-
-        def sample(logits, step_key):
-            return _sample_logits(logits, step_key, do_sample, top_k,
-                                  temperature)
-
-        def step(carry, j):
-            ids, pos, sk_all, sv_all, st, _ = carry
-            logits, sk_all, sv_all, st = one_pass(ids, pos, j, sk_all,
-                                                  sv_all, st)
-            nxt = sample(logits, jax.random.fold_in(key, j))
-            return (nxt, pos + 1, sk_all, sv_all, st, logits), ids
-
-        V = weights["embed"].shape[0]
-        init_logits = jnp.zeros((S, V), jnp.float32)
-        (_, _, sk_all, sv_all, st, final_logits), out_ids = jax.lax.scan(
-            step, (ids0, positions0, side_k0, side_v0, st0, init_logits),
-            jnp.arange(C))
-
-        # ---- chunk-end flush: the side buffers' rows -> the pool ---- #
-        # the kernels READ the pool inside the scan; the barrier ties the
-        # write's pool operand to the scan result so XLA orders the in-place
-        # write after the reads instead of cloning the (GB-scale) pool
-        kv_pages, kv_sc, _ = jax.lax.optimization_barrier(
-            (kv_pages, kv_sc, final_logits))
-        with jax.named_scope("kv_flush"):
-            new_kv = paged_kv_row_write(kv_pages, sk_all, sv_all,
-                                        block_tables, prefix, C,
-                                        kv_scales=kv_sc)
-        return (out_ids, final_logits, _state_pack(new_kv, st))
-
-    return fwd
-
-
-def build_multistep_decode(spec: RaggedModelSpec, n_steps: int,
-                           mesh=None, tp: int = 1,
-                           do_sample: bool = False,
-                           top_k: int = 0,
-                           window_ring_ok: bool = False,
-                           max_side_bytes: Optional[int] = None,
-                           lora_targets: Optional[Tuple[str, ...]] = None,
-                           n_splits: int = 1) -> Callable:
-    """Fused N-step greedy/sampled decode: the sample->embed->forward->sample
-    feedback loop runs entirely on device for ``n_steps`` tokens per sequence.
-
-    TPU-native rationale: the per-token serving loop pays one host<->device
-    round trip per generated token (sample + descriptor upload); over a remote
-    runtime or PCIe that round trip dwarfs the ~ms decode pass.  Fusing N steps
-    amortises it N-fold — the host only pre-reserves KV pages for N tokens and
-    syncs sequence lengths afterwards.  (Same motivation as the reference's
-    CUDA-graph capture of the decode step, ``InferenceEngine._create_cuda_graph``
-    engine.py:524, taken further: the whole token loop is one XLA program.)
-
-    ``window_ring_ok``: with a sliding window, the side-buffer schedule
-    freezes page reads for the whole chunk while writing ``n_steps`` tokens
-    at the flush, so the scheduler's page ring must cover window + n_steps.
-    The UNSAFE-to-assume case defaults off: windowed specs take the general
-    (per-step write) loop unless the caller has checked
-    ``scheduler.ring_covers(n_steps + 1)`` and passes True.
-
-    ``max_side_bytes``: the side-buffer schedule carries two
-    [L, S, C, Hkv, D] buffers through the scan (transient HBM the per-step
-    schedule does not need); above this budget the general loop is used
-    (default from DSTPU_SIDEBUF_MAX_MB, 6144 MB — ADVICE r4's OOM guard.
-    6 GB not 2: an MHA-12 serving leg's buffers are 2.3 GB and the general
-    loop is 4x slower there — a regression seen on the chip before PR 22,
-    of which no record survives, when the gate was 2 GB — while v5e HBM
-    comfortably holds 6 GB transient beside a sub-1B serving model; larger
-    models use the env knob).
-
-    Returns ``fwd(weights, kv_pages, ids0 [S], positions0 [S],
-    block_tables [S, MB], ctx0 [S], key) -> (out_ids [n_steps, S],
-    final_logits [S, V], new_kv)`` where ``out_ids[j]`` is the token
-    *consumed* by step j (ids0 first), and ``final_logits`` predict the token
-    after the last generated one (so the serving loop can continue seamlessly).
-    """
-    if spec.mla is not None:
-        # latent pages: the side-buffer schedule always (its gates are the
-        # K/V kernels'; tp > 1 and LoRA are refused at build)
-        from deepspeed_tpu.inference.v2.ragged_mla import build_multistep
-        return build_multistep(spec, n_steps, do_sample, top_k)
-    general = _build_multistep_general(spec, n_steps, mesh=mesh, tp=tp,
-                                       do_sample=do_sample, top_k=top_k,
-                                       lora_targets=lora_targets,
-                                       n_splits=n_splits)
-    # LoRA programs take the general (per-step write) loop only: the
-    # side-buffer schedule's decode path is the single-step pipeline's
-    # domain and wiring adapter operands into its frozen-read scan buys
-    # nothing (decode_steps bursts are NOT lora-wired; docs/SERVING.md)
-    fits = (lora_targets is None and tp == 1 and spec.head_dim % 128 == 0
-            and (spec.window is None or window_ring_ok))
-    if not fits:
-        return general
-    sidebuf = _build_multistep_sidebuf(spec, n_steps, do_sample, top_k,
-                                       n_splits=n_splits)
-    if max_side_bytes is None:
-        import os
-        max_side_bytes = int(float(os.environ.get(
-            "DSTPU_SIDEBUF_MAX_MB", "6144")) * 1e6)
-    esize = jnp.dtype(spec.dtype).itemsize
-    budget = max_side_bytes
-
-    def fwd(weights, kv_pages, ids0, *rest, **kw):
-        S = ids0.shape[0]
-        L = _kv_unpack(_state_unpack(kv_pages)[0])[0].shape[0]
-        side_bytes = (2 * L * S * n_steps * spec.num_kv_heads
-                      * spec.head_dim * esize)
-        impl = sidebuf if side_bytes <= budget else general
-        return impl(weights, kv_pages, ids0, *rest, **kw)
-
-    return fwd
-
-
-
 def _sample_logits(logits, key, do_sample: bool, top_k: int, temperature):
-    """The ONE greedy/temperature/top-k sampler shared by every fused decode
-    program (multistep scan steps and the pipeline's decode-step wrapper).
-    build_decode_step's byte-identical-to-burst guarantee depends on all
-    sites running these exact ops with the same key fold — change it here,
-    nowhere else."""
+    """The ONE greedy/temperature/top-k sampler of the fused decode step
+    (both of its forms, and the latent one). A pipelined stream equals the
+    per-token ``sample_next``/``put`` loop under greedy decoding because
+    every site runs these exact ops — change it here, nowhere else."""
     if not do_sample:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     z = logits / jnp.maximum(temperature, 1e-6)
     if top_k > 0:
         kth = jax.lax.top_k(z, top_k)[0][:, -1:]
         z = jnp.where(z < kth, -jnp.inf, z)
-    return jax.random.categorical(key, z, axis=-1).astype(jnp.int32)
+    # the pipeline folds the step's index into ``key`` before the dispatch;
+    # this fold is the program's own (a sampled stream depends on it)
+    return jax.random.categorical(jax.random.fold_in(key, 0), z,
+                                  axis=-1).astype(jnp.int32)
+
+
+def side_buffer_fits(spec: RaggedModelSpec, tp: int, window_ring_ok: bool,
+                     lora_targets: Optional[Tuple[str, ...]]) -> bool:
+    """Which form the decode step takes: True, the side buffer (the pool
+    frozen through the layers, one row write after them); False, each
+    layer's kernel writes its rows. The side-buffer kernel needs one device
+    and a lane-aligned head (``head_dim % 128``); LoRA operands are wired
+    into the in-layer form only; and with a sliding window the pages stay
+    frozen while the step's token is written after them, so the scheduler's
+    page ring must cover window + the step (``window_ring_ok``: the caller
+    has checked ``scheduler.ring_covers(2)``; unchecked, a windowed model
+    takes the in-layer write)."""
+    return (lora_targets is None and tp == 1 and spec.head_dim % 128 == 0
+            and (spec.window is None or window_ring_ok))
+
 
 def build_decode_step(spec: RaggedModelSpec, mesh=None, tp: int = 1,
                       do_sample: bool = False, top_k: int = 0,
@@ -2670,44 +2437,278 @@ def build_decode_step(spec: RaggedModelSpec, mesh=None, tp: int = 1,
     run the forward pass, and sample the NEXT token row — all in ONE device
     program, so the only thing that ever needs to cross back to the host per
     decode step is the [S] int32 token row (4 bytes/sequence instead of the
-    [S, V] logits block the per-token loop fetched).
+    [S, V] logits block the per-token loop fetched). The pipeline chains step
+    N+1's dispatch on step N's device-resident token row with no host round
+    trip in between: one program decodes one token, and how many tokens a
+    run decodes is the host loop's count of dispatches.
 
-    The forward body is exactly ``build_multistep_decode(n_steps=1)`` — the
-    same one-pass math the fused bursts run, so a pipelined token stream is
-    bit-identical to a ``decode_steps`` burst under greedy decoding; on the
-    side-buffer schedule the step's K/V write is that schedule's row write
-    (``kv_flush``) with one row per sequence, head and layer. On top
-    of it this wrapper re-derives the step's sampled next token from the
-    returned logits (same key fold as the scan's step 0, so XLA CSEs it with
-    the scan-internal sample) and RETURNS it, which the multistep builders
-    deliberately do not: the pipeline chains step N+1's dispatch on step N's
-    device-resident token row with no host round trip in between.
+    Two forms (``side_buffer_fits`` chooses, from the model and the engine's
+    layout) run the same one-pass math: the side buffer, whose K/V write is
+    one row write after the layers (scope ``kv_flush``: one row per
+    sequence, head and layer), and the in-layer write, whose attention
+    kernel writes each layer's rows as it goes. Latent pages have the side
+    buffer's form in ``ragged_mla.build_decode_step``.
 
     Returns ``fwd(weights, kv_pages, ids [S], positions [S],
-    block_tables [S, MB], ctx [S], key, temperature) ->
-    (next_ids [S] int32, logits [S, V], new_kv)`` where ``logits`` predict
-    ``next_ids`` (kept for the engine's continuation refs). With
-    ``lora_targets`` set, ``fwd`` takes the two REQUIRED trailing LoRA
-    operands ``(lora_pool, adapter_pt)`` after ``temperature`` and each
-    row's grouped adapter delta rides the targeted projections.
+    block_tables [S, MB], ctx [S], key, temperature, *tail) ->
+    (next_ids [S] int32, logits [S, V], new_kv)`` where ``ctx`` counts each
+    row's tokens INCLUDING this step's and ``logits`` predict ``next_ids``
+    (kept for the engine's continuation refs). ``tail`` is empty but for:
+    ``lora_targets`` set, the two REQUIRED LoRA operands ``(lora_pool,
+    adapter_pt)`` — each row's grouped adapter delta rides the targeted
+    projections; a model with state-space layers, ``(state_slots [S],)``,
+    the rows' slots in the state pools (LoRA is refused beside them).
     """
-    inner = build_multistep_decode(spec, 1, mesh=mesh, tp=tp,
-                                   do_sample=do_sample, top_k=top_k,
-                                   window_ring_ok=window_ring_ok,
-                                   lora_targets=lora_targets,
-                                   n_splits=n_splits)
+    if spec.mla is not None:
+        # latent pages: the side buffer always (its gates are the K/V
+        # kernels'; tp > 1 and LoRA are refused at build)
+        from deepspeed_tpu.inference.v2 import ragged_mla
+        return ragged_mla.build_decode_step(spec, do_sample, top_k)
+    if side_buffer_fits(spec, tp, window_ring_ok, lora_targets):
+        return _build_decode_sidebuf(spec, do_sample, top_k, n_splits)
+    return _build_decode_layer_write(spec, mesh, tp, do_sample, top_k,
+                                     lora_targets, n_splits)
+
+
+def _state_rows(st0, tail) -> Optional[_StateRows]:
+    """The decode rows' state slots out of a decode step's ``tail``."""
+    if st0 is None:
+        assert not tail, "operands after temperature, and nothing takes them"
+        return None
+    (state_slots,) = tail       # state pools and the rows' slots come together
+    return _StateRows(decode_slot=state_slots)
+
+
+def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
+                          top_k: int, n_splits: int = 1) -> Callable:
+    """The decode step WITHOUT a pool scatter in any layer.
+
+    Writing each layer's K/V into the paged pools as the layer runs is a
+    [S*Hkv]-row scatter per layer; TPU scatter serializes per row, and at
+    S=256 those writes cost ~2.5 ms/step — more than the dense compute
+    (measured v5e-1, 0.55B GQA: dense-only 1.8 ms, dense+scatter 4.3 ms,
+    full 7.0 ms). Here the pools stay FROZEN through the layers:
+
+      - each layer's new K/V rows go to a sequence-major side buffer
+        [L, S, rows, D] (one contiguous dynamic_update_slice a layer);
+      - attention = ONE fused kernel over the frozen prefix pages plus the
+        side slab (``paged_decode_attention_sidebuf``): the side rows fold
+        into the same online-softmax state, so the kernel reads one
+        sequence's slab into VMEM;
+      - after the layers ONE kernel writes the side buffers' rows into the
+        pools (``paged_kv_row_write``, scope ``kv_flush``): per sequence the
+        aligned group of slots its token falls in, for every layer —
+        row-granular, so the step pays for one token's rows. The whole-page
+        read-modify-write this replaced moved two pages per sequence per
+        layer: a quarter of a 32-row Mistral-7B step on a v5e (PERF.md,
+        PR 28).
+
+    ``window`` is admitted (the kernel windows both pieces by the query's
+    position); on the page ring the write stays correct because it only
+    touches the slot holding position ``prefix``, whose page the ring does
+    not recycle within the step.
+    """
+    Hkv, D = spec.num_kv_heads, spec.head_dim
+    dtype = spec.dtype
+    # the slab's rows a sequence: the step's Hkv, in whole steps' worth up to
+    # the 8-sublane tile (MQA's one row stays on this form; the kernels take
+    # the slab's capacity in steps, mask what lies past step 0, and the row
+    # write writes step 0 only)
+    side_rows = math.lcm(Hkv, 8)
 
     def fwd(weights, kv_pages, ids, positions, block_tables, ctx,
-            key, temperature=1.0, *lora_args):
-        out_ids, logits, new_kv = inner(weights, kv_pages, ids, positions,
-                                        block_tables, ctx, key, temperature,
-                                        *lora_args)
-        del out_ids  # == ids: the pipeline already holds this step's row
-        # same fold as the scan's step 0, so XLA CSEs this with the
-        # scan-internal sample
-        nxt = _sample_logits(logits, jax.random.fold_in(key, 0), do_sample,
-                             top_k, temperature)
-        return nxt, logits, new_kv
+            key, temperature=1.0, *tail):
+        kv_pages, st0 = _state_unpack(kv_pages)
+        kv_pages, kv_sc = _kv_unpack(kv_pages)
+        kvq = kv_sc is not None
+        rows = _state_rows(st0, tail)
+        S = ids.shape[0]
+        L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
+        kvp5 = kv_pages.reshape(L * NB, 2, Hkv, bs, D)
+        # scales are stored in kernel tile layout AT REST — the view below
+        # is a bitcast, so the frozen-pool reads never pay a conversion
+        r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
+        sc4 = kv_sc.reshape(L * NB, r8, 128) if kvq else None
+        # engine contract: ctx counts tokens INCLUDING this step's; the
+        # pages hold only the frozen prefix [0, ctx - 1) — this step's
+        # token lives in the side buffers
+        prefix = jnp.maximum(ctx - 1, 0)
+        # side buffers live PRE-FLATTENED as [L, S, rows, D] (row h of the
+        # step): with Hkv second-minor, the per-call reshape to kernel rows
+        # relayout-copies the WHOLE buffer at head counts whose (Hkv, D)
+        # tile pads (measured: 14 ms/step vs 2.9 at MHA-12 — the same
+        # padded-sublane trap the kv pool layout avoids, kv_cache.py).
+        # int8 pools: the slab holds kv_write_dequant'd POOL values, kept
+        # f32 so a bf16 slab round-trip cannot round them away from what
+        # every pool read (int8 * f32 scale, in f32) computes
+        side_dtype = jnp.float32 if kvq else dtype
+        side_k0 = jnp.zeros((L, S, side_rows, D), side_dtype)
+        side_v0 = jnp.zeros((L, S, side_rows, D), side_dtype)
+
+        x = _embed_in(spec, weights, ids, positions)
+
+        def make_body(rs, experts, l0):
+            if rs.mamba is not None:
+                return _mamba_body(rs, positions, rows, experts, l0)
+            ak = AttentionKernelSpec(
+                rs, mesh=None, tp=1,
+                n_splits=_kind_splits(spec, rs, n_splits))
+
+            def layer_fn(carry, scanned):
+                # side buffers ride the CARRY with in-place dynamic
+                # updates — as scan xs/ys they are repacked (a full
+                # side-buffer copy per layer, measured slower than the
+                # scatter they replace)
+                x, sk_all, sv_all, st = carry
+                w, l = scanned
+
+                def attend(q, k, v):
+                    if kvq:
+                        # int8 pools: the slab holds the rows' POOL
+                        # values (quantize-then-dequantize), so the
+                        # step's token is attended at the same values
+                        # every later pool read — and the spec verify's
+                        # write-then-attend — dequantizes; the row write
+                        # re-quantizes to the identical int8 bytes
+                        # (kv_write_dequant is value-idempotent)
+                        k = kv_write_dequant(k)
+                        v = kv_write_dequant(v)
+                    # the step's rows are the flat span [0, Hkv)
+                    sk_new = jax.lax.dynamic_update_slice(
+                        sk_all, k[None].astype(sk_all.dtype), (l, 0, 0, 0))
+                    sv_new = jax.lax.dynamic_update_slice(
+                        sv_all, v[None].astype(sv_all.dtype), (l, 0, 0, 0))
+                    # the WHOLE [L, S, rows, D] stack goes to the kernel,
+                    # which BlockSpec-indexes layer l — a dynamic_slice
+                    # here would materialise the layer's slab per call
+                    # (measured ~150 us/layer of pure copy traffic)
+                    out = ak.sidebuf(
+                        q, kvp5, block_tables + l * NB, prefix,
+                        sk_new, sv_new, 0, layer_idx=l,
+                        kv_scales=sc4 if kvq else None)
+                    return out, sk_new, sv_new
+
+                x, (sk_all, sv_all) = _transformer_layer(
+                    rs, w, x, positions, attend, experts=experts, l=l - l0)
+                return (x, sk_all, sv_all, st), None
+
+            return layer_fn
+
+        x, sk_all, sv_all, st = _scan_layers(
+            spec, weights["layers"], make_body, (x, side_k0, side_v0, st0))
+        x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
+                  spec.norm_plus_one)
+        logits = _unembed(spec, weights, x)
+
+        # ---- the side buffers' rows -> the pool ---- #
+        # the kernels READ the pool inside the layers; the barrier ties the
+        # write's pool operand to their result so XLA orders the in-place
+        # write after the reads instead of cloning the (GB-scale) pool
+        kv_pages, kv_sc, _ = jax.lax.optimization_barrier(
+            (kv_pages, kv_sc, logits))
+        with jax.named_scope("kv_flush"):
+            new_kv = paged_kv_row_write(kv_pages, sk_all, sv_all,
+                                        block_tables, prefix, 1,
+                                        kv_scales=kv_sc)
+        nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
+        return nxt, logits, _state_pack(new_kv, st)
+
+    return fwd
+
+
+def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
+                              do_sample: bool, top_k: int,
+                              lora_targets: Optional[Tuple[str, ...]],
+                              n_splits: int) -> Callable:
+    """The decode step whose attention kernel writes each layer's rows
+    (fused attention + page write, ``paged_decode_attention_step``): the
+    form of what ``side_buffer_fits`` turns away (TP sharding, a small
+    head_dim, a window whose ring was not checked, LoRA operands — docs/
+    SERVING.md "Multi-tenant LoRA")."""
+    Hkv, D = spec.num_kv_heads, spec.head_dim
+    dtype = spec.dtype
+
+    def fwd(weights, kv_pages, ids, positions, block_tables, ctx,
+            key, temperature=1.0, *tail):
+        kv_pages, st0 = _state_unpack(kv_pages)
+        kv_pages, kv_sc = _kv_unpack(kv_pages)
+        kvq = kv_sc is not None
+        assert not (kvq and tp > 1), "int8 KV pages + TP not wired"
+        L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
+        r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
+        lora_ops = None
+        if lora_targets is not None:
+            # (LoRA is refused beside state-space layers)
+            lora_pool, adapter_pt = tail
+            lora_ops = lora_layer_operands(spec, lora_targets, lora_pool,
+                                           adapter_pt)
+            tail = ()
+        rows = _state_rows(st0, tail)
+
+        # kvp flat [L*NB*2*Hkv*bs, D]. The attention + page-write is one
+        # fused unit (paged_decode_attention_step): pool aliased through
+        # the kernel, new rows scattered in place after — the pool flows
+        # through the layer scan with no copies (see the kernel docstring
+        # for why a pre-kernel scatter forces XLA to clone the pool).
+        x = _embed_in(spec, weights, ids, positions)
+
+        def make_body(rs, experts, l0):
+            if rs.mamba is not None:
+                return _mamba_body(rs, positions, rows, experts, l0)
+            ak = AttentionKernelSpec(
+                rs, mesh=mesh, tp=tp,
+                n_splits=_kind_splits(spec, rs, n_splits))
+
+            def layer_fn(carry, scanned):
+                x, kvp, sc, st = carry
+                if lora_ops is not None:
+                    w, l, lora_l = scanned
+                    lora = _lora_split(spec, lora_targets, lora_l)
+                else:
+                    w, l = scanned
+                    lora = None
+
+                def attend(q, k, v):
+                    if kvq:
+                        # the current token is attended from registers:
+                        # hand the kernel its POOL value (the in-kernel
+                        # re-quantization for the page write is
+                        # value-idempotent) so this form agrees with the
+                        # write-then-attend paths on the attended VALUES
+                        k = kv_write_dequant(k)
+                        v = kv_write_dequant(v)
+                        out, kv5, sc4 = ak.decode_step(
+                            q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
+                            block_tables + l * NB, ctx,
+                            kv_scales=sc.reshape(L * NB, r8, 128))
+                        return (out,
+                                kv5.reshape(L * NB * 2 * Hkv * bs, D),
+                                sc4.reshape(L * NB * r8 * 128))
+                    out, kv5 = ak.decode_step(
+                        q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
+                        block_tables + l * NB, ctx)
+                    return (out, kv5.reshape(L * NB * 2 * Hkv * bs, D), sc)
+
+                x, (kvp, sc) = _transformer_layer(
+                    rs, w, x, positions, attend, lora=lora, experts=experts,
+                    l=l - l0)
+                return (x, kvp, sc, st), None
+
+            return layer_fn
+
+        kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)
+        sc0 = kv_sc.reshape(L * NB * r8 * 128) if kvq else None
+        x, kvp, sc, st = _scan_layers(
+            spec, weights["layers"], make_body, (x, kvp0, sc0, st0),
+            extra_xs=() if lora_ops is None else (lora_ops,))
+        x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
+                  spec.norm_plus_one)
+        logits = _unembed(spec, weights, x)
+        new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
+        if kvq:
+            new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
+        nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
+        return nxt, logits, _state_pack(new_kv, st)
 
     return fwd
 
@@ -2883,139 +2884,3 @@ def _greedy_accept(logits, draft, n_draft):
         logits, accept[:, None, None], axis=1)[:, 0]               # [S, V]
     accept_row = jnp.stack([accept, next_ids]).astype(jnp.int32)
     return accept_row, next_ids, final_logits
-
-
-def _build_multistep_general(spec: RaggedModelSpec, n_steps: int,
-                             mesh=None, tp: int = 1,
-                             do_sample: bool = False,
-                             top_k: int = 0,
-                             lora_targets: Optional[Tuple[str, ...]] = None,
-                             n_splits: int = 1) -> Callable:
-    """The per-step-write multistep loop (fused attention+page-write kernel
-    per layer per step): the fallback when the side-buffer schedule's gates
-    fail (TP sharding, small head_dim, window-ring capacity, side-buffer HBM
-    budget). With ``lora_targets`` the built ``fwd`` takes two REQUIRED
-    trailing operands after ``temperature`` — ``lora_pool [P+2, E]`` and
-    ``adapter_pt [S, RB]`` — and every row's grouped adapter delta rides the
-    targeted projections (docs/SERVING.md "Multi-tenant LoRA")."""
-    H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    dtype = spec.dtype
-
-    def fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
-            key, temperature=1.0, *lora_args, state_slots=None):
-        kv_pages, st0 = _state_unpack(kv_pages)
-        kv_pages, kv_sc = _kv_unpack(kv_pages)
-        kvq = kv_sc is not None
-        assert not (kvq and tp > 1), "int8 KV pages + TP not wired"
-        rows = None
-        if st0 is not None:
-            # (LoRA is refused beside state-space layers)
-            assert state_slots is not None and not lora_args, \
-                "state pools and the rows' state slots come together"
-            rows = _StateRows(decode_slot=state_slots)
-        S = ids0.shape[0]
-        L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
-        r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
-        if lora_targets is not None:
-            # hoisted out of the step scan: the gather is loop-invariant
-            # (a batch's adapter bindings are frozen for the whole run)
-            lora_pool, adapter_pt = lora_args
-            lora_ops = lora_layer_operands(spec, lora_targets, lora_pool,
-                                           adapter_pt)
-        else:
-            assert not lora_args, "lora operands on a non-LoRA program"
-            lora_ops = None
-
-        def one_pass(x_ids, pos, ctx, kvp, sc, st):
-            # kvp flat [L*NB*2*Hkv*bs, D]. The attention + page-write is one
-            # fused unit (paged_decode_attention_step): pool aliased through
-            # the kernel, new rows scattered in place after — the pool flows
-            # through the layer scan with no copies (see the kernel docstring
-            # for why a pre-kernel scatter forces XLA to clone the pool).
-            x = _embed_in(spec, weights, x_ids, pos)
-
-            def make_body(rs, experts, l0):
-                if rs.mamba is not None:
-                    return _mamba_body(rs, pos, rows, experts, l0)
-                ak = AttentionKernelSpec(
-                    rs, mesh=mesh, tp=tp,
-                    n_splits=_kind_splits(spec, rs, n_splits))
-
-                def layer_fn(carry, scanned):
-                    x, kvp, sc, st = carry
-                    if lora_ops is not None:
-                        w, l, lora_l = scanned
-                        lora = _lora_split(spec, lora_targets, lora_l)
-                    else:
-                        w, l = scanned
-                        lora = None
-
-                    def attend(q, k, v):
-                        if kvq:
-                            # the current token is attended from registers:
-                            # hand the kernel its POOL value (the in-kernel
-                            # re-quantization for the page write is
-                            # value-idempotent) so this path agrees with the
-                            # write-then-attend paths on the attended VALUES
-                            k = kv_write_dequant(k)
-                            v = kv_write_dequant(v)
-                            out, kv5, sc4 = ak.decode_step(
-                                q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
-                                block_tables + l * NB, ctx,
-                                kv_scales=sc.reshape(L * NB, r8, 128))
-                            return (out,
-                                    kv5.reshape(L * NB * 2 * Hkv * bs, D),
-                                    sc4.reshape(L * NB * r8 * 128))
-                        out, kv5 = ak.decode_step(
-                            q, k, v, kvp.reshape(L * NB, 2, Hkv, bs, D),
-                            block_tables + l * NB, ctx)
-                        return (out, kv5.reshape(L * NB * 2 * Hkv * bs, D),
-                                sc)
-
-                    x, (kvp, sc) = _transformer_layer(
-                        rs, w, x, pos, attend, lora=lora, experts=experts,
-                        l=l - l0)
-                    return (x, kvp, sc, st), None
-
-                return layer_fn
-
-            x, kvp, sc, st = _scan_layers(
-                spec, weights["layers"], make_body, (x, kvp, sc, st),
-                extra_xs=() if lora_ops is None else (lora_ops,))
-            x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
-                      spec.norm_plus_one)
-            logits = _unembed(spec, weights, x)
-            return logits, kvp, sc, st
-
-        def sample(logits, step_key):
-            return _sample_logits(logits, step_key, do_sample, top_k,
-                                  temperature)
-
-        def step(carry, j):
-            ids, pos, ctx, kvp, sc, st, _ = carry
-            logits, kvp, sc, st = one_pass(ids, pos, ctx, kvp, sc, st)
-            nxt = sample(logits, jax.random.fold_in(key, j))
-            return (nxt, pos + 1, ctx + 1, kvp, sc, st, logits), ids
-
-        V = weights["embed"].shape[0]
-        init_logits = jnp.zeros((ids0.shape[0], V), jnp.float32)
-        kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)
-        sc0 = kv_sc.reshape(L * NB * r8 * 128) if kvq else None
-        (_, _, _, kvp, sc, st, final_logits), out_ids = jax.lax.scan(
-            step, (ids0, positions0, ctx0, kvp0, sc0, st0, init_logits),
-            jnp.arange(n_steps))
-        new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
-        if kvq:
-            new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
-        return (out_ids, final_logits, _state_pack(new_kv, st))
-
-    if spec.mamba is None:
-        return fwd
-
-    def fwd_state(weights, kv_pages, ids0, positions0, block_tables, ctx0,
-                  key, temperature=1.0, state_slots=None):
-        """The side-buffer form's signature: the rows' state slots by name."""
-        return fwd(weights, kv_pages, ids0, positions0, block_tables, ctx0,
-                   key, temperature, state_slots=state_slots)
-
-    return fwd_state

@@ -105,11 +105,12 @@ class DynamicSplitFuseScheduler:
 
     def ring_covers(self, n_tokens: int) -> bool:
         """True iff a consumer may freeze page reads while writing
-        ``n_tokens`` ahead (the side-buffer multistep schedule's flush
-        pattern): the ring spans window + _pass_take_cap live tokens, so a
-        frozen chunk is safe only when its whole write fits in the take the
-        ring was sized for. Without a window there is no ring — always
-        True."""
+        ``n_tokens`` ahead (the decode step's side buffer: the pool frozen
+        through the layers, the step's token written after them — and the
+        next step's reserved — so ``ring_covers(2)``): the ring spans window
+        + _pass_take_cap live tokens, so a frozen read is safe only when the
+        whole write fits in the take the ring was sized for. Without a
+        window there is no ring — always True."""
         if self.window is None:
             return True
         return n_tokens <= self._pass_take_cap
@@ -374,7 +375,7 @@ class DynamicSplitFuseScheduler:
         """Bucketed decode-only descriptors for the fused decode programs.
 
         Reserves ``n_reserve`` tokens of KV per sequence UP FRONT (so the
-        per-step host work during a fused burst / pipelined run is just the
+        per-step host work during a pipelined run is just the
         ``DecodeBatch.advance`` increments — the block tables already cover
         the whole run), then packs positions/block-tables/context-lengths
         into arrays padded to ``next_pow2(len(uids))`` rows. Pad rows point

@@ -22,8 +22,8 @@ One kernel body serves every program that reads the pool:
 - decode rows (scope ``mla_decode``): one query token a sequence, its ``H``
   heads the ``M`` dimension of both products (``[H, W] x [W, tokens]`` and
   ``[H, tokens] x [tokens, kv_lora_rank]``); optionally a per-sequence SIDE
-  slab of freshly decoded rows (the fused multistep schedule: the pool is
-  frozen for the chunk of steps) folded into the same online softmax;
+  slab of freshly decoded rows (the decode step's side buffer: the pool is
+  frozen through the step's layers) folded into the same online softmax;
 - prompt-chunk and verify rows (scope ``mla_chunk``): ``Cs`` query tokens a
   slot, row ``r`` of the slot's ``Cs * H`` query rows being token ``r // H``,
   causal by absolute position, in blocks of query rows.
@@ -314,7 +314,7 @@ def mla_paged_attention_reference(q, pool, block_tables, q_pos0, ctx_lens, *,
 
 
 # --------------------------------------------------------------------------- #
-# row write: the fused decode schedule's chunk-end flush (scope ``kv_flush``)
+# row write: the decode step's write after its layers (scope ``kv_flush``)
 # --------------------------------------------------------------------------- #
 
 

@@ -296,7 +296,7 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
     the current token's attention term folds in from registers at finalize;
     without them the pages hold everything (ctx tokens).
 
-    ``sidek_ref/sidev_ref`` (side-slab mode — the fused multistep schedule):
+    ``sidek_ref/sidev_ref`` (side-slab mode — the decode step's side buffer):
     the pages hold the FROZEN prefix [0, cl) and the per-sequence side slab
     ``[n_side*Hkv, D]`` holds the chunk's freshly decoded K/V rows (row
     cc*Hkv + h = step cc's kv head h, token position cl + cc); at finalize
@@ -826,8 +826,8 @@ def paged_decode_attention_sidebuf(q: jax.Array,
                                    layer_idx=None,
                                    alibi: bool = False) -> jax.Array:
     """Decode attention over a FROZEN paged prefix plus a per-sequence side
-    slab of freshly decoded K/V — the kernel of the scatter-free multistep
-    schedule (``inference/v2/ragged_model._build_multistep_sidebuf``).
+    slab of freshly decoded K/V — the kernel of the decode step's side
+    buffer (``inference/v2/ragged_model._build_decode_sidebuf``).
 
     q:            [S, H, D]         one query per sequence (step j's token)
     kv_pages:     [NB, 2, H_kv, bs, D] frozen prefix pages (K + V combined)
@@ -840,7 +840,7 @@ def paged_decode_attention_sidebuf(q: jax.Array,
                   pulls layer ``layer_idx``'s block directly — the caller
                   avoids a dynamic_slice that would MATERIALISE the layer's
                   [S, C, Hkv, D] slab per call (measured ~150 us/layer of
-                  pure copy traffic in the multistep loop).
+                  pure copy traffic in the decode step).
     j:            int32 scalar      current step within the chunk
     window:       optional static sliding window over position prefix + j
     kv_scales:    [NB, 2, H_kv, bs] f32 — int8 pages: per-token-head dequant

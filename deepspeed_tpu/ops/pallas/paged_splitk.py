@@ -39,7 +39,7 @@ Two implementations, one ladder:
 Caller composition (dispatched through ``AttentionKernelSpec``):
 
 - ragged decode pass: straight ``paged_decode_attention_splitk``.
-- fused decode_step/multistep: scatter-FIRST (the small-D step fallback's
+- fused decode step, in-layer write: scatter-FIRST (the small-D fallback's
   pattern, and exactly ``paged_decode_attention_step_reference``'s
   semantics), then full-context split-K decode — int8 pools get
   quantize-on-write for free because the current token is attended at its

@@ -4,7 +4,7 @@ serving paths.
 Compiled XLA executables and host-side layout tables are cached per
 (shape/config) key; a serving process that sees many distinct keys must evict
 or it leaks executables indefinitely. One helper so every such cache behaves
-identically (inference v2 multistep programs, block-sparse layouts, ...).
+identically (inference v2 decode-step programs, block-sparse layouts, ...).
 
 :func:`next_pow2` is the canonical shape-bucketing function for those cache
 keys: every device program keyed on a *variable* count (live decode rows,

@@ -211,6 +211,29 @@ def setup_summary(top: int = 5) -> str:
             + (", ".join(f"{n} {s:.1f}" for s, n in cost[:top]) or "none"))
 
 
+_roomy = None
+
+
+def with_stack_room(fn):
+    """``fn()``, called under ONE frame too large for a chunk of CPython's
+    frame stack (16 KiB, some thirty frames, given back the moment the frame
+    at its start returns): it gets a chunk of its own size, and every frame
+    above it lives in what is left. Set-up's traces run in it, because a
+    trace is thousands of call chains forty frames deep and a chunk's edge
+    inside them costs a map and an unmap each: the same decode step traced
+    in 0.22 s or 2.4 s by the depth it was called from (PERF.md, PR 45;
+    docs/OBSERVABILITY.md "Set-up and compiles"). Elsewhere a plain call."""
+    global _roomy
+    if _roomy is None:
+        # 65,536 locals that dead code names and nothing ever binds
+        names = " = ".join(f"_{i}" for i in range(1 << 16))
+        scope: dict = {}
+        exec(f"def roomy(fn):\n    if 0:\n        {names} = 0\n"
+             "    return fn()\n", scope)
+        _roomy = scope["roomy"]
+    return _roomy(fn)
+
+
 def backend_compiles() -> int:
     """Programs compiled or loaded from the cache since the listener was
     installed — module-level jits and eager operations included."""

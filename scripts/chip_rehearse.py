@@ -189,7 +189,6 @@ def compile_serving(devices) -> None:
             return LowerOnly(jitted, name, real_w, real_kv, chip)
 
         for cache, label in ((e._step_progs, "decode_step"),
-                             (e._multistep, "multistep"),
                              (e._verify_progs, "verify_step")):
             def get_or_create(key, build, _orig=cache.get_or_create,
                               _label=label):
@@ -205,9 +204,8 @@ def compile_serving(devices) -> None:
         return e
 
     with steered_to_tpu():
-        log("serving: the smoke's engine (windowed), warm-up grid + an "
-            "8-step multistep burst")
-        engine_for(REAL, {}).warmup(burst_steps=(8,))
+        log("serving: the smoke's engine (windowed), warm-up grid")
+        engine_for(REAL, {}).warmup()
         log("serving: the verify step needs a model without a window "
             "(spec x window is refused): max_context = window drops it")
         no_window = chip_smoke.dataclasses.replace(

@@ -86,3 +86,51 @@ def test_numactl_cmd_shape():
     argv, cores = get_numactl_cmd("0-7", num_local_procs=2, local_rank=1)
     assert argv[0] == "numactl" and "-C" in argv
     assert cores == [4, 5, 6, 7]
+
+
+def _engine_has_no_burst():
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+    assert not hasattr(InferenceEngineV2, "decode_steps")
+    assert hasattr(InferenceEngineV2, "decode_pipeline")
+
+
+def _warmup_takes_no_burst_lengths():
+    import inspect
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+    assert list(inspect.signature(InferenceEngineV2.warmup).parameters) == [
+        "self", "buckets", "spec_ks"]
+
+
+def _config_has_no_burst_lengths():
+    from deepspeed_tpu.inference.v2.config_v2 import (
+        CompileConfig, RaggedInferenceEngineConfig)
+    gone = "warmup_" + "decode_steps"   # in two: no file spells the option
+    with pytest.raises(TypeError, match=gone):
+        CompileConfig(**{gone: [4]})
+    with pytest.raises(TypeError, match=gone):
+        RaggedInferenceEngineConfig.load(
+            {"compile": {"warmup": True, gone: [4]}})
+
+
+def _no_builder_takes_a_step_count():
+    import inspect
+    from deepspeed_tpu.inference.v2 import ragged_mla, ragged_model
+    builders = {f"{m.__name__.rsplit('.', 1)[1]}.{n}": f
+                for m in (ragged_model, ragged_mla)
+                for n, f in vars(m).items()
+                if n.lstrip("_").startswith("build_") and callable(f)}
+    assert "ragged_model.build_decode_step" in builders
+    assert "ragged_mla.build_decode_step" in builders
+    assert not [n for n in builders if "multistep" in n]
+    for name, f in builders.items():
+        assert "n_steps" not in inspect.signature(f).parameters, name
+
+
+@pytest.mark.parametrize("check", [
+    _engine_has_no_burst, _warmup_takes_no_burst_lengths,
+    _config_has_no_burst_lengths, _no_builder_takes_a_step_count],
+    ids=lambda f: f.__name__.lstrip("_"))
+def test_one_program_decodes_a_token(check):
+    """The n-step burst (PR 45): no method, warm-up family, option or builder
+    argument chooses how many tokens one dispatch decodes — one."""
+    check()

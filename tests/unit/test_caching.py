@@ -1,5 +1,5 @@
 """utils/caching: the shape-bucketing helper and the bounded LRU that every
-long-lived serving cache (multistep programs, decode-step programs) rides."""
+long-lived serving cache (decode-step programs, verify-step programs) rides."""
 
 import threading
 
@@ -67,7 +67,7 @@ def test_lru_hit_refreshes_recency():
 
 
 def test_lru_eviction_never_invalidates_inflight_value():
-    """The engine contract: decode_steps/_decode_step_prog take a strong
+    """The engine contract: the pipeline (_decode_step_prog) takes a strong
     reference to the cached program BEFORE dispatching, so eviction (another
     key landing while the program is mid-flight) must never break the held
     value. Python reference semantics guarantee it — this pins the contract

@@ -401,8 +401,87 @@ def _mistral_7b(arr, L, weight=None):
     return spec, weights
 
 
+def _compile_decode_step(spec, weights, kv, rows, max_blocks, **build):
+    """The fused decode step of ``rows`` rows compiled for the chip the
+    shapes are on; a model with state-space layers is handed its rows'
+    state slots."""
+    from deepspeed_tpu.inference.v2.ragged_model import build_decode_step
+    arr = _on(weights["embed"].sharding)
+    state = (arr(I32, rows),) if spec.mamba is not None else ()
+    return jax.jit(build_decode_step(spec, **build), donate_argnums=(1,)
+                   ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
+                           arr(I32, rows, max_blocks), arr(I32, rows),
+                           arr(jnp.uint32, 2), arr(F32), *state).compile()
+
+
+#: family -> (its stage, decode rows, pages a sequence, what builds the step)
+_DECODE_STEPS = {
+    "mistral": (lambda arr: _mistral_7b(arr, 16) + (
+        arr(BF16, 16, 792, 2, HKV, BS, D),), 32, MB,
+        {"window_ring_ok": True}),
+    "jamba": (lambda arr: _jamba2_3b(arr), 128, 96, {}),
+    "joyai": (lambda arr: _joyai_flash(arr), 32, 80, {}),
+    "granite": (lambda arr: _granite_stage(arr), 64, 80, {}),
+    "nemotron": (lambda arr: _nemotron_stage(arr), 128, 96, {}),
+}
+
+
+@pytest.fixture(scope="module")
+def compiled_step(v5e):
+    """``family -> (compiled decode step, spec, pools)`` at the sizes the
+    benchmark's cells run, each compiled once for every test of this file
+    that reads it (the caller turns the kernels' interpreter off first)."""
+    built = {}
+
+    def get(family):
+        if family not in built:
+            stage, rows, max_blocks, build = _DECODE_STEPS[family]
+            spec, weights, kv = stage(_on(SingleDeviceSharding(v5e[0])))
+            built[family] = (_compile_decode_step(
+                spec, weights, kv, rows, max_blocks, **build), spec, kv)
+        return built[family]
+
+    return get
+
+
+#: the Mosaic calls of each family's decode step (PERF.md section 3's table
+#: of names: the paged kernels, the row write under ``kv_flush``, the state
+#: kernels, the latent ones, the experts' grouped product)
+_DECODE_STEP_CALLS = {
+    "mistral": {"paged_decode_sidebuf", "paged_kv_row_write"},
+    "jamba": {"paged_decode_sidebuf", "paged_kv_row_write",
+              "ssm_decode_step"},
+    "joyai": {"mla_decode", "mla_row_write", "moe_grouped_matmul"},
+    "granite": {"paged_decode_sidebuf", "paged_kv_row_write",
+                "ssd_decode_step", "moe_grouped_matmul"},
+    "nemotron": {"paged_decode_sidebuf", "paged_kv_row_write",
+                 "ssd_decode_step", "moe_grouped_matmul"},
+}
+
+
+@pytest.mark.parametrize("family", sorted(_DECODE_STEPS))
+def test_decode_step_loops_over_its_layers_and_nothing_else(
+        family, compiled_step, monkeypatch):
+    """One program decodes one token (PR 45 took the loop over steps out of
+    the builders; the lowered text changed with it, so the recorded hashes
+    cannot speak across that PR): the compiled step's ``while`` loops are its
+    scans over units of layers that repeat — the compiler unrolls a unit of
+    one — and its Mosaic calls are the family's kernels by name."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    compiled, spec, _ = compiled_step(family)
+    text = compiled.as_text()
+    loops = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? while\(", text, re.M)
+    assert len(loops) == sum(n > 1 for _, _, n in rm.layer_units(spec)), loops
+    mosaic = {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+    assert mosaic == _DECODE_STEP_CALLS[family]
+
+
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
-def test_decode_step_writes_kv_rows_in_place(pool, v5e, monkeypatch):
+def test_decode_step_writes_kv_rows_in_place(pool, v5e, compiled_step,
+                                             monkeypatch):
     """The serving decode step at Mistral-7B width (16 layers, 32 rows,
     pages of 128 tokens, a pool of 792 pages). Its K/V write used to gather
     two whole pages per sequence per layer (``[L, S, 2, 2, Hkv, bs, D]``,
@@ -411,21 +490,18 @@ def test_decode_step_writes_kv_rows_in_place(pool, v5e, monkeypatch):
     rows; no instruction produces a value of that size, nothing copies the
     pool, the pool (and an int8 pool's scale tiles) is the output's
     buffer, and what the program keeps besides its arguments is small."""
-    from deepspeed_tpu.inference.v2.ragged_model import build_decode_step
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     L, rows, pages = 16, 32, 792
-    chip = SingleDeviceSharding(v5e[0])
-    arr = _on(chip)
-    spec, weights = _mistral_7b(arr, L)
     kv_dtype = BF16 if pool == "bf16" else I8
-    kv = arr(kv_dtype, L, pages, 2, HKV, BS, D)
-    if pool == "int8":
-        kv = (kv, arr(F32, L, *kv_scale_tiles_shape(pages, HKV, BS)))
-    compiled = jax.jit(
-        build_decode_step(spec, window_ring_ok=True), donate_argnums=(1,)
-    ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
-            arr(I32, rows, MB), arr(I32, rows),
-            arr(jnp.uint32, 2)).compile()
+    if pool == "bf16":
+        compiled = compiled_step("mistral")[0]
+    else:
+        arr = _on(SingleDeviceSharding(v5e[0]))
+        spec, weights = _mistral_7b(arr, L)
+        kv = (arr(I8, L, pages, 2, HKV, BS, D),
+              arr(F32, L, *kv_scale_tiles_shape(pages, HKV, BS)))
+        compiled = _compile_decode_step(spec, weights, kv, rows, MB,
+                                        window_ring_ok=True)
     text = compiled.as_text()
     assert "paged_kv_row_write" in text, "the row writer is not in the program"
     pool_elems = L * pages * 2 * HKV * BS * D
@@ -642,6 +718,7 @@ def _state_pool_values(text, kv):
 @pytest.mark.parametrize("program", ["serve_decode_step",
                                      "serve_prefill_packed"])
 def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
+                                                        compiled_step,
                                                         monkeypatch):
     """AI21-Jamba2-3B at its published widths, all 28 layers: the 128-row
     decode step and the packed prefill pass (4 slots of 256). Both state
@@ -655,17 +732,11 @@ def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
     from deepspeed_tpu.inference.v2 import ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
-    spec, weights, kv = _jamba2_3b(arr)
-    assert [n for _, _, n in rm.layer_runs(spec)] == [7, 1, 13, 1, 6]
     if program == "serve_decode_step":
-        rows = 128
-        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
-                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
-                                   arr(I32, rows, 96), arr(I32, rows),
-                                   arr(jnp.uint32, 2), arr(F32),
-                                   arr(I32, rows)).compile()
+        compiled, spec, kv = compiled_step("jamba")        # 128 rows
         kernel = "ssm_decode_step"
     else:
+        spec, weights, kv = _jamba2_3b(arr)
         host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=128,
                            max_blocks=96).device_arrays()
         batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
@@ -674,6 +745,7 @@ def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
         compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
                            ).lower(weights, kv, batch).compile()
         kernel = "ssm_chunk_scan"
+    assert [n for _, _, n in rm.layer_runs(spec)] == [7, 1, 13, 1, 6]
     text = compiled.as_text()
     assert kernel in text, "the state kernel is not in the program"
     moved = _state_pool_values(text, kv)
@@ -712,7 +784,7 @@ def _joyai_flash(arr, pages=700):
                                      "serve_prefill_packed",
                                      "serve_paged_pass"])
 def test_joyai_programs_keep_the_latent_pool_and_the_weights_in_place(
-        program, v5e, monkeypatch):
+        program, v5e, compiled_step, monkeypatch):
     """JoyAI-LLM-Flash as the benchmark's configuration runs it: all 40
     layers at published widths, 16 of 256 experts held (8.90 GiB of weights)
     and 700 latent pages (4.28 GiB): the 32-row decode step, the packed
@@ -732,11 +804,7 @@ def test_joyai_programs_keep_the_latent_pool_and_the_weights_in_place(
     host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=32,
                        max_blocks=80).device_arrays()
     if program == "serve_decode_step":
-        rows = 32
-        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
-                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
-                                   arr(I32, rows, 80), arr(I32, rows),
-                                   arr(jnp.uint32, 2), arr(F32)).compile()
+        compiled = compiled_step("joyai")[0]                # 32 rows
         kernels = ("mla_decode", "mla_row_write")
     elif program == "serve_prefill_packed":
         batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
@@ -1016,6 +1084,7 @@ def _granite_stage(arr):
                                      "serve_prefill_packed",
                                      "serve_paged_pass"])
 def test_granite_programs_update_the_state_pools_in_place(program, v5e,
+                                                          compiled_step,
                                                           monkeypatch):
     """granite-4.0-h-small's stage 0 at published widths: the 64-row decode
     step, the packed prefill pass (4 slots of 256) and the paged pass. Both
@@ -1035,11 +1104,7 @@ def test_granite_programs_update_the_state_pools_in_place(program, v5e,
     host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=rows,
                        max_blocks=pages).device_arrays()
     if program == "serve_decode_step":
-        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
-                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
-                                   arr(I32, rows, pages), arr(I32, rows),
-                                   arr(jnp.uint32, 2), arr(F32),
-                                   arr(I32, rows)).compile()
+        compiled = compiled_step("granite")[0]              # 64 rows
         kernels, limit = ("ssd_decode_step",), 64 << 20
     elif program == "serve_prefill_packed":
         batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None
@@ -1105,7 +1170,7 @@ def _nemotron_stage(arr):
                                      "serve_prefill_packed",
                                      "serve_paged_pass"])
 def test_nemotron_programs_scan_units_and_update_the_pools_in_place(
-        program, v5e, monkeypatch):
+        program, v5e, compiled_step, monkeypatch):
     """Nemotron 3 Nano's first 16 layers at published widths: the 128-row
     decode step, the packed prefill pass (4 slots of 256) and the paged pass.
     The 16 one-block layers are three scans (``M, E, (MEM*EME) x 2``), not
@@ -1129,11 +1194,7 @@ def test_nemotron_programs_scan_units_and_update_the_pools_in_place(
     host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=rows,
                        max_blocks=pages).device_arrays()
     if program == "serve_decode_step":
-        compiled = jax.jit(rm.build_decode_step(spec), donate_argnums=(1,)
-                           ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
-                                   arr(I32, rows, pages), arr(I32, rows),
-                                   arr(jnp.uint32, 2), arr(F32),
-                                   arr(I32, rows)).compile()
+        compiled = compiled_step("nemotron")[0]             # 128 rows
         kernels, limit = ("ssd_decode_step",), 512 << 20
     elif program == "serve_prefill_packed":
         batch = {k: arr(I32, 4 * 256 // BS + 4) if host[k] is None

@@ -58,12 +58,11 @@ def warm_engine():
     """One warmed engine shared by the read-mostly tests (compiles are the
     expensive part on this box; tests that need fresh state build their own)."""
     return _build_engine(
-        compile_cfg={"warmup": True, "warmup_buckets": [1, 2, 4],
-                     "warmup_decode_steps": [3]})
+        compile_cfg={"warmup": True, "warmup_buckets": [1, 2, 4]})
 
 
 # --------------------------------------------------------------------------- #
-# correctness: pipeline == fused burst == per-token loop (greedy, with pads)
+# correctness: pipeline == per-token loop (greedy, with pads)
 # --------------------------------------------------------------------------- #
 
 def test_pipeline_matches_loop_with_pad_rows(warm_engine):
@@ -89,16 +88,36 @@ def test_pipeline_matches_loop_with_pad_rows(warm_engine):
     e2.flush([0, 1, 2])
 
 
-def test_warmup_covers_put_and_decode_steps(warm_engine):
-    """put() prefill + continuation passes and an in-grid decode_steps burst
-    (n_steps/buckets from the warmup config) build nothing new."""
+def test_warmup_covers_put_and_a_pipeline_run(warm_engine):
+    """put() prefill + continuation passes and an in-grid pipeline run
+    (buckets from the warmup config) build nothing new."""
     e = warm_engine
     c0 = e.compiles
     e.put([5, 6, 7], PROMPTS)
-    got = e.decode_steps([5, 6, 7], 3)         # (3, bucket 4) pre-warmed
+    got = e.decode_pipeline([5, 6, 7]).run(3)  # bucket 4 pre-warmed
     assert got.shape == (3, 3)
     assert e.compiles == c0
     e.flush([5, 6, 7])
+
+
+def test_warmup_traces_under_the_roomy_frame(warm_engine, monkeypatch):
+    """Every program of the grid is traced somewhere above ONE frame of
+    ``compile_cache.with_stack_room`` (the depth of the stack under a trace
+    sets its speed where frames are kept in small chunks: PERF.md, PR 45)."""
+    import sys
+    seen = []
+
+    def _warmup(buckets, spec_ks):
+        f, names = sys._getframe(), []
+        while f is not None:
+            names.append((f.f_code.co_name, f.f_code.co_nlocals))
+            f = f.f_back
+        seen.append(names)
+        return 0
+
+    monkeypatch.setattr(warm_engine, "_warmup", _warmup)
+    assert warm_engine.warmup(buckets=[1]) == 0
+    assert [n for n, k in seen[0] if k >= 1 << 16] == ["roomy"]
 
 
 # --------------------------------------------------------------------------- #
@@ -108,16 +127,17 @@ def test_warmup_covers_put_and_decode_steps(warm_engine):
 def test_decode_steps_key_rounds_to_bucket():
     e = _build_engine()
     e.put([0, 1, 2], PROMPTS)
-    e.decode_steps([0, 1, 2], 2)               # S=3 -> bucket 4
+    e.decode_pipeline([0, 1, 2]).run(2)        # S=3 -> bucket 4
     c_after_first = e.compiles
-    assert ((2, 4, False, 0, 1) in e._multistep)  # key carries the BUCKET (and split rung)
+    # key carries the BUCKET (then sampling, rank bucket and split rung)
+    assert (4, False, 0, 0, 1) in e._step_progs
     e.put([3], [np.array([9, 9, 9], np.int32)])
-    e.decode_steps([0, 1, 2, 3], 2)            # S=4 -> same bucket, same prog
+    e.decode_pipeline([0, 1, 2, 3]).run(2)     # S=4 -> same bucket, same prog
     assert e.compiles == c_after_first
-    assert len(e._multistep) == 1
+    assert len(e._step_progs) == 1
     # a sequence retiring below the bucket boundary compiles the next bucket
     e.flush([2, 3])
-    e.decode_steps([0, 1], 2)                  # S=2 -> bucket 2: one build
+    e.decode_pipeline([0, 1]).run(2)           # S=2 -> bucket 2: one build
     assert e.compiles == c_after_first + 1
     e.flush([0, 1])
 
@@ -167,7 +187,7 @@ def test_pipeline_on_tokens_retirement(warm_engine):
     ref = {}
     eref = _build_engine()
     eref.put([0, 1, 2], PROMPTS)
-    for u, row in zip([0, 1, 2], eref.decode_steps([0, 1, 2], 6)):
+    for u, row in zip([0, 1, 2], eref.decode_pipeline([0, 1, 2]).run(6)):
         ref[u] = list(row)
 
     retired_at = {}
@@ -304,8 +324,6 @@ def test_compile_cache_placed_from_outside(monkeypatch, tmp_path,
     assert CompileConfig(warmup_buckets=[3, 4, 6]).warmup_buckets == [4, 8]
     with pytest.raises(ValueError):
         CompileConfig(warmup_buckets=[0])
-    with pytest.raises(ValueError):
-        CompileConfig(warmup_decode_steps=[0])
 
 
 def test_second_engine_hits_persistent_cache(monkeypatch, tmp_path,
@@ -440,9 +458,9 @@ def test_generate_zero_new_compiles_in_grid(warm_engine):
 # --------------------------------------------------------------------------- #
 
 def _one_layer_engine(kvq: bool):
-    """head_dim 128 (the side-buffer schedule's gate), ONE layer: a layer's
-    K/V rows depend on no attention output, so the side-buffer schedule and
-    the per-step-write loop must leave the same BYTES in the pool."""
+    """head_dim 128 (the side buffer's gate), ONE layer: a layer's K/V rows
+    depend on no attention output, so the decode step's two forms (the side
+    buffer, the in-layer write) must leave the same BYTES in the pool."""
     cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256,
                       num_hidden_layers=1, num_attention_heads=2,
                       num_key_value_heads=2, max_position_embeddings=512,
@@ -475,10 +493,10 @@ def _pool_bytes(engine):
 def test_step_and_burst_leave_the_per_step_loops_bytes_in_the_pool(
         kvq, monkeypatch):
     """Three live rows in a bucket of four (one pad row), one of them at
-    slot 63 of its page so the next step opens a new page: two pipelined
-    single steps, then a burst of 8. The pool (and an int8 pool's scale
-    tiles) must hold what the per-step-write loop — a row scatter after
-    every step's kernel — leaves there."""
+    slot 63 of its page so the next step opens a new page: two pipeline runs
+    of one step, then a run of 8. The pool (and an int8 pool's scale tiles)
+    must hold what the step's other form — each layer's kernel writing its
+    rows — leaves there."""
     rng = np.random.RandomState(11)
     prompts = [rng.randint(0, 128, size=(n,)).astype(np.int32)
                for n in (63, 17, 70)]
@@ -487,10 +505,8 @@ def test_step_and_burst_leave_the_per_step_loops_bytes_in_the_pool(
     def serve(engine):
         engine.put(uids, list(prompts))
         pipe = engine.decode_pipeline(uids)
-        first = [pipe.run(1) for _ in range(2)]
-        pipe.retire(uids)
-        burst = engine.decode_steps(uids, 8)
-        return np.concatenate(first + [burst], axis=1), _pool_bytes(engine)
+        runs = [pipe.run(n) for n in (1, 1, 8)]
+        return np.concatenate(runs, axis=1), _pool_bytes(engine)
 
     from deepspeed_tpu.inference.v2 import ragged_model
     flushes = []
@@ -499,10 +515,11 @@ def test_step_and_burst_leave_the_per_step_loops_bytes_in_the_pool(
         ragged_model, "paged_kv_row_write",
         lambda *a, **kw: (flushes.append(a[5]), flush(*a, **kw))[1])
     got_ids, got = serve(_one_layer_engine(kvq))
-    assert set(flushes) == {1, 8}           # at each program's tracing
+    assert set(flushes) == {1}              # at the program's tracing
     traced = len(flushes)
-    # a side-buffer budget of nothing sends every program to the general loop
-    monkeypatch.setenv("DSTPU_SIDEBUF_MAX_MB", "0")
+    # the other form: each layer's kernel writes its rows, nothing flushes
+    monkeypatch.setattr(ragged_model, "side_buffer_fits",
+                        lambda *a, **kw: False)
     want_ids, want = serve(_one_layer_engine(kvq))
     assert len(flushes) == traced
     np.testing.assert_array_equal(got_ids, want_ids)

@@ -4,7 +4,7 @@ KV of one attention layer without positions; every FFN a mixture of routed
 experts (of which the engine may hold a share) plus a shared MLP; four plain
 multipliers. Against the plain reference ``chipbench/reference/granite_ref.py``
 through the packed pass, the paged passes, single tokens through the cache,
-the fused decode step and the multistep program; over splits of a prompt, a
+the fused decode step in both its forms; over splits of a prompt, a
 reused state slot, a page boundary; the shares of the experts; what is
 refused."""
 
@@ -264,21 +264,25 @@ def test_decode_rows_reordered_between_runs_keep_their_states(built):
 
 
 @pytest.mark.parametrize("loop", ["side buffer", "general"])
-def test_burst_and_pipeline_give_the_same_tokens(built, loop, monkeypatch):
-    """``decode_steps`` (the multistep program, state carried through its
-    step scan) against the single-step pipeline, in both of its loops."""
+def test_loop_and_pipeline_give_the_same_tokens(built, loop, monkeypatch):
+    """The decode step chained by the pipeline against the per-token loop
+    (``sample_next``/``put``: the ragged pass, which carries the state its
+    own way) and the reference, in both of the step's forms."""
     if loop == "general":
-        monkeypatch.setenv("DSTPU_SIDEBUF_MAX_MB", "0")
+        monkeypatch.setattr(rm, "side_buffer_fits", lambda *a, **kw: False)
     cfg, model, params = built
     p = np.random.default_rng(4).integers(0, 256, 30).astype(np.int32)
     eng = engine_for(model, params)
     eng.put([1], [p])
     eng.put([2], [p])
-    burst = eng.decode_steps([1], 6)[0]
+    looped = []
+    for _ in range(6):
+        looped.append(int(eng.sample_next([1])[0]))
+        eng.put([1], [np.asarray(looped[-1:], np.int32)])
     piped = eng.decode_pipeline([2]).run(6)[0]
-    assert list(burst) == list(piped)
-    want = np.asarray(reference(cfg, params, np.concatenate([p, burst])))
-    assert [int(t) for t in burst] == [
+    assert looped == list(piped)
+    want = np.asarray(reference(cfg, params, np.concatenate([p, piped])))
+    assert [int(t) for t in piped] == [
         int(t) for t in np.argmax(want[29:35], axis=-1)]
 
 

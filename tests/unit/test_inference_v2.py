@@ -361,9 +361,10 @@ class TestEngineV2:
         """Greedy tokens of a tiny Mixtral equal the dense v1 engine's through
         every program that scans the layers: packed prefill + decode step
         (``generate``), the paged pass over a prompt chunked across passes,
-        ``decode_steps`` bursts (general loop; side-buffer loop at head_dim
-        128) and the speculative verify step. All five address a layer's
-        experts inside the whole stacks (``_split_expert_stacks``)."""
+        pipeline runs of the decode step alone (the in-layer write; the side
+        buffer at head_dim 128) and the speculative verify step. All five
+        address a layer's experts inside the whole stacks
+        (``_split_expert_stacks``)."""
         from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
         kw, conf = {}, {k: dict(v) if isinstance(v, dict) else v
                         for k, v in V2_CONFIG.items()}
@@ -386,7 +387,7 @@ class TestEngineV2:
                                 model_parameters=params)
         if path.startswith("bursts"):
             eng.put([1, 2], [np.asarray(p, np.int32) for p in PROMPTS[:2]])
-            ids = eng.decode_steps([1, 2], n)
+            ids = eng.decode_pipeline([1, 2]).run(n)
             out = [p + ids[i].tolist() for i, p in enumerate(PROMPTS[:2])]
         else:
             if path == "paged_chunks":
@@ -588,19 +589,15 @@ def test_int8_weights_logits_close_and_top1_identical(eight_devices):
     assert (lb.argmax(-1) == lq.argmax(-1)).all()
 
 
-def test_int8_weights_decode_and_fetch_false(eight_devices):
+def test_int8_weights_decode_two_runs(eight_devices):
     rng = np.random.RandomState(1)
     eng = _tiny_llama_pair(True)
     toks = [rng.randint(0, 256, size=(20,)).astype(np.int32) for _ in range(2)]
     eng.put([7, 8], list(toks))
-    ids_sync = eng.decode_steps([7, 8], 4)
-    assert ids_sync.shape == (2, 4)
-    dev = eng.decode_steps([7, 8], 4, fetch=False)
-    # fetch=False returns the device array already shaped [S, n_steps]
-    # (ADVICE r4: matching the fetched shape removes the transpose footgun)
-    ids2 = np.asarray(dev)
-    assert ids2.shape == (2, 4)
-    # scheduler advanced for both calls
+    pipe = eng.decode_pipeline([7, 8])
+    assert pipe.run(4).shape == (2, 4)
+    assert pipe.run(4).shape == (2, 4)
+    # scheduler advanced for both runs
     assert eng.scheduler.seqs[7].seen_tokens == 20 + 8
 
 
@@ -675,15 +672,16 @@ def test_kv_quant_logits_close_and_greedy_match(eight_devices):
 
 @pytest.mark.parametrize("window", [None, 24])
 def test_kv_quant_multistep_matches_per_token(eight_devices, window):
-    """decode_steps over int8 pages (side-buffer schedule; windowed variant
-    exercises the moving-window kernel + ring flush) must greedy-match the
-    per-token loop on the SAME engine config."""
+    """A pipeline run over int8 pages (the decode step's side buffer; the
+    windowed variant exercises the moving-window kernel + the row write on
+    the ring) must greedy-match the per-token loop on the SAME engine
+    config."""
     rng = np.random.RandomState(4)
     toks = [rng.randint(0, 256, size=(20,)).astype(np.int32) for _ in range(2)]
     e1 = _kvq_llama(True, window=window)
     e2 = _kvq_llama(True, window=window)
     e1.put([1, 2], [t.copy() for t in toks])
-    ids_ms = e1.decode_steps([1, 2], 6)
+    ids_ms = e1.decode_pipeline([1, 2]).run(6)
     e2.put([1, 2], [t.copy() for t in toks])
     step_ids = []
     for _ in range(6):

@@ -217,21 +217,25 @@ def test_decode_rows_reordered_between_runs_keep_their_states(built):
 
 
 @pytest.mark.parametrize("loop", ["side buffer", "general"])
-def test_burst_and_pipeline_give_the_same_tokens(built, loop, monkeypatch):
-    """``decode_steps`` (the multistep program, state carried through its
-    step scan) against the single-step pipeline; with no room for the side
-    buffer the burst takes the per-step-write loop, which is handed the
-    rows' state slots the same way."""
+def test_loop_and_pipeline_give_the_same_tokens(built, loop, monkeypatch):
+    """The decode step chained by the pipeline against the per-token loop
+    (``sample_next``/``put``: the ragged pass, which carries the state its
+    own way), in both of the step's forms: turned away from the side buffer
+    it takes the in-layer write, which is handed the rows' state slots the
+    same way."""
     if loop == "general":
-        monkeypatch.setenv("DSTPU_SIDEBUF_MAX_MB", "0")
+        monkeypatch.setattr(rm, "side_buffer_fits", lambda *a, **kw: False)
     cfg, model, params = built
     p = np.random.default_rng(4).integers(0, 256, 30).astype(np.int32)
     eng = engine_for(model, params)
     eng.put([1], [p])
     eng.put([2], [p])
-    burst = eng.decode_steps([1], 6)[0]
+    looped = []
+    for _ in range(6):
+        looped.append(int(eng.sample_next([1])[0]))
+        eng.put([1], [np.asarray(looped[-1:], np.int32)])
     piped = eng.decode_pipeline([2]).run(6)[0]
-    assert list(burst) == list(piped)
+    assert looped == list(piped)
 
 
 # --------------------------------------------------------------------------- #
@@ -371,9 +375,10 @@ def test_recompute_preemption_prefills_again_from_a_zeroed_slot(built):
 # a model without such layers: its programs carry no state
 # --------------------------------------------------------------------------- #
 
-def _llama_programs():
+def _llama_programs(head_dim=16):
     from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    cfg = LlamaConfig.tiny(vocab_size=128, max_position_embeddings=256)
+    cfg = LlamaConfig.tiny(vocab_size=128, max_position_embeddings=256,
+                           hidden_size=4 * head_dim)
     model = LlamaForCausalLM(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
@@ -393,9 +398,8 @@ def _llama_programs():
              "page_rows": i32(4), "page_fill": i32(4)}
     pick = lambda keys: {k: batch[k] for k in keys}
     return spec, weights, kv, {
+        # heads 16 wide take the in-layer write, 128 wide the side buffer
         "serve_decode_step": (rm.build_decode_step(spec), step),
-        "serve_decode_multistep": (rm.build_multistep_decode(spec, 3), step),
-        "serve_decode_general": (rm._build_multistep_general(spec, 2), step),
         "serve_paged_pass": (rm.build_ragged_forward(spec),
                              (pick(rm.PAGED_PASS_KEYS),)),
         "serve_prefill_packed": (rm.build_prefill_forward(spec),
@@ -403,13 +407,15 @@ def _llama_programs():
     }
 
 
-@pytest.mark.parametrize("program", [
-    "serve_decode_step", "serve_decode_multistep", "serve_decode_general",
-    "serve_paged_pass", "serve_prefill_packed"])
-def test_programs_of_a_model_without_mamba_layers_carry_no_state(program):
+@pytest.mark.parametrize("program,head_dim", [
+    ("serve_decode_step", 16), ("serve_decode_step", 128),
+    ("serve_paged_pass", 16), ("serve_prefill_packed", 16)])
+def test_programs_of_a_model_without_mamba_layers_carry_no_state(program,
+                                                                 head_dim):
     """They take the bare page pool and their descriptors, and return the
     bare page pool: no argument and no result is a state pool or a slot."""
-    spec, weights, kv, programs = _llama_programs()
+    spec, weights, kv, programs = _llama_programs(head_dim)
+    assert rm.side_buffer_fits(spec, 1, False, None) == (head_dim == 128)
     assert spec.mamba is None and rm.num_state_layers(spec) == 0
     assert rm._pool_bases(spec) == [0]
     fwd, args = programs[program]

@@ -3,8 +3,8 @@ pool of latent rows with no head axis — expanded in the packed prefill,
 absorbed wherever the pool is read — and a sigmoid router over experts of
 which an engine may hold one chip's share; against the plain reference
 ``chipbench/reference/joyai_ref.py``, logits and not tokens, through the
-packed pass, paged chunk passes, single tokens through the cache, the fused
-decode step and the multistep decode; the two forms on one cache; the
+packed pass, paged chunk passes, single tokens through the cache and the
+fused decode step (one pipeline run, and two); the two forms on one cache; the
 latent row's bytes; the shares of an expert layer adding up; and what is
 refused beside latent pages."""
 
@@ -106,7 +106,9 @@ def served(request):
     """One engine a share: a prompt through the packed pass (32 tokens: two
     chunk slots), paged chunk passes (28 more, the last slot part filled),
     four single tokens through the cache; then, as other sequences, the
-    fused decode step and the multistep decode on their own greedy tokens."""
+    fused decode step on its own greedy tokens: one pipeline run of the
+    twelve steps, and two runs (5 and 7: the second reserves anew and goes
+    on from the rows the first wrote)."""
     cfg, model, params = build(request.param)
     eng = engine_for(model, params)
     got = {"packed": (eng.put([1], [IDS[:32]])[0], 31),
@@ -118,8 +120,9 @@ def served(request):
     out = {k: (np.asarray(v), want[row]) for k, (v, row) in got.items()}
     for name, uid, run in (
             ("fused", 2, lambda: eng.decode_pipeline([2]).run(FUSED)[0]),
-            ("multistep", 3, lambda: np.asarray(
-                eng.decode_steps([3], FUSED))[0])):
+            ("fused_two_runs", 3, lambda: np.concatenate(
+                [eng.decode_pipeline([3]).run(n)[0]
+                 for n in (5, FUSED - 5)]))):
         eng.put([uid], [IDS[:FUSED_FROM]])
         toks = np.asarray(run(), np.int32)
         logits = last_logits(eng, uid)
@@ -136,13 +139,13 @@ def served(request):
 
 @pytest.mark.parametrize("phase", ["packed", "paged", "single_60",
                                    "single_61", "single_62", "single_63",
-                                   "fused", "multistep"])
+                                   "fused", "fused_two_runs"])
 def test_engine_logits_match_the_reference(served, phase):
     got, want = served[phase]
     assert np.isfinite(got).all() and err(got, want) <= TOL, err(got, want)
 
 
-@pytest.mark.parametrize("loop", ["fused", "multistep"])
+@pytest.mark.parametrize("loop", ["fused", "fused_two_runs"])
 def test_decode_through_a_page_boundary_chooses_the_reference_tokens(
         served, loop):
     """Twelve steps from position 40: the side buffer's rows land in two

@@ -1,9 +1,12 @@
-"""Fused N-step decode must reproduce the per-token serving loop exactly.
+"""The fused decode step, chained by the pipeline, must reproduce the
+per-token serving loop exactly.
 
 Greedy decode over the v2 engine twice from the same prompt state: once via
-the standard one-pass-per-token loop (sample_next + put), once via the fused
-``decode_steps`` device loop.  Token streams and the engine's continuation
-state (next sample after the window) must match.
+the standard one-pass-per-token loop (sample_next + put), once via
+``decode_pipeline(uids).run(n)`` — n dispatches of the one decode-step
+program.  Token streams and the engine's continuation state (next sample
+after the run) must match. (The file's name is from when one program ran
+the n steps; PR 45 deleted that program and moved its tests here.)
 """
 
 import jax
@@ -48,6 +51,25 @@ def _loop_decode(engine, uids, n):
     return outs
 
 
+def dense_greedy(model, params, prompts, n):
+    """The dense model's greedy continuation of each prompt, ``[len(prompts),
+    n]``: ONE jitted ``forward_logits`` at one padded length for all rows
+    and steps, the logits read at ``len - 1`` (attention is causal, so what
+    lies after a row's tokens is invisible to them)."""
+    lens = np.asarray([len(p) for p in prompts])
+    buf = np.zeros((len(prompts), int(lens.max()) + n), np.int32)
+    for row, p in zip(buf, prompts):
+        row[:len(p)] = p
+    fwd = jax.jit(lambda ids: model.apply(
+        {"params": params}, ids, method=type(model).forward_logits))
+    rows = np.arange(len(prompts))
+    for step in range(n):
+        logits = np.asarray(fwd(buf))
+        buf[rows, lens + step] = np.argmax(
+            logits[rows, lens + step - 1], axis=-1)
+    return np.stack([buf[i, l:l + n] for i, l in enumerate(lens)])
+
+
 def test_decode_steps_matches_loop():
     uids = [0, 1, 2]
     e1 = _build_engine()
@@ -57,7 +79,7 @@ def test_decode_steps_matches_loop():
 
     e2 = _build_engine()
     e2.put(uids, PROMPTS)
-    got = e2.decode_steps(uids, N_STEPS)
+    got = e2.decode_pipeline(uids).run(N_STEPS)
     assert got.shape == (3, N_STEPS)
     for i in range(3):
         assert list(got[i]) == ref[i], (i, list(got[i]), ref[i])
@@ -70,12 +92,13 @@ def test_decode_steps_then_put_continues():
     uids = [0, 1]
     e = _build_engine()
     e.put(uids, PROMPTS[:2])
-    first = e.decode_steps(uids, 3)
+    first = e.decode_pipeline(uids).run(3)
+    assert first.shape == (2, 3)
     nxt = e.sample_next(uids)
     # feed the sampled token through the normal path; engine state must accept it
     logits = e.put(uids, [np.asarray([t], np.int32) for t in nxt])
     assert logits.shape[0] == 2
-    second = e.decode_steps(uids, 2)
+    second = e.decode_pipeline(uids).run(2)
     assert second.shape == (2, 2)
     # lengths consistent: prompt + 3 + 1 + 2 tokens seen
     for u, p in zip(uids, PROMPTS[:2]):
@@ -92,7 +115,7 @@ def test_decode_steps_across_block_boundary():
     ref = _loop_decode(e1, uids, 10)     # crosses 16-token boundary
     e2 = _build_engine(seed=1)
     e2.put(uids, prompt)
-    got = e2.decode_steps(uids, 10)
+    got = e2.decode_pipeline(uids).run(10)
     assert list(got[0]) == ref[0]
 
 
@@ -132,9 +155,9 @@ def test_v2_engine_qwen2_bias_logits():
 
 
 def test_sidebuf_multistep_matches_dense_model(eight_devices):
-    """The scatter-free side-buffer multistep path (head_dim % 128 == 0)
-    must match the dense model's greedy continuation exactly, across page
-    boundaries and with per-sequence context lengths."""
+    """The decode step's side-buffer form (head_dim % 128 == 0), chained by
+    the pipeline, must match the dense model's greedy continuation exactly,
+    across page boundaries and with per-sequence context lengths."""
     cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256,
                       num_hidden_layers=2, num_attention_heads=2,
                       num_key_value_heads=2, max_position_embeddings=128,
@@ -157,29 +180,16 @@ def test_sidebuf_multistep_matches_dense_model(eight_devices):
     prompts = [rng.randint(0, 128, size=(n,)).astype(np.int32) for n in lens]
     uids = [1, 2, 3]
     eng.put(uids, list(prompts))
-    ids = eng.decode_steps(uids, 20)         # crosses 2-3 page boundaries
-    for i, (u, prompt) in enumerate(zip(uids, prompts)):
-        cur = prompt.copy()
-        for step in range(20):
-            lg = model.apply({"params": params}, cur[None],
-                             method=type(model).forward_logits)
-            nxt = int(np.argmax(np.asarray(lg[0, -1])))
-            assert nxt == ids[i][step], (u, step, nxt, ids[i][step])
-            cur = np.concatenate([cur, [nxt]])
-    # and the flushed pools must let a SECOND burst continue correctly
-    ids2 = eng.decode_steps(uids, 6)
-    for i, (u, prompt) in enumerate(zip(uids, prompts)):
-        cur = np.concatenate([prompt, ids[i]])
-        for step in range(6):
-            lg = model.apply({"params": params}, cur[None],
-                             method=type(model).forward_logits)
-            nxt = int(np.argmax(np.asarray(lg[0, -1])))
-            assert nxt == ids2[i][step], (u, step)
-            cur = np.concatenate([cur, [nxt]])
+    ids = eng.decode_pipeline(uids).run(20)  # crosses 2-3 page boundaries
+    # and the written pools must let a SECOND run continue correctly
+    ids2 = eng.decode_pipeline(uids).run(6)
+    want = dense_greedy(model, params, prompts, 26)
+    np.testing.assert_array_equal(ids, want[:, :20])
+    np.testing.assert_array_equal(ids2, want[:, 20:])
 
 
 # --------------------------------------------------------------------------- #
-# the chunk-end K/V write: rows, not pages
+# the K/V row write after the layers: rows, not pages
 # --------------------------------------------------------------------------- #
 
 _L, _NB, _HKV, _BS, _D, _MB = 2, 8, 2, 128, 128, 6
@@ -248,10 +258,11 @@ def _reference_row_write(pool, scales, side_k, side_v, bt, prefix, C):
                                   "past_table"])
 def test_kv_flush_writes_rows_like_a_row_by_row_reference(case, C,
                                                           pool_dtype):
-    """Pool bytes after a decode step (C = 1) and after a burst (C = 8),
-    twice in a row so the second write continues where the first stopped,
-    equal a row-by-row reference write: same values, same dtype, every
-    other byte of the pool (and of an int8 pool's scale tiles) untouched."""
+    """Pool bytes after a decode step (C = 1) and after a slab of 8 steps
+    (the kernel keeps its step axis: ROADMAP D4b), twice in a row so the
+    second write continues where the first stopped, equal a row-by-row
+    reference write: same values, same dtype, every other byte of the pool
+    (and of an int8 pool's scale tiles) untouched."""
     import ml_dtypes
     from deepspeed_tpu.ops.pallas.paged_attention import (
         _scale_tile_rows, paged_kv_row_write)

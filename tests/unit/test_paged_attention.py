@@ -247,8 +247,8 @@ class TestPackedFlash:
 
 
 class TestPagedDecodeSidebuf:
-    """Fused frozen-prefix + side-slab decode kernel (the side-buffer
-    multistep schedule's attention body). Reference = the round-4 two-piece
+    """Fused frozen-prefix + side-slab decode kernel (the attention body of
+    the decode step's side-buffer form). Reference = the round-4 two-piece
     computation: paged prefix with lse, dense side piece, lse merge."""
 
     @pytest.mark.parametrize("Hkv,j", [(2, 0), (2, 3), (4, 5), (8, 7)])

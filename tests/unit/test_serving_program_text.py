@@ -1,22 +1,30 @@
 """The serving programs of the families the benchmark runs, as lowered text.
 
 Five families at toy widths (dense llama lineage, Mixtral, Trinity's afmoe,
-Jamba, JoyAI; and granite, recorded later: ``LATER``) x four programs (decode step, fused multistep, paged pass,
-packed prefill), lowered for the CPU — where the Pallas kernels lower as
-their interpreted bodies, so the kernels' own text is held too — and hashed.
-``data/serving_program_text.json`` holds the hashes of the commit before
-PR 39 (12c8bb9): a change that means to leave these programs as they are
-(a new family beside them, a spec field that is None for them) passes
-without touching that file. A change that means to change them writes the
-file anew and says so::
+Jamba, JoyAI; and granite, recorded later: ``LATER``) x three programs
+(decode step, paged pass, packed prefill), lowered for the CPU — where the
+Pallas kernels lower as their interpreted bodies, so the kernels' own text is
+held too — and hashed. ``data/serving_program_text.json`` holds the hashes of
+the commit before PR 39 (12c8bb9): a change that means to leave these
+programs as they are (a new family beside them, a spec field that is None
+for them) passes without touching that file. A change that means to change
+them writes the file anew and says so::
 
     PYTHONPATH=. JAX_PLATFORMS=cpu python \
         tests/unit/test_serving_program_text.py --write
 
 (``--write <file> <commit>`` with ``PYTHONPATH`` at a ``git archive`` of
 another commit records that commit's programs: how the file was made;
-``--write <file> <commit> granite`` records that family alone from it and
-merges it into the file: how granite's four were, from 83a3dac).
+``--write <file> <commit> <name>..`` records those families or those
+programs alone and merges them into the file under ``later``: how granite's
+were, from 83a3dac, and how every family's decode step was by PR 45, whose
+tree took the step loop — a ``lax.scan`` of length one around the pass —
+out of that program's text; the paged pass and the packed prefill of all six
+are still the older commits').
+
+Beside the hashes: which of its two forms each family's toy decode step
+takes (``ragged_model.side_buffer_fits``), and that the traced step holds
+no loop but its layer scans.
 """
 
 import hashlib
@@ -35,8 +43,7 @@ FAMILIES = ("llama", "mixtral", "afmoe", "jamba", "joyai")
 #: the commit before PR 42 gave the SSD kernels a group axis (83a3dac, the
 #: file's ``later``): one group lowers to the text it lowered to
 LATER = ("granite",)
-PROGRAMS = ("serve_decode_step", "serve_decode_multistep",
-            "serve_paged_pass", "serve_prefill_packed")
+PROGRAMS = ("serve_decode_step", "serve_paged_pass", "serve_prefill_packed")
 
 
 def tiny(fam, model=None):
@@ -110,7 +117,6 @@ def programs(spec):
         step += (i32(4),)
     return {
         "serve_decode_step": (rm.build_decode_step(spec), step),
-        "serve_decode_multistep": (rm.build_multistep_decode(spec, 3), step),
         "serve_paged_pass": (rm.build_ragged_forward(spec),
                              (pick(rm.PAGED_PASS_KEYS),)),
         "serve_prefill_packed": (rm.build_prefill_forward(spec),
@@ -140,10 +146,82 @@ def test_program_lowers_to_the_recorded_text(fam, program, golden):
         "another jax lowers to other text: write the file anew on the commit "
         "it records, with this jax")
     text = lowered(*tiny(fam), program)
-    commit = golden["later"][fam] if fam in LATER else golden["commit"]
+    later = golden["later"]
+    commit = later.get(program) or later.get(fam) or golden["commit"]
     assert digest(text) == golden["programs"][f"{fam}.{program}"], (
         f"{fam}'s {program} is not the text that {commit} lowers "
         "to: if that is meant, write the file anew (module docstring)")
+
+
+#: the form each family's toy decode step takes: heads 128 wide on one
+#: device take the side buffer, narrower ones the in-layer write; JoyAI's
+#: latent pages have a builder of their own and the question is not asked
+SIDE_BUFFER = {"llama": False, "mixtral": False, "afmoe": False,
+               "jamba": True, "joyai": None, "granite": False}
+
+
+@pytest.mark.parametrize("fam", FAMILIES + LATER)
+def test_the_form_each_familys_decode_step_takes(fam, monkeypatch):
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    spec = tiny(fam)[0]
+    asked = []
+    fits = rm.side_buffer_fits
+    monkeypatch.setattr(rm, "side_buffer_fits", lambda *a: (
+        asked.append(fits(*a)), asked[-1])[1])
+    rm.build_decode_step(spec)
+    assert asked == [] if SIDE_BUFFER[fam] is None else [SIDE_BUFFER[fam]]
+    assert (spec.mla is not None) == (SIDE_BUFFER[fam] is None)
+
+
+def _wide(**kw):
+    """A spec at Mistral-7B's heads (32 over 8, 128 wide, window 4096)."""
+    from deepspeed_tpu.inference.v2.ragged_model import RaggedModelSpec
+    return RaggedModelSpec(**{**dict(
+        family="llama", num_layers=2, hidden_size=4096, num_heads=32,
+        num_kv_heads=8, head_dim=128, vocab_size=128, window=4096), **kw})
+
+
+@pytest.mark.parametrize("spec,tp,ring_ok,lora,fits", [
+    pytest.param({}, 1, True, None, True, id="wide_heads_ring_checked"),
+    pytest.param({"window": None}, 1, False, None, True, id="no_window"),
+    pytest.param({}, 2, True, None, False, id="tensor_parallel"),
+    pytest.param({"head_dim": 64}, 1, True, None, False, id="head_dim_64"),
+    pytest.param({}, 1, True, ("wq", "wv"), False, id="lora_targets"),
+    pytest.param({}, 1, False, None, False, id="window_ring_not_checked"),
+])
+def test_side_buffer_fits(spec, tp, ring_ok, lora, fits):
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    assert rm.side_buffer_fits(_wide(**spec), tp, ring_ok, lora) is fits
+
+
+def _top_level_loops(jaxpr):
+    """Lengths of the loops of a jaxpr that no other loop holds (``None`` for
+    a ``while``); what a call or a jit wraps counts as where the call is, a
+    Pallas kernel's body is its own."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "scan":
+            found.append(eqn.params["length"])
+        elif name == "while":
+            found.append(None)
+        elif name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += _top_level_loops(sub)
+    return found
+
+
+@pytest.mark.parametrize("fam", FAMILIES + LATER)
+def test_the_traced_decode_step_holds_no_loop_but_its_layer_scans(fam):
+    """One program decodes one token: the step's only loops are the scans
+    over its units of layers (``layer_units``), in order, each as long as its
+    unit repeats; no loop over steps holds them."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    spec, weights, kv = tiny(fam)
+    fwd, args = programs(spec)["serve_decode_step"]
+    jaxpr = jax.make_jaxpr(fwd)(weights, kv, *args)
+    assert _top_level_loops(jaxpr.jaxpr) == [
+        n for _, _, n in rm.layer_units(spec)]
 
 
 if __name__ == "__main__":
@@ -155,14 +233,15 @@ if __name__ == "__main__":
     commit = sys.argv[3] if len(sys.argv) > 3 else subprocess.run(
         ["git", "-C", where, "rev-parse", "--short", "HEAD"],
         capture_output=True, text=True).stdout.strip()
-    # ``--write <file> <commit> <family>..``: those families only, merged
-    # into ``<file>`` under ``later`` (a family recorded from a later commit
-    # than the file's own)
+    # ``--write <file> <commit> <name>..``: those families or programs
+    # only, merged into ``<file>`` under ``later`` (recorded from a later
+    # commit than the file's own)
     only = tuple(sys.argv[4:])
+    fams_ = [n for n in only if n in FAMILIES + LATER] or FAMILIES + LATER
     hashes = {}
-    for fam_ in only or FAMILIES + LATER:
+    for fam_ in fams_:
         model_ = tiny(fam_)
-        for program_ in PROGRAMS:
+        for program_ in [n for n in only if n in PROGRAMS] or PROGRAMS:
             hashes[f"{fam_}.{program_}"] = digest(lowered(*model_, program_))
     if only:
         with open(out) as f:

@@ -126,6 +126,25 @@ def test_a_jit_that_calls_a_jit_is_charged_its_seconds_once():
     assert gained["compile/warmup/programs"] == 1       # one module
 
 
+@pytest.mark.parametrize("depth", [0, 40])
+def test_with_stack_room_runs_the_call_under_one_frame_larger_than_a_chunk(
+        depth):
+    """CPython keeps a thread's frames in chunks of 16 KiB; a frame of 65,536
+    locals cannot fit one, so it is given a chunk of its own size and what it
+    calls runs in the rest of it — from whatever depth it is called. The
+    call's value comes back and its exception passes through."""
+    import sys
+
+    def at(n, f):
+        return compile_cache.with_stack_room(f) if n == 0 else at(n - 1, f)
+
+    frame = at(depth, lambda: sys._getframe(1))
+    assert frame.f_code.co_nlocals * 8 > (1 << 14) * 16
+    assert at(depth, lambda: 7) == 7
+    with pytest.raises(ZeroDivisionError):
+        at(depth, lambda: 1 / 0)
+
+
 def test_the_program_table_stops_at_512_names(monkeypatch):
     assert compile_cache.MAX_PROGRAMS == 512
     full = {f"p{i}": {} for i in range(compile_cache.MAX_PROGRAMS - 1)}

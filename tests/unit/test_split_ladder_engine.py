@@ -2,8 +2,8 @@
 steady-state compiles, and rung-invariant token streams.
 
 The engine warms ONE program per pow2 rung ``[1, 2, ..., decode_splits]``
-for every hot-path program family (ragged pass, decode step, multistep
-burst, spec verify), then picks the rung each step from live context
+for every hot-path program family (ragged pass, decode step, spec
+verify), then picks the rung each step from live context
 (``attention.min_ctx_per_split``).  These tests pin the contract at the
 engine boundary: the ladder property, the rung selector's pow2-floor
 arithmetic, zero compiles across rung swaps after ``warmup()``, stream

@@ -113,18 +113,12 @@ def test_windowed_engine_decode_parity_and_ring_bound(eight_devices):
     rng = np.random.RandomState(4)
     prompt = rng.randint(0, 128, size=(40,)).astype(np.int32)
     engine.put([1], [prompt])
-    ids = engine.decode_steps([1], 30)      # ctx 40 -> 70: window slides
+    from tests.unit.test_multistep_decode import dense_greedy
+    ids = engine.decode_pipeline([1]).run(30)   # ctx 40 -> 70: window slides
     seq = engine.scheduler.seqs[1]
     assert len(set(seq.blocks)) <= engine.scheduler.ring_pages
-    cur = prompt.copy()
-    ref_ids = []
-    for _ in range(30):
-        lg = model.apply({"params": params}, cur[None],
-                         method=type(model).forward_logits)
-        nxt = int(np.argmax(np.asarray(lg[0, -1])))
-        ref_ids.append(nxt)
-        cur = np.concatenate([cur, [nxt]])
-    assert np.mean(np.asarray(ref_ids) == ids[0]) >= 0.9
+    ref_ids = dense_greedy(model, params, [prompt], 30)
+    assert np.mean(ref_ids[0] == ids[0]) >= 0.9
 
 
 def test_window_at_or_above_max_context_is_dropped(eight_devices):
@@ -139,7 +133,7 @@ def test_ring_frees_each_physical_page_once(eight_devices):
     engine, _, _ = _windowed_engine()
     rng = np.random.RandomState(5)
     engine.put([1], [rng.randint(0, 128, size=(40,)).astype(np.int32)])
-    engine.decode_steps([1], 30)
+    engine.decode_pipeline([1]).run(30)
     free_before = engine.allocator.free_blocks
     used = len(set(engine.scheduler.seqs[1].blocks))
     engine.flush([1])
@@ -166,16 +160,17 @@ def test_window_one_chunk_boundary_finalizes():
             assert float(jnp.max(jnp.abs(o - o_r))) < 2e-2, (W, ctx)
 
 
-@pytest.mark.parametrize("burst", [1, 8])
+@pytest.mark.parametrize("burst", [24, 8])
 def test_windowed_flush_on_the_ring_leaves_the_per_step_loops_bytes(
         burst, monkeypatch, eight_devices):
-    """A windowed model whose heads are 128 wide serves through the
-    side-buffer schedule (``ring_covers`` holds for 2 and for 9 tokens): two
-    sequences past their window, so every write lands on a ring page that
-    once held an older logical page. After 24 tokens — single pipelined
-    steps, or bursts of 8 — the pool holds, byte for byte, what the
-    per-step-write loop leaves there. One layer: its K/V rows depend on no
-    attention output, so the two schedules must agree exactly."""
+    """A windowed model whose heads are 128 wide serves through the decode
+    step's side buffer (``ring_covers(2)`` holds, and the ring covers each
+    run's reservation): two sequences past their window, so every write
+    lands on a ring page that once held an older logical page. After 24
+    tokens — one pipeline run, or three runs of 8, each reserving anew on the
+    ring — the pool holds, byte for byte, what the step's other form (each
+    layer's kernel writing its rows) leaves there. One layer: its K/V rows
+    depend on no attention output, so the two forms must agree exactly."""
     from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     cfg = LlamaConfig(vocab_size=128, hidden_size=256, intermediate_size=256,
@@ -200,13 +195,11 @@ def test_windowed_flush_on_the_ring_leaves_the_per_step_loops_bytes(
                                       "max_context": 96},
                     "kv_cache": {"block_size": 8}, "dtype": jnp.float32})
         assert engine.spec.window == 16 and engine.spec.head_dim == 128
-        assert engine.scheduler.ring_covers(burst + 1)
+        assert engine.scheduler.ring_covers(2)
         engine.put([1, 2], list(prompts))
-        if burst == 1:
-            ids = engine.decode_pipeline([1, 2]).run(24)
-        else:
-            ids = np.concatenate([engine.decode_steps([1, 2], burst)
-                                  for _ in range(24 // burst)], axis=1)
+        pipe = engine.decode_pipeline([1, 2])
+        ids = np.concatenate([pipe.run(burst) for _ in range(24 // burst)],
+                             axis=1)
         for u in (1, 2):       # the ring wrapped: fewer pages than logical
             seq = engine.scheduler.seqs[u]
             assert len(set(seq.blocks)) <= engine.scheduler.ring_pages
@@ -220,10 +213,11 @@ def test_windowed_flush_on_the_ring_leaves_the_per_step_loops_bytes(
         ragged_model, "paged_kv_row_write",
         lambda *a, **kw: (flushes.append(a[5]), flush(*a, **kw))[1])
     got_ids, got = serve()
-    assert set(flushes) == {burst}
+    assert set(flushes) == {1}
     traced = len(flushes)
-    # a side-buffer budget of nothing sends every program to the general loop
-    monkeypatch.setenv("DSTPU_SIDEBUF_MAX_MB", "0")
+    # the other form: each layer's kernel writes its rows, nothing flushes
+    monkeypatch.setattr(ragged_model, "side_buffer_fits",
+                        lambda *a, **kw: False)
     want_ids, want = serve()
     assert len(flushes) == traced
     np.testing.assert_array_equal(got_ids, want_ids)
