@@ -293,9 +293,10 @@ class InferenceEngineV2:
                 num_layers=num_state_layers(self.spec),
                 num_slots=sm.max_tracked_sequences, d_inner=m["d_inner"],
                 d_state=m["d_state"], d_conv=m["d_conv"],
-                # Mamba-2 convolves x, B and C together
+                # Mamba-2 convolves x, B and C together; a Gated DeltaNet
+                # layer q, k and v (its spec says how many channels)
                 conv_dim=m["d_inner"] + 2 * m["n_groups"] * m["d_state"]
-                if ssd else None)
+                if ssd else m.get("conv_dim"))
             self.scheduler.state_slots = StateSlotAllocator(
                 sm.max_tracked_sequences)
             with _tracer.stage("kv_alloc"):
@@ -431,8 +432,10 @@ class InferenceEngineV2:
             # over all its layers, and which recurrence fills it
             _tracer.note("serve/state/bytes_per_sequence",
                          self.state_config.bytes_per_slot())
-            recurrence = 2 if self.spec.mamba.get("kind") == "mamba2" else 1
-            _tracer.note("serve/state/kind", recurrence)
+            # (1, 2: the Mamba recurrence; 3: the gated delta rule)
+            kind = {"mamba2": 2, "gdn": 3}.get(self.spec.mamba.get("kind"), 1)
+            recurrence = "Gated DeltaNet" if kind == 3 else f"Mamba-{kind}"
+            _tracer.note("serve/state/kind", kind)
         if any(k.block is not None for k in self.spec.layer_kinds or ()):
             # one block a layer: how many layers are each block (what a
             # reader divides a block's share of a step by)
@@ -440,7 +443,7 @@ class InferenceEngineV2:
             for what in sorted(set(whats)):
                 _tracer.note(f"serve/layers/blocks/{what}", whats.count(what))
         state = "" if self.state_config is None else (
-            f"; Mamba-{recurrence} "
+            f"; {recurrence} "
             f"state pool {self.state_config.num_slots}+dump slots x "
             f"{self.state_config.num_layers} layers = "
             f"{self.state_config.total_bytes() / 2**20:.1f} MiB "
@@ -1213,6 +1216,16 @@ class InferenceEngineV2:
         batch = self.scheduler.schedule_pass()
         if batch is None:
             return None
+        if self.state_config is not None:
+            # always-on (tracer.totals; a capture reports what each gained):
+            # the prompt rows and the chunk slots that hold some, pass by
+            # pass — what the passes' state kernels had to do, for a reader
+            # that holds their device time against it
+            _tracer.bump("serve/pass/passes")
+            _tracer.bump("serve/pass/prompt_tokens",
+                         float(batch.chunk_ntok.sum()))
+            _tracer.bump("serve/pass/live_slots",
+                         float((batch.chunk_ntok > 0).sum()))
         arrays = batch.device_arrays()
         # each jitted pass receives only the keys it reads (the two paths are
         # separate jit functions; shipping the other path's descriptors is

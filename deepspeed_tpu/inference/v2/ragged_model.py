@@ -49,6 +49,7 @@ import numpy as np
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
 from deepspeed_tpu.inference.v2.ragged.state_pool import StatefulKV
 from deepspeed_tpu.monitor.trace import tracer as _tracer
+from deepspeed_tpu.ops.pallas.gdn import gdn_chunk_scan, gdn_decode_step
 from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
                                                      plan_visits, row_tile)
 from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -113,6 +114,26 @@ class MambaKind(NamedTuple):
 
     def describe(self) -> str:
         return ("Mamba state-space mixer (no pages), "
+                f"{'MoE' if self.moe else 'dense'} FFN")
+
+
+class DeltaKind(NamedTuple):
+    """The kind of a layer whose mixer is a Gated DeltaNet block (qwen3_next):
+    linear attention whose state a head is a matrix CORRECTED by a delta
+    rule, where a Mamba state decays and takes a rank-one term added. To the
+    pools it is a Mamba layer — no pages, no window, no positions, one slot of
+    the state pool a sequence (``mamba`` is true, and ``RaggedModelSpec.mamba``
+    holds its widths under ``"kind": "gdn"``) — and to the layer loop a kind
+    of its own, with a mixer (:func:`_gdn_mixer`) and scopes (``gdn/..``) of
+    its own."""
+    moe: bool = False       # routed experts (else the dense MLP)
+    window = None
+    rope = False
+    mamba = True
+    block = None
+
+    def describe(self) -> str:
+        return ("Gated DeltaNet delta-rule mixer (no pages), "
                 f"{'MoE' if self.moe else 'dense'} FFN")
 
 
@@ -207,7 +228,7 @@ class RaggedModelSpec:
     # and ``weights["layers"]`` is a tuple with one entry per unit the layer
     # loop scans (:func:`layer_units`): a run's stacked tree, or for a unit of
     # several kinds that repeats a tuple of stacked trees, one a kind
-    layer_kinds: Optional[Tuple[Any, ...]] = None   # Layer-/Mamba-/BlockKind
+    layer_kinds: Optional[Tuple[Any, ...]] = None   # Layer-/Mamba-/Delta-/BlockKind
     # on a run's spec (:func:`layer_runs`) of layers that are one block
     # (:class:`BlockKind`): "mixer" (no FFN follows) or "ffn" (no mixer
     # before it). None: the pair every other layer is
@@ -218,7 +239,12 @@ class RaggedModelSpec:
     # Mamba-1: {"d_inner": E, "d_state": N, "dt_rank": R, "d_conv": K}.
     # Mamba-2 (SSD), told by "kind": "mamba2": {"d_inner": E = H * P,
     # "n_heads": H, "d_head": P, "n_groups": G (1: the kernels' one group),
-    # "d_state": N, "d_conv": K, "chunk": the product form's chunk size}
+    # "d_state": N, "d_conv": K, "chunk": the product form's chunk size}.
+    # Gated DeltaNet (:class:`DeltaKind`), told by "kind": "gdn": {"d_inner":
+    # E = Hv * P, "n_heads": Hv value heads, "d_head": P, "n_key_heads": Hk,
+    # "d_state": N (a key head's width), "d_conv": K, "conv_dim": the
+    # convolved channels (q, k and v: 2 Hk N + E), "chunk": the chunked
+    # scan's chunk}
     mamba: Optional[Dict[str, Any]] = None
     # plain multipliers (granite): on the embedding's output, on each
     # branch's output before it joins the residual stream, on the logits,
@@ -263,55 +289,55 @@ def layer_runs(spec: RaggedModelSpec
 
 def _unit_cuts(kinds: Tuple[Any, ...]) -> List[Tuple[int, int, int]]:
     """``kinds`` cut into repeating units, as ``(first layer, period p,
-    repeats r)``: the fewest units, then the shortest periods. A run of two
-    or more layers of one kind is always a unit of its own (p 1); a stretch
-    of layers that each differ from their neighbours is cut into units of
-    p >= 2 kinds that repeat r >= 2 times, and single layers."""
-    cuts: List[Tuple[int, int, int]] = []
-    n, i = len(kinds), 0
-    while i < n:
-        j = i
-        while j + 1 < n and kinds[j + 1] == kinds[i]:
-            j += 1
-        if j > i:
-            cuts.append((i, 1, j - i + 1))
-            i = j + 1
-            continue
-        # the stretch of single layers from i on
-        j = i
-        while j + 1 < n and kinds[j + 1] != kinds[j] and (
-                j + 2 >= n or kinds[j + 2] != kinds[j + 1]):
-            j += 1
-        end = j + 1
-        best: Dict[int, Tuple[Tuple[int, int], List]] = {end: ((0, 0), [])}
-        for a in range(end - 1, i - 1, -1):
-            cost, rest = best[a + 1]
-            pick = ((cost[0] + 1, cost[1] + 1), [(a, 1, 1)] + rest)
-            for p in range(2, (end - a) // 2 + 1):
-                r = 1
-                while a + (r + 1) * p <= end and kinds[
-                        a + r * p:a + (r + 1) * p] == kinds[a:a + p]:
-                    r += 1
-                for reps in range(2, r + 1):
-                    cost, rest = best[a + reps * p]
-                    cand = (cost[0] + 1, cost[1] + p)
-                    if cand < pick[0]:
-                        pick = (cand, [(a, p, reps)] + rest)
-            best[a] = pick
-        cuts.extend(best[i][1])
-        i = end
-    return cuts
+    repeats r)``: a run of r layers of one kind (p 1), or p >= 2 kinds that
+    repeat r >= 2 times. Of all such cuts, the one with the fewest layer
+    BODIES to trace and compile (a unit costs its period: a scan's body runs
+    each of its p layers once), then the fewest units; of equals, a single
+    layer or a run before a longer period. So maximal runs stay units of
+    their own where a longer period would cost more bodies than it saves
+    (Jamba's ``(7 M, A, 6 M) x 2``: five runs, not a body of fourteen), a
+    stretch of alternating layers becomes units of pairs (nemotron_h: ``M E M
+    E M * ..``), and a period that holds a run is taken where it is cheaper
+    (qwen3_next: ``(D D D A) x 3`` is one scan of four bodies, not six)."""
+    n = len(kinds)
+    # best[a]: ((bodies, units), cuts) for kinds[a:]
+    best: Dict[int, Tuple[Tuple[int, int], List]] = {n: ((0, 0), [])}
+    for a in range(n - 1, -1, -1):
+        pick = None
+        run = 1
+        while a + run < n and kinds[a + run] == kinds[a]:
+            run += 1
+        for r in range(1, run + 1):
+            cost, rest = best[a + r]
+            cand = (cost[0] + 1, cost[1] + 1)
+            if pick is None or cand < pick[0]:
+                pick = (cand, [(a, 1, r)] + rest)
+        for p in range(2, (n - a) // 2 + 1):
+            if len(set(kinds[a:a + p])) == 1:
+                continue            # a run, counted above
+            r = 1
+            while a + (r + 1) * p <= n and kinds[
+                    a + r * p:a + (r + 1) * p] == kinds[a:a + p]:
+                r += 1
+            for reps in range(2, r + 1):
+                cost, rest = best[a + reps * p]
+                cand = (cost[0] + p, cost[1] + 1)
+                if cand < pick[0]:
+                    pick = (cand, [(a, p, reps)] + rest)
+        best[a] = pick
+    return best[0][1]
 
 
 def layer_units(spec: RaggedModelSpec
                 ) -> List[Tuple[Tuple[RaggedModelSpec, ...], int, int]]:
     """The layers as the layer loop scans them: ``(the specs of a unit's p
     layers, the unit's first layer, how many times it repeats)``. A unit of
-    one kind is a run of :func:`layer_runs` and is scanned as ever; where
-    every layer differs from the one before it (nemotron_h: ``M E M E M *
-    E M ..``) maximal runs would be one scan a layer, and a unit of p kinds
-    that repeats is ONE scan whose body runs the p layers in turn
-    (:func:`_unit_cuts`)."""
+    one kind is a run of layers and is scanned as ever; where every layer
+    differs from the one before it (nemotron_h: ``M E M E M * E M ..``)
+    maximal runs would be one scan a layer, and a unit of p kinds that
+    repeats is ONE scan whose body runs the p layers in turn
+    (:func:`_unit_cuts`; qwen3_next: three delta layers and an attention
+    layer, three times)."""
     if spec.layer_kinds is None:
         return [((spec,), 0, spec.num_layers)]
     kinds = tuple(spec.layer_kinds)
@@ -327,9 +353,11 @@ def describe_layer_kinds(spec: RaggedModelSpec) -> str:
     def one(rs, l):
         if kinds is not None:
             return kinds[l].describe()
-        return (MambaKind(rs.moe is not None) if rs.mamba is not None
-                else LayerKind(rs.window, rs.rope_theta is not None,
-                               rs.moe is not None)).describe()
+        if rs.mamba is not None:
+            state = DeltaKind if rs.mamba.get("kind") == "gdn" else MambaKind
+            return state(rs.moe is not None).describe()
+        return LayerKind(rs.window, rs.rope_theta is not None,
+                         rs.moe is not None).describe()
 
     return "; ".join(
         f"layers {l0}-{l0 + len(specs) * n - 1}: "
@@ -540,12 +568,13 @@ def adapt_decoder(params: Dict, config,
     if getattr(config, "attn_scale", None) is not None:
         unsupported.append("attn_scale")
     if unsupported:
-        # attn_scale is a kernel limit; the local layers are not (the spec
-        # carries a kind per layer) but this adapter does not map them
+        # neither is a kernel limit any more: the paged kernels take a score
+        # scale (``spec.attn_scale``; granite's, PR 39) and the spec carries a
+        # kind per layer — this adapter maps neither yet
         raise ValueError(
             f"config features {unsupported} are not served by the ragged "
-            "(paged) attention path: the paged kernels take no score scale "
-            "other than 1/sqrt(head_dim), and this adapter does not map "
+            "(paged) attention path: this adapter maps neither a score scale "
+            "other than 1/sqrt(head_dim) onto spec.attn_scale nor "
             "'local' attention_layers onto the spec's per-layer kinds — "
             "serve through deepspeed_tpu.init_inference (v1 dense engine) "
             "instead")
@@ -1002,6 +1031,131 @@ def adapt_nemotron_h(params: Dict, config,
     return spec, weights
 
 
+def adapt_qwen3_next(params: Dict, config,
+                     max_context: Optional[int] = None
+                     ) -> Tuple[RaggedModelSpec, Dict]:
+    """models/qwen3_next.py param tree (Qwen3NextForCausalLM; Qwen3-Next,
+    ``qwen3_next``), published layout.
+
+    One kind per layer: :class:`DeltaKind` (Gated DeltaNet; ``spec.mamba``
+    with ``"kind": "gdn"``) or a full-attention :class:`LayerKind` with
+    rotation, both over routed experts. What the published layout fuses is
+    taken apart here, once:
+
+    - ``in_proj_qkvz``'s columns (a key head's q, k, its value heads' v and z
+      together) are put in the order ``[q | k | v | z]`` over all heads, so
+      that the convolution's input is the product's first ``2 Hk N + E``
+      columns; ``in_proj_ba``'s likewise ``[b | a]``;
+    - ``q_proj`` (a head's query, then its gate) becomes ``wq`` and the output
+      gate ``wg`` of the branch afmoe's gated attention takes;
+    - the rotation pairs value ``i`` with ``i + rotary_dim / 2`` where the
+      ragged path's pairs ``2i`` with ``2i + 1``: the first ``rotary_dim``
+      columns of each q and k head (and their norms' gains) are interleaved,
+      the same way in both, which leaves every ``q . k`` as it was.
+
+    Every norm but the mixer's own scales by ``1 + w`` (``norm_plus_one``).
+    The router is the softmax one (top-k of the logits, softmax over the
+    chosen = softmax over all, top-k, renormalised); the stacks hold
+    ``config.held`` of its ``num_experts``; the shared expert rides as the
+    layer's ``shared`` expert behind ``shared_gate``."""
+    del max_context
+    kinds = tuple(LayerKind(None, True, True) if config.is_attention_layer(i)
+                  else DeltaKind(True)
+                  for i in range(config.num_hidden_layers))
+    first, count = config.held
+    moe = {"num_experts": config.num_experts,
+           "top_k": config.num_experts_per_tok, "shared_gate": True}
+    if count != config.num_experts:
+        moe["held"] = (first, count)
+    Hk, Hv = config.linear_num_key_heads, config.linear_num_value_heads
+    N, P = config.linear_key_head_dim, config.linear_value_head_dim
+    spec = RaggedModelSpec(
+        family="qwen3_next",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=config.num_attention_heads,
+        num_kv_heads=config.num_key_value_heads,
+        head_dim=config.head_dim,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
+        rotary_dim=config.rotary_dim, norm_plus_one=True,
+        tied_lm_head=False, eps=config.rms_norm_eps, moe=moe,
+        layer_kinds=kinds, dtype=config.dtype,
+        mamba={"kind": "gdn", "d_inner": config.value_dim, "n_heads": Hv,
+               "d_head": P, "n_key_heads": Hk, "d_state": N,
+               "d_conv": config.linear_conv_kernel_dim,
+               "conv_dim": config.conv_dim,
+               "chunk": config.chunk_size} if any(
+                   k.mamba for k in kinds) else None)
+    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
+        spec = layer_runs(spec)[0][0]
+
+    H, D, rd = config.num_attention_heads, config.head_dim, config.rotary_dim
+    # half-split pairs -> interleaved pairs, inside a head's first rd values
+    turn = np.concatenate([np.arange(rd).reshape(2, rd // 2).T.reshape(-1),
+                           np.arange(rd, D)])
+    heads = lambda x, n: x.reshape(x.shape[0], n, -1)
+    R = Hv // Hk
+
+    def swiglu(p):
+        return {"w_gate": p["gate_proj"]["kernel"],
+                "w_up": p["up_proj"]["kernel"],
+                "w_down": p["down_proj"]["kernel"]}
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        ff = lp["mlp"]
+        out = {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
+            "moe": {"router": ff["gate"]["kernel"],
+                    "w_gate": ff["w_gate"], "w_up": ff["w_up"],
+                    "w_down": ff["w_down"],
+                    "shared": swiglu(ff["shared_expert"]),
+                    "shared_gate": ff["shared_expert_gate"]["kernel"]},
+        }
+        if kinds[i].mamba:
+            m = lp["linear_attn"]
+            qkvz = heads(m["in_proj_qkvz"]["kernel"], Hk)
+            ba = heads(m["in_proj_ba"]["kernel"], Hk)
+            flat = lambda x: x.reshape(x.shape[0], -1)
+            out["gdn"] = {
+                "in_proj": jnp.concatenate(
+                    [flat(qkvz[..., :N]), flat(qkvz[..., N:2 * N]),
+                     flat(qkvz[..., 2 * N:2 * N + R * P]),
+                     flat(qkvz[..., 2 * N + R * P:])], axis=1),
+                "in_ba": jnp.concatenate(
+                    [flat(ba[..., :R]), flat(ba[..., R:])], axis=1),
+                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, W]
+                "dt_bias": m["dt_bias"], "A_log": m["A_log"],
+                "norm": m["norm"],
+                "out_proj": m["out_proj"]["kernel"],
+            }
+        else:
+            attn = lp["self_attn"]
+            qg = heads(attn["q_proj"]["kernel"], H)              # [hid, H, 2D]
+            wq = qg[..., :D][..., turn]
+            wk = heads(attn["k_proj"]["kernel"],
+                       config.num_key_value_heads)[..., turn]
+            out.update(
+                wq=wq.reshape(wq.shape[0], -1),
+                wg=qg[..., D:].reshape(qg.shape[0], -1),
+                wk=wk.reshape(wk.shape[0], -1),
+                wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"],
+                q_norm=attn["q_norm"]["weight"][turn],
+                k_norm=attn["k_norm"]["weight"][turn])
+        return out
+
+    stacks = _stack_units(spec, layer)
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": stacks if spec.layer_kinds is not None else stacks[0],
+        "final_norm": {"scale": params["norm"]["weight"]},
+        "lm_head": params["lm_head"]["kernel"],
+    }
+    return spec, weights
+
+
 ADAPTERS: Dict[str, Callable] = {
     # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
     # LlamaConfig features the adapter reads)
@@ -1037,13 +1191,19 @@ ADAPTERS: Dict[str, Callable] = {
     # two-matrix relu2 experts behind a sigmoid router): BlockKind, and the
     # layer loop scans repeating units of the pattern (layer_units)
     "nemotron_h": adapt_nemotron_h,
+    # Gated DeltaNet layers (a delta-rule state in the same pool: DeltaKind,
+    # _gdn_mixer) beside gated attention with 256-wide heads, a quarter of
+    # each rotated; 512 small experts of which this chip may hold a share,
+    # and a shared expert behind a sigmoid gate
+    "qwen3_next": adapt_qwen3_next,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
 #: serve these through the v1 dense engine instead
 _UNSUPPORTED = {
     # gpt_neo scores attention WITHOUT the 1/sqrt(head_dim) factor
-    # (attn_scale=1.0), which the paged kernels do not take. Its alternating
+    # (attn_scale=1.0), which adapt_decoder does not map onto
+    # ``spec.attn_scale`` (the paged kernels take one since PR 39). Its alternating
     # global/local layers are no longer what blocks it: the spec carries a
     # kind per layer (``layer_kinds``); adapt_decoder does not map
     # ``attention_layers`` onto them yet
@@ -1318,7 +1478,9 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     scores every expert by itself, chooses with ``w["expert_bias"]`` added
     and weighs without it (the bias balances load, it is not a weight), over
     the chosen scores' sum if ``route_norm``, times ``route_scale``. A
-    ``w["shared"]`` expert sees every token, unweighted. Experts with a
+    ``w["shared"]`` expert sees every token, unweighted — or, with a
+    ``w["shared_gate"]`` ``[hid, 1]``, times ``sigmoid(x . shared_gate)``
+    (qwen3_next; ``routing["shared_gate"]`` says so). Experts with a
     ``w_gate`` stack are SwiGLUs; without one they are two matrices with the
     plain activation ``routing["act"]`` between them (``"relu2"``:
     nemotron_h; absent: tanh-gelu), and so is a shared expert without one.
@@ -1404,8 +1566,12 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     if "shared" in w:
         with jax.named_scope("shared"):
             sh = w["shared"]
-            out = out + (_swiglu(x, sh) if "w_gate" in sh else
-                         _mm(plain(_mm(x, sh["w_up"])), sh["w_down"]))
+            shared = (_swiglu(x, sh) if "w_gate" in sh else
+                      _mm(plain(_mm(x, sh["w_up"])), sh["w_down"]))
+            if "shared_gate" in w:      # one dot product a token (qwen3_next)
+                shared = shared * jax.nn.sigmoid(
+                    _mm(x, w["shared_gate"]).astype(jnp.float32))
+            out = out + shared
     return out.astype(dtype)
 
 
@@ -1663,6 +1829,107 @@ def _slots_put(flat, rows, values):
     return flat
 
 
+class _ChunkRows(NamedTuple):
+    """Where a pass's chunk slots lie (:func:`_conv_rows`): ``CT`` prompt
+    rows in slots of ``Cs``, each slot's ``mode``, the pool row its state is
+    read from and the one it is written to (the dump slot's unless the slot
+    is its sequence's last of the pass)."""
+    CT: int = 0
+    Cs: int = 0
+    mode: Any = None
+    pool_rows: Any = None
+    store_rows: Any = None
+
+
+def _tail_slots(conv):
+    """The tail pool ``[Lm, NS1, (K-1)*8, Wp/8]`` as one list of all layers'
+    slots (merging the leading dimensions is a view)."""
+    return conv.reshape((-1,) + conv.shape[2:])
+
+
+def _conv_rows(conv, conv2, l, rows: _StateRows, a, conv_w, bias, dtype):
+    """The causal depthwise convolution and SiLU over the rows ``a`` ``[T,
+    W]`` of a layer that keeps a state (:func:`_mamba_mixer`,
+    :func:`_gdn_mixer`), each row's ``K - 1`` predecessors read from the rows
+    before, the slot before or the tail pool ``conv`` (``conv2``:
+    :func:`_tail_slots` of it) at layer ``l``; the chunk slots' new tails are
+    written there (a decode row's shift rides with its recurrence kernel).
+    ``conv_w`` ``[K, W]`` float32, ``bias`` ``[W]`` float32 or 0.0. Returns
+    ``(the convolved rows [T, W] in dtype, conv, the chunk slots'
+    _ChunkRows)``."""
+    K, W = conv_w.shape
+    NS1 = conv.shape[1]
+    dump = NS1 - 1
+    # a slot's tile rows are its K - 1 taps x W channels in order (padded to
+    # whole tiles a tap where W is not), so the rows GATHERED from it reshape
+    # to [n, K - 1, W] (a small copy — reshaping the pool itself so would lay
+    # it out anew, in every layer)
+    Wp = 8 * conv.shape[3]
+    if Wp == W:
+        taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, W)
+        as_taps = lambda t: t.reshape((-1,) + conv.shape[2:])
+    else:
+        taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, Wp)[..., :W]
+        as_taps = lambda t: jnp.pad(
+            t, ((0, 0), (0, 0), (0, Wp - W))).reshape((-1,) + conv.shape[2:])
+    f32 = jnp.float32
+
+    def conv_act(ext):          # [.., K + n - 1, W] inputs -> [.., n, W]
+        n = ext.shape[-2] - (K - 1)
+        acc = bias + sum(ext[..., j:j + n, :].astype(f32) * conv_w[j]
+                         for j in range(K))
+        return jax.nn.silu(acc).astype(dtype)
+
+    parts, chunk = [], _ChunkRows()
+    if rows.chunk_slot is not None:
+        NC = rows.chunk_slot.shape[0]
+        CT = a.shape[0] - (0 if rows.decode_slot is None
+                           else rows.decode_slot.shape[0])
+        Cs = CT // NC
+        mode = rows.chunk_mode
+        a_c = a[:CT].reshape(NC, Cs, W)
+        pool_rows = l * NS1 + rows.chunk_slot
+        # a sequence's last slot of the pass writes back; the others
+        # (and empty slots) write the dump slot
+        last = jnp.concatenate([mode[1:] != 2, jnp.ones((1,), bool)])
+        store_rows = l * NS1 + jnp.where(last, rows.chunk_slot, dump)
+        tail = jnp.where(
+            (mode == 2)[:, None, None],
+            jnp.roll(a_c[:, Cs - (K - 1):], 1, axis=0),
+            jnp.where((mode == 1)[:, None, None], taps(pool_rows), 0))
+        ext = jnp.concatenate([tail.astype(dtype), a_c], axis=1)
+        parts.append(conv_act(ext).reshape(CT, W))
+        new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice(
+            e, (n, 0), (K - 1, W)))(ext, rows.chunk_ntok)
+        conv2 = conv2.at[store_rows].set(
+            as_taps(new_tail.astype(conv.dtype)))
+        chunk = _ChunkRows(CT, Cs, mode, pool_rows, store_rows)
+    if rows.decode_slot is not None:
+        # the rows' tails are read here; their shift by one token rides
+        # with the recurrence kernel
+        drows = l * NS1 + rows.decode_slot
+        ext = jnp.concatenate([taps(drows).astype(dtype),
+                               a[chunk.CT:, None]], axis=1)      # [S, K, W]
+        parts.append(conv_act(ext)[:, 0])
+    conv = conv2.reshape(conv.shape)
+    c = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    return c, conv, chunk
+
+
+def _chunk_states(ssm, rows: _StateRows, chunk: _ChunkRows):
+    """For the chunked scan of a pass's prompt rows: which rows hold a token
+    ``[CT, 1]``, the pool as a list of slots ``[Lm * NS1, N, E]`` and the
+    state each chunk slot starts from (the pool's where ``mode`` is 1, else
+    zero); a slot whose ``mode`` is 2 continues the one before it."""
+    N, E = ssm.shape[2:]
+    live = (jnp.arange(chunk.Cs)[None, :]
+            < rows.chunk_ntok[:, None]).reshape(chunk.CT, 1)
+    flat = ssm.reshape(-1, N, E)
+    h0 = jnp.where((chunk.mode == 1)[:, None, None],
+                   _slots_take(flat, chunk.pool_rows), 0.0)
+    return live, flat, h0
+
+
 def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     """The Mamba mixer on the normed rows ``u`` ``[T, hid]`` of one layer,
     reading and updating its rows' states in the pools ``state = (ssm [Lm,
@@ -1694,27 +1961,12 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     the model's dtype."""
     m, mw = spec.mamba, w["mamba"]
     ssd = m.get("kind") == "mamba2"
-    E, N, K = m["d_inner"], m["d_state"], m["d_conv"]
+    E, N = m["d_inner"], m["d_state"]
     G = m.get("n_groups", 1) if ssd else 1      # groups of heads sharing B, C
     W = E + 2 * G * N if ssd else E      # channels the convolution runs over
     dtype = spec.dtype
     ssm, conv = state
-    NS1 = ssm.shape[1]
-    dump = NS1 - 1
-    # all layers' slots in one list (merging the leading dimensions is a
-    # view); a slot's tile rows are its K - 1 taps x W channels in order
-    # (padded to whole tiles a tap where W is not), so the rows GATHERED
-    # from it reshape to [n, K - 1, W] (a small copy — reshaping the pool
-    # itself so would lay it out anew, in every layer)
-    conv2 = conv.reshape((-1,) + conv.shape[2:])
-    Wp = 8 * conv.shape[3]
-    if Wp == W:
-        taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, W)
-        as_taps = lambda t: t.reshape((-1,) + conv.shape[2:])
-    else:
-        taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, Wp)[..., :W]
-        as_taps = lambda t: jnp.pad(
-            t, ((0, 0), (0, 0), (0, Wp - W))).reshape((-1,) + conv.shape[2:])
+    conv2 = _tail_slots(conv)
     f32 = jnp.float32
     conv_w, conv_b = mw["conv_w"].astype(f32), mw["conv_b"].astype(f32)
 
@@ -1724,46 +1976,10 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
         z, a, dt_in = az[:, :E], az[:, E:E + W], az[:, E + W:]
     else:
         a, z = az[:, :E], az[:, E:]
-
-    def conv_act(ext):          # [.., K + n - 1, W] inputs -> [.., n, W]
-        n = ext.shape[-2] - (K - 1)
-        acc = conv_b + sum(ext[..., j:j + n, :].astype(f32) * conv_w[j]
-                           for j in range(K))
-        return jax.nn.silu(acc).astype(dtype)
-
-    parts, CT = [], 0
     with jax.named_scope("conv"):
-        if rows.chunk_slot is not None:
-            NC = rows.chunk_slot.shape[0]
-            CT = a.shape[0] - (0 if rows.decode_slot is None
-                               else rows.decode_slot.shape[0])
-            Cs = CT // NC
-            mode = rows.chunk_mode
-            a_c = a[:CT].reshape(NC, Cs, W)
-            pool_rows = l * NS1 + rows.chunk_slot
-            # a sequence's last slot of the pass writes back; the others
-            # (and empty slots) write the dump slot
-            last = jnp.concatenate([mode[1:] != 2, jnp.ones((1,), bool)])
-            store_rows = l * NS1 + jnp.where(last, rows.chunk_slot, dump)
-            tail = jnp.where(
-                (mode == 2)[:, None, None],
-                jnp.roll(a_c[:, Cs - (K - 1):], 1, axis=0),
-                jnp.where((mode == 1)[:, None, None], taps(pool_rows), 0))
-            ext = jnp.concatenate([tail.astype(dtype), a_c], axis=1)
-            parts.append(conv_act(ext).reshape(CT, W))
-            new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice(
-                e, (n, 0), (K - 1, W)))(ext, rows.chunk_ntok)
-            conv2 = conv2.at[store_rows].set(
-                as_taps(new_tail.astype(conv.dtype)))
-        if rows.decode_slot is not None:
-            # the rows' tails are read here; their shift by one token rides
-            # with the recurrence kernel below
-            drows = l * NS1 + rows.decode_slot
-            ext = jnp.concatenate([taps(drows).astype(dtype),
-                                   a[CT:, None]], axis=1)        # [S, K, W]
-            parts.append(conv_act(ext)[:, 0])
-    conv = conv2.reshape(conv.shape)
-    c = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        c, conv, chunk = _conv_rows(conv, conv2, l, rows, a, conv_w, conv_b,
+                                    dtype)
+    CT, store_rows = chunk.CT, chunk.store_rows
 
     if ssd:
         # x, B and C are the convolved rows' three parts; a step size and a
@@ -1795,14 +2011,10 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     ys = []
     if rows.chunk_slot is not None:
         with jax.named_scope("scan"):
-            live = (jnp.arange(Cs)[None, :]
-                    < rows.chunk_ntok[:, None]).reshape(CT, 1)
-            flat = ssm.reshape(-1, N, E)
-            h0 = jnp.where((mode == 1)[:, None, None],
-                           _slots_take(flat, pool_rows), 0.0)
+            live, flat, h0 = _chunk_states(ssm, rows, chunk)
             y, hT = chunk_scan(jnp.where(live, dt[:CT], 0.0), cf[:CT],
                                Bm[:CT], Cm[:CT], A, h0,
-                               (mode == 2).astype(jnp.int32))
+                               (chunk.mode == 2).astype(jnp.int32))
             ssm = _slots_put(flat, store_rows, hT).reshape(ssm.shape)
             ys.append(y)
     if rows.decode_slot is not None:
@@ -1825,6 +2037,90 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
             y = y.reshape(-1, E) * mw["norm"].astype(f32)
     else:
         y = (y + mw["D"].astype(f32) * cf) * jax.nn.silu(z.astype(f32))
+    with jax.named_scope("out_proj"):
+        out = _mm(y.astype(dtype), mw["out_proj"])
+    return out, ssm, conv
+
+
+def _gdn_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
+    """The Gated DeltaNet mixer (qwen3_next; ``ops/pallas/gdn.py`` states the
+    recurrence) on the normed rows ``u`` ``[T, hid]`` of one layer, reading
+    and updating its rows' states in the pools ``state`` exactly as
+    :func:`_mamba_mixer` does — the same slots, modes, tails and dump slot
+    (:func:`_conv_rows`, :func:`_chunk_states`) — at layer ``l`` of them.
+    Returns ``(out [T, hid], ssm, conv)``.
+
+    ``in_proj`` gives q, k and v (the convolution's input, ``2 Hk N + E``
+    channels) and the output gate ``z``; ``in_ba`` a value head's ``b`` and
+    ``a``. After the convolution and SiLU, q and k are L2-normalised a head
+    (q scaled by ``N ** -0.5``) and handed on in the model's dtype; ``beta =
+    sigmoid(b)`` and the log-decay ``g = -exp(A_log) softplus(a + dt_bias)``
+    are float32, as are the state and ``o``. Prompt rows take the chunked
+    scan (scope ``gdn/scan``), decode rows the one-token step
+    (``gdn/step``). The mixer's own RMSNorm (plain gain, over each value
+    head's ``P``) comes FIRST, then the gate ``silu(z)`` (``gdn/gate_norm``):
+    Mamba-2's gated norm gates first."""
+    m, mw = spec.mamba, w["gdn"]
+    E, N, Hv, Hk = m["d_inner"], m["d_state"], m["n_heads"], m["n_key_heads"]
+    KD, P = Hk * N, m["d_head"]
+    dtype, f32 = spec.dtype, jnp.float32
+    ssm, conv = state
+    info = jnp.finfo(dtype)
+
+    def held(x):
+        """``x`` (the model's dtype) as float32 values of that dtype. Left to
+        itself the compiler drops the rounding between a product and what
+        reads its result as float32 where it fuses the two (a convert pair:
+        5e-4 of the first layer's state in the chip's check, 2e-7 with the
+        rounding held; PERF.md, PR 47), and then a prompt row's
+        convolution sees other inputs than the tail pool hands a decode row.
+        ``reduce_precision`` is an operation of its own and stays."""
+        return jax.lax.reduce_precision(x.astype(f32), info.nexp, info.nmant)
+
+    with jax.named_scope("in_proj"):
+        az = _mm(u, mw["in_proj"])
+        a, z = held(az[:, :2 * KD + E]), az[:, 2 * KD + E:]
+        ba = held(_mm(u, mw["in_ba"]))
+    with jax.named_scope("conv"):
+        c, conv, chunk = _conv_rows(conv, _tail_slots(conv), l, rows, a,
+                                    mw["conv_w"].astype(f32), 0.0, dtype)
+    CT = chunk.CT
+
+    def unit(x, scale):
+        x = held(x).reshape(-1, Hk, N)
+        x = x * (jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+                 * scale)
+        return x.reshape(-1, KD).astype(dtype)
+
+    q, k, v = unit(c[:, :KD], N ** -0.5), unit(c[:, KD:2 * KD], 1.0), \
+        c[:, 2 * KD:]
+    beta = jax.nn.sigmoid(ba[:, :Hv])
+    g = -jnp.exp(mw["A_log"].astype(f32)) * jax.nn.softplus(
+        ba[:, Hv:] + mw["dt_bias"].astype(f32))
+
+    ys = []
+    if rows.chunk_slot is not None:
+        with jax.named_scope("scan"):
+            live, flat, h0 = _chunk_states(ssm, rows, chunk)
+            y, hT = gdn_chunk_scan(
+                q[:CT], k[:CT], v[:CT], jnp.where(live, g[:CT], 0.0),
+                jnp.where(live, beta[:CT], 0.0), h0,
+                (chunk.mode == 2).astype(jnp.int32),
+                chunk=m.get("chunk", 64))
+            ssm = _slots_put(flat, chunk.store_rows, hT).reshape(ssm.shape)
+            ys.append(y)
+    if rows.decode_slot is not None:
+        with jax.named_scope("step"):
+            y, ssm, conv = gdn_decode_step(
+                ssm, conv, l, rows.decode_slot, g[CT:], beta[CT:], q[CT:],
+                k[CT:], v[CT:], a[CT:])
+            ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    with jax.named_scope("gate_norm"):
+        y = y.reshape(-1, Hv, P)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + spec.eps) * mw["norm"].astype(f32)
+        y = y.reshape(-1, E) * jax.nn.silu(z.astype(f32))
     with jax.named_scope("out_proj"):
         out = _mm(y.astype(dtype), mw["out_proj"])
     return out, ssm, conv
@@ -1891,8 +2187,10 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     elif spec.mamba is not None:
         # a layer whose mixer is no attention: ``attend(normed rows) ->
         # (mixer output [N, hid], *state)`` runs :func:`_mamba_mixer` with
-        # the caller's rows and carried state pools
-        with jax.named_scope("ssm"):
+        # the caller's rows and carried state pools (:func:`_gdn_mixer` for a
+        # Gated DeltaNet layer, under a scope of its own)
+        with jax.named_scope("gdn" if spec.mamba.get("kind") == "gdn"
+                             else "ssm"):
             h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)
             attn_out, *state = attend(h1)
@@ -1930,8 +2228,10 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                 k = k + w["bk"].reshape(Hkv, D)
                 v = v + w["bv"].reshape(Hkv, D)
             if "q_norm" in w:       # RMSNorm over each head's values (afmoe)
-                q = _norm(q, {"scale": w["q_norm"]}, "rms", spec.eps, dtype)
-                k = _norm(k, {"scale": w["k_norm"]}, "rms", spec.eps, dtype)
+                q = _norm(q, {"scale": w["q_norm"]}, "rms", spec.eps, dtype,
+                          spec.norm_plus_one)
+                k = _norm(k, {"scale": w["k_norm"]}, "rms", spec.eps, dtype,
+                          spec.norm_plus_one)
             if spec.rope_theta is not None:
                 q = _rope_flat(q, positions, spec.rope_theta, spec.rotary_dim)
                 k = _rope_flat(k, positions, spec.rope_theta, spec.rotary_dim)
@@ -2187,18 +2487,21 @@ STATE_PASS_KEYS = ("chunk_state_slot", "chunk_state_mode",
 
 def _mamba_body(rs: RaggedModelSpec, positions, rows: _StateRows,
                 experts=None, l0=0):
-    """The scan body of a run of Mamba layers, for every serving program:
+    """The scan body of a run of layers that keep a state (Mamba, or Gated
+    DeltaNet: ``rs.mamba["kind"]``), for every serving program:
     the carry is ``(x, *the program's KV carry, (ssm, conv))``; the KV part
     passes through untouched and ``l`` is the layer's rank among the Mamba
     layers (:func:`_pool_bases`), ``l - l0`` its place in the run's expert
     stacks where its FFN routes experts (``MambaKind(moe=True)``)."""
+    mixer = _gdn_mixer if rs.mamba.get("kind") == "gdn" else _mamba_mixer
+
     def layer_fn(carry, scanned):
         x, *cache, st = carry
         w, l = scanned[:2]
         moe = {} if rs.moe is None else dict(experts=experts, l=l - l0)
         x, st = _transformer_layer(
             rs, w, x, positions,
-            lambda u: _mamba_mixer(rs, w, u, st, l, rows), **moe)
+            lambda u: mixer(rs, w, u, st, l, rows), **moe)
         return (x, *cache, st), None
 
     return layer_fn

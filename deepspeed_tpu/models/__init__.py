@@ -2,9 +2,10 @@
 gpt-neox) plus the inference-container families (opt, falcon, phi, bert) and
 afmoe (Arcee Trinity: layers of several kinds in one model), jamba
 (state-space layers), joyai (JoyAI-LLM-Flash: latent attention), granite
-(IBM Granite 4.0-H: Mamba-2 layers over routed experts) and nemotron_h
-(Nemotron 3 Nano: one block a layer — Mamba-2, experts or attention) —
-matching the reference's model coverage (module_inject/containers,
+(IBM Granite 4.0-H: Mamba-2 layers over routed experts), nemotron_h
+(Nemotron 3 Nano: one block a layer — Mamba-2, experts or attention) and
+qwen3_next (Qwen3-Next: Gated DeltaNet delta-rule layers beside gated
+attention, 512 small experts) — matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
 from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
@@ -19,6 +20,8 @@ from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, init_cache
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
                                              NemotronHForCausalLM)
+from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                             Qwen3NextForCausalLM)
 from deepspeed_tpu.models.diffusion import (DiffusionConfig,
                                             DiffusionPipeline,
                                             init_diffusion_inference)

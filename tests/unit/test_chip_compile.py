@@ -36,6 +36,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     kv_scale_tiles_shape, paged_chunk_attention_batched,
     paged_decode_attention, paged_decode_attention_sidebuf,
     paged_decode_attention_step)
+from deepspeed_tpu.ops.pallas.gdn import gdn_chunk_scan, gdn_decode_step
 from deepspeed_tpu.ops.pallas.ssm import ssd_chunk_scan, ssd_decode_step
 from deepspeed_tpu.ops.pallas.paged_splitk import (
     paged_decode_attention_splitk_pallas, paged_sidebuf_attention_splitk)
@@ -197,6 +198,24 @@ CASES = {
         [((9, 73, 128, 8192), F32), ((9, 73, 24, 1152), F32), ((), I32),
          ((64,), I32), ((64, 128), F32), ((64, 8192), F32), ((64, 128), F32),
          ((64, 128), F32), ((128,), F32), ((64, 8448), BF16)]),
+    # the gated delta-rule kernels at Qwen3-Next's widths: 16 key heads of
+    # 128 serving 32 value heads of 128, a pass of 8 chunk slots of 256 in
+    # chunks of 64 and a 64-row step over the pools of 9 layers x 73 slots
+    "gdn_chunk_scan_hk16_hv32_n128": (
+        gdn_chunk_scan,
+        [((2048, 2048), BF16), ((2048, 2048), BF16), ((2048, 4096), BF16),
+         ((2048, 32), F32), ((2048, 32), F32), ((8, 128, 4096), F32),
+         ((8,), I32)]),
+    "gdn_decode_step_hk16_hv32_n128": (
+        gdn_decode_step,
+        [((9, 73, 128, 4096), F32), ((9, 73, 24, 1024), F32), ((), I32),
+         ((64,), I32), ((64, 32), F32), ((64, 32), F32), ((64, 2048), BF16),
+         ((64, 2048), BF16), ((64, 4096), BF16), ((64, 8192), BF16)]),
+    # 256-wide heads, 16 query heads over 2 KV heads (Qwen3-Next's attention):
+    # the paged decode kernel over 264 pages a sequence
+    "decode_h16_kv2_d256": (
+        paged_decode_attention,
+        [((S, 16, 256), BF16), _pool(2, 256), ((S, 264), I32), _CL]),
 }
 
 
@@ -423,6 +442,7 @@ _DECODE_STEPS = {
     "joyai": (lambda arr: _joyai_flash(arr), 32, 80, {}),
     "granite": (lambda arr: _granite_stage(arr), 64, 80, {}),
     "nemotron": (lambda arr: _nemotron_stage(arr), 128, 96, {}),
+    "qwen3_next": (lambda arr: _qwen3_next_stage(arr), 64, 264, {}),
 }
 
 
@@ -456,6 +476,8 @@ _DECODE_STEP_CALLS = {
                 "ssd_decode_step", "moe_grouped_matmul"},
     "nemotron": {"paged_decode_sidebuf", "paged_kv_row_write",
                  "ssd_decode_step", "moe_grouped_matmul"},
+    "qwen3_next": {"paged_decode_sidebuf", "paged_kv_row_write",
+                   "gdn_decode_step", "moe_grouped_matmul"},
 }
 
 
@@ -1219,3 +1241,91 @@ def test_nemotron_programs_scan_units_and_update_the_pools_in_place(
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < limit
+
+
+def _qwen3_next_stage(arr):
+    """Spec, stacked weight trees (shapes only) and pools of
+    Qwen3-Next-80B-A3B as the benchmark's configuration runs it: published
+    layers 0-11 (three periods of 3 Gated DeltaNet layers and 1 attention
+    layer) at published widths, 64 of 512 experts held, ids 0-18,991 of the
+    vocabulary (5.46 GiB of weights), 8,000 pages over the three attention
+    layers (256-wide heads, 64 values rotated) and the state pool of 72 + 1
+    slots over the nine delta layers (1.34 GiB)."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
+                                                              StatePoolConfig)
+    from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                 Qwen3NextForCausalLM)
+    cfg = Qwen3NextConfig.qwen3_next_80b_a3b(
+        num_hidden_layers=12, experts_held=(0, 64), vocab_size=18992,
+        dtype=BF16)
+    model = Qwen3NextForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_qwen3_next(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    pool = StatePoolConfig(num_layers=9, num_slots=72, d_inner=4096,
+                           d_state=128, d_conv=4, conv_dim=8192)
+    ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
+    kv = StatefulKV(arr(BF16, 3, 8001, 2, 2, BS, 256),
+                    arr(F32, *ssm_shape.shape), arr(F32, *conv_shape.shape))
+    return spec, weights, kv
+
+
+@pytest.mark.parametrize("program", ["serve_decode_step",
+                                     "serve_prefill_packed",
+                                     "serve_paged_pass"])
+def test_qwen3_next_programs_hold_the_delta_kernels_and_the_pools_in_place(
+        program, v5e, compiled_step, monkeypatch):
+    """Qwen3-Next's first 12 layers at published widths: the 64-row decode
+    step, the packed prefill pass (8 slots of 256) and the paged pass. Both
+    delta-rule kernels are where they belong, the attention is the paged
+    kernels' at 256-wide heads (64 values rotated before them), the 64 held
+    experts' products (2 MiB matrices) are the Pallas grouped matmul's and no
+    stack is copied; the pools (1.27 GiB of states, 62 MiB of tails, 5.86 GiB
+    of pages) are the outputs' buffers and the temporaries stay small."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _qwen3_next_stage(arr)
+    assert rm.num_page_layers(spec) == 3 and rm.num_state_layers(spec) == 9
+    assert spec.head_dim == 256 and spec.rotary_dim == 64
+    rows, pages, slots = 64, 264, 8
+    host = RaggedBatch(num_slots=slots, slot_size=256, max_sequences=rows,
+                       max_blocks=pages).device_arrays()
+    if program == "serve_decode_step":
+        compiled = compiled_step("qwen3_next")[0]           # 64 rows
+        kernels, limit = ("gdn_decode_step",), 128 << 20
+    elif program == "serve_prefill_packed":
+        batch = {k: arr(I32, slots * 256 // BS + slots) if host[k] is None
+                 else arr(I32, *host[k].shape)
+                 for k in rm.PREFILL_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels, limit = ("gdn_chunk_scan",), 1024 << 20
+    else:
+        batch = {k: arr(I32, *host[k].shape)
+                 for k in rm.PAGED_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_ragged_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        kernels, limit = ("gdn_chunk_scan", "gdn_decode_step"), 1024 << 20
+    text = compiled.as_text()
+    for kernel in kernels + ("moe_grouped_matmul",):
+        assert kernel in text, f"{kernel} is not in the program"
+    assert "ragged-dot" not in text and "mini-gather" not in text
+    assert not re.search(r"bf16\[\d+,64,(2048,512|512,2048)\]\S* copy\(",
+                         text), "an expert stack is copied"
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes >> 20
