@@ -10,23 +10,21 @@ Hk`` value heads), the state a matrix ``S[h]`` ``[N, P]``::
     o_t[h]  = S_t[h]^T q_t
 
 all in float32. Where Mamba-2 (``ssm.py``) decays its state and ADDS a
-rank-one term, this CORRECTS the state: what the state already predicts for
-the key, ``S'^T k_t``, is taken off the value before it is written. The state
-takes the state pool's layout, ``[.., N, E]`` with channel ``h * P + p`` of
-value head ``h`` on the lanes and the key's ``N`` values down the sublanes
-(2 MiB a sequence a layer at ``N`` 128 and 32 heads of 128), so the pool, its
-slots and its tails are the ones the Mamba layers use.
+rank-one term, this CORRECTS it: what the state already predicts for the key,
+``S'^T k_t``, is taken off the value before it is written. The state takes the
+pool's layout, ``[.., N, E]`` with channel ``h * P + p`` of value head ``h`` on
+the lanes and the key's ``N`` values down the sublanes (2 MiB a sequence a layer
+at ``N`` 128, 32 heads of 128): the pool, slots and tails of the Mamba layers.
 
 - :func:`gdn_decode_step`: one token per row, each row's state somewhere in
   the pool ``[Lm, slots, N, E]``, aliased through the call. A grid step takes
   one row's whole state where it lies (layer and slot from prefetched
   scalars), decays it, forms ``S'^T k`` (a reduction down the sublanes),
   writes the rank-one correction, forms ``S^T q`` (the second reduction) and
-  puts the state back: one read and one write of the state, both reductions
-  on the block in on-chip memory. The keys and queries arrive with their
-  ``N`` values down the sublanes (``[S, N, 128]``: lane ``j`` key head ``j``,
-  lane ``Hk + j`` query head ``j``), so the kernel turns nothing. The row's
-  convolution tail rides along as in ``ssd_decode_step``.
+  puts the state back: one read and one write of it, both reductions on chip.
+  Keys and queries arrive with their ``N`` values down the sublanes (``[S, N,
+  128]``: lane ``j`` key head ``j``, lane ``Hk + j`` query head ``j``), so the
+  kernel turns nothing. The tail rides along as in ``ssd_decode_step``.
 - :func:`gdn_chunk_scan`: a pass's packed prompt rows, ``G`` chunk slots of
   ``Cs`` rows, in the chunked (WY / UT) form over chunks of ``Q`` tokens.
   With ``c_t`` the running sum of ``g`` inside a chunk, ``A[t, s] = beta_t
@@ -38,24 +36,26 @@ slots and its tails are the ones the Mamba layers use.
       S_Q       = exp(c_Q) S_0 + (exp(c_Q - c) K)^T W
 
   ``(I + A)^-1`` is unit lower triangular and is built by block forward
-  substitution, doubling the block: with ``P_b`` the inverse's diagonal
-  blocks of size ``b`` and ``A_b`` the part of ``A`` below the diagonal of
-  each ``2b`` block, ``P_2b = P_b - P_b A_b P_b`` (two ``Q x Q`` products a
-  level, ``log2 Q - 1`` levels; the Neumann doubling ``(I - A)(I + A^2)..``
-  would cancel large powers of ``A`` where keys repeat). Everything that
-  meets the state is a float32 product at the highest precision; ``Q K^T``
-  and ``K K^T`` are products of the activations as they are (exact in one
-  pass where they are bfloat16). ``exp`` only ever sees differences ``<= 0``.
-  The state stays in on-chip memory across a slot's chunks; ``h0``/``cont``
-  are ``ssd_chunk_scan``'s, so chunked and paged prefill resume a sequence.
-  Rows with ``g = 0`` and ``beta = 0`` leave the state as it is, which is how
-  a chunk shorter than its slot is padded.
+  substitution, doubling the block: ``P_2b = P_b - P_b A_b P_b`` with ``P_b``
+  the inverse's diagonal blocks of size ``b`` and ``A_b`` what ``A`` has below
+  the diagonal of each ``2b`` block (two products a level, ``log2 Q - 1``
+  levels; the Neumann doubling ``(I - A)(I + A^2)..`` would cancel large
+  powers of ``A`` where keys repeat). The value heads of a key head that fit
+  one 128-wide tile (two at ``Q`` 64) lie block-diagonally in ONE such chain.
+  A grid step issues only the MXU passes that change its float32 result: the
+  products with no bfloat16 operand (the chain, ``inv . rhs``, ``(M o Q K^T)
+  W``) take the six passes of the highest precision; where the activations
+  are bfloat16, ``[K; Q] S_0`` and ``K^T (d W)`` take three (``_on_state``:
+  the other three terms of the six-pass sum multiply zeros) and ``Q K^T``,
+  ``K K^T`` one; a chunk whose ``g`` and ``beta`` are all zero (how a slot is
+  padded) is ``o = Q S_0`` alone. ``exp`` only sees differences ``<= 0``. The
+  state stays on chip across a slot's chunks; ``h0``/``cont`` are
+  ``ssd_chunk_scan``'s, so chunked and paged prefill resume a sequence.
 
 Each has a plain-XLA twin (``*_xla``), the recurrence token by token, for
 shapes the kernels refuse and as what the tests hold them to. The state is
-float32 here as in ``ssm.py``, and for its reason: a state rounded to
-bfloat16 after every token loses what a token wrote within a few hundred
-tokens. On the CPU the kernels run through the Pallas interpreter.
+float32 as in ``ssm.py``, for its reason: rounded to bfloat16 after every token
+it loses what one wrote within a few hundred. On the CPU: the interpreter.
 """
 
 from __future__ import annotations
@@ -220,40 +220,93 @@ def gdn_decode_step_xla(pool, tails, l, slots, g, beta, q, k, v, new):
 # a pass's packed prompt rows, state on chip across a slot
 # --------------------------------------------------------------------------- #
 
-def _scan_kernel(cont_ref, q_ref, k_ref, kt_ref, v_ref, col_ref, row_ref,
-                 beta_ref, h0_ref, y_ref, ht_ref, h_sc, *,
-                 blocks_per_slot: int, P: int, exact):
+def _dot(a, b, precision=_HIGHEST, contract=((1,), (0,))):
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _on_state(a, x):
+    """Activations ``a`` times float32 ``x``, to float32. The six-pass
+    product splits each operand in three bfloat16 parts and sums ``a0 x0, a0
+    x1, a1 x0, a1 x1, a0 x2, a2 x0``; where ``a`` IS bfloat16, ``a1 = a2 =
+    0`` and three of the six multiply zeros. So ``x`` is split here
+    (``hi + mid + lo`` is ``x`` to 24 bits) and the three terms that are left
+    are one-pass products, the smallest summed first. Float32 activations
+    take the six passes."""
+    if a.dtype != jnp.bfloat16:
+        return _dot(a, x)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = x.astype(bf16)
+    rest = x - hi.astype(f32)
+    mid = rest.astype(bf16)
+    lo = (rest - mid.astype(f32)).astype(bf16)
+    return _dot(a, lo, None) + _dot(a, mid, None) + _dot(a, hi, None)
+
+
+def _heads_packed(R: int, Q: int) -> int:
+    """How many of a key head's ``R`` value heads share one inverse chain:
+    the most that fit a ``LANES``-wide tile at chunks of ``Q`` and divide
+    ``R`` (2 at ``Q`` 64, 1 at ``Q`` 128)."""
+    return max(p for p in range(1, R + 1) if R % p == 0 and p * Q <= LANES)
+
+
+def _scan_kernel(cont_ref, still_ref, q_ref, k_ref, kt_ref, v_ref, col_ref,
+                 row_ref, h0_ref, y_ref, ht_ref, h_sc, *,
+                 blocks_per_slot: int, P: int, pack: int):
     tb, e = pl.program_id(0), pl.program_id(1)
     slot = tb // blocks_per_slot
-    f32 = jnp.float32
-
-    def dot(a, b, precision=_HIGHEST):
-        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                                   precision=precision,
-                                   preferred_element_type=f32)
 
     @pl.when(jnp.logical_and(tb % blocks_per_slot == 0, cont_ref[slot] == 0))
     def _():
         h_sc[e] = h0_ref[0]
 
-    Q = q_ref.shape[0]
-    Qm, Km, Kt = q_ref[...], k_ref[...], kt_ref[0, 0]    # [Q, N] x2, [N, Q]
-    # the activations' own products: exact in one pass where they are
-    # bfloat16 (``exact`` is then None), float32 products otherwise
-    KK, QK = dot(Km, Kt, exact), dot(Qm, Kt, exact)      # [Q, Q]
-    Qf, Kf, Ktf = Qm.astype(f32), Km.astype(f32), Kt.astype(f32)
-    t = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    s = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    seen, before = t >= s, t > s
+    # a chunk whose every g and beta is zero writes nothing (A = 0, W = 0,
+    # every decay 1): o = Q S_0, and the state is what it was
+    @pl.when(still_ref[tb] != 0)
+    def _():
+        y_ref[...] = _on_state(q_ref[...], h_sc[e])
+        ht_ref[0] = h_sc[e]
+
+    @pl.when(still_ref[tb] == 0)
+    def _():
+        y_ref[...], S_new = _scan_chunk(
+            q_ref[...], k_ref[...], kt_ref[0, 0], v_ref[...], col_ref[0, 0],
+            row_ref[0, 0], h_sc[e], P=P, pack=pack)
+        h_sc[e] = S_new
+        ht_ref[0] = S_new
+
+
+def _scan_chunk(Qm, Km, Kt, Vm, col, row, S_all, *, P: int, pack: int):
+    """One chunk of one key head from the state ``S_all`` ``[N, Eb]`` -> (``o``
+    ``[Q, Eb]``, the new state), ``pack`` of its value heads at a time: their
+    ``A`` is block diagonal in ONE ``[pack Q, pack Q]`` matrix (row ``i Q +
+    t``, column ``i Q + s`` for head ``i`` of the pack), and the doubling never
+    couples two blocks (``Q`` is a multiple of every ``2b``), so one chain of
+    products on full tiles inverts them all."""
+    f32 = jnp.float32
+    Q, Rp = Qm.shape[0], row.shape[0]
+    PQ = pack * Q
+    stack = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
+    beside = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1)
+    # the activations' own products, K K^T (Q K^T) in every [Q, Q] block:
+    # exact in one pass where they are bfloat16, float32 products otherwise
+    own = None if Qm.dtype == Km.dtype == jnp.bfloat16 else _HIGHEST
+    Kp, nt = stack([Km] * pack), ((1,), (1,))            # [PQ, N]
+    KK, QK = _dot(Kp, Kp, own, nt), _dot(stack([Qm] * pack), Kp, own, nt)
+    t = jax.lax.broadcasted_iota(jnp.int32, (PQ, PQ), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (PQ, PQ), 1)
+    same = t // Q == s // Q                              # one head's block
+    seen, before = jnp.logical_and(same, t >= s), jnp.logical_and(same, t > s)
     eye = (t == s).astype(f32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
-    col, row, bcol = col_ref[0, 0], row_ref[0, 0], beta_ref[0, 0]
-    S_all = h_sc[e]                                      # [N, Eb]
-    ys, states = [], []
-    for r in range(S_all.shape[1] // P):
-        cj, rj = col[:, r:r + 1], row[r:r + 1, :]        # [Q, 1], [1, Q]
-        bj = bcol[:, r:r + 1]
-        last = cj[Q - 1:Q, :]                            # [1, 1]
+    # [K; Q] S for every head at once: each part of S is latched once
+    KQS = _on_state(stack([Km, Qm]), S_all)              # [2Q, Eb]
+    ys, writes, wholes = [], [], []
+    for j in range(Rp):
+        heads = range(j * pack, (j + 1) * pack)
+        of = lambda x: stack([x[:, r * P:(r + 1) * P] for r in heads])
+        cj, rj = col[:, j:j + 1], row[j:j + 1, :]        # [PQ, 1], [1, PQ]
+        bj = col[:, Rp + j:Rp + j + 1]
         D = jnp.exp(jnp.where(seen, cj - rj, -jnp.inf))  # exp(c_t - c_s)
         A = jnp.where(before, bj * D * KK, 0.0)
         # (I + A)^-1 by block forward substitution, the block doubled
@@ -263,23 +316,24 @@ def _scan_kernel(cont_ref, q_ref, k_ref, kt_ref, v_ref, col_ref, row_ref,
             below = jnp.logical_and(
                 t // (2 * b) == s // (2 * b),
                 jnp.logical_and((t // b) % 2 == 1, (s // b) % 2 == 0))
-            inv = inv - dot(inv, dot(jnp.where(below, A, 0.0), inv))
+            inv = inv - _dot(inv, _dot(jnp.where(below, A, 0.0), inv))
             b *= 2
-        S = S_all[:, r * P:(r + 1) * P]                  # [N, P]
         carried = jnp.exp(cj)                            # exp(c_t)
-        V = v_ref[:, r * P:(r + 1) * P].astype(f32)
-        W = dot(inv, bj * (V - carried * dot(Kf, S)))    # [Q, P]
-        ys.append(carried * dot(Qf, S)
-                  + dot(jnp.where(seen, D * QK, 0.0), W))
-        # ([1, 1] -> [N, P] one axis at a time, as ``_ssd_scan_kernel`` does
-        # it: Mosaic broadcasts along the lanes or down the sublanes, not
-        # both at once, and folds a multiplication by ones into one)
-        whole = jnp.where(lane >= 0, jnp.exp(last), 0.0)  # exp(c_Q), [1, P]
-        states.append(whole * S + dot(Ktf * jnp.exp(last - rj), W))
-    y_ref[...] = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
-    S_new = states[0] if len(states) == 1 else jnp.concatenate(states, axis=1)
-    h_sc[e] = S_new
-    ht_ref[0] = S_new
+        rhs = bj * (of(Vm).astype(f32) - carried * of(KQS[:Q]))
+        W = _dot(inv, rhs)                               # [PQ, P]
+        y = carried * of(KQS[Q:]) + _dot(jnp.where(seen, D * QK, 0.0), W)
+        for i in range(pack):
+            rows = slice(i * Q, (i + 1) * Q)
+            last = cj[(i + 1) * Q - 1:(i + 1) * Q, :]    # [1, 1]
+            ys.append(y[rows])
+            # (K^T diag(d)) W = K^T (diag(d) W): W's rows scaled on the vector
+            # unit, so that K^T stays the bfloat16 array it arrived as
+            writes.append(jnp.exp(last - cj[rows]) * W[rows])
+            # ([1, 1] -> [N, P] one axis at a time, as ``_ssd_scan_kernel``
+            # does it: Mosaic broadcasts along the lanes or down the sublanes,
+            # not both at once, and folds a multiplication by ones into one)
+            wholes.append(jnp.where(lane >= 0, jnp.exp(last), 0.0))
+    return beside(ys), beside(wholes) * S_all + _on_state(Kt, beside(writes))
 
 
 def gdn_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -306,31 +360,38 @@ def gdn_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         return gdn_chunk_scan_xla(q, k, v, g, beta, h0, cont)
     R = Hv // Hk                    # value heads a key head serves: a block
     Eb, nC, bps = R * P, T // Q, Cs // Q
+    pack = _heads_packed(R, Q)      # of them, heads an inverse chain holds
+    Rp, PQ = R // pack, pack * Q
     f32 = jnp.float32
     with jax.named_scope("gdn_chunk_scan"):
-        heads = lambda x: x.astype(f32).reshape(nC, Q, Hk, R)
-        cum = jnp.cumsum(heads(g), axis=1)
-        col = jnp.transpose(cum, (0, 2, 1, 3))                # [nC, Hk, Q, R]
-        row = jnp.transpose(cum, (0, 2, 3, 1))                # [nC, Hk, R, Q]
-        bcol = jnp.transpose(heads(beta), (0, 2, 1, 3))
+        # tokens on the lanes, a pack's heads side by side: lane i * Q + t is
+        # token t of its head i; the same numbers down the sublanes in ONE
+        # array (a [.., PQ, 1] array is 128 lanes wide in memory whatever it
+        # holds), column j the sums of group j, column Rp + j its betas
+        rows = lambda x: jnp.transpose(
+            x.reshape(Hk, Rp, pack, nC, Q), (3, 0, 1, 2, 4)).reshape(
+                nC, Hk, Rp, PQ)
+        row = rows(jnp.cumsum(g.astype(f32).T.reshape(Hv, nC, Q), axis=-1))
+        cols = jnp.swapaxes(jnp.concatenate(
+            [row, rows(beta.astype(f32).T)], axis=2), 2, 3)  # [nC, Hk, PQ, 2 Rp]
         Kt = jnp.transpose(k.reshape(nC, Q, Hk, N), (0, 2, 3, 1))
         cont = cont.astype(jnp.int32).at[0].set(0)
-        tok = lambda tb, e, c: (tb, e)
-        slot = lambda tb, e, c: (tb // bps, 0, e)
-        head = lambda tb, e, c: (tb, e, 0, 0)
+        # 1 where a chunk's every g and beta is 0: nothing to write
+        still = jnp.all(jnp.logical_and(g == 0, beta == 0).reshape(nC, -1),
+                        axis=1).astype(jnp.int32)
+        tok = lambda tb, e, c, z: (tb, e)
+        slot = lambda tb, e, c, z: (tb // bps, 0, e)
+        head = lambda tb, e, c, z: (tb, e, 0, 0)
         call = pl.pallas_call(
             functools.partial(
-                _scan_kernel, blocks_per_slot=bps, P=P,
-                exact=None if q.dtype == k.dtype == jnp.bfloat16
-                else _HIGHEST),
+                _scan_kernel, blocks_per_slot=bps, P=P, pack=pack),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(nC, Hk),
+                num_scalar_prefetch=2, grid=(nC, Hk),
                 in_specs=[pl.BlockSpec((Q, N), tok), pl.BlockSpec((Q, N), tok),
                           pl.BlockSpec((1, 1, N, Q), head),
                           pl.BlockSpec((Q, Eb), tok),
-                          pl.BlockSpec((1, 1, Q, R), head),
-                          pl.BlockSpec((1, 1, R, Q), head),
-                          pl.BlockSpec((1, 1, Q, R), head),
+                          pl.BlockSpec((1, 1, PQ, 2 * Rp), head),
+                          pl.BlockSpec((1, 1, Rp, PQ), head),
                           pl.BlockSpec((1, N, Eb), slot)],
                 out_specs=[pl.BlockSpec((Q, Eb), tok),
                            pl.BlockSpec((1, N, Eb), slot)],
@@ -342,7 +403,7 @@ def gdn_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 vmem_limit_bytes=64 << 20),
             interpret=_backend.interpret(),
         )
-        y, hT = call(cont, q, k, Kt, v, col, row, bcol, h0.astype(f32))
+        y, hT = call(cont, still, q, k, Kt, v, cols, row, h0.astype(f32))
     return y, hT
 
 

@@ -1,8 +1,11 @@
 """The gated delta-rule kernels (``ops/pallas/gdn.py``) against the
 sequential recurrence written out in numpy: the chunked scan (the Pallas
 kernel through the interpreter, and its XLA twin) over ragged lengths,
-continued across calls, at the edges of ``beta`` and of the decay; the
-one-token step against one step of it, the dump slot untouched."""
+continued across calls, at the edges of ``beta`` and of the decay, one, two
+and four value heads a key head (packed into one inverse chain where they fit
+a tile), a chunk of zeros taking the short way; the MXU passes a grid step
+issues, counted in the kernel's jaxpr; the one-token step against one step of
+the recurrence, the dump slot untouched."""
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +55,7 @@ def rel(a, b):
 
 
 SCANS = {"pallas": gdn.gdn_chunk_scan, "xla": gdn.gdn_chunk_scan_xla}
+DTYPES = [jnp.float32, jnp.bfloat16]
 
 
 @pytest.mark.parametrize("which", list(SCANS))
@@ -81,12 +85,17 @@ def test_chunk_scan_is_the_recurrence(which, edge):
     assert rel(hT[1], Sa) < 2e-5 and rel(hT[2], Sb) < 2e-5
 
 
-@pytest.mark.parametrize("chunk", [64, 128, 16])
-def test_any_chunk_is_the_same_recurrence(chunk):
-    """Two key heads serving four value heads, the chunk varied: the chunk
-    is no part of the mathematics."""
-    rng = np.random.default_rng(chunk)
-    Hk, Hv, Cs = 2, 4, 128
+@pytest.mark.parametrize("chunk,Hk,Hv", [
+    (64, 2, 4), (128, 2, 4), (16, 2, 4),       # two heads a key head
+    (64, 1, 1), (128, 1, 1),                   # one: nothing to pack
+    (64, 1, 4), (16, 1, 4), (16, 1, 8)],       # four: two chains of two; one
+    ids=lambda x: str(x))                      # of four; one of eight
+def test_any_chunk_is_the_same_recurrence(chunk, Hk, Hv):
+    """The chunk and the value heads a key head serves varied: neither the
+    chunk nor how many heads share an inverse chain is part of the
+    mathematics."""
+    rng = np.random.default_rng(chunk + Hv)
+    Cs = 128
     q, k, v, g, beta = draw(rng, Cs, Hk, Hv)
     want, S = recurrence(q, k, v, g, beta, np.zeros((N, Hv * P)))
     y, hT = gdn.gdn_chunk_scan(flat(q), flat(k), flat(v), jnp.asarray(g),
@@ -96,52 +105,177 @@ def test_any_chunk_is_the_same_recurrence(chunk):
     assert rel(y, want) < 2e-5 and rel(hT[0], S) < 2e-5
 
 
-def test_a_sequence_resumes_across_calls():
+def as_dtype(x, dtype):
+    """``x`` ``[T, ..]`` as the kernel's ``[T, -1]`` argument of ``dtype``,
+    and the values it then holds, in ``x``'s shape."""
+    arg = jnp.asarray(x.reshape(x.shape[0], -1), dtype)
+    return arg, np.asarray(arg.astype(jnp.float32)).reshape(x.shape)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+def test_a_sequence_resumes_across_calls(dtype):
     """A call's last state handed to the next call as ``h0`` gives what one
-    call over all the tokens gives."""
+    call over all the tokens gives; inside a call the second slot goes on
+    from the first (``cont``)."""
     rng = np.random.default_rng(11)
     Hk, Hv, Cs = 1, 2, 64
-    q, k, v, g, beta = draw(rng, 2 * Cs, Hk, Hv)
+    q, k, v, g, beta = draw(rng, 4 * Cs, Hk, Hv)
+    (qa, q), (ka, k), (va, v) = (as_dtype(x, dtype) for x in (q, k, v))
     want, S = recurrence(q, k, v, g, beta, np.zeros((N, Hv * P)))
-    h = jnp.zeros((1, N, Hv * P))
+    h = jnp.zeros((N, Hv * P))
     ys = []
     for i in range(2):
-        part = slice(i * Cs, (i + 1) * Cs)
-        y, h = gdn.gdn_chunk_scan(flat(q[part]), flat(k[part]), flat(v[part]),
-                                  jnp.asarray(g[part]), jnp.asarray(beta[part]),
-                                  h, jnp.zeros((1,), int))
+        part = slice(2 * i * Cs, 2 * (i + 1) * Cs)
+        y, hT = gdn.gdn_chunk_scan(
+            qa[part], ka[part], va[part], jnp.asarray(g[part]),
+            jnp.asarray(beta[part]), jnp.stack([h, jnp.ones_like(h)]),
+            jnp.asarray([0, 1]))
+        h = hT[1]
         ys.append(y)
-    assert rel(jnp.concatenate(ys), want) < 2e-5 and rel(h[0], S) < 2e-5
+    assert rel(jnp.concatenate(ys), want) < 2e-5 and rel(h, S) < 2e-5
 
 
-def test_repeated_keys_do_not_cancel():
+@pytest.mark.parametrize("Hv", [2, 1, 4])
+def test_repeated_keys_do_not_cancel(Hv):
     """The same key written 128 times with beta 1 and no decay: the powers of
     ``A`` a Neumann series would sum reach 1e37, block substitution stays at
     rounding."""
     rng = np.random.default_rng(5)
-    q, k, v, g, beta = draw(rng, 128, 1, 2, beta=1.0, decay=1.0)
+    q, k, v, g, beta = draw(rng, 128, 1, Hv, beta=1.0, decay=1.0)
     k[:] = k[0]
-    want, S = recurrence(q, k, v, g, beta, np.zeros((N, 2 * P)))
+    want, S = recurrence(q, k, v, g, beta, np.zeros((N, Hv * P)))
     y, hT = gdn.gdn_chunk_scan(flat(q), flat(k), flat(v), jnp.asarray(g),
-                               jnp.asarray(beta), jnp.zeros((1, N, 2 * P)),
+                               jnp.asarray(beta), jnp.zeros((1, N, Hv * P)),
                                jnp.zeros((1,), int))
     assert rel(y, want) < 1e-4 and rel(hT[0], S) < 1e-4
 
 
-def test_bfloat16_activations_take_the_one_pass_products():
+@pytest.mark.parametrize("Hv", [2, 1, 4])
+def test_bfloat16_activations_take_the_one_pass_products(Hv):
     """q, k and v as the engine hands them (bfloat16 values): the scan still
-    follows the recurrence on those values."""
+    follows the recurrence on those values, from a state that is not zero
+    (the three-pass products ``[K; Q] S_0`` and ``K^T (d W)`` against it)."""
     rng = np.random.default_rng(9)
-    q, k, v, g, beta = draw(rng, 64, 1, 2)
-    bf = lambda x: jnp.asarray(x.reshape(x.shape[0], -1), jnp.bfloat16)
-    back = lambda x, like: np.asarray(x.astype(jnp.float32)).reshape(
-        like.shape)
-    want, S = recurrence(back(bf(q), q), back(bf(k), k), back(bf(v), v), g,
-                         beta, np.zeros((N, 2 * P)))
-    y, hT = gdn.gdn_chunk_scan(bf(q), bf(k), bf(v), jnp.asarray(g),
-                               jnp.asarray(beta), jnp.zeros((1, N, 2 * P)),
-                               jnp.zeros((1,), int))
+    q, k, v, g, beta = draw(rng, 64, 1, Hv)
+    (qa, q), (ka, k), (va, v) = (as_dtype(x, jnp.bfloat16) for x in (q, k, v))
+    S0 = rng.standard_normal((N, Hv * P)).astype(np.float32)
+    want, S = recurrence(q, k, v, g, beta, S0)
+    y, hT = gdn.gdn_chunk_scan(qa, ka, va, jnp.asarray(g), jnp.asarray(beta),
+                               jnp.asarray(S0)[None], jnp.zeros((1,), int))
     assert rel(y, want) < 2e-5 and rel(hT[0], S) < 2e-5
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+def test_a_slow_head_beside_a_fast_one(dtype):
+    """The two value heads of a key head share one inverse chain: one that
+    forgets nothing in a chunk (``exp(g)`` 0.98-1, as the configuration's
+    slow heads) beside one whose decay underflows within it. Nothing of
+    either reaches the other's block."""
+    rng = np.random.default_rng(13)
+    q, k, v, g, beta = draw(rng, 128, 1, 2)
+    g[:, 0] = np.log(rng.uniform(0.98, 1.0, 128))
+    g[:, 1] = np.log(rng.uniform(1e-4, 5e-2, 128))      # sums to -300 .. -600
+    (qa, q), (ka, k), (va, v) = (as_dtype(x, dtype) for x in (q, k, v))
+    S0 = rng.standard_normal((N, 2 * P)).astype(np.float32)
+    want, S = recurrence(q, k, v, g, beta, S0)
+    y, hT = gdn.gdn_chunk_scan(qa, ka, va, jnp.asarray(g), jnp.asarray(beta),
+                               jnp.asarray(S0)[None], jnp.zeros((1,), int))
+    y, hT = np.asarray(y), np.asarray(hT)
+    assert np.isfinite(y).all() and np.isfinite(hT).all()
+    for h in range(2):                  # each head against its own scale
+        lanes = slice(h * P, (h + 1) * P)
+        assert rel(y[:, lanes], want[:, lanes]) < 2e-5
+        assert rel(hT[0][:, lanes], S[:, lanes]) < 2e-5
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+@pytest.mark.parametrize("where", ["inside_a_slot", "a_whole_slot"])
+def test_a_chunk_of_zeros_writes_nothing(where, dtype):
+    """A chunk whose ``g`` and ``beta`` are all zero between two live ones,
+    its q, k and v NOT zero: ``o = S^T q`` on its rows and the state goes
+    through it untouched, bit for bit. Inside a slot of three chunks, and as
+    a whole slot between two that it joins (``cont`` 1, then 2)."""
+    rng = np.random.default_rng(17)
+    Hk, Hv, Q = 1, 2, 64
+    q, k, v, g, beta = draw(rng, 3 * Q, Hk, Hv)
+    g[Q:2 * Q], beta[Q:2 * Q] = 0.0, 0.0
+    (qa, q), (ka, k), (va, v) = (as_dtype(x, dtype) for x in (q, k, v))
+    S0 = rng.standard_normal((N, Hv * P)).astype(np.float32)
+    want, S = recurrence(q, k, v, g, beta, S0)
+    G = 1 if where == "inside_a_slot" else 3
+    h0 = jnp.concatenate([jnp.asarray(S0)[None], jnp.ones((G - 1, N, Hv * P))])
+    cont = jnp.asarray([0, 1, 2][:G])
+    y, hT = gdn.gdn_chunk_scan(qa, ka, va, jnp.asarray(g), jnp.asarray(beta),
+                               h0, cont)
+    assert rel(y, want) < 2e-5 and rel(hT[-1], S) < 2e-5
+    if G == 3:
+        # the slot of zeros hands on the bits it was handed, and reads them
+        carried = np.asarray(hT[0], np.float64).reshape(N, Hv, P)
+        read = np.einsum("nhp,tn->thp", carried, q[Q:2 * Q, 0])
+        assert rel(y[Q:2 * Q], read.reshape(Q, Hv * P)) < 2e-5
+        assert (np.asarray(hT[1]) == np.asarray(hT[0])).all()
+    else:
+        # the same tokens without the chunk of zeros end in the same bits
+        live = np.r_[0:Q, 2 * Q:3 * Q]
+        _, h2 = gdn.gdn_chunk_scan(qa[live], ka[live], va[live],
+                                   jnp.asarray(g[live]),
+                                   jnp.asarray(beta[live]), h0, cont)
+        assert (np.asarray(h2) == np.asarray(hT)).all()
+
+
+def _passes(jaxpr):
+    """MXU passes of the products in ``jaxpr`` and whatever it calls: six for
+    a ``dot_general`` at the highest precision, one for any other; and how
+    many of the products are at the highest."""
+    total = highest = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            top = "HIGHEST" in str(eqn.params["precision"])
+            total, highest = total + (6 if top else 1), highest + top
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n, h = _passes(sub)
+            total, highest = total + n, highest + h
+    return total, highest
+
+
+def _kernel_of(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn.params["jaxpr"]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found = _kernel_of(sub)
+            if found is not None:
+                return found
+
+
+@pytest.mark.parametrize("dtype,most", [(jnp.bfloat16, 90), (jnp.float32, 192)],
+                         ids=["bfloat16", "float32"])
+def test_a_grid_step_issues_the_passes_that_change_the_result(dtype, most):
+    """The kernel's jaxpr at one key head serving two value heads, chunks of
+    64. PR 47's step held 2 + 2 x 15 x 6 = 182 passes at bfloat16 activations
+    (192 at float32). Now: ``K K^T``, ``Q K^T`` 2; ONE chain of ten products
+    for both heads 60; ``inv . rhs`` and ``(M o Q K^T) W`` 12; ``[K; Q] S_0``
+    and ``K^T (d W)`` three each where the activations are bfloat16, six
+    where they are not. The branch of a chunk of zeros holds ``Q S_0`` alone,
+    and at bfloat16 no product at the highest precision. A shape that fell
+    back to a chain a head, or to six-pass products, shows here."""
+    T, Hk, Hv = 128, 1, 2
+    args = (jnp.zeros((T, Hk * N), dtype), jnp.zeros((T, Hk * N), dtype),
+            jnp.zeros((T, Hv * P), dtype), jnp.zeros((T, Hv)),
+            jnp.zeros((T, Hv)), jnp.zeros((1, N, Hv * P)),
+            jnp.zeros((1,), jnp.int32))
+    kernel = _kernel_of(jax.make_jaxpr(gdn.gdn_chunk_scan)(*args).jaxpr)
+    branches = []                       # the ``pl.when``s that hold products
+    for eqn in kernel.eqns:
+        assert eqn.primitive.name != "dot_general", "a product on every step"
+        if eqn.primitive.name == "cond":
+            counts = [_passes(b.jaxpr) for b in eqn.params["branches"]]
+            branches += [c for c in counts if c[0]]
+    (short, short_top), (full, full_top) = sorted(branches)
+    bf16 = dtype == jnp.bfloat16
+    assert full == (80 if bf16 else 96) and full <= most
+    assert full_top == (12 if bf16 else 16)
+    assert (short, short_top) == ((3, 0) if bf16 else (6, 1))
 
 
 STEPS = {"pallas": gdn.gdn_decode_step, "xla": gdn.gdn_decode_step_xla}
