@@ -1597,116 +1597,220 @@ def paged_chunk_attention(q: jax.Array,
 
 
 
-def _chunk_head_scale(mat, sc_ref, flat0, bs):
-    """Multiply ``mat`` [rows, bs] by one head's per-token dequant scales,
-    read from a page scale tile ref [1, R8, 128] starting at FLAT scale
-    index ``flat0`` (= kv*Hkv*bs + h*bs). Handles bs that is not itself a
-    multiple of 128: the engine gate requires (Hkv*bs) % 128 == 0, so a
-    head's span either covers whole lane rows (bs >= 128) or shares one
-    lane row with its neighbours at a 128-aligned base (bs < 128), in which
-    case the span is sliced out of that row."""
-    if bs % 128 == 0:
-        pieces = []
-        for t0 in range(bs // 128):
-            row = flat0 // 128 + t0
-            pieces.append(mat[:, t0 * 128:(t0 + 1) * 128]
-                          * sc_ref[0, row, :][None, :])
-        return jnp.concatenate(pieces, axis=1) if len(pieces) > 1 \
-            else pieces[0]
-    row = flat0 // 128
-    lane0 = flat0 % 128
-    return mat * sc_ref[0, row, lane0:lane0 + bs][None, :]
+#: the most pages a grid step of the chunk kernel attends, whatever the bytes
+#: allow: a step issues and awaits its page copies one by one, unrolled (the
+#: body itself is traced once, over the concatenated pages)
+MAX_PAGES_PER_CHUNK_STEP = 8
+
+#: what a chunk step's blocks, scratch and score arrays may take of VMEM
+#: (the compiler's default keeps a kernel to 16 MiB of a v5e's 128)
+_CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_ref, o_ref,
-                          acc_sc, m_sc, l_sc, *, scale, block_size, block_q,
-                          max_blocks, h_kv, groups, window=None,
-                          sc_ref=None, alibi=False):
-    """Multi-slot variant of ``_chunk_kernel``: grid (slot, q-block, page);
-    each slot is an independent prompt chunk with its own block table and
-    (q_start, ctx) row in ``meta_ref``. Slot padding (ctx 0) writes zeros.
-    With ``window``, row q_pos attends only k_pos > q_pos - window (and
-    pages wholly below the q-block's window skip). ``sc_ref`` (int8 pages):
-    the page's scale tile, applied as score-column (K) and p-column (V)
-    multipliers."""
-    sl, iq, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    q0 = meta_ref[sl, 0]
-    ctx = meta_ref[sl, 1]
+def _pick_chunk_pages(bs: int, h_kv: int, d: int, esize: int, rows: int,
+                      max_blocks: int, scale_tile_rows: int = 0) -> int:
+    """Pages a step of the chunk kernel attends. A KV head's step pays its
+    row statistics — two lane reductions and the ``m``/``l``/``alpha``
+    columns, a cost by ``rows`` alone, 3.6 us at 1,024 rows on a v5e — beside
+    products that grow with the keys and the head's width, so a step takes
+    the keys that make the products the larger part: ``P * bs * d`` of 128 K
+    (512 keys at a width of 256, 1,024 at 128; PERF.md, PR 49). No more than
+    ``MAX_PAGES_PER_CHUNK_STEP`` or the table's pages, and no more than keep
+    the two slots of K+V pages (and int8 scale tiles) and the score arrays —
+    one KV head's ``[rows, P * bs]`` scores and ``p`` in float32 and ``p``
+    again as the MXU takes it — within 16 MB of VMEM."""
+    per_page = 2 * 2 * h_kv * bs * d * esize + rows * bs * 10
+    if scale_tile_rows:
+        per_page += 2 * scale_tile_rows * 128 * 4
+    return max(1, min(max_blocks, -(-128 * 1024 // (bs * d)),
+                      (16 * 1024 * 1024) // per_page,
+                      MAX_PAGES_PER_CHUNK_STEP))
 
-    @pl.when(i == 0)
-    def _():
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    run = (i * block_size <= q0 + iq * block_q + block_q - 1) & \
-          (i * block_size < ctx)
+def _chunk_scale_row(sc_buf, slot, pages, flat0, bs):
+    """One head's per-token dequant scales over a step's pages as a ``[1, P *
+    bs]`` row, read from the step's scale tiles ``sc_buf[slot]`` ``[P, R8,
+    128]``; ``flat0`` (= kv*Hkv*bs + h*bs, ``h`` traced) is the head's FLAT
+    index in a tile. Handles bs that is not itself a multiple of 128: the
+    engine gate requires (Hkv*bs) % 128 == 0, so a head's span either covers
+    whole lane rows (bs >= 128) or shares one lane row with its neighbours at
+    a 128-aligned base (bs < 128), in which case the span is sliced out of
+    that row."""
+    row, lane0 = flat0 // 128, flat0 % 128
+    pieces = []
+    for j in range(pages):
+        if bs % 128 == 0:
+            pieces += [sc_buf[slot, j, pl.ds(row + t0, 1), :]
+                       for t0 in range(bs // 128)]
+        else:
+            pieces.append(sc_buf[slot, j, pl.ds(row, 1), pl.ds(lane0, bs)])
+    return jnp.concatenate(pieces, axis=1) if len(pieces) > 1 else pieces[0]
+
+
+def _chunk_group_inner(k0, T, row0, bq, ctx, window):
+    """Is every key of the group ``[k0, k0 + T)`` visible to every row of
+    the q-block ``[row0, row0 + bq)`` — below its first row, inside ``ctx``
+    and inside its last row's window? Such a group builds no mask."""
+    inner = (k0 + T - 1 <= row0) & (k0 + T <= ctx)
     if window is not None:
-        # lowest visible k for this q block: min q_pos - window + 1
-        run = run & ((i + 1) * block_size > q0 + iq * block_q - window + 1)
+        inner = inner & (k0 > row0 + bq - 1 - window)
+    return inner
 
-    @pl.when(run)
-    def _():
-        bq, G, bs = block_q, groups, block_size
-        q = q_ref[0].astype(jnp.float32)                       # [bq, H, D]
-        q_pos = q0 + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 0)
-        k_pos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
-        mask = (k_pos <= q_pos) & (k_pos < ctx)
-        if window is not None:
-            mask = mask & (k_pos > q_pos - window)
-        mask = jnp.broadcast_to(mask[:, None, :], (bq, G, bs)).reshape(bq * G, bs)
 
-        nrow = bs // 128
-        for h in range(h_kv):
-            qh = q[:, h * G:(h + 1) * G, :].reshape(bq * G, -1)
-            kh = kv_ref[0, 0, h].astype(jnp.float32)           # [bs, D]
-            vh = kv_ref[0, 1, h].astype(jnp.float32)
-            sc = jax.lax.dot_general(qh, kh, (((1,), (1,)), ((), ())),
+def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
+                          q_sc, kv_buf, sems, acc_sc, m_sc, l_sc, *, scale,
+                          block_size, block_q, pages, max_blocks, h_kv,
+                          groups, window=None, sc_hbm=None, sc_buf=None,
+                          alibi=False):
+    """Grid (slot, q-block); each slot is an independent prompt chunk with
+    its own block table and (q_start, ctx) row in ``meta_ref``. A step walks
+    the GROUPS of ``pages`` consecutive pages that hold a key some row of the
+    q-block sees — from the window's first page (0 without one) to the
+    diagonal or the context's end, so a table entry the sequence does not
+    use costs nothing — through a two-slot pipeline of page copies, and
+    attends a whole group at once. Slot padding (ctx 0) walks no group and
+    writes zeros. With ``window``, row q_pos attends only k_pos > q_pos -
+    window. ``sc_hbm``/``sc_buf`` (int8 pages): the pages' scale tiles,
+    applied as score-column (K) and p-column (V) multipliers.
+
+    The MXU takes the operands as they are stored: bfloat16 q against
+    bfloat16 (or int8, widened exactly) pages is one pass to float32; any
+    float32 operand keeps float32 products. q is laid out for the products
+    (``[Hkv, bq * G, D]``) once a step. A group wholly below the q-block's
+    first row, inside ``ctx`` and inside the last row's window builds no
+    mask."""
+    sl, iq = pl.program_id(0), pl.program_id(1)
+    bq, G, bs, P = block_q, groups, block_size, pages
+    T, R = P * bs, bq * G
+    quant = sc_hbm is not None
+    q0 = meta_ref[sl, 0]
+    ctx = jnp.minimum(meta_ref[sl, 1], max_blocks * bs)   # the table's keys
+    row0 = q0 + iq * bq                      # the q-block's first position
+    # keys [lo, hi) are visible to some row of this q-block
+    hi = jnp.minimum(ctx, row0 + bq)
+    lo = jnp.int32(0) if window is None \
+        else jnp.maximum(row0 - window + 1, 0)
+    g_lo = jax.lax.div(lo, T)
+    g_hi = jax.lax.div(hi + (T - 1), T)
+    mxu = q_sc.dtype
+
+    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[:] = jnp.zeros_like(l_sc)
+    acc_sc[:] = jnp.zeros_like(acc_sc)
+    qf = q_ref[0].astype(jnp.float32)                          # [bq, H, D]
+    for h in range(h_kv):
+        q_sc[h] = qf[:, h * G:(h + 1) * G, :].reshape(R, -1).astype(mxu)
+
+    def copies(g, slot):
+        """A group's page copies, built identically at start and wait. A
+        page past the table's end repeats its last entry (masked)."""
+        cps = []
+        for j in range(P):
+            page = bt_ref[sl, jnp.minimum(g * P + j, max_blocks - 1)]
+            cps.append(pltpu.make_async_copy(
+                kv_hbm.at[page], kv_buf.at[slot, j], sems.at[slot]))
+            if quant:
+                cps.append(pltpu.make_async_copy(
+                    sc_hbm.at[page], sc_buf.at[slot, j], sems.at[slot]))
+        return cps
+
+    def attend(slot, k0, masked):
+        if masked:
+            # row r of a head's R is q row r // G, at q_pos = row0 + r // G;
+            # for an integer a, a <= r // G is a * G <= r: no division, and
+            # the mask is built as the scores lie, [R, T]
+            r = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
+            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+            mask = ((k_pos - row0) * G <= r) & (k_pos < ctx)
+            if window is not None:
+                mask = mask & (r < (k_pos - row0 + window) * G)
+
+        def head(h, carry):
+            # slice the REF: a head's K and V rows of every page of the group
+            kh = kv_buf[slot, :, 0, h].reshape(T, -1)
+            vh = kv_buf[slot, :, 1, h].reshape(T, -1)
+            sc = jax.lax.dot_general(q_sc[h], kh.astype(mxu),
+                                     (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32) * scale
-            if sc_ref is not None:
-                # K scales for head h start at flat index h*bs in the tile;
-                # the (Hkv*bs) % 128 == 0 gate guarantees 128-alignment of
-                # every head's span even when bs < 128
-                sc = _chunk_head_scale(sc, sc_ref, h * bs, bs)
+            if quant:
+                # K scales for head h start at flat index h*bs in a tile
+                sc = sc * _chunk_scale_row(sc_buf, slot, P, h * bs, bs)
             if alibi:
                 # rows of this slice are (q-row, g) for q heads h*G + g;
-                # built in (bq, G, bs) then merged like the mask above
-                gof = jax.lax.broadcasted_iota(jnp.float32, (bq, G, bs), 1)
-                slope = _alibi_slope(h * G + gof, h_kv * G)
-                kpf = jnp.broadcast_to(
-                    (i * bs + jax.lax.broadcasted_iota(
-                        jnp.float32, (bq, bs), 1))[:, None, :], (bq, G, bs))
-                sc = sc + (slope * kpf).reshape(bq * G, bs)
-            sc = jnp.where(mask, sc, NEG_INF)
-            rows = slice(h * bq * G, (h + 1) * bq * G)
-            m_prev = m_sc[rows, 0:1]
+                # built in (bq, G, T), then merged to the scores' [R, T]
+                gof = jax.lax.broadcasted_iota(jnp.int32, (bq, G, T), 1)
+                slope = _alibi_slope((h * G + gof).astype(jnp.float32),
+                                     h_kv * G)
+                kpf = (k0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (bq, G, T), 2)).astype(jnp.float32)
+                sc = sc + (slope * kpf).reshape(R, T)
+            if masked:
+                sc = jnp.where(mask, sc, NEG_INF)
+            m_prev = m_sc[h, :, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+            p = jnp.exp(sc - m_new)
+            if masked:
+                # explicit, not exp alone: a row that sees nothing yet has
+                # m_new == sc == NEG_INF and the bare exp would give 1.0
+                p = jnp.where(mask, p, 0.0)
             alpha = jnp.exp(m_prev - m_new)
-            l_sc[rows, 0:1] = l_sc[rows, 0:1] * alpha + jnp.sum(p, axis=1,
-                                                               keepdims=True)
-            m_sc[rows, 0:1] = m_new
-            pv = p if sc_ref is None \
-                else _chunk_head_scale(p, sc_ref, (h_kv + h) * bs, bs)
-            acc_sc[rows, :] = acc_sc[rows, :] * alpha + jax.lax.dot_general(
-                pv, vh, (((1,), (0,)), ((), ())),
+            l_sc[h, :, 0:1] = l_sc[h, :, 0:1] * alpha + jnp.sum(
+                p, axis=1, keepdims=True)
+            m_sc[h, :, 0:1] = m_new
+            if quant:
+                p = p * _chunk_scale_row(sc_buf, slot, P, (h_kv + h) * bs, bs)
+            acc_sc[h] = acc_sc[h] * alpha + jax.lax.dot_general(
+                p.astype(mxu), vh.astype(mxu), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            return carry
 
-    @pl.when(i == max_blocks - 1)
+        # ONE head's body, looped: unrolled, eight KV heads of two branches
+        # compiled for half a minute
+        jax.lax.fori_loop(0, h_kv, head, 0)
+
+    @pl.when(g_lo < g_hi)
     def _():
-        bq, G = block_q, groups
-        l = l_sc[:, 0:1]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o = acc_sc[:] / safe_l                                  # [Hkv*bq*G, D]
-        o = o.reshape(h_kv, bq, G, -1)
-        o_ref[0] = jnp.moveaxis(o, 0, 1).reshape(bq, h_kv * G,
-                                                 -1).astype(o_ref.dtype)
+        for cp in copies(g_lo, 0):
+            cp.start()
+
+    def group(g, carry):
+        slot = jax.lax.rem(g - g_lo, 2)
+
+        @pl.when(g + 1 < g_hi)
+        def _():
+            for cp in copies(g + 1, 1 - slot):
+                cp.start()
+
+        for cp in copies(g, slot):
+            cp.wait()
+        k0 = g * T
+        inner = _chunk_group_inner(k0, T, row0, bq, ctx, window)
+
+        @pl.when(inner)
+        def _():
+            attend(slot, k0, masked=False)
+
+        @pl.when(jnp.logical_not(inner))
+        def _():
+            attend(slot, k0, masked=True)
+
+        return carry
+
+    jax.lax.fori_loop(g_lo, g_hi, group, 0)
+
+    l = l_sc[:, :, 0:1]
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o = (acc_sc[:] / safe_l).reshape(h_kv, bq, G, -1)           # [Hkv, R, D]
+    o_ref[0] = jnp.moveaxis(o, 0, 1).reshape(bq, h_kv * G,
+                                             -1).astype(o_ref.dtype)
 
 
-def _chunk_kernel_batched_quant(bt_ref, meta_ref, q_ref, kv_ref, sc_ref,
-                                o_ref, acc_sc, m_sc, l_sc, **kw):
-    _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_ref, o_ref,
-                          acc_sc, m_sc, l_sc, sc_ref=sc_ref, **kw)
+def _chunk_kernel_batched_quant(bt_ref, meta_ref, q_ref, kv_hbm, sc_hbm,
+                                o_ref, q_sc, kv_buf, sc_buf, sems, *rest,
+                                **kw):
+    _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref, q_sc,
+                          kv_buf, sems, *rest, sc_hbm=sc_hbm, sc_buf=sc_buf,
+                          **kw)
 
 
 def paged_chunk_attention_batched(q: jax.Array,
@@ -1746,45 +1850,53 @@ def paged_chunk_attention_batched(q: jax.Array,
         bq //= 2
     bq = max(bq, 1)
     nq = Cs // bq
+    r8 = _scale_tile_rows(Hkv, bs) if quant else 0
+    P = _pick_chunk_pages(bs, Hkv, D, kv_pages.dtype.itemsize, bq * G, MB,
+                          r8)
+    # what the MXU is handed: the operands as stored where both are
+    # bfloat16 (int8 pages widen to it exactly), float32 otherwise
+    mxu = jnp.bfloat16 if (q.dtype == jnp.bfloat16 and kv_pages.dtype in (
+        jnp.bfloat16, jnp.int8)) else jnp.float32
 
     meta = jnp.stack([jnp.asarray(q_starts, jnp.int32),
                       jnp.asarray(ctx_lens, jnp.int32)], axis=1)   # [NC, 2]
     kernel = functools.partial(
         _chunk_kernel_batched_quant if quant else _chunk_kernel_batched,
-        scale=scale, block_size=bs, block_q=bq, max_blocks=MB,
+        scale=scale, block_size=bs, block_q=bq, pages=P, max_blocks=MB,
         h_kv=Hkv, groups=G, window=window, alibi=alibi)
     in_specs = [
-        pl.BlockSpec((1, bq, H, D), lambda sl, iq, i, bt, m: (sl, iq, 0, 0)),
-        pl.BlockSpec((1, 2, Hkv, bs, D),
-                     lambda sl, iq, i, bt, m: (bt[sl, i], 0, 0, 0, 0)),
+        pl.BlockSpec((1, bq, H, D), lambda sl, iq, bt, m: (sl, iq, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [block_tables.astype(jnp.int32), meta, q, kv_pages]
+    scratch = [pltpu.VMEM((Hkv, bq * G, D), mxu),
+               pltpu.VMEM((2, P, 2, Hkv, bs, D), kv_pages.dtype)]
     if quant:
         assert (Hkv * bs) % 128 == 0
-        r8 = _scale_tile_rows(Hkv, bs)
-        in_specs += [
-            pl.BlockSpec((1, r8, 128),
-                         lambda sl, iq, i, bt, m: (bt[sl, i], 0, 0)),
-        ]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)]
         operands += [_scales_to_tiles(kv_scales)]
+        scratch += [pltpu.VMEM((2, P, r8, 128), jnp.float32)]
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((Hkv, bq * G, D), jnp.float32),
+        pltpu.VMEM((Hkv, bq * G, 128), jnp.float32),
+        pltpu.VMEM((Hkv, bq * G, 128), jnp.float32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(NC, nq, MB),
+        grid=(NC, nq),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, H, D),
-                               lambda sl, iq, i, bt, m: (sl, iq, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv * bq * G, D), jnp.float32),
-            pltpu.VMEM((Hkv * bq * G, 128), jnp.float32),
-            pltpu.VMEM((Hkv * bq * G, 128), jnp.float32),
-        ],
+                               lambda sl, iq, bt, m: (sl, iq, 0, 0)),
+        scratch_shapes=scratch,
     )
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((NC, Cs, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT),
         interpret=_backend.interpret(),
     )
     with jax.named_scope("paged_chunk"):

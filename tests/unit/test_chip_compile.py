@@ -216,6 +216,18 @@ CASES = {
     "decode_h16_kv2_d256": (
         paged_decode_attention,
         [((S, 16, 256), BF16), _pool(2, 256), ((S, 264), I32), _CL]),
+    # the same heads' prompt chunks over cached pages: 8 slots of 256 rows,
+    # 272-page tables, several pages a grid step through manual copies
+    "chunk_h16_kv2_d256": (
+        paged_chunk_attention_batched,
+        [((8, 256, 16, 256), BF16), _pool(2, 256), ((8, 272), I32),
+         ((8,), I32), ((8,), I32)]),
+    # int8 pages under a chunk: a scale tile a page beside its copy
+    "chunk_int8": (
+        lambda q, kv, bt, q0, cl, sc: paged_chunk_attention_batched(
+            q, kv, bt, q0, cl, kv_scales=sc),
+        [_CHUNK_Q, _pool(dtype=I8), ((4, MB), I32), ((4,), I32), ((4,), I32),
+         _SCALES]),
 }
 
 

@@ -85,6 +85,161 @@ class TestPagedChunkBatched:
         assert np.all(np.asarray(out)[3] == 0)
 
 
+# what a step of several pages, walked only where the sequence has pages, can
+# get wrong: (dims NC, Cs, H, Hkv, D, bs, MB), pages a step, q_starts, ctxs and
+# what else the call is given
+_STEP_CASES = {
+    "bfloat16_inputs": dict(dims=(3, 16, 8, 2, 64, 8, 8), P=3,
+                            q0s=[0, 13, 40], ctxs=[16, 29, 56],
+                            dtype=jnp.bfloat16),
+    "ctx_not_a_multiple_of_a_step": dict(dims=(2, 16, 8, 2, 64, 8, 8), P=3,
+                                         q0s=[29, 5], ctxs=[45, 21]),
+    "q0_inside_a_page": dict(dims=(2, 16, 4, 2, 32, 8, 8), P=2,
+                             q0s=[13, 35], ctxs=[29, 51]),
+    "window_edge_inside_a_group": dict(dims=(3, 16, 8, 2, 64, 8, 10), P=3,
+                                       q0s=[0, 30, 61], ctxs=[16, 46, 77],
+                                       window=20),
+    "window_wider_than_a_context": dict(dims=(2, 16, 4, 2, 32, 8, 6), P=2,
+                                        q0s=[0, 20], ctxs=[16, 36],
+                                        window=64),
+    "empty_slot_between_live_ones": dict(dims=(3, 16, 8, 2, 64, 8, 8), P=2,
+                                         q0s=[24, 0, 40], ctxs=[40, 0, 56]),
+    "g20_over_one_kv_head": dict(dims=(2, 8, 20, 1, 32, 8, 6), P=4,
+                                 q0s=[3, 38], ctxs=[11, 46]),
+    "d256": dict(dims=(2, 8, 4, 2, 256, 8, 6), P=4,
+                 q0s=[0, 33], ctxs=[8, 41]),
+    "int8_pages_of_128": dict(dims=(2, 16, 4, 2, 32, 128, 3), P=2,
+                              q0s=[0, 300], ctxs=[16, 316], int8=True),
+    "int8_pages_sharing_a_lane_row": dict(dims=(2, 16, 4, 2, 32, 64, 5), P=2,
+                                          q0s=[70, 200], ctxs=[86, 216],
+                                          int8=True),
+    "int8_pages_bfloat16_q": dict(dims=(2, 16, 4, 2, 32, 64, 5), P=3,
+                                  q0s=[70, 200], ctxs=[86, 216], int8=True,
+                                  dtype=jnp.bfloat16),
+    "alibi": dict(dims=(2, 16, 8, 2, 64, 8, 6), P=2,
+                  q0s=[0, 29], ctxs=[16, 45], alibi=True),
+    "alibi_window": dict(dims=(2, 16, 8, 2, 64, 8, 6), P=4,
+                         q0s=[0, 29], ctxs=[16, 45], alibi=True, window=12),
+    "verify_slots_of_4_rows": dict(dims=(5, 4, 8, 2, 64, 8, 6), P=2,
+                                   q0s=[0, 17, 44, 0, 30],
+                                   ctxs=[4, 21, 48, 0, 33]),
+    "one_page_a_step": dict(dims=(2, 16, 8, 2, 64, 8, 6), P=1,
+                            q0s=[0, 29], ctxs=[16, 45]),
+    "step_longer_than_the_table": dict(dims=(2, 16, 8, 2, 64, 8, 5), P=8,
+                                       q0s=[0, 24], ctxs=[16, 40]),
+    "picked_step": dict(dims=(2, 16, 8, 2, 64, 8, 12), P=None,
+                        q0s=[70, 5], ctxs=[86, 21], window=48),
+}
+
+
+class TestPagedChunkStep:
+    """The chunk kernel's grid step: ``P`` pages at once, from the window's
+    first page to the diagonal or the context's end, the MXU handed the
+    operands as stored, no mask where a group is wholly visible."""
+
+    def _arguments(self, case, seed=49):
+        from deepspeed_tpu.ops.pallas.paged_attention import kv_quantize_rows
+        NC, Cs, H, Hkv, D, bs, MB = case["dims"]
+        rng = np.random.RandomState(seed)
+        dtype = case.get("dtype", jnp.float32)
+        NB = NC * MB + 1
+        kv = jnp.asarray(rng.randn(NB, 2, Hkv, bs, D), dtype)
+        q = jnp.asarray(rng.randn(NC, Cs, H, D), dtype)
+        bt = jnp.asarray(rng.permutation(NB - 1)[:NC * MB].reshape(NC, MB) + 1,
+                         jnp.int32)
+        kw = {k: case[k] for k in ("window", "alibi") if k in case}
+        ref_kv, call_kv = kv.astype(jnp.float32), kv
+        if case.get("int8"):
+            call_kv, sc = kv_quantize_rows(kv)
+            ref_kv = call_kv.astype(jnp.float32) * sc[..., None]
+            kw["kv_scales"] = sc
+        tail = (bt, jnp.asarray(case["q0s"], jnp.int32),
+                jnp.asarray(case["ctxs"], jnp.int32))
+        return (q, call_kv) + tail, (q.astype(jnp.float32), ref_kv) + tail, kw
+
+    @staticmethod
+    def _pages(monkeypatch, pages):
+        """Hold a step to ``pages`` pages (None: what the shapes pick)."""
+        from deepspeed_tpu.ops.pallas import paged_attention as pa
+        if pages is not None:
+            monkeypatch.setattr(pa, "_pick_chunk_pages",
+                                lambda *a, **kw: pages)
+        return pa
+
+    @pytest.mark.parametrize("name", sorted(_STEP_CASES))
+    def test_matches_float32_reference(self, name, monkeypatch):
+        case = _STEP_CASES[name]
+        pa = self._pages(monkeypatch, case["P"])
+        args, ref_args, kw = self._arguments(case)
+        out = pa.paged_chunk_attention_batched(*args, **kw)
+        assert out.dtype == args[0].dtype
+        kw.pop("kv_scales", None)
+        ref = pa.paged_chunk_attention_batched_reference(*ref_args, **kw)
+        # a bfloat16 call rounds p for the MXU and its output: 2**-8
+        tol = dict(atol=3e-5, rtol=3e-4) if out.dtype == jnp.float32 \
+            else dict(atol=2e-2, rtol=2e-2)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), **tol)
+        for sl, ctx in enumerate(case["ctxs"]):
+            if ctx == 0:
+                assert not np.asarray(out[sl], np.float32).any()
+
+    @pytest.mark.parametrize("name", ["q0_inside_a_page",
+                                      "window_edge_inside_a_group",
+                                      "bfloat16_inputs",
+                                      "int8_pages_sharing_a_lane_row"])
+    def test_unmasked_and_masked_branch_agree(self, name, monkeypatch):
+        """A group every row sees whole may take either branch: with the
+        interior test switched off every group builds its mask, and the
+        result is the same."""
+        pa = self._pages(monkeypatch, 1)
+        case = _STEP_CASES[name]
+        args, _, kw = self._arguments(case)
+        taken = []
+        inner = pa._chunk_group_inner
+        monkeypatch.setattr(
+            pa, "_chunk_group_inner",
+            lambda *a: taken.append(1) or inner(*a))
+        out = pa.paged_chunk_attention_batched(*args, **kw)
+        assert taken                         # the kernel asks this function
+        monkeypatch.setattr(pa, "_chunk_group_inner",
+                            lambda k0, *a: k0 < 0)
+        masked = pa.paged_chunk_attention_batched(*args, **kw)
+        # the same arithmetic: what is left is how the interpreter's two
+        # programs fuse it (one unit in the last place of a float32)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(masked, np.float32),
+                                   atol=1e-6, rtol=1e-6)
+
+    def test_which_groups_build_no_mask(self):
+        """The predicate itself, on plain integers: below the first row,
+        inside ctx, inside the last row's window."""
+        from deepspeed_tpu.ops.pallas.paged_attention import \
+            _chunk_group_inner as inner
+        i = lambda *a: bool(inner(*map(np.int32, a[:5]), a[5]))
+        assert i(0, 24, 23, 16, 40, None)        # last key == first row
+        assert not i(0, 24, 22, 16, 40, None)    # straddles the diagonal
+        assert not i(24, 24, 60, 16, 47, None)   # runs past ctx
+        assert i(24, 24, 60, 16, 48, None)
+        assert i(24, 24, 60, 16, 76, 52)         # 24 > 60 + 15 - 52
+        assert not i(24, 24, 60, 16, 76, 51)     # the last row's edge at 24
+
+    def test_step_pages_follow_the_shapes(self):
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            MAX_PAGES_PER_CHUNK_STEP, _pick_chunk_pages)
+        cell11 = _pick_chunk_pages(128, 2, 256, 2, 128 * 8, 272)
+        assert 2 <= cell11 <= MAX_PAGES_PER_CHUNK_STEP
+        # more rows a KV head or wider pages: no more pages a step
+        assert _pick_chunk_pages(128, 1, 128, 2, 128 * 20, 64) <= cell11
+        assert _pick_chunk_pages(128, 8, 128, 2, 128 * 4, 40) <= \
+            _pick_chunk_pages(128, 4, 128, 2, 128 * 4, 40)
+        # never more than the table has, never fewer than one
+        assert _pick_chunk_pages(128, 2, 256, 2, 1024, 3) == 3
+        assert _pick_chunk_pages(128, 8, 256, 4, 128 * 64, 40) == 1
+        assert _pick_chunk_pages(8, 2, 64, 4, 64, 100) == \
+            MAX_PAGES_PER_CHUNK_STEP
+
+
 class TestPagedDecodeStep:
     """Fused decode step: prior-context flash + inline current token + page
     write, pool aliased through. Edge cases: ctx 1 (no pages yet), page

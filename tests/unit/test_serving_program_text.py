@@ -19,8 +19,10 @@ another commit records that commit's programs: how the file was made;
 programs alone and merges them into the file under ``later``: how granite's
 were, from 83a3dac, and how every family's decode step was by PR 45, whose
 tree took the step loop — a ``lax.scan`` of length one around the pass —
-out of that program's text; the paged pass and the packed prefill of all six
-are still the older commits').
+out of that program's text, and how the K/V families' paged pass was by PR
+49, whose chunk kernel walks a slot's own pages in groups — JoyAI's paged
+pass, over latent pages, kept its hash; the packed prefill of all six is
+still the older commits').
 
 Beside the hashes: which of its two forms each family's toy decode step
 takes (``ragged_model.side_buffer_fits``), and that the traced step holds
