@@ -200,7 +200,10 @@ class AttentionKernelSpec:
         # pages of an earlier position, or moves its pages without its
         # state, would need a snapshot of the state at a block boundary,
         # which no program writes (docs/SERVING.md "State-space layers")
-        if getattr(spec, "mamba", None) is not None:
+        # .. and so does a layer that keeps a convolution tail beside its
+        # pages (``spec.cca``): pages of an earlier position have no tail
+        if getattr(spec, "mamba", None) is not None \
+                or getattr(spec, "cca", None) is not None:
             from deepspeed_tpu.inference.v2.scheduler import (
                 STATE_SNAPSHOT_MSG)
             refused = {

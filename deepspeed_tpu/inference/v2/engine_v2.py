@@ -283,10 +283,10 @@ class InferenceEngineV2:
         # the recurrent-state pools of a model with state-space layers: one
         # slot per tracked sequence (+ the dump slot), riding with the pages
         # as ONE donated pytree through every program
+        from deepspeed_tpu.inference.v2.ragged.state_pool import (
+            StatefulKV, StatePoolConfig, StateSlotAllocator)
         self.state_config = None
         if self.spec.mamba is not None:
-            from deepspeed_tpu.inference.v2.ragged.state_pool import (
-                StatefulKV, StatePoolConfig, StateSlotAllocator)
             m = self.spec.mamba
             ssd = m.get("kind") == "mamba2"
             self.state_config = StatePoolConfig(
@@ -297,6 +297,14 @@ class InferenceEngineV2:
                 # layer q, k and v (its spec says how many channels)
                 conv_dim=m["d_inner"] + 2 * m["n_groups"] * m["d_state"]
                 if ssd else m.get("conv_dim"))
+        elif self.spec.cca is not None:
+            # attention that keeps a convolution tail beside its pages: the
+            # same pool with no recurrent state in it (tails only)
+            self.state_config = StatePoolConfig.tails_only(
+                num_state_layers(self.spec), sm.max_tracked_sequences,
+                taps=self.spec.cca["taps"],
+                channels=self.spec.cca["tail_channels"])
+        if self.state_config is not None:
             self.scheduler.state_slots = StateSlotAllocator(
                 sm.max_tracked_sequences)
             with _tracer.stage("kv_alloc"):
@@ -427,14 +435,27 @@ class InferenceEngineV2:
                         / (kv_cfg.num_layers * kv_cfg.block_size))
         if self.spec.moe is not None and "held" in self.spec.moe:
             _tracer.note("serve/moe/held_experts", self.spec.moe["held"][1])
+        if self.spec.moe is not None:
+            # (1: one linear map of the layer's input; 2: an MLP on a state
+            # that goes from layer to layer)
+            _tracer.note("serve/moe/router_kind",
+                         2 if self.spec.moe.get("router") == "mlp" else 1)
         if self.state_config is not None:
             # always-on values: what a tracked sequence costs the state pool
             # over all its layers, and which recurrence fills it
             _tracer.note("serve/state/bytes_per_sequence",
                          self.state_config.bytes_per_slot())
-            # (1, 2: the Mamba recurrence; 3: the gated delta rule)
-            kind = {"mamba2": 2, "gdn": 3}.get(self.spec.mamba.get("kind"), 1)
-            recurrence = "Gated DeltaNet" if kind == 3 else f"Mamba-{kind}"
+            # (1, 2: the Mamba recurrence; 3: the gated delta rule; 4: no
+            # recurrence, convolution tails beside attention's pages)
+            if self.spec.mamba is None:
+                kind, recurrence = 4, "convolution tails (no recurrence)"
+                _tracer.note("serve/cca/tail_channels",
+                             self.spec.cca["tail_channels"])
+            else:
+                kind = {"mamba2": 2, "gdn": 3}.get(
+                    self.spec.mamba.get("kind"), 1)
+                recurrence = "Gated DeltaNet" if kind == 3 \
+                    else f"Mamba-{kind}"
             _tracer.note("serve/state/kind", kind)
         if any(k.block is not None for k in self.spec.layer_kinds or ()):
             # one block a layer: how many layers are each block (what a
@@ -785,6 +806,13 @@ class InferenceEngineV2:
         slot = self.scheduler.seqs[int(uid)].state_slot
         if slot < 0:
             raise ValueError("this model has no state-space layers")
+        sc = self.state_config
+        if not sc.d_state:
+            # tails only (no recurrence): the sequence's convolution tails
+            # ``[L, taps, channels]``, oldest token first
+            tail = fetch_to_host(self.kv.kv.conv[:, slot])
+            return tail.reshape(sc.num_layers, sc.d_conv - 1,
+                                sc.conv_width)[..., :sc.conv_dim]
         return fetch_to_host(self.kv.kv.ssm[:, slot])
 
     @property

@@ -16,6 +16,15 @@ share the pool (``ops/pallas/ssm.py`` states both):
   convolution runs over ``E + 2 G N`` channels — x, B and C together
   (``conv_dim``; 8,448 there), padded to whole tiles a tap (9,216).
 
+A third tenant keeps NO recurrence: a layer of compressed convolutional
+attention (zaya) writes K and V into pages as any attention layer does, and
+beside them keeps the tail of the two small convolutions that mix its q and k
+along the sequence, plus the one earlier value that its second value head is
+(2 taps x 1,408 channels, padded to 2,048: 16 KiB a sequence a layer). Its
+pool is :meth:`StatePoolConfig.tails_only`: ``ssm`` of zero size, ``conv`` as
+below, and the layer updates a decode row's tail itself (no recurrence kernel
+follows to do it).
+
 Pages do not fit that: their lifetime follows tokens, the allocator frees and
 shares them block by block, and a state can be neither shared nor rolled
 back. So there is a second kind of per-sequence device state with a lifetime
@@ -92,6 +101,15 @@ class StatePoolConfig:
     def __post_init__(self):
         if self.d_inner % 8:
             raise ValueError(f"d_inner {self.d_inner} is not a multiple of 8")
+
+    @classmethod
+    def tails_only(cls, num_layers: int, num_slots: int, taps: int,
+                   channels: int) -> "StatePoolConfig":
+        """The pool of layers that keep a convolution tail and NO recurrent
+        state (compressed convolutional attention, beside its pages): ``ssm``
+        is of zero size and a slot costs its ``taps`` x ``channels`` tail."""
+        return cls(num_layers=num_layers, num_slots=num_slots, d_inner=0,
+                   d_state=0, d_conv=taps + 1, conv_dim=channels)
 
     @property
     def conv_width(self) -> int:

@@ -91,6 +91,7 @@ class LayerKind(NamedTuple):
     moe: bool               # routed experts (else the dense MLP)
     mamba = False           # an attention layer (else: MambaKind)
     block = None            # the pair: mixer, then FFN (else: BlockKind)
+    tail = False            # pages alone (else: CcaKind)
 
     def describe(self) -> str:
         attn = "full" if self.window is None else f"window {self.window}"
@@ -111,6 +112,7 @@ class MambaKind(NamedTuple):
     rope = False
     mamba = True
     block = None
+    tail = False
 
     def describe(self) -> str:
         return ("Mamba state-space mixer (no pages), "
@@ -131,9 +133,32 @@ class DeltaKind(NamedTuple):
     rope = False
     mamba = True
     block = None
+    tail = False
 
     def describe(self) -> str:
         return ("Gated DeltaNet delta-rule mixer (no pages), "
+                f"{'MoE' if self.moe else 'dense'} FFN")
+
+
+class CcaKind(NamedTuple):
+    """The kind of a layer whose attention is compressed convolutional
+    attention (zaya; :func:`_cca_project`): q and k are mixed along the
+    sequence by two small causal convolutions before they attend, and one
+    value head is the previous token's. Such a layer addresses BOTH pools: it
+    writes K and V into pages as any attention layer does (full, rotary), and
+    keeps a tail of the convolutions' last inputs in a slot of the state
+    pool (``tail``), with no recurrent state beside it
+    (``RaggedModelSpec.cca`` holds its widths)."""
+    moe: bool = True        # routed experts (else the dense MLP)
+    window = None
+    rope = True
+    mamba = False
+    block = None
+    tail = True
+
+    def describe(self) -> str:
+        return ("compressed convolutional attention (full, rotary; pages "
+                "and a convolution tail), "
                 f"{'MoE' if self.moe else 'dense'} FFN")
 
 
@@ -146,6 +171,7 @@ class BlockKind(NamedTuple):
     what: str                       # "mamba" | "attention" | "moe" | "mlp"
     window: Optional[int] = None    # of an attention block
     rope: bool = False
+    tail = False
 
     @property
     def mamba(self) -> bool:
@@ -172,9 +198,12 @@ class BlockKind(NamedTuple):
 
 def _holds(kind) -> Optional[str]:
     """The pool a layer of ``kind`` addresses: ``"state"`` (a Mamba mixer),
-    ``"pages"`` (attention) or None (an FFN alone)."""
+    ``"pages"`` (attention), ``"both"`` (attention that keeps a convolution
+    tail: :class:`CcaKind`) or None (an FFN alone)."""
     if kind.mamba:
         return "state"
+    if kind.tail:
+        return "both"
     return None if kind.block == "ffn" else "pages"
 
 
@@ -206,7 +235,12 @@ class RaggedModelSpec:
     # over the chosen (Mixtral). With "score_func": "sigmoid" the scores are
     # sigmoid(logits), chosen with the layer's "expert_bias" added, weighed
     # without it, over their sum if "route_norm", times "route_scale" (afmoe,
-    # joyai). "held": (first, count) — the expert stacks hold only experts
+    # joyai). "router": "mlp" (zaya) — an MLP of width "router_hidden" on a
+    # state that every layer's router adds to and hands to the next
+    # (:func:`moe_route_mlp`), top-1 by its softmax with a stored bias, the
+    # weight not renormalised; with "skip" it scores one choice more than
+    # there are experts, and a token that takes it passes no expert.
+    # "held": (first, count) — the expert stacks hold only experts
     # first..first+count-1 of the E the router scores (one chip's share of an
     # expert-parallel deployment); absent: all E. "act": the plain activation
     # of experts that are two matrices (no gate stack), as ``activation``
@@ -246,6 +280,13 @@ class RaggedModelSpec:
     # convolved channels (q, k and v: 2 Hk N + E), "chunk": the chunked
     # scan's chunk}
     mamba: Optional[Dict[str, Any]] = None
+    # widths of compressed convolutional attention (:class:`CcaKind`; zaya):
+    # {"time0", "time1": the taps of the depthwise and of the grouped
+    # convolution, "conv_dim": the channels they mix (q and k of every head),
+    # "tail_channels": the channels a sequence keeps a tail of (those and the
+    # shifted value's), "taps": how many earlier tokens it keeps (time0 +
+    # time1 - 2)}. On a run's spec it is set for a run of such layers
+    cca: Optional[Dict[str, Any]] = None
     # plain multipliers (granite): on the embedding's output, on each
     # branch's output before it joins the residual stream, on the logits,
     # and the softmax scale where it is not head_dim ** -0.5. None (or the
@@ -268,6 +309,7 @@ def _run_spec(spec: RaggedModelSpec, kind) -> RaggedModelSpec:
                    rope_theta=spec.rope_theta if kind.rope else None,
                    moe=spec.moe if kind.moe else None,
                    mamba=spec.mamba if kind.mamba else None,
+                   cca=spec.cca if kind.tail else None,
                    block=kind.block)
 
 
@@ -356,6 +398,8 @@ def describe_layer_kinds(spec: RaggedModelSpec) -> str:
         if rs.mamba is not None:
             state = DeltaKind if rs.mamba.get("kind") == "gdn" else MambaKind
             return state(rs.moe is not None).describe()
+        if rs.cca is not None:
+            return CcaKind(rs.moe is not None).describe()
         return LayerKind(rs.window, rs.rope_theta is not None,
                          rs.moe is not None).describe()
 
@@ -371,13 +415,17 @@ def _layer_holds(spec: RaggedModelSpec) -> List[Optional[str]]:
     """For each layer, the pool it addresses (:func:`_holds`)."""
     if spec.layer_kinds is None:
         return ["state" if spec.mamba is not None else
+                "both" if spec.cca is not None else
                 None if spec.block == "ffn" else "pages"] * spec.num_layers
     return [_holds(k) for k in spec.layer_kinds]
 
 
 def num_state_layers(spec: RaggedModelSpec) -> int:
-    """Layers that hold a recurrent state per sequence (Mamba mixers)."""
-    return _layer_holds(spec).count("state")
+    """Layers that hold a slot of the state pool per sequence: Mamba mixers
+    (a recurrent state and a tail) and attention that keeps a convolution
+    tail beside its pages."""
+    holds = _layer_holds(spec)
+    return holds.count("state") + holds.count("both")
 
 
 def num_page_layers(spec: RaggedModelSpec) -> int:
@@ -385,19 +433,26 @@ def num_page_layers(spec: RaggedModelSpec) -> int:
     page's bytes and of everything counted in tokens x layers: the layers
     that attend. Not ``spec.num_layers`` where some layers carry no
     attention (a Mamba mixer holds a state, an FFN alone holds nothing)."""
-    return _layer_holds(spec).count("pages")
+    holds = _layer_holds(spec)
+    return holds.count("pages") + holds.count("both")
 
 
-def _pool_index(spec: RaggedModelSpec) -> List[int]:
+def _pool_index(spec: RaggedModelSpec, pool: Optional[str] = None
+                ) -> List[int]:
     """For each layer, its index in the pool it addresses: its rank among
     the layers of its sort (pages for attention, state for Mamba; a layer
     that addresses neither counts among its like, and nothing reads that).
     A model whose layers all hold pages addresses them by the layer's index
-    in the model, as ever."""
+    in the model, as ever. A layer that addresses both pools
+    (:class:`CcaKind`) counts among the layers that hold pages; with ``pool``
+    named (``"pages"`` or ``"state"``) the ranks are those among the layers
+    that address THAT pool, such a layer counted in each."""
     holds = _layer_holds(spec)
+    sort = (lambda h: "pages" if h == "both" else h) if pool is None else \
+        (lambda h: pool if h in (pool, "both") else None)
     seen: Dict[Optional[str], int] = {}
     index = []
-    for h in holds:
+    for h in map(sort, holds):
         index.append(seen.get(h, 0))
         seen[h] = index[-1] + 1
     return index
@@ -1156,6 +1211,113 @@ def adapt_qwen3_next(params: Dict, config,
     return spec, weights
 
 
+def adapt_zaya(params: Dict, config,
+               max_context: Optional[int] = None
+               ) -> Tuple[RaggedModelSpec, Dict]:
+    """models/zaya.py param tree (ZayaForCausalLM; Zyphra ZAYA1, ``zaya``),
+    published layout. Every layer is of one kind, :class:`CcaKind` over
+    routed experts, so the scalar fields say it (``spec.cca``, ``spec.moe``
+    with ``"router": "mlp"``).
+
+    - the four compressed projections become ONE matrix ``cca.in_proj``,
+      columns ``[qp | kp | z | v1]``: the first ``tail_channels`` are what a
+      sequence keeps a tail of (q and k of every head for the convolutions,
+      ``z`` for the shifted value), the token's own value last;
+    - the depthwise taps are stored ``[tap, channel]`` and the grouped
+      convolution's ``[tap, head, in, out]`` (PyTorch: ``[out, in, tap]``);
+    - the rotation pairs value ``i`` with ``i + rotary_dim / 2`` where the
+      ragged path's pairs ``2i`` with ``2i + 1``: the first ``rotary_dim``
+      channels of each q and k head are interleaved, the same way in the
+      projections, both convolutions' weights and biases (the q-k mean is
+      channel by channel and the norm does not see the order), which leaves
+      every ``q . k`` as it was. The tail pool holds the channels in that
+      order (:func:`zaya_channel_order`);
+    - the router's state scale ``gamma`` of the FIRST layer is zero: its
+      router is handed a state of zeros and adds ``gamma * 0``, which is the
+      published "every layer but the first" without a layer of another
+      shape."""
+    del max_context
+    H, Hk, D = (config.num_attention_heads, config.num_key_value_heads,
+                config.head_dim)
+    C, K0, K1 = config.conv_dim, config.cca_time0, config.cca_time1
+    E = config.num_experts
+    spec = RaggedModelSpec(
+        family="zaya",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=H, num_kv_heads=Hk, head_dim=D,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
+        rotary_dim=config.rotary_dim, tied_lm_head=True,
+        eps=config.rms_norm_eps,
+        moe={"num_experts": E, "top_k": 1, "router": "mlp",
+             "router_hidden": config.router_hidden_size, "skip": True},
+        cca={"time0": K0, "time1": K1, "conv_dim": C,
+             "tail_channels": C + D, "taps": config.tail_taps},
+        dtype=config.dtype)
+    turn = zaya_channel_order(H + Hk, D, config.rotary_dim)     # [C]
+    turn_d = turn[:D]
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        attn, ff = lp["self_attn"], lp["mlp"]
+        w1 = attn["conv1_weight"].reshape(C // D, D, D, K1)   # h, out, in, tap
+        gamma = ff["router_state_scale"]
+        return {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
+            "res_scale": lp["residual_scale"],
+            "res_bias": lp["residual_bias"],
+            "cca": {
+                "in_proj": jnp.concatenate(
+                    [attn["q_proj"]["kernel"][:, turn[:H * D]],
+                     attn["k_proj"]["kernel"][:, turn[H * D:] - H * D],
+                     attn["v_prev_proj"]["kernel"],
+                     attn["v_proj"]["kernel"]], axis=1),
+                "conv0_w": jnp.transpose(attn["conv0_weight"][turn]),
+                "conv0_b": attn["conv0_bias"][turn],
+                "conv1_w": jnp.transpose(
+                    w1[:, turn_d][:, :, turn_d], (3, 0, 2, 1)),
+                "conv1_b": attn["conv1_bias"][turn],
+                "temp": attn["temp"],
+            },
+            "wo": attn["o_proj"]["kernel"],
+            "moe": {
+                "router_down": ff["router_down"]["kernel"],
+                "router_down_b": ff["router_down"]["bias"],
+                "router_gamma": gamma if i else jnp.zeros_like(gamma),
+                "router_norm": ff["router_norm"]["weight"],
+                "router_fc1": ff["router_fc1"]["kernel"],
+                "router_fc1_b": ff["router_fc1"]["bias"],
+                "router_fc2": ff["router_fc2"]["kernel"],
+                "router_fc2_b": ff["router_fc2"]["bias"],
+                "router_out": ff["router_out"]["kernel"],
+                "router_bias": ff["balancing_bias"],
+                "w_gate": ff["w_gate"], "w_up": ff["w_up"],
+                "w_down": ff["w_down"],
+            },
+        }
+
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": _stack([layer(i) for i in range(config.num_hidden_layers)]),
+        "final_norm": {"scale": params["norm"]["weight"]},
+    }
+    return spec, weights
+
+
+def zaya_channel_order(heads: int, head_dim: int, rotary_dim: int
+                       ) -> np.ndarray:
+    """For each channel of ``heads`` heads of ``head_dim`` as
+    :func:`adapt_zaya` lays them out, the published channel it holds: inside
+    a head's first ``rotary_dim`` values, half-split pairs (``i``, ``i +
+    rotary_dim / 2``) become neighbours (``2i``, ``2i + 1``)."""
+    turn = np.concatenate([
+        np.arange(rotary_dim).reshape(2, rotary_dim // 2).T.reshape(-1),
+        np.arange(rotary_dim, head_dim)])
+    return (np.arange(heads)[:, None] * head_dim + turn[None]).reshape(-1)
+
+
 ADAPTERS: Dict[str, Callable] = {
     # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
     # LlamaConfig features the adapter reads)
@@ -1196,6 +1358,11 @@ ADAPTERS: Dict[str, Callable] = {
     # each rotated; 512 small experts of which this chip may hold a share,
     # and a shared expert behind a sigmoid gate
     "qwen3_next": adapt_qwen3_next,
+    # compressed convolutional attention (pages AND a convolution tail in
+    # every layer: CcaKind, _cca_project), a top-1 MLP router whose state
+    # goes from layer to layer, a choice that skips the experts, learned
+    # scales and biases where a branch joins the stream
+    "zaya": adapt_zaya,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
@@ -1339,6 +1506,15 @@ def _scan_layers(spec: "RaggedModelSpec", layers, make_body, carry,
     units = layer_units(spec)
     assert len(stacks) == len(units), (len(stacks), len(units))
     index = _pool_index(spec)
+    if spec.cca is not None and index != _pool_index(spec, "state"):
+        # a layer that keeps a tail beside its pages is handed ONE index for
+        # both pools: its rank among the layers that hold pages has to be its
+        # rank among those that hold a state slot
+        raise NotImplementedError(
+            "layers that keep a convolution tail beside their pages, in a "
+            "model where other layers address one pool only: the layer loop "
+            "hands a layer one index, and its place differs between the "
+            "pools")
 
     def body_of(rs, experts, l0):
         return (_ffn_body if rs.block == "ffn" else make_body)(rs, experts,
@@ -1460,9 +1636,45 @@ def moe_route(x: jax.Array, w: Dict, top_k: int,
     return gates, ids
 
 
+def moe_route_mlp(x: jax.Array, w: Dict, routing: Dict[str, Any],
+                  r_in: jax.Array, eps: float
+                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router that is an MLP with a state (zaya; ``routing["router"] ==
+    "mlp"``): each token's choice ``[T, 1]`` among the experts and, with
+    ``routing["skip"]``, one choice more (id ``num_experts``: no expert),
+    its weight ``[T, 1]`` and the router's state ``[T, R]`` for the next
+    layer, all float32, the products at the highest precision (the choice is
+    an argmax over probabilities that lie a few hundredths apart).
+
+    ``r = x Wd + bd + gamma * r_in`` (``router_gamma`` is zero in the first
+    layer); ``logits = W3 gelu(W2 gelu(W1 nr(r) + b1) + b2)``, ``nr`` an
+    RMSNorm, gelu exact; ``p = softmax(logits)``; the choice is ``argmax(p +
+    router_bias)`` — the stored bias balances load, it chooses and does not
+    weigh — and its weight ``p`` of it, not renormalised."""
+    f32 = jnp.float32
+    g = lambda name: w[name].astype(f32)
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    gelu = _PLAIN_ACTS["gelu_exact"]
+    with jax.named_scope("mlp"):
+        r = dot(x.astype(f32), g("router_down")) + g("router_down_b") \
+            + g("router_gamma") * r_in
+        h = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + eps) \
+            * g("router_norm")
+        h = gelu(dot(h, g("router_fc1")) + g("router_fc1_b"))
+        h = gelu(dot(h, g("router_fc2")) + g("router_fc2_b"))
+        p = jax.nn.softmax(dot(h, g("router_out")), axis=-1)
+        if not routing.get("skip"):
+            p = p[:, :routing["num_experts"]]
+        ids = jnp.argmax(p + g("router_bias")[:p.shape[-1]], axis=-1)[:, None]
+        gates = jnp.take_along_axis(p, ids, axis=-1)
+    return gates, ids.astype(jnp.int32), r
+
+
 @jax.named_scope("moe_ffn")
 def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
-             routing: Optional[Dict[str, Any]] = None) -> jax.Array:
+             routing: Optional[Dict[str, Any]] = None,
+             routed: Optional[Tuple[jax.Array, jax.Array]] = None
+             ) -> jax.Array:
     """Sort-based token dispatch + grouped GEMM (parity: reference moe_scatter ->
     CUTLASS moe_gemm -> moe_gather, inference/v2/kernels). x: [T, hid].
 
@@ -1484,13 +1696,26 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     ``w_gate`` stack are SwiGLUs; without one they are two matrices with the
     plain activation ``routing["act"]`` between them (``"relu2"``:
     nemotron_h; absent: tanh-gelu), and so is a shared expert without one.
+
+    ``routed`` is ``(gates, ids)`` of a router that ran before
+    (:func:`moe_route_mlp`, whose state is the caller's to carry). With
+    ``routing["skip"]`` an id of ``num_experts`` is the choice of no expert:
+    it is dropped the way an assignment to an expert not held is — a row
+    past the groups, which no grouped product visits — and the token's
+    output is zero.
     """
     T, hid = x.shape
-    E = w["router"].shape[-1]
     held = (routing or {}).get("held")
     plain = _plain_act((routing or {}).get("act", "gelu"))
-    with jax.named_scope("router"):
-        gates, ids = moe_route(x, w, top_k, routing)
+    if routed is None:
+        E = w["router"].shape[-1]
+        with jax.named_scope("router"):
+            gates, ids = moe_route(x, w, top_k, routing)
+    else:
+        E = routing["num_experts"]
+        gates, ids = routed
+        if routing.get("skip") and held is None:
+            held = (0, E)
 
     with jax.named_scope("sort"):
         tok_idx = jnp.repeat(jnp.arange(T), top_k)                     # [T*K]
@@ -1847,17 +2072,38 @@ def _tail_slots(conv):
     return conv.reshape((-1,) + conv.shape[2:])
 
 
-def _conv_rows(conv, conv2, l, rows: _StateRows, a, conv_w, bias, dtype):
-    """The causal depthwise convolution and SiLU over the rows ``a`` ``[T,
-    W]`` of a layer that keeps a state (:func:`_mamba_mixer`,
-    :func:`_gdn_mixer`), each row's ``K - 1`` predecessors read from the rows
-    before, the slot before or the tail pool ``conv`` (``conv2``:
+def _depthwise_silu(conv_w, bias, dtype):
+    """``mix`` of :func:`_conv_rows` for a Mamba or Gated DeltaNet layer: the
+    causal depthwise convolution and SiLU. ``conv_w`` ``[K, W]`` float32,
+    ``bias`` ``[W]`` float32 or 0.0."""
+    K = conv_w.shape[0]
+    f32 = jnp.float32
+
+    def conv_act(ext):          # [.., K + n - 1, W] inputs -> [.., n, W]
+        n = ext.shape[-2] - (K - 1)
+        acc = bias + sum(ext[..., j:j + n, :].astype(f32) * conv_w[j]
+                         for j in range(K))
+        return jax.nn.silu(acc).astype(dtype)
+
+    return conv_act
+
+
+def _conv_rows(conv, conv2, l, rows: _StateRows, a, K: int, mix, dtype,
+               shift_decode: bool = False):
+    """What mixes the rows ``a`` ``[T, W]`` of a layer along the sequence
+    over ``K`` taps — ``mix``, which is handed each row behind its ``K - 1``
+    predecessors ``[.., K - 1 + n, W]`` and returns ``[.., n, W']``: the
+    causal depthwise convolution and SiLU of a layer that keeps a state
+    (:func:`_depthwise_silu`; :func:`_mamba_mixer`, :func:`_gdn_mixer`), the
+    two convolutions and the shifted value of compressed convolutional
+    attention (:func:`_cca_project`) — each row's predecessors read from the
+    rows before, the slot before or the tail pool ``conv`` (``conv2``:
     :func:`_tail_slots` of it) at layer ``l``; the chunk slots' new tails are
-    written there (a decode row's shift rides with its recurrence kernel).
-    ``conv_w`` ``[K, W]`` float32, ``bias`` ``[W]`` float32 or 0.0. Returns
-    ``(the convolved rows [T, W] in dtype, conv, the chunk slots'
+    written there. A decode row's shift by one token rides with its
+    recurrence kernel, or, where no kernel follows (``shift_decode``), is
+    written here. Returns ``(the mixed rows [T, W'], conv, the chunk slots'
     _ChunkRows)``."""
-    K, W = conv_w.shape
+    W = a.shape[-1]
     NS1 = conv.shape[1]
     dump = NS1 - 1
     # a slot's tile rows are its K - 1 taps x W channels in order (padded to
@@ -1872,14 +2118,6 @@ def _conv_rows(conv, conv2, l, rows: _StateRows, a, conv_w, bias, dtype):
         taps = lambda rows_: conv2[rows_].reshape(-1, K - 1, Wp)[..., :W]
         as_taps = lambda t: jnp.pad(
             t, ((0, 0), (0, 0), (0, Wp - W))).reshape((-1,) + conv.shape[2:])
-    f32 = jnp.float32
-
-    def conv_act(ext):          # [.., K + n - 1, W] inputs -> [.., n, W]
-        n = ext.shape[-2] - (K - 1)
-        acc = bias + sum(ext[..., j:j + n, :].astype(f32) * conv_w[j]
-                         for j in range(K))
-        return jax.nn.silu(acc).astype(dtype)
-
     parts, chunk = [], _ChunkRows()
     if rows.chunk_slot is not None:
         NC = rows.chunk_slot.shape[0]
@@ -1898,7 +2136,7 @@ def _conv_rows(conv, conv2, l, rows: _StateRows, a, conv_w, bias, dtype):
             jnp.roll(a_c[:, Cs - (K - 1):], 1, axis=0),
             jnp.where((mode == 1)[:, None, None], taps(pool_rows), 0))
         ext = jnp.concatenate([tail.astype(dtype), a_c], axis=1)
-        parts.append(conv_act(ext).reshape(CT, W))
+        parts.append(mix(ext).reshape(CT, -1))
         new_tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice(
             e, (n, 0), (K - 1, W)))(ext, rows.chunk_ntok)
         conv2 = conv2.at[store_rows].set(
@@ -1910,7 +2148,10 @@ def _conv_rows(conv, conv2, l, rows: _StateRows, a, conv_w, bias, dtype):
         drows = l * NS1 + rows.decode_slot
         ext = jnp.concatenate([taps(drows).astype(dtype),
                                a[chunk.CT:, None]], axis=1)      # [S, K, W]
-        parts.append(conv_act(ext)[:, 0])
+        parts.append(mix(ext)[:, 0])
+        if shift_decode:
+            conv2 = conv2.at[drows].set(
+                as_taps(ext[:, 1:].astype(conv.dtype)))
     conv = conv2.reshape(conv.shape)
     c = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
     return c, conv, chunk
@@ -1977,8 +2218,9 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     else:
         a, z = az[:, :E], az[:, E:]
     with jax.named_scope("conv"):
-        c, conv, chunk = _conv_rows(conv, conv2, l, rows, a, conv_w, conv_b,
-                                    dtype)
+        c, conv, chunk = _conv_rows(
+            conv, conv2, l, rows, a, conv_w.shape[0],
+            _depthwise_silu(conv_w, conv_b, dtype), dtype)
     CT, store_rows = chunk.CT, chunk.store_rows
 
     if ssd:
@@ -2042,6 +2284,19 @@ def _mamba_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     return out, ssm, conv
 
 
+def _held(x, dtype):
+    """``x`` (of ``dtype``) as float32 values of that dtype. Left to itself
+    the compiler drops the rounding between a product and what reads its
+    result as float32 where it fuses the two (a convert pair: 5e-4 of the
+    first delta layer's state in the chip's check, 2e-7 with the rounding
+    held; PERF.md, PR 47), and then a prompt row's convolution sees other
+    inputs than the tail pool hands a decode row. ``reduce_precision`` is an
+    operation of its own and stays."""
+    info = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x.astype(jnp.float32), info.nexp,
+                                    info.nmant)
+
+
 def _gdn_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     """The Gated DeltaNet mixer (qwen3_next; ``ops/pallas/gdn.py`` states the
     recurrence) on the normed rows ``u`` ``[T, hid]`` of one layer, reading
@@ -2065,25 +2320,17 @@ def _gdn_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     KD, P = Hk * N, m["d_head"]
     dtype, f32 = spec.dtype, jnp.float32
     ssm, conv = state
-    info = jnp.finfo(dtype)
-
-    def held(x):
-        """``x`` (the model's dtype) as float32 values of that dtype. Left to
-        itself the compiler drops the rounding between a product and what
-        reads its result as float32 where it fuses the two (a convert pair:
-        5e-4 of the first layer's state in the chip's check, 2e-7 with the
-        rounding held; PERF.md, PR 47), and then a prompt row's
-        convolution sees other inputs than the tail pool hands a decode row.
-        ``reduce_precision`` is an operation of its own and stays."""
-        return jax.lax.reduce_precision(x.astype(f32), info.nexp, info.nmant)
+    held = functools.partial(_held, dtype=dtype)
 
     with jax.named_scope("in_proj"):
         az = _mm(u, mw["in_proj"])
         a, z = held(az[:, :2 * KD + E]), az[:, 2 * KD + E:]
         ba = held(_mm(u, mw["in_ba"]))
     with jax.named_scope("conv"):
-        c, conv, chunk = _conv_rows(conv, _tail_slots(conv), l, rows, a,
-                                    mw["conv_w"].astype(f32), 0.0, dtype)
+        conv_w = mw["conv_w"].astype(f32)
+        c, conv, chunk = _conv_rows(
+            conv, _tail_slots(conv), l, rows, a, conv_w.shape[0],
+            _depthwise_silu(conv_w, 0.0, dtype), dtype)
     CT = chunk.CT
 
     def unit(x, scale):
@@ -2124,6 +2371,87 @@ def _gdn_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     with jax.named_scope("out_proj"):
         out = _mm(y.astype(dtype), mw["out_proj"])
     return out, ssm, conv
+
+
+def _cca_mix(spec: "RaggedModelSpec", cw):
+    """``mix`` of :func:`_conv_rows` for compressed convolutional attention:
+    of the rows' channels ``[s = q and k of every head | z]`` behind their
+    ``taps`` predecessors, ``[y | z of the token before]`` in float32 —
+    ``m_t = w0[0] s_{t-1} + w0[1] s_t + b0`` (depthwise), ``y_t = M[0]
+    m_{t-1} + M[1] m_t + b1`` (a ``[d, d]`` block a head a tap), no
+    activation. Before a sequence's first token the INPUT is zero (the tail
+    a chunk slot starts from), so ``m_{-1} = b0``. ``m`` is rounded to the
+    model's dtype, as a convolution's output is, and the blocks' products
+    accumulate in float32 (float32 operands that hold the model's dtype's
+    values: at the chip's default precision one pass of the MXU, exact)."""
+    c, D, f32 = spec.cca, spec.head_dim, jnp.float32
+    C, taps = c["conv_dim"], c["taps"]
+    w0, b0 = cw["conv0_w"].astype(f32), cw["conv0_b"].astype(f32)
+    w1, b1 = cw["conv1_w"].astype(f32), cw["conv1_b"].astype(f32)
+    K0, K1 = w0.shape[0], w1.shape[0]
+
+    def mix(ext):               # [B, taps + n, C + D] -> [B, n, C + D]
+        n = ext.shape[-2] - taps
+        s = ext[..., :C].astype(f32)
+        nm = n + K1 - 1
+        m = b0 + sum(s[:, j:j + nm] * w0[j] for j in range(K0))
+        m = _held(m, spec.dtype).reshape(m.shape[:-1] + (C // D, D))
+        y = sum(jnp.einsum("bthi,hio->btho", m[:, j:j + n], w1[j])
+                for j in range(K1))
+        y = y.reshape(y.shape[:2] + (C,)) + b1
+        return jnp.concatenate(
+            [y, ext[:, taps - 1:taps - 1 + n, C:].astype(f32)], axis=-1)
+
+    return mix
+
+
+def _cca_project(spec: "RaggedModelSpec", w, u, positions, conv, l,
+                 rows: _StateRows):
+    """Compressed convolutional attention (zaya) up to the kernel, on the
+    normed rows ``u`` ``[T, hid]`` of one layer: ``(q [T, H, D], k [T, Hk,
+    D], v [T, Hk, D], conv)``, ``k`` and ``v`` what the pages hold of a
+    token. The rows' predecessors are read from the tail pool ``conv``
+    ``[L, NS + 1, taps * 8, W / 8]`` (float32; ragged/state_pool.py) at
+    layer ``l`` of it and the new tails written there, the rows' bookkeeping
+    :func:`_conv_rows`'s — the same slots, modes and dump slot as a layer
+    that keeps a state.
+
+    Scope ``proj``: ``in_proj`` gives ``[qp | kp | z | v1]`` (:func:`adapt_zaya`).
+    Scope ``mix``: the two convolutions over ``s = [qp ; kp]`` (:func:`_cca_mix`);
+    the q-k mean from the PRE-convolution values, ``q_j = y^q_j + (qp_j +
+    kp_{j // G}) / 2``, ``k_i = y^k_i + (kp_i + mean_{j in i} qp_j) / 2``;
+    each head normed to ``sqrt(D)`` (an RMS norm without a gain), ``k`` times
+    the head's temperature; the first ``rotary_dim`` values of each head
+    rotated; key/value head 0's value the token's own ``v1``, head 1's ``z``
+    of the token BEFORE. Float32 from the products' (rounded) results to q
+    and k, which are rounded once."""
+    c, cw = spec.cca, w["cca"]
+    H, Hk, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
+    G, C, Wt = H // Hk, c["conv_dim"], c["tail_channels"]
+    dtype, f32 = spec.dtype, jnp.float32
+    with jax.named_scope("proj"):
+        az = _mm(u, cw["in_proj"])
+        a, v1 = _held(az[:, :Wt], dtype), az[:, Wt:]
+    with jax.named_scope("mix"):
+        mixed, conv, _ = _conv_rows(
+            conv, _tail_slots(conv), l, rows, a, c["taps"] + 1,
+            _cca_mix(spec, cw), dtype, shift_decode=True)
+        qp = a[:, :H * D].reshape(-1, Hk, G, D)
+        kp = a[:, H * D:C].reshape(-1, Hk, D)
+        q = mixed[:, :H * D].reshape(-1, Hk, G, D) \
+            + (qp + kp[:, :, None]) * 0.5
+        k = mixed[:, H * D:C].reshape(-1, Hk, D) \
+            + (kp + jnp.mean(qp, axis=2)) * 0.5
+        unit = lambda x: x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + spec.eps)
+        q = unit(q).reshape(-1, H, D)
+        k = unit(k) * cw["temp"].astype(f32)[:, None]
+        q = _rope_flat(q, positions, spec.rope_theta,
+                       spec.rotary_dim).astype(dtype)
+        k = _rope_flat(k, positions, spec.rope_theta,
+                       spec.rotary_dim).astype(dtype)
+        v = jnp.stack([v1, mixed[:, C:].astype(dtype)], axis=1)
+    return q, k, v, conv
 
 
 def latent_width(spec: "RaggedModelSpec") -> int:
@@ -2167,7 +2495,7 @@ def _mla_project(spec: "RaggedModelSpec", w, h1, positions):
 
 
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
-                       lora=None, experts=None, l=0):
+                       lora=None, experts=None, l=0, tails=None):
     """Shared per-layer transformer body for BOTH the ragged forward (put
     passes) and the fused decode step — one implementation so the two
     paths cannot diverge.  ``attend(q, k, v) -> (attn_raw [N, H, D],
@@ -2177,11 +2505,20 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     row's grouped adapter delta to the targeted attention projections.
     ``experts`` are the whole expert stacks ``_split_expert_stacks`` kept out
     of the scanned ``w``, and ``l`` this layer's index in them.
-    Returns ``(x_out, state_tuple)``.
+    ``tails`` (:func:`_tail_args`), for a layer that keeps a convolution
+    tail beside its pages: ``(the tail pool, the rows' state slots, the
+    layer's index in that pool)``; the new tail pool is then the last value
+    of ``state_tuple``. ``x`` is the residual stream, or for a model whose
+    router carries a state from layer to layer the pair ``(stream, router
+    state [N, R] float32)`` (:func:`_router_stream`), and comes back as it
+    came. Returns ``(x_out, state_tuple)``.
     """
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
     state = ()
+    r = None
+    if isinstance(x, tuple):
+        x, r = x
     if spec.block == "ffn":
         pass        # the layer is its FFN alone: no mixer, ``attend`` unused
     elif spec.mamba is not None:
@@ -2204,6 +2541,21 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                        spec.norm_plus_one)
             attn_raw, *state = attend(*_mla_project(spec, w, h1, positions))
             attn_out = _mm(attn_raw, w["wo"])
+    elif spec.cca is not None:
+        # compressed convolutional attention: what is attended over exists
+        # only after the mixing; ``attend(q, k, v)`` is then any attention
+        # layer's (the page write and the kernel of the caller's program)
+        with jax.named_scope("attn"), jax.named_scope("cca"):
+            h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
+                       spec.norm_plus_one)
+            conv, rows, l_tail = tails
+            q, k, v, conv = _cca_project(spec, w, h1, positions, conv,
+                                         l_tail, rows)
+            with jax.named_scope("attn_full"):
+                attn_raw, *state = attend(q, k, v)
+            state.append(conv)
+            with jax.named_scope("out"):
+                attn_out = _mm(attn_raw.reshape(-1, H * D), w["wo"])
     else:
         # the two halves carry scopes: a device trace tells the layer's
         # attention (projections, rope, KV write, kernel) from its FFN
@@ -2255,11 +2607,21 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     # a branch joins the residual stream times ``residual_scale`` (granite),
     # in float32 so that the stream is rounded once
     scaled = spec.residual_scale not in (None, 1.0)
-    join = (lambda x, out: (x.astype(jnp.float32) + spec.residual_scale
-                            * out.astype(jnp.float32)).astype(dtype)) \
-        if scaled else (lambda x, out: x + out)
+    join = (lambda x, out, i=0: (x.astype(jnp.float32) + spec.residual_scale
+                                 * out.astype(jnp.float32)).astype(dtype)) \
+        if scaled else (lambda x, out, i=0: x + out)
+    if "res_scale" in w:
+        # .. or by learned vectors (zaya): a scale and a bias a channel on
+        # the stream and on the branch, rows i and i + 1 of the layer's four
+        res_a = w["res_scale"].astype(jnp.float32)
+        res_c = w["res_bias"].astype(jnp.float32)
+        join = lambda x, out, i=0: (
+            (res_a[i] * x.astype(jnp.float32) + res_c[i])
+            + (res_a[i + 1] * out.astype(jnp.float32) + res_c[i + 1])
+        ).astype(dtype)
+    stream = lambda x: x if r is None else (x, r)
     if spec.block == "mixer":       # one block a layer: no FFN follows
-        return join(x, attn_out).astype(dtype), tuple(state)
+        return stream(join(x, attn_out).astype(dtype)), tuple(state)
     if spec.block == "ffn":         # .. or none went before: its one norm
         mlp_in = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)
@@ -2274,8 +2636,14 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
 
     with jax.named_scope("ffn"):
         if spec.moe is not None:
+            routed = None
+            if spec.moe.get("router") == "mlp":
+                with jax.named_scope("moe_ffn"), jax.named_scope("router"):
+                    *routed, r = moe_route_mlp(mlp_in, w["moe"], spec.moe, r,
+                                               spec.eps)
             mlp_out = _moe_ffn(mlp_in, {**w["moe"], **(experts or {})},
-                               spec.moe["top_k"], dtype, l, routing=spec.moe)
+                               spec.moe["top_k"], dtype, l, routing=spec.moe,
+                               routed=routed)
         else:
             m = w["mlp"]
             if spec.activation in ("swiglu", "geglu"):
@@ -2297,8 +2665,36 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     if spec.parallel_block:
         x = join(x, attn_out + mlp_out) if scaled else x + attn_out + mlp_out
     else:
-        x = join(x, mlp_out)
-    return x.astype(dtype), tuple(state)
+        x = join(x, mlp_out, 2)
+    return stream(x.astype(dtype)), tuple(state)
+
+
+def _router_stream(spec: "RaggedModelSpec", x):
+    """What the layer loop carries first: the residual stream ``x`` ``[T,
+    hid]``, or beside it the router's state ``[T, R]`` (float32, zero before
+    the first layer) where the router hands one from layer to layer
+    (:func:`moe_route_mlp`)."""
+    if spec.moe is None or spec.moe.get("router") != "mlp":
+        return x
+    return x, jnp.zeros((x.shape[0], spec.moe["router_hidden"]), jnp.float32)
+
+
+def _stream_out(x):
+    """The residual stream out of what :func:`_router_stream` made."""
+    return x[0] if isinstance(x, tuple) else x
+
+
+def _tail_args(rs: "RaggedModelSpec", st, rows, l) -> Dict[str, Any]:
+    """``_transformer_layer``'s ``tails`` for a layer of run ``rs`` at index
+    ``l`` of the pools, out of the program's state pools ``st = (ssm,
+    conv)``; nothing for a layer that keeps no tail beside its pages."""
+    return {} if rs.cca is None else {"tails": (st[1], rows, l)}
+
+
+def _tail_kept(st, tail):
+    """The state pools with the tail pool ``_transformer_layer`` handed back
+    (``tail``: what it returned past the attention's own state)."""
+    return (st[0], *tail) if tail else st
 
 
 def _embed_in(spec: "RaggedModelSpec", weights, tokens, positions):
@@ -2546,7 +2942,7 @@ def build_ragged_forward(spec: RaggedModelSpec,
         tokens = jnp.concatenate([b["chunk_tokens"], b["decode_tokens"]])
         positions = jnp.concatenate([b["chunk_positions"], b["decode_positions"]])
 
-        x = _embed_in(spec, weights, tokens, positions)
+        x = _router_stream(spec, _embed_in(spec, weights, tokens, positions))
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -2578,9 +2974,10 @@ def build_ragged_forward(spec: RaggedModelSpec,
                     return (jnp.concatenate([out_c.reshape(CT, H, D), out_d],
                                             axis=0), kvp_, sc_)
 
-                x, (kvp, sc) = _transformer_layer(rs, w, x, positions, attend,
-                                                  experts=experts, l=l - l0)
-                return (x, kvp, sc, st), None
+                x, (kvp, sc, *tail) = _transformer_layer(
+                    rs, w, x, positions, attend, experts=experts, l=l - l0,
+                    **_tail_args(rs, st, rows, l))
+                return (x, kvp, sc, _tail_kept(st, tail)), None
 
             return layer_fn
 
@@ -2591,8 +2988,8 @@ def build_ragged_forward(spec: RaggedModelSpec,
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
         new_kv = _state_pack(new_kv, st)
 
-        x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
-                  spec.norm_plus_one)
+        x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
+                  dtype, spec.norm_plus_one)
         # only NC + S rows are ever read (parity: ragged_ops/logits_gather —
         # the reference also gathers the needed rows before the unembed GEMM)
         last_rows = (jnp.arange(NC) * Cs
@@ -2645,7 +3042,7 @@ def build_prefill_forward(spec: RaggedModelSpec,
         positions = b["chunk_positions"]
         seg = b["row_seg"]
 
-        x = _embed_in(spec, weights, tokens, positions)
+        x = _router_stream(spec, _embed_in(spec, weights, tokens, positions))
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -2674,9 +3071,10 @@ def build_prefill_forward(spec: RaggedModelSpec,
                         sc_ = sc
                     return out, kvp_, sc_
 
-                x, (kvp, sc) = _transformer_layer(rs, w, x, positions, attend,
-                                                  experts=experts, l=l - l0)
-                return (x, kvp, sc, st), None
+                x, (kvp, sc, *tail) = _transformer_layer(
+                    rs, w, x, positions, attend, experts=experts, l=l - l0,
+                    **_tail_args(rs, st, rows, l))
+                return (x, kvp, sc, _tail_kept(st, tail)), None
 
             return layer_fn
 
@@ -2687,8 +3085,8 @@ def build_prefill_forward(spec: RaggedModelSpec,
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
         new_kv = _state_pack(new_kv, st)
 
-        x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
-                  spec.norm_plus_one)
+        x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
+                  dtype, spec.norm_plus_one)
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))    # [NC]
         logits = _unembed(spec, weights, x[last_rows])
@@ -2848,7 +3246,7 @@ def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
         side_k0 = jnp.zeros((L, S, side_rows, D), side_dtype)
         side_v0 = jnp.zeros((L, S, side_rows, D), side_dtype)
 
-        x = _embed_in(spec, weights, ids, positions)
+        x = _router_stream(spec, _embed_in(spec, weights, ids, positions))
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -2891,16 +3289,17 @@ def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
                         kv_scales=sc4 if kvq else None)
                     return out, sk_new, sv_new
 
-                x, (sk_all, sv_all) = _transformer_layer(
-                    rs, w, x, positions, attend, experts=experts, l=l - l0)
-                return (x, sk_all, sv_all, st), None
+                x, (sk_all, sv_all, *tail) = _transformer_layer(
+                    rs, w, x, positions, attend, experts=experts, l=l - l0,
+                    **_tail_args(rs, st, rows, l))
+                return (x, sk_all, sv_all, _tail_kept(st, tail)), None
 
             return layer_fn
 
         x, sk_all, sv_all, st = _scan_layers(
             spec, weights["layers"], make_body, (x, side_k0, side_v0, st0))
-        x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
-                  spec.norm_plus_one)
+        x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
+                  dtype, spec.norm_plus_one)
         logits = _unembed(spec, weights, x)
 
         # ---- the side buffers' rows -> the pool ---- #
@@ -2953,7 +3352,7 @@ def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
         # the kernel, new rows scattered in place after — the pool flows
         # through the layer scan with no copies (see the kernel docstring
         # for why a pre-kernel scatter forces XLA to clone the pool).
-        x = _embed_in(spec, weights, ids, positions)
+        x = _router_stream(spec, _embed_in(spec, weights, ids, positions))
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -2992,10 +3391,10 @@ def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
                         block_tables + l * NB, ctx)
                     return (out, kv5.reshape(L * NB * 2 * Hkv * bs, D), sc)
 
-                x, (kvp, sc) = _transformer_layer(
+                x, (kvp, sc, *tail) = _transformer_layer(
                     rs, w, x, positions, attend, lora=lora, experts=experts,
-                    l=l - l0)
-                return (x, kvp, sc, st), None
+                    l=l - l0, **_tail_args(rs, st, rows, l))
+                return (x, kvp, sc, _tail_kept(st, tail)), None
 
             return layer_fn
 
@@ -3004,8 +3403,8 @@ def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
         x, kvp, sc, st = _scan_layers(
             spec, weights["layers"], make_body, (x, kvp0, sc0, st0),
             extra_xs=() if lora_ops is None else (lora_ops,))
-        x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
-                  spec.norm_plus_one)
+        x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
+                  dtype, spec.norm_plus_one)
         logits = _unembed(spec, weights, x)
         new_kv = kvp.reshape(L, NB, 2, Hkv, bs, D)
         if kvq:
@@ -3066,7 +3465,7 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
     ``final_logits`` predict ``next_ids``'s successor source row (the
     engine's continuation refs).
     """
-    if spec.mamba is not None:
+    if num_state_layers(spec):
         from deepspeed_tpu.inference.v2.scheduler import STATE_SNAPSHOT_MSG
         raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
             what="the speculative verify step (rejected drafts have already "

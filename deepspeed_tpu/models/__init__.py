@@ -5,7 +5,8 @@ afmoe (Arcee Trinity: layers of several kinds in one model), jamba
 (IBM Granite 4.0-H: Mamba-2 layers over routed experts), nemotron_h
 (Nemotron 3 Nano: one block a layer — Mamba-2, experts or attention) and
 qwen3_next (Qwen3-Next: Gated DeltaNet delta-rule layers beside gated
-attention, 512 small experts) — matching the reference's model coverage (module_inject/containers,
+attention, 512 small experts) and zaya (ZAYA1: compressed convolutional
+attention, a top-1 MLP router with a state) — matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
 from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
@@ -22,6 +23,7 @@ from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
                                              NemotronHForCausalLM)
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextForCausalLM)
+from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
 from deepspeed_tpu.models.diffusion import (DiffusionConfig,
                                             DiffusionPipeline,
                                             init_diffusion_inference)

@@ -436,9 +436,10 @@ def _compile_decode_step(spec, weights, kv, rows, max_blocks, **build):
     """The fused decode step of ``rows`` rows compiled for the chip the
     shapes are on; a model with state-space layers is handed its rows'
     state slots."""
-    from deepspeed_tpu.inference.v2.ragged_model import build_decode_step
+    from deepspeed_tpu.inference.v2.ragged_model import (build_decode_step,
+                                                         num_state_layers)
     arr = _on(weights["embed"].sharding)
-    state = (arr(I32, rows),) if spec.mamba is not None else ()
+    state = (arr(I32, rows),) if num_state_layers(spec) else ()
     return jax.jit(build_decode_step(spec, **build), donate_argnums=(1,)
                    ).lower(weights, kv, arr(I32, rows), arr(I32, rows),
                            arr(I32, rows, max_blocks), arr(I32, rows),
@@ -455,6 +456,7 @@ _DECODE_STEPS = {
     "granite": (lambda arr: _granite_stage(arr), 64, 80, {}),
     "nemotron": (lambda arr: _nemotron_stage(arr), 128, 96, {}),
     "qwen3_next": (lambda arr: _qwen3_next_stage(arr), 64, 264, {}),
+    "zaya": (lambda arr: _zaya_stage(arr), 64, 96, {}),
 }
 
 
@@ -490,6 +492,10 @@ _DECODE_STEP_CALLS = {
                  "ssd_decode_step", "moe_grouped_matmul"},
     "qwen3_next": {"paged_decode_sidebuf", "paged_kv_row_write",
                    "gdn_decode_step", "moe_grouped_matmul"},
+    # (compressed convolutional attention adds no kernel: the mixing is
+    # XLA's, the tail's shift the layer's own)
+    "zaya": {"paged_decode_sidebuf", "paged_kv_row_write",
+             "moe_grouped_matmul"},
 }
 
 
@@ -1337,6 +1343,91 @@ def test_qwen3_next_programs_hold_the_delta_kernels_and_the_pools_in_place(
     assert "ragged-dot" not in text and "mini-gather" not in text
     assert not re.search(r"bf16\[\d+,64,(2048,512|512,2048)\]\S* copy\(",
                          text), "an expert stack is copied"
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes >> 20
+
+
+def _zaya_stage(arr):
+    """Spec, stacked weight tree (shapes only) and pools of ZAYA1-8B as the
+    benchmark's configuration runs it: published layers 0-19 at published
+    widths, all 16 experts and the whole 262,272-row vocabulary tied to the
+    head (8.73 GiB of weights), 1,810 pages over all twenty layers (2 KV
+    heads of 128) and the tail pool of 72 + 1 slots over the same twenty
+    (22.8 MiB, no recurrent state)."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
+                                                              StatePoolConfig)
+    from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+    cfg = ZayaConfig.zaya1_8b(num_hidden_layers=20, dtype=BF16)
+    model = ZayaForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_zaya(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    pool = StatePoolConfig.tails_only(20, 72, taps=2, channels=1408)
+    ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
+    kv = StatefulKV(arr(BF16, 20, 1811, 2, 2, BS, 128),
+                    arr(F32, *ssm_shape.shape), arr(F32, *conv_shape.shape))
+    return spec, weights, kv
+
+
+@pytest.mark.parametrize("program", ["serve_decode_step",
+                                     "serve_prefill_packed",
+                                     "serve_paged_pass"])
+def test_zaya_programs_keep_the_pools_and_the_weights_in_place(
+        program, v5e, compiled_step, monkeypatch):
+    """ZAYA1-8B's first 20 layers at published widths: the 64-row decode
+    step, the packed prefill pass (4 slots of 256) and the paged pass. Every
+    layer addresses both pools: the pages (4.4 GiB) and the tails (22.8 MiB)
+    are the outputs' buffers; the 16 experts' products (8 MiB matrices) are
+    the Pallas grouped matmul's and no stack is copied; the tied head reads
+    the 262,272-row embedding where it lies (no float32 copy of it: 2 GiB)
+    and the temporaries stay small."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _zaya_stage(arr)
+    assert rm.num_page_layers(spec) == rm.num_state_layers(spec) == 20
+    assert spec.head_dim == 128 and spec.rotary_dim == 64
+    assert kv.ssm.size == 0
+    rows, pages, slots = 64, 96, 4
+    host = RaggedBatch(num_slots=slots, slot_size=256, max_sequences=rows,
+                       max_blocks=pages).device_arrays()
+    if program == "serve_decode_step":
+        compiled = compiled_step("zaya")[0]                 # 64 rows
+        limit = 256 << 20
+    elif program == "serve_prefill_packed":
+        batch = {k: arr(I32, slots * 256 // BS + slots) if host[k] is None
+                 else arr(I32, *host[k].shape)
+                 for k in rm.PREFILL_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        limit = 1024 << 20
+    else:
+        batch = {k: arr(I32, *host[k].shape)
+                 for k in rm.PAGED_PASS_KEYS + rm.STATE_PASS_KEYS}
+        compiled = jax.jit(rm.build_ragged_forward(spec), donate_argnums=(1,)
+                           ).lower(weights, kv, batch).compile()
+        limit = 1024 << 20
+    text = compiled.as_text()
+    assert "moe_grouped_matmul" in text
+    assert "ragged-dot" not in text and "mini-gather" not in text
+    assert not re.search(r"bf16\[\d+,16,2048,2048\]\S* copy\(", text), \
+        "an expert stack is copied"
+    assert not re.search(r"f32\[262272,2048\]", text), \
+        "the tied head's embedding is widened to float32"
     mem = compiled.memory_analysis()
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools
