@@ -14,28 +14,35 @@ ranks this the hardest kernel in the project; this is the TPU-native take:
     every pool-sized reshape in the layer scan materialises a multi-hundred-MB
     copy (measured 26+ ms per decode step at 0.55B); TP slices the pool on the
     head dim with each shard's pages still contiguous.
-    (2) K+V COMBINED: the decode kernel is per-DMA-copy bound, not byte bound
-    (round-4 measurement: doubling the page size doubled standalone kernel
-    speed; round-5: adding two scale copies per page for int8 made the int8
-    path SLOWER than bf16 despite halving the bytes). One page = one value
-    copy — half the copy count of split K/V pools — and the int8 scale tile
-    rides as one more small copy instead of two.
-  - One grid step = (one sequence, one CHUNK of P pages). Page ids come from the
-    scalar-prefetched block table and the chunk streams HBM->VMEM through a
-    manual two-slot DMA pipeline (``pltpu.make_async_copy``): while chunk c
-    computes, chunk c+1's pages — including the NEXT sequence's first chunk at a
-    sequence boundary — are already in flight, so the whole decode batch is one
-    continuous stream of page reads with compute hidden under DMA. No
-    materialised per-sequence KV copy (the XLA fallback below pays that copy).
-  - Online softmax (flash) across a sequence's chunks with running (m, l, acc)
+    (2) K+V COMBINED: one page = one value copy — half the copy count of
+    split K/V pools — and an int8 page's scale tile rides as one more small
+    copy instead of two. (Rounds 4 and 5 read the decode kernel as bound by
+    its copies' count at smaller pages; at the cells' pages of 64-512 KiB
+    the stream of page copies by itself reads 92% of the HBM rate, PERF.md,
+    PR 51.)
+  - One grid step = one decode row, and inside it a loop over the GROUPS of
+    pages that hold a key the row sees (``_decode_walk_kernel``): page ids
+    come from the scalar-prefetched block table and a group streams HBM ->
+    VMEM through manual copies (``pltpu.make_async_copy``) into one of
+    three slots. The call's groups are one sequence across rows, started
+    two ahead of the one computed, so the whole decode batch is one
+    continuous stream of page reads with compute hidden under it; a table
+    entry a row does not use costs nothing. No materialised per-sequence KV
+    copy (the XLA fallback below pays that copy).
+  - Online softmax (flash) across a row's groups with running (m, l, acc)
     in VMEM scratch, exactly like the training flash kernel
     (``ops/pallas/flash_attention.py``).
-  - Heads: scores for all H q heads against a chunk's H_kv x T (kv head, token)
-    rows come from ONE ``[H, D] x [D, Hkv*T]`` dot with non-matching (q, kv)
-    head pairs masked block-diagonally (and one more for p@V). The H_kv-fold
-    flop overhead is irrelevant — decode attention is HBM-bandwidth bound —
-    while the alternative (H_kv separate M=G dots per page, each with ~fixed-op
-    cost) dominated the old kernel's runtime at MHA head counts.
+  - Heads: a KV head's G query heads meet that head's keys only — ``Hkv``
+    products of ``[G, D] x [D, T]`` a group (and as many for p@V). With so
+    few rows a product the MXU is bound by loading K and V as its weights,
+    0.09 us a 128 KiB page against the 0.16 us its copy takes, so the
+    products hide under the stream while scores, mask and ``exp`` are done
+    once. (The form before PR 51 — ONE ``[H, D] x [D, Hkv*T]`` product
+    with the other heads' columns masked block-diagonally, over chunks of
+    up to 32 pages on a grid as wide as the block table — did ``Hkv`` times
+    the mask and ``exp`` and spent three grid steps in four on chunks that
+    held no key: 46% of the HBM rate at 8 query heads over 2 KV heads where
+    this reads 83%. ``paged_decode_attention_step`` still has it.)
   - int8 pages (``kv_scales``): values int8 with per-token-head f32 scales
     (reference role: ZeRO-Inference's KV quantization, README.md:23, on the
     blocked-flash path). Scales live in one (8k, 128) f32 tile per page —
@@ -47,8 +54,8 @@ ranks this the hardest kernel in the project; this is the TPU-native take:
 
 Decode-only by design (one query token per sequence): SplitFuse prompt chunks take
 the chunked-flash path (``paged_chunk_attention``) — chunk attention is
-compute-bound where paging buys little, while decode attention is
-bandwidth-bound and must not copy the KV.
+compute-bound, while decode attention is bound by the page stream and must
+not copy the KV.
 """
 
 from __future__ import annotations
@@ -170,26 +177,30 @@ def _colscale_pages(mat, tile_ref, n_pages, nsub, off):
     return jnp.concatenate(cols, axis=1) if len(cols) > 1 else cols[0]
 
 
-#: the most pages a chunk holds, whatever the bytes allow: the body issues,
-#: awaits and zeroes a chunk's page copies one by one, unrolled at six places,
-#: so its trace and its code grow with P. By bytes alone one KV head of 128
-#: would take 63 pages a chunk (8k tokens: a 2k context computes 8k), and a
+#: the most pages a chunk of the in-layer-write step and of the split-K
+#: kernels holds, whatever the bytes allow: their body issues, awaits and
+#: zeroes a chunk's page copies one by one, unrolled at six places, so its
+#: trace and its code grow with P. By bytes alone one KV head of 128 would
+#: take 63 pages a chunk (8k tokens: a 2k context computes 8k), and a
 #: 28-layer decode step then traced for 13 s a bucket on the chip's host
-#: (PERF.md, PR 31). 8 KV heads take 7 and 4 take 15: under the cap.
+#: (PERF.md, PR 31). 8 KV heads take 7 and 4 take 15: under the cap. (The
+#: decode step's side-buffer kernel and a paged pass's decode rows walk
+#: groups of ``_pick_decode_pages`` pages instead.)
 MAX_PAGES_PER_CHUNK = 32
 
 
 def _pick_pages_per_chunk(bs: int, h_kv: int, d: int, esize: int,
-                          max_blocks: int, reserve_bytes: int = 0,
+                          max_blocks: int,
                           scale_tile_rows: int = 0, flash_heads: int = 0,
                           out_bytes: int = 0) -> int:
-    """Largest P with the 2-slot combined-KV slabs within ~8 MB of VMEM
-    (~16 MB on v5e; q blocks and score tiles are small), up to
-    ``MAX_PAGES_PER_CHUNK``. Fatter chunks amortise the per-grid-step fixed
-    cost, the dominant decode overhead.
+    """Pages a chunk of ``_decode_body`` (the in-layer-write step) and of
+    the split-K kernels: the largest P with the 2-slot combined-KV slabs
+    within ~8 MB of VMEM (~16 MB on v5e; q blocks and score tiles are
+    small), up to ``MAX_PAGES_PER_CHUNK``. Fatter chunks amortise the
+    per-grid-step fixed cost: about a microsecond a step whether or not the
+    chunk holds a key (PERF.md, PR 51).
 
-    ``reserve_bytes``: VMEM the caller holds besides the page slabs (the
-    sidebuf kernel's side slabs). ``flash_heads``: H of the f32 flash
+    ``flash_heads``: H of the f32 flash
     scratch ((m, l) [H, 128] pair + [H, D] accumulator) — the running
     partial state split-K multiplies across virtual rows, reserved off the
     top so fat chunks can't overrun the budget. ``out_bytes``: the
@@ -199,7 +210,7 @@ def _pick_pages_per_chunk(bs: int, h_kv: int, d: int, esize: int,
     its scale-tile slot, so the cost scales with P, not off the top)."""
     import os
     budget = int(os.environ.get("DSTPU_PAGED_VMEM_BUDGET",
-                                8 * 1024 * 1024)) - reserve_bytes - out_bytes
+                                8 * 1024 * 1024)) - out_bytes
     if flash_heads:
         budget -= (flash_heads * d + 2 * flash_heads * 128) * 4
     per_page = 2 * 2 * bs * h_kv * d * esize     # 2 slots x (K + V)
@@ -288,20 +299,15 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
                  kv_hbm, o_ref,
                  kv_buf, sems, acc_sc, m_sc, l_sc, *,
                  scale, block_size, pages_per_chunk, n_chunks, max_blocks,
-                 n_seqs, h_kv, groups, window=None, lse_ref=None,
-                 j_ref=None, sidek_ref=None, sidev_ref=None, n_side=0,
+                 n_seqs, h_kv, groups, window=None,
                  sc_hbm=None, sc_buf=None, alibi=False):
-    """Shared batched-decode body (see module docstring). With
-    ``knew_ref/vnew_ref`` (step mode) the pages hold tokens [0, ctx-1) and
-    the current token's attention term folds in from registers at finalize;
-    without them the pages hold everything (ctx tokens).
-
-    ``sidek_ref/sidev_ref`` (side-slab mode — the decode step's side buffer):
-    the pages hold the FROZEN prefix [0, cl) and the per-sequence side slab
-    ``[n_side*Hkv, D]`` holds the chunk's freshly decoded K/V rows (row
-    cc*Hkv + h = step cc's kv head h, token position cl + cc); at finalize
-    rows cc <= ``j_ref[0]`` fold into the same (m, l, acc) state — one flash
-    stream over pages + side, no separate dense piece, no lse merge.
+    """The body of the in-layer-write decode step
+    (:func:`paged_decode_attention_step`; the decode step's side-buffer form
+    and a paged pass's decode rows run ``_decode_walk_kernel``): a grid of
+    (sequence, CHUNK of P pages) over the whole block table, a chunk's live
+    pages copied and all P computed in one block-diagonal product. The
+    pages hold tokens [0, ctx-1) and the current token's attention term
+    folds in from registers (``knew_ref/vnew_ref``) at finalize.
 
     ``sc_hbm/sc_buf`` (int8 pages): per-page scale tiles, one DMA per page.
 
@@ -310,12 +316,8 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
     position ctx-1 attends only tokens >= ctx - window. Chunks wholly below
     the window start are skipped (grid range) and pages outside
     [window_lo, ctx) are neither DMA'd nor computed — the window bounds the
-    per-step KV read the way the reference's sliding cache does. In side-slab
-    mode the query position is cl + j, so the window start moves with j."""
-    inline_current = knew_ref is not None
-    side = sidek_ref is not None
+    per-step KV read the way the reference's sliding cache does."""
     quant = sc_hbm is not None
-    ctx_off = 1 if inline_current else 0
     P, bs, T = pages_per_chunk, block_size, pages_per_chunk * block_size
     HB = h_kv * bs
     s, c = pl.program_id(0), pl.program_id(1)
@@ -326,9 +328,6 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
         # first visible token (window start); 0 without a window
         if window is None:
             return jnp.int32(0)
-        if side:
-            # query position = prefix + j (cl holds the prefix length)
-            return jnp.maximum(cl_ref[s_] + j_ref[0] + 1 - window, 0)
         return jnp.maximum(cl_ref[s_] - window, 0)
 
     def c0_of(s_):
@@ -343,14 +342,14 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
 
     def n_chunks_of(s_):
         # every sequence runs >= 1 chunk (ctx 0 rows mask to zeros)
-        return jax.lax.div(jnp.maximum(cl_ref[s_] - ctx_off, 1) + (T - 1), T)
+        return jax.lax.div(jnp.maximum(cl_ref[s_] - 1, 1) + (T - 1), T)
 
     def page_needed(s_, c_, j):
-        """Page j of chunk c_ overlaps [tok_lo, ctx - ctx_off)? Skipped
+        """Page j of chunk c_ overlaps [tok_lo, ctx - 1)? Skipped
         pages are neither started nor waited (identical predicate on both
         sides keeps the semaphore counts consistent)."""
         t0 = (c_ * P + j) * bs
-        need = t0 < jnp.maximum(cl_ref[s_] - ctx_off, 1)
+        need = t0 < jnp.maximum(cl_ref[s_] - 1, 1)
         if window is not None:
             need = jnp.logical_and(need, t0 + bs > tok_lo_of(s_))
         return need
@@ -441,7 +440,7 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
         # slab and slicing the value forces a full-slab relayout per chunk
         kk = kv_buf[slot, :, :HB, :].reshape(P * HB, -1)
         vv = kv_buf[slot, :, HB:, :].reshape(P * HB, -1)
-        mask = _chunk_mask(c, ctx - ctx_off, T, h_kv, bs, H,
+        mask = _chunk_mask(c, ctx - 1, T, h_kv, bs, H,
                            tok_lo=None if window is None else tok_lo_of(s))
         v_scale_fn = None
         if quant:
@@ -475,51 +474,6 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
 
         @pl.when(c == nc_s - 1)
         def _():
-            if side:
-                # fold the side slab: one [H, D] x [D, n_side*Hkv] dot with
-                # the block-diagonal + step mask, same flash update as a page
-                # chunk. Rows past j hold zeros/garbage — masked. Column j is
-                # always visible, so l > 0 even at prefix 0 (no empty-row
-                # special case).
-                jcur = j_ref[0]
-                sk = sidek_ref[0, 0]                           # [Cs*Hkv, D]
-                sv = sidev_ref[0, 0]
-                Ws = n_side * h_kv
-                col = jax.lax.broadcasted_iota(jnp.int32, (H, Ws), 1)
-                row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, Ws), 0) \
-                    // groups
-                cc = col // h_kv
-                col_kv = jax.lax.rem(col, h_kv)
-                smask = jnp.logical_and(col_kv == row_kv, cc <= jcur)
-                if window is not None:
-                    smask = jnp.logical_and(smask, cc >= jcur + 1 - window)
-                sc_s = jax.lax.dot_general(
-                    q_ref[0].astype(sk.dtype), sk,
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if alibi:
-                    # side token position = prefix + cc
-                    headf = jax.lax.broadcasted_iota(jnp.float32, (H, Ws), 0)
-                    sc_s = sc_s + _alibi_slope(headf, H) \
-                        * (ctx + cc).astype(jnp.float32)
-                # rows > j may hold reused garbage; p is 0 there but
-                # 0 * inf = NaN through the pv dot, so zero sv's dead rows
-                # (same reasoning as the skipped-page V zeroing above)
-                row1 = jax.lax.broadcasted_iota(jnp.int32, (Ws, 1), 0)
-                sv = jnp.where(row1 // h_kv <= jcur, sv, 0.0)
-                _flash_update(sc_s, smask, sv, m_sc, l_sc, acc_sc)
-            if not inline_current:
-                l = l_sc[:, 0:1]
-                safe_l = jnp.where(l > 0.0, l, 1.0)
-                o_ref[0] = (acc_sc[:] / safe_l).astype(o_ref.dtype)
-                if lse_ref is not None:
-                    # lse = m + log(l) per head; NEG_INF when nothing was
-                    # attended (the merge hook for a second attention piece —
-                    # same contract as flash_attention_packed's lse output)
-                    lse = m_sc[:, 0:1] + jnp.log(safe_l)
-                    lse_ref[0] = jnp.broadcast_to(
-                        jnp.where(l > 0.0, lse, NEG_INF), lse_ref[0].shape)
-                return
             # fold in the current token from registers (one extra softmax
             # column per head group), then normalise
             qf = q_ref[0].astype(jnp.float32)
@@ -553,257 +507,410 @@ def _decode_body(bt_ref, cl_ref, q_ref, knew_ref, vnew_ref,
             o_ref[0] = jnp.where(ctx > 0, out, jnp.zeros_like(out))
 
 
-def _decode_kernel(bt_ref, cl_ref, q_ref, kv_hbm, o_ref,
-                   kv_buf, sems, acc_sc, m_sc, l_sc, **kw):
-    _decode_body(bt_ref, cl_ref, q_ref, None, None, kv_hbm, o_ref,
-                 kv_buf, sems, acc_sc, m_sc, l_sc, **kw)
+#: the most pages a group of the decode kernel holds, whatever the bytes
+#: allow: a group's copies are issued and awaited one by one, unrolled, at
+#: one place each in two forms
+MAX_PAGES_PER_GROUP = 16
+
+#: the slots a decode call's groups stream through: one computed from, the
+#: others in flight. With two, the stream stops whenever a row's prologue,
+#: its fold of the slab or a masked last group takes longer than one
+#: group's copies; the third slot hid that at every cell's shape (PERF.md,
+#: PR 51: 244 -> 220 us at cell 12's, 213 -> 184 at Trinity's window), a
+#: fourth added nothing
+_DECODE_SLOTS = 3
 
 
-def _decode_kernel_lse(bt_ref, cl_ref, q_ref, kv_hbm, o_ref, lse_ref,
-                       kv_buf, sems, acc_sc, m_sc, l_sc, **kw):
-    _decode_body(bt_ref, cl_ref, q_ref, None, None, kv_hbm, o_ref,
-                 kv_buf, sems, acc_sc, m_sc, l_sc, lse_ref=lse_ref, **kw)
+def _pick_decode_pages(bs: int, h_kv: int, d: int, esize: int,
+                       max_blocks: int, scale_tile_rows: int = 0) -> int:
+    """Pages a group of the decode kernel's walk holds (PERF.md, PR 51): as
+    many as keep the slots of K+V pages (and int8 scale tiles) within 8 MB
+    of VMEM, up to ``MAX_PAGES_PER_GROUP`` and the table's pages. A group's
+    turn costs about half a microsecond whatever it holds (its copies'
+    descriptors and, a KV head, one chain of product, row statistics and
+    product), against 0.16 us a 128 KiB page at the HBM rate, so a group is
+    large: 2 pages a group read 39% of the rate at cell 12's shape, 4 56%,
+    8 72%, 16 75% (two slots). A step's other blocks (q, the slab's K and V
+    rows, the output, each twice) and its state are a few dozen KiB beside
+    the slots."""
+    per_page = _DECODE_SLOTS * 2 * h_kv * bs * d * esize
+    if scale_tile_rows:
+        per_page += _DECODE_SLOTS * scale_tile_rows * 128 * 4
+    return max(1, min(max_blocks, (8 * 1024 * 1024) // per_page,
+                      MAX_PAGES_PER_GROUP))
 
 
-def _decode_kernel_quant(bt_ref, cl_ref, q_ref, kv_hbm, sc_hbm,
-                         o_ref, kv_buf, sc_buf, sems,
-                         acc_sc, m_sc, l_sc, **kw):
-    _decode_body(bt_ref, cl_ref, q_ref, None, None, kv_hbm, o_ref,
-                 kv_buf, sems, acc_sc, m_sc, l_sc,
-                 sc_hbm=sc_hbm, sc_buf=sc_buf, **kw)
+def _decode_update(sc, mask, vh, h, m_sc, l_sc, acc_sc, v_scale=None):
+    """One KV head's online-softmax update over a group's keys (the decode
+    kernel's and the chunk kernel's): ``sc`` ``[rows, T]`` float32 scores,
+    ``mask`` their visibility (None where every key is seen), ``vh`` ``[T,
+    D]`` the head's values as the MXU takes them, ``v_scale`` ``[1, T]`` an
+    int8 page's dequant scales; the state is ``[Hkv, rows, .]``. ``p`` goes
+    to the MXU in the values' dtype."""
+    if mask is not None:
+        sc = jnp.where(mask, sc, NEG_INF)
+    m_prev = m_sc[h, :, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+    p = jnp.exp(sc - m_new)
+    if mask is not None:
+        # explicit, not exp alone: a row that has seen nothing yet has
+        # m_new == sc == NEG_INF and the bare exp would give 1.0
+        p = jnp.where(mask, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_sc[h, :, 0:1] = l_sc[h, :, 0:1] * alpha + jnp.sum(p, axis=1,
+                                                        keepdims=True)
+    m_sc[h, :, 0:1] = m_new
+    if v_scale is not None:
+        p = p * v_scale
+    acc_sc[h] = acc_sc[h] * alpha + jax.lax.dot_general(
+        p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
-def _sidebuf_batched_body(bt_ref, cl_ref, j_ref, q_ref, sidek_ref, sidev_ref,
-                          kv_hbm, o_ref,
-                          kv_buf, sc_buf, sems, acc_sc, m_sc, l_sc, *,
-                          scale, block_size, pages_per_chunk, n_chunks,
-                          max_blocks, n_seqs, h_kv, groups, window=None,
-                          n_side=0, batch_seqs=1, sc_hbm=None, alibi=False):
-    """SB-batched side-slab decode body: one grid step carries
-    ``batch_seqs`` sequences' chunks. The decode grid is sequential
-    ("arbitrary" semantics for the 2-slot DMA pipeline) and MEASURED to be
-    bound by per-grid-step overhead, not DMA bytes or copy count (round 5:
-    combined K+V pages halved copies for +2%; int8 halved bytes and LOST —
-    the stream is already hidden under the per-step floor). Batching SB
-    sequences per step divides that floor by SB while keeping each
-    sequence's dot/flash exactly as in the single-sequence body.
+def _decode_walk_kernel(*refs, scale, block_size, pages, max_blocks, n_seqs,
+                        h_kv, groups, window=None, n_side=0, quant=False,
+                        with_lse=False, alibi=False):
+    """One grid step = one decode row; inside it a loop walks the GROUPS of
+    ``pages`` consecutive pages that hold a key the row sees — from the
+    window's first page (0 without one) to the page that holds token
+    ``n - 1`` — and nothing else is copied, awaited, multiplied or masked: a
+    table entry the row does not use costs nothing, and a row computes at
+    most one group more than it holds.
 
-    Scratch: kv_buf [2, SB, P, 2*Hkv*bs, D], per-sequence flash state
-    acc [SB, H, D] / m, l [SB, H, 128]."""
-    quant = sc_hbm is not None
-    SB = batch_seqs
-    P, bs, T = pages_per_chunk, block_size, pages_per_chunk * block_size
-    HB = h_kv * bs
-    sb, c = pl.program_id(0), pl.program_id(1)
-    g = sb * n_chunks + c
-    H = h_kv * groups
+    The groups stream HBM -> VMEM through ``kv_buf``'s slots, and the stream
+    does not stop at a row's end: the call's groups — rows in order, a row's
+    groups in order, a row that walks none passed over — are ONE sequence,
+    started in that order (``issue``; ``st`` holds where the sequence
+    stands) and computed in that order, each turn of the loop starting one
+    group before it waits for its own, so all slots but the one computed
+    from are in flight across rows' prologues and folds. A group wholly
+    inside ``[lo, n)`` issues its copies without predicates and builds no
+    mask; the group that holds the context's end or the window's start
+    copies the pages that hold a visible key, zeroes the V half of the
+    others (``p`` is exactly 0 there, but 0 * NaN is NaN) and masks. A KV
+    head's queries meet that head's keys only: ``Hkv`` products of ``[G, D]
+    x [D, T]``, the state laid out ``[Hkv, G, .]``.
 
-    def tok_lo_of(s_):
+    ``n_side`` > 0 (the decode step's side buffer): the pages hold the
+    FROZEN prefix ``[0, cl)`` and the row's slab ``[n_side*Hkv, D]`` its
+    freshly decoded K/V rows (row cc*Hkv + h = step cc's KV head h, at
+    position cl + cc); after the last group rows cc <= ``j`` fold into the
+    same (m, l, acc) — one flash stream over pages and slab, no lse merge —
+    and the query's position is cl + j, so the window's start moves with j.
+    Without a slab the pages hold everything (``cl`` keys) and a row of no
+    key writes zeros. ``quant``: int8 pages, their scale tiles one more copy
+    a page, applied as score-column (K) and p-column (V) multipliers."""
+    side = n_side > 0
+    it = iter(refs)
+    bt_ref, cl_ref = next(it), next(it)
+    j_ref = next(it) if side else None
+    if side:
+        next(it)        # the layer's index: the slab's index maps read it
+    q_ref = next(it)
+    sidek_ref, sidev_ref = (next(it), next(it)) if side else (None, None)
+    kv_hbm = next(it)
+    sc_hbm = next(it) if quant else None
+    o_ref = next(it)
+    lse_ref = next(it) if with_lse else None
+    q_sc, kv_buf = next(it), next(it)
+    sc_buf = next(it) if quant else None
+    sems, st, acc_sc, m_sc, l_sc = it
+    NS = kv_buf.shape[0]
+
+    P, bs, G = pages, block_size, groups
+    T, H = P * bs, h_kv * groups
+    s = pl.program_id(0)
+    mxu = q_sc.dtype
+
+    def span(s_):
+        """Row ``s_``: the keys ``[lo, n)`` of its pages that it sees and
+        the groups ``[g_lo, g_hi)`` that hold them (none: ``g_hi == g_lo``)."""
+        cl = cl_ref[s_]
+        n = jnp.minimum(cl, max_blocks * bs)                # the table's keys
         if window is None:
-            return jnp.int32(0)
-        return jnp.maximum(cl_ref[s_] + j_ref[0] + 1 - window, 0)
+            lo = jnp.int32(0)
+        elif side:
+            lo = jnp.maximum(cl + j_ref[0] + 1 - window, 0)
+        else:
+            lo = jnp.maximum(cl - window, 0)
+        g_lo = jax.lax.div(lo, T)
+        return lo, n, g_lo, jnp.maximum(jax.lax.div(n + (T - 1), T), g_lo)
 
-    def n_chunks_of(s_):
-        return jax.lax.div(jnp.maximum(cl_ref[s_], 1) + (T - 1), T)
+    def inner(g, lo, n):
+        """Does every key of group ``g`` lie in ``[lo, n)``?"""
+        return jnp.logical_and(g * T >= lo, (g + 1) * T <= n)
 
-    def c0_of(s_):
-        if window is None:
-            return jnp.int32(0)
-        return jnp.minimum(jax.lax.div(tok_lo_of(s_), T),
-                           n_chunks_of(s_) - 1)
+    def needed(page, lo, n):
+        return jnp.logical_and(page * bs < n, (page + 1) * bs > lo)
 
-    def page_needed(s_, c_, j):
-        t0 = (c_ * P + j) * bs
-        need = t0 < jnp.maximum(cl_ref[s_], 1)
-        if window is not None:
-            need = jnp.logical_and(need, t0 + bs > tok_lo_of(s_))
-        return need
-
-    def block_runs(sb_, c_):
-        """Does chunk c_ run for ANY sequence of block sb_?"""
-        runs = jnp.bool_(False)
-        for i in range(SB):
-            s_ = sb_ * SB + i
-            runs = jnp.logical_or(
-                runs, jnp.logical_and(c_ < n_chunks_of(s_), c_ >= c0_of(s_)))
-        return runs
-
-    def chunk_copies(sb_, c_, slot):
+    def copies(s_, g, slot):
+        """(index in the table, its copies) of each page of a group, built
+        identically at start and wait. An index past the table's end repeats
+        its last entry (never needed)."""
         cps = []
-        for i in range(SB):
-            s_ = sb_ * SB + i
-            # a sequence whose chunk range excludes c_ skips its copies;
-            # the predicates are identical at start and wait
-            seq_on = jnp.logical_and(c_ < n_chunks_of(s_), c_ >= c0_of(s_))
-            for j in range(P):
-                page = bt_ref[s_, jnp.minimum(c_ * P + j, max_blocks - 1)]
-                need = jnp.logical_and(seq_on, page_needed(s_, c_, j))
-                cps.append((need, i, pltpu.make_async_copy(
-                    kv_hbm.at[page], kv_buf.at[slot, i, j], sems.at[slot])))
-                if quant:
-                    cps.append((need, i, pltpu.make_async_copy(
-                        sc_hbm.at[page], sc_buf.at[slot, i, j],
-                        sems.at[slot])))
+        for jp in range(P):
+            page = bt_ref[s_, jnp.minimum(g * P + jp, max_blocks - 1)]
+            cp = [pltpu.make_async_copy(kv_hbm.at[page], kv_buf.at[slot, jp],
+                                        sems.at[slot])]
+            if quant:
+                cp.append(pltpu.make_async_copy(
+                    sc_hbm.at[page], sc_buf.at[slot, jp], sems.at[slot]))
+            cps.append((g * P + jp, cp))
         return cps
 
-    per_page = 2 if quant else 1
+    def start(s_, g, slot):
+        lo_, n_, _, _ = span(s_)
+        whole = inner(g, lo_, n_)
 
-    def start_copies(sb_, c_, slot):
-        for need, _i, cp in chunk_copies(sb_, c_, slot):
-            @pl.when(need)
-            def _():
-                cp.start()
+        @pl.when(whole)
+        def _():
+            for _, cp in copies(s_, g, slot):
+                for c in cp:
+                    c.start()
 
-    def wait_copies(sb_, c_, slot):
-        for j2, (need, i, cp) in enumerate(chunk_copies(sb_, c_, slot)):
-            @pl.when(need)
-            def _():
-                cp.wait()
-            if j2 % per_page == 0:
-                jj = (j2 // per_page) % P
-                # skipped pages: V half must be finite (0 * NaN = NaN)
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            for page, cp in copies(s_, g, slot):
+                @pl.when(needed(page, lo_, n_))
+                def _():
+                    for c in cp:
+                        c.start()
+
+    def issue():
+        """Start the copies of the next group of the call's sequence, if one
+        is left: ``st[0]`` and ``st[1]`` are the row and the group to look
+        at next (a group of -1: the row's first), ``st[2]`` how many groups
+        were started, ``st[3]`` how many were computed."""
+        def spent(c):
+            s_i, g_i = c
+            _, _, lo_g, hi_g = span(jnp.minimum(s_i, n_seqs - 1))
+            return jnp.logical_and(
+                s_i < n_seqs, jnp.where(g_i < 0, lo_g, g_i) >= hi_g)
+
+        s_i, g_i = jax.lax.while_loop(
+            spent, lambda c: (c[0] + 1, jnp.int32(-1)), (st[0], st[1]))
+        _, _, lo_g, _ = span(jnp.minimum(s_i, n_seqs - 1))
+        g_i = jnp.where(g_i < 0, lo_g, g_i)
+        st[0] = s_i
+
+        @pl.when(s_i < n_seqs)
+        def _():
+            start(s_i, g_i, jax.lax.rem(st[2], NS))
+            st[1] = g_i + 1
+            st[2] = st[2] + 1
+
+    @pl.when(s == 0)
+    def _():
+        st[0] = jnp.int32(0)
+        st[1] = jnp.int32(-1)
+        st[2] = jnp.int32(0)
+        st[3] = jnp.int32(0)
+        for _ in range(NS - 1):
+            issue()
+
+    lo, n, g_lo, g_hi = span(s)
+
+    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[:] = jnp.zeros_like(l_sc)
+    acc_sc[:] = jnp.zeros_like(acc_sc)
+    qf = q_ref[0].astype(jnp.float32)                          # [H, D]
+    for h in range(h_kv):
+        q_sc[h] = qf[h * G:(h + 1) * G, :].astype(mxu)
+
+    def attend(slot, g, masked):
+        k0 = g * T
+        mask = None
+        if masked or alibi:
+            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (G, T), 1)
+        if masked:
+            mask = k_pos < n
+            if window is not None:
+                mask = jnp.logical_and(mask, k_pos >= lo)
+        for h in range(h_kv):
+            # slice the REF: the head's K and V rows of every page
+            kh = kv_buf[slot, :, 0, h].reshape(T, -1)
+            vh = kv_buf[slot, :, 1, h].reshape(T, -1)
+            sc = jax.lax.dot_general(q_sc[h], kh.astype(mxu),
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32) * scale
+            v_scale = None
+            if quant:
+                # a tile holds K's scales from flat index h*bs on, V's from
+                # (Hkv + h)*bs on
+                sc = sc * _chunk_scale_row(sc_buf, slot, P, h * bs, bs)
+                v_scale = _chunk_scale_row(sc_buf, slot, P, (h_kv + h) * bs,
+                                           bs)
+            if alibi:
+                head = h * G + jax.lax.broadcasted_iota(jnp.int32, (G, T), 0)
+                sc = sc + _alibi_slope(head.astype(jnp.float32), H) \
+                    * k_pos.astype(jnp.float32)
+            _decode_update(sc, mask, vh.astype(mxu), h, m_sc, l_sc, acc_sc,
+                           v_scale=v_scale)
+
+    def group(g, carry):
+        # the slot the group before this one was computed from is free:
+        # NS - 1 groups are in flight while one is computed
+        issue()
+        slot = jax.lax.rem(st[3], NS)
+        st[3] = st[3] + 1
+
+        whole = inner(g, lo, n)
+
+        @pl.when(whole)
+        def _():
+            for _, cp in copies(s, g, slot):
+                for c in cp:
+                    c.wait()
+            attend(slot, g, masked=False)
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            for jp, (page, cp) in enumerate(copies(s, g, slot)):
+                need = needed(page, lo, n)
+
+                @pl.when(need)
+                def _():
+                    for c in cp:
+                        c.wait()
+
                 @pl.when(jnp.logical_not(need))
                 def _():
-                    kv_buf[slot, i, jj, HB:, :] = jnp.zeros_like(
-                        kv_buf[slot, i, jj, HB:, :])
-            if quant and j2 % per_page == 1:
-                jj = (j2 // per_page) % P
-                @pl.when(jnp.logical_not(need))
-                def _():
-                    sc_buf[slot, i, jj] = jnp.zeros_like(sc_buf[slot, i, jj])
+                    kv_buf[slot, jp, 1] = jnp.zeros_like(kv_buf[slot, jp, 1])
+                    if quant:
+                        sc_buf[slot, jp] = jnp.zeros_like(sc_buf[slot, jp])
+            attend(slot, g, masked=True)
 
-    n_blocks = n_seqs // SB
+        return carry
 
-    @pl.when(jnp.logical_and(g == 0, block_runs(0, 0)))
-    def _():
-        start_copies(0, 0, 0)
+    jax.lax.fori_loop(g_lo, g_hi, group, 0)
 
-    sb_n = jax.lax.div(g + 1, n_chunks)
-    c_n = jax.lax.rem(g + 1, n_chunks)
-    next_real = jnp.logical_and(g + 1 < n_blocks * n_chunks,
-                                block_runs(sb_n, c_n))
-
-    @pl.when(next_real)
-    def _():
-        start_copies(sb_n, c_n, jax.lax.rem(g + 1, 2))
-
-    @pl.when(block_runs(sb, c))
-    def _():
-        slot = jax.lax.rem(g, 2)
-        wait_copies(sb, c, slot)
-
-        for i in range(SB):
-            s_ = sb * SB + i
-            ctx = cl_ref[s_]
-            nc_s = n_chunks_of(s_)
-            c0_s = c0_of(s_)
-
-            @pl.when(c == c0_s)
-            def _():
-                m_sc[i] = jnp.full_like(m_sc[i], NEG_INF)
-                l_sc[i] = jnp.zeros_like(l_sc[i])
-                acc_sc[i] = jnp.zeros_like(acc_sc[i])
-
-            @pl.when(jnp.logical_and(c < nc_s, c >= c0_s))
-            def _():
-                q = q_ref[i]                                   # [H, D]
-                kk = kv_buf[slot, i, :, :HB, :].reshape(P * HB, -1)
-                vv = kv_buf[slot, i, :, HB:, :].reshape(P * HB, -1)
-                mask = _chunk_mask(c, ctx, T, h_kv, bs, H,
-                                   tok_lo=None if window is None
-                                   else tok_lo_of(s_))
-                v_scale_fn = None
-                if quant:
-                    kk = kk.astype(q.dtype)
-                    nsub = HB // 128
-                    st = sc_buf[slot, i]
-                    v_scale_fn = functools.partial(
-                        _colscale_pages, tile_ref=st, n_pages=P, nsub=nsub,
-                        off=nsub)
-                sc = jax.lax.dot_general(q.astype(kk.dtype), kk,
-                                         (((1,), (1,)), ((), ())),
-                                         preferred_element_type=jnp.float32
-                                         ) * scale
-                if quant:
-                    sc = _colscale_pages(sc, st, P, nsub, 0)
-                if alibi:
-                    col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-                    tok = c * T + (col // HB) * bs + jax.lax.rem(col, bs)
-                    headf = jax.lax.broadcasted_iota(jnp.float32, sc.shape, 0)
-                    sc = sc + _alibi_slope(headf, H) * tok.astype(jnp.float32)
-                # per-sequence flash state rows i
-                m_i, l_i, acc_i = m_sc.at[i], l_sc.at[i], acc_sc.at[i]
-                _flash_update(sc, mask, vv, m_i, l_i, acc_i,
-                              v_scale_fn=v_scale_fn, compute_dtype=q.dtype)
-
-            @pl.when(c == nc_s - 1)
-            def _():
-                jcur = j_ref[0]
-                sk = sidek_ref[0, i]                           # [Cs*Hkv, D]
-                sv = sidev_ref[0, i]
-                Ws = n_side * h_kv
-                col = jax.lax.broadcasted_iota(jnp.int32, (H, Ws), 1)
-                row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, Ws), 0) \
-                    // groups
-                cc = col // h_kv
-                col_kv = jax.lax.rem(col, h_kv)
-                smask = jnp.logical_and(col_kv == row_kv, cc <= jcur)
-                if window is not None:
-                    smask = jnp.logical_and(smask, cc >= jcur + 1 - window)
-                sc_s = jax.lax.dot_general(
-                    q_ref[i].astype(sk.dtype), sk,
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if alibi:
-                    headf = jax.lax.broadcasted_iota(jnp.float32, (H, Ws), 0)
-                    sc_s = sc_s + _alibi_slope(headf, H) \
-                        * (ctx + cc).astype(jnp.float32)
-                row1 = jax.lax.broadcasted_iota(jnp.int32, (Ws, 1), 0)
-                sv = jnp.where(row1 // h_kv <= jcur, sv, 0.0)
-                m_i, l_i, acc_i = m_sc.at[i], l_sc.at[i], acc_sc.at[i]
-                _flash_update(sc_s, smask, sv, m_i, l_i, acc_i)
-                l = l_sc[i, :, 0:1]
-                safe_l = jnp.where(l > 0.0, l, 1.0)
-                o_ref[i] = (acc_sc[i] / safe_l).astype(o_ref.dtype)
+    # the state as the output lies, [H, .]
+    m = m_sc[:].reshape(H, -1)[:, 0:1]
+    l = l_sc[:].reshape(H, -1)[:, 0:1]
+    acc = acc_sc[:].reshape(H, -1)
+    if side:
+        # fold the slab: one [H, D] x [D, n_side*Hkv] product under the
+        # block-diagonal + step mask. Rows past j hold zeros or garbage —
+        # masked. Column j is always visible, so l > 0 even at prefix 0
+        jcur = j_ref[0]
+        sk = sidek_ref[0, 0]                                   # [Cs*Hkv, D]
+        sv = sidev_ref[0, 0]
+        Ws = n_side * h_kv
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, Ws), 1)
+        row_kv = jax.lax.broadcasted_iota(jnp.int32, (H, Ws), 0) // G
+        cc = col // h_kv
+        smask = jnp.logical_and(jax.lax.rem(col, h_kv) == row_kv, cc <= jcur)
+        if window is not None:
+            smask = jnp.logical_and(smask, cc >= jcur + 1 - window)
+        sc_s = jax.lax.dot_general(q_ref[0].astype(sk.dtype), sk,
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32) * scale
+        if alibi:
+            # a slab row's position is prefix + cc
+            headf = jax.lax.broadcasted_iota(jnp.float32, (H, Ws), 0)
+            sc_s = sc_s + _alibi_slope(headf, H) \
+                * (cl_ref[s] + cc).astype(jnp.float32)
+        # rows > j may hold reused garbage; p is 0 there but 0 * inf = NaN
+        # through the product, so zero sv's dead rows
+        row1 = jax.lax.broadcasted_iota(jnp.int32, (Ws, 1), 0)
+        sv = jnp.where(row1 // h_kv <= jcur, sv, 0.0)
+        sc_s = jnp.where(smask, sc_s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc_s, axis=1, keepdims=True))
+        p = jnp.where(smask, jnp.exp(sc_s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(sv.dtype), sv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m = m_new
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
+    if lse_ref is not None:
+        # lse = m + log(l) per head; NEG_INF when nothing was attended (the
+        # merge hook for a second attention piece — same contract as
+        # flash_attention_packed's lse output)
+        lse_ref[0] = jnp.broadcast_to(
+            jnp.where(l > 0.0, m + jnp.log(safe_l), NEG_INF),
+            lse_ref[0].shape)
 
 
-def _decode_kernel_sidebuf(bt_ref, cl_ref, j_ref, l_ref, q_ref, sidek_ref,
-                           sidev_ref, kv_hbm, o_ref,
-                           kv_buf, sems, acc_sc, m_sc, l_sc, **kw):
-    del l_ref  # layer index: consumed by the side-slab BlockSpec index maps
-    _decode_body(bt_ref, cl_ref, q_ref, None, None, kv_hbm, o_ref,
-                 kv_buf, sems, acc_sc, m_sc, l_sc,
-                 j_ref=j_ref, sidek_ref=sidek_ref, sidev_ref=sidev_ref, **kw)
-
-
-def _decode_kernel_sidebuf_quant(bt_ref, cl_ref, j_ref, l_ref, q_ref,
-                                 sidek_ref, sidev_ref, kv_hbm, sc_hbm,
-                                 o_ref, kv_buf, sc_buf, sems,
-                                 acc_sc, m_sc, l_sc, **kw):
-    del l_ref
-    _decode_body(bt_ref, cl_ref, q_ref, None, None, kv_hbm, o_ref,
-                 kv_buf, sems, acc_sc, m_sc, l_sc,
-                 j_ref=j_ref, sidek_ref=sidek_ref, sidev_ref=sidev_ref,
-                 sc_hbm=sc_hbm, sc_buf=sc_buf, **kw)
-
-
-def _sidebuf_batched_kernel(bt_ref, cl_ref, j_ref, l_ref, q_ref, sidek_ref,
-                            sidev_ref, kv_hbm, o_ref,
-                            kv_buf, sems, acc_sc, m_sc, l_sc, **kw):
-    del l_ref  # layer index: consumed by the side-slab BlockSpec index maps
-    _sidebuf_batched_body(bt_ref, cl_ref, j_ref, q_ref, sidek_ref, sidev_ref,
-                          kv_hbm, o_ref, kv_buf, None, sems,
-                          acc_sc, m_sc, l_sc, **kw)
-
-
-def _sidebuf_batched_kernel_quant(bt_ref, cl_ref, j_ref, l_ref, q_ref,
-                                  sidek_ref, sidev_ref, kv_hbm, sc_hbm, o_ref,
-                                  kv_buf, sc_buf, sems, acc_sc, m_sc, l_sc,
-                                  **kw):
-    del l_ref
-    _sidebuf_batched_body(bt_ref, cl_ref, j_ref, q_ref, sidek_ref, sidev_ref,
-                          kv_hbm, o_ref, kv_buf, sc_buf, sems,
-                          acc_sc, m_sc, l_sc, sc_hbm=sc_hbm, **kw)
+def _decode_walk_call(q, kv_pages, block_tables, lens, *, scale, window,
+                      kv_scales, alibi, with_lse=False, side=None):
+    """The decode kernel's ``pallas_call``: ``side`` is ``(side_k, side_v, j,
+    layer_idx)`` with the slabs ``[L, S, Cs*Hkv, D]``, or None where the
+    pages hold everything."""
+    S, H, D = q.shape
+    NB, two, Hkv, bs, Dk = kv_pages.shape
+    assert two == 2 and Dk == D, (kv_pages.shape, D)
+    assert H % Hkv == 0, f"GQA: {H} q heads not divisible by {Hkv} kv heads"
+    assert (bs * Hkv) % 8 == 0, \
+        f"page rows {Hkv}*{bs} must align to the 8-sublane tile"
+    G = H // Hkv
+    MB = block_tables.shape[1]
+    quant = kv_scales is not None
+    r8 = _scale_tile_rows(Hkv, bs) if quant else 0
+    if quant:
+        assert (Hkv * bs) % 128 == 0, "scale tiles need lane alignment"
+    P = _pick_decode_pages(bs, Hkv, D, jnp.dtype(kv_pages.dtype).itemsize,
+                           MB, r8)
+    # what the MXU is handed: the pages as stored (int8 pages widen to q's
+    # dtype exactly), q and p rounded to that
+    mxu = q.dtype if quant else kv_pages.dtype
+    n_pre = 4 if side is not None else 2
+    row = lambda s, *pre: (s, 0, 0)
+    prefetch = [block_tables.astype(jnp.int32), lens.astype(jnp.int32)]
+    in_specs = [pl.BlockSpec((1, H, D), row)]
+    operands = [q]
+    n_side = 0
+    if side is not None:
+        side_k, side_v, j, layer_idx = side
+        CsH = side_k.shape[2]
+        n_side = CsH // Hkv
+        prefetch += [jnp.asarray(j, jnp.int32).reshape(1),
+                     jnp.asarray(layer_idx, jnp.int32).reshape(1)]
+        slab = pl.BlockSpec((1, 1, CsH, D),
+                            lambda s, bt, cl, jj, ll: (ll[0], s, 0, 0))
+        in_specs += [slab, slab]
+        operands += [side_k, side_v]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)]     # pages stay in HBM
+    operands += [kv_pages]
+    if quant:
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)]
+        operands += [_scales_to_tiles(kv_scales)]
+    out_specs = pl.BlockSpec((1, H, D), row)
+    out_shape = jax.ShapeDtypeStruct((S, H, D), q.dtype)
+    if with_lse:
+        # lse rides as a [1, H, 128] f32 block (broadcast along the lane dim:
+        # a bare [1, H] output would hand Mosaic a sub-lane tile)
+        out_specs = [out_specs, pl.BlockSpec((1, H, 128), row)]
+        out_shape = [out_shape, jax.ShapeDtypeStruct((S, H, 128), jnp.float32)]
+    NS = _DECODE_SLOTS
+    scratch = [pltpu.VMEM((Hkv, G, D), mxu),
+               pltpu.VMEM((NS, P, 2, Hkv, bs, D), kv_pages.dtype)]
+    if quant:
+        scratch += [pltpu.VMEM((NS, P, r8, 128), jnp.float32)]
+    scratch += [
+        pltpu.SemaphoreType.DMA((NS,)),
+        pltpu.SMEM((4,), jnp.int32),
+        pltpu.VMEM((Hkv, G, D), jnp.float32),
+        pltpu.VMEM((Hkv, G, 128), jnp.float32),
+        pltpu.VMEM((Hkv, G, 128), jnp.float32),
+    ]
+    kernel = functools.partial(
+        _decode_walk_kernel, scale=scale, block_size=bs, pages=P,
+        max_blocks=MB, n_seqs=S, h_kv=Hkv, groups=G, window=window,
+        n_side=n_side, quant=quant, with_lse=with_lse, alibi=alibi)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_pre, grid=(S,), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            # the slots are handed from row to row, so the rows run in order
+            dimension_semantics=("arbitrary",)),
+        interpret=_backend.interpret(),
+    )(*prefetch, *operands)
 
 
 def _kv_flat(kv_pages):
@@ -869,137 +976,15 @@ def paged_decode_attention_sidebuf(q: jax.Array,
         side_v = side_v.reshape(Ls, S2, Cs * Hkv, D)
     Ls, S2, CsH, D2 = side_k.shape
     assert CsH % Hkv == 0
-    Cs = CsH // Hkv
     assert two == 2 and Dk == D and D2 == D and S2 == S
-    assert H % Hkv == 0
-    assert D % 128 == 0 and (Cs * Hkv) % 8 == 0, \
+    assert D % 128 == 0 and CsH % 8 == 0, \
         "side-slab kernel needs lane-aligned D and 8-sublane-aligned C*Hkv"
-    G = H // Hkv
-    MB = block_tables.shape[1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / (D ** 0.5)
-    quant = kv_scales is not None
-    esize = jnp.dtype(kv_pages.dtype).itemsize
-    side_vmem = 2 * Cs * Hkv * D * jnp.dtype(side_k.dtype).itemsize
-    P = _pick_pages_per_chunk(bs, Hkv, D, esize, MB,
-                              reserve_bytes=side_vmem, flash_heads=H,
-                              scale_tile_rows=_scale_tile_rows(Hkv, bs)
-                              if quant else 0)
-    NC = -(-MB // P)
-    assert (bs * Hkv) % 8 == 0
-    if quant:
-        assert (Hkv * bs) % 128 == 0, "scale tiles need lane alignment"
-    r8 = _scale_tile_rows(Hkv, bs)
-
-    # SB-batched grid: the sequential decode grid is bound by per-grid-step
-    # overhead (see _sidebuf_batched_body); pick the largest SB dividing S
-    # whose 2-slot kv slabs PLUS the pipeline's double-buffered side blocks
-    # (K + V, x2 buffers, xSB sequences) fit the VMEM budget
-    import os
-    budget = int(os.environ.get("DSTPU_PAGED_VMEM_BUDGET",
-                                8 * 1024 * 1024))
-    side_block = 2 * Cs * Hkv * D * jnp.dtype(side_k.dtype).itemsize
-    SB = 1
-    for cand in (8, 4, 2):
-        slab = 2 * cand * P * 2 * Hkv * bs * D * esize
-        slab += 2 * cand * side_block          # (1, SB, Cs*Hkv, D) x2 bufs x k/v
-        if quant:
-            slab += 2 * cand * P * r8 * 128 * 4
-        if S % cand == 0 and slab <= budget:
-            SB = cand
-            break
-
-    operands = [block_tables.astype(jnp.int32), prefix_lens.astype(jnp.int32),
-                jnp.asarray(j, jnp.int32).reshape(1),
-                jnp.asarray(layer_idx, jnp.int32).reshape(1), q,
-                side_k, side_v,
-                _kv_flat(kv_pages)]
-    if SB > 1:
-        kernel = functools.partial(
-            _sidebuf_batched_kernel_quant if quant
-            else _sidebuf_batched_kernel,
-            scale=scale, block_size=bs, pages_per_chunk=P, n_chunks=NC,
-            max_blocks=MB, n_seqs=S, h_kv=Hkv, groups=G, window=window,
-            n_side=Cs, batch_seqs=SB, alibi=alibi)
-        in_specs = [
-            pl.BlockSpec((SB, H, D), lambda s, c, bt, cl, jj, ll: (s, 0, 0)),
-            pl.BlockSpec((1, SB, Cs * Hkv, D),
-                         lambda s, c, bt, cl, jj, ll: (ll[0], s, 0, 0)),
-            pl.BlockSpec((1, SB, Cs * Hkv, D),
-                         lambda s, c, bt, cl, jj, ll: (ll[0], s, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ]
-        scratch = [pltpu.VMEM((2, SB, P, 2 * Hkv * bs, D), kv_pages.dtype)]
-        if quant:
-            in_specs += [pl.BlockSpec(memory_space=pl.ANY)]
-            scratch += [pltpu.VMEM((2, SB, P, r8, 128), jnp.float32)]
-            operands += [_scales_to_tiles(kv_scales)]
-        scratch += [
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((SB, H, D), jnp.float32),
-            pltpu.VMEM((SB, H, 128), jnp.float32),
-            pltpu.VMEM((SB, H, 128), jnp.float32),
-        ]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(S // SB, NC),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((SB, H, D),
-                                   lambda s, c, bt, cl, jj, ll: (s, 0, 0)),
-            scratch_shapes=scratch,
-        )
-        call = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary", "arbitrary")),
-            interpret=_backend.interpret(),
-        )
-        with jax.named_scope("paged_decode_sidebuf_batched"):
-            return call(*operands)
-
-    kernel = functools.partial(
-        _decode_kernel_sidebuf_quant if quant else _decode_kernel_sidebuf,
-        scale=scale, block_size=bs,
-        pages_per_chunk=P, n_chunks=NC, max_blocks=MB, n_seqs=S, h_kv=Hkv,
-        groups=G, window=window, n_side=Cs, alibi=alibi)
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda s, c, bt, cl, jj, ll: (s, 0, 0)),
-        pl.BlockSpec((1, 1, Cs * Hkv, D),
-                     lambda s, c, bt, cl, jj, ll: (ll[0], s, 0, 0)),
-        pl.BlockSpec((1, 1, Cs * Hkv, D),
-                     lambda s, c, bt, cl, jj, ll: (ll[0], s, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    scratch = [pltpu.VMEM((2, P, 2 * Hkv * bs, D), kv_pages.dtype)]
-    if quant:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((2, P, r8, 128), jnp.float32)]
-        operands += [_scales_to_tiles(kv_scales)]
-    scratch += [
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM((H, D), jnp.float32),
-        pltpu.VMEM((H, 128), jnp.float32),
-        pltpu.VMEM((H, 128), jnp.float32),
-    ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(S, NC),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D),
-                               lambda s, c, bt, cl, jj, ll: (s, 0, 0)),
-        scratch_shapes=scratch,
-    )
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_backend.interpret(),
-    )
     with jax.named_scope("paged_decode_sidebuf"):
-        return call(*operands)
+        return _decode_walk_call(
+            q, kv_pages, block_tables, prefix_lens, scale=scale,
+            window=window, kv_scales=kv_scales, alibi=alibi,
+            side=(side_k, side_v, j, layer_idx))
 
 
 def _row_group(pos0, g, n_rows, group, n_slots):
@@ -1283,82 +1268,20 @@ def paged_decode_attention(q: jax.Array,
     Returns [S, H, D] (plus lse when requested). Rows whose ctx_len is 0
     return zeros.
     """
-    S, H, D = q.shape
-    NB, two, Hkv, bs, Dk = kv_pages.shape
-    assert two == 2 and Dk == D, (kv_pages.shape, D)
-    assert H % Hkv == 0, f"GQA: {H} q heads not divisible by {Hkv} kv heads"
-    G = H // Hkv
-    MB = block_tables.shape[1]
+    D = q.shape[-1]
     scale = softmax_scale if softmax_scale is not None else 1.0 / (D ** 0.5)
-    quant = kv_scales is not None
     if D % 128 != 0:   # manual-DMA lane-alignment limit — see _paged_decode_smalld
         assert not with_lse, "with_lse needs the manual-DMA path (D % 128 == 0)"
-        assert not quant, "int8 pages need the manual-DMA path (D % 128 == 0)"
+        assert kv_scales is None, \
+            "int8 pages need the manual-DMA path (D % 128 == 0)"
         return _paged_decode_smalld(q, kv_pages, block_tables,
                                     ctx_lens, scale, window=window,
                                     alibi=alibi)
-    if quant:
-        assert not with_lse, "with_lse + int8 pages not needed by any caller"
-        assert (Hkv * bs) % 128 == 0, "scale tiles need lane alignment"
-    P = _pick_pages_per_chunk(bs, Hkv, D, jnp.dtype(kv_pages.dtype).itemsize,
-                              MB, flash_heads=H,
-                              scale_tile_rows=_scale_tile_rows(Hkv, bs)
-                              if quant else 0)
-    NC = -(-MB // P)
-
-    kernel = functools.partial(
-        _decode_kernel_quant if quant
-        else (_decode_kernel_lse if with_lse else _decode_kernel),
-        scale=scale, block_size=bs, pages_per_chunk=P,
-        n_chunks=NC, max_blocks=MB, n_seqs=S, h_kv=Hkv, groups=G,
-        window=window, alibi=alibi)
-    out_spec = pl.BlockSpec((1, H, D), lambda s, c, bt, cl: (s, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((S, H, D), q.dtype)
-    if with_lse:
-        # lse rides as a [1, H, 128] f32 block (broadcast along the lane dim:
-        # a bare [1, H] output would hand Mosaic a sub-lane tile)
-        out_spec = [out_spec,
-                    pl.BlockSpec((1, H, 128), lambda s, c, bt, cl: (s, 0, 0))]
-        out_shape = [out_shape, jax.ShapeDtypeStruct((S, H, 128), jnp.float32)]
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda s, c, bt, cl: (s, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),     # pages stay in HBM;
-    ]
-    scratch = [pltpu.VMEM((2, P, 2 * Hkv * bs, D), kv_pages.dtype)]
-    operands = [block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), q,
-                _kv_flat(kv_pages)]
-    if quant:
-        r8 = _scale_tile_rows(Hkv, bs)
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((2, P, r8, 128), jnp.float32)]
-        operands += [_scales_to_tiles(kv_scales)]
-    scratch += [
-        pltpu.SemaphoreType.DMA((2,)),
-        pltpu.VMEM((H, D), jnp.float32),
-        pltpu.VMEM((H, 128), jnp.float32),
-        pltpu.VMEM((H, 128), jnp.float32),
-    ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, NC),
-        in_specs=in_specs,
-        out_specs=out_spec,
-        scratch_shapes=scratch,
-    )
-    assert (bs * Hkv) % 8 == 0, \
-        f"page rows {Hkv}*{bs} must align to the 8-sublane tile"
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            # the 2-slot DMA pipeline hands buffers across grid steps (and
-            # across sequences), so iteration order must stay sequential
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_backend.interpret(),
-    )
     with jax.named_scope("paged_decode"):
-        res = call(*operands)
+        res = _decode_walk_call(q, kv_pages, block_tables, ctx_lens,
+                                scale=scale, window=window,
+                                kv_scales=kv_scales, alibi=alibi,
+                                with_lse=with_lse)
     if with_lse:
         return res[0], res[1][:, :, 0]
     return res
@@ -1744,24 +1667,10 @@ def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
                 kpf = (k0 + jax.lax.broadcasted_iota(
                     jnp.int32, (bq, G, T), 2)).astype(jnp.float32)
                 sc = sc + (slope * kpf).reshape(R, T)
-            if masked:
-                sc = jnp.where(mask, sc, NEG_INF)
-            m_prev = m_sc[h, :, 0:1]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-            p = jnp.exp(sc - m_new)
-            if masked:
-                # explicit, not exp alone: a row that sees nothing yet has
-                # m_new == sc == NEG_INF and the bare exp would give 1.0
-                p = jnp.where(mask, p, 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_sc[h, :, 0:1] = l_sc[h, :, 0:1] * alpha + jnp.sum(
-                p, axis=1, keepdims=True)
-            m_sc[h, :, 0:1] = m_new
-            if quant:
-                p = p * _chunk_scale_row(sc_buf, slot, P, (h_kv + h) * bs, bs)
-            acc_sc[h] = acc_sc[h] * alpha + jax.lax.dot_general(
-                p.astype(mxu), vh.astype(mxu), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            _decode_update(
+                sc, mask if masked else None, vh.astype(mxu), h, m_sc, l_sc,
+                acc_sc, v_scale=_chunk_scale_row(
+                    sc_buf, slot, P, (h_kv + h) * bs, bs) if quant else None)
             return carry
 
         # ONE head's body, looped: unrolled, eight KV heads of two branches

@@ -158,6 +158,22 @@ CASES = {
         lambda q, kv, bt, pl_, sk, sv, j: paged_decode_attention_sidebuf(
             q, kv, bt, pl_, sk, sv, j, window=WINDOW),
         [_Q, _pool(), _BT, _CL, _SIDE, _SIDE, ((), I32)]),
+    # the same kernel as cell 12's decode step hands it (ZAYA1-8B: 8 query
+    # heads over 2 KV heads of 128, 64 rows, 96-page tables) and as cell 11's
+    # does (Qwen3-Next: 16 over 2 heads of 256, 272-page tables): the slab the
+    # whole stack of layers, the layer's index traced
+    "sidebuf_h8_kv2_d128_rows64": (
+        lambda q, kv, bt, pl_, sk, sv, j, l: paged_decode_attention_sidebuf(
+            q, kv, bt, pl_, sk, sv, j, layer_idx=l),
+        [((64, 8, D), BF16), _pool(2, D), ((64, 96), I32), ((64,), I32),
+         ((20, 64, 8, D), BF16), ((20, 64, 8, D), BF16), ((), I32),
+         ((), I32)]),
+    "sidebuf_h16_kv2_d256_pages272": (
+        lambda q, kv, bt, pl_, sk, sv, j, l: paged_decode_attention_sidebuf(
+            q, kv, bt, pl_, sk, sv, j, layer_idx=l),
+        [((64, 16, 256), BF16), _pool(2, 256), ((64, 272), I32),
+         ((64,), I32), ((3, 64, 8, 256), BF16), ((3, 64, 8, 256), BF16),
+         ((), I32), ((), I32)]),
     "splitk4": (
         lambda *a: paged_decode_attention_splitk_pallas(*a, 4,
                                                         window=WINDOW),

@@ -559,56 +559,153 @@ class TestInt8Pages:
                                    atol=3e-5, rtol=3e-4)
 
 
-class TestSidebufBatched:
-    """SB-batched sidebuf grid (multiple sequences per grid step): ragged
-    prefixes across a block, windowed, and int8 variants must all match the
-    single-sequence reference."""
+def _walk_group(bs, Hkv, D, MB, esize=4, quant=False):
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _pick_decode_pages, _scale_tile_rows)
+    return bs * _pick_decode_pages(
+        bs, Hkv, D, esize, MB, _scale_tile_rows(Hkv, bs) if quant else 0)
 
-    @pytest.mark.parametrize("window", [None, 12])
-    def test_batched_matches_reference(self, window):
-        from deepspeed_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention_sidebuf,
-            paged_decode_attention_sidebuf_reference)
-        rng = np.random.RandomState(31)
-        S, H, Hkv, D, bs, MB, C = 8, 4, 2, 128, 8, 3, 8
-        NB = S * MB + 1
-        q = jnp.asarray(rng.randn(S, H, D), jnp.float32)
-        kv = jnp.asarray(rng.randn(NB, 2, Hkv, bs, D), jnp.float32)
-        bt = jnp.asarray(rng.permutation(NB - 1)[:S * MB].reshape(S, MB) + 1,
-                         jnp.int32)
-        prefix = jnp.asarray([0, 5, 8, 24, 1, 16, 13, 20], jnp.int32)
-        sk = jnp.asarray(rng.randn(S, C, Hkv, D), jnp.float32)
-        sv = jnp.asarray(rng.randn(S, C, Hkv, D), jnp.float32)
-        out = paged_decode_attention_sidebuf(q, kv, bt, prefix, sk, sv, 4,
-                                             window=window)
-        ref = paged_decode_attention_sidebuf_reference(q, kv, bt, prefix,
-                                                       sk, sv, 4,
-                                                       window=window)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-4)
 
-    def test_batched_int8_matches_dequant_reference(self):
+#: name -> (H, Hkv, D, bs, MB, prefixes, options). A prefix is tokens, or a
+#: function of the tokens a group of the kernel's walk holds at that shape;
+#: options: window, j (default 4), int8, alibi, C (the slab's steps, default 8)
+_WALK_CASES = {
+    # no key in the pages, and one
+    "prefix_0_and_1": (8, 2, 128, 8, 24, [0, 1, 0, 1], {}),
+    # the context's end on a page's and on a group's last slot, and one past
+    "ends_on_a_page_and_on_a_group": (
+        8, 2, 128, 8, 40, [8, lambda T: T, lambda T: 2 * T, 16], {}),
+    "one_past_a_group": (
+        8, 2, 128, 8, 40, [lambda T: T + 1, lambda T: 2 * T + 1, 9], {}),
+    # more pages than a chunk of the older kernel could hold (32)
+    "longer_than_32_pages": (8, 2, 128, 8, 48, [40 * 8 + 3, 48 * 8], {}),
+    "very_different_lengths": (
+        8, 2, 128, 8, 48, [3, 48 * 8, 0, 130, 17, 300, 1, 255], {}),
+    # a row that walks nothing between two that do: the stream is handed
+    # over the gap by a cold start, and the first row of all starts cold
+    "empty_rows_between": (8, 2, 128, 8, 24, [0, 0, 100, 0, 0, 150, 0], {}),
+    "window_start_inside_a_group": (
+        8, 2, 128, 8, 48, [lambda T: 2 * T + 5, 300, 7, 48 * 8],
+        {"window": 37}),
+    "window_skips_whole_groups": (
+        8, 2, 128, 8, 48, [lambda T: 3 * T - 1, 48 * 8, 200, 0],
+        {"window": 100, "j": 2}),
+    "window_of_one_step": (8, 2, 128, 8, 24, [64, 65, 0], {"window": 1,
+                                                           "j": 0}),
+    "int8_pages": (4, 2, 128, 128, 20, [0, 70, 1024, 1025, 2500],
+                   {"int8": True, "j": 5}),
+    "int8_pages_windowed": (4, 2, 128, 128, 20, [1300, 2560, 129],
+                            {"int8": True, "window": 1100, "j": 1}),
+    "alibi": (8, 2, 128, 8, 40, [0, 5, 130, 320], {"alibi": True, "j": 3}),
+    "heads_8_over_2": (8, 2, 128, 8, 24, [0, 77, 150, 192], {"j": 0, "C": 4}),
+    "heads_16_over_2_width_256": (16, 2, 256, 8, 24, [0, 77, 150, 192],
+                                  {"j": 0, "C": 4}),
+    "heads_20_over_1": (20, 1, 128, 8, 24, [0, 77, 150, 192], {"j": 0}),
+    "heads_32_over_2": (32, 2, 128, 8, 24, [0, 77, 150, 192], {"j": 0,
+                                                               "C": 4}),
+    "heads_32_over_4": (32, 4, 128, 8, 24, [0, 77, 150, 192], {"j": 0,
+                                                               "C": 2}),
+    "heads_32_over_8": (32, 8, 128, 8, 24, [0, 77, 150, 192], {"j": 0,
+                                                               "C": 1}),
+    "table_272_pages_over_a_3_page_row": (8, 2, 128, 8, 272, [20, 24, 3], {}),
+    # what the several-rows-a-step form of the older kernel was tested on
+    "batched": (4, 2, 128, 8, 3, [0, 5, 8, 24, 1, 16, 13, 20], {}),
+    "batched_windowed": (4, 2, 128, 8, 3, [0, 5, 8, 24, 1, 16, 13, 20],
+                         {"window": 12}),
+    "batched_int8": (4, 2, 128, 128, 2, [0, 70, 128, 250],
+                     {"int8": True, "j": 5}),
+}
+
+
+class TestDecodeWalk:
+    """The decode kernel's walk over a row's groups of pages
+    (``_decode_walk_kernel``) against the two-piece reference: where a
+    context ends and a window starts relative to pages and groups, rows that
+    walk nothing, every cell's head shape, int8 pages, ALiBi."""
+
+    @pytest.mark.parametrize("name", sorted(_WALK_CASES))
+    def test_sidebuf_matches_reference(self, name):
         from deepspeed_tpu.ops.pallas.paged_attention import (
             kv_quantize_rows, paged_decode_attention_sidebuf,
             paged_decode_attention_sidebuf_reference)
-        rng = np.random.RandomState(32)
-        S, H, Hkv, D, bs, MB, C = 4, 4, 2, 128, 128, 2, 8
-        NB = S * MB + 1
+        H, Hkv, D, bs, MB, prefixes, opt = _WALK_CASES[name]
+        int8 = opt.get("int8", False)
+        T = _walk_group(bs, Hkv, D, MB, esize=1 if int8 else 4, quant=int8)
+        prefix = [p(T) if callable(p) else p for p in prefixes]
+        assert max(prefix) <= MB * bs
+        rng = np.random.RandomState(51)
+        S, C, j = len(prefix), opt.get("C", 8), opt.get("j", 4)
+        held = [-(-p // bs) for p in prefix]
+        NB = sum(held) + 1
         kv = jnp.asarray(rng.randn(NB, 2, Hkv, bs, D), jnp.float32)
-        kvq, sc = kv_quantize_rows(kv)
-        kvd = kvq.astype(jnp.float32) * sc[..., None]
+        # a row's pages are its own; page 0 is nobody's and holds NaN, as do
+        # the table's unused entries: what a row does not have is not read
+        kv = kv.at[0].set(jnp.nan)
+        order = rng.permutation(NB - 1) + 1
+        bt = np.zeros((S, MB), np.int32)
+        at = 0
+        for s_, n in enumerate(held):
+            bt[s_, :n] = order[at:at + n]
+            at += n
         q = jnp.asarray(rng.randn(S, H, D), jnp.float32)
-        bt = jnp.asarray(rng.permutation(NB - 1)[:S * MB].reshape(S, MB) + 1,
-                         jnp.int32)
-        prefix = jnp.asarray([0, 70, 128, 250], jnp.int32)
         sk = jnp.asarray(rng.randn(S, C, Hkv, D), jnp.float32)
         sv = jnp.asarray(rng.randn(S, C, Hkv, D), jnp.float32)
-        out = paged_decode_attention_sidebuf(q, kvq, bt, prefix, sk, sv, 5,
-                                             kv_scales=sc)
-        ref = paged_decode_attention_sidebuf_reference(q, kvd, bt, prefix,
-                                                       sk, sv, 5)
+        kw = {k: opt[k] for k in ("window", "alibi") if k in opt}
+        pages, scales = kv, {}
+        if int8:
+            pages, sc = kv_quantize_rows(kv.at[0].set(0.0))
+            kv = (pages.astype(jnp.float32) * sc[..., None]).at[0].set(
+                jnp.nan)
+            scales = {"kv_scales": sc.at[0].set(jnp.nan)}
+        out = paged_decode_attention_sidebuf(
+            q, pages, jnp.asarray(bt), jnp.asarray(prefix, jnp.int32), sk,
+            sv, j, **kw, **scales)
+        # the reference gathers whole tables: give it zeros where the kernel
+        # must not look
+        ref = paged_decode_attention_sidebuf_reference(
+            q, kv.at[0].set(0.0), jnp.asarray(bt),
+            jnp.asarray(prefix, jnp.int32), sk, sv, j, **kw)
+        assert np.all(np.isfinite(np.asarray(out))), name
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-5, rtol=3e-4)
+
+    @pytest.mark.parametrize("Hkv,window", [(2, None), (8, None), (2, 21)])
+    def test_without_a_slab_matches_reference_and_its_lse(self, Hkv, window):
+        """A paged pass's decode rows: the pages hold everything, a row of
+        no key writes zeros and an lse of NEG_INF."""
+        rng = np.random.RandomState(52)
+        S, H, D, bs, MB = 6, 8, 128, 8, 40
+        NB = S * MB
+        q, kv, bt = _setup(rng, S, H, D, Hkv, NB, bs, MB)
+        T = _walk_group(bs, Hkv, D, MB)
+        cl = jnp.asarray([0, 1, T, T + 1, 2 * T + 5, MB * bs], jnp.int32)
+        out, lse = paged_decode_attention(q, kv, bt, cl, window=window,
+                                          with_lse=True)
+        ref, ref_lse = paged_decode_attention_reference(
+            q, kv, bt, cl, window=window, with_lse=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-4)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                                   atol=2e-5, rtol=2e-5)
+        assert np.all(np.asarray(out)[0] == 0)
+
+    def test_group_pages_follow_the_shapes(self):
+        """A group is as large as keeps the slots inside 8 MB, up to
+        ``MAX_PAGES_PER_GROUP`` and the table's width: at the cells' shapes
+        16 pages of 64 and of 128 KiB, 10 of 256 KiB, 5 of 512 KiB."""
+        from deepspeed_tpu.ops.pallas.paged_attention import (
+            _DECODE_SLOTS, MAX_PAGES_PER_GROUP, _pick_decode_pages)
+        picked = {}
+        for hkv, d, mb in ((2, 128, 96), (2, 256, 272), (1, 128, 96),
+                           (4, 128, 208), (8, 128, 40)):
+            p = picked[hkv, d] = _pick_decode_pages(128, hkv, d, 2, mb)
+            assert 1 <= p <= MAX_PAGES_PER_GROUP
+            assert _DECODE_SLOTS * p * 2 * hkv * 128 * d * 2 <= 8 << 20
+        assert picked == {(2, 128): 16, (2, 256): 10, (1, 128): 16,
+                          (4, 128): 10, (8, 128): 5}
+        assert _pick_decode_pages(128, 8, 128, 2, 3) == 3
+        # int8 pages: half the bytes and a scale tile a page
+        assert _pick_decode_pages(128, 8, 128, 1, 40, 16) == 10
 
 
 class TestAlibi:

@@ -1,7 +1,8 @@
 """The serving programs of the families the benchmark runs, as lowered text.
 
-Five families at toy widths (dense llama lineage, Mixtral, Trinity's afmoe,
-Jamba, JoyAI; and granite, recorded later: ``LATER``) x three programs
+Nine families at toy widths (dense llama lineage, Mixtral, Trinity's afmoe,
+Jamba, JoyAI; granite, recorded later: ``LATER``; Nemotron-H, Qwen3-Next and
+ZAYA, later still: ``NEWER``) x three programs
 (decode step, paged pass, packed prefill), lowered for the CPU — where the
 Pallas kernels lower as their interpreted bodies, so the kernels' own text is
 held too — and hashed. ``data/serving_program_text.json`` holds the hashes of
@@ -22,7 +23,13 @@ tree took the step loop — a ``lax.scan`` of length one around the pass —
 out of that program's text, and how the K/V families' paged pass was by PR
 49, whose chunk kernel walks a slot's own pages in groups — JoyAI's paged
 pass, over latent pages, kept its hash; the packed prefill of all six is
-still the older commits').
+still the older commits'; and how PR 51 recorded the nine programs of
+``NEWER`` and, anew, jamba's decode step and paged pass — with zaya's the
+only toy programs whose heads are 128 wide, so the only ones that hold the
+paged decode kernel PR 51 rewrote: ``--write <file> "PR 51 on c92e0a8"
+nemotron_h qwen3_next zaya``, then ``... jamba serve_decode_step
+serve_paged_pass``; the other twenty-three hashes are what c92e0a8 lowers
+to, and so are the packed prefills of the three new families).
 
 Beside the hashes: which of its two forms each family's toy decode step
 takes (``ragged_model.side_buffer_fits``), and that the traced step holds
@@ -45,6 +52,11 @@ FAMILIES = ("llama", "mixtral", "afmoe", "jamba", "joyai")
 #: the commit before PR 42 gave the SSD kernels a group axis (83a3dac, the
 #: file's ``later``): one group lowers to the text it lowered to
 LATER = ("granite",)
+#: the three families that came after (PRs 42, 47, 50), recorded by PR 51 on
+#: the tree it left: Mamba-2 with two groups under relu^2 experts, Gated
+#: DeltaNet beside gated attention, and compressed convolutional attention
+#: (heads 128 wide: its decode step and paged pass hold the paged kernels)
+NEWER = ("nemotron_h", "qwen3_next", "zaya")
 PROGRAMS = ("serve_decode_step", "serve_paged_pass", "serve_prefill_packed")
 
 
@@ -81,6 +93,20 @@ def tiny(fam, model=None):
                                                   GraniteForCausalLM)
         cfg = GraniteConfig.tiny(dtype=f32)
         model, adapt = GraniteForCausalLM(cfg), rm.adapt_granite
+    elif fam == "nemotron_h":
+        from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
+                                                     NemotronHForCausalLM)
+        cfg = NemotronHConfig.tiny(dtype=f32)
+        model, adapt = NemotronHForCausalLM(cfg), rm.adapt_nemotron_h
+    elif fam == "qwen3_next":
+        from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                                     Qwen3NextForCausalLM)
+        cfg = Qwen3NextConfig.tiny(dtype=f32)
+        model, adapt = Qwen3NextForCausalLM(cfg), rm.adapt_qwen3_next
+    elif fam == "zaya":
+        from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
+        cfg = ZayaConfig.tiny(dtype=f32)
+        model, adapt = ZayaForCausalLM(cfg), rm.adapt_zaya
     else:
         from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         cfg = LlamaConfig.tiny(dtype=f32)
@@ -93,13 +119,20 @@ def tiny(fam, model=None):
             (spec.num_layers, 9, 16, rm.latent_width(spec)), f32)
     pages = jnp.zeros((max(1, rm.num_page_layers(spec)), 9, 2,
                        spec.num_kv_heads, 16, spec.head_dim), f32)
-    if spec.mamba is None:
+    # the state pool as the engine sizes it (``engine_v2.py``), 4 slots
+    if spec.mamba is not None:
+        m = spec.mamba
+        pool = StatePoolConfig(
+            rm.num_state_layers(spec), 4, m["d_inner"], m["d_state"],
+            m["d_conv"],
+            conv_dim=m["d_inner"] + 2 * m.get("n_groups", 1) * m["d_state"]
+            if m.get("kind") == "mamba2" else m.get("conv_dim"))
+    elif spec.cca is not None:
+        pool = StatePoolConfig.tails_only(
+            rm.num_state_layers(spec), 4, taps=spec.cca["taps"],
+            channels=spec.cca["tail_channels"])
+    else:
         return spec, weights, pages
-    m = spec.mamba
-    pool = StatePoolConfig(
-        rm.num_state_layers(spec), 4, m["d_inner"], m["d_state"],
-        m["d_conv"], **({"conv_dim": m["d_inner"] + 2 * m.get("n_groups", 1)
-                         * m["d_state"]} if m.get("kind") == "mamba2" else {}))
     return spec, weights, StatefulKV(pages, *pool.zeros())
 
 
@@ -109,13 +142,14 @@ def programs(spec):
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
     host = RaggedBatch(num_slots=2, slot_size=16, max_sequences=4,
                        max_blocks=16).device_arrays()
-    state = rm.STATE_PASS_KEYS if spec.mamba is not None else ()
+    pooled = spec.mamba is not None or spec.cca is not None
+    state = rm.STATE_PASS_KEYS if pooled else ()
     pick = lambda keys: {k: jnp.zeros((4,), jnp.int32) if host[k] is None
                          else jnp.asarray(host[k]) for k in keys + state}
     i32 = lambda *s: jnp.zeros(s, jnp.int32)
     step = (i32(4), i32(4), i32(4, 16), i32(4) + 1,
             jax.random.PRNGKey(0), jnp.float32(1.0))
-    if spec.mamba is not None:
+    if pooled:
         step += (i32(4),)
     return {
         "serve_decode_step": (rm.build_decode_step(spec), step),
@@ -142,14 +176,14 @@ def golden():
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
-@pytest.mark.parametrize("fam", FAMILIES + LATER)
+@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER)
 def test_program_lowers_to_the_recorded_text(fam, program, golden):
     assert golden["jax"] == jax.__version__, (
         "another jax lowers to other text: write the file anew on the commit "
         "it records, with this jax")
     text = lowered(*tiny(fam), program)
     later = golden["later"]
-    commit = later.get(program) or later.get(fam) or golden["commit"]
+    commit = later.get(fam) or later.get(program) or golden["commit"]
     assert digest(text) == golden["programs"][f"{fam}.{program}"], (
         f"{fam}'s {program} is not the text that {commit} lowers "
         "to: if that is meant, write the file anew (module docstring)")
@@ -159,10 +193,11 @@ def test_program_lowers_to_the_recorded_text(fam, program, golden):
 #: device take the side buffer, narrower ones the in-layer write; JoyAI's
 #: latent pages have a builder of their own and the question is not asked
 SIDE_BUFFER = {"llama": False, "mixtral": False, "afmoe": False,
-               "jamba": True, "joyai": None, "granite": False}
+               "jamba": True, "joyai": None, "granite": False,
+               "nemotron_h": False, "qwen3_next": False, "zaya": True}
 
 
-@pytest.mark.parametrize("fam", FAMILIES + LATER)
+@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER)
 def test_the_form_each_familys_decode_step_takes(fam, monkeypatch):
     from deepspeed_tpu.inference.v2 import ragged_model as rm
     spec = tiny(fam)[0]
@@ -213,7 +248,7 @@ def _top_level_loops(jaxpr):
     return found
 
 
-@pytest.mark.parametrize("fam", FAMILIES + LATER)
+@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER)
 def test_the_traced_decode_step_holds_no_loop_but_its_layer_scans(fam):
     """One program decodes one token: the step's only loops are the scans
     over its units of layers (``layer_units``), in order, each as long as its
@@ -239,7 +274,8 @@ if __name__ == "__main__":
     # only, merged into ``<file>`` under ``later`` (recorded from a later
     # commit than the file's own)
     only = tuple(sys.argv[4:])
-    fams_ = [n for n in only if n in FAMILIES + LATER] or FAMILIES + LATER
+    every = FAMILIES + LATER + NEWER
+    fams_ = [n for n in only if n in every] or every
     hashes = {}
     for fam_ in fams_:
         model_ = tiny(fam_)
@@ -252,7 +288,8 @@ if __name__ == "__main__":
         record.setdefault("later", {}).update({f: commit for f in only})
     else:
         record = {"commit": commit, "jax": jax.__version__,
-                  "later": {f: commit for f in LATER}, "programs": hashes}
+                  "later": {f: commit for f in LATER + NEWER},
+                  "programs": hashes}
     with open(out, "w") as f:
         json.dump(record, f, indent=1)
         f.write("\n")
