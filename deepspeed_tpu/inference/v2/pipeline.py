@@ -49,6 +49,32 @@ from deepspeed_tpu.inference.v2.engine_v2 import fetch_to_host
 from deepspeed_tpu.monitor.trace import tracer as _tracer
 
 
+def rows_held(engine, ctx_lens: np.ndarray, live: np.ndarray) -> dict:
+    """What the rows of ``live`` held at one decode step, as arguments of its
+    ``serve/decode/step`` record (plain ints: ``tracer.export()`` writes them
+    as JSON). ``ctx_lens`` are the context lengths the step's program was
+    handed, a live sequence a row. ``ctx``: their sum, in tokens; ``pages``:
+    the whole pages that hold those tokens, a row at most the ring where the
+    engine runs a page ring; and where the model has windowed layers
+    ``ctx_window``: the tokens a windowed layer's query still sees,
+    ``min(ctx, window)`` a row — one int, or a tuple in the order of
+    ``engine._windowed_layers`` where the windows differ. Called under
+    ``tracer.enabled`` only: it is what a reader needs to hold the paged
+    kernels' device time against the bytes their rows' contexts are
+    (docs/OBSERVABILITY.md)."""
+    ctx = ctx_lens[live]
+    pages = -(-ctx // engine.kv.config.block_size)
+    ring = engine.scheduler.ring_pages
+    if ring is not None:
+        pages = np.minimum(pages, ring)
+    held = {"ctx": int(ctx.sum()), "pages": int(pages.sum())}
+    if engine._windowed_layers:
+        clipped = tuple(int(np.minimum(ctx, window).sum())
+                        for window, _ in engine._windowed_layers)
+        held["ctx_window"] = clipped[0] if len(clipped) == 1 else clipped
+    return held
+
+
 class DecodePipeline:
     """Double-buffered decode over a fixed live set of sequences.
 
@@ -218,7 +244,9 @@ class DecodePipeline:
                             live[i] = False
                             recorded[i] = j + 1
                 # build stage: step j+1's descriptors (blocks pre-reserved,
-                # so this is the whole of it)
+                # so this is the whole of it; it rebinds the context lengths
+                # step j's program was handed, which its span reports)
+                handed = db.ctx_lens
                 db.advance(1)
                 ids = nxt
                 t3 = perf()
@@ -247,9 +275,12 @@ class DecodePipeline:
                     else:
                         _tracer.add("serve/decode/build", t2, t3,
                                     lane="serve/decode", step=j)
+                    # with what the rows held whose token j was drained
+                    # (recording stops at j + 1 for a row retired this step)
                     _tracer.add("serve/decode/step", t0, t3,
                                 lane="serve/decode", step=j,
-                                live=drained_tokens)
+                                live=drained_tokens,
+                                **rows_held(e, handed[:S], recorded > j))
         except BaseException:
             # an escaping on_tokens (or interrupt) must not leave sequence
             # state desynchronized from the KV already written: settle every

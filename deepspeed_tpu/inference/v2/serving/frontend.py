@@ -736,9 +736,23 @@ class ServingFrontend:
         batch = self.engine._run_pass()
         if batch is None:
             return
+        # slot by live slot: the prompt tokens each chunk holds, and the keys
+        # before its first that it reads from pages — an earlier pass's, a
+        # prefix-cache hit's, or its own sequence's previous slot, scattered
+        # before attention runs (a packed pass reads no page). Tuples, not
+        # sums: a chunk's query-key pairs are ntok * cached + ntok * (ntok +
+        # 1) / 2, and that is what holds the chunk kernel's device time
+        # (chipbench/readers/paged.py)
+        live = batch.chunk_ntok > 0
+        ntok = batch.chunk_ntok[live]
+        # (the engine's own rule for which program a pass is: _run_pass)
+        packed = batch.pure_prefill and not self.engine.spec.alibi
+        cached = (np.zeros_like(ntok) if packed
+                  else batch.chunk_ctx_lens[live] - ntok)
         self._mark("serve/prefill/pass", slots=len(batch.slot_uid),
-                   tokens=int(batch.chunk_ntok.sum()),
-                   kind="packed" if batch.pure_prefill else "paged")
+                   tokens=int(ntok.sum()),
+                   kind="packed" if packed else "paged",
+                   ntok=tuple(map(int, ntok)), cached=tuple(map(int, cached)))
 
     def _handle(self, msg) -> None:
         kind, payload = msg

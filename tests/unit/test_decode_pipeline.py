@@ -394,6 +394,118 @@ def test_pipeline_traced_run_byte_identical_with_serve_spans(warm_engine):
         tracer.reset()
 
 
+def _trace_check(path):
+    """The real ``scripts/trace_check.py`` (docs/OBSERVABILITY.md, "Validating
+    traces") over an exported timeline."""
+    import pathlib
+    import subprocess
+    import sys
+    r = subprocess.run(
+        [sys.executable, "scripts/trace_check.py", str(path),
+         "--require", "serve/decode"], capture_output=True, text=True,
+        cwd=str(pathlib.Path(__file__).resolve().parents[2]))
+    assert r.returncode == 0, r.stdout + r.stderr
+
+
+def _step_records(tracer):
+    return sorted((r[5] for r in tracer.iter_records()
+                   if r[1] == "serve/decode/step"), key=lambda a: a["step"])
+
+
+def test_decode_step_records_say_what_the_live_rows_held(warm_engine,
+                                                         tmp_path):
+    """Every ``serve/decode/step`` record carries, for the rows still live at
+    that step, the sum of the contexts the step's program was handed (a row:
+    the tokens the scheduler held at the run's start, the ones decoded since
+    and the one fed now) and the whole pages that hold them — through a
+    retirement in the middle of the run: the retired row stops counting at
+    the step ``live`` drops. Plain ints, and the exported timeline is valid."""
+    from deepspeed_tpu.monitor.trace import tracer
+    N, GONE_AT = 13, 4
+    e = warm_engine
+    bs = e.kv.config.block_size
+    tracer.reset()
+    tracer.configure(trace_dir=str(tmp_path), enabled=True, ring_size=1024)
+    try:
+        e.put([0, 1, 2], PROMPTS)
+        seen = [e.scheduler.seqs[u].seen_tokens for u in (0, 1, 2)]
+        assert seen == [len(p) for p in PROMPTS]
+        pipe = e.decode_pipeline([0, 1, 2])
+        pipe.run(N, on_tokens=lambda j, uids, row: [1] if j == GONE_AT
+                 else None)
+        assert pipe.uids == [0, 2]
+        steps = _step_records(tracer)
+        assert [a["step"] for a in steps] == list(range(N))
+        crossed = False
+        for j, a in enumerate(steps):
+            rows = [0, 1, 2] if j <= GONE_AT else [0, 2]
+            ctx = [seen[i] + j + 1 for i in rows]
+            assert a["live"] == len(rows)
+            assert a["ctx"] == sum(ctx)
+            assert a["pages"] == sum(-(-c // bs) for c in ctx)
+            crossed = crossed or a["pages"] > len(rows)
+            assert "ctx_window" not in a         # no windowed layer here
+            assert all(type(v) is int for v in a.values())
+        assert crossed                            # a row took a second page
+        _trace_check(tracer.export())
+        e.flush([0, 1, 2])
+    finally:
+        tracer.reset()
+
+
+def test_decode_step_records_clip_to_the_window_and_the_ring(tmp_path):
+    """On a windowed model the record also carries ``ctx_window``, the tokens
+    a windowed layer's query still sees (``min(ctx, window)`` a row, summed),
+    and a row's pages stop at the page ring."""
+    from deepspeed_tpu.monitor.trace import tracer
+    cfg = LlamaConfig.tiny(vocab_size=128, max_position_embeddings=128,
+                           sliding_window=8)
+    model = LlamaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(3),
+                        {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
+    e = _build_engine(model_params=(model, params))
+    ring, bs = e.scheduler.ring_pages, e.kv.config.block_size
+    assert e._windowed_layers == [(8, cfg.num_hidden_layers)] and ring
+    prompts = [np.arange(ring * bs + 3, dtype=np.int32) % 128, PROMPTS[0]]
+    N = 5
+    tracer.reset()
+    tracer.configure(enabled=True, ring_size=1024)
+    try:
+        e.put([0, 1], prompts)
+        e.decode_pipeline([0, 1]).run(N)
+        steps = _step_records(tracer)
+        assert len(steps) == N
+        for j, a in enumerate(steps):
+            ctx = [len(p) + j + 1 for p in prompts]
+            assert a["ctx"] == sum(ctx)
+            assert a["ctx_window"] == sum(min(c, 8) for c in ctx)
+            assert a["pages"] == sum(min(-(-c // bs), ring) for c in ctx)
+            assert a["pages"] < sum(-(-c // bs) for c in ctx)
+            assert all(type(v) is int for v in a.values())
+        # the short row crossed the window inside the run
+        assert steps[0]["ctx_window"] < steps[-1]["ctx_window"] == 16
+    finally:
+        tracer.reset()
+
+
+def test_untraced_steps_compute_nothing_of_what_they_held(warm_engine,
+                                                          monkeypatch):
+    """With tracing off the step loop does not run the reductions."""
+    from deepspeed_tpu.inference.v2 import pipeline
+    from deepspeed_tpu.monitor.trace import tracer
+
+    def boom(*a, **kw):
+        raise AssertionError("rows_held ran with tracing off")
+
+    monkeypatch.setattr(pipeline, "rows_held", boom)
+    assert not tracer.enabled
+    e = warm_engine
+    e.put([0, 1, 2], PROMPTS)
+    out = e.decode_pipeline([0, 1, 2]).run(3)
+    assert out.shape == (3, 3)
+    e.flush([0, 1, 2])
+
+
 # --------------------------------------------------------------------------- #
 # generate() routed through the pipeline (the one-off API shares the hot path)
 # --------------------------------------------------------------------------- #

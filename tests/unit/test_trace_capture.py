@@ -223,6 +223,41 @@ def test_engine_thread_phases_tile_a_step():
     fe.close()
 
 
+def test_a_prefill_pass_says_what_its_chunks_held(tmp_path):
+    """``serve/prefill/pass`` carries, slot by live slot, the prompt tokens a
+    chunk holds (``ntok``) and the keys it reads from pages before its first
+    (``cached``): nothing in a packed pass, the prefix in a long prompt's
+    later chunks — the earlier pass's and, for the same pass's later slots,
+    the slots before them. Plain ints in tuples; the export is valid JSON."""
+    import json
+    engine, fe = _frontend()
+    rng = np.random.RandomState(1)
+    tracer.configure(enabled=True)
+    handles = [fe.submit(rng.randint(0, 128, size=(n,)).astype(np.int32),
+                         priority="hi", max_new_tokens=2) for n in (168,)]
+    for _ in range(8):
+        fe.step()
+    assert all(h.finished for h in handles)
+    tracer.enabled = False
+    passes = [r[5] for r in sorted(tracer.iter_records(), key=lambda r: r[2])
+              if r[1] == "serve/prefill/pass"]
+    assert [a["kind"] for a in passes] == ["packed", "paged"]
+    first, second = passes
+    assert first["ntok"] == (32, 32, 32) and first["cached"] == (0, 0, 0)
+    assert second["ntok"] == (32, 32, 8)
+    assert second["cached"] == (96, 128, 160)
+    for a in passes:
+        assert sum(a["ntok"]) == a["tokens"]
+        assert len(a["ntok"]) == len(a["cached"]) == a["slots"]
+        assert all(type(v) is int for v in a["ntok"] + a["cached"])
+    path = tracer.export(str(tmp_path / "trace.json"))
+    names = {ev["name"]: ev.get("args") for ev in json.load(open(path))[
+        "traceEvents"] if ev["ph"] == "B"}
+    assert names["serve/prefill/pass"]["cached"] == [96, 128, 160]
+    assert isinstance(names["serve/decode/step"]["ctx"], int)
+    fe.close()
+
+
 def test_phases_cost_nothing_recorded_when_off():
     engine, fe = _frontend()
     h = fe.submit(np.arange(5, dtype=np.int32), priority="hi",
