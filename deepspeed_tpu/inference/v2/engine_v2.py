@@ -50,9 +50,52 @@ from deepspeed_tpu.utils.fault_injection import maybe_fail as _maybe_fail
 from deepspeed_tpu.utils.logging import log_dist
 
 
+import collections
 import contextlib
 import functools
 import time as _time
+
+
+#: what the programs of a held share returned as their fourth result and no
+#: host has read yet (``serve/moe/held_overflow_turns``): int32 scalars on
+#: the device, read by :func:`_count_held_turns` once their program is done
+_held_turns_pending: "collections.deque" = collections.deque()
+
+
+def _count_held_turns() -> None:
+    """Add the finished programs' overflow turns to the always-on counter
+    ``serve/moe/held_overflow_turns``. Called where the host is fetching a
+    step's results anyway (:func:`fetch_to_host`); it reads only scalars
+    whose program has finished, oldest first, and waits for none."""
+    while _held_turns_pending:
+        try:
+            turns = _held_turns_pending.popleft()
+        except IndexError:      # another thread took the last
+            return
+        if not turns.is_ready():
+            _held_turns_pending.appendleft(turns)
+            return
+        _tracer.bump("serve/moe/held_overflow_turns",
+                     float(np.asarray(turns)))  # jaxlint: disable=JL007 -- 4 bytes of a finished pass
+
+
+class _ThreeResults:
+    """A jitted prefill pass or decode step, called for its three results.
+    The program of a held share returns a fourth, its MoE layers' turns past
+    the first (``ragged_model._stream_turns``): that is left on the device
+    for :func:`_count_held_turns`. Everything else (``lower``, the cache's
+    counters) is the program's own."""
+
+    def __init__(self, prog):
+        self.prog = prog
+
+    def __call__(self, *args):
+        first, second, new_kv, *turns = self.prog(*args)
+        _held_turns_pending.extend(turns)
+        return first, second, new_kv
+
+    def __getattr__(self, name):
+        return getattr(self.prog, name)
 
 
 def fetch_to_host(arr) -> np.ndarray:
@@ -72,17 +115,19 @@ def fetch_to_host(arr) -> np.ndarray:
     if _locksan.enabled():
         # runtime TL002 signal: a drain while sanitized locks are held
         _locksan.note_blocking("fetch_to_host")
-    if not _tracer.enabled:
-        return np.asarray(arr)  # jaxlint: disable=JL007 -- the intentional drain
-    t0 = _time.perf_counter()
+    traced = _tracer.enabled
+    t0 = _time.perf_counter() if traced else 0.0
     out = np.asarray(arr)  # jaxlint: disable=JL007 -- the intentional drain
-    _tracer.add("serve/drain/fetch_to_host", t0, _time.perf_counter(),
-                lane="serve/drain")
+    _count_held_turns()
+    if traced:
+        _tracer.add("serve/drain/fetch_to_host", t0, _time.perf_counter(),
+                    lane="serve/drain")
     return out
 
 
 def _program(fn, name: str, **jit_kwargs):
-    """``jax.jit(fn)`` under a name of its own. Every builder's inner function
+    """``jax.jit(fn)`` under a name of its own (for a pass or a decode step,
+    inside :class:`_ThreeResults`). Every builder's inner function
     is called ``fwd``; under its name the program is ``jit_<name>`` in a
     device trace, the compile log and the cache key (docs/OBSERVABILITY.md,
     "Names on the device's work"). A split-K rung above 1 is part of the
@@ -342,7 +387,8 @@ class InferenceEngineV2:
                 "ragged engine (shard-local slope schedules would be wrong); "
                 "run tp=1 or serve through init_inference")
         fwd = build_ragged_forward(self.spec, mesh=self.topology.mesh, tp=tp)
-        self._pass = _program(fwd, "serve_paged_pass", donate_argnums=(1,))
+        self._pass = _ThreeResults(
+            _program(fwd, "serve_paged_pass", donate_argnums=(1,)))
         self.compiles += 1
         # flash-decoding split ladder (config.attention; docs/SERVING.md
         # "Attention kernels"): one ragged-pass program per pow2 rung.
@@ -357,8 +403,8 @@ class InferenceEngineV2:
         for r in self.attn_split_ladder[1:]:
             fwd_r = build_ragged_forward(self.spec, mesh=self.topology.mesh,
                                          tp=tp, n_splits=r)
-            self._pass_rungs[r] = _program(
-                fwd_r, "serve_paged_pass" + _rung(r), donate_argnums=(1,))
+            self._pass_rungs[r] = _ThreeResults(_program(
+                fwd_r, "serve_paged_pass" + _rung(r), donate_argnums=(1,)))
             self.compiles += 1
         # test knob: pin the dispatched rung (None = admission-driven)
         self.attn_rung_override: Optional[int] = None
@@ -435,6 +481,17 @@ class InferenceEngineV2:
                         / (kv_cfg.num_layers * kv_cfg.block_size))
         if self.spec.moe is not None and "held" in self.spec.moe:
             _tracer.note("serve/moe/held_experts", self.spec.moe["held"][1])
+            # the sorted rows one turn of the paged pass's MoE layers takes
+            # (0: the pass sorts and combines every choice), and the turns
+            # its passes and decode steps took past the first, which stays
+            # 0 while none sends its held experts more than twice their
+            # even share
+            from deepspeed_tpu.inference.v2.ragged_model import (
+                pass_held_rows_bound)
+            _tracer.note("serve/moe/held_rows_bound", pass_held_rows_bound(
+                self.spec, self.weights, sm.num_chunk_slots
+                * sm.chunk_slot_size + sm.max_ragged_sequence_count) or 0)
+            _tracer.bump("serve/moe/held_overflow_turns", 0.0)
         if self.spec.moe is not None:
             # (1: one linear map of the layer's input; 2: an MLP on a state
             # that goes from layer to layer)
@@ -748,8 +805,8 @@ class InferenceEngineV2:
                                     lora_targets=self._lora_targets(rb),
                                     n_splits=sp)
             self.compiles += 1
-            return _program(fwd, "serve_decode_step" + _rung(sp),
-                            donate_argnums=(1,))
+            return _ThreeResults(_program(
+                fwd, "serve_decode_step" + _rung(sp), donate_argnums=(1,)))
 
         return self._step_progs.get_or_create(
             (bucket, bool(do_sample), int(top_k), int(rb), sp), _build)
@@ -980,8 +1037,14 @@ class InferenceEngineV2:
         from deepspeed_tpu.utils.compile_cache import (setup_summary,
                                                        with_stack_room)
         # set-up's second stage (tracer.stage), a child a family of the grid
+        counted = _tracer.totals.get("serve/moe/held_overflow_turns")
         with _tracer.stage("warmup"):
             built = with_stack_room(lambda: self._warmup(buckets, spec_ks))
+        if counted is not None:
+            # scratch rows are no traffic: a warmed decode step's rows are
+            # all alike, so a router may send every one to a held expert
+            _held_turns_pending.clear()
+            _tracer.note("serve/moe/held_overflow_turns", counted)
         if not self._setup_logged:      # once: a rejoin warms again
             self._setup_logged = True
             log_dist(f"engine_v2: {setup_summary()}", ranks=[0])
@@ -1231,10 +1294,10 @@ class InferenceEngineV2:
         if self._pass_prefill is None:
             from deepspeed_tpu.inference.v2.ragged_model import (
                 build_prefill_forward)
-            self._pass_prefill = _program(
+            self._pass_prefill = _ThreeResults(_program(
                 build_prefill_forward(self.spec, mesh=self.topology.mesh,
                                       tp=self.config.tensor_parallel),
-                "serve_prefill_packed", donate_argnums=(1,))
+                "serve_prefill_packed", donate_argnums=(1,)))
             self.compiles += 1
         return self._pass_prefill
 

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
 from deepspeed_tpu.inference.v2.ragged_model import (
     RaggedModelSpec, _embed_in, _greedy_accept, _layer_dest, _norm,
-    _sample_logits, _scan_layers, _transformer_layer, _unembed)
+    _pass_rows_live, _router_stream, _sample_logits, _scan_layers,
+    _stream_out, _stream_turns, _transformer_layer, _unembed)
 from deepspeed_tpu.ops.pallas.mla_attention import mla_row_write
 
 
@@ -99,7 +100,9 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
         tokens = jnp.concatenate([b["chunk_tokens"], b["decode_tokens"]])
         positions = jnp.concatenate([b["chunk_positions"],
                                      b["decode_positions"]])
-        x = _embed_in(spec, weights, tokens, positions)
+        x = _router_stream(
+            spec, _embed_in(spec, weights, tokens, positions), weights,
+            lambda: _pass_rows_live(b, Cs, True))
 
         def make_body(rs, experts, l0):
             ak = AttentionKernelSpec(rs)
@@ -137,12 +140,13 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
 
         x, flat = _scan_layers(spec, weights["layers"], make_body,
                                (x, pool.reshape(L * NB * bs, W)))
-        x = _finish(spec, weights, x)
+        turns = _stream_turns(x)
+        x = _finish(spec, weights, _stream_out(x))
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))
         logits = _unembed(spec, weights,
                           jnp.concatenate([x[last_rows], x[CT:]], axis=0))
-        return logits[:NC], logits[NC:], flat.reshape(pool.shape)
+        return (logits[:NC], logits[NC:], flat.reshape(pool.shape)) + turns
 
     return fwd
 
@@ -158,7 +162,9 @@ def build_packed_prefill(spec: RaggedModelSpec) -> Callable:
         S = b["decode_tokens"].shape[0]
         L, NB, bs, W = pool.shape
         positions = b["chunk_positions"]
-        x = _embed_in(spec, weights, b["chunk_tokens"], positions)
+        x = _router_stream(
+            spec, _embed_in(spec, weights, b["chunk_tokens"], positions),
+            weights, lambda: _pass_rows_live(b, Cs, False))
         # the page plan's windows of rows (RaggedBatch.page_ids/rows/fill)
         j = jnp.arange(bs, dtype=jnp.int32)
         rows = jnp.minimum(b["page_rows"][:, None] + j[None, :], CT - 1)
@@ -189,12 +195,13 @@ def build_packed_prefill(spec: RaggedModelSpec) -> Callable:
 
         x, pages = _scan_layers(spec, weights["layers"], make_body,
                                 (x, pool.reshape(L * NB, bs, W)))
-        x = _finish(spec, weights, x)
+        turns = _stream_turns(x)
+        x = _finish(spec, weights, _stream_out(x))
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))
         logits = _unembed(spec, weights, x[last_rows])
         return (logits, jnp.zeros((S, logits.shape[1]), logits.dtype),
-                pages.reshape(pool.shape))
+                pages.reshape(pool.shape)) + turns
 
     return fwd
 
@@ -214,7 +221,8 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
         pages = pool.reshape(L * NB, bs, W)
         # ctx counts this step's token; the pages hold the prefix
         prefix = jnp.maximum(ctx - 1, 0)
-        x = _embed_in(spec, weights, ids, positions)
+        x = _router_stream(spec, _embed_in(spec, weights, ids, positions),
+                           weights)
 
         def make_body(rs, experts, l0):
             ak = AttentionKernelSpec(rs)
@@ -244,14 +252,16 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
 
         x, side = _scan_layers(spec, weights["layers"], make_body,
                                (x, jnp.zeros((L, S, 8, W), pool.dtype)))
-        logits = _unembed(spec, weights, _finish(spec, weights, x))
+        turns = _stream_turns(x)
+        logits = _unembed(spec, weights,
+                          _finish(spec, weights, _stream_out(x)))
         # the kernels READ the pool inside the layers; the barrier orders
         # the in-place write after them instead of cloning the pool
         pool, _ = jax.lax.optimization_barrier((pool, logits))
         with jax.named_scope("kv_flush"):
             new_pool = mla_row_write(pool, side, block_tables, prefix, 1)
         nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
-        return nxt, logits, new_pool
+        return (nxt, logits, new_pool) + turns
 
     return fwd
 

@@ -1670,11 +1670,49 @@ def moe_route_mlp(x: jax.Array, w: Dict, routing: Dict[str, Any],
     return gates, ids.astype(jnp.int32), r
 
 
+def held_rows_bound(choices: int, held: int, routed: int,
+                    kernel: str) -> Optional[int]:
+    """How many sorted rows one turn of a held share's compact path takes
+    (:func:`_moe_ffn`), from what is static at trace time: TWICE the count a
+    router that spreads its ``choices`` (rows x top-k) evenly over its
+    ``routed`` outputs sends to the ``held`` experts, in whole row tiles of
+    the grouped kernel (``kernel``: :func:`moe_grouped_kernel`). None where
+    that is not under ``choices`` — a half held (granite, nemotron_h), every
+    expert beside a skip id (zaya): the path that sorts and combines every
+    choice is the cheaper there, and the program is the one it always was.
+    A pass that sends more than the bound to the held experts takes a second
+    turn (and a third ..), never a second program: nothing is dropped."""
+    want = -(-2 * choices * held // routed)
+    tm = row_tile(want) if kernel == "pallas" else 8
+    bound = -(-want // tm) * tm
+    return bound if bound < choices else None
+
+
+def pass_held_rows_bound(spec: "RaggedModelSpec", weights: Dict,
+                         rows: int) -> Optional[int]:
+    """:func:`held_rows_bound` of a pass of ``rows`` rows of this model
+    (``serve/moe/held_rows_bound``), None where no share is held or the
+    bound is not under the pass's choices."""
+    moe = spec.moe
+    if moe is None or "held" not in moe:
+        return None
+    for layers in _layer_stacks(weights["layers"]):
+        m = layers.get("moe") if isinstance(layers, dict) else None
+        if isinstance(m, dict) and "w_up" in m:
+            routed = (m["router"].shape[-1] if "router" in m else
+                      moe["num_experts"] + bool(moe.get("skip")))
+            return held_rows_bound(
+                rows * moe["top_k"], moe["held"][1], routed,
+                moe_grouped_kernel(m["w_up"], spec.dtype))
+    return None
+
+
 @jax.named_scope("moe_ffn")
 def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
              routing: Optional[Dict[str, Any]] = None,
-             routed: Optional[Tuple[jax.Array, jax.Array]] = None
-             ) -> jax.Array:
+             routed: Optional[Tuple[jax.Array, jax.Array]] = None,
+             turns: Optional[jax.Array] = None,
+             live: Optional[jax.Array] = None):
     """Sort-based token dispatch + grouped GEMM (parity: reference moe_scatter ->
     CUTLASS moe_gemm -> moe_gather, inference/v2/kernels). x: [T, hid].
 
@@ -1703,22 +1741,43 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
     it is dropped the way an assignment to an expert not held is — a row
     past the groups, which no grouped product visits — and the token's
     output is zero.
+
+    ``turns`` (an int32 scalar, a program's count so far; the prefill passes
+    and the decode step hand one, :func:`_router_stream`) lets a held share
+    take the compact path where :func:`held_rows_bound` gives a bound ``B``:
+    everything after the sort — the gather of ``x``, the plan, the grouped
+    products, the activation, the combine — works on slabs of ``B`` sorted
+    rows, as many as the held choices fill (one, unless the program's rows
+    send more than twice the even share to the held experts), and no array
+    has ``T * top_k`` rows of ``hid``. The result is then ``(out, turns +
+    the turns past the first)``; without ``turns`` it is ``out``. On that
+    path the rows that ``live`` ``[T]`` says hold no token (a pass's padding,
+    all alike, so routed alike: a thousand such rows on one held expert would
+    take a second turn for nobody) ask no expert, and their routed output is
+    zero.
     """
     T, hid = x.shape
     held = (routing or {}).get("held")
     plain = _plain_act((routing or {}).get("act", "gelu"))
     if routed is None:
-        E = w["router"].shape[-1]
+        E = routed_width = w["router"].shape[-1]
         with jax.named_scope("router"):
             gates, ids = moe_route(x, w, top_k, routing)
     else:
         E = routing["num_experts"]
+        routed_width = E + bool(routing.get("skip"))
         gates, ids = routed
         if routing.get("skip") and held is None:
             held = (0, E)
 
+    kernel = moe_grouped_kernel(w["w_up"], x.dtype)
+    bound = None
+    if turns is not None and held is not None:
+        bound = held_rows_bound(T * top_k, held[1], routed_width, kernel)
+
     with jax.named_scope("sort"):
-        tok_idx = jnp.repeat(jnp.arange(T), top_k)                     # [T*K]
+        if bound is None:
+            tok_idx = jnp.repeat(jnp.arange(T), top_k)                 # [T*K]
         expert_ids = ids.reshape(-1)
         if held is not None:
             # this chip's share: the router chose among all E and weighed
@@ -1729,65 +1788,83 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
             first, E = held
             local = expert_ids - first
             on = (local >= 0) & (local < E)
+            if bound is not None and live is not None:
+                on = on & jnp.repeat(live, top_k)
             expert_ids = jnp.where(on, local, E)
             gates = jnp.where(on.reshape(gates.shape), gates, 0.0)
-        order = jnp.argsort(expert_ids)
-        kernel = moe_grouped_kernel(w["w_up"], x.dtype)
         _tracer.bump(f"serve/moe/grouped_kernel/{kernel}")
-        # the Pallas kernel walks whole row tiles. XLA:TPU runs its own
-        # grouped-GEMM kernel only on a row count that is a multiple of 8;
-        # any other count lowers to a dense product over EVERY group of the
-        # rhs, all other layers' experts included. Rows past the last group
-        # belong to no expert and are dropped from ys.
-        tm = row_tile(order.shape[0]) if kernel == "pallas" else 8
-        rows = jnp.pad(order, (0, -order.shape[0] % tm))
-        xs = x[tok_idx[rows]]                                  # [T*K + pad, hid]
-        group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
-        if kernel == "pallas":      # one plan for the layer's three products
-            visits = plan_visits(group_sizes, rows.shape[0], tm)
-        row_e = expert_ids[rows]
-        if held is not None:
-            row_e = jnp.minimum(row_e, E - 1)
+        if bound is None:
+            order = jnp.argsort(expert_ids)
+            # the Pallas kernel walks whole row tiles. XLA:TPU runs its own
+            # grouped-GEMM kernel only on a row count that is a multiple of
+            # 8; any other count lowers to a dense product over EVERY group
+            # of the rhs, all other layers' experts included. Rows past the
+            # last group belong to no expert and are dropped from ys.
+            tm = row_tile(order.shape[0]) if kernel == "pallas" else 8
+            rows = jnp.pad(order, (0, -order.shape[0] % tm))
+            xs = x[tok_idx[rows]]                              # [T*K + pad, hid]
+            group_sizes = jnp.bincount(expert_ids, length=E).astype(jnp.int32)
+            visits = None
+            if kernel == "pallas":  # one plan for the layer's three products
+                visits = plan_visits(group_sizes, rows.shape[0], tm)
+            row_e = expert_ids[rows]
+            if held is not None:
+                row_e = jnp.minimum(row_e, E - 1)
 
-    def gg(lhs, rhs):
-        if isinstance(rhs, dict) and "w8" in rhs:
-            # int8 expert stacks (ADVICE r4: the experts are the dominant
-            # streamed bytes of an MoE serving step — leaving them bf16 made
-            # quantization.weight_bits a silent no-op on mixtral). The
-            # per-(expert, output-column) scale applies per ROW of the
-            # grouped output, indexed by the row's expert.
-            raw = jax.lax.ragged_dot(lhs, rhs["w8"].astype(lhs.dtype),
-                                     group_sizes,
-                                     preferred_element_type=jnp.float32)
-            return (raw * rhs["scale"][row_e, 0, :]).astype(lhs.dtype)
-        groups = rhs.reshape((-1,) + rhs.shape[-2:])       # [L*E, K, N]
-        if kernel == "pallas":
-            # each touched expert read once where it lies: group g of this
-            # layer is matrix l*E + g, and an empty group is never fetched
-            return grouped_matmul(lhs, groups, visits,
-                                  l if groups.shape[0] != E else 0)
-        # XLA's kernel takes its sizes over every group of the rhs: the
-        # layer's at offset l*E among zeros
-        sizes = group_sizes
-        if groups.shape[0] != E:
-            sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros(groups.shape[0], jnp.int32), group_sizes, (l * E,))
-        return jax.lax.ragged_dot(lhs, groups.astype(lhs.dtype), sizes)
+    def experts_of(xs, sizes, visits, row_e):
+        """The experts' output for sorted rows ``xs`` ``[M, hid]``, group
+        ``g`` holding ``sizes[g]`` of them (``visits``: the Pallas kernel's
+        plan of them; ``row_e``: each row's group, for an int8 stack's
+        scales); rows of no group hold nothing defined."""
 
-    with jax.named_scope("experts"):
+        def gg(lhs, rhs):
+            if isinstance(rhs, dict) and "w8" in rhs:
+                # int8 expert stacks (ADVICE r4: the experts are the dominant
+                # streamed bytes of an MoE serving step — leaving them bf16
+                # made quantization.weight_bits a silent no-op on mixtral).
+                # The per-(expert, output-column) scale applies per ROW of
+                # the grouped output, indexed by the row's expert.
+                raw = jax.lax.ragged_dot(lhs, rhs["w8"].astype(lhs.dtype),
+                                         sizes,
+                                         preferred_element_type=jnp.float32)
+                return (raw * rhs["scale"][row_e, 0, :]).astype(lhs.dtype)
+            groups = rhs.reshape((-1,) + rhs.shape[-2:])       # [L*E, K, N]
+            if kernel == "pallas":
+                # each touched expert read once where it lies: group g of
+                # this layer is matrix l*E + g, and an empty group is never
+                # fetched
+                return grouped_matmul(lhs, groups, visits,
+                                      l if groups.shape[0] != E else 0)
+            # XLA's kernel takes its sizes over every group of the rhs: the
+            # layer's at offset l*E among zeros
+            full = sizes
+            if groups.shape[0] != E:
+                full = jax.lax.dynamic_update_slice(
+                    jnp.zeros(groups.shape[0], jnp.int32), sizes, (l * E,))
+            return jax.lax.ragged_dot(lhs, groups.astype(lhs.dtype), full)
+
         if "w_gate" in w:
             h = jax.nn.silu(gg(xs, w["w_gate"])) * gg(xs, w["w_up"])
         else:       # two matrices an expert: ``routing["act"]`` between them
             h = plain(gg(xs, w["w_up"]))
-        ys = gg(h, w["w_down"])[:order.shape[0]]                       # [T*K, hid]
-        if held is not None:    # rows of no group hold nothing defined
-            ys = jnp.where((expert_ids[order] < E)[:, None], ys, 0)
-    with jax.named_scope("combine"):
-        scale = gates.reshape(-1)[order].astype(ys.dtype)
-        # scatter-free combine: invert the sort permutation and sum the K
-        # choices (parallel/moe.py dropless_moe — TPU scatter-add serializes)
-        inv = jnp.argsort(order)
-        out = (ys * scale[:, None])[inv].reshape(T, top_k, hid).sum(axis=1)
+        return gg(h, w["w_down"])
+
+    if bound is None:
+        with jax.named_scope("experts"):
+            ys = experts_of(xs, group_sizes, visits, row_e)[:order.shape[0]]
+            if held is not None:    # rows of no group hold nothing defined
+                ys = jnp.where((expert_ids[order] < E)[:, None], ys, 0)
+        with jax.named_scope("combine"):
+            scale = gates.reshape(-1)[order].astype(ys.dtype)
+            # scatter-free combine: invert the sort permutation and sum the
+            # K choices (parallel/moe.py dropless_moe — TPU scatter-add
+            # serializes)
+            inv = jnp.argsort(order)
+            out = (ys * scale[:, None])[inv].reshape(T, top_k, hid).sum(axis=1)
+    else:
+        out, over = _held_compact(x, expert_ids, gates.reshape(-1), top_k,
+                                  E, bound, kernel, experts_of)
+        turns = turns + over
     if "shared" in w:
         with jax.named_scope("shared"):
             sh = w["shared"]
@@ -1797,7 +1874,101 @@ def _moe_ffn(x: jax.Array, w: Dict, top_k: int, dtype, l=0,
                 shared = shared * jax.nn.sigmoid(
                     _mm(x, w["shared_gate"]).astype(jnp.float32))
             out = out + shared
-    return out.astype(dtype)
+    out = out.astype(dtype)
+    return out if turns is None else (out, turns)
+
+
+def _combine_chunk(bound: int, tm: int) -> int:
+    """Rows a step of the compact path's combine (:func:`_held_compact`):
+    the most whole row tiles, up to 1,024 rows, that divide the slab — a
+    slab is twice the even share, so about half its rows are live, and the
+    combine multiplies only the chunks that hold some (cell 11: 896 of
+    5,376, three steps of six; cell 8: 384 of 1,152, two of three)."""
+    tiles = bound // tm
+    return tm * max(d for d in range(1, tiles + 1)
+                    if tiles % d == 0 and (tm * d <= 1024 or d == 1))
+
+
+def _held_compact(x, expert_ids, gates, top_k: int, E: int, bound: int,
+                  kernel: str, experts_of):
+    """The held share of :func:`_moe_ffn` over slabs of ``bound`` sorted
+    rows: ``(out [T, hid] float32, the turns past the first)``.
+
+    ``expert_ids`` ``[T * top_k]`` hold each choice's held expert or ``E``
+    (held elsewhere, or no expert), ``gates`` its weight (0 there). One sort
+    puts the held choices first, by expert, their weights beside them; turn
+    ``t`` takes sorted rows ``t * bound ..``: it gathers their tokens' rows
+    of ``x``, plans and runs the grouped products over the part of each
+    group that lies in the slab, and adds each token's rows to its sum — a
+    product of gate-weighted one-hots ``[T, rows]`` (the gates in the rows'
+    dtype) with the rows ``[rows, hid]``, accumulated in float32, chunk by
+    chunk (:func:`_combine_chunk`) as far as the slab's live rows go; no
+    second sort, no gather back to ``T * top_k`` rows. There are
+    ``ceil(held choices / bound)`` turns: none where no choice is held, one
+    where the router spreads its choices (the bound is twice the even
+    share), as many as it takes otherwise."""
+    T, hid = x.shape
+    N = expert_ids.shape[0]
+    tm = row_tile(bound) if kernel == "pallas" else 8
+    assert bound % tm == 0, (bound, tm)
+    chunk = _combine_chunk(bound, tm)
+    with jax.named_scope("sort"):
+        # sorted keys, the choices they came from and their weights; past
+        # the end, slabs read choices of no expert and no weight
+        pad = -N % bound
+        skey, order, sgate = jax.lax.sort(
+            (expert_ids.astype(jnp.int32), jnp.arange(N, dtype=jnp.int32),
+             gates), num_keys=1)
+        skey = jnp.pad(skey, (0, pad), constant_values=E)
+        order, sgate = jnp.pad(order, (0, pad)), jnp.pad(sgate, (0, pad))
+        # the groups' ends, counted (a bincount is a scatter-add: 186 us at
+        # cell 11's 21,120 choices, PERF.md PR 53)
+        ends = jnp.sum(expert_ids[None, :] <= jnp.arange(E)[:, None],
+                       axis=1, dtype=jnp.int32)
+        starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+        n_turns = (ends[-1] + bound - 1) // bound
+
+    def turn(t, acc):
+        with jax.named_scope("sort"):
+            lo = t * bound
+            row_e = jax.lax.dynamic_slice(skey, (lo,), (bound,))
+            tok = jax.lax.dynamic_slice(order, (lo,), (bound,)) // top_k
+            xs = x[tok]                                        # [bound, hid]
+            sizes = jnp.clip(ends, lo, lo + bound) \
+                - jnp.clip(starts, lo, lo + bound)
+            visits = (plan_visits(sizes, bound, tm) if kernel == "pallas"
+                      else None)
+        with jax.named_scope("experts"):
+            ys = experts_of(xs, sizes, visits, jnp.minimum(row_e, E - 1))
+        with jax.named_scope("combine"):
+            scale = jax.lax.dynamic_slice(sgate, (lo,), (bound,))
+            tokens = jnp.arange(T, dtype=tok.dtype)[:, None]
+
+            def add(c, acc):
+                at = c * chunk
+                live = jax.lax.dynamic_slice(row_e, (at,), (chunk,)) < E
+                # rows of no group hold nothing defined
+                rows = jnp.where(
+                    live[:, None],
+                    jax.lax.dynamic_slice(ys, (at, 0), (chunk, hid)), 0)
+                onehot = jnp.where(
+                    jax.lax.dynamic_slice(tok, (at,), (chunk,))[None, :]
+                    == tokens,
+                    jnp.where(live, jax.lax.dynamic_slice(
+                        scale, (at,), (chunk,)), 0).astype(ys.dtype)[None, :],
+                    0)                                         # [T, chunk]
+                return acc + jnp.dot(
+                    onehot, rows, preferred_element_type=jnp.float32,
+                    precision=(jax.lax.Precision.HIGHEST
+                               if ys.dtype == jnp.float32 else None))
+
+            n_live = jnp.clip(ends[-1] - lo, 0, bound)
+            return jax.lax.fori_loop(0, (n_live + chunk - 1) // chunk, add,
+                                     acc)
+
+    out = jax.lax.fori_loop(0, n_turns, turn,
+                            jnp.zeros((T, hid), jnp.float32))
+    return out, jnp.maximum(n_turns - 1, 0).astype(jnp.int32)
 
 
 
@@ -2516,9 +2687,9 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     dtype = spec.dtype
     state = ()
-    r = None
+    r = turns = live = None
     if isinstance(x, tuple):
-        x, r = x
+        x, r, turns, live = x
     if spec.block == "ffn":
         pass        # the layer is its FFN alone: no mixer, ``attend`` unused
     elif spec.mamba is not None:
@@ -2619,7 +2790,8 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
             (res_a[i] * x.astype(jnp.float32) + res_c[i])
             + (res_a[i + 1] * out.astype(jnp.float32) + res_c[i + 1])
         ).astype(dtype)
-    stream = lambda x: x if r is None else (x, r)
+    stream = lambda x: x if r is None and turns is None else (x, r, turns,
+                                                              live)
     if spec.block == "mixer":       # one block a layer: no FFN follows
         return stream(join(x, attn_out).astype(dtype)), tuple(state)
     if spec.block == "ffn":         # .. or none went before: its one norm
@@ -2643,7 +2815,9 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
                                                spec.eps)
             mlp_out = _moe_ffn(mlp_in, {**w["moe"], **(experts or {})},
                                spec.moe["top_k"], dtype, l, routing=spec.moe,
-                               routed=routed)
+                               routed=routed, turns=turns, live=live)
+            if turns is not None:
+                mlp_out, turns = mlp_out
         else:
             m = w["mlp"]
             if spec.activation in ("swiglu", "geglu"):
@@ -2669,19 +2843,50 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
     return stream(x.astype(dtype)), tuple(state)
 
 
-def _router_stream(spec: "RaggedModelSpec", x):
+def _router_stream(spec: "RaggedModelSpec", x, weights=None, live=None):
     """What the layer loop carries first: the residual stream ``x`` ``[T,
-    hid]``, or beside it the router's state ``[T, R]`` (float32, zero before
-    the first layer) where the router hands one from layer to layer
-    (:func:`moe_route_mlp`)."""
-    if spec.moe is None or spec.moe.get("router") != "mlp":
+    hid]``, or ``(x, r, turns, live)`` — ``r`` the router's state ``[T, R]``
+    (float32, zero before the first layer) where the router hands one from
+    layer to layer (:func:`moe_route_mlp`), else None; ``turns`` an int32
+    count of a held share's turns past the first, summed over the layers, in
+    a program that hands its ``weights`` (the prefill passes and the decode
+    step; not the verify step) and whose rows give a held share a bound
+    (:func:`pass_held_rows_bound`) — which lets its MoE layers take the
+    compact path of :func:`_moe_ffn` — else None; beside such a count, what
+    ``live()`` gives: ``[T]``, the rows that hold a token, where the program
+    can tell (a pass's padding is routed like any row and read by nobody: on
+    the compact path it asks no expert), else None."""
+    moe = spec.moe or {}
+    r = turns = None
+    if moe.get("router") == "mlp":
+        r = jnp.zeros((x.shape[0], moe["router_hidden"]), jnp.float32)
+    if weights is not None and pass_held_rows_bound(
+            spec, weights, x.shape[0]) is not None:
+        turns = jnp.zeros((), jnp.int32)
+    if r is None and turns is None:
         return x
-    return x, jnp.zeros((x.shape[0], spec.moe["router_hidden"]), jnp.float32)
+    return x, r, turns, None if turns is None or live is None else live()
+
+
+def _pass_rows_live(b, slot_size: int, decode_rows: bool):
+    """Which rows of a pass hold a token: of its chunk slots ``[NC * Cs]``,
+    and with ``decode_rows`` of the decode rows after them ``[S]``."""
+    live = (jnp.arange(slot_size)[None, :]
+            < b["chunk_ntok"][:, None]).reshape(-1)
+    if decode_rows:
+        live = jnp.concatenate([live, b["decode_ctx_lens"] > 0])
+    return live
 
 
 def _stream_out(x):
     """The residual stream out of what :func:`_router_stream` made."""
     return x[0] if isinstance(x, tuple) else x
+
+
+def _stream_turns(x) -> Tuple:
+    """What a program returns after its three results: nothing, or its held
+    share's turns past the first (``(turns,)``) where it counted them."""
+    return (x[2],) if isinstance(x, tuple) and x[2] is not None else ()
 
 
 def _tail_args(rs: "RaggedModelSpec", st, rows, l) -> Dict[str, Any]:
@@ -2909,7 +3114,10 @@ def build_ragged_forward(spec: RaggedModelSpec,
                          n_splits: int = 1) -> Callable:
     """Returns ``fwd(weights, kv_pages, batch) ->
     (chunk_logits [NC, V], decode_logits [S, V], new_kv)`` where
-    ``chunk_logits[j]`` holds the logits after slot j's last token.
+    ``chunk_logits[j]`` holds the logits after slot j's last token — and,
+    where the model holds a share of its experts that gives the pass a bound
+    (:func:`pass_held_rows_bound`), a fourth result: the int32 count of the
+    turns its MoE layers took past their first (:func:`_stream_turns`).
 
     kv_pages: [L, NB, 2, Hkv, bs, D] combined head-major pages (see
     ragged/kv_cache.py), or an (int8 values, f32 scales) tuple for the
@@ -2942,7 +3150,9 @@ def build_ragged_forward(spec: RaggedModelSpec,
         tokens = jnp.concatenate([b["chunk_tokens"], b["decode_tokens"]])
         positions = jnp.concatenate([b["chunk_positions"], b["decode_positions"]])
 
-        x = _router_stream(spec, _embed_in(spec, weights, tokens, positions))
+        x = _router_stream(
+            spec, _embed_in(spec, weights, tokens, positions), weights,
+            lambda: _pass_rows_live(b, Cs, True))
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -2988,6 +3198,7 @@ def build_ragged_forward(spec: RaggedModelSpec,
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
         new_kv = _state_pack(new_kv, st)
 
+        turns = _stream_turns(x)
         x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
                   dtype, spec.norm_plus_one)
         # only NC + S rows are ever read (parity: ragged_ops/logits_gather —
@@ -2996,7 +3207,7 @@ def build_ragged_forward(spec: RaggedModelSpec,
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))    # [NC]
         xs = jnp.concatenate([x[last_rows], x[CT:]], axis=0)   # [NC + S, hid]
         logits = _unembed(spec, weights, xs)
-        return logits[:NC], logits[NC:], new_kv
+        return (logits[:NC], logits[NC:], new_kv) + turns
 
     return fwd
 
@@ -3042,7 +3253,8 @@ def build_prefill_forward(spec: RaggedModelSpec,
         positions = b["chunk_positions"]
         seg = b["row_seg"]
 
-        x = _router_stream(spec, _embed_in(spec, weights, tokens, positions))
+        x = _router_stream(spec, _embed_in(spec, weights, tokens, positions),
+                           weights, lambda: _pass_rows_live(b, Cs, False))
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -3085,13 +3297,14 @@ def build_prefill_forward(spec: RaggedModelSpec,
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
         new_kv = _state_pack(new_kv, st)
 
+        turns = _stream_turns(x)
         x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
                   dtype, spec.norm_plus_one)
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))    # [NC]
         logits = _unembed(spec, weights, x[last_rows])
         decode_logits = jnp.zeros((S, logits.shape[1]), logits.dtype)
-        return logits, decode_logits, new_kv
+        return (logits, decode_logits, new_kv) + turns
 
     return fwd
 
@@ -3154,7 +3367,9 @@ def build_decode_step(spec: RaggedModelSpec, mesh=None, tp: int = 1,
     block_tables [S, MB], ctx [S], key, temperature, *tail) ->
     (next_ids [S] int32, logits [S, V], new_kv)`` where ``ctx`` counts each
     row's tokens INCLUDING this step's and ``logits`` predict ``next_ids``
-    (kept for the engine's continuation refs). ``tail`` is empty but for:
+    (kept for the engine's continuation refs) — and a fourth result, the
+    step's count of overflow turns, where the model holds a share of its
+    experts that gives the step's rows a bound (:func:`_stream_turns`). ``tail`` is empty but for:
     ``lora_targets`` set, the two REQUIRED LoRA operands ``(lora_pool,
     adapter_pt)`` — each row's grouped adapter delta rides the targeted
     projections; a model with state-space layers, ``(state_slots [S],)``,
@@ -3246,7 +3461,8 @@ def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
         side_k0 = jnp.zeros((L, S, side_rows, D), side_dtype)
         side_v0 = jnp.zeros((L, S, side_rows, D), side_dtype)
 
-        x = _router_stream(spec, _embed_in(spec, weights, ids, positions))
+        x = _router_stream(spec, _embed_in(spec, weights, ids, positions),
+                           weights)
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -3298,6 +3514,7 @@ def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
 
         x, sk_all, sv_all, st = _scan_layers(
             spec, weights["layers"], make_body, (x, side_k0, side_v0, st0))
+        turns = _stream_turns(x)
         x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
                   dtype, spec.norm_plus_one)
         logits = _unembed(spec, weights, x)
@@ -3313,7 +3530,7 @@ def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
                                         block_tables, prefix, 1,
                                         kv_scales=kv_sc)
         nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
-        return nxt, logits, _state_pack(new_kv, st)
+        return (nxt, logits, _state_pack(new_kv, st)) + turns
 
     return fwd
 
@@ -3352,7 +3569,8 @@ def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
         # the kernel, new rows scattered in place after — the pool flows
         # through the layer scan with no copies (see the kernel docstring
         # for why a pre-kernel scatter forces XLA to clone the pool).
-        x = _router_stream(spec, _embed_in(spec, weights, ids, positions))
+        x = _router_stream(spec, _embed_in(spec, weights, ids, positions),
+                           weights)
 
         def make_body(rs, experts, l0):
             if rs.mamba is not None:
@@ -3403,6 +3621,7 @@ def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
         x, kvp, sc, st = _scan_layers(
             spec, weights["layers"], make_body, (x, kvp0, sc0, st0),
             extra_xs=() if lora_ops is None else (lora_ops,))
+        turns = _stream_turns(x)
         x = _norm(_stream_out(x), weights["final_norm"], spec.norm, spec.eps,
                   dtype, spec.norm_plus_one)
         logits = _unembed(spec, weights, x)
@@ -3410,7 +3629,7 @@ def _build_decode_layer_write(spec: RaggedModelSpec, mesh, tp: int,
         if kvq:
             new_kv = (new_kv, sc.reshape(L, NB, r8, 128))
         nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
-        return nxt, logits, _state_pack(new_kv, st)
+        return (nxt, logits, _state_pack(new_kv, st)) + turns
 
     return fwd
 

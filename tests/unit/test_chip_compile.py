@@ -515,6 +515,16 @@ _DECODE_STEP_CALLS = {
 }
 
 
+#: the MoE layer bodies of a decode step whose held share gives its rows a
+#: bound (``ragged_model.held_rows_bound``: JoyAI's 16 of 256 at 32 rows x
+#: top-8, its 39 MoE layers one scanned body; Qwen3-Next's 64 of 512 at 64 x
+#: top-10, the four bodies of its repeating unit). Each holds the compact
+#: path's two loops: over slabs of sorted rows, and over a slab's live chunks
+#: in the combine (docs/SERVING.md "Held experts"); granite and nemotron hold
+#: half, zaya every expert beside a skip id: no bound, no such loop
+_COMPACT_MOE_BODIES = {"joyai": 1, "qwen3_next": 4}
+
+
 @pytest.mark.parametrize("family", sorted(_DECODE_STEPS))
 def test_decode_step_loops_over_its_layers_and_nothing_else(
         family, compiled_step, monkeypatch):
@@ -522,13 +532,16 @@ def test_decode_step_loops_over_its_layers_and_nothing_else(
     the builders; the lowered text changed with it, so the recorded hashes
     cannot speak across that PR): the compiled step's ``while`` loops are its
     scans over units of layers that repeat — the compiler unrolls a unit of
-    one — and its Mosaic calls are the family's kernels by name."""
+    one — and, inside them, the two loops of each MoE layer body that takes
+    the compact path of a held share (``_COMPACT_MOE_BODIES``); its Mosaic
+    calls are the family's kernels by name."""
     from deepspeed_tpu.inference.v2 import ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     compiled, spec, _ = compiled_step(family)
     text = compiled.as_text()
     loops = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? while\(", text, re.M)
-    assert len(loops) == sum(n > 1 for _, _, n in rm.layer_units(spec)), loops
+    assert len(loops) == sum(n > 1 for _, _, n in rm.layer_units(spec)) \
+        + 2 * _COMPACT_MOE_BODIES.get(family, 0), loops
     mosaic = {m.group(1) for m in re.finditer(
         r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
         r'custom_call_target="tpu_custom_call"', text, re.M)}
@@ -619,6 +632,16 @@ def _executed(text):
                              if d)
                 out.append((m.group(1), dims, m.group(3), line))
     return out, params
+
+
+def _rows_of_every_choice(text, choices, widths):
+    """The values in memory of a compiled pass that hold a row for every
+    choice of its router (``choices`` = rows x top-k) at one of the model's
+    ``widths``: what the compact path of a held share leaves none of
+    (docs/SERVING.md "Held experts") — its gather, products, activation and
+    combine see slabs of ``held_rows_bound`` rows."""
+    return [line.strip()[:120] for _, dims, _, line in _executed(text)[0]
+            if len(dims) >= 2 and choices in dims[:-1] and dims[-1] in widths]
 
 
 _QKV = ((HID, H * D), (HID, HKV * D))                 # wq; wk and wv
@@ -876,6 +899,14 @@ def test_joyai_programs_keep_the_latent_pool_and_the_weights_in_place(
     text = compiled.as_text()
     for kernel in kernels:
         assert kernel in text, f"{kernel} is not in the program"
+    if program != "serve_decode_step":
+        # 16 of 256 held: a pass's MoE layers work on slabs of 1,152 (the
+        # paged pass: 1,056 rows x top-8) or 1,024 sorted rows, and nothing
+        # holds a row of 2,048 or of 768 for every choice
+        tokens = 4 * 256 + (32 if program == "serve_paged_pass" else 0)
+        assert rm.pass_held_rows_bound(spec, weights, tokens) == (
+            1152 if program == "serve_paged_pass" else 1024)
+        assert not _rows_of_every_choice(text, tokens * 8, (2048, 768))
     pool = math.prod(kv.shape) * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool
@@ -1359,6 +1390,14 @@ def test_qwen3_next_programs_hold_the_delta_kernels_and_the_pools_in_place(
     assert "ragged-dot" not in text and "mini-gather" not in text
     assert not re.search(r"bf16\[\d+,64,(2048,512|512,2048)\]\S* copy\(",
                          text), "an expert stack is copied"
+    if program != "serve_decode_step":
+        # 64 of 512 held: a pass's MoE layers work on slabs of 5,376 (the
+        # paged pass: 2,112 rows x top-10) or 5,120 sorted rows, and nothing
+        # holds a row of 2,048 or of 512 for every choice
+        tokens = slots * 256 + (rows if program == "serve_paged_pass" else 0)
+        assert rm.pass_held_rows_bound(spec, weights, tokens) == (
+            5376 if program == "serve_paged_pass" else 5120)
+        assert not _rows_of_every_choice(text, tokens * 10, (2048, 512))
     mem = compiled.memory_analysis()
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools

@@ -204,6 +204,45 @@ def test_held_experts_give_their_share(built):
     assert close(got, want)
 
 
+@pytest.mark.parametrize("routing", ["as_drawn", "one_held_choice_a_token"])
+def test_a_small_held_share_takes_the_compact_path(routing):
+    """Experts 2-3 of 16 held: the two prefill passes have a bound under
+    their choices (32 of the paged pass's 36 x 3) and their MoE layers work
+    on slabs of that many sorted rows; the logits are the reference's given
+    the same share. With every router bent so that each token sends exactly
+    one of its three choices to a held expert (expert 2 scores ``x . u``,
+    expert 3 ``-x . u``, the fourteen others 0), the packed pass's 32 rows
+    hold 32 held choices against its bound of 24, so each of its 8 layers
+    takes a second turn: ``serve/moe/held_overflow_turns`` counts them, and
+    the logits are still the reference's (nothing is dropped). The paged
+    passes' padding (24 and 2 of their 32 chunk rows, 4 decode rows) asks no
+    expert, so their 8 and 30 held choices stay under their bound of 32."""
+    from deepspeed_tpu.monitor.trace import tracer
+    cfg, model, params = build(num_experts=16, experts_held=(2, 2))
+    if routing == "one_held_choice_a_token":
+        u = jax.random.normal(jax.random.PRNGKey(5), (cfg.hidden_size,))
+
+        def bend(path, leaf):
+            names = [getattr(p, "key", "") for p in path]
+            if names[-2:] == ["gate", "kernel"] and leaf.shape[-1] == 16:
+                return jnp.zeros_like(leaf).at[:, 2].set(u).at[:, 3].set(-u)
+            return leaf
+
+        params = jax.tree_util.tree_map_with_path(bend, params)
+    ids = np.random.default_rng(2).integers(0, 256, 70).astype(np.int32)
+    eng = engine_for(model, params)
+    assert eng.spec.moe["held"] == (2, 2)
+    assert tracer.totals["serve/moe/held_rows_bound"] == 32
+    before = tracer.totals["serve/moe/held_overflow_turns"]
+    got = eng.put([1], [ids[:40]])[0]           # a packed pass, a paged pass
+    got2 = eng.put([1], [ids[40:]])[0]          # one paged pass
+    want = np.asarray(reference(cfg, params, ids))
+    assert close(got, want[39]) and close(got2, want[-1])
+    over = tracer.totals["serve/moe/held_overflow_turns"] - before
+    # the packed pass's 8 MoE layers, a second turn each; none as drawn
+    assert over == (8 if routing == "one_held_choice_a_token" else 0)
+
+
 def test_the_adapter_takes_the_fused_layouts_apart(built):
     cfg, _, params = built
     spec, weights = rm.adapt_qwen3_next(params, cfg)

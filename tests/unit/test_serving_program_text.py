@@ -57,6 +57,12 @@ LATER = ("granite",)
 #: DeltaNet beside gated attention, and compressed convolutional attention
 #: (heads 128 wide: its decode step and paged pass hold the paged kernels)
 NEWER = ("nemotron_h", "qwen3_next", "zaya")
+#: Qwen3-Next's toy model with 2 of 16 experts held, recorded by PR 53: the
+#: one toy spec whose share gives its programs a bound
+#: (``ragged_model.held_rows_bound``: 32 of the paged pass's 108 choices, 24
+#: of the packed prefill's 96, 8 of the decode step's 12), so the one whose
+#: programs hold the compact path of ``_moe_ffn``
+HELD = ("qwen3_next_held",)
 PROGRAMS = ("serve_decode_step", "serve_paged_pass", "serve_prefill_packed")
 
 
@@ -98,10 +104,11 @@ def tiny(fam, model=None):
                                                      NemotronHForCausalLM)
         cfg = NemotronHConfig.tiny(dtype=f32)
         model, adapt = NemotronHForCausalLM(cfg), rm.adapt_nemotron_h
-    elif fam == "qwen3_next":
+    elif fam in ("qwen3_next", "qwen3_next_held"):
         from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                                      Qwen3NextForCausalLM)
-        cfg = Qwen3NextConfig.tiny(dtype=f32)
+        held = dict(num_experts=16, experts_held=(2, 2)) if fam in HELD else {}
+        cfg = Qwen3NextConfig.tiny(dtype=f32, **held)
         model, adapt = Qwen3NextForCausalLM(cfg), rm.adapt_qwen3_next
     elif fam == "zaya":
         from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
@@ -176,7 +183,7 @@ def golden():
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
-@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER)
+@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER + HELD)
 def test_program_lowers_to_the_recorded_text(fam, program, golden):
     assert golden["jax"] == jax.__version__, (
         "another jax lowers to other text: write the file anew on the commit "
@@ -194,10 +201,11 @@ def test_program_lowers_to_the_recorded_text(fam, program, golden):
 #: latent pages have a builder of their own and the question is not asked
 SIDE_BUFFER = {"llama": False, "mixtral": False, "afmoe": False,
                "jamba": True, "joyai": None, "granite": False,
-               "nemotron_h": False, "qwen3_next": False, "zaya": True}
+               "nemotron_h": False, "qwen3_next": False, "zaya": True,
+               "qwen3_next_held": False}
 
 
-@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER)
+@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER + HELD)
 def test_the_form_each_familys_decode_step_takes(fam, monkeypatch):
     from deepspeed_tpu.inference.v2 import ragged_model as rm
     spec = tiny(fam)[0]
@@ -248,7 +256,7 @@ def _top_level_loops(jaxpr):
     return found
 
 
-@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER)
+@pytest.mark.parametrize("fam", FAMILIES + LATER + NEWER + HELD)
 def test_the_traced_decode_step_holds_no_loop_but_its_layer_scans(fam):
     """One program decodes one token: the step's only loops are the scans
     over its units of layers (``layer_units``), in order, each as long as its
@@ -259,6 +267,28 @@ def test_the_traced_decode_step_holds_no_loop_but_its_layer_scans(fam):
     jaxpr = jax.make_jaxpr(fwd)(weights, kv, *args)
     assert _top_level_loops(jaxpr.jaxpr) == [
         n for _, _, n in rm.layer_units(spec)]
+
+
+@pytest.mark.parametrize("program,choices,bound", [
+    ("serve_paged_pass", 108, 32), ("serve_prefill_packed", 96, 24),
+    ("serve_decode_step", 12, 8)])
+def test_the_held_toy_specs_programs_take_the_compact_path(program, choices,
+                                                           bound):
+    """2 of 16 held: each program has a bound under its choices, returns its
+    count of overflow turns after its three results, and its MoE layers hold
+    the slab loop (a ``while`` inside their layer scans, which the test
+    above does not count); the same programs of the model that holds every
+    expert have three results."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    spec, weights, kv = tiny("qwen3_next_held")
+    assert spec.moe["held"] == (2, 2)
+    assert rm.pass_held_rows_bound(spec, weights, choices // 3) == bound
+    fwd, args = programs(spec)[program]
+    out = jax.eval_shape(fwd, weights, kv, *args)
+    assert len(out) == 4 and out[3].shape == () and out[3].dtype == jnp.int32
+    whole, whole_weights, whole_kv = tiny("qwen3_next")
+    assert len(jax.eval_shape(programs(whole)[program][0], whole_weights,
+                              whole_kv, *args)) == 3
 
 
 if __name__ == "__main__":
@@ -274,7 +304,7 @@ if __name__ == "__main__":
     # only, merged into ``<file>`` under ``later`` (recorded from a later
     # commit than the file's own)
     only = tuple(sys.argv[4:])
-    every = FAMILIES + LATER + NEWER
+    every = FAMILIES + LATER + NEWER + HELD
     fams_ = [n for n in only if n in every] or every
     hashes = {}
     for fam_ in fams_:
@@ -288,7 +318,7 @@ if __name__ == "__main__":
         record.setdefault("later", {}).update({f: commit for f in only})
     else:
         record = {"commit": commit, "jax": jax.__version__,
-                  "later": {f: commit for f in LATER + NEWER},
+                  "later": {f: commit for f in LATER + NEWER + HELD},
                   "programs": hashes}
     with open(out, "w") as f:
         json.dump(record, f, indent=1)
