@@ -293,10 +293,19 @@ class InferenceEngineV2:
         # bucket never touches a live sequence's KV). Outside the allocator
         # on purpose — free/total accounting and the prefix cache never see
         # it, and it can never be handed to a sequence.
-        # pages exist for the layers that attend; a Mamba layer holds a state
-        # slot per sequence instead (ragged/state_pool.py)
+        # pages exist for the layers that attend; a layer that keeps a state
+        # (Mamba, Gated DeltaNet, power retention) holds a slot of the state
+        # pool per sequence instead (ragged/state_pool.py). Where NO layer
+        # attends (brumby) the page pool is its scratch page alone — one
+        # layer of one page, which the programs' padding rows address — the
+        # allocator hands out nothing, the scheduler funds no block a token
+        # (``scheduler.pageless``), and ``kv_cache.num_blocks`` is not read:
+        # what a sequence costs the device is its state slot
         from deepspeed_tpu.inference.v2.ragged_model import (
             latent_width, num_page_layers, num_state_layers)
+        pageless = num_page_layers(self.spec) == 0
+        if pageless:
+            nb = 0
         kv_cfg = KVCacheConfig(
             num_layers=max(1, num_page_layers(self.spec)),
             num_kv_heads=self.spec.num_kv_heads,
@@ -325,6 +334,7 @@ class InferenceEngineV2:
                 cow_fn=self.kv.copy_page)
         self.scheduler = DynamicSplitFuseScheduler(sm, self.kv, self.allocator,
                                                    prefix_cache=self.prefix_cache)
+        self.scheduler.pageless = pageless
         # the recurrent-state pools of a model with state-space layers: one
         # slot per tracked sequence (+ the dump slot), riding with the pages
         # as ONE donated pytree through every program
@@ -339,7 +349,8 @@ class InferenceEngineV2:
                 num_slots=sm.max_tracked_sequences, d_inner=m["d_inner"],
                 d_state=m["d_state"], d_conv=m["d_conv"],
                 # Mamba-2 convolves x, B and C together; a Gated DeltaNet
-                # layer q, k and v (its spec says how many channels)
+                # layer q, k and v (its spec says how many channels); power
+                # retention nothing (d_conv 1: the tail pool is of zero size)
                 conv_dim=m["d_inner"] + 2 * m["n_groups"] * m["d_state"]
                 if ssd else m.get("conv_dim"))
         elif self.spec.cca is not None:
@@ -503,16 +514,24 @@ class InferenceEngineV2:
             _tracer.note("serve/state/bytes_per_sequence",
                          self.state_config.bytes_per_slot())
             # (1, 2: the Mamba recurrence; 3: the gated delta rule; 4: no
-            # recurrence, convolution tails beside attention's pages)
+            # recurrence, convolution tails beside attention's pages; 5:
+            # power retention, a state and its normaliser and no tail)
             if self.spec.mamba is None:
                 kind, recurrence = 4, "convolution tails (no recurrence)"
                 _tracer.note("serve/cca/tail_channels",
                              self.spec.cca["tail_channels"])
             else:
-                kind = {"mamba2": 2, "gdn": 3}.get(
+                kind = {"mamba2": 2, "gdn": 3, "pr": 5}.get(
                     self.spec.mamba.get("kind"), 1)
-                recurrence = "Gated DeltaNet" if kind == 3 \
-                    else f"Mamba-{kind}"
+                recurrence = {3: "Gated DeltaNet", 5: "power retention"}.get(
+                    kind, f"Mamba-{kind}")
+                if kind == 5:
+                    # the lanes of a state (the key's expansion), the power,
+                    # the chunk of the prompt rows' scan
+                    _tracer.note("serve/pr/state_rows",
+                                 self.spec.mamba["d_inner"])
+                    _tracer.note("serve/pr/power", 2)
+                    _tracer.note("serve/pr/chunk", self.spec.mamba["chunk"])
             _tracer.note("serve/state/kind", kind)
         if any(k.block is not None for k in self.spec.layer_kinds or ()):
             # one block a layer: how many layers are each block (what a
@@ -859,7 +878,10 @@ class InferenceEngineV2:
     def sequence_state(self, uid: int) -> np.ndarray:
         """A tracked sequence's recurrent state ``h`` ``[Lm, N, E]``
         (float32; Mamba-2: channel ``h * P + p`` of head ``h`` on the last
-        axis) fetched to the host, for a check that compares it."""
+        axis; power retention: the heads' ``S`` and a normaliser a head down
+        ``N``, the key's expansion along ``E`` —
+        ``ops/pallas/power_retention.py``) fetched to the host, for a check
+        that compares it."""
         slot = self.scheduler.seqs[int(uid)].state_slot
         if slot < 0:
             raise ValueError("this model has no state-space layers")

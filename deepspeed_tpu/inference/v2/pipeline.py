@@ -63,7 +63,9 @@ def rows_held(engine, ctx_lens: np.ndarray, live: np.ndarray) -> dict:
     kernels' device time against the bytes their rows' contexts are
     (docs/OBSERVABILITY.md)."""
     ctx = ctx_lens[live]
-    pages = -(-ctx // engine.kv.config.block_size)
+    # (a model in which no layer holds pages holds its contexts in none)
+    pages = np.zeros_like(ctx) if engine.scheduler.pageless \
+        else -(-ctx // engine.kv.config.block_size)
     ring = engine.scheduler.ring_pages
     if ring is not None:
         pages = np.minimum(pages, ring)

@@ -24,8 +24,10 @@ import numpy as np
 class BlockedAllocator:
 
     def __init__(self, num_blocks: int):
-        if num_blocks < 1:
-            raise ValueError(f"need at least 1 block, got {num_blocks}")
+        # (0: the pool of a model in which no layer holds pages — nothing is
+        # ever asked of it)
+        if num_blocks < 0:
+            raise ValueError(f"a pool of {num_blocks} blocks")
         self._num_blocks = num_blocks
         self._free = deque(range(num_blocks))
         # block id -> refcount, for every block NOT on the free list. Doubles

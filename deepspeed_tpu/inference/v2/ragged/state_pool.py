@@ -25,6 +25,16 @@ pool is :meth:`StatePoolConfig.tails_only`: ``ssm`` of zero size, ``conv`` as
 below, and the layer updates a decode row's tail itself (no recurrence kernel
 follows to do it).
 
+A fourth tenant keeps NO tail: a power-retention layer (brumby;
+``ops/pallas/power_retention.py``) holds, a KV head, the sum of values times
+the key's symmetric square and a normaliser beside it — ``ssm`` with ``N =
+Hk d + 8`` (the heads' value channels, then a normaliser a head) and ``E =
+D`` (the square's ``d (d + 1) / 2`` pairs in ``d / 2 + 1`` tiles of ``d``
+lanes: 1,032 x 8,320 = 32.75 MiB a sequence a layer at 8 heads of 128) — and
+convolves nothing: ``d_conv`` is 1 and ``conv`` is of zero size, the mirror
+of the third tenant. A model of such layers alone holds no pages at all: the
+slots are everything a sequence costs the device.
+
 Pages do not fit that: their lifetime follows tokens, the allocator frees and
 shares them block by block, and a state can be neither shared nor rolled
 back. So there is a second kind of per-sequence device state with a lifetime
@@ -35,7 +45,8 @@ of its own:
   (``max_tracked_sequences``), so taking one cannot fail once admission has
   passed. Sizing rule: a slot costs :meth:`StatePoolConfig.bytes_per_slot`
   whatever the context, so where that is large (36.9 MiB over 9 Mamba-2
-  layers) the slots, not the pages, set how many sequences an engine tracks:
+  layers; 163.8 MiB over 5 power-retention layers) the slots, not the pages,
+  set how many sequences an engine tracks:
   whoever builds the engine takes ``(tracked + 1) x bytes_per_slot`` off the
   memory budget first and gives the pages the rest (docs/SERVING.md);
 - a slot is zeroed when taken, not when freed: the first pass that runs a
@@ -93,7 +104,7 @@ class StatePoolConfig:
     num_slots: int              # NS, the dump slot not counted
     d_inner: int                # E (Mamba-2: heads x head size)
     d_state: int                # N
-    d_conv: int                 # K
+    d_conv: int                 # K (1: no convolution, a tail of zero size)
     # channels the convolution runs over where they are not the E of the
     # state (Mamba-2: x, B and C together); None: E
     conv_dim: Optional[int] = None

@@ -54,6 +54,9 @@ from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
                                                      plan_visits, row_tile)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
+from deepspeed_tpu.ops.pallas.power_retention import (
+    pr_chunk_scan, pr_decode_step, state_cols as pr_state_cols,
+    state_rows as pr_state_rows)
 from deepspeed_tpu.ops.pallas.ssm import (ssd_chunk_scan, ssd_decode_step,
                                           ssm_chunk_scan, ssm_decode_step)
 
@@ -89,7 +92,7 @@ class LayerKind(NamedTuple):
     window: Optional[int]   # sliding-window span in tokens; None = full
     rope: bool              # rotates q/k by position (else no positions)
     moe: bool               # routed experts (else the dense MLP)
-    mamba = False           # an attention layer (else: MambaKind)
+    mamba = False           # an attention layer (else: Mamba-/Delta-/PowerKind)
     block = None            # the pair: mixer, then FFN (else: BlockKind)
     tail = False            # pages alone (else: CcaKind)
 
@@ -138,6 +141,35 @@ class DeltaKind(NamedTuple):
     def describe(self) -> str:
         return ("Gated DeltaNet delta-rule mixer (no pages), "
                 f"{'MoE' if self.moe else 'dense'} FFN")
+
+
+class PowerKind(NamedTuple):
+    """The kind of a layer whose mixer is power retention (brumby;
+    ``ops/pallas/power_retention.py`` states it): linear attention whose
+    state a KV head is the sum of values times the key's symmetric SQUARE,
+    decayed by a gate a token, read through the query's square and divided
+    by a normaliser carried beside it. To the pools it is a Mamba layer — no
+    pages, no window, one slot of the state pool a sequence (``mamba`` is
+    true, and ``RaggedModelSpec.mamba`` holds its widths under ``"kind":
+    "pr"``) — but it ROTATES: q and k are normed and rotated by position
+    before they reach the state, so positions reach this layer as they reach
+    an attention layer; and it keeps no convolution tail. A mixer
+    (:func:`_pr_mixer`) and scopes (``pr/..``) of its own."""
+    moe: bool = False       # routed experts (else the dense MLP)
+    window = None
+    rope = True
+    mamba = True
+    block = None
+    tail = False
+
+    def describe(self) -> str:
+        return ("power-retention mixer (rotary; no pages), "
+                f"{'MoE' if self.moe else 'dense'} FFN")
+
+
+#: ``RaggedModelSpec.mamba["kind"]`` -> the kind of a layer that keeps such a
+#: state (absent: Mamba-1)
+_STATE_KINDS = {"gdn": DeltaKind, "pr": PowerKind}
 
 
 class CcaKind(NamedTuple):
@@ -197,9 +229,10 @@ class BlockKind(NamedTuple):
 
 
 def _holds(kind) -> Optional[str]:
-    """The pool a layer of ``kind`` addresses: ``"state"`` (a Mamba mixer),
-    ``"pages"`` (attention), ``"both"`` (attention that keeps a convolution
-    tail: :class:`CcaKind`) or None (an FFN alone)."""
+    """The pool a layer of ``kind`` addresses: ``"state"`` (a mixer that
+    keeps a state: Mamba, Gated DeltaNet, power retention — whether or not it
+    rotates by position), ``"pages"`` (attention), ``"both"`` (attention that
+    keeps a convolution tail: :class:`CcaKind`) or None (an FFN alone)."""
     if kind.mamba:
         return "state"
     if kind.tail:
@@ -262,7 +295,7 @@ class RaggedModelSpec:
     # and ``weights["layers"]`` is a tuple with one entry per unit the layer
     # loop scans (:func:`layer_units`): a run's stacked tree, or for a unit of
     # several kinds that repeats a tuple of stacked trees, one a kind
-    layer_kinds: Optional[Tuple[Any, ...]] = None   # Layer-/Mamba-/Delta-/BlockKind
+    layer_kinds: Optional[Tuple[Any, ...]] = None   # Layer-/Mamba-/Delta-/Power-/BlockKind
     # on a run's spec (:func:`layer_runs`) of layers that are one block
     # (:class:`BlockKind`): "mixer" (no FFN follows) or "ffn" (no mixer
     # before it). None: the pair every other layer is
@@ -278,7 +311,10 @@ class RaggedModelSpec:
     # E = Hv * P, "n_heads": Hv value heads, "d_head": P, "n_key_heads": Hk,
     # "d_state": N (a key head's width), "d_conv": K, "conv_dim": the
     # convolved channels (q, k and v: 2 Hk N + E), "chunk": the chunked
-    # scan's chunk}
+    # scan's chunk}. Power retention (:class:`PowerKind`), told by "kind":
+    # "pr": {"d_inner": E = D, the lanes of a state (the key's expansion),
+    # "d_state": N, its sublanes (Hk heads' d value channels and a normaliser
+    # a head), "d_conv": 1 (no tail), "chunk", "eps": the normaliser's}
     mamba: Optional[Dict[str, Any]] = None
     # widths of compressed convolutional attention (:class:`CcaKind`; zaya):
     # {"time0", "time1": the taps of the depthwise and of the grouped
@@ -396,7 +432,7 @@ def describe_layer_kinds(spec: RaggedModelSpec) -> str:
         if kinds is not None:
             return kinds[l].describe()
         if rs.mamba is not None:
-            state = DeltaKind if rs.mamba.get("kind") == "gdn" else MambaKind
+            state = _STATE_KINDS.get(rs.mamba.get("kind"), MambaKind)
             return state(rs.moe is not None).describe()
         if rs.cca is not None:
             return CcaKind(rs.moe is not None).describe()
@@ -1306,6 +1342,65 @@ def adapt_zaya(params: Dict, config,
     return spec, weights
 
 
+def adapt_brumby(params: Dict, config,
+                 max_context: Optional[int] = None
+                 ) -> Tuple[RaggedModelSpec, Dict]:
+    """models/brumby.py param tree (BrumbyForCausalLM; Manifest AI Brumby,
+    ``brumby``), published layout. Every layer is of one kind,
+    :class:`PowerKind` over a dense SwiGLU: the model holds NO pages
+    (``num_page_layers`` 0), and a sequence's device state is its slot of the
+    state pool, ``N x D`` float32 a layer (``spec.mamba`` under ``"kind":
+    "pr"``; ``ops/pallas/power_retention.py`` gives the layout).
+
+    The rotation pairs value ``i`` with ``i + d / 2`` where the ragged path's
+    pairs ``2i`` with ``2i + 1``: each q and k head's columns (and their
+    norms' gains) are interleaved, the same way in both, which leaves every
+    ``q . k`` — all the layer reads of them — as it was."""
+    del max_context
+    H, Hk, D = (config.num_attention_heads, config.num_key_value_heads,
+                config.head_dim)
+    spec = RaggedModelSpec(
+        family="brumby",
+        num_layers=config.num_hidden_layers,
+        hidden_size=config.hidden_size,
+        num_heads=H, num_kv_heads=Hk, head_dim=D,
+        vocab_size=config.vocab_size,
+        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
+        tied_lm_head=False, eps=config.rms_norm_eps, dtype=config.dtype,
+        mamba={"kind": "pr", "d_inner": pr_state_cols(D),
+               "d_state": pr_state_rows(Hk, D), "d_conv": 1,
+               "chunk": config.chunk_size, "eps": config.retention_eps})
+    turn = np.arange(D).reshape(2, D // 2).T.reshape(-1)
+    heads = lambda x, n: x.reshape(x.shape[0], n, D)[..., turn].reshape(
+        x.shape)
+
+    def layer(i):
+        lp = params[f"layers_{i}"]
+        attn, ff = lp["self_attn"], lp["mlp"]
+        return {
+            "ln1": {"scale": lp["input_layernorm"]["weight"]},
+            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
+            "pr": {"wq": heads(attn["q_proj"]["kernel"], H),
+                   "wk": heads(attn["k_proj"]["kernel"], Hk),
+                   "wv": attn["v_proj"]["kernel"],
+                   "wg": attn["g_proj"]["kernel"], "g_bias": attn["g_bias"],
+                   "q_norm": attn["q_norm"]["weight"][turn],
+                   "k_norm": attn["k_norm"]["weight"][turn],
+                   "wo": attn["o_proj"]["kernel"]},
+            "mlp": {"w_gate": ff["gate_proj"]["kernel"],
+                    "w_up": ff["up_proj"]["kernel"],
+                    "w_down": ff["down_proj"]["kernel"]},
+        }
+
+    weights = {
+        "embed": params["embed_tokens"]["embedding"],
+        "layers": _stack([layer(i) for i in range(config.num_hidden_layers)]),
+        "final_norm": {"scale": params["norm"]["weight"]},
+        "lm_head": params["lm_head"]["kernel"],
+    }
+    return spec, weights
+
+
 def zaya_channel_order(heads: int, head_dim: int, rotary_dim: int
                        ) -> np.ndarray:
     """For each channel of ``heads`` heads of ``head_dim`` as
@@ -1363,6 +1458,10 @@ ADAPTERS: Dict[str, Callable] = {
     # goes from layer to layer, a choice that skips the experts, learned
     # scales and biases where a branch joins the stream
     "zaya": adapt_zaya,
+    # power retention in every layer (a gated degree-2 linear-attention state
+    # and its normaliser in the state pool: PowerKind, _pr_mixer), q and k
+    # normed and rotated in front of it; no layer holds pages
+    "brumby": adapt_brumby,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —
@@ -2198,7 +2297,8 @@ class _StateRows(NamedTuple):
 #: (:func:`_slots_take`), a smaller one by XLA's gather and scatter: of rows
 #: of 4 MiB XLA's gather first slices the WHOLE pool into column blocks (2.6
 #: GiB of copies a layer at 73 slots; compile, PR 39), of rows of 320 KiB it
-#: does not (cell 7, PR 31) — the rule lies between the two sizes seen
+#: does not (cell 7, PR 31) — the rule lies between the two sizes seen; a
+#: power-retention slot (32.75 MiB) is far on the slices' side of it
 _SLOT_SLICE_BYTES = 1 << 20
 
 
@@ -2235,6 +2335,27 @@ class _ChunkRows(NamedTuple):
     mode: Any = None
     pool_rows: Any = None
     store_rows: Any = None
+
+
+def _chunk_extent(rows: _StateRows, T: int) -> Tuple[int, int]:
+    """``(CT, Cs)``: the prompt rows of a pass of ``T`` rows and the rows of
+    one of its chunk slots."""
+    CT = T - (0 if rows.decode_slot is None else rows.decode_slot.shape[0])
+    return CT, CT // rows.chunk_slot.shape[0]
+
+
+def _chunk_rows(rows: _StateRows, NS1: int, l, T: int) -> _ChunkRows:
+    """Where the chunk slots of a pass of ``T`` rows lie in the state pools
+    of ``NS1`` slots (the dump slot the last) at layer ``l``."""
+    if rows.chunk_slot is None:
+        return _ChunkRows()
+    mode = rows.chunk_mode
+    pool_rows = l * NS1 + rows.chunk_slot
+    # a sequence's last slot of the pass writes back; the others (and empty
+    # slots) write the dump slot
+    last = jnp.concatenate([mode[1:] != 2, jnp.ones((1,), bool)])
+    store_rows = l * NS1 + jnp.where(last, rows.chunk_slot, NS1 - 1)
+    return _ChunkRows(*_chunk_extent(rows, T), mode, pool_rows, store_rows)
 
 
 def _tail_slots(conv):
@@ -2276,7 +2397,6 @@ def _conv_rows(conv, conv2, l, rows: _StateRows, a, K: int, mix, dtype,
     _ChunkRows)``."""
     W = a.shape[-1]
     NS1 = conv.shape[1]
-    dump = NS1 - 1
     # a slot's tile rows are its K - 1 taps x W channels in order (padded to
     # whole tiles a tap where W is not), so the rows GATHERED from it reshape
     # to [n, K - 1, W] (a small copy — reshaping the pool itself so would lay
@@ -2291,17 +2411,10 @@ def _conv_rows(conv, conv2, l, rows: _StateRows, a, K: int, mix, dtype,
             t, ((0, 0), (0, 0), (0, Wp - W))).reshape((-1,) + conv.shape[2:])
     parts, chunk = [], _ChunkRows()
     if rows.chunk_slot is not None:
-        NC = rows.chunk_slot.shape[0]
-        CT = a.shape[0] - (0 if rows.decode_slot is None
-                           else rows.decode_slot.shape[0])
-        Cs = CT // NC
-        mode = rows.chunk_mode
-        a_c = a[:CT].reshape(NC, Cs, W)
-        pool_rows = l * NS1 + rows.chunk_slot
-        # a sequence's last slot of the pass writes back; the others
-        # (and empty slots) write the dump slot
-        last = jnp.concatenate([mode[1:] != 2, jnp.ones((1,), bool)])
-        store_rows = l * NS1 + jnp.where(last, rows.chunk_slot, dump)
+        CT, Cs = _chunk_extent(rows, a.shape[0])
+        a_c = a[:CT].reshape(-1, Cs, W)
+        chunk = _chunk_rows(rows, NS1, l, a.shape[0])
+        mode, pool_rows, store_rows = chunk[2:]
         tail = jnp.where(
             (mode == 2)[:, None, None],
             jnp.roll(a_c[:, Cs - (K - 1):], 1, axis=0),
@@ -2312,7 +2425,6 @@ def _conv_rows(conv, conv2, l, rows: _StateRows, a, K: int, mix, dtype,
             e, (n, 0), (K - 1, W)))(ext, rows.chunk_ntok)
         conv2 = conv2.at[store_rows].set(
             as_taps(new_tail.astype(conv.dtype)))
-        chunk = _ChunkRows(CT, Cs, mode, pool_rows, store_rows)
     if rows.decode_slot is not None:
         # the rows' tails are read here; their shift by one token rides
         # with the recurrence kernel
@@ -2544,6 +2656,74 @@ def _gdn_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows):
     return out, ssm, conv
 
 
+def _pr_mixer(spec: "RaggedModelSpec", w, u, state, l, rows: _StateRows,
+              positions):
+    """The power-retention mixer (brumby; ``ops/pallas/power_retention.py``
+    states both forms) on the normed rows ``u`` ``[T, hid]`` of one layer at
+    ``positions`` ``[T]``, reading and updating its rows' states in the pool
+    ``state[0]`` (``ssm [Lm, NS+1, N, D]`` float32: ``Hk`` heads' ``S`` and a
+    normaliser a head; the tail pool ``state[1]`` is of zero size and passes
+    through) at layer ``l``, by :func:`_mamba_mixer`'s bookkeeping of slots,
+    modes and the dump slot (:func:`_chunk_rows`, :func:`_chunk_states`).
+    Returns ``(out [T, hid], ssm, conv)``.
+
+    q, k and v are three projections of the rows; q and k take an RMSNorm a
+    head and the rotation by position (``pr/qk_norm_rope``), and all three
+    reach the recurrence as values of the model's dtype; the gate is
+    ``log_sigmoid`` of a fourth projection plus a bias, one a KV head, in
+    float32 (``pr/gate``). Prompt rows take the chunked scan (``pr/scan``),
+    decode rows the one-token step (``pr/step``); a row that holds no token
+    has its key and its log-gate zeroed, so it neither decays nor writes."""
+    m, mw = spec.mamba, w["pr"]
+    H, Hk, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
+    dtype, f32 = spec.dtype, jnp.float32
+    ssm, conv = state
+
+    with jax.named_scope("qkv_proj"):
+        # (values of their own behind a barrier: _transformer_layer's note)
+        q, k, v = jax.lax.optimization_barrier((
+            _mm(u, mw["wq"]), _mm(u, mw["wk"]), _mm(u, mw["wv"])))
+    with jax.named_scope("qk_norm_rope"):
+        # (the norm's result HELD at the model's dtype: left to itself the
+        # compiler drops the rounding between the norm and the rotation it
+        # fuses it with — 7e-4 of the first layer's state in the chip's
+        # check, PR 54 — and a program that fuses otherwise would write
+        # other keys into the same state)
+        def normed(x, heads, gain):
+            x = _held(_norm(x.reshape(-1, heads, D), {"scale": gain}, "rms",
+                            spec.eps, dtype), dtype)
+            return _rope_flat(x, positions, spec.rope_theta, None).astype(
+                dtype).reshape(-1, heads * D)
+
+        q, k = normed(q, H, mw["q_norm"]), normed(k, Hk, mw["k_norm"])
+    with jax.named_scope("gate"):
+        lg = jax.nn.log_sigmoid(_held(_mm(u, mw["wg"]), dtype)
+                                + mw["g_bias"].astype(f32))
+
+    chunk = _chunk_rows(rows, ssm.shape[1], l, u.shape[0])
+    CT = chunk.CT
+    ys = []
+    if rows.chunk_slot is not None:
+        with jax.named_scope("scan"):
+            live, flat, h0 = _chunk_states(ssm, rows, chunk)
+            y, hT = pr_chunk_scan(
+                q[:CT], jnp.where(live, k[:CT], 0).astype(dtype), v[:CT],
+                jnp.where(live, lg[:CT], 0.0), h0,
+                (chunk.mode == 2).astype(jnp.int32),
+                chunk=m.get("chunk", 128), eps=m["eps"])
+            ssm = _slots_put(flat, chunk.store_rows, hT).reshape(ssm.shape)
+            ys.append(y)
+    if rows.decode_slot is not None:
+        with jax.named_scope("step"):
+            y, ssm = pr_decode_step(ssm, l, rows.decode_slot, lg[CT:], q[CT:],
+                                    k[CT:], v[CT:], eps=m["eps"])
+            ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    with jax.named_scope("out_proj"):
+        out = _mm(y.astype(dtype), mw["wo"])
+    return out, ssm, conv
+
+
 def _cca_mix(spec: "RaggedModelSpec", cw):
     """``mix`` of :func:`_conv_rows` for compressed convolutional attention:
     of the rows' channels ``[s = q and k of every head | z]`` behind their
@@ -2696,9 +2876,10 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
         # a layer whose mixer is no attention: ``attend(normed rows) ->
         # (mixer output [N, hid], *state)`` runs :func:`_mamba_mixer` with
         # the caller's rows and carried state pools (:func:`_gdn_mixer` for a
-        # Gated DeltaNet layer, under a scope of its own)
-        with jax.named_scope("gdn" if spec.mamba.get("kind") == "gdn"
-                             else "ssm"):
+        # Gated DeltaNet layer, :func:`_pr_mixer` for power retention, each
+        # under a scope of its own)
+        kind = spec.mamba.get("kind")
+        with jax.named_scope(kind if kind in ("gdn", "pr") else "ssm"):
             h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)
             attn_out, *state = attend(h1)
@@ -3088,13 +3269,17 @@ STATE_PASS_KEYS = ("chunk_state_slot", "chunk_state_mode",
 
 def _mamba_body(rs: RaggedModelSpec, positions, rows: _StateRows,
                 experts=None, l0=0):
-    """The scan body of a run of layers that keep a state (Mamba, or Gated
-    DeltaNet: ``rs.mamba["kind"]``), for every serving program:
+    """The scan body of a run of layers that keep a state (Mamba, Gated
+    DeltaNet or power retention: ``rs.mamba["kind"]``), for every serving
+    program:
     the carry is ``(x, *the program's KV carry, (ssm, conv))``; the KV part
     passes through untouched and ``l`` is the layer's rank among the Mamba
     layers (:func:`_pool_bases`), ``l - l0`` its place in the run's expert
     stacks where its FFN routes experts (``MambaKind(moe=True)``)."""
-    mixer = _gdn_mixer if rs.mamba.get("kind") == "gdn" else _mamba_mixer
+    mixer = {"gdn": _gdn_mixer,
+             # the one mixer of a state that rotates: positions reach it
+             "pr": functools.partial(_pr_mixer, positions=positions),
+             }.get(rs.mamba.get("kind"), _mamba_mixer)
 
     def layer_fn(carry, scanned):
         x, *cache, st = carry
@@ -3523,12 +3708,15 @@ def _build_decode_sidebuf(spec: RaggedModelSpec, do_sample: bool,
         # the kernels READ the pool inside the layers; the barrier ties the
         # write's pool operand to their result so XLA orders the in-place
         # write after the reads instead of cloning the (GB-scale) pool
-        kv_pages, kv_sc, _ = jax.lax.optimization_barrier(
-            (kv_pages, kv_sc, logits))
-        with jax.named_scope("kv_flush"):
-            new_kv = paged_kv_row_write(kv_pages, sk_all, sv_all,
-                                        block_tables, prefix, 1,
-                                        kv_scales=kv_sc)
+        if num_page_layers(spec):
+            kv_pages, kv_sc, _ = jax.lax.optimization_barrier(
+                (kv_pages, kv_sc, logits))
+            with jax.named_scope("kv_flush"):
+                new_kv = paged_kv_row_write(kv_pages, sk_all, sv_all,
+                                            block_tables, prefix, 1,
+                                            kv_scales=kv_sc)
+        else:       # no layer wrote a row: the pool is its scratch page
+            new_kv = kv_pages if kv_sc is None else (kv_pages, kv_sc)
         nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
         return (nxt, logits, _state_pack(new_kv, st)) + turns
 

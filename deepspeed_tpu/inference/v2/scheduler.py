@@ -76,6 +76,11 @@ class DynamicSplitFuseScheduler:
         # a model with state-space layers: one per tracked sequence, taken
         # here at admission, freed at flush
         self.state_slots: "Optional[StateSlotAllocator]" = None
+        # set by the engine for a model in which NO layer holds pages (every
+        # layer keeps a state slot instead): no block is funded a token, a
+        # sequence's block table stays empty (every entry the scratch page),
+        # and admission rests on the tracked-sequence count alone
+        self.pageless = False
 
     @property
     def _dump_slot(self) -> int:
@@ -200,6 +205,8 @@ class DynamicSplitFuseScheduler:
                            new_tokens: int) -> int:
         """Fresh allocator blocks required for ``new_tokens`` more tokens —
         under a window, capped by the ring (pages beyond it are reuses)."""
+        if self.pageless:
+            return 0
         bs = self.cache.config.block_size
         need = seq.kv_blocks_needed(new_tokens, bs)
         ring = self.ring_pages
@@ -230,8 +237,10 @@ class DynamicSplitFuseScheduler:
         seq = self.seqs.get(uid, DSSequenceDescriptor(uid=uid))
         bs = self.cache.config.block_size
         avail = self._available_blocks()
-        if self.ring_pages is not None and len(seq.blocks) >= self.ring_pages:
-            # ring complete: any request fits in place (up to max_context)
+        if self.pageless or (self.ring_pages is not None
+                             and len(seq.blocks) >= self.ring_pages):
+            # no pages to fund, or the ring complete: any request fits in
+            # place (up to max_context)
             return max_request_tokens, avail
         slack = len(seq.blocks) * bs - seq.seen_tokens - len(seq.pending)
         fundable = max(0, slack + avail * bs)
@@ -454,6 +463,8 @@ class DynamicSplitFuseScheduler:
     # ------------------------------------------------------------------ #
 
     def _ensure_blocks(self, seq: DSSequenceDescriptor, new_tokens: int) -> None:
+        if self.pageless:
+            return
         bs = self.cache.config.block_size
         ring = self.ring_pages
         if ring is None:
@@ -493,8 +504,9 @@ class DynamicSplitFuseScheduler:
             batch.decode_ctx_lens[row] = pos + 1
             if seq.state_slot >= 0:
                 batch.decode_state_slot[row] = seq.state_slot
-            kv_dest[NC * Cs + row] = self.cache.flat_write_index(
-                seq.blocks[pos // bs], pos % bs)
+            if not self.pageless:
+                kv_dest[NC * Cs + row] = self.cache.flat_write_index(
+                    seq.blocks[pos // bs], pos % bs)
             seq.in_flight_tokens = 1
 
         # prompt chunks, up to NC slots: longest pending first (prefer
@@ -531,7 +543,7 @@ class DynamicSplitFuseScheduler:
             batch.chunk_is_final.append(take == len(seq.pending))
             if seq.seen_tokens > 0:
                 from_zero = False
-            else:
+            elif not self.pageless:
                 # from position 0, tokens fill pages in order: one plan entry
                 # per touched page, rows contiguous from this seq's first row.
                 # Under a window, pages wholly dead by the end of the take are
@@ -564,8 +576,9 @@ class DynamicSplitFuseScheduler:
                     batch.chunk_state_mode[sl] = (2 if taken else
                                                   1 if q0 else 0)
                 batch.row_seg[r0:r0 + n] = len(batch.chunk_uids) - 1
-                kv_dest[r0:r0 + n] = self.cache.flat_write_index(
-                    blocks[positions // bs], positions % bs)
+                if not self.pageless:
+                    kv_dest[r0:r0 + n] = self.cache.flat_write_index(
+                        blocks[positions // bs], positions % bs)
                 batch.slot_uid.append(seq.uid)
                 taken += n
                 sl += 1

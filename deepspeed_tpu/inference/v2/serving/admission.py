@@ -158,6 +158,8 @@ class AdmissionController:
     # ------------------------------------------------------------------ #
 
     def _blocks(self, n_tokens: int) -> int:
+        if self.engine.scheduler.pageless:      # no layer holds pages
+            return 0
         bs = self.engine.kv.config.block_size
         return -(-int(n_tokens) // bs)
 

@@ -411,7 +411,8 @@ class ServingFrontend:
                 f"+ slice reservation ({slice_tokens}) = {need} "
                 f"exceeds max_context {max_context}")
         bs = self.engine.kv.config.block_size
-        if -(-need // bs) > total_blocks:
+        if not self.engine.scheduler.pageless \
+                and -(-need // bs) > total_blocks:
             raise ValueError(
                 f"request needs {-(-need // bs)} KV blocks at its budget but "
                 f"the pool holds {total_blocks}")

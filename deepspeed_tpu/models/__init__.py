@@ -6,11 +6,13 @@ afmoe (Arcee Trinity: layers of several kinds in one model), jamba
 (Nemotron 3 Nano: one block a layer — Mamba-2, experts or attention) and
 qwen3_next (Qwen3-Next: Gated DeltaNet delta-rule layers beside gated
 attention, 512 small experts) and zaya (ZAYA1: compressed convolutional
-attention, a top-1 MLP router with a state) — matching the reference's model coverage (module_inject/containers,
+attention, a top-1 MLP router with a state) and brumby (Brumby: power
+retention in every layer, no attention over cached keys) — matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
 from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
 from deepspeed_tpu.models.bert import BertConfig, BertForMaskedLM
+from deepspeed_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 from deepspeed_tpu.models.decoder import (DecoderConfig, DecoderLM,
                                           init_decoder_cache)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead

@@ -473,6 +473,7 @@ _DECODE_STEPS = {
     "nemotron": (lambda arr: _nemotron_stage(arr), 128, 96, {}),
     "qwen3_next": (lambda arr: _qwen3_next_stage(arr), 64, 264, {}),
     "zaya": (lambda arr: _zaya_stage(arr), 64, 96, {}),
+    "brumby": (lambda arr: _brumby_stage(arr), 32, 272, {}),
 }
 
 
@@ -512,6 +513,9 @@ _DECODE_STEP_CALLS = {
     # XLA's, the tail's shift the layer's own)
     "zaya": {"paged_decode_sidebuf", "paged_kv_row_write",
              "moe_grouped_matmul"},
+    # (no layer holds pages: no paged kernel and no row write after the
+    # layers)
+    "brumby": {"pr_decode_step"},
 }
 
 
@@ -1487,3 +1491,61 @@ def test_zaya_programs_keep_the_pools_and_the_weights_in_place(
     pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes >> 20
+
+
+def _brumby_stage(arr):
+    """Spec, stacked weight tree (shapes only) and pools of Brumby-14B-Base as
+    the benchmark's configuration runs it: published layers 0-4 at published
+    widths with the whole 151,936-row vocabulary (5.975 GiB of weights), the
+    state pool of 36 + 1 slots over the five power-retention layers (1,032 x
+    8,320 float32 a layer: 5.92 GiB) with a tail pool of zero size, and a
+    page pool that is its scratch page."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
+                                                              StatePoolConfig)
+    from deepspeed_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
+    cfg = BrumbyConfig.brumby_14b_base(num_hidden_layers=5, dtype=BF16)
+    model = BrumbyForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_brumby(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    m = spec.mamba
+    pool = StatePoolConfig(num_layers=5, num_slots=36, d_inner=m["d_inner"],
+                           d_state=m["d_state"], d_conv=m["d_conv"])
+    ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
+    kv = StatefulKV(arr(BF16, 1, 1, 2, 8, BS, 128),
+                    arr(F32, *ssm_shape.shape), arr(F32, *conv_shape.shape))
+    return spec, weights, kv
+
+
+def test_brumby_decode_step_keeps_the_states_in_place_and_no_expansion(
+        compiled_step, monkeypatch):
+    """Brumby-14B-Base's first 5 layers at published widths, the 32-row
+    decode step of the benchmark's cell: the 5.92 GiB state pool is the
+    output's buffer (aliased through ``pr_decode_step``, no copy of it), the
+    temporaries stay far under the file's 1 GiB of headroom, and the
+    expansion of a key or a query is no array in HBM — the only arrays that
+    wide are the pool itself and its flat view."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    compiled, spec, kv = compiled_step("brumby")
+    assert rm.num_page_layers(spec) == 0 and rm.num_state_layers(spec) == 5
+    assert kv.ssm.shape == (5, 37, 1032, 8320) and kv.conv.size == 0
+    text = compiled.as_text()
+    assert "pr_decode_step" in text and "paged_kv_row_write" not in text
+    mem = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * jnp.dtype(a.dtype).itemsize for a in kv)
+    assert pools > 5.9 * 2 ** 30 and mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes >> 20
+    wide = set(re.findall(r"\b(?:f32|bf16)\[([\d,]*8320)\]", text))
+    assert wide == {"5,37,1032,8320", "185,1032,8320"}, wide
