@@ -377,16 +377,16 @@ class DeepSpeedTPUEngine:
     # ------------------------------------------------------------------ #
 
     def _build_state(self, model_parameters: Any,
-                     init_params: Optional[Callable[[], Any]] = None):
+                     init_params: Optional[Callable] = None, init_rng=None):
         """:meth:`_init_state` as a stage of set-up (``tracer.stage``): run
         and blocked on, so ``setup/state_build_s`` holds the build and not
         only its dispatch."""
         with _tracer.stage("state_build"):
-            self._init_state(model_parameters, init_params)
+            self._init_state(model_parameters, init_params, init_rng)
             jax.block_until_ready(self.state)
 
     def _init_state(self, model_parameters: Any,
-                    init_params: Optional[Callable[[], Any]] = None):
+                    init_params: Optional[Callable] = None, init_rng=None):
         """Place master/params/opt-state with their ZeRO shardings.
 
         Parity: this replaces ``zero.Init`` + ``_configure_distributed_model``
@@ -395,11 +395,11 @@ class DeepSpeedTPUEngine:
         partitioned layout — no full-model replication transient.
 
         ``init_params`` (the lazy path, ``_ensure_state``): ``model_parameters``
-        is then only the abstract tree, and ``init_params()`` makes the values
-        INSIDE the jitted build, so the initial weights too are born
-        partitioned. Made eagerly first, they sit whole and in fp32 on one
-        device (4 B/param) while the state is built beside them, which is
-        what decides whether a model fits."""
+        is only the abstract tree, and ``init_params(init_rng)`` makes the
+        values INSIDE the jitted build: born partitioned, where made eagerly
+        they sit whole and in fp32 on one device (4 B/param) beside the state
+        being built. The key is the build's ARGUMENT: a constant in its text
+        made every seed a program the persistent cache had never seen."""
         topo = self.topology
         # compression plan over the full param tree (parity: init_compression
         # walking the model, compression/compress.py); applied in _current_params
@@ -441,7 +441,7 @@ class DeepSpeedTPUEngine:
         param_sh = self.partitioner.param_sharding(model_parameters, self._tp_specs)
         if self._offload_cfg is not None:
             if init_params is not None:   # the host optimizer reads values
-                model_parameters = init_params()
+                model_parameters = init_params(init_rng)
             return self._init_state_offload(model_parameters, master_sh, param_sh)
         opt_template = jax.eval_shape(self.optimizer.init,
                                       jax.eval_shape(lambda t: tree_cast(t, jnp.float32),
@@ -490,10 +490,10 @@ class DeepSpeedTPUEngine:
         donate = (0,) if self.config.donate_model_parameters else ()
         with topo.mesh:
             if init_params is not None:
-                def train_state_build_lazy():
-                    return train_state_build(init_params())
-                self.state = jax.jit(train_state_build_lazy,
-                                     out_shardings=shardings)()
+                def train_state_build_lazy(rng):
+                    return train_state_build(init_params(rng))
+                self.state = jax.jit(train_state_build_lazy, in_shardings=repl,
+                                     out_shardings=shardings)(init_rng)
             else:
                 self.state = jax.jit(train_state_build,
                                      out_shardings=shardings,
@@ -1254,10 +1254,11 @@ class DeepSpeedTPUEngine:
         micro = jax.tree_util.tree_map(lambda x: x[:rows], as_host_tree(batch))
         self._rng, init_rng = jax.random.split(self._rng)
 
-        def init_params():
-            return self.module.init(init_rng, micro)["params"]
+        def init_params(rng):
+            return self.module.init(rng, micro)["params"]
 
-        self._build_state(jax.eval_shape(init_params), init_params)
+        self._build_state(jax.eval_shape(init_params, init_rng), init_params,
+                          init_rng)
 
     def _inject_pld(self, batch, leading: int, step: Optional[int] = None,
                     micro: Optional[int] = None):

@@ -232,23 +232,25 @@ _jit = jax.jit
 
 
 class AbstractJit:
-    """``jax.jit`` for the engine's state build: compiles the build for the
-    described devices, then returns the shapes it would produce, carrying
-    its out_shardings. Nothing is allocated (a described device cannot hold
-    an array)."""
+    """``jax.jit`` for the engine's state build: compiles the build AS THE
+    ENGINE JITS IT (its shardings and every other option; the init key its
+    argument, by shape) for the described devices, then returns the shapes
+    it would produce, carrying its out_shardings. Nothing is allocated (a
+    described device cannot hold an array)."""
 
-    def __init__(self, fn, out_shardings=None, **_):
-        self.fn, self.out_shardings = fn, out_shardings
+    def __init__(self, fn, **options):
+        self.fn, self.options = fn, options
 
     def __call__(self, *args):
         t0 = time.time()
-        report("state_build", _jit(
-            self.fn, out_shardings=self.out_shardings).lower(*args).compile(),
-            t0)
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+        report("state_build",
+               _jit(self.fn, **self.options).lower(*shapes).compile(), t0)
         out = jax.eval_shape(self.fn, *args)
         return jax.tree_util.tree_map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-            out, self.out_shardings)
+            out, self.options["out_shardings"])
 
 
 def compile_train_step(devices, layers: int, fsdp: int, prefetch_depth,

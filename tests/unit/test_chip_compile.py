@@ -967,26 +967,26 @@ class _ShapesOnly:
     """``jax.jit`` for the engine's state build: the shapes it would make,
     under its out_shardings (a described device cannot hold an array)."""
 
-    def __init__(self, fn, out_shardings=None, **_):
-        self.fn, self.out_shardings = fn, out_shardings
+    def __init__(self, fn, **options):
+        self.fn, self.options = fn, options
 
     def __call__(self, *args):
         return jax.tree_util.tree_map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-            jax.eval_shape(self.fn, *args), self.out_shardings)
+            jax.eval_shape(self.fn, *args), self.options["out_shardings"])
 
 
-def _train_step(v5e, monkeypatch, layers, fsdp=1, rows=1, remat_policy=None):
-    """The fused step of ``chipbench/configs/mistral7b-train-d2.json``'s
-    engine (``layers`` deep, ``rows`` sequences of 4096 a chip a step, ZeRO-3
-    over ``fsdp`` chips), built and compiled as ``train_batch`` builds it at
-    its first step. Returns (engine, compiled)."""
+def _train_engine(v5e, monkeypatch, layers, fsdp=1, rows=1, remat_policy=None,
+                  jit=_ShapesOnly, seed=0):
+    """``chipbench/configs/mistral7b-train-d2.json``'s engine (``layers``
+    deep, ``rows`` sequences of 4096 a chip a step, ZeRO-3 over ``fsdp``
+    chips) over described chips, its state built from a first batch with
+    ``jit`` in ``jax.jit``'s place. Returns (engine, topology)."""
     import json
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
     import deepspeed_tpu
     from deepspeed_tpu.accelerator import get_accelerator
-    from deepspeed_tpu.comm.mesh import BATCH_AXES, build_topology
+    from deepspeed_tpu.comm.mesh import build_topology
     from deepspeed_tpu.config import MeshConfig
     from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from deepspeed_tpu.ops import attention
@@ -1008,11 +1008,22 @@ def _train_step(v5e, monkeypatch, layers, fsdp=1, rows=1, remat_policy=None):
         num_hidden_layers=layers, dtype=BF16, remat=True,
         remat_policy=remat_policy))
     engine, *_ = deepspeed_tpu.initialize(model=model, config=config,
-                                          mesh_topology=topo)
+                                          mesh_topology=topo,
+                                          rngs=jax.random.PRNGKey(seed))
     batch = {"input_ids": np.zeros((rows * fsdp, 4096), np.int32)}
     with monkeypatch.context() as m:
-        m.setattr(jax, "jit", _ShapesOnly)
+        m.setattr(jax, "jit", jit)
         engine._ensure_state(batch)
+    return engine, topo
+
+
+def _train_step(v5e, monkeypatch, layers, fsdp=1, rows=1, remat_policy=None):
+    """The fused step of that engine, built and compiled as ``train_batch``
+    builds it at its first step. Returns (engine, compiled)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.comm.mesh import BATCH_AXES
+    engine, topo = _train_engine(v5e, monkeypatch, layers, fsdp, rows,
+                                 remat_policy)
     # the engine asks the default backend which options it may pass
     options = engine._compiler_options("tpu")
     monkeypatch.setattr(engine, "_compiler_options",
@@ -1024,6 +1035,43 @@ def _train_step(v5e, monkeypatch, layers, fsdp=1, rows=1, remat_policy=None):
     if engine.remat_plan is None:
         return engine, step.lower(engine.state, staged).compile()
     return engine, engine._compile_fitted(engine.state, staged)
+
+
+class _CompiledBuild(_ShapesOnly):
+    """... after compiling the build for the described chips as the engine
+    jits it (its shardings, its arguments by shape); ``texts`` keeps what the
+    compiler made of each."""
+
+    texts: list = []
+    real = staticmethod(jax.jit)
+
+    def __call__(self, *args):
+        shapes = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+        self.texts.append(self.real(self.fn, **self.options).lower(
+            *shapes).compile().as_text())
+        return super().__call__(*args)
+
+
+@pytest.mark.parametrize("fsdp", [1, 4])
+def test_state_build_takes_the_init_key_as_a_parameter(fsdp, v5e,
+                                                       monkeypatch):
+    """The lazy state build of cells ``mistral7b-train.seq4k`` and
+    ``mistral7b-zero3x4.seq4k`` as the chip's compiler sees it: the init key
+    is the program's one parameter, and the compiled text is the same at two
+    seeds — no ``u32[2]`` constant that a seed could change, so the second
+    run on a machine finds the build in the persistent cache."""
+    monkeypatch.setattr(_CompiledBuild, "texts", [])
+    for seed in (0, 1):
+        _train_engine(v5e, monkeypatch, layers=2, fsdp=fsdp,
+                      jit=_CompiledBuild, seed=seed)
+    first, second = _CompiledBuild.texts
+    assert first == second
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", first,
+                      re.M | re.S).group(1)
+    params = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(\d+\)", entry)
+    assert params == ["u32[2]"], params
+    assert not re.search(r"u32\[2\][^ ]* constant\(", first)
 
 
 def _device_bytes(compiled) -> int:
