@@ -58,6 +58,15 @@ LATENT_PAGES_MSG = ("{what} is not wired for a model with latent attention "
                     "pages\")")
 
 
+#: .. and beside an index-key pool (``spec.mla["index"]``: a learned
+#: selection inside latent attention)
+INDEX_POOL_MSG = ("{what} is not wired for a model that selects inside "
+                  "latent attention: a token's index key lies in a second "
+                  "pool under the same page ids, and would have to move "
+                  "with its latent row (docs/SERVING.md \"A selection over "
+                  "them\")")
+
+
 class AttentionKernelSpec:
     """Kernel dispatch for one model spec on one mesh.
 
@@ -163,6 +172,19 @@ class AttentionKernelSpec:
                 if on:
                     raise NotImplementedError(LATENT_PAGES_MSG.format(
                         what=what))
+            if "index" in spec.mla:
+                refused = {
+                    "prefix_cache.enabled (a copied page would need its "
+                    "index keys copied too)": cfg.prefix_cache.enabled,
+                    "spec_decode.enabled (the verify step's k + 1 rows a "
+                    "sequence would each need a selection of their own; no "
+                    "dense attention stands in)": cfg.spec_decode.enabled,
+                }   # (serving.preemption: offload is the frontend's to
+                #      refuse, export_kv / import_kv the engine's)
+                for what, on in refused.items():
+                    if on:
+                        raise NotImplementedError(INDEX_POOL_MSG.format(
+                            what=what))
         if tp > 1 and (spec.num_heads % tp or spec.num_kv_heads % tp):
             raise ValueError(
                 f"tensor_parallel={tp} does not divide num_heads="

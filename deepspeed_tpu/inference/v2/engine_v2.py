@@ -302,7 +302,9 @@ class InferenceEngineV2:
         # (``scheduler.pageless``), and ``kv_cache.num_blocks`` is not read:
         # what a sequence costs the device is its state slot
         from deepspeed_tpu.inference.v2.ragged_model import (
-            latent_width, num_page_layers, num_state_layers)
+            index_width, latent_width, num_page_layers, num_state_layers)
+        # a learned selection inside latent attention (``adapt_glm_dsa``)
+        self.index = (self.spec.mla or {}).get("index")
         pageless = num_page_layers(self.spec) == 0
         if pageless:
             nb = 0
@@ -314,9 +316,11 @@ class InferenceEngineV2:
             num_blocks=nb + 1,
             dtype=cfg.dtype,
             quantized=cfg.kv_quant.enabled,
-            # latent attention: one row a token a layer, no K/V pair
+            # latent attention: one row a token a layer, no K/V pair; with
+            # an indexer, an index key a token a layer in a pool beside it
             latent_dim=None if self.spec.mla is None
-            else latent_width(self.spec))
+            else latent_width(self.spec),
+            index_dim=index_width(self.spec) if self.index else None)
         self.scratch_block = nb
         with _tracer.stage("kv_alloc"):
             self.kv = BlockedKVCache(kv_cfg, self.topology)
@@ -487,9 +491,28 @@ class InferenceEngineV2:
             # always-on values (tracer.totals; docs/OBSERVABILITY.md): what a
             # token costs the latent pool a layer, and how many of the
             # router's experts this engine holds
+            item = jnp.dtype(kv_cfg.dtype).itemsize
             _tracer.note("serve/latent/bytes_per_token",
-                        kv_cfg.bytes_per_block()
-                        / (kv_cfg.num_layers * kv_cfg.block_size))
+                         kv_cfg.latent_dim * item)
+        if self.index:
+            # what the selection keeps a query, what a token costs the index
+            # pool a layer, and that pool's size
+            _tracer.note("serve/index/topk", self.index["topk"])
+            _tracer.note("serve/index/bytes_per_token",
+                         kv_cfg.index_dim * item)
+            _tracer.note("serve/index/pool_bytes", self.kv.kv[1].nbytes)
+            log_dist(f"engine_v2: a selection over the latent pages: "
+                     f"{self.index['heads']} index heads of "
+                     f"{self.index['head_dim']} keep the top "
+                     f"{self.index['topk']} cached tokens a query; pools "
+                     f"latent {self.kv.kv[0].nbytes / 2**20:.1f} MiB + index "
+                     f"{self.kv.kv[1].nbytes / 2**20:.1f} MiB, "
+                     f"{kv_cfg.latent_dim * item} + {kv_cfg.index_dim * item}"
+                     " B a token a layer; the packed prefill pass "
+                     + ("selects nothing (it cannot hold more than top-k "
+                        "tokens)" if self.packed_prefill else
+                        "is off (it could hold more than top-k tokens of a "
+                        "sequence): prompts take the paged pass"), ranks=[0])
         if self.spec.moe is not None and "held" in self.spec.moe:
             _tracer.note("serve/moe/held_experts", self.spec.moe["held"][1])
             # the sorted rows one turn of the paged pass's MoE layers takes
@@ -1158,10 +1181,11 @@ class InferenceEngineV2:
         # over the scratch page (content round-trips to itself; int8 pools
         # round-trip their packed values+scale-tile payload the same way)
         # (a model with state-space layers moves no pages to the host: what
-        # needs that is refused, see validate_engine_build)
-        with family("page_movers", self.state_config is None
-                    or self.lora is not None):
-            for b in self.page_buckets if self.state_config is None else ():
+        # needs that is refused, see validate_engine_build; nor does one
+        # whose pages have index keys in a second pool)
+        movers = self.state_config is None and not self.index
+        with family("page_movers", movers or self.lora is not None):
+            for b in self.page_buckets if movers else ():
                 pages = self.fetch_pages([self.scratch_block] * b)
                 self.put_pages(pages, [self.scratch_block] * b)
             # the adapter-pool movers over their own rank-sized bucket grid —
@@ -1282,8 +1306,8 @@ class InferenceEngineV2:
             # direct rebind (not .update()) so JL003 sees the donated pool's
             # reference replaced before the next pass reads it
             self.kv.kv = new_kv
-        if self.spec.alibi:
-            return  # ALiBi engines never take the packed prefill fast path
+        if not self.packed_prefill:
+            return  # (ALiBi; a selection over more than the pass holds)
         # prefill fast path: a one-token prompt prefilling into scratch
         b = scratch_batch()
         b.chunk_ntok[0] = 1
@@ -1309,6 +1333,18 @@ class InferenceEngineV2:
                 STATE_PASS_KEYS)
             keys = keys + STATE_PASS_KEYS
         return {k: arrays[k] for k in keys}
+
+    @property
+    def packed_prefill(self) -> bool:
+        """Whether a pass of prompts from position 0 takes the packed
+        program (expanded attention over the pass's own rows, no page read).
+        Not under ALiBi (the packed flash kernel has no position bias), and
+        not where a selection keeps fewer tokens than the pass can hold of
+        one sequence: the packed program selects nothing."""
+        sm = self.config.state_manager
+        return not self.spec.alibi and (
+            not self.index or sm.num_chunk_slots * sm.chunk_slot_size
+            <= self.index["topk"])
 
     def _ensure_prefill_pass(self):
         """Build (once) the packed pure-prefill fast-path program — shared by
@@ -1349,7 +1385,7 @@ class InferenceEngineV2:
         # (build_prefill_forward) — measured 3-4x wave throughput on v5e-1.
         # ALiBi models take the paged chunk path (the packed flash kernel
         # has no per-head position bias; the paged kernels do)
-        if batch.pure_prefill and not self.spec.alibi:
+        if batch.pure_prefill and self.packed_prefill:
             pass_fn = self._ensure_prefill_pass()
             arrays = self._pass_arrays(arrays, PREFILL_PASS_KEYS)
         else:
@@ -1530,6 +1566,12 @@ class InferenceEngineV2:
         # reference replaced before the next pass reads it
         self.kv.kv = scatter(self.kv.kv, payload, jnp.asarray(idx))
 
+    def _refuse_beside_index(self, what: str) -> None:
+        if self.index:
+            from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG
+            raise NotImplementedError(INDEX_POOL_MSG.format(
+                what=what + " (handing a sequence's pages to another engine)"))
+
     def export_kv(self, uid: int) -> Tuple[np.ndarray, np.ndarray]:
         """``(pages, logits)``: the whole logical KV of a fully-prefilled
         sequence fetched to host in one bucketed gather, plus its last
@@ -1542,6 +1584,7 @@ class InferenceEngineV2:
         flush returns this sequence's pages to the LOCAL radix tree — the
         prefill replica stays warm for the next matching prompt."""
         uid = int(uid)
+        self._refuse_beside_index("export_kv")
         if self.state_config is not None:
             from deepspeed_tpu.inference.v2.scheduler import (
                 STATE_SNAPSHOT_MSG)
@@ -1571,6 +1614,7 @@ class InferenceEngineV2:
         The sequence is then in steady decode state: ``decode_pipeline`` can
         admit it directly. Returns the allocated block ids."""
         uid = int(uid)
+        self._refuse_beside_index("import_kv")
         if self.state_config is not None:
             from deepspeed_tpu.inference.v2.scheduler import (
                 STATE_SNAPSHOT_MSG)

@@ -54,6 +54,12 @@ class KVCacheConfig:
     # then [L, NB, bs, latent_dim]: no head axis, no K/V pair, and
     # ``num_kv_heads``/``head_dim`` describe nothing in it
     latent_dim: Optional[int] = None
+    # an index key a token a layer beside the latent row (a learned
+    # selection inside latent attention: ``ragged_model.index_width``): a
+    # SECOND pool [L, NB, bs, index_dim] under the same page ids — one
+    # allocator, one block table — so that the index scan reads pages of
+    # keys and nothing else; the cache is then the pair (latent, index)
+    index_dim: Optional[int] = None
 
     @property
     def max_tokens(self) -> int:
@@ -74,8 +80,8 @@ class KVCacheConfig:
             return self.num_layers * (values + r8 * lanes * 4)
         itemsize = jnp.dtype(self.dtype).itemsize
         if self.latent_dim is not None:
-            return (self.num_layers * self.block_size * self.latent_dim
-                    * itemsize)
+            return (self.num_layers * self.block_size
+                    * (self.latent_dim + (self.index_dim or 0)) * itemsize)
         return (2 * self.num_layers * self.block_size * self.num_kv_heads
                 * self.head_dim * itemsize)
 
@@ -91,17 +97,19 @@ class KVCacheConfig:
     def from_memory_budget(cls, num_layers: int, num_kv_heads: int, head_dim: int,
                            budget_bytes: int, block_size: int = 128,
                            dtype: Any = jnp.bfloat16,
-                           latent_dim: Optional[int] = None
+                           latent_dim: Optional[int] = None,
+                           index_dim: Optional[int] = None
                            ) -> "KVCacheConfig":
         """Size the pool from an HBM budget (parity: the reference sizes its pool
         from free GPU memory after model load, ``engine_v2.py`` memory config).
         With ``latent_dim`` the pages are latent rows and the two head
-        arguments count for nothing."""
+        arguments count for nothing; ``index_dim`` funds an index key a
+        token a layer beside each."""
         probe = cls(num_layers, num_kv_heads, head_dim, block_size, 1, dtype,
-                    latent_dim=latent_dim)
+                    latent_dim=latent_dim, index_dim=index_dim)
         nb = max(1, budget_bytes // probe.bytes_per_block())
         return cls(num_layers, num_kv_heads, head_dim, block_size, int(nb),
-                   dtype, latent_dim=latent_dim)
+                   dtype, latent_dim=latent_dim, index_dim=index_dim)
 
 
 class BlockedKVCache:
@@ -113,7 +121,8 @@ class BlockedKVCache:
     ``config.latent_dim`` (latent attention) it is [L, NB, bs, latent_dim]:
     one row a token a layer, the page axis still at 1, so whatever moves
     whole pages by that axis (``copy_page``, the engine's page gather and
-    scatter) carries it unchanged."""
+    scatter) carries it unchanged. With ``config.index_dim`` too it is the
+    pair (latent pages, index-key pages [L, NB, bs, index_dim])."""
 
     def __init__(self, config: KVCacheConfig, topology: Optional[MeshTopology] = None):
         self.config = config
@@ -145,6 +154,11 @@ class BlockedKVCache:
                        _zeros(sshape, jnp.float32, None))
         else:
             self.kv = _zeros(shape, config.dtype, sharding)
+            if config.index_dim is not None:
+                assert config.latent_dim is not None, \
+                    "index keys ride beside latent pages only"
+                self.kv = (self.kv, _zeros(shape[:-1] + (config.index_dim,),
+                                           config.dtype, sharding))
         self.sharding = sharding
 
     def update(self, kv) -> None:

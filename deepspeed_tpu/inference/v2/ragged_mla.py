@@ -34,6 +34,20 @@ the verify step, whole pages in the packed pass, and in the fused decode
 step through a side buffer ``[L, S, 8, W]`` that the kernel attends beside
 the frozen pages and one row write (scope ``kv_flush``) puts into the pool
 after the layers.
+
+A SELECTION over them (``spec.mla["index"]``; GLM-5's DeepSeek Sparse
+Attention): the cache is then a pair of pools — the latent pages and
+``[L, NB, bs, Di]`` index keys under the same page ids — every program
+writes a token's index key wherever it writes its latent row, and every
+program that reads the pool attends over what the indexer chose
+(``ops/pallas/sparse_mla.py``): a chunk slot under the per-(query, key) mask
+of its scores against its thresholds (scopes ``index/score``,
+``index/select``, ``prefill``), a decode row over its chosen rows gathered
+out of the latent pages (``index/score``, ``index/select``,
+``index/gather``, ``decode``). The packed pass selects nothing: it cannot
+hold more tokens of a sequence than the selection keeps (asserted; an engine
+whose pass could takes the paged pass instead). No program falls back to
+attention over all cached tokens.
 """
 
 from __future__ import annotations
@@ -48,6 +62,7 @@ from deepspeed_tpu.inference.v2.ragged_model import (
     RaggedModelSpec, _embed_in, _greedy_accept, _layer_dest, _norm,
     _pass_rows_live, _router_stream, _sample_logits, _scan_layers,
     _stream_out, _stream_turns, _transformer_layer, _unembed)
+from deepspeed_tpu.ops.pallas import sparse_mla
 from deepspeed_tpu.ops.pallas.mla_attention import mla_row_write
 
 
@@ -81,22 +96,126 @@ def mla_absorb_o(w, o_lat):
         o_lat.shape[0], -1)
 
 
+def _scale(spec: RaggedModelSpec) -> float:
+    return (spec.mla["qk_nope_head_dim"]
+            + spec.mla["qk_rope_head_dim"]) ** -0.5
+
+
+def _kept(topk: int, seen):
+    """How many positions a query that sees ``seen`` keeps (1 for a pad
+    row that sees none: the bisection wants a count)."""
+    return jnp.clip(jnp.minimum(seen, topk), 1)
+
+
+def select_chunk(spec: RaggedModelSpec, q_idx, w_idx, ipages, block_tables,
+                 q0, ctx):
+    """The selection of ``NC`` chunk slots' query tokens: ``(tiled scores,
+    thr [NC, Cs], pcut [NC, Cs])`` (``sparse_mla.index_scores``,
+    ``select``)."""
+    Cs = q_idx.shape[1]
+    with jax.named_scope("index"):
+        with jax.named_scope("score"):
+            scores = sparse_mla.index_scores(q_idx, w_idx, ipages,
+                                             block_tables, q0, ctx)
+        with jax.named_scope("select"):
+            seen = jnp.minimum(ctx[:, None], q0[:, None] + 1 + jnp.arange(
+                Cs, dtype=jnp.int32)[None])
+            thr, pcut = sparse_mla.select(
+                scores, _kept(spec.mla["index"]["topk"], seen), ctx)
+    return scores, thr, pcut
+
+
+def select_decode(spec: RaggedModelSpec, q_idx, w_idx, k_own, ipages, rows,
+                  block_tables, q_pos, ctx):
+    """The selection of ``S`` decode rows and its latent rows: ``(rows
+    [S, topk, W] gathered from ``rows`` [pages * bs, W], how many of them are
+    live [S], whether the row's OWN token is chosen [S])``.
+
+    A row at position ``q_pos`` scores the ``ctx`` tokens its pages hold.
+    Where the pages hold the row's own token too (``ctx == q_pos + 1``: a
+    ragged pass scatters before it attends) ``k_own`` is None; in the fused
+    decode step the pages hold the prefix (``ctx == q_pos``), the own token's
+    key ``k_own`` [S, Di] is scored here and takes part in the selection like
+    any other, and the caller attends its latent row from the side buffer if
+    it was chosen."""
+    ix = spec.mla["index"]
+    topk, bs = ix["topk"], ipages.shape[1]
+    MB = block_tables.shape[1]
+    own = k_own is not None
+    with jax.named_scope("index"):
+        with jax.named_scope("score"):
+            scores = sparse_mla.index_scores(
+                q_idx[:, None], w_idx[:, None], ipages, block_tables, q_pos,
+                ctx)                                         # [S, C, 1, T]
+            C, T = scores.shape[1], scores.shape[3]
+            pos = (jnp.arange(C, dtype=jnp.int32)[:, None, None] * T
+                   + jnp.arange(T, dtype=jnp.int32)[None, None, :])[None]
+            if own:
+                s_own = jnp.einsum("shd,sd->sh", q_idx.astype(jnp.float32),
+                                   k_own.astype(jnp.float32),
+                                   precision="highest")
+                s_own = jnp.sum(jnp.maximum(s_own, 0.0) * w_idx, axis=-1)
+                s_own = jnp.where(s_own == 0.0, 0.0, s_own)
+                scores = jnp.where(pos == q_pos[:, None, None, None],
+                                   s_own[:, None, None, None], scores)
+        with jax.named_scope("select"):
+            # the rows of a step as the query rows of ONE slot: the sweeps
+            # then fill whole registers (a row a sublane, not a row a tile)
+            seen = jnp.minimum(ctx, q_pos + 1) + int(own)
+            thr, pcut = sparse_mla.select(
+                scores.transpose(2, 1, 0, 3), _kept(topk, seen)[None],
+                jnp.max(ctx, keepdims=True) + int(own))
+            thr, pcut = thr[0][:, None], pcut[0][:, None]
+        with jax.named_scope("gather"):
+            keep = sparse_mla.keep_mask(scores, thr, pcut)[:, 0]  # [S, C*T]
+            flat_pos = pos.reshape(1, -1)
+            own_on = jnp.any(keep & (flat_pos == q_pos[:, None]), axis=-1) \
+                if own else jnp.zeros(q_pos.shape, bool)
+            keep = keep & (flat_pos < ctx[:, None])
+            chosen = sparse_mla.chosen_positions(keep, topk)   # [S, topk]
+            live = chosen < C * T
+            # a position's page through a one-hot product, exact in float32
+            # (XLA's gather of 32k single values took 0.27 ms a layer)
+            at = jnp.minimum(chosen // bs, MB - 1)[..., None] == jnp.arange(
+                MB, dtype=jnp.int32)
+            page = jnp.einsum("skb,sb->sk", at.astype(jnp.float32),
+                              block_tables.astype(jnp.float32),
+                              precision="highest").astype(jnp.int32)
+            got = rows[jnp.where(live, page * bs + chosen % bs, 0)]
+    return got, jnp.sum(live, axis=-1, dtype=jnp.int32), \
+        own_on.astype(jnp.int32)
+
+
 def _finish(spec, weights, x):
     return _norm(x, weights["final_norm"], spec.norm, spec.eps, spec.dtype,
                  spec.norm_plus_one)
 
 
+def _pools(spec: RaggedModelSpec, cache) -> tuple:
+    """A program's cache argument as a tuple of pools: the latent pages and,
+    where the model selects, the index keys (the cache is then the pair)."""
+    return tuple(cache) if "index" in spec.mla else (cache,)
+
+
+def _cache(spec: RaggedModelSpec, pools):
+    """The pools as the cache a program returns (:func:`_pools` undone)."""
+    return tuple(pools) if "index" in spec.mla else pools[0]
+
+
 def build_paged_pass(spec: RaggedModelSpec) -> Callable:
     """``build_ragged_forward`` over latent pages: every row's latent is
     scattered into the pool, then chunk slots and decode rows attend
-    absorbed, causal by absolute position."""
+    absorbed, causal by absolute position. With a selection the rows' index
+    keys are scattered too, and every chunk slot and decode row attends over
+    what its indexer chose."""
     H, R = spec.num_heads, spec.mla["kv_lora_rank"]
 
-    def fwd(weights, pool, b):
+    def fwd(weights, cache, b):
+        pools = _pools(spec, cache)
         NC = b["chunk_ntok"].shape[0]
         CT = b["chunk_tokens"].shape[0]
         Cs = CT // NC
-        L, NB, bs, W = pool.shape
+        L, NB, bs, W = pools[0].shape
         tokens = jnp.concatenate([b["chunk_tokens"], b["decode_tokens"]])
         positions = jnp.concatenate([b["chunk_positions"],
                                      b["decode_positions"]])
@@ -108,59 +227,99 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
             ak = AttentionKernelSpec(rs)
 
             def layer_fn(carry, scanned):
-                x, flat = carry
+                x, *flats = carry
                 w, l = scanned
 
-                def attend(q_nope, q_rope, lat):
+                # ``index``: the indexer's (queries, weights, keys), or none
+                def attend(q_nope, q_rope, lat, *index):
                     dest = _layer_dest(b["kv_dest"], l, NB, bs, L)
-                    flat_ = flat.at[dest].set(lat.astype(flat.dtype),
-                                              mode="drop")
-                    pages = flat_.reshape(L * NB, bs, W)
+                    flats_ = tuple(
+                        f.at[dest].set(r.astype(f.dtype), mode="drop")
+                        for f, r in zip(flats, (lat,) + index[2:]))
+                    pages = flats_[0].reshape(L * NB, bs, W)
                     with jax.named_scope("absorb"):
                         q = mla_absorb_q(rs, w, q_nope, q_rope, W)
-                    with jax.named_scope("prefill"):
-                        o_c = ak.latent(
-                            q[:CT].reshape(NC, Cs * H, W), pages,
-                            b["chunk_block_tables"] + l * NB, b["chunk_q0"],
-                            b["chunk_ctx_lens"])
-                    with jax.named_scope("decode"):
-                        o_d = ak.latent(
-                            q[CT:], pages, b["decode_block_tables"] + l * NB,
-                            b["decode_ctx_lens"] - 1, b["decode_ctx_lens"])
+                    if index:
+                        o_c, o_d = selected(q, pages, flats_, *index[:2])
+                    else:
+                        with jax.named_scope("prefill"):
+                            o_c = ak.latent(
+                                q[:CT].reshape(NC, Cs * H, W), pages,
+                                b["chunk_block_tables"] + l * NB,
+                                b["chunk_q0"], b["chunk_ctx_lens"])
+                        with jax.named_scope("decode"):
+                            o_d = ak.latent(
+                                q[CT:], pages,
+                                b["decode_block_tables"] + l * NB,
+                                b["decode_ctx_lens"] - 1,
+                                b["decode_ctx_lens"])
                     with jax.named_scope("absorb"):
                         out = mla_absorb_o(w, jnp.concatenate(
                             [o_c.reshape(CT, H, R), o_d], axis=0))
-                    return out, flat_
+                    return (out,) + flats_
 
-                x, (flat,) = _transformer_layer(rs, w, x, positions, attend,
-                                                experts=experts, l=l - l0)
-                return (x, flat), None
+                def selected(q, pages, flats_, q_idx, w_idx):
+                    kw = dict(v_dim=R, softmax_scale=_scale(rs))
+                    ipages = flats_[1].reshape(L * NB, bs, -1)
+                    bt_c = b["chunk_block_tables"] + l * NB
+                    scores, thr, pcut = select_chunk(
+                        rs, q_idx[:CT].reshape((NC, Cs) + q_idx.shape[1:]),
+                        w_idx[:CT].reshape(NC, Cs, -1), ipages, bt_c,
+                        b["chunk_q0"], b["chunk_ctx_lens"])
+                    with jax.named_scope("prefill"):
+                        o_c = sparse_mla.attend_chunk(
+                            q[:CT].reshape(NC, Cs * H, W), pages, bt_c,
+                            b["chunk_q0"], b["chunk_ctx_lens"], scores, thr,
+                            pcut, heads=H, **kw)
+                    got, live, _ = select_decode(
+                        rs, q_idx[CT:], w_idx[CT:], None, ipages, flats_[0],
+                        b["decode_block_tables"] + l * NB,
+                        b["decode_ctx_lens"] - 1, b["decode_ctx_lens"])
+                    with jax.named_scope("decode"):
+                        o_d = sparse_mla.attend_decode(q[CT:], got, live,
+                                                       **kw)
+                    return o_c, o_d
+
+                x, flats = _transformer_layer(rs, w, x, positions, attend,
+                                              experts=experts, l=l - l0)
+                return (x,) + tuple(flats), None
 
             return layer_fn
 
-        x, flat = _scan_layers(spec, weights["layers"], make_body,
-                               (x, pool.reshape(L * NB * bs, W)))
+        x, *flats = _scan_layers(
+            spec, weights["layers"], make_body,
+            (x,) + tuple(p.reshape(L * NB * bs, p.shape[-1]) for p in pools))
         turns = _stream_turns(x)
         x = _finish(spec, weights, _stream_out(x))
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))
         logits = _unembed(spec, weights,
                           jnp.concatenate([x[last_rows], x[CT:]], axis=0))
-        return (logits[:NC], logits[NC:], flat.reshape(pool.shape)) + turns
+        return (logits[:NC], logits[NC:], _cache(spec, [
+            f.reshape(p.shape) for f, p in zip(flats, pools)])) + turns
 
     return fwd
 
 
 def build_packed_prefill(spec: RaggedModelSpec) -> Callable:
     """``build_prefill_forward`` over latent pages: the expanded form on the
-    packed rows, then whole pages of latent rows written by the page plan."""
+    packed rows, then whole pages of latent rows written by the page plan —
+    and of index keys beside an index pool. Nothing is selected there: the
+    pass's rows start at position 0 and there are no more of them than the
+    selection keeps, so every row's selection is all it sees."""
 
-    def fwd(weights, pool, b):
+    def fwd(weights, cache, b):
+        pools = _pools(spec, cache)
         NC = b["chunk_ntok"].shape[0]
         CT = b["chunk_tokens"].shape[0]
+        assert len(pools) == 1 or CT <= spec.mla["index"]["topk"], (
+            f"a packed pass of {CT} prompt tokens can hold more of one "
+            "sequence than the selection keeps: it selects nothing "
+            "(InferenceEngineV2.packed_prefill sends such an engine's "
+            "prompts through the paged pass)")
         Cs = CT // NC
         S = b["decode_tokens"].shape[0]
-        L, NB, bs, W = pool.shape
+        L, NB, bs, W = pools[0].shape
         positions = b["chunk_positions"]
         x = _router_stream(
             spec, _embed_in(spec, weights, b["chunk_tokens"], positions),
@@ -174,34 +333,40 @@ def build_packed_prefill(spec: RaggedModelSpec) -> Callable:
             ak = AttentionKernelSpec(rs)
 
             def layer_fn(carry, scanned):
-                x, pages = carry
+                x, *pages = carry
                 w, l = scanned
 
-                def attend(q_nope, q_rope, lat):
+                # ``index``: the indexer's (queries, weights, keys), or none
+                def attend(q_nope, q_rope, lat, *index):
                     with jax.named_scope("prefill"):
                         out = mla_expanded(rs, ak, w, q_nope, q_rope, lat,
                                            b["row_seg"])
                     # sentinel pages (id >= NB) go out of range GLOBALLY
                     tgt = jnp.where(b["page_ids"] < NB,
                                     l * NB + b["page_ids"], L * NB)
-                    new = jnp.where(valid, lat[rows], 0).astype(pages.dtype)
-                    return out, pages.at[tgt].set(new, mode="drop")
+                    return (out,) + tuple(
+                        p.at[tgt].set(
+                            jnp.where(valid, r[rows], 0).astype(p.dtype),
+                            mode="drop")
+                        for p, r in zip(pages, (lat,) + index[2:]))
 
-                x, (pages,) = _transformer_layer(rs, w, x, positions, attend,
-                                                 experts=experts, l=l - l0)
-                return (x, pages), None
+                x, pages = _transformer_layer(rs, w, x, positions, attend,
+                                              experts=experts, l=l - l0)
+                return (x,) + tuple(pages), None
 
             return layer_fn
 
-        x, pages = _scan_layers(spec, weights["layers"], make_body,
-                                (x, pool.reshape(L * NB, bs, W)))
+        x, *pages = _scan_layers(
+            spec, weights["layers"], make_body,
+            (x,) + tuple(p.reshape(L * NB, bs, p.shape[-1]) for p in pools))
         turns = _stream_turns(x)
         x = _finish(spec, weights, _stream_out(x))
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))
         logits = _unembed(spec, weights, x[last_rows])
         return (logits, jnp.zeros((S, logits.shape[1]), logits.dtype),
-                pages.reshape(pool.shape)) + turns
+                _cache(spec, [g.reshape(p.shape)
+                              for g, p in zip(pages, pools)])) + turns
 
     return fwd
 
@@ -212,13 +377,19 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
     pages: the pool stays frozen through the layers, each layer's latent row
     goes to a side buffer ``[L, S, 8, W]`` (one sublane tile, row 0 the
     step's) the kernel attends beside the pages, and one row write puts the
-    rows into the pool after the layers."""
+    rows into the pool after the layers. With a selection both pools stay
+    frozen and each has its side buffer and its row write; a row scores its
+    pages' index keys and its own, and attends over the chosen rows gathered
+    from the latent pages (and its own row from the side buffer where it
+    chose it)."""
+    R = spec.mla["kv_lora_rank"]
 
-    def fwd(weights, pool, ids, positions, block_tables, ctx, key,
+    def fwd(weights, cache, ids, positions, block_tables, ctx, key,
             temperature=1.0):
+        pools = _pools(spec, cache)
         S = ids.shape[0]
-        L, NB, bs, W = pool.shape
-        pages = pool.reshape(L * NB, bs, W)
+        L, NB, bs, W = pools[0].shape
+        pages = pools[0].reshape(L * NB, bs, W)
         # ctx counts this step's token; the pages hold the prefix
         prefix = jnp.maximum(ctx - 1, 0)
         x = _router_stream(spec, _embed_in(spec, weights, ids, positions),
@@ -228,40 +399,62 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
             ak = AttentionKernelSpec(rs)
 
             def layer_fn(carry, scanned):
-                x, side = carry
+                x, *sides = carry
                 w, l = scanned
 
-                def attend(q_nope, q_rope, lat):
-                    side_ = jax.lax.dynamic_update_slice(
-                        side, lat[None, :, None].astype(side.dtype),
-                        (l, 0, 0, 0))
+                # ``index``: the indexer's (queries, weights, keys), or none
+                def attend(q_nope, q_rope, lat, *index):
+                    sides_ = tuple(
+                        jax.lax.dynamic_update_slice(
+                            s, r[None, :, None].astype(s.dtype), (l, 0, 0, 0))
+                        for s, r in zip(sides, (lat,) + index[2:]))
                     with jax.named_scope("absorb"):
                         q = mla_absorb_q(rs, w, q_nope, q_rope, W)
-                    with jax.named_scope("decode"):
-                        o_lat = ak.latent(
-                            q, pages, block_tables + l * NB, prefix,
-                            prefix, side=side_, side_j=0, layer_idx=l)
+                    if index:
+                        o_lat = selected(q, lat, *index)
+                    else:
+                        with jax.named_scope("decode"):
+                            o_lat = ak.latent(
+                                q, pages, block_tables + l * NB, prefix,
+                                prefix, side=sides_[0], side_j=0, layer_idx=l)
                     with jax.named_scope("absorb"):
-                        return mla_absorb_o(w, o_lat), side_
+                        return (mla_absorb_o(w, o_lat),) + sides_
 
-                x, (side,) = _transformer_layer(
+                def selected(q, lat, q_idx, w_idx, k_idx):
+                    got, live, own_on = select_decode(
+                        rs, q_idx, w_idx, k_idx.astype(pools[1].dtype),
+                        pools[1].reshape(L * NB, bs, -1),
+                        pages.reshape(L * NB * bs, W), block_tables + l * NB,
+                        prefix, prefix)
+                    with jax.named_scope("decode"):
+                        own = jnp.pad(lat[:, None].astype(pages.dtype),
+                                      ((0, 0), (0, 7), (0, 0)))
+                        return sparse_mla.attend_decode(
+                            q, got, live, v_dim=R, softmax_scale=_scale(rs),
+                            side=own, side_on=own_on)
+
+                x, sides = _transformer_layer(
                     rs, w, x, positions, attend, experts=experts, l=l - l0)
-                return (x, side), None
+                return (x,) + tuple(sides), None
 
             return layer_fn
 
-        x, side = _scan_layers(spec, weights["layers"], make_body,
-                               (x, jnp.zeros((L, S, 8, W), pool.dtype)))
+        x, *sides = _scan_layers(
+            spec, weights["layers"], make_body,
+            (x,) + tuple(jnp.zeros((L, S, 8, p.shape[-1]), p.dtype)
+                         for p in pools))
         turns = _stream_turns(x)
         logits = _unembed(spec, weights,
                           _finish(spec, weights, _stream_out(x)))
-        # the kernels READ the pool inside the layers; the barrier orders
-        # the in-place write after them instead of cloning the pool
-        pool, _ = jax.lax.optimization_barrier((pool, logits))
+        # the kernels READ the pools inside the layers; the barrier orders
+        # the in-place writes after them instead of cloning a pool
+        *pools, _ = jax.lax.optimization_barrier((*pools, logits))
         with jax.named_scope("kv_flush"):
-            new_pool = mla_row_write(pool, side, block_tables, prefix, 1)
+            new = _cache(spec, [
+                mla_row_write(p, s, block_tables, prefix, 1)
+                for p, s in zip(pools, sides)])
         nxt = _sample_logits(logits, key, do_sample, top_k, temperature)
-        return (nxt, logits, new_pool) + turns
+        return (nxt, logits, new) + turns
 
     return fwd
 
@@ -271,6 +464,11 @@ def build_verify(spec: RaggedModelSpec, k: int) -> Callable:
     sequence are scattered into the pool, then attended absorbed, one slot a
     sequence, causal by absolute position — the visible set of every row is
     what the decode step sees one token at a time."""
+    if "index" in spec.mla:
+        from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG
+        raise NotImplementedError(INDEX_POOL_MSG.format(
+            what="the speculative verify step (its k + 1 rows a sequence "
+            "would each need a selection of their own)"))
     H, R = spec.num_heads, spec.mla["kv_lora_rank"]
     K1 = k + 1
 

@@ -282,8 +282,11 @@ class RaggedModelSpec:
     # multi-head latent attention: {"q_lora_rank", "kv_lora_rank",
     # "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"}. The pages then
     # hold one latent row a token a layer (no head axis, no K/V pair:
-    # ragged/kv_cache.py) and the programs are ragged_mla.py's
-    mla: Optional[Dict[str, int]] = None
+    # ragged/kv_cache.py) and the programs are ragged_mla.py's. With "index":
+    # {"heads", "head_dim", "topk", "rope_dim", "eps"} every layer selects
+    # the topk cached tokens a query attends to (``adapt_glm_dsa``) and a
+    # second pool holds one index key a token a layer
+    mla: Optional[Dict[str, Any]] = None
     # mistral/qwen2 sliding-window span (tokens); None = full attention.
     # Reference parity: inference/v2/model_implementations/mistral.
     window: Optional[int] = None
@@ -854,8 +857,9 @@ def adapt_jamba(params: Dict, config,
     return spec, weights
 
 
-def adapt_joyai(params: Dict, config,
-                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
+def adapt_joyai(params: Dict, config, max_context: Optional[int] = None,
+                family: str = "joyai", index: Optional[Dict[str, int]] = None
+                ) -> Tuple[RaggedModelSpec, Dict]:
     """models/joyai.py param tree (JoyaiForCausalLM; JoyAI-LLM-Flash).
 
     Latent attention (``spec.mla``): ``kv_b_proj`` is stored split by what
@@ -875,7 +879,7 @@ def adapt_joyai(params: Dict, config,
                      and int(k[len("layers_"):]) >= config.num_hidden_layers)
     if skipped:
         from deepspeed_tpu.utils.logging import log_dist
-        log_dist(f"adapt_joyai: {skipped} (the multi-token-prediction "
+        log_dist(f"adapt_{family}: {skipped} (the multi-token-prediction "
                  "module) not loaded", ranks=[0])
     kinds = tuple(LayerKind(None, True, config.is_moe_layer(i))
                   for i in range(config.num_hidden_layers))
@@ -886,17 +890,18 @@ def adapt_joyai(params: Dict, config,
            "route_scale": config.routed_scaling_factor}
     if count != config.n_routed_experts:
         moe["held"] = (first, count)
+    mla = {"q_lora_rank": config.q_lora_rank, "kv_lora_rank": R,
+           "qk_nope_head_dim": dn, "qk_rope_head_dim": dr, "v_head_dim": dv}
+    if index is not None:
+        mla["index"] = index
     spec = RaggedModelSpec(
-        family="joyai",
+        family=family,
         num_layers=config.num_hidden_layers,
         hidden_size=config.hidden_size,
         num_heads=H, num_kv_heads=H, head_dim=dv,
         vocab_size=config.vocab_size,
         norm="rms", activation="swiglu", rope_theta=config.rope_theta,
-        eps=config.rms_norm_eps, moe=moe, layer_kinds=kinds,
-        mla={"q_lora_rank": config.q_lora_rank, "kv_lora_rank": R,
-             "qk_nope_head_dim": dn, "qk_rope_head_dim": dr,
-             "v_head_dim": dv},
+        eps=config.rms_norm_eps, moe=moe, layer_kinds=kinds, mla=mla,
         dtype=config.dtype)
     if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
         spec = layer_runs(spec)[0][0]
@@ -922,6 +927,13 @@ def adapt_joyai(params: Dict, config,
             "w_uk": kvb[..., :dn], "w_uv": kvb[..., dn:],
             "wo": attn["o_proj"]["kernel"],
         }
+        if index is not None:
+            ix = attn["indexer"]
+            out["index"] = {"wq": ix["wq_b"]["kernel"],
+                            "wk": ix["wk"]["kernel"],
+                            "k_norm": ix["k_norm"]["scale"],
+                            "k_bias": ix["k_norm"]["bias"],
+                            "ww": ix["weights_proj"]["kernel"]}
         mlp = lp["mlp"]
         if config.is_moe_layer(i):
             out["moe"] = {"router": mlp["gate"]["kernel"],
@@ -942,6 +954,24 @@ def adapt_joyai(params: Dict, config,
         "lm_head": params["lm_head"]["kernel"],
     }
     return spec, weights
+
+
+def adapt_glm_dsa(params: Dict, config, max_context: Optional[int] = None
+                  ) -> Tuple[RaggedModelSpec, Dict]:
+    """models/glm_dsa.py param tree (GlmDsaForCausalLM; GLM-5,
+    ``glm_moe_dsa``): :func:`adapt_joyai`'s latent attention, router and held
+    experts, and in every layer an indexer — ``spec.mla["index"]``: ``heads``
+    of ``head_dim`` whose first ``rope_dim`` values are rotated, keeping the
+    ``topk`` best cached tokens a query; ``eps`` of the index key's LayerNorm
+    — whose weights ride in the layer as ``w["index"]``: ``wq`` (from the
+    normed query latent), ``wk``, ``k_norm``/``k_bias`` and ``ww`` (from the
+    layer's normed input). The pool gains one index key a token a layer
+    (``ragged/kv_cache.py``) and the programs are ragged_mla.py's with a
+    selection (``ops/pallas/sparse_mla.py``)."""
+    return adapt_joyai(params, config, max_context, family="glm_dsa", index={
+        "heads": config.index_n_heads, "head_dim": config.index_head_dim,
+        "topk": config.index_topk, "rope_dim": config.qk_rope_head_dim,
+        "eps": config.index_norm_eps})
 
 
 def adapt_granite(params: Dict, config,
@@ -1440,6 +1470,9 @@ ADAPTERS: Dict[str, Callable] = {
     # (ragged_mla.py); a sigmoid router over experts of which this chip may
     # hold a share
     "joyai": adapt_joyai,
+    # the same with a learned selection: an indexer a layer, an index-key
+    # pool beside the latent pages, attention over the top-k chosen
+    "glm_dsa": adapt_glm_dsa,
     # Mamba-2 (SSD) layers — a matrix state per head in the same pool —
     # beside a few no-position GQA layers, every FFN routed experts (of which
     # this chip may hold a share) plus a shared MLP; four plain multipliers
@@ -2842,7 +2875,45 @@ def _mla_project(spec: "RaggedModelSpec", w, h1, positions):
             [ckv, k_rope,
              jnp.zeros((ckv.shape[0], latent_width(spec) - R - dr), dtype)],
             axis=-1)
+    if "index" in m:
+        return (q[..., :dn], q_rope, lat) + _index_project(
+            spec, w["index"], h1, cq, positions)
     return q[..., :dn], q_rope, lat
+
+
+def index_width(spec: "RaggedModelSpec") -> int:
+    """Values of one index key in its pool (``spec.mla["index"]``): the
+    indexer's head width in whole lane tiles."""
+    return -(-spec.mla["index"]["head_dim"] // 128) * 128
+
+
+def _index_project(spec: "RaggedModelSpec", wi, h1, cq, positions):
+    """The indexer's projections (scope ``index/project``): ``(index
+    queries [N, Hi, Di'] from the normed query latent, the heads' signed
+    weights [N, Hi] in float32, the rows' index keys [N, Di'])``. A key is
+    what the index pool holds of a token: ``h1 W_k`` after its LayerNorm,
+    its first ``rope_dim`` values rotated (so are the queries'), zeros up to
+    the pool's width ``Di'``."""
+    ix = spec.mla["index"]
+    Hi, Di, dr = ix["heads"], ix["head_dim"], ix["rope_dim"]
+    dtype = spec.dtype
+
+    def rotated(x):
+        return jnp.concatenate(
+            [_rope_flat(x[..., :dr], positions, spec.rope_theta, None),
+             x[..., dr:],
+             jnp.zeros(x.shape[:-1] + (index_width(spec) - Di,), x.dtype)],
+            axis=-1).astype(dtype)
+
+    with jax.named_scope("index"), jax.named_scope("project"):
+        q = rotated(jax.lax.optimization_barrier(
+            _mm(cq, wi["wq"])).reshape(-1, Hi, Di))
+        k = rotated(_norm(_mm(h1, wi["wk"]), {"scale": wi["k_norm"],
+                                              "bias": wi["k_bias"]},
+                          "layer", ix["eps"], dtype)[:, None])[:, 0]
+        wts = jnp.dot(h1, wi["ww"], preferred_element_type=jnp.float32) \
+            * (Hi ** -0.5 * Di ** -0.5)
+    return q, wts, k
 
 
 def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
@@ -2887,7 +2958,8 @@ def _transformer_layer(spec: "RaggedModelSpec", w, x, positions, attend,
         # latent attention: ``attend(q_nope [N, H, nope], q_rope [N, H,
         # rope], latent rows [N, W]) -> (attention output [N, H * v],
         # *state)`` writes the rows into the latent pages and attends in the
-        # form its program uses (expanded or absorbed: ragged_mla.py)
+        # form its program uses (expanded or absorbed: ragged_mla.py); with
+        # an indexer it is handed the index queries, weights and keys too
         with jax.named_scope("attn"), jax.named_scope("mla"):
             h1 = _norm(x, w["ln1"], spec.norm, spec.eps, dtype,
                        spec.norm_plus_one)

@@ -264,6 +264,11 @@ class ServingFrontend:
             raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
                 what="preemption='offload' (pages go to the host, the state "
                 "would not; run 'recompute' or 'none')"))
+        if cfg.preemption == "offload" \
+                and engine.kv.config.index_dim is not None:
+            from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG
+            raise NotImplementedError(INDEX_POOL_MSG.format(
+                what="preemption='offload' (run 'recompute' or 'none')"))
         if cfg.preemption == "recompute" and getattr(engine, "lora", None) \
                 is not None:
             raise NotImplementedError(
@@ -747,7 +752,7 @@ class ServingFrontend:
         live = batch.chunk_ntok > 0
         ntok = batch.chunk_ntok[live]
         # (the engine's own rule for which program a pass is: _run_pass)
-        packed = batch.pure_prefill and not self.engine.spec.alibi
+        packed = batch.pure_prefill and self.engine.packed_prefill
         cached = (np.zeros_like(ntok) if packed
                   else batch.chunk_ctx_lens[live] - ntok)
         self._mark("serve/prefill/pass", slots=len(batch.slot_uid),

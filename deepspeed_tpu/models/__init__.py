@@ -7,7 +7,8 @@ afmoe (Arcee Trinity: layers of several kinds in one model), jamba
 qwen3_next (Qwen3-Next: Gated DeltaNet delta-rule layers beside gated
 attention, 512 small experts) and zaya (ZAYA1: compressed convolutional
 attention, a top-1 MLP router with a state) and brumby (Brumby: power
-retention in every layer, no attention over cached keys) — matching the reference's model coverage (module_inject/containers,
+retention in every layer, no attention over cached keys) and glm_dsa (GLM-5:
+latent attention over a learned top-k selection, an indexer a layer) — matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
 from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
@@ -16,6 +17,7 @@ from deepspeed_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 from deepspeed_tpu.models.decoder import (DecoderConfig, DecoderLM,
                                           init_decoder_cache)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+from deepspeed_tpu.models.glm_dsa import GlmDsaConfig, GlmDsaForCausalLM
 from deepspeed_tpu.models.granite import GraniteConfig, GraniteForCausalLM
 from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
 from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM

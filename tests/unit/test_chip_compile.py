@@ -36,6 +36,7 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     kv_scale_tiles_shape, paged_chunk_attention_batched,
     paged_decode_attention, paged_decode_attention_sidebuf,
     paged_decode_attention_step)
+from deepspeed_tpu.ops.pallas import sparse_mla
 from deepspeed_tpu.ops.pallas.gdn import gdn_chunk_scan, gdn_decode_step
 from deepspeed_tpu.ops.pallas.ssm import ssd_chunk_scan, ssd_decode_step
 from deepspeed_tpu.ops.pallas.paged_splitk import (
@@ -98,6 +99,10 @@ _LAT = dict(heads=32, v_dim=512, softmax_scale=192 ** -0.5)
 _LAT_POOL = ((40 * 64, BS, 640), BF16)
 _LAT_Q = ((32, 32, 640), BF16)
 _LAT_BT, _LAT_CL = ((32, 80), I32), ((32,), I32)
+
+
+_DSA = dict(v_dim=512, softmax_scale=256 ** -0.5)
+_DSA_IPOOL = ((5 * 6437, BS, 128), BF16)
 
 
 CASES = {
@@ -238,6 +243,34 @@ CASES = {
         paged_chunk_attention_batched,
         [((8, 256, 16, 256), BF16), _pool(2, 256), ((8, 272), I32),
          ((8,), I32), ((8,), I32)]),
+    # the selection inside latent attention at GLM-5's widths (32 index heads
+    # of 128, 64 query heads over rows of 640, the 2,048 best kept), over the
+    # benchmark's pools (5 layers x 6,437 pages) and 272-page tables: a
+    # decode row's index scores over its pages (a loop of 2,048-key tiles) ..
+    "dsa_index_decode": (sparse_mla.index_scores, [
+        ((16, 1, 32, 128), BF16), ((16, 1, 32), F32), _DSA_IPOOL,
+        ((16, 272), I32), ((16,), I32), ((16,), I32)]),
+    # .. a chunk slot's 256 queries (a grid of 512-key tiles, a product a head)
+    "dsa_index_chunk": (sparse_mla.index_scores, [
+        ((8, 256, 32, 128), BF16), ((8, 256, 32), F32), _DSA_IPOOL,
+        ((8, 272), I32), ((8,), I32), ((8,), I32)]),
+    # .. the exact k-th largest of 34,816 scores a row, 16 rows a step
+    "dsa_select": (sparse_mla.select, [
+        ((8, 68, 256, 512), F32), ((8, 256), I32), ((8,), I32)]),
+    "dsa_select_decode": (sparse_mla.select, [
+        ((16, 17, 1, 2048), F32), ((16, 1), I32), ((16,), I32)]),
+    # .. a decode row over its 2,048 gathered rows and its own from the side
+    "dsa_attend_decode": (
+        lambda q, r, n, s, o: sparse_mla.attend_decode(
+            q, r, n, side=s, side_on=o, **_DSA),
+        [((16, 64, 640), BF16), ((16, 2048, 640), BF16), ((16,), I32),
+         ((16, 8, 640), BF16), ((16,), I32)]),
+    # .. a chunk slot's 16,384 query rows over its pages under the mask
+    "dsa_attend_chunk": (
+        lambda *a: sparse_mla.attend_chunk(*a, heads=64, **_DSA),
+        [((8, 256 * 64, 640), BF16), ((5 * 6437, BS, 640), BF16),
+         ((8, 272), I32), ((8,), I32), ((8,), I32),
+         ((8, 68, 256, 512), F32), ((8, 256), F32), ((8, 256), I32)]),
     # int8 pages under a chunk: a scale tile a page beside its copy
     "chunk_int8": (
         lambda q, kv, bt, q0, cl, sc: paged_chunk_attention_batched(
@@ -1597,3 +1630,75 @@ def test_brumby_decode_step_keeps_the_states_in_place_and_no_expansion(
     assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes >> 20
     wide = set(re.findall(r"\b(?:f32|bf16)\[([\d,]*8320)\]", text))
     assert wide == {"5,37,1032,8320", "185,1032,8320"}, wide
+
+
+def _glm5_stage(arr, pages=6436):
+    """Spec, stacked weight trees (shapes only) and the two pools of GLM-5 as
+    the benchmark's configuration runs it: 5 layers (one dense, four MoE with
+    experts 0-15 of 256 held) at published widths, an eighth of the
+    vocabulary (7.28 GiB of weights), ``pages`` pages of latent rows and of
+    index keys (5.89 GiB)."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.models.glm_dsa import GlmDsaConfig, GlmDsaForCausalLM
+    cfg = GlmDsaConfig.glm_5(dtype=BF16, experts_held=(0, 16),
+                             num_hidden_layers=5, first_k_dense_replace=1,
+                             vocab_size=19360)
+    model = GlmDsaForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = rm.adapt_glm_dsa(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    return spec, weights, (arr(BF16, 5, pages + 1, BS, 640),
+                           arr(BF16, 5, pages + 1, BS, 128))
+
+
+def test_glm5_decode_step_reads_the_index_pool_and_gathers_its_selection(
+        v5e, monkeypatch):
+    """GLM-5's 16-row decode step as the benchmark's cell runs it (contexts
+    to 34,816: 272-page tables): the selection's three kernels and two row
+    writes are in it and the dense latent kernel is not — no program attends
+    over all cached tokens of a row; both pools are the output's buffers and
+    no instruction copies either; what attention reads of the latent pool is
+    a gather of 2,048 rows a sequence; and the temporaries stay far under
+    the file's 1 GiB of headroom."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _glm5_stage(arr)
+    assert [n for _, _, n in rm.layer_runs(spec)] == [1, 4]
+    weight_bytes = sum(math.prod(a.shape) * 2
+                       for a in jax.tree_util.tree_leaves(weights))
+    assert abs(weight_bytes / 2 ** 30 - 7.28) < 0.01
+    compiled = _compile_decode_step(spec, weights, kv, 16, 272)
+    text = compiled.as_text()
+    mosaic = {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+    # (experts of 6,144 x 2,048 take XLA's ragged product, not the Pallas
+    # grouped one: ``ragged_model.moe_grouped_kernel``'s rule)
+    assert mosaic == {"dsa_index_decode", "dsa_select", "dsa_attend_decode",
+                      "mla_row_write"}, mosaic
+    pools = sum(math.prod(a.shape) * 2 for a in kv)
+    mem = compiled.memory_analysis()
+    assert pools > 5.88 * 2 ** 30 and mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes >> 20
+    instructions, _ = _executed(text)
+    shapes = {a.shape for a in kv} | {
+        (5 * 6437,) + a.shape[2:] for a in kv} | {
+        (5 * 6437 * 128, a.shape[3]) for a in kv}
+    moved = [line.strip()[:120] for _, dims, op, line in instructions
+             if dims in shapes and op not in _FREE + (
+                 "fusion", "custom-call", "while", "tuple", "gather")]
+    assert not moved, f"a pool is copied or laid out anew: {moved}"
+    # (the gather sits inside a fusion: read the whole text for it)
+    gathers = set(re.findall(r"= bf16\[([\d,]+,640)\]\S* gather\(", text))
+    assert gathers == {"16,2048,640"}, gathers
