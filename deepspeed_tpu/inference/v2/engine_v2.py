@@ -1389,8 +1389,8 @@ class InferenceEngineV2:
             pass_fn = self._ensure_prefill_pass()
             arrays = self._pass_arrays(arrays, PREFILL_PASS_KEYS)
         else:
-            # rung-keyed paged pass: the decode rows ride this step's
-            # split rung (rung 1 is self._pass — byte-identical)
+            # the paged pass of this step's split rung (rung 1: self._pass)
+            _count_selecting_pass(self.index, batch)
             pass_fn = self._pass_rungs.get(self._attn_rung(), self._pass)
             arrays = self._pass_arrays(arrays, PAGED_PASS_KEYS)
         chunk_logits, decode_logits, new_kv = pass_fn(
@@ -1850,3 +1850,17 @@ def _guess_family(model) -> str:
             return fam
     raise ValueError(f"cannot infer model family from {type(model).__name__}; "
                      f"pass family=")
+
+
+def _count_selecting_pass(index, batch) -> None:
+    """The paged passes of a model that selects inside latent attention
+    (``index``: its ``spec.mla["index"]``), always on in ``tracer.totals``:
+    ``serve/mla/paged_passes``, and of them ``serve/mla/expanded_passes`` —
+    those whose chunk slots hold ONE sequence in two or more slots, whose
+    rows the program attends expanded. The program decides from the arrays
+    it is handed (``ragged_mla.one_sequence``); this is the scheduler's side
+    of the same rule (a sequence's slots are consecutive, over its table)."""
+    if index:
+        _tracer.bump("serve/mla/paged_passes")
+        _tracer.bump("serve/mla/expanded_passes", float(
+            len(batch.chunk_uids) == 1 and (batch.chunk_ntok > 0).sum() >= 2))

@@ -22,12 +22,20 @@ Attention is one function in two forms that agree through that pool:
   ``W_UV`` onto the output, and the kernel
   (``ops/pallas/mla_attention.py``) reads latent pages as they lie, each
   once, as keys and as values: ``2 (W + kv_lora_rank)`` operations per
-  (query, key, head) but ``W`` values read per key for all heads. Every
-  program that reads the pool uses it: decode rows (ragged pass, fused
-  decode step), the verify step, and a paged chunk's rows — for
-  the chunk's EARLIER context and, after its rows are written, for its own
-  rows too (docs/SERVING.md "Latent pages" has the count against expanding
-  the cached latents).
+  (query, key, head) but ``W`` values read per key for all heads. What
+  reads the pool uses it: decode rows (ragged pass, fused decode step), the
+  verify step, and a paged chunk's rows — for the chunk's EARLIER context
+  and, after its rows are written, for its own rows too.
+- EXPANDED AGAIN, where it pays: expanding a cached token costs ``2 H R
+  (nope + v)`` a layer whoever reads it, and attending it costs a query
+  token ``2 H (nope + rope + v)`` expanded against ``2 H (W + R)`` absorbed,
+  so ``n`` query tokens of ONE sequence in a pass break even at ``n = R
+  (nope + v) / (W + R - nope - rope - v)`` — 359 at GLM-5's widths: one
+  slot of 256 loses, two win, a pass of 8 that is one prompt's does 1.85
+  times fewer operations (docs/SERVING.md "Latent pages"). A selecting
+  model's paged pass takes it for its chunk rows when they are one
+  sequence's (:func:`one_sequence`, read from the slots; a ``lax.cond`` a
+  layer around :func:`_chunk_expanded`), the expansion inside the kernel.
 
 New rows reach the pool as K/V rows do: a flat scatter in the ragged pass and
 the verify step, whole pages in the packed pass, and in the fused decode
@@ -42,12 +50,14 @@ writes a token's index key wherever it writes its latent row, and every
 program that reads the pool attends over what the indexer chose
 (``ops/pallas/sparse_mla.py``): a chunk slot under the per-(query, key) mask
 of its scores against its thresholds (scopes ``index/score``,
-``index/select``, ``prefill``), a decode row over its chosen rows gathered
+``index/select``, ``prefill``: absorbed, ``dsa_attend_chunk``, or, the pass
+one sequence's, expanded, ``dsa_attend_expanded``), a decode row over its
+chosen rows gathered
 out of the latent pages (``index/score``, ``index/select``,
 ``index/gather``, ``decode``). The packed pass selects nothing: it cannot
 hold more tokens of a sequence than the selection keeps (asserted; an engine
-whose pass could takes the paged pass instead). No program falls back to
-attention over all cached tokens.
+whose pass could takes the paged pass instead). No program, absorbed or
+expanded, falls back to attention over all cached tokens.
 """
 
 from __future__ import annotations
@@ -222,6 +232,8 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
         x = _router_stream(
             spec, _embed_in(spec, weights, tokens, positions), weights,
             lambda: _pass_rows_live(b, Cs, True))
+        one_seq = len(pools) > 1 and one_sequence(
+            b["chunk_ntok"], b["chunk_q0"], b["chunk_block_tables"], Cs)
 
         def make_body(rs, experts, l0):
             ak = AttentionKernelSpec(rs)
@@ -237,48 +249,70 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
                         f.at[dest].set(r.astype(f.dtype), mode="drop")
                         for f, r in zip(flats, (lat,) + index[2:]))
                     pages = flats_[0].reshape(L * NB, bs, W)
+                    if index:
+                        return (selected(q_nope, q_rope, pages, flats_,
+                                         *index[:2]),) + flats_
                     with jax.named_scope("absorb"):
                         q = mla_absorb_q(rs, w, q_nope, q_rope, W)
-                    if index:
-                        o_c, o_d = selected(q, pages, flats_, *index[:2])
-                    else:
-                        with jax.named_scope("prefill"):
-                            o_c = ak.latent(
-                                q[:CT].reshape(NC, Cs * H, W), pages,
-                                b["chunk_block_tables"] + l * NB,
-                                b["chunk_q0"], b["chunk_ctx_lens"])
-                        with jax.named_scope("decode"):
-                            o_d = ak.latent(
-                                q[CT:], pages,
-                                b["decode_block_tables"] + l * NB,
-                                b["decode_ctx_lens"] - 1,
-                                b["decode_ctx_lens"])
+                    with jax.named_scope("prefill"):
+                        o_c = ak.latent(
+                            q[:CT].reshape(NC, Cs * H, W), pages,
+                            b["chunk_block_tables"] + l * NB,
+                            b["chunk_q0"], b["chunk_ctx_lens"])
+                    with jax.named_scope("decode"):
+                        o_d = ak.latent(
+                            q[CT:], pages,
+                            b["decode_block_tables"] + l * NB,
+                            b["decode_ctx_lens"] - 1,
+                            b["decode_ctx_lens"])
                     with jax.named_scope("absorb"):
                         out = mla_absorb_o(w, jnp.concatenate(
                             [o_c.reshape(CT, H, R), o_d], axis=0))
                     return (out,) + flats_
 
-                def selected(q, pages, flats_, q_idx, w_idx):
+                def selected(q_nope, q_rope, pages, flats_, q_idx, w_idx):
+                    """Every row over what its indexer chose: ``[N, H * v]``.
+                    The chunk rows of a pass that holds ONE sequence attend
+                    expanded (:func:`_chunk_expanded`), else absorbed."""
                     kw = dict(v_dim=R, softmax_scale=_scale(rs))
                     ipages = flats_[1].reshape(L * NB, bs, -1)
                     bt_c = b["chunk_block_tables"] + l * NB
-                    scores, thr, pcut = select_chunk(
+                    sel = select_chunk(
                         rs, q_idx[:CT].reshape((NC, Cs) + q_idx.shape[1:]),
                         w_idx[:CT].reshape(NC, Cs, -1), ipages, bt_c,
                         b["chunk_q0"], b["chunk_ctx_lens"])
+
+                    def absorbed():
+                        q = mla_absorb_q(rs, w, q_nope[:CT], q_rope[:CT], W)
+                        o = sparse_mla.attend_chunk(
+                            q.reshape(NC, Cs * H, W), pages, bt_c,
+                            b["chunk_q0"], b["chunk_ctx_lens"], *sel,
+                            heads=H, **kw)
+                        return mla_absorb_o(w, o.reshape(CT, H, R))
+
+                    # (the branches INSIDE the scope: a name in a branch
+                    # comes after ``cond/branch_n_fun`` in an operation's
+                    # path, and a reader of ``mla/prefill`` would miss it —
+                    # so the absorbed branch's two products are prefill's)
                     with jax.named_scope("prefill"):
-                        o_c = sparse_mla.attend_chunk(
-                            q[:CT].reshape(NC, Cs * H, W), pages, bt_c,
-                            b["chunk_q0"], b["chunk_ctx_lens"], scores, thr,
-                            pcut, heads=H, **kw)
+                        o_c = jax.lax.cond(
+                            one_seq, lambda: _chunk_expanded(
+                                rs, w, q_nope[:CT], q_rope[:CT], pages,
+                                bt_c[0], jnp.where(b["chunk_ntok"] > 0,
+                                                   b["chunk_ctx_lens"], 0),
+                                *sel),
+                            absorbed)
                     got, live, _ = select_decode(
                         rs, q_idx[CT:], w_idx[CT:], None, ipages, flats_[0],
                         b["decode_block_tables"] + l * NB,
                         b["decode_ctx_lens"] - 1, b["decode_ctx_lens"])
+                    with jax.named_scope("absorb"):
+                        q = mla_absorb_q(rs, w, q_nope[CT:], q_rope[CT:], W)
                     with jax.named_scope("decode"):
-                        o_d = sparse_mla.attend_decode(q[CT:], got, live,
-                                                       **kw)
-                    return o_c, o_d
+                        o_d = sparse_mla.attend_decode(q, got, live, **kw)
+                    with jax.named_scope("absorb"):
+                        return jnp.concatenate([o_c, mla_absorb_o(w, o_d)],
+                                               axis=0)
 
                 x, flats = _transformer_layer(rs, w, x, positions, attend,
                                               experts=experts, l=l - l0)
@@ -521,3 +555,63 @@ def build_verify(spec: RaggedModelSpec, k: int) -> Callable:
             flat.reshape(pool.shape),)
 
     return fwd
+
+
+# --------------------------------------------------------------------------- #
+# a paged pass that holds ONE sequence (module docstring, "expanded again")
+# --------------------------------------------------------------------------- #
+
+
+def one_sequence(chunk_ntok, chunk_q0, chunk_block_tables, slot_size: int):
+    """Whether a pass's chunk slots hold ONE sequence in two or more slots
+    and nothing else — the live slots are the first ``n >= 2``, each over
+    slot 0's block table, at consecutive positions — as a traced scalar: what
+    the scheduler gives a long prompt (``schedule_pass``: "a sequence may
+    claim SEVERAL consecutive slots"), read from what the program is handed.
+    ``InferenceEngineV2`` counts the same rule on the host
+    (``serve/mla/expanded_passes``)."""
+    i = jnp.arange(chunk_ntok.shape[0], dtype=jnp.int32)
+    live = chunk_ntok > 0
+    n = jnp.sum(live, dtype=jnp.int32)
+    same = jnp.all(chunk_block_tables == chunk_block_tables[:1], axis=1) & (
+        chunk_q0 == chunk_q0[0] + i * slot_size)
+    return (n >= 2) & jnp.all(live == (i < n)) & jnp.all(same | ~live)
+
+
+def expansion_weights(w, rank: int, rope: int, width: int):
+    """``[H, W, nope + rope + v]``: a head's map from a latent ROW to its key
+    and its value — ``W_UK`` over the latent part and the identity from the
+    row's rotary key to the key's last ``rope`` values, beside ``W_UV``;
+    zeros under the row's padding."""
+    w_uk, w_uv = w["w_uk"], w["w_uv"]
+    H, _, nope = w_uk.shape
+    zeros = lambda *shape: jnp.zeros(shape, w_uk.dtype)
+    below = jnp.concatenate(
+        [zeros(width - rank, nope), jnp.eye(width - rank, rope,
+                                            dtype=w_uk.dtype),
+         zeros(width - rank, w_uv.shape[-1])], axis=-1)
+    return jnp.concatenate(
+        [jnp.concatenate([w_uk, zeros(H, rank, rope), w_uv], axis=-1),
+         jnp.broadcast_to(below, (H,) + below.shape)], axis=1)
+
+
+def expanded_queries(q_nope, q_rope, slots: int):
+    """``[N, H, Cs, nope + rope]``: a slot's query tokens a head."""
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    return q.reshape((slots, -1) + q.shape[1:]).transpose(0, 2, 1, 3)
+
+
+def _chunk_expanded(spec: RaggedModelSpec, w, q_nope, q_rope, pages,
+                    block_table, key_lims, scores, thr, pcut):
+    """The chunk rows of a pass that is one sequence, over what each row's
+    indexer chose, EXPANDED: ``[CT, H * v]`` with no absorbed query and no
+    latent output in between (the caller's scope: ``attn/mla/prefill``)."""
+    m = spec.mla
+    out = sparse_mla.attend_expanded(
+        expanded_queries(q_nope, q_rope, scores.shape[0]),
+        expansion_weights(w, m["kv_lora_rank"], m["qk_rope_head_dim"],
+                          pages.shape[-1]),
+        pages, block_table, key_lims, scores, thr, pcut,
+        k_dim=m["qk_nope_head_dim"] + m["qk_rope_head_dim"],
+        softmax_scale=_scale(spec))
+    return out.reshape(q_nope.shape[0], -1)

@@ -729,3 +729,249 @@ def attend_chunk_reference(q, pool, block_tables, q_pos0, ctx_lens, scores,
     l = p.sum(axis=-1, keepdims=True)
     return jnp.einsum("nrt,ntv->nrv", p / jnp.where(l > 0, l, 1.0),
                       rows[..., :v_dim]).astype(q.dtype)
+
+
+# --------------------------------------------------------------------------- #
+# 4. a pass that holds ONE sequence: attention over the selection, expanded
+# --------------------------------------------------------------------------- #
+
+#: query heads a grid step of :func:`attend_expanded` takes are the most
+#: whose share of the pass's rows — queries, outputs and the running softmax
+#: of every slot — stays under this many bytes of VMEM (GLM-5: 8 MiB a head,
+#: four heads; the score tiles, the weights and the expanded tile are some
+#: 20 MiB more whatever the group)
+EXPANDED_GROUP_BYTES = 40 << 20
+_EXPANDED_VMEM_LIMIT = 100 << 20
+
+
+def _expanded_group(heads: int, rows: int, k_dim: int, v_dim: int,
+                    itemsize: int) -> int:
+    """Heads a grid step (a power-of-two share of ``heads``)."""
+    a_head = rows * (v_dim * 4 + 2 * LANES * 4
+                     + 2 * (k_dim + v_dim) * itemsize)
+    g = heads
+    while g % 2 == 0 and g * a_head > EXPANDED_GROUP_BYTES:
+        g //= 2
+    return g
+
+
+def _attend_expanded_kernel(bt_ref, lim_ref, q_ref, w_ref, thr_ref, pcut_ref,
+                            kv_hbm, sc_hbm, o_ref, kv_buf, sc_buf, sems,
+                            k_sc, v_sc, bias_sc, acc_sc, m_sc, l_sc, *,
+                            scale, group, k_dim, block_size, pages, n_slots):
+    """One grid step = a group of heads over the WHOLE pass: a loop over the
+    tiles of keys any slot can see, two slots of copies (the tile's latent
+    pages and each seeing slot's tile of index scores). A tile is expanded
+    once a head — ``[T, W] x [W, k + v]``, the rotary key through the
+    identity rows of the weights, the softmax scale into the keys — and
+    every slot that sees it attends it, one mask a slot for all heads. The
+    group's heads are one traced body laid out side by side (``unroll``): a
+    head's softmax then runs under the next head's products, 12% of the
+    kernel at 9k and at 32k of context (my chip run, PR 58; four heads
+    compile in under a second more than the loop)."""
+    P, bs, G, N = pages, block_size, group, n_slots
+    T = P * bs
+    Cs, v_dim = acc_sc.shape[1:]
+    top = lim_ref[0]
+    for i in range(1, N):
+        top = jnp.maximum(top, lim_ref[i])
+    nt = jax.lax.div(top + (T - 1), T)
+
+    def copies(c, slot):
+        out = [(c * T < lim_ref[i], pltpu.make_async_copy(
+            sc_hbm.at[i, c], sc_buf.at[slot, i], sems.at[slot]))
+            for i in range(N)]
+        return out + [((c * P + j) * bs < top, pltpu.make_async_copy(
+            kv_hbm.at[bt_ref[c * P + j]], kv_buf.at[slot, j], sems.at[slot]))
+            for j in range(P)]
+
+    def start(c, slot):
+        for need, cp in copies(c, slot):
+            @pl.when(need)
+            def _():
+                cp.start()
+
+    def wait(c, slot):
+        for j, (need, cp) in enumerate(copies(c, slot)):
+            @pl.when(need)
+            def _():
+                cp.wait()
+
+            if j >= N:          # a page past every slot's keys: 0 x 0, not
+                @pl.when(jnp.logical_not(need))      # 0 x what VMEM held
+                def _():
+                    kv_buf[slot, j - N] = jnp.zeros_like(kv_buf[slot, j - N])
+
+    m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[:] = jnp.zeros_like(l_sc)
+    acc_sc[:] = jnp.zeros_like(acc_sc)
+
+    @pl.when(nt > 0)
+    def _():
+        start(0, 0)
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (Cs, T), 1)
+
+    def tile(c, carry):
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < nt)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        rows = kv_buf[slot].reshape(T, -1)                     # [T, W]
+
+        def expand(g, carry):
+            kv = jax.lax.dot_general(rows, w_ref[g].astype(rows.dtype),
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            k_sc[g] = (kv[:, :k_dim] * scale).astype(k_sc.dtype)
+            v_sc[g] = kv[:, k_dim:].astype(v_sc.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, G, expand, 0, unroll=True)
+
+        def a_slot(i, carry):
+            @pl.when(c * T < lim_ref[i])
+            def _():
+                # the scores are -inf wherever a query may not look, so the
+                # selection's mask is the causal and the context mask too
+                si, thr = sc_buf[slot, i], thr_ref[i]          # [Cs, T]
+                keep = jnp.logical_or(si > thr, jnp.logical_and(
+                    si == thr, c * T + col <= pcut_ref[i]))
+                bias_sc[:] = jnp.where(keep, 0.0, NEG_INF)
+
+                def a_head(g, carry):
+                    r = i * G + g
+                    sc = jax.lax.dot_general(
+                        q_ref[i, g].astype(k_sc.dtype), k_sc[g],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) + bias_sc[:]
+                    m_prev = m_sc[r, :, 0:1]
+                    m_new = jnp.maximum(m_prev,
+                                        jnp.max(sc, axis=1, keepdims=True))
+                    # a row that has kept nothing yet: exp(-1e30 - 0), not
+                    # exp(-1e30 + 1e30)
+                    p = jnp.exp(sc - jnp.where(m_new > 0.5 * NEG_INF, m_new,
+                                               0.0))
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_sc[r, :, 0:1] = l_sc[r, :, 0:1] * alpha + jnp.sum(
+                        p, axis=1, keepdims=True)
+                    m_sc[r, :, 0:1] = m_new
+                    acc_sc[r] = acc_sc[r] * alpha + jax.lax.dot_general(
+                        p.astype(v_sc.dtype), v_sc[g],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    return carry
+
+                jax.lax.fori_loop(0, G, a_head, 0, unroll=True)
+
+            return carry
+
+        jax.lax.fori_loop(0, N, a_slot, 0)
+        return carry
+
+    jax.lax.fori_loop(0, nt, tile, 0)
+
+    def finish(i, carry):
+        for g in range(G):
+            l = l_sc[i * G + g, :, 0:1]
+            o_ref[i, :, g * v_dim:(g + 1) * v_dim] = (
+                acc_sc[i * G + g] / jnp.where(l > 0.0, l, 1.0)
+            ).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, N, finish, 0)
+
+
+def attend_expanded(q: jax.Array, w_kv: jax.Array, pool: jax.Array,
+                    block_table: jax.Array, key_lims: jax.Array,
+                    scores: jax.Array, thr: jax.Array, pcut: jax.Array, *,
+                    k_dim: int, softmax_scale: float) -> jax.Array:
+    """Expanded attention of ``N`` slots of prompt rows that are ONE
+    sequence's, each query token over the positions its selection keeps: the
+    sequence's latent pages go through ``w_kv`` once a head a pass, whatever
+    the number of slots that read them.
+
+    q:           [N, H, Cs, k_dim] queries, nope part then rotated part
+    w_kv:        [H, W, k_dim + v] a head's map from a latent ROW to its key
+                 and its value: ``W_UK`` then the identity on the row's
+                 rotary key, beside ``W_UV``, zeros under the row's padding
+    pool:        [NB, bs, W] latent pages
+    block_table: [MB] int32, the sequence's
+    key_lims:    [N] int32 keys a slot's rows may see (0: an empty slot)
+    scores:      [N, C, Cs, T] the slots' index scores (:func:`index_scores`)
+    thr, pcut:   [N, Cs] (:func:`select`)
+
+    Returns ``[N, Cs, H * v]``, a token's heads side by side; a token that
+    keeps nothing (an empty slot's) gets zeros."""
+    N, H, Cs, Dk = q.shape
+    NB, bs, W = pool.shape
+    _, C, _, T = scores.shape
+    v_dim = w_kv.shape[2] - k_dim
+    assert Dk == k_dim and w_kv.shape[:2] == (H, W) and v_dim > 0
+    assert scores.shape[:3] == (N, C, Cs) and T % bs == 0
+    P = T // bs
+    bt = _pad_tables(block_table[None], P)[0]
+    assert bt.shape[0] == C * P, (bt.shape, C, P)
+    G = _expanded_group(H, N * Cs, k_dim, v_dim, pool.dtype.itemsize)
+    kernel = functools.partial(
+        _attend_expanded_kernel, scale=float(softmax_scale), group=G,
+        k_dim=k_dim, block_size=bs, pages=P, n_slots=N)
+    whole = lambda g, *_: (0, 0, 0)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(H // G,),
+            in_specs=[pl.BlockSpec((N, G, Cs, k_dim),
+                                   lambda g, *_: (0, g, 0, 0)),
+                      pl.BlockSpec((G, W, k_dim + v_dim),
+                                   lambda g, *_: (g, 0, 0)),
+                      pl.BlockSpec((N, Cs, 1), whole),
+                      pl.BlockSpec((N, Cs, 1), whole),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((N, Cs, G * v_dim),
+                                   lambda g, *_: (0, 0, g)),
+            scratch_shapes=[
+                pltpu.VMEM((2, P, bs, W), pool.dtype),
+                pltpu.VMEM((2, N, Cs, T), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((G, T, k_dim), pool.dtype),
+                pltpu.VMEM((G, T, v_dim), pool.dtype),
+                pltpu.VMEM((Cs, T), jnp.float32),
+                pltpu.VMEM((N * G, Cs, v_dim), jnp.float32),
+                pltpu.VMEM((N * G, Cs, LANES), jnp.float32),
+                pltpu.VMEM((N * G, Cs, LANES), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((N, Cs, H * v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_EXPANDED_VMEM_LIMIT),
+        interpret=_backend.interpret(),
+    )
+    with jax.named_scope("dsa_attend_expanded"):
+        return call(bt, key_lims.astype(jnp.int32), q, w_kv,
+                    thr.astype(jnp.float32)[..., None],
+                    pcut.astype(jnp.int32)[..., None], pool, scores)
+
+
+def attend_expanded_reference(q, w_kv, pool, block_table, key_lims, scores,
+                              thr, pcut, *, k_dim, softmax_scale):
+    """Plain ``jnp`` statement of :func:`attend_expanded` (float32)."""
+    del key_lims                            # the scores' -inf carries them
+    N, H, Cs, _ = q.shape
+    T, bs = scores.shape[3], pool.shape[1]
+    bt = _pad_tables(block_table[None], T // bs)[0]
+    rows = pool[bt].reshape(-1, pool.shape[2]).astype(jnp.float32)
+    kv = jnp.einsum("tw,hwd->htd", rows, w_kv.astype(jnp.float32))
+    mask = keep_mask(scores, thr, pcut)[:, None]           # [N, 1, Cs, S]
+    s = jnp.einsum("nhcd,htd->nhct", q.astype(jnp.float32),
+                   kv[..., :k_dim]) * softmax_scale
+    s = jnp.where(mask, s, NEG_INF)
+    p = jnp.where(mask, jnp.exp(s - s.max(axis=-1, keepdims=True)), 0.0)
+    l = p.sum(axis=-1, keepdims=True)
+    out = jnp.einsum("nhct,htv->nchv", p / jnp.where(l > 0, l, 1.0),
+                     kv[..., k_dim:])
+    return out.reshape(N, Cs, -1).astype(q.dtype)

@@ -271,6 +271,14 @@ CASES = {
         [((8, 256 * 64, 640), BF16), ((5 * 6437, BS, 640), BF16),
          ((8, 272), I32), ((8,), I32), ((8,), I32),
          ((8, 68, 256, 512), F32), ((8, 256), F32), ((8, 256), I32)]),
+    # .. and a pass that is ONE sequence's: 4 heads a grid step over all 8
+    # slots, a 512-key tile of latent rows expanded once a head in VMEM
+    "dsa_attend_expanded": (
+        lambda *a: sparse_mla.attend_expanded(*a, k_dim=256,
+                                              softmax_scale=1 / 16),
+        [((8, 64, 256, 256), BF16), ((64, 640, 512), BF16),
+         ((5 * 6437, BS, 640), BF16), ((272,), I32), ((8,), I32),
+         ((8, 68, 256, 512), F32), ((8, 256), F32), ((8, 256), I32)]),
     # int8 pages under a chunk: a scale tile a page beside its copy
     "chunk_int8": (
         lambda q, kv, bt, q0, cl, sc: paged_chunk_attention_batched(
@@ -1702,3 +1710,38 @@ def test_glm5_decode_step_reads_the_index_pool_and_gathers_its_selection(
     # (the gather sits inside a fusion: read the whole text for it)
     gathers = set(re.findall(r"= bf16\[([\d,]+,640)\]\S* gather\(", text))
     assert gathers == {"16,2048,640"}, gathers
+
+
+def test_glm5_paged_pass_holds_both_chunk_kernels_and_fits(v5e, monkeypatch):
+    """GLM-5's paged pass as the benchmark's cell runs it (8 slots of 256, 16
+    decode rows, 272-page tables): a chunk's rows attend under the
+    selection's mask by ``dsa_attend_expanded`` where the pass is one
+    sequence's and by ``dsa_attend_chunk`` where it is not — both in the
+    program, under a conditional — both pools are the output's buffers, and
+    the temporaries (784.5 MiB before the expanded branch came: compile, PR
+    58) stay under the configuration's 1 GiB of headroom and the 1.57 GiB
+    its fill leaves beside it."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _glm5_stage(arr)
+    host = RaggedBatch(num_slots=8, slot_size=256, max_sequences=16,
+                       max_blocks=272).device_arrays()
+    batch = {k: arr(I32, *host[k].shape) for k in rm.PAGED_PASS_KEYS}
+    compiled = jax.jit(rm.build_ragged_forward(spec), donate_argnums=(1,)
+                       ).lower(weights, kv, batch).compile()
+    text = compiled.as_text()
+    mosaic = {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+    assert mosaic == {"dsa_index_chunk", "dsa_index_decode", "dsa_select",
+                      "dsa_attend_chunk", "dsa_attend_expanded",
+                      "dsa_attend_decode"}, mosaic
+    assert " conditional(" in text
+    pools = sum(math.prod(a.shape) * 2 for a in kv)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools
+    print(f"glm5 paged pass: temporaries {mem.temp_size_in_bytes / 2**20:.1f}"
+          " MiB")
+    assert mem.temp_size_in_bytes < 900 << 20, mem.temp_size_in_bytes >> 20

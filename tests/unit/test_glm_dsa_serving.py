@@ -7,6 +7,7 @@ one), paged chunk passes, single tokens through the cache (ragged decode)
 and the fused decode step, two sequences of which one is shorter than the
 selection; the two pools; and what is refused beside them."""
 
+import functools
 import os
 import sys
 
@@ -41,7 +42,10 @@ TOL = 3e-4
 TOPKS = {"paged_only": 24, "packed": 32}
 
 
+@functools.lru_cache(maxsize=None)
 def build(topk=24, held=None, seed=0, **kw):
+    """(config, model, parameters), made once a set of arguments: a test
+    hands them to an engine, which keeps a copy of its own."""
     cfg = GlmDsaConfig.tiny(dtype=jnp.float32, index_topk=topk,
                             experts_held=held, **kw)
     model = GlmDsaForCausalLM(cfg)
@@ -357,3 +361,84 @@ def test_the_cells_selection_check_runs_the_programs_own_selection(
          "index_control_dtype": "float8_e4m3fn"}, np.random.default_rng(5))
     assert got["control"] > 0 and got["kept"] == 2 * 16 * 24
     assert (got["differ"] + got["miscounted"] > 0) == (fault != "sound"), got
+
+
+# --------------------------------------------------------------------------- #
+# a paged pass that holds ONE sequence attends expanded
+# --------------------------------------------------------------------------- #
+
+def _both_forms(eng, uids, tokens):
+    """``eng.put`` with every paged pass run TWICE through the engine's one
+    program: first on copies of the pools with the unused last column of
+    every later slot's block table changed — the same pass to every kernel
+    (no slot reads that far), and no longer one sequence's to the program —
+    then as it is. ``(logits, the absorbed twin's chunk logits a pass, the
+    batches)``."""
+    batches, twins = [], []
+    run, prog = eng._run_pass, eng._pass_rungs[1]
+
+    def recording():
+        batch = run()
+        batches.extend([] if batch is None else [batch])
+        return batch
+
+    def twice(weights, kv, arrays):
+        bt = np.array(arrays["chunk_block_tables"])
+        bt[1:, -1] += 1
+        twins.append(np.asarray(prog(
+            weights, jax.tree_util.tree_map(jnp.copy, kv),
+            dict(arrays, chunk_block_tables=bt))[0]))
+        return prog(weights, kv, arrays)
+
+    eng._run_pass, eng._pass_rungs[1] = recording, twice
+    try:
+        return ([np.asarray(x) for x in eng.put(uids, tokens)], twins,
+                batches)
+    finally:
+        eng._run_pass, eng._pass_rungs[1] = run, prog
+
+
+def test_one_sequence_pass_attends_expanded_and_agrees_with_absorbed():
+    """A sequence alone in both slots from position 0, again over 32 cached
+    tokens with its last slot part full, two sequences a pass, a sequence
+    alone in ONE slot: the program's predicate and the host's counter say
+    the same of every batch, and the logits of each pass agree with the same
+    pass's absorbed form (``_both_forms``) to float32's summation order —
+    exactly, where the pass was absorbed to begin with."""
+    _, model, params = build(24, held=(4, 4))
+    eng = engine_for(model, params)
+    assert not eng.packed_prefill
+    puts = [([1], [IDS[:32]]), ([1], [IDS[32:60]]),
+            ([1, 2], [IDS[60:70], SHORT[:12]]), ([2], [SHORT[12:20]])]
+    want = [True, True, False, False]
+    for (uids, tokens), expanded in zip(puts, want):
+        before = dict(tracer.totals)
+        logits, twins, batches = _both_forms(eng, uids, tokens)
+        assert len(batches) == len(twins) == 1
+        b = batches[0]
+        assert bool(ragged_mla.one_sequence(
+            jnp.asarray(b.chunk_ntok), jnp.asarray(b.chunk_q0),
+            jnp.asarray(b.chunk_block_tables), 16)) == expanded, uids
+        gained = lambda k: tracer.totals[k] - before.get(k, 0.0)
+        assert gained("serve/mla/paged_passes") == 1
+        assert gained("serve/mla/expanded_passes") == expanded, uids
+        last = [np.flatnonzero(np.asarray(b.slot_uid) == u)[-1] for u in uids]
+        for mine, slot in zip(logits, last):
+            assert np.isfinite(mine).all()
+            e = err(mine, twins[0][slot])
+            assert (0 < e <= TOL) if expanded else e == 0, (uids, e)
+
+
+@pytest.mark.parametrize("case,ntok,q0,tables,want", [
+    ("one_sequence", [16, 16, 5, 0], [32, 48, 64, 0], [3, 3, 3, 0], True),
+    ("one_slot", [16, 0, 0, 0], [32, 0, 0, 0], [3, 0, 0, 0], False),
+    ("another_table", [16, 16, 5, 0], [32, 48, 64, 0], [3, 3, 4, 0], False),
+    ("not_consecutive", [16, 16, 0, 0], [32, 64, 0, 0], [3, 3, 0, 0], False),
+    ("a_gap", [16, 0, 16, 0], [32, 0, 64, 0], [3, 0, 3, 0], False),
+    ("all_empty", [0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0], False),
+])
+def test_one_sequence_is_read_from_the_slots(case, ntok, q0, tables, want):
+    bt = np.asarray(tables, np.int32)[:, None] * 10 + np.arange(6)
+    assert bool(ragged_mla.one_sequence(
+        jnp.asarray(ntok, jnp.int32), jnp.asarray(q0, jnp.int32),
+        jnp.asarray(bt, jnp.int32), 16)) == want, case

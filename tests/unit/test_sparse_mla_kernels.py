@@ -185,3 +185,76 @@ def test_attend_chunk_attends_what_the_selection_keeps(ties):
     want = _softmax_over(q, rows, np.repeat(mask, H, axis=1), 0.1)
     np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=2e-2)
     np.testing.assert_allclose(np.asarray(twin, np.float32), want, atol=2e-2)
+
+
+def _expansion(rng, heads, rank, nope, rope, v):
+    """``w_uk`` [H, rank, nope], ``w_uv`` [H, rank, v] and the map from a
+    latent ROW to a head's key and value that ``attend_expanded`` takes:
+    ``W_UK`` and ``W_UV`` over the latent part, the identity from the row's
+    rotary key to the key's last ``rope`` values."""
+    w_uk = rng.standard_normal((heads, rank, nope)).astype(np.float32) * 0.2
+    w_uv = rng.standard_normal((heads, rank, v)).astype(np.float32) * 0.2
+    w_kv = np.zeros((heads, W, nope + rope + v), np.float32)
+    w_kv[:, :rank, :nope] = w_uk
+    w_kv[:, rank:rank + rope, nope:nope + rope] = np.eye(rope)
+    w_kv[:, :rank, nope + rope:] = w_uv
+    return w_uk, w_uv, jnp.asarray(w_kv, jnp.bfloat16)
+
+
+#: (nope, rope, v) of a head: the value as wide as the key's nope part, and
+#: wider (GLM-5: 192 + 64 and 256)
+EXPANDED_WIDTHS = {"v_is_nope": (64, 64, 64), "v_wider": (32, 32, 128)}
+
+
+@pytest.mark.parametrize("widths", list(EXPANDED_WIDTHS))
+@pytest.mark.parametrize("ties", [False, True])
+def test_attend_expanded_is_attend_chunk_of_one_sequence(ties, widths):
+    """Four slots of 16 query tokens that are ONE sequence's (one block
+    table, consecutive positions: contexts below, at and above ``topk``, the
+    third slot partly filled, the fourth empty) through the expanded kernel
+    and through the absorbed one: the same attention, ``W_UK`` in the keys
+    or in the queries, ``W_UV`` in the values or on the output."""
+    nope, rope, v = EXPANDED_WIDTHS[widths]
+    n, cs, topk, rank = 4, 16, 24, V
+    q0 = jnp.asarray([8, 24, 40, 0], jnp.int32)
+    ctx = jnp.asarray([24, 40, 51, 0], jnp.int32)
+    rng, qi, w, ipool, bt = _index_inputs(5, 1, cs)
+    bt = jnp.broadcast_to(bt, (n, MB))
+    qi = jnp.asarray(rng.standard_normal((n, cs, HI, D)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((n, cs, HI)), jnp.float32)
+    if ties:       # few distinct keys: many equal scores, ``pcut`` decides
+        ipool = jnp.asarray(rng.integers(0, 2, (NB, 1, D)) * np.ones(
+            (1, BS, 1)), jnp.bfloat16)
+    pool = np.zeros((NB, BS, W), np.float32)
+    pool[..., :rank + rope] = rng.standard_normal((NB, BS, rank + rope))
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    w_uk, w_uv, w_kv = _expansion(rng, H, rank, nope, rope, v)
+    q = jnp.asarray(rng.standard_normal((n, H, cs, nope + rope)) * 0.5,
+                    jnp.bfloat16)
+    scores = sm.index_scores(qi, w, ipool, bt, q0, ctx)
+    seen = jnp.minimum(ctx[:, None], q0[:, None] + jnp.arange(cs) + 1)
+    thr, pcut = sm.select(scores, jnp.clip(jnp.minimum(seen, topk), 1), ctx)
+    if ties:
+        assert (np.asarray(pcut)[:3] < 2**31 - 1).any(), "no tie to cut"
+    kw = dict(k_dim=nope + rope, softmax_scale=0.1)
+    got = sm.attend_expanded(q, w_kv, pool, bt[0], ctx, scores, thr, pcut,
+                             **kw)
+    twin = sm.attend_expanded_reference(q, w_kv, pool, bt[0], ctx, scores,
+                                        thr, pcut, **kw)
+    assert got.shape == (n, cs, H * v) and got.dtype == q.dtype
+    # the absorbed form in float32 on the same (bfloat16) values
+    qf = np.asarray(q, np.float32)
+    uk = np.asarray(w_kv, np.float32)[:, :rank, :nope]
+    q_abs = np.zeros((n, cs, H, W), np.float32)
+    q_abs[..., :rank] = np.einsum("nhcd,hrd->nchr", qf[..., :nope], uk)
+    q_abs[..., rank:rank + rope] = qf[..., nope:].transpose(0, 2, 1, 3)
+    o_lat = sm.attend_chunk_reference(
+        jnp.asarray(q_abs.reshape(n, cs * H, W)), pool, bt, q0, ctx, scores,
+        thr, pcut, heads=H, v_dim=rank, softmax_scale=0.1)
+    want = np.einsum("nchr,hrv->nchv", np.asarray(o_lat).reshape(
+        n, cs, H, rank), np.asarray(w_kv, np.float32)[:, :rank, nope + rope:]
+    ).reshape(n, cs, H * v)
+    assert np.abs(want[:3]).max() > 0.5 and (want[3] == 0).all()
+    # the third slot's rows past its 11 tokens see what its last token does
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(twin, np.float32), want, atol=3e-2)
