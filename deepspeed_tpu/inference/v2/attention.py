@@ -36,6 +36,8 @@ from __future__ import annotations
 import functools
 from typing import Any, Optional
 
+import jax
+
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_packed
 from deepspeed_tpu.ops.pallas.mla_attention import mla_paged_attention
 from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -65,6 +67,16 @@ INDEX_POOL_MSG = ("{what} is not wired for a model that selects inside "
                   "pool under the same page ids, and would have to move "
                   "with its latent row (docs/SERVING.md \"A selection over "
                   "them\")")
+
+
+#: what every feature that needs a copy of a sequence's recurrent state at
+#: some earlier position is refused with (docs/SERVING.md "State-space layers")
+STATE_SNAPSHOT_MSG = (
+    "{what} is not wired for a model with state-space (Mamba) layers: a "
+    "layer's recurrent state is one fixed-size value per sequence that every "
+    "token overwrites, so pages of an earlier position have no state to go "
+    "with them — it takes a snapshot of the state at a block boundary, which "
+    "no program writes")
 
 
 class AttentionKernelSpec:
@@ -142,7 +154,7 @@ class AttentionKernelSpec:
         """THE build-time capability table for the v2 engine: raises the
         canonical refusal for every (feature x feature) pair the kernel
         surface cannot carry, in one place. ``spec`` is the adapted
-        :class:`~deepspeed_tpu.inference.v2.ragged_model.RaggedModelSpec``,
+        :class:`~deepspeed_tpu.inference.v2.model_spec.RaggedModelSpec``,
         ``cfg`` the :class:`RaggedInferenceEngineConfig`. What is absent
         here COMPOSES: int8 KV pages run under the prefix cache, spec
         decode, preempt-offload and the cross-engine page fabric (the PR
@@ -226,8 +238,6 @@ class AttentionKernelSpec:
         # pages (``spec.cca``): pages of an earlier position have no tail
         if getattr(spec, "mamba", None) is not None \
                 or getattr(spec, "cca", None) is not None:
-            from deepspeed_tpu.inference.v2.scheduler import (
-                STATE_SNAPSHOT_MSG)
             refused = {
                 "prefix_cache.enabled (a cached prefix's pages carry no "
                 "state for the layers that do not attend)":
@@ -265,9 +275,8 @@ class AttentionKernelSpec:
     # ------------------------------------------------------------------ #
 
     def _tp_wrap(self, fn, in_specs, out_specs):
-        from deepspeed_tpu.utils.jax_compat import shard_map
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def decode(self, q, kv_l, block_tables, ctx_lens,
                kv_scales: Optional[Any] = None):

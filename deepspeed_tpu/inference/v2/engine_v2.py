@@ -36,10 +36,20 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu.comm.mesh import (TENSOR_AXIS, MeshTopology, build_topology,
                                      set_topology)
 from deepspeed_tpu.config import MeshConfig
+from deepspeed_tpu.inference.v2.adapters import adapt_model
+from deepspeed_tpu.inference.v2.attention import (INDEX_POOL_MSG,
+                                                  STATE_SNAPSHOT_MSG,
+                                                  AttentionKernelSpec)
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+from deepspeed_tpu.inference.v2.model_spec import (
+    describe_layer_kinds, index_width, latent_width, layer_runs,
+    num_page_layers, num_state_layers)
 from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache, KVCacheConfig
-from deepspeed_tpu.inference.v2.ragged_model import adapt_model, build_ragged_forward
+from deepspeed_tpu.inference.v2.ragged_model import (
+    PAGED_PASS_KEYS, PREFILL_PASS_KEYS, STATE_PASS_KEYS, build_decode_step,
+    build_prefill_forward, build_ragged_forward, build_verify_step,
+    pass_held_rows_bound, quantize_weights_int4, quantize_weights_int8)
 from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
 from deepspeed_tpu.monitor.trace import install_from_env as _trace_from_env
 from deepspeed_tpu.monitor.trace import tracer as _tracer
@@ -263,8 +273,6 @@ class InferenceEngineV2:
                         "weight-only int4/int8 with tensor_parallel > 1 is "
                         "not wired yet (the AutoTP rule walker shards plain "
                         "arrays); run quantized at tp=1 or bf16 under tp")
-                from deepspeed_tpu.inference.v2.ragged_model import (
-                    quantize_weights_int4, quantize_weights_int8)
                 weights = (quantize_weights_int8(weights)
                            if cfg.quantization.weight_bits == 8
                            else quantize_weights_int4(weights))
@@ -285,7 +293,6 @@ class InferenceEngineV2:
         # every surviving (feature x feature) refusal raises here; what
         # does NOT raise composes — int8 KV pages run under the prefix
         # cache, spec decode, preempt-offload and the page fabric
-        from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
         AttentionKernelSpec.validate_engine_build(self.spec, cfg)
         # the pool carries ONE page beyond the allocator's reach: the scratch
         # page backing bucket-padding rows in the fused decode programs (pad
@@ -301,8 +308,6 @@ class InferenceEngineV2:
         # allocator hands out nothing, the scheduler funds no block a token
         # (``scheduler.pageless``), and ``kv_cache.num_blocks`` is not read:
         # what a sequence costs the device is its state slot
-        from deepspeed_tpu.inference.v2.ragged_model import (
-            index_width, latent_width, num_page_layers, num_state_layers)
         # a learned selection inside latent attention (``adapt_glm_dsa``)
         self.index = (self.spec.mla or {}).get("index")
         pageless = num_page_layers(self.spec) == 0
@@ -378,7 +383,6 @@ class InferenceEngineV2:
         # ones reading only their last ``window`` tokens
         self.scheduler.window = self.spec.window
         # [(window, how many layers have it)], for kv_window_dead_tokens()
-        from deepspeed_tpu.inference.v2.ragged_model import layer_runs
         windows = [rs.window for rs, _, n in layer_runs(self.spec)
                    for _ in range(n) if rs.window is not None]
         self._windowed_layers = [(w, windows.count(w))
@@ -484,8 +488,6 @@ class InferenceEngineV2:
                              compile_hook=_count_compile),
                 swap_buffers=cfg.lora.swap_buffers,
                 max_rank=cfg.lora.max_rank)
-        from deepspeed_tpu.inference.v2.ragged_model import (
-            describe_layer_kinds)
         ring = self.scheduler.ring_pages
         if self.spec.mla is not None:
             # always-on values (tracer.totals; docs/OBSERVABILITY.md): what a
@@ -520,8 +522,6 @@ class InferenceEngineV2:
             # its passes and decode steps took past the first, which stays
             # 0 while none sends its held experts more than twice their
             # even share
-            from deepspeed_tpu.inference.v2.ragged_model import (
-                pass_held_rows_bound)
             _tracer.note("serve/moe/held_rows_bound", pass_held_rows_bound(
                 self.spec, self.weights, sm.num_chunk_slots
                 * sm.chunk_slot_size + sm.max_ragged_sequence_count) or 0)
@@ -837,8 +837,6 @@ class InferenceEngineV2:
         sp = self._attn_rung() if sp is None else int(sp)
 
         def _build():
-            from deepspeed_tpu.inference.v2.ragged_model import (
-                build_decode_step)
             tp = self.topology.tp_world_size
             fwd = build_decode_step(self.spec, mesh=self.topology.mesh,
                                     tp=tp if tp > 1 else 1,
@@ -988,8 +986,6 @@ class InferenceEngineV2:
         sp = self._attn_rung() if sp is None else int(sp)
 
         def _build():
-            from deepspeed_tpu.inference.v2.ragged_model import (
-                build_verify_step)
             tp = self.topology.tp_world_size
             fwd = build_verify_step(self.spec, k, mesh=self.topology.mesh,
                                     tp=tp if tp > 1 else 1,
@@ -1274,8 +1270,6 @@ class InferenceEngineV2:
         shapes are fully static, so this is exactly the executable every live
         put()/mixed pass reuses."""
         from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-        from deepspeed_tpu.inference.v2.ragged_model import (
-            PAGED_PASS_KEYS, PREFILL_PASS_KEYS)
         sm = self.config.state_manager
         NC, Cs = sm.num_chunk_slots, sm.chunk_slot_size
         S, MB = sm.max_ragged_sequence_count, self.scheduler.max_blocks
@@ -1329,8 +1323,6 @@ class InferenceEngineV2:
         jit programs; the other's would be dead upload weight), with the
         rows' state slots for a model with state-space layers."""
         if self.state_config is not None:
-            from deepspeed_tpu.inference.v2.ragged_model import (
-                STATE_PASS_KEYS)
             keys = keys + STATE_PASS_KEYS
         return {k: arrays[k] for k in keys}
 
@@ -1350,8 +1342,6 @@ class InferenceEngineV2:
         """Build (once) the packed pure-prefill fast-path program — shared by
         the live pass router and warmup so both compile the identical jit."""
         if self._pass_prefill is None:
-            from deepspeed_tpu.inference.v2.ragged_model import (
-                build_prefill_forward)
             self._pass_prefill = _ThreeResults(_program(
                 build_prefill_forward(self.spec, mesh=self.topology.mesh,
                                       tp=self.config.tensor_parallel),
@@ -1379,8 +1369,6 @@ class InferenceEngineV2:
         # each jitted pass receives only the keys it reads (the two paths are
         # separate jit functions; shipping the other path's descriptors is
         # pure upload waste over a slow link)
-        from deepspeed_tpu.inference.v2.ragged_model import (
-            PAGED_PASS_KEYS, PREFILL_PASS_KEYS)
         # prefill-from-zero passes need no paged reads: packed-flash fast path
         # (build_prefill_forward) — measured 3-4x wave throughput on v5e-1.
         # ALiBi models take the paged chunk path (the packed flash kernel
@@ -1568,7 +1556,6 @@ class InferenceEngineV2:
 
     def _refuse_beside_index(self, what: str) -> None:
         if self.index:
-            from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG
             raise NotImplementedError(INDEX_POOL_MSG.format(
                 what=what + " (handing a sequence's pages to another engine)"))
 
@@ -1586,8 +1573,6 @@ class InferenceEngineV2:
         uid = int(uid)
         self._refuse_beside_index("export_kv")
         if self.state_config is not None:
-            from deepspeed_tpu.inference.v2.scheduler import (
-                STATE_SNAPSHOT_MSG)
             raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
                 what="export_kv (handing a sequence's pages to another "
                 "engine)"))
@@ -1616,8 +1601,6 @@ class InferenceEngineV2:
         uid = int(uid)
         self._refuse_beside_index("import_kv")
         if self.state_config is not None:
-            from deepspeed_tpu.inference.v2.scheduler import (
-                STATE_SNAPSHOT_MSG)
             raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
                 what="import_kv (adopting a sequence whose pages were "
                 "computed on another engine)"))

@@ -50,12 +50,12 @@ class KVCacheConfig:
     quantized: bool = False
     # latent pages (multi-head latent attention): the values of ONE row a
     # token a layer holds — the compressed latent and the shared rotary key,
-    # padded to whole lane tiles (``ragged_model.latent_width``). The pool is
+    # padded to whole lane tiles (``model_spec.latent_width``). The pool is
     # then [L, NB, bs, latent_dim]: no head axis, no K/V pair, and
     # ``num_kv_heads``/``head_dim`` describe nothing in it
     latent_dim: Optional[int] = None
     # an index key a token a layer beside the latent row (a learned
-    # selection inside latent attention: ``ragged_model.index_width``): a
+    # selection inside latent attention: ``model_spec.index_width``): a
     # SECOND pool [L, NB, bs, index_dim] under the same page ids — one
     # allocator, one block table — so that the index scan reads pages of
     # keys and nothing else; the cache is then the pair (latent, index)

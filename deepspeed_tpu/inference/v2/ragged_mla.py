@@ -68,10 +68,11 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
+from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
 from deepspeed_tpu.inference.v2.ragged_model import (
-    RaggedModelSpec, _embed_in, _greedy_accept, _layer_dest, _norm,
-    _pass_rows_live, _router_stream, _sample_logits, _scan_layers,
-    _stream_out, _stream_turns, _transformer_layer, _unembed)
+    _embed_in, _greedy_accept, _layer_dest, _norm, _pass_rows_live,
+    _router_stream, _sample_logits, _scan_layers, _stream_out, _stream_turns,
+    _transformer_layer, _unembed)
 from deepspeed_tpu.ops.pallas import sparse_mla
 from deepspeed_tpu.ops.pallas.mla_attention import mla_row_write
 

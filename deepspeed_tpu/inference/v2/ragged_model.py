@@ -1,12 +1,14 @@
-"""Ragged model implementations for the v2 engine.
+"""Ragged model implementations for the v2 engine: the traced programs.
 
 Parity: reference ``inference/v2/model_implementations/`` (llama_v2, mistral,
 mixtral, opt, falcon, phi — each a hand-assembled stack of DSModule kernels over a
 ragged batch) and the module registry in ``inference/v2/modules``. TPU-native
 re-design: ONE generic ragged forward — a ``lax.scan`` over layer-stacked weights —
 specialised per family by a :class:`RaggedModelSpec` (norm type, activation,
-rope/learned positions, parallel residual, MoE) and a weight *adapter* that
-re-keys the zoo model's param tree into the canonical stacked layout.
+rope/learned positions, parallel residual, MoE; ``model_spec.py``) and a weight
+*adapter* that re-keys the zoo model's param tree into the canonical stacked
+layout (``adapters/``, one module a family). This module holds what is traced:
+the layer body, the MoE FFN, the mixers, the page writes and the builders.
 
 Pass structure (see ``ragged/ragged_batch.py``): tokens = [NC prompt-chunk
 slots | decode rows]. Each layer writes the pass's K/V into the paged cache
@@ -36,17 +38,19 @@ other group read (``_split_expert_stacks``; docs/SERVING.md "MoE layers").
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
-from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec
+from deepspeed_tpu.inference.v2.attention import (STATE_SNAPSHOT_MSG,
+                                                  AttentionKernelSpec)
+from deepspeed_tpu.inference.v2.model_spec import (
+    RaggedModelSpec, _pool_index, index_width, latent_width, layer_units,
+    num_page_layers, num_state_layers)
 from deepspeed_tpu.inference.v2.ragged.state_pool import StatefulKV
 from deepspeed_tpu.monitor.trace import tracer as _tracer
 from deepspeed_tpu.ops.pallas.gdn import gdn_chunk_scan, gdn_decode_step
@@ -54,11 +58,21 @@ from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
                                                      plan_visits, row_tile)
 from deepspeed_tpu.ops.pallas.paged_attention import (
     _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
-from deepspeed_tpu.ops.pallas.power_retention import (
-    pr_chunk_scan, pr_decode_step, state_cols as pr_state_cols,
-    state_rows as pr_state_rows)
+from deepspeed_tpu.ops.pallas.power_retention import (pr_chunk_scan,
+                                                      pr_decode_step)
 from deepspeed_tpu.ops.pallas.ssm import (ssd_chunk_scan, ssd_decode_step,
                                           ssm_chunk_scan, ssm_decode_step)
+
+# The names the benchmark's accepted files (chipbench/, tests/chipbench/)
+# still read through this module, though they live in model_spec.py and
+# adapters/ now. Nothing else imports them from here, and the block goes
+# when a `benchmark` PR repoints those files (ROADMAP D22).
+from deepspeed_tpu.inference.v2.adapters import (  # noqa: F401
+    ADAPTERS, adapt_glm_dsa, adapt_zaya)
+from deepspeed_tpu.inference.v2.adapters.zaya import (  # noqa: F401
+    zaya_channel_order)
+from deepspeed_tpu.inference.v2.model_spec import (  # noqa: F401
+    describe_layer_kinds, layer_runs)
 
 
 def _kv_unpack(kp):
@@ -85,1442 +99,6 @@ def _state_unpack(kp):
 
 def _state_pack(new_kv, state):
     return new_kv if state is None else StatefulKV(new_kv, *state)
-
-
-class LayerKind(NamedTuple):
-    """What may differ from one layer of a model to the next."""
-    window: Optional[int]   # sliding-window span in tokens; None = full
-    rope: bool              # rotates q/k by position (else no positions)
-    moe: bool               # routed experts (else the dense MLP)
-    mamba = False           # an attention layer (else: Mamba-/Delta-/PowerKind)
-    block = None            # the pair: mixer, then FFN (else: BlockKind)
-    tail = False            # pages alone (else: CcaKind)
-
-    def describe(self) -> str:
-        attn = "full" if self.window is None else f"window {self.window}"
-        return (f"{attn}, {'rotary' if self.rope else 'no positions'}, "
-                f"{'MoE' if self.moe else 'dense'} FFN")
-
-
-class MambaKind(NamedTuple):
-    """The kind of a layer whose mixer is a Mamba state-space block, not
-    attention: it holds no pages and has neither window nor positions, and
-    keeps a fixed-size state per sequence (ragged/state_pool.py). Which
-    recurrence (Mamba-1, Mamba-2) is the model's, not the layer's
-    (``RaggedModelSpec.mamba``). A kind of its own beside
-    :class:`LayerKind`, which stays the three values an attention layer is
-    told by."""
-    moe: bool = False       # routed experts (else the dense MLP)
-    window = None
-    rope = False
-    mamba = True
-    block = None
-    tail = False
-
-    def describe(self) -> str:
-        return ("Mamba state-space mixer (no pages), "
-                f"{'MoE' if self.moe else 'dense'} FFN")
-
-
-class DeltaKind(NamedTuple):
-    """The kind of a layer whose mixer is a Gated DeltaNet block (qwen3_next):
-    linear attention whose state a head is a matrix CORRECTED by a delta
-    rule, where a Mamba state decays and takes a rank-one term added. To the
-    pools it is a Mamba layer — no pages, no window, no positions, one slot of
-    the state pool a sequence (``mamba`` is true, and ``RaggedModelSpec.mamba``
-    holds its widths under ``"kind": "gdn"``) — and to the layer loop a kind
-    of its own, with a mixer (:func:`_gdn_mixer`) and scopes (``gdn/..``) of
-    its own."""
-    moe: bool = False       # routed experts (else the dense MLP)
-    window = None
-    rope = False
-    mamba = True
-    block = None
-    tail = False
-
-    def describe(self) -> str:
-        return ("Gated DeltaNet delta-rule mixer (no pages), "
-                f"{'MoE' if self.moe else 'dense'} FFN")
-
-
-class PowerKind(NamedTuple):
-    """The kind of a layer whose mixer is power retention (brumby;
-    ``ops/pallas/power_retention.py`` states it): linear attention whose
-    state a KV head is the sum of values times the key's symmetric SQUARE,
-    decayed by a gate a token, read through the query's square and divided
-    by a normaliser carried beside it. To the pools it is a Mamba layer — no
-    pages, no window, one slot of the state pool a sequence (``mamba`` is
-    true, and ``RaggedModelSpec.mamba`` holds its widths under ``"kind":
-    "pr"``) — but it ROTATES: q and k are normed and rotated by position
-    before they reach the state, so positions reach this layer as they reach
-    an attention layer; and it keeps no convolution tail. A mixer
-    (:func:`_pr_mixer`) and scopes (``pr/..``) of its own."""
-    moe: bool = False       # routed experts (else the dense MLP)
-    window = None
-    rope = True
-    mamba = True
-    block = None
-    tail = False
-
-    def describe(self) -> str:
-        return ("power-retention mixer (rotary; no pages), "
-                f"{'MoE' if self.moe else 'dense'} FFN")
-
-
-#: ``RaggedModelSpec.mamba["kind"]`` -> the kind of a layer that keeps such a
-#: state (absent: Mamba-1)
-_STATE_KINDS = {"gdn": DeltaKind, "pr": PowerKind}
-
-
-class CcaKind(NamedTuple):
-    """The kind of a layer whose attention is compressed convolutional
-    attention (zaya; :func:`_cca_project`): q and k are mixed along the
-    sequence by two small causal convolutions before they attend, and one
-    value head is the previous token's. Such a layer addresses BOTH pools: it
-    writes K and V into pages as any attention layer does (full, rotary), and
-    keeps a tail of the convolutions' last inputs in a slot of the state
-    pool (``tail``), with no recurrent state beside it
-    (``RaggedModelSpec.cca`` holds its widths)."""
-    moe: bool = True        # routed experts (else the dense MLP)
-    window = None
-    rope = True
-    mamba = False
-    block = None
-    tail = True
-
-    def describe(self) -> str:
-        return ("compressed convolutional attention (full, rotary; pages "
-                "and a convolution tail), "
-                f"{'MoE' if self.moe else 'dense'} FFN")
-
-
-class BlockKind(NamedTuple):
-    """The kind of a layer that is ONE block, ``x + block(norm(x))``, where
-    the two kinds above are a mixer followed by an FFN (nemotron_h: a Mamba
-    mixer, OR attention, OR routed experts, and nothing else in the layer).
-    A layer of experts or of a dense MLP alone addresses neither pool: it
-    holds no pages and no state."""
-    what: str                       # "mamba" | "attention" | "moe" | "mlp"
-    window: Optional[int] = None    # of an attention block
-    rope: bool = False
-    tail = False
-
-    @property
-    def mamba(self) -> bool:
-        return self.what == "mamba"
-
-    @property
-    def moe(self) -> bool:
-        return self.what == "moe"
-
-    @property
-    def block(self) -> str:         # which half of the pair the layer is
-        return "mixer" if self.what in ("mamba", "attention") else "ffn"
-
-    def describe(self) -> str:
-        if self.what == "mamba":
-            return "Mamba state-space mixer alone (no pages)"
-        if self.what == "attention":
-            attn = "full" if self.window is None else f"window {self.window}"
-            return (f"attention alone ({attn}, "
-                    f"{'rotary' if self.rope else 'no positions'})")
-        return ("routed experts" if self.moe else "dense MLP") \
-            + " alone (no pages, no state)"
-
-
-def _holds(kind) -> Optional[str]:
-    """The pool a layer of ``kind`` addresses: ``"state"`` (a mixer that
-    keeps a state: Mamba, Gated DeltaNet, power retention — whether or not it
-    rotates by position), ``"pages"`` (attention), ``"both"`` (attention that
-    keeps a convolution tail: :class:`CcaKind`) or None (an FFN alone)."""
-    if kind.mamba:
-        return "state"
-    if kind.tail:
-        return "both"
-    return None if kind.block == "ffn" else "pages"
-
-
-@dataclass
-class RaggedModelSpec:
-    family: str
-    num_layers: int
-    hidden_size: int
-    num_heads: int
-    num_kv_heads: int
-    head_dim: int
-    vocab_size: int
-    norm: str = "rms"              # "rms" | "ln"
-    # gated: "swiglu" (silu gate) | "geglu" (tanh-gelu gate, Gemma)
-    # plain: "gelu" (tanh) | "gelu_exact" (erf) | "silu" | "relu" | "relu2"
-    activation: str = "swiglu"
-    rope_theta: Optional[float] = 10000.0   # None -> no rotary
-    rotary_dim: Optional[int] = None        # partial rotary (phi); None = full head
-    learned_pos: bool = False      # gpt2/opt learned position embeddings
-    pos_offset: int = 0            # opt: positions are offset by 2 in the table
-    parallel_block: bool = False   # falcon/phi: attn + mlp both from the same norm
-    parallel_dual_norm: bool = False  # gpt_neox: parallel, but MLP from ln2(x)
-    tied_lm_head: bool = False     # gpt2: logits = x @ embed.T
-    head_bias: bool = False        # phi/gpt-j: bias added to the logits
-    embed_scale_by_sqrt_dim: bool = False  # gemma: x *= sqrt(hidden) after embed
-    norm_plus_one: bool = False    # gemma: RMSNorm scales by (1 + weight)
-    eps: float = 1e-5
-    # {"num_experts": E, "top_k": k}: top-k of the router logits, softmax
-    # over the chosen (Mixtral). With "score_func": "sigmoid" the scores are
-    # sigmoid(logits), chosen with the layer's "expert_bias" added, weighed
-    # without it, over their sum if "route_norm", times "route_scale" (afmoe,
-    # joyai). "router": "mlp" (zaya) — an MLP of width "router_hidden" on a
-    # state that every layer's router adds to and hands to the next
-    # (:func:`moe_route_mlp`), top-1 by its softmax with a stored bias, the
-    # weight not renormalised; with "skip" it scores one choice more than
-    # there are experts, and a token that takes it passes no expert.
-    # "held": (first, count) — the expert stacks hold only experts
-    # first..first+count-1 of the E the router scores (one chip's share of an
-    # expert-parallel deployment); absent: all E. "act": the plain activation
-    # of experts that are two matrices (no gate stack), as ``activation``
-    # names them; absent: "gelu"
-    moe: Optional[Dict[str, Any]] = None
-    # multi-head latent attention: {"q_lora_rank", "kv_lora_rank",
-    # "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"}. The pages then
-    # hold one latent row a token a layer (no head axis, no K/V pair:
-    # ragged/kv_cache.py) and the programs are ragged_mla.py's. With "index":
-    # {"heads", "head_dim", "topk", "rope_dim", "eps"} every layer selects
-    # the topk cached tokens a query attends to (``adapt_glm_dsa``) and a
-    # second pool holds one index key a token a layer
-    mla: Optional[Dict[str, Any]] = None
-    # mistral/qwen2 sliding-window span (tokens); None = full attention.
-    # Reference parity: inference/v2/model_implementations/mistral.
-    window: Optional[int] = None
-    # one kind per layer, for a model whose layers differ in attention
-    # (window or full, rotary or none) or FFN (dense or MoE). None: every
-    # layer is of the one kind the scalar fields give. Where kinds differ,
-    # ``window`` is None (no page ring: every layer holds whole-context
-    # pages), ``rope_theta`` and ``moe`` describe the layers that have them,
-    # and ``weights["layers"]`` is a tuple with one entry per unit the layer
-    # loop scans (:func:`layer_units`): a run's stacked tree, or for a unit of
-    # several kinds that repeats a tuple of stacked trees, one a kind
-    layer_kinds: Optional[Tuple[Any, ...]] = None   # Layer-/Mamba-/Delta-/Power-/BlockKind
-    # on a run's spec (:func:`layer_runs`) of layers that are one block
-    # (:class:`BlockKind`): "mixer" (no FFN follows) or "ffn" (no mixer
-    # before it). None: the pair every other layer is
-    block: Optional[str] = None
-    # widths of the Mamba mixer of a model that has such layers
-    # (``layer_kinds`` says which); on a run's spec (:func:`layer_runs`) it is
-    # set for a run of Mamba layers and None for a run of attention layers.
-    # Mamba-1: {"d_inner": E, "d_state": N, "dt_rank": R, "d_conv": K}.
-    # Mamba-2 (SSD), told by "kind": "mamba2": {"d_inner": E = H * P,
-    # "n_heads": H, "d_head": P, "n_groups": G (1: the kernels' one group),
-    # "d_state": N, "d_conv": K, "chunk": the product form's chunk size}.
-    # Gated DeltaNet (:class:`DeltaKind`), told by "kind": "gdn": {"d_inner":
-    # E = Hv * P, "n_heads": Hv value heads, "d_head": P, "n_key_heads": Hk,
-    # "d_state": N (a key head's width), "d_conv": K, "conv_dim": the
-    # convolved channels (q, k and v: 2 Hk N + E), "chunk": the chunked
-    # scan's chunk}. Power retention (:class:`PowerKind`), told by "kind":
-    # "pr": {"d_inner": E = D, the lanes of a state (the key's expansion),
-    # "d_state": N, its sublanes (Hk heads' d value channels and a normaliser
-    # a head), "d_conv": 1 (no tail), "chunk", "eps": the normaliser's}
-    mamba: Optional[Dict[str, Any]] = None
-    # widths of compressed convolutional attention (:class:`CcaKind`; zaya):
-    # {"time0", "time1": the taps of the depthwise and of the grouped
-    # convolution, "conv_dim": the channels they mix (q and k of every head),
-    # "tail_channels": the channels a sequence keeps a tail of (those and the
-    # shifted value's), "taps": how many earlier tokens it keeps (time0 +
-    # time1 - 2)}. On a run's spec it is set for a run of such layers
-    cca: Optional[Dict[str, Any]] = None
-    # plain multipliers (granite): on the embedding's output, on each
-    # branch's output before it joins the residual stream, on the logits,
-    # and the softmax scale where it is not head_dim ** -0.5. None (or the
-    # neutral value) leaves the program as it is without them
-    embed_scale: Optional[float] = None
-    residual_scale: Optional[float] = None
-    logits_scale: Optional[float] = None
-    attn_scale: Optional[float] = None
-    # BLOOM lineage: per-head linear position bias applied inside the paged
-    # kernels (reference csrc/transformer/inference/csrc/softmax.cu) and a
-    # LayerNorm right after the embedding
-    alibi: bool = False
-    embed_norm: bool = False
-    dtype: Any = jnp.bfloat16
-
-
-def _run_spec(spec: RaggedModelSpec, kind) -> RaggedModelSpec:
-    """The spec the layers of one ``kind`` are built with."""
-    return replace(spec, layer_kinds=None, window=kind.window,
-                   rope_theta=spec.rope_theta if kind.rope else None,
-                   moe=spec.moe if kind.moe else None,
-                   mamba=spec.mamba if kind.mamba else None,
-                   cca=spec.cca if kind.tail else None,
-                   block=kind.block)
-
-
-def layer_runs(spec: RaggedModelSpec
-               ) -> List[Tuple[RaggedModelSpec, int, int]]:
-    """Maximal runs of layers of one kind, as ``(the spec that run's layers
-    are built with, its first layer, how many)``. A model of one kind is one
-    run under its own spec."""
-    if spec.layer_kinds is None:
-        return [(spec, 0, spec.num_layers)]
-    runs: List[List[Any]] = []
-    for l, kind in enumerate(spec.layer_kinds):
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, l, 1])
-    return [(_run_spec(spec, kind), l0, n) for kind, l0, n in runs]
-
-
-def _unit_cuts(kinds: Tuple[Any, ...]) -> List[Tuple[int, int, int]]:
-    """``kinds`` cut into repeating units, as ``(first layer, period p,
-    repeats r)``: a run of r layers of one kind (p 1), or p >= 2 kinds that
-    repeat r >= 2 times. Of all such cuts, the one with the fewest layer
-    BODIES to trace and compile (a unit costs its period: a scan's body runs
-    each of its p layers once), then the fewest units; of equals, a single
-    layer or a run before a longer period. So maximal runs stay units of
-    their own where a longer period would cost more bodies than it saves
-    (Jamba's ``(7 M, A, 6 M) x 2``: five runs, not a body of fourteen), a
-    stretch of alternating layers becomes units of pairs (nemotron_h: ``M E M
-    E M * ..``), and a period that holds a run is taken where it is cheaper
-    (qwen3_next: ``(D D D A) x 3`` is one scan of four bodies, not six)."""
-    n = len(kinds)
-    # best[a]: ((bodies, units), cuts) for kinds[a:]
-    best: Dict[int, Tuple[Tuple[int, int], List]] = {n: ((0, 0), [])}
-    for a in range(n - 1, -1, -1):
-        pick = None
-        run = 1
-        while a + run < n and kinds[a + run] == kinds[a]:
-            run += 1
-        for r in range(1, run + 1):
-            cost, rest = best[a + r]
-            cand = (cost[0] + 1, cost[1] + 1)
-            if pick is None or cand < pick[0]:
-                pick = (cand, [(a, 1, r)] + rest)
-        for p in range(2, (n - a) // 2 + 1):
-            if len(set(kinds[a:a + p])) == 1:
-                continue            # a run, counted above
-            r = 1
-            while a + (r + 1) * p <= n and kinds[
-                    a + r * p:a + (r + 1) * p] == kinds[a:a + p]:
-                r += 1
-            for reps in range(2, r + 1):
-                cost, rest = best[a + reps * p]
-                cand = (cost[0] + p, cost[1] + 1)
-                if cand < pick[0]:
-                    pick = (cand, [(a, p, reps)] + rest)
-        best[a] = pick
-    return best[0][1]
-
-
-def layer_units(spec: RaggedModelSpec
-                ) -> List[Tuple[Tuple[RaggedModelSpec, ...], int, int]]:
-    """The layers as the layer loop scans them: ``(the specs of a unit's p
-    layers, the unit's first layer, how many times it repeats)``. A unit of
-    one kind is a run of layers and is scanned as ever; where every layer
-    differs from the one before it (nemotron_h: ``M E M E M * E M ..``)
-    maximal runs would be one scan a layer, and a unit of p kinds that
-    repeats is ONE scan whose body runs the p layers in turn
-    (:func:`_unit_cuts`; qwen3_next: three delta layers and an attention
-    layer, three times)."""
-    if spec.layer_kinds is None:
-        return [((spec,), 0, spec.num_layers)]
-    kinds = tuple(spec.layer_kinds)
-    return [(tuple(_run_spec(spec, k) for k in kinds[l0:l0 + p]), l0, r)
-            for l0, p, r in _unit_cuts(kinds)]
-
-
-def describe_layer_kinds(spec: RaggedModelSpec) -> str:
-    """One line for the engine's set-up log: the layers as the layer loop
-    scans them (:func:`layer_units`)."""
-    kinds = spec.layer_kinds
-
-    def one(rs, l):
-        if kinds is not None:
-            return kinds[l].describe()
-        if rs.mamba is not None:
-            state = _STATE_KINDS.get(rs.mamba.get("kind"), MambaKind)
-            return state(rs.moe is not None).describe()
-        if rs.cca is not None:
-            return CcaKind(rs.moe is not None).describe()
-        return LayerKind(rs.window, rs.rope_theta is not None,
-                         rs.moe is not None).describe()
-
-    return "; ".join(
-        f"layers {l0}-{l0 + len(specs) * n - 1}: "
-        + (one(specs[0], l0) if len(specs) == 1 else
-           f"{n} x [" + " | ".join(one(rs, l0 + k)
-                                   for k, rs in enumerate(specs)) + "]")
-        for specs, l0, n in layer_units(spec))
-
-
-def _layer_holds(spec: RaggedModelSpec) -> List[Optional[str]]:
-    """For each layer, the pool it addresses (:func:`_holds`)."""
-    if spec.layer_kinds is None:
-        return ["state" if spec.mamba is not None else
-                "both" if spec.cca is not None else
-                None if spec.block == "ffn" else "pages"] * spec.num_layers
-    return [_holds(k) for k in spec.layer_kinds]
-
-
-def num_state_layers(spec: RaggedModelSpec) -> int:
-    """Layers that hold a slot of the state pool per sequence: Mamba mixers
-    (a recurrent state and a tail) and attention that keeps a convolution
-    tail beside its pages."""
-    holds = _layer_holds(spec)
-    return holds.count("state") + holds.count("both")
-
-
-def num_page_layers(spec: RaggedModelSpec) -> int:
-    """Layers that hold KV pages — THE layer count of the page pool, of a
-    page's bytes and of everything counted in tokens x layers: the layers
-    that attend. Not ``spec.num_layers`` where some layers carry no
-    attention (a Mamba mixer holds a state, an FFN alone holds nothing)."""
-    holds = _layer_holds(spec)
-    return holds.count("pages") + holds.count("both")
-
-
-def _pool_index(spec: RaggedModelSpec, pool: Optional[str] = None
-                ) -> List[int]:
-    """For each layer, its index in the pool it addresses: its rank among
-    the layers of its sort (pages for attention, state for Mamba; a layer
-    that addresses neither counts among its like, and nothing reads that).
-    A model whose layers all hold pages addresses them by the layer's index
-    in the model, as ever. A layer that addresses both pools
-    (:class:`CcaKind`) counts among the layers that hold pages; with ``pool``
-    named (``"pages"`` or ``"state"``) the ranks are those among the layers
-    that address THAT pool, such a layer counted in each."""
-    holds = _layer_holds(spec)
-    sort = (lambda h: "pages" if h == "both" else h) if pool is None else \
-        (lambda h: pool if h in (pool, "both") else None)
-    seen: Dict[Optional[str], int] = {}
-    index = []
-    for h in map(sort, holds):
-        index.append(seen.get(h, 0))
-        seen[h] = index[-1] + 1
-    return index
-
-
-def _pool_bases(spec: RaggedModelSpec) -> List[int]:
-    """For each run of :func:`layer_runs`, its first layer's index in the
-    pool its layers address (:func:`_pool_index`)."""
-    index = _pool_index(spec)
-    return [index[l0] for _, l0, _ in layer_runs(spec)]
-
-
-# --------------------------------------------------------------------------- #
-# adapters: zoo param tree -> canonical stacked weights
-# --------------------------------------------------------------------------- #
-
-def _stack(trees: List[Any]) -> Any:
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
-
-
-def _stack_units(spec: RaggedModelSpec, layer: Callable[[int], Any]) -> Tuple:
-    """``weights["layers"]`` of a model of several kinds, from ``layer(i)``
-    (layer ``i``'s canonical weights): one entry per unit of
-    :func:`layer_units` — a run's layers stacked, or for a unit of p kinds a
-    tuple of p trees, tree k stacking layer k of each of its repeats."""
-    return tuple(
-        _stack([layer(l0 + i) for i in range(n)]) if len(specs) == 1 else
-        tuple(_stack([layer(l0 + i * len(specs) + k) for i in range(n)])
-              for k in range(len(specs)))
-        for specs, l0, n in layer_units(spec))
-
-
-def adapt_llama(params: Dict, config,
-                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
-    """models/llama.py param tree (LlamaForCausalLM / MixtralForCausalLM).
-
-    Parity anchors: reference ``inference/v2/model_implementations/llama_v2`` /
-    ``mistral`` / ``mixtral``."""
-    moe = None
-    if hasattr(config, "num_local_experts"):
-        moe = {"num_experts": config.num_local_experts,
-               "top_k": config.num_experts_per_tok}
-    # Gemma lineage rides the llama adapter: its structural differences are
-    # config flags on LlamaConfig (module_inject/containers.py GemmaPolicy)
-    mlp_act = getattr(config, "mlp_act", "silu")
-    if mlp_act not in ("silu", "gelu"):
-        raise ValueError(f"llama-lineage mlp_act '{mlp_act}' has no ragged "
-                         "gated-MLP mapping (expected 'silu' or 'gelu')")
-    window = getattr(config, "sliding_window", None)
-    if window is not None and (max_context is not None
-                               and max_context <= window):
-        # no position can ever see past the window: full attention is
-        # exactly equivalent, so skip the window masks (and their small
-        # kernel cost) entirely
-        window = None
-    spec = RaggedModelSpec(
-        family="mixtral" if moe else "llama",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.num_key_value_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm="rms",
-        activation="swiglu" if mlp_act == "silu" else "geglu",
-        rope_theta=config.rope_theta,
-        embed_scale_by_sqrt_dim=getattr(config, "embed_scale_by_sqrt_dim", False),
-        norm_plus_one=getattr(config, "norm_plus_one", False),
-        eps=config.rms_norm_eps, moe=moe, window=window, dtype=config.dtype)
-
-    layers = []
-    for i in range(config.num_hidden_layers):
-        lp = params[f"layers_{i}"]
-        attn = lp["self_attn"]
-        layer = {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "wq": attn["q_proj"]["kernel"],
-            "wk": attn["k_proj"]["kernel"],
-            "wv": attn["v_proj"]["kernel"],
-            "wo": attn["o_proj"]["kernel"],
-        }
-        if "bias" in attn["q_proj"]:   # Qwen2 lineage: biased q/k/v
-            layer["bq"] = attn["q_proj"]["bias"]
-            layer["bk"] = attn["k_proj"]["bias"]
-            layer["bv"] = attn["v_proj"]["bias"]
-        if moe:
-            mb = lp["block_sparse_moe"]
-            layer["moe"] = {
-                "router": mb["gate"]["kernel"],
-                "w_gate": mb["w_gate"], "w_up": mb["w_up"], "w_down": mb["w_down"],
-            }
-        else:
-            layer["mlp"] = {
-                "w_gate": lp["mlp"]["gate_proj"]["kernel"],
-                "w_up": lp["mlp"]["up_proj"]["kernel"],
-                "w_down": lp["mlp"]["down_proj"]["kernel"],
-            }
-        layers.append(layer)
-
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": _stack(layers),
-        "final_norm": {"scale": params["norm"]["weight"]},
-        "lm_head": params["lm_head"]["kernel"],
-    }
-    return spec, weights
-
-
-def adapt_gpt2(params: Dict, config,
-               max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
-    """models/gpt2.py param tree (GPT2LMHead): fused c_attn qkv, tied head."""
-    spec = RaggedModelSpec(
-        family="gpt2",
-        num_layers=config.n_layer,
-        hidden_size=config.n_embd,
-        num_heads=config.n_head,
-        num_kv_heads=config.n_head,
-        head_dim=config.n_embd // config.n_head,
-        vocab_size=config.vocab_size,
-        norm="ln", activation="gelu", rope_theta=None, learned_pos=True,
-        tied_lm_head=True, eps=1e-5, dtype=config.dtype)
-
-    E = config.n_embd
-    layers = []
-    for i in range(config.n_layer):
-        lp = params[f"h_{i}"]
-        wqkv = lp["attn"]["c_attn"]["kernel"]     # [E, 3E]
-        bqkv = lp["attn"]["c_attn"]["bias"]
-        layers.append({
-            "ln1": {"scale": lp["ln_1"]["scale"], "bias": lp["ln_1"]["bias"]},
-            "ln2": {"scale": lp["ln_2"]["scale"], "bias": lp["ln_2"]["bias"]},
-            "wq": wqkv[:, :E], "wk": wqkv[:, E:2 * E], "wv": wqkv[:, 2 * E:],
-            "bq": bqkv[:E], "bk": bqkv[E:2 * E], "bv": bqkv[2 * E:],
-            "wo": lp["attn"]["c_proj"]["kernel"],
-            "bo": lp["attn"]["c_proj"]["bias"],
-            "mlp": {
-                "w_up": lp["mlp"]["c_fc"]["kernel"],
-                "b_up": lp["mlp"]["c_fc"]["bias"],
-                "w_down": lp["mlp"]["c_proj"]["kernel"],
-                "b_down": lp["mlp"]["c_proj"]["bias"],
-            },
-        })
-
-    weights = {
-        "embed": params["wte"]["embedding"],
-        "pos_embed": params["wpe"]["embedding"],
-        "layers": _stack(layers),
-        "final_norm": {"scale": params["ln_f"]["scale"],
-                       "bias": params["ln_f"]["bias"]},
-    }
-    return spec, weights
-
-
-def adapt_decoder(params: Dict, config,
-                  max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
-    """models/decoder.py (DecoderLM — opt/falcon/phi/gpt_neox/gptj/
-    gpt_bigcode): canonical names, so adaptation is re-rooting + stacking.
-    Parity anchors: reference ``inference/v2/model_implementations/
-    {opt,falcon,phi}``. Guards on the FEATURES the ragged path can't carry
-    (not family names), so a config with e.g. alibi under any family is
-    rejected instead of silently served wrong."""
-    unsupported = []
-    if getattr(config, "local_window", None) is not None:
-        unsupported.append("local_window")
-    if any(k == "local" for k in getattr(config, "attention_layers", None) or ()):
-        unsupported.append("attention_layers with 'local' entries")
-    if getattr(config, "attn_scale", None) is not None:
-        unsupported.append("attn_scale")
-    if unsupported:
-        # neither is a kernel limit any more: the paged kernels take a score
-        # scale (``spec.attn_scale``; granite's, PR 39) and the spec carries a
-        # kind per layer — this adapter maps neither yet
-        raise ValueError(
-            f"config features {unsupported} are not served by the ragged "
-            "(paged) attention path: this adapter maps neither a score scale "
-            "other than 1/sqrt(head_dim) onto spec.attn_scale nor "
-            "'local' attention_layers onto the spec's per-layer kinds — "
-            "serve through deepspeed_tpu.init_inference (v1 dense engine) "
-            "instead")
-    spec = RaggedModelSpec(
-        family=config.family,
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.kv_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm=config.norm, activation=config.activation,
-        rope_theta=config.rope_theta, rotary_dim=config.rotary_dim,
-        learned_pos=config.learned_pos, pos_offset=config.pos_offset,
-        parallel_block=config.parallel_block,
-        parallel_dual_norm=config.parallel_dual_norm,
-        tied_lm_head=config.tied_lm_head, head_bias=config.head_bias,
-        alibi=getattr(config, "alibi", False),
-        embed_norm=getattr(config, "embed_norm", False),
-        eps=config.eps, dtype=config.dtype)
-
-    layers = [params[f"layers_{i}"] for i in range(config.num_hidden_layers)]
-    weights = {
-        "embed": params["embed"]["embedding"],
-        "layers": _stack(layers),
-        "final_norm": params["final_norm"],
-    }
-    if spec.embed_norm:
-        weights["embed_norm"] = params["embed_norm"]
-    if config.learned_pos:
-        weights["pos_embed"] = params["pos_embed"]["embedding"]
-    if not config.tied_lm_head:
-        weights["lm_head"] = params["lm_head"]
-    if config.head_bias:
-        weights["lm_head_bias"] = params["lm_head_bias"]
-    return spec, weights
-
-
-def adapt_afmoe(params: Dict, config,
-                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
-    """models/afmoe.py param tree (AfmoeForCausalLM; Arcee Trinity).
-
-    Everything that sets the family apart is read from the config and the
-    tree: one :class:`LayerKind` per layer from ``layer_types`` and
-    ``num_dense_layers``; q/k norm (``q_norm``/``k_norm``), the output gate
-    (``wg``) and the sandwich norms (``ln1_post``/``ln2_post``) by their
-    presence in a layer's weights; the router by ``spec.moe``."""
-    window = config.sliding_window
-    if max_context is not None and max_context <= window:
-        window = None           # as adapt_llama: no position sees past it
-    kinds = tuple(
-        LayerKind(window if t == "sliding_attention" else None,
-                  t == "sliding_attention", config.is_moe_layer(i))
-        for i, t in enumerate(config.layer_types))
-    spec = RaggedModelSpec(
-        family="afmoe",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.num_key_value_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
-        embed_scale_by_sqrt_dim=config.mup_enabled, eps=config.rms_norm_eps,
-        moe={"num_experts": config.num_experts,
-             "top_k": config.num_experts_per_tok,
-             "score_func": config.score_func,
-             "route_norm": config.route_norm,
-             "route_scale": config.route_scale},
-        layer_kinds=kinds, dtype=config.dtype)
-    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
-        spec = layer_runs(spec)[0][0]
-
-    def swiglu(p):
-        return {"w_gate": p["gate_proj"]["kernel"],
-                "w_up": p["up_proj"]["kernel"],
-                "w_down": p["down_proj"]["kernel"]}
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        attn = lp["self_attn"]
-        out = {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln1_post": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "ln2": {"scale": lp["pre_mlp_layernorm"]["weight"]},
-            "ln2_post": {"scale": lp["post_mlp_layernorm"]["weight"]},
-            "wq": attn["q_proj"]["kernel"], "wk": attn["k_proj"]["kernel"],
-            "wv": attn["v_proj"]["kernel"], "wo": attn["o_proj"]["kernel"],
-            "wg": attn["gate_proj"]["kernel"],
-            "q_norm": attn["q_norm"]["weight"],
-            "k_norm": attn["k_norm"]["weight"],
-        }
-        mlp = lp["mlp"]
-        if config.is_moe_layer(i):
-            out["moe"] = {"router": mlp["router"]["kernel"],
-                          "expert_bias": mlp["expert_bias"],
-                          "w_gate": mlp["w_gate"], "w_up": mlp["w_up"],
-                          "w_down": mlp["w_down"]}
-            if "shared_experts" in mlp:
-                out["moe"]["shared"] = swiglu(mlp["shared_experts"])
-        else:
-            out["mlp"] = swiglu(mlp)
-        return out
-
-    stacks = _stack_units(spec, layer)
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": stacks if spec.layer_kinds is not None else stacks[0],
-        "final_norm": {"scale": params["norm"]["weight"]},
-        "lm_head": params["lm_head"]["kernel"],
-    }
-    return spec, weights
-
-
-def adapt_jamba(params: Dict, config,
-                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
-    """models/jamba.py param tree (JambaForCausalLM; AI21 Jamba).
-
-    One kind per layer from the config's ``layer_types`` (what
-    ``attn_layer_period``/``attn_layer_offset`` build): :class:`MambaKind` or
-    an attention :class:`LayerKind` without window or positions, every FFN
-    dense. A run of Mamba layers stacks
-    the mixer's matrices under their own names (``in_proj`` ... ``out_proj``)
-    where a run of attention layers has ``wq``..``wo``; ``A_log`` is stored
-    transposed, ``[N, E]``, the state's layout (ops/pallas/ssm.py)."""
-    from deepspeed_tpu.models.jamba import MAMBA
-    kinds = tuple(MambaKind() if t == MAMBA else LayerKind(None, False, False)
-                  for t in config.layer_types)
-    spec = RaggedModelSpec(
-        family="jamba",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.num_key_value_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=None,
-        tied_lm_head=True, eps=config.rms_norm_eps,
-        layer_kinds=kinds, dtype=config.dtype,
-        mamba={"d_inner": config.mamba_d_inner,
-               "d_state": config.mamba_d_state,
-               "dt_rank": config.mamba_dt_rank,
-               "d_conv": config.mamba_d_conv} if any(
-                   k.mamba for k in kinds) else None)
-    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
-        spec = layer_runs(spec)[0][0]
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        ff = lp["feed_forward"]
-        out = {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["pre_ff_layernorm"]["weight"]},
-            "mlp": {"w_gate": ff["gate_proj"]["kernel"],
-                    "w_up": ff["up_proj"]["kernel"],
-                    "w_down": ff["down_proj"]["kernel"]},
-        }
-        if kinds[i].mamba:
-            m = lp["mamba"]
-            out["mamba"] = {
-                "in_proj": m["in_proj"]["kernel"],
-                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, E]
-                "conv_b": m["conv_bias"],
-                "x_proj": m["x_proj"]["kernel"],
-                "dt_norm": m["dt_layernorm"]["weight"],
-                "b_norm": m["b_layernorm"]["weight"],
-                "c_norm": m["c_layernorm"]["weight"],
-                "dt_proj": m["dt_proj"]["kernel"],
-                "dt_bias": m["dt_bias"],
-                "A_log": jnp.transpose(m["A_log"]),              # [N, E]
-                "D": m["D"],
-                "out_proj": m["out_proj"]["kernel"],
-            }
-        else:
-            attn = lp["self_attn"]
-            out.update(wq=attn["q_proj"]["kernel"], wk=attn["k_proj"]["kernel"],
-                       wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"])
-        return out
-
-    stacks = _stack_units(spec, layer)
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": stacks if spec.layer_kinds is not None else stacks[0],
-        "final_norm": {"scale": params["final_layernorm"]["weight"]},
-    }
-    return spec, weights
-
-
-def adapt_joyai(params: Dict, config, max_context: Optional[int] = None,
-                family: str = "joyai", index: Optional[Dict[str, int]] = None
-                ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/joyai.py param tree (JoyaiForCausalLM; JoyAI-LLM-Flash).
-
-    Latent attention (``spec.mla``): ``kv_b_proj`` is stored split by what
-    it makes and head-major, ``w_uk`` ``[H, R, nope]`` (keys) and ``w_uv``
-    ``[H, R, v]`` (values): the layout the decode step's per-head products
-    read in place (``[R, H, .]`` was copied transposed in every layer). Each
-    is used by the expanded form (latent -> keys/values) and by the absorbed
-    form (queries -> latent space, latent output -> values) alike. One leading run of dense layers, then MoE layers whose stacks
-    hold ``config.held`` of the router's ``n_routed_experts``. The
-    multi-token-prediction module (``layers_<num_hidden_layers>`` and on in
-    a converted checkpoint) feeds no logit and is not loaded."""
-    del max_context
-    H = config.num_attention_heads
-    R, dn, dr, dv = (config.kv_lora_rank, config.qk_nope_head_dim,
-                     config.qk_rope_head_dim, config.v_head_dim)
-    skipped = sorted(k for k in params if k.startswith("layers_")
-                     and int(k[len("layers_"):]) >= config.num_hidden_layers)
-    if skipped:
-        from deepspeed_tpu.utils.logging import log_dist
-        log_dist(f"adapt_{family}: {skipped} (the multi-token-prediction "
-                 "module) not loaded", ranks=[0])
-    kinds = tuple(LayerKind(None, True, config.is_moe_layer(i))
-                  for i in range(config.num_hidden_layers))
-    first, count = config.held
-    moe = {"num_experts": config.n_routed_experts,
-           "top_k": config.num_experts_per_tok, "score_func": "sigmoid",
-           "route_norm": config.norm_topk_prob,
-           "route_scale": config.routed_scaling_factor}
-    if count != config.n_routed_experts:
-        moe["held"] = (first, count)
-    mla = {"q_lora_rank": config.q_lora_rank, "kv_lora_rank": R,
-           "qk_nope_head_dim": dn, "qk_rope_head_dim": dr, "v_head_dim": dv}
-    if index is not None:
-        mla["index"] = index
-    spec = RaggedModelSpec(
-        family=family,
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=H, num_kv_heads=H, head_dim=dv,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
-        eps=config.rms_norm_eps, moe=moe, layer_kinds=kinds, mla=mla,
-        dtype=config.dtype)
-    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
-        spec = layer_runs(spec)[0][0]
-
-    def swiglu(p):
-        return {"w_gate": p["gate_proj"]["kernel"],
-                "w_up": p["up_proj"]["kernel"],
-                "w_down": p["down_proj"]["kernel"]}
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        attn = lp["self_attn"]
-        kvb = jnp.transpose(
-            attn["kv_b_proj"]["kernel"].reshape(R, H, dn + dv), (1, 0, 2))
-        out = {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "wqa": attn["q_a_proj"]["kernel"],
-            "q_a_norm": attn["q_a_layernorm"]["weight"],
-            "wqb": attn["q_b_proj"]["kernel"],
-            "wkva": attn["kv_a_proj_with_mqa"]["kernel"],
-            "kv_a_norm": attn["kv_a_layernorm"]["weight"],
-            "w_uk": kvb[..., :dn], "w_uv": kvb[..., dn:],
-            "wo": attn["o_proj"]["kernel"],
-        }
-        if index is not None:
-            ix = attn["indexer"]
-            out["index"] = {"wq": ix["wq_b"]["kernel"],
-                            "wk": ix["wk"]["kernel"],
-                            "k_norm": ix["k_norm"]["scale"],
-                            "k_bias": ix["k_norm"]["bias"],
-                            "ww": ix["weights_proj"]["kernel"]}
-        mlp = lp["mlp"]
-        if config.is_moe_layer(i):
-            out["moe"] = {"router": mlp["gate"]["kernel"],
-                          "expert_bias": mlp["e_score_correction_bias"],
-                          "w_gate": mlp["w_gate"], "w_up": mlp["w_up"],
-                          "w_down": mlp["w_down"]}
-            if "shared_experts" in mlp:
-                out["moe"]["shared"] = swiglu(mlp["shared_experts"])
-        else:
-            out["mlp"] = swiglu(mlp)
-        return out
-
-    stacks = _stack_units(spec, layer)
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": stacks if spec.layer_kinds is not None else stacks[0],
-        "final_norm": {"scale": params["norm"]["weight"]},
-        "lm_head": params["lm_head"]["kernel"],
-    }
-    return spec, weights
-
-
-def adapt_glm_dsa(params: Dict, config, max_context: Optional[int] = None
-                  ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/glm_dsa.py param tree (GlmDsaForCausalLM; GLM-5,
-    ``glm_moe_dsa``): :func:`adapt_joyai`'s latent attention, router and held
-    experts, and in every layer an indexer — ``spec.mla["index"]``: ``heads``
-    of ``head_dim`` whose first ``rope_dim`` values are rotated, keeping the
-    ``topk`` best cached tokens a query; ``eps`` of the index key's LayerNorm
-    — whose weights ride in the layer as ``w["index"]``: ``wq`` (from the
-    normed query latent), ``wk``, ``k_norm``/``k_bias`` and ``ww`` (from the
-    layer's normed input). The pool gains one index key a token a layer
-    (``ragged/kv_cache.py``) and the programs are ragged_mla.py's with a
-    selection (``ops/pallas/sparse_mla.py``)."""
-    return adapt_joyai(params, config, max_context, family="glm_dsa", index={
-        "heads": config.index_n_heads, "head_dim": config.index_head_dim,
-        "topk": config.index_topk, "rope_dim": config.qk_rope_head_dim,
-        "eps": config.index_norm_eps})
-
-
-def adapt_granite(params: Dict, config,
-                  max_context: Optional[int] = None
-                  ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/granite.py param tree (GraniteForCausalLM; IBM Granite 4.0-H,
-    ``granitemoehybrid``).
-
-    One kind per layer from the config's ``layer_types``:
-    :class:`MambaKind` with routed experts, or an attention
-    :class:`LayerKind` without window or positions, with them too. The
-    mixer is Mamba-2 (``spec.mamba["kind"] == "mamba2"``): a run of Mamba
-    layers stacks ``in_proj`` (gate, convolution input, ``dt`` a head), the
-    convolution over x, B and C together, ``A_log``/``D``/``dt_bias`` a head,
-    the gated norm's gain and ``out_proj``. The router is the softmax one
-    (top-k of the logits, softmax over the chosen); the stacks hold
-    ``config.held`` of its ``num_local_experts``; the shared MLP rides as the
-    layer's ``shared`` expert. The four published multipliers are the spec's
-    plain floats."""
-    del max_context
-    from deepspeed_tpu.models.granite import MAMBA
-    kinds = tuple(MambaKind(True) if t == MAMBA
-                  else LayerKind(None, False, True)
-                  for t in config.layer_types)
-    first, count = config.held
-    moe = {"num_experts": config.num_local_experts,
-           "top_k": config.num_experts_per_tok}
-    if count != config.num_local_experts:
-        moe["held"] = (first, count)
-    spec = RaggedModelSpec(
-        family="granite",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.num_key_value_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=None,
-        tied_lm_head=True, eps=config.rms_norm_eps, moe=moe,
-        layer_kinds=kinds, dtype=config.dtype,
-        embed_scale=float(config.embedding_multiplier),
-        residual_scale=float(config.residual_multiplier),
-        logits_scale=1.0 / float(config.logits_scaling),
-        attn_scale=float(config.attention_multiplier),
-        mamba={"kind": "mamba2", "d_inner": config.mamba_d_inner,
-               "n_heads": config.mamba_n_heads,
-               "d_head": config.mamba_d_head,
-               "n_groups": config.mamba_n_groups,
-               "d_state": config.mamba_d_state,
-               "d_conv": config.mamba_d_conv,
-               "chunk": config.mamba_chunk_size} if any(
-                   k.mamba for k in kinds) else None)
-    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
-        spec = layer_runs(spec)[0][0]
-
-    def swiglu(p):
-        return {"w_gate": p["gate_proj"]["kernel"],
-                "w_up": p["up_proj"]["kernel"],
-                "w_down": p["down_proj"]["kernel"]}
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        ff = lp["block_sparse_moe"]
-        out = {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "moe": {"router": ff["router"]["kernel"],
-                    "w_gate": ff["w_gate"], "w_up": ff["w_up"],
-                    "w_down": ff["w_down"],
-                    "shared": swiglu(ff["shared_mlp"])},
-        }
-        if kinds[i].mamba:
-            m = lp["mamba"]
-            out["mamba"] = {
-                "in_proj": m["in_proj"]["kernel"],
-                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, W]
-                "conv_b": m["conv_bias"],
-                "dt_bias": m["dt_bias"], "A_log": m["A_log"], "D": m["D"],
-                "norm": m["norm"],
-                "out_proj": m["out_proj"]["kernel"],
-            }
-        else:
-            attn = lp["self_attn"]
-            out.update(wq=attn["q_proj"]["kernel"], wk=attn["k_proj"]["kernel"],
-                       wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"])
-        return out
-
-    stacks = _stack_units(spec, layer)
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": stacks if spec.layer_kinds is not None else stacks[0],
-        "final_norm": {"scale": params["norm"]["weight"]},
-    }
-    return spec, weights
-
-
-def adapt_nemotron_h(params: Dict, config,
-                     max_context: Optional[int] = None
-                     ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/nemotron_h.py param tree (NemotronHForCausalLM; NVIDIA
-    Nemotron-H, ``nemotron_h``).
-
-    One :class:`BlockKind` per layer from ``hybrid_override_pattern``: each
-    layer is ONE block behind its one norm (``ln1``) — a Mamba-2 mixer
-    (``spec.mamba`` with ``n_groups`` pairs of B and C), attention without
-    window or positions, or routed experts. The experts are two stacks
-    (``w_up``, ``w_down``: no gate; the width zero-padded to whole lane
-    tiles, :func:`_pad_expert_width`) with ``relu2`` between them, and so is
-    the shared expert (unpadded: a dense product); the router is the sigmoid
-    one with its selection bias (``expert_bias``), weights normalised over
-    the chosen and scaled; the stacks hold ``config.held`` of its
-    ``n_routed_experts``."""
-    del max_context
-    from deepspeed_tpu.models import nemotron_h as zoo
-    what = {zoo.MAMBA: "mamba", zoo.MOE: "moe", zoo.ATTENTION: "attention"}
-    kinds = tuple(BlockKind(what[c]) for c in config.hybrid_override_pattern)
-    first, count = config.held
-    moe = {"num_experts": config.n_routed_experts,
-           "top_k": config.num_experts_per_tok, "score_func": "sigmoid",
-           "route_norm": bool(config.norm_topk_prob),
-           "route_scale": float(config.routed_scaling_factor),
-           "act": config.mlp_hidden_act}
-    if count != config.n_routed_experts:
-        moe["held"] = (first, count)
-    spec = RaggedModelSpec(
-        family="nemotron_h",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.num_key_value_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm="rms", activation=config.mlp_hidden_act, rope_theta=None,
-        tied_lm_head=False, eps=config.norm_eps,
-        moe=moe if any(k.moe for k in kinds) else None,
-        layer_kinds=kinds, dtype=config.dtype,
-        mamba={"kind": "mamba2", "d_inner": config.mamba_d_inner,
-               "n_heads": config.mamba_num_heads,
-               "d_head": config.mamba_head_dim,
-               "n_groups": config.n_groups,
-               "d_state": config.ssm_state_size,
-               "d_conv": config.conv_kernel,
-               "chunk": config.chunk_size} if any(
-                   k.mamba for k in kinds) else None)
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        m = lp["mixer"]
-        out = {"ln1": {"scale": lp["norm"]["weight"]}}
-        if kinds[i].mamba:
-            out["mamba"] = {
-                "in_proj": m["in_proj"]["kernel"],
-                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, W]
-                "conv_b": m["conv_bias"],
-                "dt_bias": m["dt_bias"], "A_log": m["A_log"], "D": m["D"],
-                "norm": m["norm"],
-                "out_proj": m["out_proj"]["kernel"],
-            }
-        elif kinds[i].moe:
-            w_up, w_down = _pad_expert_width(m["w_up"], m["w_down"])
-            out["moe"] = {
-                "router": m["router"]["kernel"],
-                "expert_bias": m["e_score_correction_bias"],
-                "w_up": w_up, "w_down": w_down,
-                "shared": {"w_up": m["shared_up"]["kernel"],
-                           "w_down": m["shared_down"]["kernel"]}}
-        else:
-            out.update(wq=m["q_proj"]["kernel"], wk=m["k_proj"]["kernel"],
-                       wv=m["v_proj"]["kernel"], wo=m["o_proj"]["kernel"])
-        return out
-
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": _stack_units(spec, layer),
-        "final_norm": {"scale": params["norm_f"]["weight"]},
-        "lm_head": params["lm_head"]["kernel"],
-    }
-    return spec, weights
-
-
-def adapt_qwen3_next(params: Dict, config,
-                     max_context: Optional[int] = None
-                     ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/qwen3_next.py param tree (Qwen3NextForCausalLM; Qwen3-Next,
-    ``qwen3_next``), published layout.
-
-    One kind per layer: :class:`DeltaKind` (Gated DeltaNet; ``spec.mamba``
-    with ``"kind": "gdn"``) or a full-attention :class:`LayerKind` with
-    rotation, both over routed experts. What the published layout fuses is
-    taken apart here, once:
-
-    - ``in_proj_qkvz``'s columns (a key head's q, k, its value heads' v and z
-      together) are put in the order ``[q | k | v | z]`` over all heads, so
-      that the convolution's input is the product's first ``2 Hk N + E``
-      columns; ``in_proj_ba``'s likewise ``[b | a]``;
-    - ``q_proj`` (a head's query, then its gate) becomes ``wq`` and the output
-      gate ``wg`` of the branch afmoe's gated attention takes;
-    - the rotation pairs value ``i`` with ``i + rotary_dim / 2`` where the
-      ragged path's pairs ``2i`` with ``2i + 1``: the first ``rotary_dim``
-      columns of each q and k head (and their norms' gains) are interleaved,
-      the same way in both, which leaves every ``q . k`` as it was.
-
-    Every norm but the mixer's own scales by ``1 + w`` (``norm_plus_one``).
-    The router is the softmax one (top-k of the logits, softmax over the
-    chosen = softmax over all, top-k, renormalised); the stacks hold
-    ``config.held`` of its ``num_experts``; the shared expert rides as the
-    layer's ``shared`` expert behind ``shared_gate``."""
-    del max_context
-    kinds = tuple(LayerKind(None, True, True) if config.is_attention_layer(i)
-                  else DeltaKind(True)
-                  for i in range(config.num_hidden_layers))
-    first, count = config.held
-    moe = {"num_experts": config.num_experts,
-           "top_k": config.num_experts_per_tok, "shared_gate": True}
-    if count != config.num_experts:
-        moe["held"] = (first, count)
-    Hk, Hv = config.linear_num_key_heads, config.linear_num_value_heads
-    N, P = config.linear_key_head_dim, config.linear_value_head_dim
-    spec = RaggedModelSpec(
-        family="qwen3_next",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=config.num_attention_heads,
-        num_kv_heads=config.num_key_value_heads,
-        head_dim=config.head_dim,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
-        rotary_dim=config.rotary_dim, norm_plus_one=True,
-        tied_lm_head=False, eps=config.rms_norm_eps, moe=moe,
-        layer_kinds=kinds, dtype=config.dtype,
-        mamba={"kind": "gdn", "d_inner": config.value_dim, "n_heads": Hv,
-               "d_head": P, "n_key_heads": Hk, "d_state": N,
-               "d_conv": config.linear_conv_kernel_dim,
-               "conv_dim": config.conv_dim,
-               "chunk": config.chunk_size} if any(
-                   k.mamba for k in kinds) else None)
-    if len(set(kinds)) == 1:    # one kind after all: the scalar fields say it
-        spec = layer_runs(spec)[0][0]
-
-    H, D, rd = config.num_attention_heads, config.head_dim, config.rotary_dim
-    # half-split pairs -> interleaved pairs, inside a head's first rd values
-    turn = np.concatenate([np.arange(rd).reshape(2, rd // 2).T.reshape(-1),
-                           np.arange(rd, D)])
-    heads = lambda x, n: x.reshape(x.shape[0], n, -1)
-    R = Hv // Hk
-
-    def swiglu(p):
-        return {"w_gate": p["gate_proj"]["kernel"],
-                "w_up": p["up_proj"]["kernel"],
-                "w_down": p["down_proj"]["kernel"]}
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        ff = lp["mlp"]
-        out = {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "moe": {"router": ff["gate"]["kernel"],
-                    "w_gate": ff["w_gate"], "w_up": ff["w_up"],
-                    "w_down": ff["w_down"],
-                    "shared": swiglu(ff["shared_expert"]),
-                    "shared_gate": ff["shared_expert_gate"]["kernel"]},
-        }
-        if kinds[i].mamba:
-            m = lp["linear_attn"]
-            qkvz = heads(m["in_proj_qkvz"]["kernel"], Hk)
-            ba = heads(m["in_proj_ba"]["kernel"], Hk)
-            flat = lambda x: x.reshape(x.shape[0], -1)
-            out["gdn"] = {
-                "in_proj": jnp.concatenate(
-                    [flat(qkvz[..., :N]), flat(qkvz[..., N:2 * N]),
-                     flat(qkvz[..., 2 * N:2 * N + R * P]),
-                     flat(qkvz[..., 2 * N + R * P:])], axis=1),
-                "in_ba": jnp.concatenate(
-                    [flat(ba[..., :R]), flat(ba[..., R:])], axis=1),
-                "conv_w": jnp.transpose(m["conv_weight"]),       # [K, W]
-                "dt_bias": m["dt_bias"], "A_log": m["A_log"],
-                "norm": m["norm"],
-                "out_proj": m["out_proj"]["kernel"],
-            }
-        else:
-            attn = lp["self_attn"]
-            qg = heads(attn["q_proj"]["kernel"], H)              # [hid, H, 2D]
-            wq = qg[..., :D][..., turn]
-            wk = heads(attn["k_proj"]["kernel"],
-                       config.num_key_value_heads)[..., turn]
-            out.update(
-                wq=wq.reshape(wq.shape[0], -1),
-                wg=qg[..., D:].reshape(qg.shape[0], -1),
-                wk=wk.reshape(wk.shape[0], -1),
-                wv=attn["v_proj"]["kernel"], wo=attn["o_proj"]["kernel"],
-                q_norm=attn["q_norm"]["weight"][turn],
-                k_norm=attn["k_norm"]["weight"][turn])
-        return out
-
-    stacks = _stack_units(spec, layer)
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": stacks if spec.layer_kinds is not None else stacks[0],
-        "final_norm": {"scale": params["norm"]["weight"]},
-        "lm_head": params["lm_head"]["kernel"],
-    }
-    return spec, weights
-
-
-def adapt_zaya(params: Dict, config,
-               max_context: Optional[int] = None
-               ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/zaya.py param tree (ZayaForCausalLM; Zyphra ZAYA1, ``zaya``),
-    published layout. Every layer is of one kind, :class:`CcaKind` over
-    routed experts, so the scalar fields say it (``spec.cca``, ``spec.moe``
-    with ``"router": "mlp"``).
-
-    - the four compressed projections become ONE matrix ``cca.in_proj``,
-      columns ``[qp | kp | z | v1]``: the first ``tail_channels`` are what a
-      sequence keeps a tail of (q and k of every head for the convolutions,
-      ``z`` for the shifted value), the token's own value last;
-    - the depthwise taps are stored ``[tap, channel]`` and the grouped
-      convolution's ``[tap, head, in, out]`` (PyTorch: ``[out, in, tap]``);
-    - the rotation pairs value ``i`` with ``i + rotary_dim / 2`` where the
-      ragged path's pairs ``2i`` with ``2i + 1``: the first ``rotary_dim``
-      channels of each q and k head are interleaved, the same way in the
-      projections, both convolutions' weights and biases (the q-k mean is
-      channel by channel and the norm does not see the order), which leaves
-      every ``q . k`` as it was. The tail pool holds the channels in that
-      order (:func:`zaya_channel_order`);
-    - the router's state scale ``gamma`` of the FIRST layer is zero: its
-      router is handed a state of zeros and adds ``gamma * 0``, which is the
-      published "every layer but the first" without a layer of another
-      shape."""
-    del max_context
-    H, Hk, D = (config.num_attention_heads, config.num_key_value_heads,
-                config.head_dim)
-    C, K0, K1 = config.conv_dim, config.cca_time0, config.cca_time1
-    E = config.num_experts
-    spec = RaggedModelSpec(
-        family="zaya",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=H, num_kv_heads=Hk, head_dim=D,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
-        rotary_dim=config.rotary_dim, tied_lm_head=True,
-        eps=config.rms_norm_eps,
-        moe={"num_experts": E, "top_k": 1, "router": "mlp",
-             "router_hidden": config.router_hidden_size, "skip": True},
-        cca={"time0": K0, "time1": K1, "conv_dim": C,
-             "tail_channels": C + D, "taps": config.tail_taps},
-        dtype=config.dtype)
-    turn = zaya_channel_order(H + Hk, D, config.rotary_dim)     # [C]
-    turn_d = turn[:D]
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        attn, ff = lp["self_attn"], lp["mlp"]
-        w1 = attn["conv1_weight"].reshape(C // D, D, D, K1)   # h, out, in, tap
-        gamma = ff["router_state_scale"]
-        return {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "res_scale": lp["residual_scale"],
-            "res_bias": lp["residual_bias"],
-            "cca": {
-                "in_proj": jnp.concatenate(
-                    [attn["q_proj"]["kernel"][:, turn[:H * D]],
-                     attn["k_proj"]["kernel"][:, turn[H * D:] - H * D],
-                     attn["v_prev_proj"]["kernel"],
-                     attn["v_proj"]["kernel"]], axis=1),
-                "conv0_w": jnp.transpose(attn["conv0_weight"][turn]),
-                "conv0_b": attn["conv0_bias"][turn],
-                "conv1_w": jnp.transpose(
-                    w1[:, turn_d][:, :, turn_d], (3, 0, 2, 1)),
-                "conv1_b": attn["conv1_bias"][turn],
-                "temp": attn["temp"],
-            },
-            "wo": attn["o_proj"]["kernel"],
-            "moe": {
-                "router_down": ff["router_down"]["kernel"],
-                "router_down_b": ff["router_down"]["bias"],
-                "router_gamma": gamma if i else jnp.zeros_like(gamma),
-                "router_norm": ff["router_norm"]["weight"],
-                "router_fc1": ff["router_fc1"]["kernel"],
-                "router_fc1_b": ff["router_fc1"]["bias"],
-                "router_fc2": ff["router_fc2"]["kernel"],
-                "router_fc2_b": ff["router_fc2"]["bias"],
-                "router_out": ff["router_out"]["kernel"],
-                "router_bias": ff["balancing_bias"],
-                "w_gate": ff["w_gate"], "w_up": ff["w_up"],
-                "w_down": ff["w_down"],
-            },
-        }
-
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": _stack([layer(i) for i in range(config.num_hidden_layers)]),
-        "final_norm": {"scale": params["norm"]["weight"]},
-    }
-    return spec, weights
-
-
-def adapt_brumby(params: Dict, config,
-                 max_context: Optional[int] = None
-                 ) -> Tuple[RaggedModelSpec, Dict]:
-    """models/brumby.py param tree (BrumbyForCausalLM; Manifest AI Brumby,
-    ``brumby``), published layout. Every layer is of one kind,
-    :class:`PowerKind` over a dense SwiGLU: the model holds NO pages
-    (``num_page_layers`` 0), and a sequence's device state is its slot of the
-    state pool, ``N x D`` float32 a layer (``spec.mamba`` under ``"kind":
-    "pr"``; ``ops/pallas/power_retention.py`` gives the layout).
-
-    The rotation pairs value ``i`` with ``i + d / 2`` where the ragged path's
-    pairs ``2i`` with ``2i + 1``: each q and k head's columns (and their
-    norms' gains) are interleaved, the same way in both, which leaves every
-    ``q . k`` — all the layer reads of them — as it was."""
-    del max_context
-    H, Hk, D = (config.num_attention_heads, config.num_key_value_heads,
-                config.head_dim)
-    spec = RaggedModelSpec(
-        family="brumby",
-        num_layers=config.num_hidden_layers,
-        hidden_size=config.hidden_size,
-        num_heads=H, num_kv_heads=Hk, head_dim=D,
-        vocab_size=config.vocab_size,
-        norm="rms", activation="swiglu", rope_theta=config.rope_theta,
-        tied_lm_head=False, eps=config.rms_norm_eps, dtype=config.dtype,
-        mamba={"kind": "pr", "d_inner": pr_state_cols(D),
-               "d_state": pr_state_rows(Hk, D), "d_conv": 1,
-               "chunk": config.chunk_size, "eps": config.retention_eps})
-    turn = np.arange(D).reshape(2, D // 2).T.reshape(-1)
-    heads = lambda x, n: x.reshape(x.shape[0], n, D)[..., turn].reshape(
-        x.shape)
-
-    def layer(i):
-        lp = params[f"layers_{i}"]
-        attn, ff = lp["self_attn"], lp["mlp"]
-        return {
-            "ln1": {"scale": lp["input_layernorm"]["weight"]},
-            "ln2": {"scale": lp["post_attention_layernorm"]["weight"]},
-            "pr": {"wq": heads(attn["q_proj"]["kernel"], H),
-                   "wk": heads(attn["k_proj"]["kernel"], Hk),
-                   "wv": attn["v_proj"]["kernel"],
-                   "wg": attn["g_proj"]["kernel"], "g_bias": attn["g_bias"],
-                   "q_norm": attn["q_norm"]["weight"][turn],
-                   "k_norm": attn["k_norm"]["weight"][turn],
-                   "wo": attn["o_proj"]["kernel"]},
-            "mlp": {"w_gate": ff["gate_proj"]["kernel"],
-                    "w_up": ff["up_proj"]["kernel"],
-                    "w_down": ff["down_proj"]["kernel"]},
-        }
-
-    weights = {
-        "embed": params["embed_tokens"]["embedding"],
-        "layers": _stack([layer(i) for i in range(config.num_hidden_layers)]),
-        "final_norm": {"scale": params["norm"]["weight"]},
-        "lm_head": params["lm_head"]["kernel"],
-    }
-    return spec, weights
-
-
-def zaya_channel_order(heads: int, head_dim: int, rotary_dim: int
-                       ) -> np.ndarray:
-    """For each channel of ``heads`` heads of ``head_dim`` as
-    :func:`adapt_zaya` lays them out, the published channel it holds: inside
-    a head's first ``rotary_dim`` values, half-split pairs (``i``, ``i +
-    rotary_dim / 2``) become neighbours (``2i``, ``2i + 1``)."""
-    turn = np.concatenate([
-        np.arange(rotary_dim).reshape(2, rotary_dim // 2).T.reshape(-1),
-        np.arange(rotary_dim, head_dim)])
-    return (np.arange(heads)[:, None] * head_dim + turn[None]).reshape(-1)
-
-
-ADAPTERS: Dict[str, Callable] = {
-    # llama lineage (qwen2 = biased qkv; gemma = structural flags — both are
-    # LlamaConfig features the adapter reads)
-    "llama": adapt_llama,
-    "mistral": adapt_llama,
-    "mixtral": adapt_llama,
-    "qwen2": adapt_llama,
-    "gemma": adapt_llama,
-    "gpt2": adapt_gpt2,
-    # generic-decoder lineage (canonical param names; re-root + stack)
-    "opt": adapt_decoder,
-    "falcon": adapt_decoder,
-    "phi": adapt_decoder,
-    "gpt_neox": adapt_decoder,
-    "gptj": adapt_decoder,
-    "gpt_bigcode": adapt_decoder,
-    "bloom": adapt_decoder,   # ALiBi carried by the paged kernels
-    # layers of several kinds in one model (window+rotary / full without
-    # positions; dense / MoE), gated attention, sigmoid router, shared expert
-    "afmoe": adapt_afmoe,
-    # Mamba state-space layers beside a few attention layers: a state pool
-    # beside the pages (ragged/state_pool.py)
-    "jamba": adapt_jamba,
-    # latent attention (MLA): pages of one latent row a token, no head axis
-    # (ragged_mla.py); a sigmoid router over experts of which this chip may
-    # hold a share
-    "joyai": adapt_joyai,
-    # the same with a learned selection: an indexer a layer, an index-key
-    # pool beside the latent pages, attention over the top-k chosen
-    "glm_dsa": adapt_glm_dsa,
-    # Mamba-2 (SSD) layers — a matrix state per head in the same pool —
-    # beside a few no-position GQA layers, every FFN routed experts (of which
-    # this chip may hold a share) plus a shared MLP; four plain multipliers
-    "granite": adapt_granite,
-    # one block a layer (Mamba-2 with groups of B and C, OR attention, OR
-    # two-matrix relu2 experts behind a sigmoid router): BlockKind, and the
-    # layer loop scans repeating units of the pattern (layer_units)
-    "nemotron_h": adapt_nemotron_h,
-    # Gated DeltaNet layers (a delta-rule state in the same pool: DeltaKind,
-    # _gdn_mixer) beside gated attention with 256-wide heads, a quarter of
-    # each rotated; 512 small experts of which this chip may hold a share,
-    # and a shared expert behind a sigmoid gate
-    "qwen3_next": adapt_qwen3_next,
-    # compressed convolutional attention (pages AND a convolution tail in
-    # every layer: CcaKind, _cca_project), a top-1 MLP router whose state
-    # goes from layer to layer, a choice that skips the experts, learned
-    # scales and biases where a branch joins the stream
-    "zaya": adapt_zaya,
-    # power retention in every layer (a gated degree-2 linear-attention state
-    # and its normaliser in the state pool: PowerKind, _pr_mixer), q and k
-    # normed and rotated in front of it; no layer holds pages
-    "brumby": adapt_brumby,
-}
-
-#: families whose attention needs a bias the ragged kernels don't carry —
-#: serve these through the v1 dense engine instead
-_UNSUPPORTED = {
-    # gpt_neo scores attention WITHOUT the 1/sqrt(head_dim) factor
-    # (attn_scale=1.0), which adapt_decoder does not map onto
-    # ``spec.attn_scale`` (the paged kernels take one since PR 39). Its alternating
-    # global/local layers are no longer what blocks it: the spec carries a
-    # kind per layer (``layer_kinds``); adapt_decoder does not map
-    # ``attention_layers`` onto them yet
-    "gpt_neo": "unscaled attention scores (attn_scale)",
-}
-
-
-def adapt_model(family: str, params: Dict, config,
-                max_context: Optional[int] = None) -> Tuple[RaggedModelSpec, Dict]:
-    if family in _UNSUPPORTED:
-        raise ValueError(
-            f"family '{family}' uses {_UNSUPPORTED[family]}, which the ragged "
-            "(paged) attention path does not support — serve it through "
-            "deepspeed_tpu.init_inference (v1 dense engine) instead")
-    if family not in ADAPTERS:
-        raise ValueError(f"no ragged adapter for family '{family}' "
-                         f"(have {sorted(ADAPTERS)})")
-    return ADAPTERS[family](params, config, max_context=max_context)
 
 
 # --------------------------------------------------------------------------- #
@@ -1724,24 +302,6 @@ def moe_grouped_kernel(stack, dtype) -> str:
     if K * N * 2 > GROUPED_PALLAS_MATRIX_BYTES:
         return "xla"
     return "pallas"
-
-
-def _pad_expert_width(w_up: jax.Array, w_down: jax.Array):
-    """Two-matrix experts ``[E, hid, F]``, ``[E, F, hid]`` with ``F`` padded
-    with zeros to whole 128-lane tiles (nemotron_h: 1856 -> 1920, 3.4% more
-    bytes). The result is the same — ``act(0) = 0`` for every plain
-    activation here but gelu's, whose 0 it is too, and a zero row of
-    ``w_down`` adds nothing — and both grouped kernels need it: the chip
-    lays a ``[.., 2688, 1856]`` array out with 2688 on the lanes (no padding
-    that way), so a kernel that wants rows of 1856 is first handed a
-    transposed COPY of the whole stack (1.2 GiB a two-layer unit; compile,
-    PR 42), and XLA's ``ragged_dot`` reads the unpadded matrices at 87 GB/s
-    (chip table, PR 42)."""
-    pad = -w_up.shape[-1] % 128
-    if not pad:
-        return w_up, w_down
-    return (jnp.pad(w_up, ((0, 0), (0, 0), (0, pad))),
-            jnp.pad(w_down, ((0, 0), (0, pad), (0, 0))))
 
 
 def moe_route(x: jax.Array, w: Dict, top_k: int,
@@ -2838,14 +1398,6 @@ def _cca_project(spec: "RaggedModelSpec", w, u, positions, conv, l,
     return q, k, v, conv
 
 
-def latent_width(spec: "RaggedModelSpec") -> int:
-    """Values of one latent row in the pool (``spec.mla``): the latent and
-    the rotary key, padded to whole lane tiles."""
-    from deepspeed_tpu.ops.pallas.mla_attention import latent_row_width
-    return latent_row_width(spec.mla["kv_lora_rank"],
-                            spec.mla["qk_rope_head_dim"])
-
-
 def _mla_project(spec: "RaggedModelSpec", w, h1, positions):
     """The projections of latent attention on the normed rows ``h1``:
     ``(q_nope [N, H, nope], q_rope [N, H, rope] rotated, the rows' latent
@@ -2879,12 +1431,6 @@ def _mla_project(spec: "RaggedModelSpec", w, h1, positions):
         return (q[..., :dn], q_rope, lat) + _index_project(
             spec, w["index"], h1, cq, positions)
     return q[..., :dn], q_rope, lat
-
-
-def index_width(spec: "RaggedModelSpec") -> int:
-    """Values of one index key in its pool (``spec.mla["index"]``): the
-    indexer's head width in whole lane tiles."""
-    return -(-spec.mla["index"]["head_dim"] // 128) * 128
 
 
 def _index_project(spec: "RaggedModelSpec", wi, h1, cq, positions):
@@ -3945,7 +2491,6 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
     engine's continuation refs).
     """
     if num_state_layers(spec):
-        from deepspeed_tpu.inference.v2.scheduler import STATE_SNAPSHOT_MSG
         raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
             what="the speculative verify step (rejected drafts have already "
             "advanced the state; rolling back needs the state before them)"))

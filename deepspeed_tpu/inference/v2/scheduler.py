@@ -19,6 +19,7 @@ from typing import Dict, List, Optional, TYPE_CHECKING, Tuple
 
 import numpy as np
 
+from deepspeed_tpu.inference.v2.attention import STATE_SNAPSHOT_MSG
 from deepspeed_tpu.inference.v2.config_v2 import DSStateManagerConfig
 from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
@@ -26,15 +27,6 @@ from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
 from deepspeed_tpu.inference.v2.ragged.state_pool import StateSlotAllocator
 from deepspeed_tpu.monitor.trace import tracer as _tracer
-
-#: what every feature that needs a copy of a sequence's recurrent state at
-#: some earlier position is refused with (docs/SERVING.md "State-space layers")
-STATE_SNAPSHOT_MSG = (
-    "{what} is not wired for a model with state-space (Mamba) layers: a "
-    "layer's recurrent state is one fixed-size value per sequence that every "
-    "token overwrites, so pages of an earlier position have no state to go "
-    "with them — it takes a snapshot of the state at a block boundary, which "
-    "no program writes")
 
 if TYPE_CHECKING:  # avoid an import cycle at runtime
     from deepspeed_tpu.inference.v2.prefix_cache import RadixPrefixCache

@@ -43,6 +43,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from deepspeed_tpu.inference.v2.attention import (INDEX_POOL_MSG,
+                                                  STATE_SNAPSHOT_MSG)
 from deepspeed_tpu.inference.v2.config_v2 import ServingConfig
 from deepspeed_tpu.inference.v2.serving.admission import AdmissionController
 from deepspeed_tpu.inference.v2.serving.kv_offload import KVOffloadManager
@@ -259,14 +261,11 @@ class ServingFrontend:
                 "preemption='none'")
         if cfg.preemption == "offload" \
                 and engine.scheduler.state_slots is not None:
-            from deepspeed_tpu.inference.v2.scheduler import (
-                STATE_SNAPSHOT_MSG)
             raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
                 what="preemption='offload' (pages go to the host, the state "
                 "would not; run 'recompute' or 'none')"))
         if cfg.preemption == "offload" \
                 and engine.kv.config.index_dim is not None:
-            from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG
             raise NotImplementedError(INDEX_POOL_MSG.format(
                 what="preemption='offload' (run 'recompute' or 'none')"))
         if cfg.preemption == "recompute" and getattr(engine, "lora", None) \

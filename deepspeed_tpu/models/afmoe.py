@@ -6,7 +6,7 @@ layers that carry NO position embedding, a few leading dense SwiGLU layers
 before the sparse ones, and a sigmoid-scored router over many small experts
 with a selection bias, sum-normalised weights, a scale and an always-on
 shared expert. The serving path is ``inference/v2`` through ``adapt_afmoe``
-(``ragged_model.py``); this module gives the parameter tree (``init``) and a
+(``adapters/afmoe.py``); this module gives the parameter tree (``init``) and a
 plain dense forward the tests hold the engine and the benchmark's reference
 to.
 

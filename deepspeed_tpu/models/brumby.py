@@ -8,7 +8,7 @@ Gelada, Zhang, *Scaling Context Requires Rethinking Attention*, 2025, and
 Manifest AI's release note for Brumby-14B-Base, 2025-10): linear attention
 whose feature map is the degree-``p`` tensor power of the key, so that a
 layer's memory of a sequence is a state of fixed size. The serving path is
-``inference/v2`` through ``adapt_brumby`` (``ragged_model.py``), where a
+``inference/v2`` through ``adapt_brumby`` (``adapters/brumby.py``), where a
 layer keeps a slot of the state pool a sequence and the model holds no
 pages; this module gives the parameter tree (``init``) in the published
 layout and a plain dense forward in the attention form.

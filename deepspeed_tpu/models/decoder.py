@@ -7,7 +7,7 @@ the families differ only in a handful of structural flags (norm type, activation
 rotary fraction vs learned positions, parallel residual, biases), so the zoo
 carries ONE flax module — :class:`DecoderLM` — specialised by
 :class:`DecoderConfig` classmethods, with canonical parameter names (``wq``,
-``mlp/w_up``...) shared with the v2 ragged adapter (``inference/v2/ragged_model``).
+``mlp/w_up``...) shared with the v2 ragged adapter (``inference/v2/adapters``).
 
 Family structural facts encoded here:
   - **OPT**: pre-LN, learned positions offset by 2, ReLU MLP, biases everywhere,

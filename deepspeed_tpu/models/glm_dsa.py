@@ -24,7 +24,7 @@ Layer equations, on the normed input ``h`` of position ``t``
 - softmax attention over those positions only.
 
 The serving path is ``inference/v2`` through ``adapt_glm_dsa``
-(``ragged_model.py``) and the kernels of ``ops/pallas/sparse_mla.py``; this
+(``adapters/joyai.py``) and the kernels of ``ops/pallas/sparse_mla.py``; this
 module gives the parameter tree (``init``) and a plain dense forward. Left
 out, as the bfloat16 serving path leaves them out: the float8 storage of
 index keys and the Hadamard rotation of the published inference code (an

@@ -4,7 +4,7 @@ The family is here for its mixer: most layers are **Mamba-2** (SSD) blocks
 whose state is a matrix per head, beside a few layers of grouped-query
 attention with NO position term of any kind; every layer's feed-forward is a
 mixture of routed experts plus a shared SwiGLU. The serving path is
-``inference/v2`` through ``adapt_granite`` (``ragged_model.py``); this module
+``inference/v2`` through ``adapt_granite`` (``adapters/granite``); this module
 gives the parameter tree (``init``) and a plain dense forward.
 
 Layer equations (``chipbench/reference/granite_ref.py`` states them once more,

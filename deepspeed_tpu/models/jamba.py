@@ -5,7 +5,7 @@ at all. A layer's mixer is a Mamba-1 selective state-space block, except
 every ``attn_layer_period``-th layer (from ``attn_layer_offset``), which is
 causal attention with NO position embedding of any kind; every layer's
 feed-forward is the dense SwiGLU (``num_experts: 1``). The serving path is
-``inference/v2`` through ``adapt_jamba`` (``ragged_model.py``), where a
+``inference/v2`` through ``adapt_jamba`` (``adapters/jamba.py``), where a
 Mamba layer keeps a fixed-size state per sequence beside the paged keys and
 values of the attention layers; this module gives the parameter tree
 (``init``) and a plain dense forward.

@@ -8,7 +8,7 @@ feed-forward: a sigmoid router over many small experts (``noaux_tc`` with
 one group: choose with ``e_score_correction_bias`` added, weigh without it,
 over the chosen scores' sum, times ``routed_scaling_factor``) with a shared
 expert, behind one leading dense layer. The serving path is ``inference/v2``
-through ``adapt_joyai`` (``ragged_model.py``); this module gives the
+through ``adapt_joyai`` (``adapters/joyai.py``); this module gives the
 parameter tree (``init``) and a plain dense forward in the EXPANDED form the
 tests hold the engine to.
 

@@ -6,7 +6,7 @@ The family is here for its layers: each is ONE block, ``x = x + block(norm(x))``
 expert (``E``), OR grouped-query attention (``*``) — in the order
 ``hybrid_override_pattern`` gives, where every other hybrid of the zoo pairs
 a mixer with a feed-forward in each layer. The serving path is
-``inference/v2`` through ``adapt_nemotron_h`` (``ragged_model.py``); this
+``inference/v2`` through ``adapt_nemotron_h`` (``adapters/nemotron_h``); this
 module gives the parameter tree (``init``) and a plain dense forward.
 
 Layer equations (``chipbench/reference/nemotron_h_ref.py`` states them once

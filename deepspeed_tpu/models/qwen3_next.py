@@ -6,7 +6,7 @@ corrected by a delta rule — and the fourth is softmax attention with an
 output gate, 256-wide heads and a quarter of each head rotated; every layer's
 feed-forward is a mixture of 512 small experts, top-10, beside a shared
 expert behind a sigmoid gate. The serving path is ``inference/v2`` through
-``adapt_qwen3_next`` (``ragged_model.py``); this module gives the parameter
+``adapt_qwen3_next`` (``adapters/qwen3_next``); this module gives the parameter
 tree in the published layout (``init``) and a plain dense forward.
 
 Layer equations (``chipbench/reference/qwen3_next_ref.py`` states them once

@@ -7,7 +7,7 @@ attend, and one of the two value heads is the PREVIOUS token's) and a
 **router that is an MLP with a state**: a 256-wide stream that every layer's
 router adds to and hands to the next layer's, choosing one of 16 experts or
 none at all. The serving path is ``inference/v2`` through ``adapt_zaya``
-(``ragged_model.py``); this module gives the parameter tree in the published
+(``adapters/zaya.py``); this module gives the parameter tree in the published
 layout (``init``) and a plain dense forward.
 
 Layer equations (``chipbench/reference/zaya_ref.py`` states them once more,

@@ -80,10 +80,9 @@ def _flash_over_mesh(q, k, v, causal, segment_ids, softmax_scale):
             or jax.sharding.get_abstract_mesh().manual_axes):
         return local(q, k, v)
     from jax.sharding import PartitionSpec as P
-    from deepspeed_tpu.utils.jax_compat import shard_map
     rows = P(BATCH_AXES, None, TENSOR_AXIS, None)
-    return shard_map(local, mesh=topo.mesh, in_specs=(rows,) * 3,
-                     out_specs=rows, check_vma=False)(q, k, v)
+    return jax.shard_map(local, mesh=topo.mesh, in_specs=(rows,) * 3,
+                         out_specs=rows, check_vma=False)(q, k, v)
 
 
 def reference_attention(q, k, v, causal=False, bias=None, segment_ids=None,

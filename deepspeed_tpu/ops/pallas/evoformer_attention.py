@@ -33,10 +33,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas import _backend
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 NEG_INF = -1e30
 

@@ -23,10 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas import _backend
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 # Re-tuned on v5e-1 (B=64/T=1024 and B=16/T=2048, H=16, D=64, causal,
 # fwd+bwd): 1024/1024 beats 512/512 by ~23% and ~6% respectively — the larger

@@ -65,13 +65,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.ops.pallas.ssm import (LANES, TAP_ROWS, _per_channel,
                                           _tail_rows)
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: tokens of one chunk of :func:`gdn_chunk_scan`: the largest of these at or

@@ -37,11 +37,10 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _backend
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 # what one rhs block may take of on-chip memory (it is double-buffered): a
 # whole 3-4 MiB expert matrix fits, and a larger one is cut along N, then K

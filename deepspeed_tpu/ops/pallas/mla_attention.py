@@ -46,13 +46,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.ops.pallas.paged_attention import (
     NEG_INF, _flash_update, _row_group)
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 LANES = 128
 #: pages a chunk of the kernel's loop. Both products of a chunk run over all

@@ -66,16 +66,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas import _backend
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
 from deepspeed_tpu.ops.pallas.paged_attention import (
     NEG_INF, _alibi_slope, _chunk_mask, _colscale_pages, _flash_update,
     _kv_flat, _pick_pages_per_chunk, _scale_tile_rows,
     _scales_to_tiles, _step_write_rows, kv_quantize_rows,
     paged_chunk_attention_batched, paged_decode_attention)
-
-pltpu = import_pltpu()
 
 
 # --------------------------------------------------------------------- #

@@ -76,11 +76,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _backend
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: tokens of one chunk of :func:`pr_chunk_scan`: the largest of these at or

@@ -43,10 +43,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.pallas import _backend
-from deepspeed_tpu.utils.jax_compat import import_pltpu
-
-pltpu = import_pltpu()
 
 
 def quantize_weight_int8(w: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -46,13 +46,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas import _backend
 from deepspeed_tpu.ops.pallas.mla_attention import _pick_rows_block
 from deepspeed_tpu.ops.pallas.paged_attention import NEG_INF, _flash_update
-from deepspeed_tpu.utils.jax_compat import import_pltpu
 
-pltpu = import_pltpu()
 
 LANES = 128
 #: keys a tile of a chunk's scores: the attention kernel's chunk of pages

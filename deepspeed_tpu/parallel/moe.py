@@ -224,7 +224,6 @@ def dropless_moe_ep(tokens: jax.Array, gate_logits: jax.Array, k: int,
     applies the local experts' FFN to expert-sorted rows.
     Returns (out [N, D] replicated over 'expert', l_aux).
     """
-    from deepspeed_tpu.utils.jax_compat import shard_map
     N, D = tokens.shape
     E = gate_logits.shape[-1]
     assert E % ep == 0, (E, ep)
@@ -257,7 +256,7 @@ def dropless_moe_ep(tokens: jax.Array, gate_logits: jax.Array, k: int,
 
     ws_specs = tuple(P(EXPERT_AXIS, *([None] * (w.ndim - 1)))
                      for w in expert_ws)
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh, axis_names={EXPERT_AXIS},
         in_specs=(P(), P(), P()) + ws_specs,
         out_specs=P())(tokens, top_w, top_e, *expert_ws)

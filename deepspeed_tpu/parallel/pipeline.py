@@ -43,7 +43,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.comm.mesh import PIPE_AXIS, get_topology
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 
 def partition_balanced(weights: Sequence[float], n_parts: int) -> List[int]:
@@ -195,7 +194,7 @@ def gpipe_apply(block_fn: Callable[[Any, jax.Array], jax.Array],
             axis_name)
         return out_full
 
-    f = shard_map(
+    f = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(P(PIPE_AXIS), P()),
         out_specs=P(),
@@ -254,7 +253,7 @@ def hetero_gpipe_apply(stage_fns: Sequence[Callable[[Any, jax.Array, jax.Array],
             axis_name)
         return out_full
 
-    f = shard_map(
+    f = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(P(), P(), P()),
         out_specs=P(),

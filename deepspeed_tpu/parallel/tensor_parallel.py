@@ -69,7 +69,7 @@ DECODER_TP_RULES: List[Tuple[str, str]] = [
     (r"lm_head", COLUMN),
 ]
 
-# canonical *stacked* ragged-model weights (inference/v2/ragged_model.py): layer
+# canonical *stacked* ragged-model weights (inference/v2/adapters/): layer
 # kernels carry a leading [L] (and MoE an [E]) dim, which COLUMN (last dim) / ROW
 # (second-to-last) already handle; embeddings/norms/router replicate (no rule)
 RAGGED_STACKED_TP_RULES: List[Tuple[str, str]] = [

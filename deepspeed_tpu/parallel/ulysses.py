@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.comm.mesh import BATCH_AXES, SEQ_AXIS, get_topology
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 
 def ulysses_attention(attn_fn: Callable, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -105,7 +104,7 @@ def sequence_parallel_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                      softmax_scale=softmax_scale)
 
     dist_attn = DistributedAttention(_local)
-    fn = shard_map(
+    fn = jax.shard_map(
         dist_attn, mesh=mesh,
         in_specs=(P(BATCH_AXES, SEQ_AXIS, None, None),) * 3,
         out_specs=P(BATCH_AXES, SEQ_AXIS, None, None),
@@ -144,7 +143,7 @@ def context_parallel_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return ring_attention(q, k, v, causal=causal,
                               softmax_scale=softmax_scale)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(BATCH_AXES, SEQ_AXIS, None, None),) * 3,
         out_specs=P(BATCH_AXES, SEQ_AXIS, None, None),

@@ -41,7 +41,7 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 import jax
 
 from deepspeed_tpu.checkpoint.state import flatten_tree
-from deepspeed_tpu.inference.v2.ragged_model import adapt_model
+from deepspeed_tpu.inference.v2.adapters import adapt_model
 from deepspeed_tpu.monitor.trace import tracer as _tracer
 from deepspeed_tpu.monitor.training import RolloutStats
 from deepspeed_tpu.runtime.data_pipeline import PrefetchLoader

@@ -356,7 +356,6 @@ def _gather_wave(plan: Zero3Plan, wave: Zero3Wave, ptrees: Dict[str, Any],
     bucket — differentiating through this function w.r.t. the sharded leaves
     yields the bucketed reduce-scatter of their grads.
     """
-    from ...utils.jax_compat import shard_map
 
     leaves = [ptrees[lp.layer] for lp in wave.leaves]
     for i, lp in enumerate(wave.leaves):
@@ -374,7 +373,7 @@ def _gather_wave(plan: Zero3Plan, wave: Zero3Wave, ptrees: Dict[str, Any],
         n_shards = 1
         for a in axes:
             n_shards *= mesh.shape[a]
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_fused_allgather, plans=plans,
                               n_shards=n_shards, axes=axes),
             mesh=mesh,

@@ -8,7 +8,6 @@ JL002  constant PRNG keys baked into library code
 JL003  donated-buffer reuse after a ``donate_argnums`` call
 JL004  Python control flow on tracer values inside a jitted body
 JL005  PartitionSpec/collective axis names no Mesh declares
-JL006  raw imports that bypass the ``utils/jax_compat`` shim layer
 JL007  blocking host fetches inside configured hot-path modules
 JL008  tracer spans enclosing a blocking fetch in hot-path modules
 ====== ==============================================================
@@ -623,58 +622,6 @@ class HotPathHostFetch(Rule):
                     f".{node.func.attr}() forces a device->host transfer in "
                     "a hot-path module — drain through fetch_to_host (or "
                     "suppress if the receiver is host data)")
-
-
-# --------------------------------------------------------------------------- #
-# JL006 — compat-shim bypass
-# --------------------------------------------------------------------------- #
-
-@register
-class CompatShimBypass(Rule):
-    """Raw imports of surfaces ``utils/jax_compat`` exists to version-shim.
-
-    ``jax.experimental.shard_map`` (renamed kwargs across versions),
-    ``from jax import shard_map`` (only exists on new jax — or via the shim's
-    monkey-patch), and raw ``jax.experimental.pallas.tpu`` (CompilerParams
-    renamed) must route through ``deepspeed_tpu.utils.jax_compat``
-    (``shard_map`` / ``import_pltpu``) so one source tree runs on every
-    supported jax."""
-
-    rule_id = "JL006"
-    summary = "raw import bypasses the utils/jax_compat version shims"
-    default_options = {
-        # path substrings allowed to touch the raw surfaces (the shim itself)
-        "allow_paths": ["utils/jax_compat.py", "tools/jaxlint/"],
-    }
-
-    def check(self, mod, options):
-        norm = mod.path.replace("\\", "/")
-        if any(pat in norm for pat in options["allow_paths"]):
-            return
-        for node in ast.walk(mod.tree):
-            bad: Optional[str] = None
-            if isinstance(node, ast.Import):
-                for alias in node.names:
-                    if alias.name.startswith("jax.experimental.shard_map"):
-                        bad = "import jax.experimental.shard_map"
-                    elif alias.name.startswith("jax.experimental.pallas.tpu"):
-                        bad = "import jax.experimental.pallas.tpu"
-            elif isinstance(node, ast.ImportFrom):
-                names = {a.name for a in node.names}
-                if node.module == "jax.experimental.shard_map":
-                    bad = "from jax.experimental.shard_map import ..."
-                elif node.module == "jax.experimental" and "shard_map" in names:
-                    bad = "from jax.experimental import shard_map"
-                elif node.module == "jax.experimental.pallas" and "tpu" in names:
-                    bad = "from jax.experimental.pallas import tpu"
-                elif node.module == "jax" and "shard_map" in names:
-                    bad = "from jax import shard_map"
-            if bad:
-                fix = "import_pltpu()" if "pallas" in bad else "shard_map"
-                yield Finding(
-                    self.rule_id, mod.path, node.lineno, node.col_offset,
-                    f"{bad} bypasses the version shims — use "
-                    f"deepspeed_tpu.utils.jax_compat.{fix}")
 
 
 # --------------------------------------------------------------------------- #

@@ -15,9 +15,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
+from deepspeed_tpu.inference.v2.adapters import adapt_model  # noqa: E402
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
-from deepspeed_tpu.inference.v2.ragged_model import (  # noqa: E402
-    LayerKind, RaggedModelSpec, _scan_layers, adapt_model, layer_runs)
+from deepspeed_tpu.inference.v2.model_spec import (  # noqa: E402
+    LayerKind, RaggedModelSpec, layer_runs)
+from deepspeed_tpu.inference.v2.ragged_model import _scan_layers  # noqa: E402
 from deepspeed_tpu.models.afmoe import (FULL, SLIDING, AfmoeConfig,  # noqa: E402
                                         AfmoeForCausalLM)
 
@@ -294,6 +296,5 @@ def test_config_derives_layer_types_and_refuses_a_bad_list():
 
 
 def test_gpt_neo_refusal_names_what_still_blocks_it():
-    from deepspeed_tpu.inference.v2.ragged_model import adapt_model
     with pytest.raises(ValueError, match="unscaled attention scores"):
         adapt_model("gpt_neo", {}, None)

@@ -21,7 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm  # noqa: E402
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.models.brumby import (BrumbyConfig,  # noqa: E402
                                          BrumbyForCausalLM)
@@ -218,8 +218,8 @@ def test_no_page_but_the_scratch_page_and_no_block_funded(served):
     empty whatever it has seen, and ``kv_cache.num_blocks`` (64 in the
     engine's config) is not read."""
     eng = served[0]
-    assert rm.num_page_layers(eng.spec) == 0
-    assert rm.num_state_layers(eng.spec) == 2
+    assert ms.num_page_layers(eng.spec) == 0
+    assert ms.num_state_layers(eng.spec) == 2
     assert eng.kv.kv.pages.shape[:2] == (1, 1) and eng.scratch_block == 0
     assert eng.allocator.total_blocks == 0 and eng.scheduler.pageless
     seq = eng.scheduler.seqs[1]
@@ -287,20 +287,20 @@ def test_the_spec_says_a_rotated_state_layer(served):
     """To the pools a state layer, to the layer loop one that rotates: the
     kind, the pool it addresses, the one scanned unit, the set-up line."""
     spec = served[0].spec
-    kind = rm.PowerKind()
+    kind = ms.PowerKind()
     assert (kind.mamba, kind.rope, kind.moe, kind.window, kind.tail) == (
         True, True, False, None, False)
-    assert rm._holds(kind) == "state"
-    assert rm._holds(rm.DeltaKind()) == "state" and not rm.DeltaKind().rope
+    assert ms._holds(kind) == "state"
+    assert ms._holds(ms.DeltaKind()) == "state" and not ms.DeltaKind().rope
     assert spec.layer_kinds is None and spec.mamba["kind"] == "pr"
     assert spec.rope_theta == 10000.0 and spec.mamba["d_conv"] == 1
-    assert [(len(s), l0, n) for s, l0, n in rm.layer_units(spec)] \
+    assert [(len(s), l0, n) for s, l0, n in ms.layer_units(spec)] \
         == [(1, 0, 2)]
-    assert rm.describe_layer_kinds(spec) == (
+    assert ms.describe_layer_kinds(spec) == (
         "layers 0-1: power-retention mixer (rotary; no pages), dense FFN")
     # a model of mixed kinds keeps the rotation for this kind's layers
-    mixed = rm._run_spec(rm.RaggedModelSpec(
+    mixed = ms._run_spec(ms.RaggedModelSpec(
         family="x", num_layers=2, hidden_size=8, num_heads=1, num_kv_heads=1,
         head_dim=8, vocab_size=8, mamba={"kind": "pr"},
-        layer_kinds=(kind, rm.MambaKind())), kind)
+        layer_kinds=(kind, ms.MambaKind())), kind)
     assert mixed.rope_theta is not None and mixed.mamba is not None

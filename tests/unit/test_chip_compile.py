@@ -472,7 +472,7 @@ def _mistral_7b(arr, L, weight=None):
     """Spec and stacked weight tree (shapes only) of Mistral-7B at ``L``
     layers; ``weight(L, K, N)`` makes a layer-stacked matrix (bf16 unless
     given)."""
-    from deepspeed_tpu.inference.v2.ragged_model import RaggedModelSpec
+    from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
     w = weight or (lambda *shape: arr(BF16, *shape))
     spec = RaggedModelSpec(family="llama", num_layers=L, hidden_size=HID,
                            num_heads=H, num_kv_heads=HKV, head_dim=D,
@@ -493,8 +493,8 @@ def _compile_decode_step(spec, weights, kv, rows, max_blocks, **build):
     """The fused decode step of ``rows`` rows compiled for the chip the
     shapes are on; a model with state-space layers is handed its rows'
     state slots."""
-    from deepspeed_tpu.inference.v2.ragged_model import (build_decode_step,
-                                                         num_state_layers)
+    from deepspeed_tpu.inference.v2.model_spec import num_state_layers
+    from deepspeed_tpu.inference.v2.ragged_model import build_decode_step
     arr = _on(weights["embed"].sharding)
     state = (arr(I32, rows),) if num_state_layers(spec) else ()
     return jax.jit(build_decode_step(spec, **build), donate_argnums=(1,)
@@ -580,12 +580,12 @@ def test_decode_step_loops_over_its_layers_and_nothing_else(
     one — and, inside them, the two loops of each MoE layer body that takes
     the compact path of a held share (``_COMPACT_MOE_BODIES``); its Mosaic
     calls are the family's kernels by name."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     compiled, spec, _ = compiled_step(family)
     text = compiled.as_text()
     loops = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? while\(", text, re.M)
-    assert len(loops) == sum(n > 1 for _, _, n in rm.layer_units(spec)) \
+    assert len(loops) == sum(n > 1 for _, _, n in ms.layer_units(spec)) \
         + 2 * _COMPACT_MOE_BODIES.get(family, 0), loops
     mosaic = {m.group(1) for m in re.finditer(
         r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
@@ -796,7 +796,7 @@ def _jamba2_3b(arr):
     """Spec and stacked weight trees (shapes only) of AI21-Jamba2-3B, all 28
     layers, as ``adapt_jamba`` stacks them, and its pools for 160 tracked
     sequences and ``pages`` pages."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters, model_spec as ms
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
@@ -808,17 +808,17 @@ def _jamba2_3b(arr):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_jamba(p, cfg)
+        held["spec"], w = adapters.adapt_jamba(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
         lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
     spec = held["spec"]
     spec.dtype = BF16
-    pool = StatePoolConfig(num_layers=rm.num_state_layers(spec),
+    pool = StatePoolConfig(num_layers=ms.num_state_layers(spec),
                            num_slots=160, d_inner=5120, d_state=16, d_conv=4)
     ssm_shape, conv_shape = jax.eval_shape(pool.zeros)
-    kv = StatefulKV(arr(BF16, rm.num_page_layers(spec), 2049, 2, 1, BS, D),
+    kv = StatefulKV(arr(BF16, ms.num_page_layers(spec), 2049, 2, 1, BS, D),
                     arr(ssm_shape.dtype, *ssm_shape.shape),
                     arr(conv_shape.dtype, *conv_shape.shape))
     return spec, weights, kv
@@ -853,7 +853,7 @@ def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
     (127 MiB of temporaries, twice a layer, against 4 now), and a reshape of
     the tile-exact pool to ``[.., K - 1, E]`` would do the same."""
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     if program == "serve_decode_step":
@@ -869,7 +869,7 @@ def test_jamba_programs_update_the_state_pools_in_place(program, v5e,
         compiled = jax.jit(rm.build_prefill_forward(spec), donate_argnums=(1,)
                            ).lower(weights, kv, batch).compile()
         kernel = "ssm_chunk_scan"
-    assert [n for _, _, n in rm.layer_runs(spec)] == [7, 1, 13, 1, 6]
+    assert [n for _, _, n in ms.layer_runs(spec)] == [7, 1, 13, 1, 6]
     text = compiled.as_text()
     assert kernel in text, "the state kernel is not in the program"
     moved = _state_pool_values(text, kv)
@@ -884,7 +884,7 @@ def _joyai_flash(arr, pages=700):
     """Spec and stacked weight trees (shapes only) of JoyAI-LLM-Flash, all 40
     layers at published widths with experts 0-15 of 256 held, as
     ``adapt_joyai`` stacks them, and its pool of ``pages`` latent pages."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters, model_spec as ms
     from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM
     cfg = JoyaiConfig.joyai_llm_flash(dtype=BF16, experts_held=(0, 16))
     model = JoyaiForCausalLM(cfg)
@@ -894,14 +894,14 @@ def _joyai_flash(arr, pages=700):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_joyai(p, cfg)
+        held["spec"], w = adapters.adapt_joyai(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
         lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
     spec = held["spec"]
     spec.dtype = BF16
-    return spec, weights, arr(BF16, 40, pages + 1, BS, rm.latent_width(spec))
+    return spec, weights, arr(BF16, 40, pages + 1, BS, ms.latent_width(spec))
 
 
 @pytest.mark.parametrize("program", ["serve_decode_step",
@@ -919,11 +919,11 @@ def test_joyai_programs_keep_the_latent_pool_and_the_weights_in_place(
     transposed in every layer of the decode step — and no layer's matrix is
     staged before its dot."""
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     spec, weights, kv = _joyai_flash(arr)
-    assert [n for _, _, n in rm.layer_runs(spec)] == [1, 39]
+    assert [n for _, _, n in ms.layer_runs(spec)] == [1, 39]
     assert kv.shape == (40, 701, 128, 640)
     host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=32,
                        max_blocks=80).device_arrays()
@@ -1229,7 +1229,7 @@ def _granite_stage(arr):
     small as the benchmark's configuration runs it: published layers 0-9 at
     published widths, 36 of 72 experts held (9.24 GiB of weights), 2,656
     pages and the state pool of 72 + 1 slots (2.63 GiB)."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     from deepspeed_tpu.models.granite import (GraniteConfig,
@@ -1245,7 +1245,7 @@ def _granite_stage(arr):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_granite(p, cfg)
+        held["spec"], w = adapters.adapt_granite(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
@@ -1275,11 +1275,11 @@ def test_granite_programs_update_the_state_pools_in_place(program, v5e,
     and the pass did not fit the chip; compile, PR 39), so those rows move
     one dynamic slice each."""
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     spec, weights, kv = _granite_stage(arr)
-    assert [n for _, _, n in rm.layer_runs(spec)] == [5, 1, 4]
+    assert [n for _, _, n in ms.layer_runs(spec)] == [5, 1, 4]
     rows, pages = 64, 80
     host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=rows,
                        max_blocks=pages).device_arrays()
@@ -1316,7 +1316,7 @@ def _nemotron_stage(arr):
     the vocabulary (9.84 GiB of weights), 5,256 pages over the two attention
     layers and the state pool of 144 + 1 slots over the seven Mamba layers
     (2.05 GiB)."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
@@ -1331,7 +1331,7 @@ def _nemotron_stage(arr):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_nemotron_h(p, cfg)
+        held["spec"], w = adapters.adapt_nemotron_h(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
@@ -1363,13 +1363,13 @@ def test_nemotron_programs_scan_units_and_update_the_pools_in_place(
     the step did not fit; compile, PR 42); the pools are the outputs' buffers
     and the temporaries stay small."""
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     spec, weights, kv = _nemotron_stage(arr)
-    assert [(len(s), n) for s, _, n in rm.layer_units(spec)] == [
+    assert [(len(s), n) for s, _, n in ms.layer_units(spec)] == [
         (1, 1), (1, 1), (7, 2)]
-    assert rm.num_page_layers(spec) == 2 and rm.num_state_layers(spec) == 7
+    assert ms.num_page_layers(spec) == 2 and ms.num_state_layers(spec) == 7
     rows, pages = 128, 96
     host = RaggedBatch(num_slots=4, slot_size=256, max_sequences=rows,
                        max_blocks=pages).device_arrays()
@@ -1409,7 +1409,7 @@ def _qwen3_next_stage(arr):
     vocabulary (5.46 GiB of weights), 8,000 pages over the three attention
     layers (256-wide heads, 64 values rotated) and the state pool of 72 + 1
     slots over the nine delta layers (1.34 GiB)."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
@@ -1424,7 +1424,7 @@ def _qwen3_next_stage(arr):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_qwen3_next(p, cfg)
+        held["spec"], w = adapters.adapt_qwen3_next(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
@@ -1452,11 +1452,11 @@ def test_qwen3_next_programs_hold_the_delta_kernels_and_the_pools_in_place(
     stack is copied; the pools (1.27 GiB of states, 62 MiB of tails, 5.86 GiB
     of pages) are the outputs' buffers and the temporaries stay small."""
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     spec, weights, kv = _qwen3_next_stage(arr)
-    assert rm.num_page_layers(spec) == 3 and rm.num_state_layers(spec) == 9
+    assert ms.num_page_layers(spec) == 3 and ms.num_state_layers(spec) == 9
     assert spec.head_dim == 256 and spec.rotary_dim == 64
     rows, pages, slots = 64, 264, 8
     host = RaggedBatch(num_slots=slots, slot_size=256, max_sequences=rows,
@@ -1504,7 +1504,7 @@ def _zaya_stage(arr):
     head (8.73 GiB of weights), 1,810 pages over all twenty layers (2 KV
     heads of 128) and the tail pool of 72 + 1 slots over the same twenty
     (22.8 MiB, no recurrent state)."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
@@ -1516,7 +1516,7 @@ def _zaya_stage(arr):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_zaya(p, cfg)
+        held["spec"], w = adapters.adapt_zaya(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
@@ -1543,11 +1543,11 @@ def test_zaya_programs_keep_the_pools_and_the_weights_in_place(
     the 262,272-row embedding where it lies (no float32 copy of it: 2 GiB)
     and the temporaries stay small."""
     from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms, ragged_model as rm
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     spec, weights, kv = _zaya_stage(arr)
-    assert rm.num_page_layers(spec) == rm.num_state_layers(spec) == 20
+    assert ms.num_page_layers(spec) == ms.num_state_layers(spec) == 20
     assert spec.head_dim == 128 and spec.rotary_dim == 64
     assert kv.ssm.size == 0
     rows, pages, slots = 64, 96, 4
@@ -1589,7 +1589,7 @@ def _brumby_stage(arr):
     state pool of 36 + 1 slots over the five power-retention layers (1,032 x
     8,320 float32 a layer: 5.92 GiB) with a tail pool of zero size, and a
     page pool that is its scratch page."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     from deepspeed_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
@@ -1601,7 +1601,7 @@ def _brumby_stage(arr):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_brumby(p, cfg)
+        held["spec"], w = adapters.adapt_brumby(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
@@ -1625,10 +1625,10 @@ def test_brumby_decode_step_keeps_the_states_in_place_and_no_expansion(
     temporaries stay far under the file's 1 GiB of headroom, and the
     expansion of a key or a query is no array in HBM — the only arrays that
     wide are the pool itself and its flat view."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     compiled, spec, kv = compiled_step("brumby")
-    assert rm.num_page_layers(spec) == 0 and rm.num_state_layers(spec) == 5
+    assert ms.num_page_layers(spec) == 0 and ms.num_state_layers(spec) == 5
     assert kv.ssm.shape == (5, 37, 1032, 8320) and kv.conv.size == 0
     text = compiled.as_text()
     assert "pr_decode_step" in text and "paged_kv_row_write" not in text
@@ -1646,7 +1646,7 @@ def _glm5_stage(arr, pages=6436):
     experts 0-15 of 256 held) at published widths, an eighth of the
     vocabulary (7.28 GiB of weights), ``pages`` pages of latent rows and of
     index keys (5.89 GiB)."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters
     from deepspeed_tpu.models.glm_dsa import GlmDsaConfig, GlmDsaForCausalLM
     cfg = GlmDsaConfig.glm_5(dtype=BF16, experts_held=(0, 16),
                              num_hidden_layers=5, first_k_dense_replace=1,
@@ -1658,7 +1658,7 @@ def _glm5_stage(arr, pages=6436):
     held = {}
 
     def adapt(p):
-        held["spec"], w = rm.adapt_glm_dsa(p, cfg)
+        held["spec"], w = adapters.adapt_glm_dsa(p, cfg)
         return w
 
     weights = jax.tree_util.tree_map(
@@ -1678,11 +1678,11 @@ def test_glm5_decode_step_reads_the_index_pool_and_gathers_its_selection(
     no instruction copies either; what attention reads of the latent pool is
     a gather of 2,048 rows a sequence; and the temporaries stay far under
     the file's 1 GiB of headroom."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms
     monkeypatch.setattr(_backend, "interpret", lambda: False)
     arr = _on(SingleDeviceSharding(v5e[0]))
     spec, weights, kv = _glm5_stage(arr)
-    assert [n for _, _, n in rm.layer_runs(spec)] == [1, 4]
+    assert [n for _, _, n in ms.layer_runs(spec)] == [1, 4]
     weight_bytes = sum(math.prod(a.shape) * 2
                        for a in jax.tree_util.tree_leaves(weights))
     assert abs(weight_bytes / 2 ** 30 - 7.28) < 0.01

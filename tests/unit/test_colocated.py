@@ -339,7 +339,7 @@ def test_frontend_swap_quiesces_inflight_decode():
 def test_lora_drain_swap_settles_pool_byte_safely():
     from deepspeed_tpu.inference.v2.lora import (LoraAdapterRegistry,
                                                  LoraPagePool)
-    from deepspeed_tpu.inference.v2.ragged_model import RaggedModelSpec
+    from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
     spec = RaggedModelSpec(family="llama", num_layers=2, hidden_size=8,
                            num_heads=2, num_kv_heads=2, head_dim=4,
                            vocab_size=64, dtype=jnp.float32)

@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_mla, ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_mla)
 from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG  # noqa: E402
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.inference.v2.ragged.kv_cache import KVCacheConfig  # noqa: E402
@@ -188,7 +189,7 @@ def test_two_pools_one_budget_at_the_published_widths():
     """A token costs a layer 640 + 128 bfloat16 values = 1,536 B, funded
     from one budget under one page id."""
     cfg = GlmDsaConfig.glm_5()
-    spec = rm.RaggedModelSpec(
+    spec = ms.RaggedModelSpec(
         family="glm_dsa", num_layers=5, hidden_size=6144, num_heads=64,
         num_kv_heads=64, head_dim=256, vocab_size=19360,
         mla={"q_lora_rank": cfg.q_lora_rank, "kv_lora_rank": cfg.kv_lora_rank,
@@ -197,7 +198,7 @@ def test_two_pools_one_budget_at_the_published_widths():
              "v_head_dim": cfg.v_head_dim,
              "index": {"heads": 32, "head_dim": 128, "topk": 2048,
                        "rope_dim": 64, "eps": 1e-6}})
-    assert (rm.latent_width(spec), rm.index_width(spec)) == (640, 128)
+    assert (ms.latent_width(spec), ms.index_width(spec)) == (640, 128)
     kv = KVCacheConfig(5, 64, 256, 128, 10, jnp.bfloat16, latent_dim=640,
                        index_dim=128)
     assert kv.bytes_per_block() == 5 * 128 * 1536
@@ -230,7 +231,7 @@ def test_engine_holds_both_pools_and_says_what_a_token_costs(served):
 
 def test_adapter_reads_the_indexer_and_joyai_has_none():
     cfg, _, params = build(held=(4, 4))
-    spec, weights = rm.adapt_glm_dsa(params, cfg)
+    spec, weights = adapters.adapt_glm_dsa(params, cfg)
     assert spec.family == "glm_dsa" and spec.mla["index"] == {
         "heads": 4, "head_dim": 32, "topk": 24, "rope_dim": 16, "eps": 1e-6}
     dense, sparse = weights["layers"]
@@ -242,7 +243,7 @@ def test_adapter_reads_the_indexer_and_joyai_has_none():
     jcfg = JoyaiConfig.tiny(dtype=jnp.float32)
     jparams = JoyaiForCausalLM(jcfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    jspec, jweights = rm.adapt_joyai(jparams, jcfg)
+    jspec, jweights = adapters.adapt_joyai(jparams, jcfg)
     assert "index" not in jspec.mla and "index" not in jweights["layers"][1]
 
 

@@ -21,7 +21,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_model as rm)
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.inference.v2.ragged.state_pool import StatefulKV  # noqa: E402
 from deepspeed_tpu.models.granite import (ATTENTION, MAMBA, GraniteConfig,  # noqa: E402
@@ -296,7 +297,7 @@ def test_pages_are_the_attention_layer_and_states_the_mamba_layers(served):
     assert [k.mamba for k in spec.layer_kinds] == [True, False, True, True]
     assert all(k.moe and not k.rope and k.window is None
                for k in spec.layer_kinds)
-    assert rm.num_page_layers(spec) == 1 and rm.num_state_layers(spec) == 3
+    assert ms.num_page_layers(spec) == 1 and ms.num_state_layers(spec) == 3
     kv = eng.kv.kv
     assert isinstance(kv, StatefulKV)
     assert kv.pages.shape[0] == 1
@@ -311,7 +312,7 @@ def test_pages_are_the_attention_layer_and_states_the_mamba_layers(served):
     assert (spec.embed_scale, spec.residual_scale, spec.logits_scale,
             spec.attn_scale) == (12.0, 0.22, 1 / 16, 1 / 64)
     assert "score_func" not in spec.moe and "held" not in spec.moe
-    text = rm.describe_layer_kinds(spec)
+    text = ms.describe_layer_kinds(spec)
     assert text.count("Mamba state-space mixer (no pages), MoE FFN") == 2
     assert "layers 1-1: full, no positions, MoE FFN" in text
     assert tracer.totals["serve/state/bytes_per_sequence"] \
@@ -321,8 +322,8 @@ def test_pages_are_the_attention_layer_and_states_the_mamba_layers(served):
 
 def test_adapter_stacks_a_tree_per_run(built):
     cfg, _, params = built
-    spec, weights = rm.adapt_granite(params, cfg)
-    assert [n for _, _, n in rm.layer_runs(spec)] == [1, 1, 2]
+    spec, weights = adapters.adapt_granite(params, cfg)
+    assert [n for _, _, n in ms.layer_runs(spec)] == [1, 1, 2]
     stacks = weights["layers"]
     assert "mamba" in stacks[0] and "wq" not in stacks[0]
     assert "wq" in stacks[1] and "mamba" not in stacks[1]
@@ -472,7 +473,7 @@ def _tiny(fam):
     """(spec, weights, pools) of a family at toy widths."""
     if fam == "granite":
         cfg, model, _ = build()
-        return program_text.tiny(fam, (cfg, model, rm.adapt_granite))
+        return program_text.tiny(fam, (cfg, model, adapters.adapt_granite))
     return program_text.tiny(fam)
 
 
@@ -525,7 +526,7 @@ def test_tensor_parallel_beside_mamba2_is_refused(built):
     from deepspeed_tpu.inference.v2.config_v2 import (
         RaggedInferenceEngineConfig)
     cfg, _, params = built
-    spec, _ = rm.adapt_granite(params, cfg)
+    spec, _ = adapters.adapt_granite(params, cfg)
     spec = dataclasses.replace(spec, num_kv_heads=2)    # whole heads a shard
     conf = RaggedInferenceEngineConfig.load(
         {**ENGINE, "tensor_parallel": 2})

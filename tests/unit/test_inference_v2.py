@@ -517,7 +517,7 @@ class TestEngineV2:
         """ALiBi is ragged-supported since r5; the remaining genuinely
         uncarryable feature — per-layer alternating local windows
         (gpt_neo) — must still be refused with v1 guidance."""
-        from deepspeed_tpu.inference.v2.ragged_model import adapt_decoder
+        from deepspeed_tpu.inference.v2.adapters.decoder import adapt_decoder
         from deepspeed_tpu.models.decoder import DecoderConfig, DecoderLM
         cfg = DecoderConfig.tiny("opt", dtype=jnp.float32)
         object.__setattr__(cfg, "attention_layers",

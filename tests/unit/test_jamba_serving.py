@@ -17,7 +17,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_model as rm)
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.inference.v2.ragged.state_pool import (  # noqa: E402
     StatefulKV, StateSlotAllocator)
@@ -245,9 +246,9 @@ def test_loop_and_pipeline_give_the_same_tokens(built, loop, monkeypatch):
 def test_pages_are_the_attention_layers_and_states_the_mamba_layers(served):
     eng = served[0]
     assert [k.mamba for k in eng.spec.layer_kinds] == [True, False, True, True]
-    assert rm.num_page_layers(eng.spec) == 1
-    assert rm.num_state_layers(eng.spec) == 3
-    assert rm._pool_bases(eng.spec) == [0, 0, 1]
+    assert ms.num_page_layers(eng.spec) == 1
+    assert ms.num_state_layers(eng.spec) == 3
+    assert ms._pool_bases(eng.spec) == [0, 0, 1]
     kv = eng.kv.kv
     assert isinstance(kv, StatefulKV)
     assert kv.pages.shape[0] == eng.kv.config.num_layers == 1
@@ -256,7 +257,7 @@ def test_pages_are_the_attention_layers_and_states_the_mamba_layers(served):
     assert kv.conv.dtype == jnp.float32
     assert eng.state_config.bytes_per_slot() == 3 * (16 * 512 * 4
                                                      + 3 * 512 * 4)
-    text = rm.describe_layer_kinds(eng.spec)
+    text = ms.describe_layer_kinds(eng.spec)
     assert text.count("Mamba state-space mixer (no pages)") == 2
     assert "layers 1-1: full, no positions, dense FFN" in text
     # tokens x layers of the pages: one layer holds them, not four
@@ -294,8 +295,8 @@ def test_adapter_stacks_a_tree_per_run_and_layer_types_follow_the_period():
     with pytest.raises(ValueError, match="num_experts"):
         JambaConfig(num_experts=16)
     cfg, model, params = build()
-    spec, weights = rm.adapt_model("jamba", params, cfg)
-    assert [(n, rs.mamba is not None) for rs, _, n in rm.layer_runs(spec)] \
+    spec, weights = adapters.adapt_model("jamba", params, cfg)
+    assert [(n, rs.mamba is not None) for rs, _, n in ms.layer_runs(spec)] \
         == [(1, True), (1, False), (2, True)]
     assert spec.tied_lm_head and spec.rope_theta is None
     assert spec.mamba == {"d_inner": 512, "d_state": 16, "dt_rank": 16,
@@ -382,7 +383,7 @@ def _llama_programs(head_dim=16):
     model = LlamaForCausalLM(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"]
-    spec, weights = rm.adapt_model("llama", params, cfg)
+    spec, weights = adapters.adapt_model("llama", params, cfg)
     S, MB = 4, 4
     kv = jnp.zeros((spec.num_layers, 9, 2, spec.num_kv_heads, 16,
                     spec.head_dim), jnp.float32)
@@ -416,8 +417,8 @@ def test_programs_of_a_model_without_mamba_layers_carry_no_state(program,
     bare page pool: no argument and no result is a state pool or a slot."""
     spec, weights, kv, programs = _llama_programs(head_dim)
     assert rm.side_buffer_fits(spec, 1, False, None) == (head_dim == 128)
-    assert spec.mamba is None and rm.num_state_layers(spec) == 0
-    assert rm._pool_bases(spec) == [0]
+    assert spec.mamba is None and ms.num_state_layers(spec) == 0
+    assert ms._pool_bases(spec) == [0]
     fwd, args = programs[program]
     jaxpr = jax.make_jaxpr(fwd)(weights, kv, *args)
     n_in = len(jax.tree_util.tree_leaves((weights, kv, args)))

@@ -25,9 +25,9 @@ def rules_of(findings):
     return [f.rule for f in findings]
 
 
-def test_registry_has_all_eight_rules():
+def test_registry_has_all_seven_rules():
     assert set(RULE_REGISTRY) == {"JL001", "JL002", "JL003", "JL004",
-                                  "JL005", "JL006", "JL007", "JL008"}
+                                  "JL005", "JL007", "JL008"}
 
 
 # --------------------------------------------------------------------------- #
@@ -416,39 +416,6 @@ def test_jl005_axis_index_first_positional():
 
 
 # --------------------------------------------------------------------------- #
-# JL006 — compat shim bypass
-# --------------------------------------------------------------------------- #
-
-@pytest.mark.parametrize("stmt", [
-    "from jax.experimental.shard_map import shard_map",
-    "from jax.experimental import shard_map",
-    "import jax.experimental.shard_map",
-    "from jax.experimental.pallas import tpu as pltpu",
-    "import jax.experimental.pallas.tpu as pltpu",
-    "from jax import shard_map",
-])
-def test_jl006_flags_raw_imports(stmt):
-    assert rules_of(lint(stmt)) == ["JL006"]
-
-
-def test_jl006_compat_imports_clean():
-    findings = lint("""
-        from deepspeed_tpu.utils.jax_compat import shard_map, import_pltpu
-
-        pltpu = import_pltpu()
-    """)
-    assert findings == []
-
-
-def test_jl006_allow_paths_exempts_the_shim():
-    src = "from jax.experimental.shard_map import shard_map"
-    cfg = LintConfig()
-    findings = lint_text(src, path="deepspeed_tpu/utils/jax_compat.py",
-                         config=cfg)
-    assert findings == []
-
-
-# --------------------------------------------------------------------------- #
 # JL007 — blocking host fetch in a hot-path module
 # --------------------------------------------------------------------------- #
 
@@ -664,9 +631,10 @@ def test_line_suppression_wrong_rule_does_not_hide():
 
 def test_file_suppression():
     findings = lint("""
-        # jaxlint: disable-file=JL006
-        from jax import shard_map
-        from jax.experimental.pallas import tpu
+        # jaxlint: disable-file=JL002
+        import jax
+        KEY = jax.random.PRNGKey(0)
+        OTHER = jax.random.PRNGKey(1)
     """)
     assert findings == []
 
@@ -674,10 +642,11 @@ def test_file_suppression():
 def test_docstring_mention_is_not_a_suppression():
     # documenting the directive in a docstring must not install it
     findings = lint('''
-        """Docs: write ``# jaxlint: disable-file=JL006`` to suppress a file."""
-        from jax import shard_map
+        """Docs: write ``# jaxlint: disable-file=JL002`` to suppress a file."""
+        import jax
+        KEY = jax.random.PRNGKey(0)
     ''')
-    assert rules_of(findings) == ["JL006"]
+    assert rules_of(findings) == ["JL002"]
 
 
 def test_disable_all_on_line():
@@ -689,8 +658,8 @@ def test_disable_all_on_line():
 
 
 def test_rule_disabled_via_config():
-    src = "from jax import shard_map"
-    cfg = LintConfig(rules={"JL006": RuleSettings(enabled=False)})
+    src = "import jax\nKEY = jax.random.PRNGKey(0)\n"
+    cfg = LintConfig(rules={"JL002": RuleSettings(enabled=False)})
     assert lint_text(src, path="pkg/mod.py", config=cfg) == []
 
 
@@ -725,7 +694,7 @@ def test_cli_end_to_end(tmp_path, capsys):
     assert "JL002" in out
 
     # --select an unrelated rule: clean
-    assert jaxlint_main([str(bad), "--no-config", "--select", "JL006"]) == 0
+    assert jaxlint_main([str(bad), "--no-config", "--select", "JL001"]) == 0
     # --disable the firing rule: clean
     assert jaxlint_main([str(bad), "--no-config", "--disable", "JL002"]) == 0
 

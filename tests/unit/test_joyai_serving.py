@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_mla, ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_mla, ragged_model as rm)
 from deepspeed_tpu.inference.v2.attention import AttentionKernelSpec  # noqa: E402
 from deepspeed_tpu.inference.v2.config_v2 import (  # noqa: E402
     RaggedInferenceEngineConfig)
@@ -160,12 +161,12 @@ def test_absorbed_equals_expanded_on_the_same_cache():
     against the expanded form computed from the same rows in plain jnp (keys
     and values of every head made from each cached latent)."""
     cfg, _, params = build()
-    spec, weights = rm.adapt_joyai(params, cfg)
+    spec, weights = adapters.adapt_joyai(params, cfg)
     w = jax.tree_util.tree_map(lambda a: a[0], weights["layers"][1])
     m, H = spec.mla, spec.num_heads
     R, dn, dr, dv = (m["kv_lora_rank"], m["qk_nope_head_dim"],
                      m["qk_rope_head_dim"], m["v_head_dim"])
-    W, bs, NB, S = rm.latent_width(spec), 16, 12, 3
+    W, bs, NB, S = ms.latent_width(spec), 16, 12, 3
     rng = np.random.default_rng(3)
     rows = rng.standard_normal((NB, bs, W)).astype(np.float32)
     rows[..., R + dr:] = 0
@@ -174,7 +175,7 @@ def test_absorbed_equals_expanded_on_the_same_cache():
     ctx = jnp.asarray([5, 33, 64], jnp.int32)
     q_nope = jnp.asarray(rng.standard_normal((S, H, dn)), jnp.float32)
     q_rope = jnp.asarray(rng.standard_normal((S, H, dr)), jnp.float32)
-    ak = AttentionKernelSpec(rm.layer_runs(spec)[1][0])
+    ak = AttentionKernelSpec(ms.layer_runs(spec)[1][0])
     o_lat = ak.latent(ragged_mla.mla_absorb_q(spec, w, q_nope, q_rope, W),
                       pool, bt, ctx - 1, ctx)
     got = np.asarray(ragged_mla.mla_absorb_o(w, o_lat)).reshape(S, H, dv)
@@ -201,16 +202,16 @@ def test_latent_pool_is_one_row_a_token_a_layer():
     (512 + 64 padded to whole lane tiles) = 1,280 B: not the latent twice,
     not keys and values per head (20,480 B)."""
     cfg = JoyaiConfig.joyai_llm_flash()
-    spec = rm.RaggedModelSpec(
+    spec = ms.RaggedModelSpec(
         family="joyai", num_layers=40, hidden_size=2048, num_heads=32,
         num_kv_heads=32, head_dim=128, vocab_size=129280,
         mla={"q_lora_rank": cfg.q_lora_rank, "kv_lora_rank": cfg.kv_lora_rank,
              "qk_nope_head_dim": cfg.qk_nope_head_dim,
              "qk_rope_head_dim": cfg.qk_rope_head_dim,
              "v_head_dim": cfg.v_head_dim})
-    assert rm.latent_width(spec) == 640
+    assert ms.latent_width(spec) == 640
     kv = KVCacheConfig(40, 32, 128, 128, 10, jnp.bfloat16,
-                       latent_dim=rm.latent_width(spec))
+                       latent_dim=ms.latent_width(spec))
     assert kv.page_shape == (40, 128, 640)
     per_token_layer = kv.bytes_per_block() / (40 * 128)
     assert 576 * 2 <= per_token_layer <= 1280
@@ -235,8 +236,8 @@ def test_engine_pool_has_no_head_axis_and_says_what_a_token_costs(served):
 def test_adapter_reads_the_published_tree_and_skips_the_mtp_module():
     cfg, _, params = build("held")
     extra = dict(params, layers_4=params["layers_3"])    # the MTP module
-    spec, weights = rm.adapt_joyai(extra, cfg)
-    assert spec.num_layers == 4 and [n for _, _, n in rm.layer_runs(spec)] \
+    spec, weights = adapters.adapt_joyai(extra, cfg)
+    assert spec.num_layers == 4 and [n for _, _, n in ms.layer_runs(spec)] \
         == [1, 3]
     assert spec.moe["num_experts"] == 16 and spec.moe["held"] == (4, 4)
     dense, sparse = weights["layers"]
@@ -244,7 +245,7 @@ def test_adapter_reads_the_published_tree_and_skips_the_mtp_module():
     assert sparse["moe"]["router"].shape == (3, 64, 16)
     assert sparse["moe"]["w_gate"].shape == (3, 4, 64, 32)
     assert sparse["w_uk"].shape == (3, 4, 64, 32)       # [L, H, R, nope]
-    full, _ = rm.adapt_joyai(params, build()[0])
+    full, _ = adapters.adapt_joyai(params, build()[0])
     assert "held" not in full.moe
 
 
@@ -260,7 +261,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     fam, d = family(), file_keys(cfg)
     layer = fam.reference_weights(params, d)["layers"][2]
     hp = fam.reference_hp(d)
-    spec, weights = rm.adapt_joyai(params, cfg)
+    spec, weights = adapters.adapt_joyai(params, cfg)
     w = jax.tree_util.tree_map(lambda a: a[1], weights["layers"][1]["moe"])
     x = jnp.asarray(np.random.default_rng(5).standard_normal((24, 64)),
                     jnp.float32)
@@ -358,7 +359,7 @@ def test_a_layer_holding_all_its_experts_lowers_to_the_parents_text(router):
     ({"quantization": {"weight_bits": 8}}, r"quantization\.weight_bits")])
 def test_engine_build_refuses_beside_latent_pages(over, says):
     cfg, _, params = build()
-    spec, _ = rm.adapt_joyai(params, cfg)
+    spec, _ = adapters.adapt_joyai(params, cfg)
     config = RaggedInferenceEngineConfig.load({**ENGINE, **over})
     with pytest.raises(NotImplementedError, match=says) as e:
         AttentionKernelSpec.validate_engine_build(spec, config)

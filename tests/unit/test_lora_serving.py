@@ -15,7 +15,7 @@ import pytest
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.lora import LoraAdapterRegistry, LoraPagePool
 from deepspeed_tpu.inference.v2.pipeline import DecodePipeline
-from deepspeed_tpu.inference.v2.ragged_model import RaggedModelSpec
+from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.module_inject.lora import load_lora_adapter
 from deepspeed_tpu.utils import fault_injection as fi

@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
-from deepspeed_tpu.inference.v2.ragged_model import (RaggedModelSpec,
-                                                     lora_page_layout)
+from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
+from deepspeed_tpu.inference.v2.ragged_model import lora_page_layout
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.module_inject.lora import (load_lora_adapter,
                                               pack_lora_pages,

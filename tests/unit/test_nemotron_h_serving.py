@@ -20,7 +20,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_model as rm)
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,  # noqa: E402
                                              NemotronHForCausalLM)
@@ -148,22 +149,22 @@ def test_one_block_a_layer_kinds_pools_and_description(served):
     spec = eng.spec
     assert [k.what for k in spec.layer_kinds] == [
         "mamba", "moe", "mamba", "attention", "moe", "mamba", "moe"]
-    assert all(isinstance(k, rm.BlockKind) for k in spec.layer_kinds)
+    assert all(isinstance(k, ms.BlockKind) for k in spec.layer_kinds)
     # an E layer addresses neither pool: pages count attention layers, not
     # "what is left", and states the Mamba layers
-    assert rm.num_page_layers(spec) == 1 and rm.num_state_layers(spec) == 3
-    assert rm._layer_holds(spec) == ["state", None, "state", "pages", None,
+    assert ms.num_page_layers(spec) == 1 and ms.num_state_layers(spec) == 3
+    assert ms._layer_holds(spec) == ["state", None, "state", "pages", None,
                                      "state", None]
-    assert rm._pool_index(spec) == [0, 0, 1, 0, 1, 2, 2]
+    assert ms._pool_index(spec) == [0, 0, 1, 0, 1, 2, 2]
     assert eng.kv.config.num_layers == 1
     assert eng.state_config.num_layers == 3
     assert eng.state_config.conv_dim == 512 + 2 * 2 * 128
-    text = rm.describe_layer_kinds(spec)
+    text = ms.describe_layer_kinds(spec)
     assert text.count("Mamba state-space mixer alone") == 3
     assert text.count("routed experts alone (no pages, no state)") == 3
     assert text.count("attention alone (full, no positions)") == 1
     assert "FFN" not in text
-    runs = rm.layer_runs(spec)
+    runs = ms.layer_runs(spec)
     assert [rs.block for rs, _, _ in runs] == [
         "mixer", "ffn", "mixer", "mixer", "ffn", "mixer", "ffn"]
     assert all((rs.moe is not None) == (rs.block == "ffn")
@@ -187,7 +188,7 @@ def test_two_held_shares_add_up_to_the_uncut_expert_layer():
     equals the reference's uncut ``E`` block."""
     from chipbench.reference import nemotron_h_ref
     cfg, _, params = build(num_hidden_layers=1, hybrid_override_pattern="E")
-    spec, weights = rm.adapt_nemotron_h(params, cfg)
+    spec, weights = adapters.adapt_nemotron_h(params, cfg)
     w = jax.tree_util.tree_map(lambda a: a[0], weights["layers"][0])["moe"]
     x = jnp.asarray(np.random.default_rng(2).standard_normal((24, 128)),
                     jnp.float32)
@@ -260,8 +261,8 @@ UNITS = {
 @pytest.mark.parametrize("pattern", list(UNITS))
 def test_the_pattern_is_cut_into_repeating_units(pattern):
     what = {"M": "mamba", "E": "moe", "*": "attention"}
-    kinds = tuple(rm.BlockKind(what[c]) for c in pattern)
-    cuts = rm._unit_cuts(kinds)
+    kinds = tuple(ms.BlockKind(what[c]) for c in pattern)
+    cuts = ms._unit_cuts(kinds)
     # the units tile the layers in order, and each repeats what it says
     at = 0
     for l0, p, r in cuts:
@@ -280,13 +281,13 @@ def test_maximal_runs_stay_units_of_their_own():
     """The accepted families' patterns are cut as they always were: one unit
     a run, whatever repeats a longer period would find (Jamba's 28 layers
     are (7 M, A, 6 M) twice over)."""
-    M, A = rm.MambaKind(False), rm.LayerKind(None, False, False)
+    M, A = ms.MambaKind(False), ms.LayerKind(None, False, False)
     jamba = (M,) * 7 + (A,) + (M,) * 13 + (A,) + (M,) * 6
-    assert [(p, r) for _, p, r in rm._unit_cuts(jamba)] == [
+    assert [(p, r) for _, p, r in ms._unit_cuts(jamba)] == [
         (1, 7), (1, 1), (1, 13), (1, 1), (1, 6)]
-    granite = (rm.MambaKind(True),) * 5 + (rm.LayerKind(None, False, True),) \
-        + (rm.MambaKind(True),) * 4
-    assert [(p, r) for _, p, r in rm._unit_cuts(granite)] == [
+    granite = (ms.MambaKind(True),) * 5 + (ms.LayerKind(None, False, True),) \
+        + (ms.MambaKind(True),) * 4
+    assert [(p, r) for _, p, r in ms._unit_cuts(granite)] == [
         (1, 5), (1, 1), (1, 4)]
 
 
@@ -305,13 +306,13 @@ def test_unit_scans_give_what_one_layer_scans_give(monkeypatch):
         return eng, out, toks
 
     eng, out, toks = run()
-    units = [(len(s), n) for s, _, n in rm.layer_units(eng.spec)]
+    units = [(len(s), n) for s, _, n in ms.layer_units(eng.spec)]
     assert units == [(1, 1), (2, 2), (1, 1), (2, 3)]
     assert isinstance(eng.weights["layers"][1], tuple)
-    monkeypatch.setattr(rm, "_unit_cuts",
+    monkeypatch.setattr(ms, "_unit_cuts",
                         lambda kinds: [(i, 1, 1) for i in range(len(kinds))])
     eng1, out1, toks1 = run()
-    assert len(rm.layer_units(eng1.spec)) == 12
+    assert len(ms.layer_units(eng1.spec)) == 12
     assert all(close(a, b, 1e-5) for a, b in zip(out, out1))
     assert (toks == toks1).all()
     ids = np.concatenate([prompt, toks])
@@ -323,7 +324,7 @@ def test_the_experts_width_is_padded_to_whole_lane_tiles_when_adapted():
     """A width that is not whole 128-lane tiles (the published 1856) is
     zero-padded in the engine's stacks, and only there: the same numbers."""
     cfg, model, params = build(moe_intermediate_size=72)
-    spec, weights = rm.adapt_nemotron_h(params, cfg)
+    spec, weights = adapters.adapt_nemotron_h(params, cfg)
     moe = weights["layers"][1]["moe"]
     assert params["layers_1"]["mixer"]["w_up"].shape == (8, 128, 72)
     assert moe["w_up"].shape == (1, 8, 128, 128)
@@ -334,7 +335,7 @@ def test_the_experts_width_is_padded_to_whole_lane_tiles_when_adapted():
     got = engine_for(model, params).put([1], [ids])[0]
     assert close(got, np.asarray(reference(cfg, params, ids))[-1])
     up = jnp.zeros((2, 2688, 1856), jnp.bfloat16)
-    padded = jax.eval_shape(rm._pad_expert_width, up,
+    padded = jax.eval_shape(adapters._stacks._pad_expert_width, up,
                             jnp.zeros((2, 1856, 2688), jnp.bfloat16))
     assert [p.shape for p in padded] == [(2, 2688, 1920), (2, 1920, 2688)]
     # 9.8 MiB a matrix: the Pallas kernel's, by the rule's size limit

@@ -19,7 +19,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_model as rm)
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,  # noqa: E402
                                              Qwen3NextForCausalLM)
@@ -245,14 +246,14 @@ def test_a_small_held_share_takes_the_compact_path(routing):
 
 def test_the_adapter_takes_the_fused_layouts_apart(built):
     cfg, _, params = built
-    spec, weights = rm.adapt_qwen3_next(params, cfg)
+    spec, weights = adapters.adapt_qwen3_next(params, cfg)
     assert [type(k).__name__ for k in spec.layer_kinds] == \
         ["DeltaKind"] * 3 + ["LayerKind"] + ["DeltaKind"] * 3 + ["LayerKind"]
     assert spec.norm_plus_one and spec.rotary_dim == 8
     assert spec.mamba["kind"] == "gdn" and spec.mamba["conv_dim"] == 512
-    assert rm.num_state_layers(spec) == 6 and rm.num_page_layers(spec) == 2
+    assert ms.num_state_layers(spec) == 6 and ms.num_page_layers(spec) == 2
     # one unit of four kinds, twice: a tuple of four stacked trees
-    assert [(len(u), n) for u, _, n in rm.layer_units(spec)] == [(4, 2)]
+    assert [(len(u), n) for u, _, n in ms.layer_units(spec)] == [(4, 2)]
     (unit,) = weights["layers"]
     delta, attn = unit[0], unit[3]
     # [q | k | v | z] over all heads; one key head here, so the fused
@@ -273,9 +274,9 @@ def test_the_period_is_one_scan_of_four_bodies():
     """Three delta layers and an attention layer, three times: ONE unit of
     four kinds (four bodies to trace), not six runs; a single period stays
     two runs; and the units give what one scan a layer gives."""
-    D, A = rm.DeltaKind(True), rm.LayerKind(None, True, True)
-    assert [(p, r) for _, p, r in rm._unit_cuts((D, D, D, A) * 3)] == [(4, 3)]
-    assert [(p, r) for _, p, r in rm._unit_cuts((D, D, D, A))] == [
+    D, A = ms.DeltaKind(True), ms.LayerKind(None, True, True)
+    assert [(p, r) for _, p, r in ms._unit_cuts((D, D, D, A) * 3)] == [(4, 3)]
+    assert [(p, r) for _, p, r in ms._unit_cuts((D, D, D, A))] == [
         (1, 3), (1, 1)]
 
 
@@ -289,11 +290,11 @@ def test_unit_scans_give_what_one_layer_scans_give(built, monkeypatch):
         return eng, out, eng.decode_pipeline([1]).run(6)[0]
 
     eng, out, toks = run()
-    assert len(rm.layer_units(eng.spec)) == 1
-    monkeypatch.setattr(rm, "_unit_cuts",
+    assert len(ms.layer_units(eng.spec)) == 1
+    monkeypatch.setattr(ms, "_unit_cuts",
                         lambda kinds: [(i, 1, 1) for i in range(len(kinds))])
     eng1, out1, toks1 = run()
-    assert len(rm.layer_units(eng1.spec)) == 8
+    assert len(ms.layer_units(eng1.spec)) == 8
     assert all(close(a, b, 1e-5) for a, b in zip(out, out1))
     assert (toks == toks1).all()
 
@@ -326,7 +327,7 @@ def test_what_is_refused_beside_the_state(built):
 def test_the_engine_reports_its_kind(built):
     cfg, model, params = built
     eng = engine_for(model, params)
-    text = rm.describe_layer_kinds(eng.spec)
+    text = ms.describe_layer_kinds(eng.spec)
     assert "Gated DeltaNet" in text and "rotary" in text
     sc = eng.state_config
     assert (sc.num_layers, sc.d_inner, sc.d_state, sc.conv_dim) == \

@@ -69,7 +69,7 @@ PROGRAMS = ("serve_decode_step", "serve_paged_pass", "serve_prefill_packed")
 def tiny(fam, model=None):
     """(spec, weights, pools) of a family at toy widths, float32; ``model``
     is ``(config, module, adapter)`` of a family this file does not name."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import adapters, model_spec as ms
     from deepspeed_tpu.inference.v2.ragged.state_pool import (StatefulKV,
                                                               StatePoolConfig)
     key, ids = jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
@@ -80,63 +80,63 @@ def tiny(fam, model=None):
         from deepspeed_tpu.models.jamba import JambaConfig, JambaForCausalLM
         cfg = JambaConfig.tiny(dtype=f32, hidden_size=256,
                                num_attention_heads=2, mamba_dt_rank=16)
-        model, adapt = JambaForCausalLM(cfg), rm.adapt_jamba
+        model, adapt = JambaForCausalLM(cfg), adapters.adapt_jamba
     elif fam == "mixtral":
         from deepspeed_tpu.models.mixtral import (MixtralConfig,
                                                   MixtralForCausalLM)
         cfg = MixtralConfig.tiny(dtype=f32)
-        model, adapt = MixtralForCausalLM(cfg), rm.adapt_llama
+        model, adapt = MixtralForCausalLM(cfg), adapters.adapt_llama
     elif fam == "afmoe":
         from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
         cfg = AfmoeConfig.tiny(dtype=f32)
-        model, adapt = AfmoeForCausalLM(cfg), rm.adapt_afmoe
+        model, adapt = AfmoeForCausalLM(cfg), adapters.adapt_afmoe
     elif fam == "joyai":
         from deepspeed_tpu.models.joyai import JoyaiConfig, JoyaiForCausalLM
         cfg = JoyaiConfig.tiny(dtype=f32)
-        model, adapt = JoyaiForCausalLM(cfg), rm.adapt_joyai
+        model, adapt = JoyaiForCausalLM(cfg), adapters.adapt_joyai
     elif fam == "granite":
         from deepspeed_tpu.models.granite import (GraniteConfig,
                                                   GraniteForCausalLM)
         cfg = GraniteConfig.tiny(dtype=f32)
-        model, adapt = GraniteForCausalLM(cfg), rm.adapt_granite
+        model, adapt = GraniteForCausalLM(cfg), adapters.adapt_granite
     elif fam == "nemotron_h":
         from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
                                                      NemotronHForCausalLM)
         cfg = NemotronHConfig.tiny(dtype=f32)
-        model, adapt = NemotronHForCausalLM(cfg), rm.adapt_nemotron_h
+        model, adapt = NemotronHForCausalLM(cfg), adapters.adapt_nemotron_h
     elif fam in ("qwen3_next", "qwen3_next_held"):
         from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                                      Qwen3NextForCausalLM)
         held = dict(num_experts=16, experts_held=(2, 2)) if fam in HELD else {}
         cfg = Qwen3NextConfig.tiny(dtype=f32, **held)
-        model, adapt = Qwen3NextForCausalLM(cfg), rm.adapt_qwen3_next
+        model, adapt = Qwen3NextForCausalLM(cfg), adapters.adapt_qwen3_next
     elif fam == "zaya":
         from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
         cfg = ZayaConfig.tiny(dtype=f32)
-        model, adapt = ZayaForCausalLM(cfg), rm.adapt_zaya
+        model, adapt = ZayaForCausalLM(cfg), adapters.adapt_zaya
     else:
         from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         cfg = LlamaConfig.tiny(dtype=f32)
-        model, adapt = LlamaForCausalLM(cfg), rm.adapt_llama
+        model, adapt = LlamaForCausalLM(cfg), adapters.adapt_llama
     params = model.init(key, ids)["params"]
     spec, weights = adapt(params, cfg)
     spec.dtype = f32
     if spec.mla is not None:
         return spec, weights, jnp.zeros(
-            (spec.num_layers, 9, 16, rm.latent_width(spec)), f32)
-    pages = jnp.zeros((max(1, rm.num_page_layers(spec)), 9, 2,
+            (spec.num_layers, 9, 16, ms.latent_width(spec)), f32)
+    pages = jnp.zeros((max(1, ms.num_page_layers(spec)), 9, 2,
                        spec.num_kv_heads, 16, spec.head_dim), f32)
     # the state pool as the engine sizes it (``engine_v2.py``), 4 slots
     if spec.mamba is not None:
         m = spec.mamba
         pool = StatePoolConfig(
-            rm.num_state_layers(spec), 4, m["d_inner"], m["d_state"],
+            ms.num_state_layers(spec), 4, m["d_inner"], m["d_state"],
             m["d_conv"],
             conv_dim=m["d_inner"] + 2 * m.get("n_groups", 1) * m["d_state"]
             if m.get("kind") == "mamba2" else m.get("conv_dim"))
     elif spec.cca is not None:
         pool = StatePoolConfig.tails_only(
-            rm.num_state_layers(spec), 4, taps=spec.cca["taps"],
+            ms.num_state_layers(spec), 4, taps=spec.cca["taps"],
             channels=spec.cca["tail_channels"])
     else:
         return spec, weights, pages
@@ -220,7 +220,7 @@ def test_the_form_each_familys_decode_step_takes(fam, monkeypatch):
 
 def _wide(**kw):
     """A spec at Mistral-7B's heads (32 over 8, 128 wide, window 4096)."""
-    from deepspeed_tpu.inference.v2.ragged_model import RaggedModelSpec
+    from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
     return RaggedModelSpec(**{**dict(
         family="llama", num_layers=2, hidden_size=4096, num_heads=32,
         num_kv_heads=8, head_dim=128, vocab_size=128, window=4096), **kw})
@@ -261,12 +261,12 @@ def test_the_traced_decode_step_holds_no_loop_but_its_layer_scans(fam):
     """One program decodes one token: the step's only loops are the scans
     over its units of layers (``layer_units``), in order, each as long as its
     unit repeats; no loop over steps holds them."""
-    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    from deepspeed_tpu.inference.v2 import model_spec as ms
     spec, weights, kv = tiny(fam)
     fwd, args = programs(spec)["serve_decode_step"]
     jaxpr = jax.make_jaxpr(fwd)(weights, kv, *args)
     assert _top_level_loops(jaxpr.jaxpr) == [
-        n for _, _, n in rm.layer_units(spec)]
+        n for _, _, n in ms.layer_units(spec)]
 
 
 @pytest.mark.parametrize("program,choices,bound", [

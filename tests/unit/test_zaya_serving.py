@@ -22,7 +22,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from deepspeed_tpu.inference.v2 import ragged_model as rm  # noqa: E402
+from deepspeed_tpu.inference.v2 import (  # noqa: E402
+    adapters, model_spec as ms, ragged_model as rm)
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.inference.v2.ragged.state_pool import (  # noqa: E402
     StatePoolConfig)
@@ -295,7 +296,7 @@ def test_the_adapters_permutation_leaves_every_q_dot_k(built):
     order, so every ``q . k`` is as it was, and v is untouched."""
     from chipbench.reference import zaya_ref
     cfg, model, params = built
-    spec, weights = rm.adapt_zaya(params, cfg)
+    spec, weights = adapters.adapt_zaya(params, cfg)
     T, L = 21, cfg.num_hidden_layers
     u = jnp.asarray(np.random.default_rng(2).standard_normal(
         (T, cfg.hidden_size)), jnp.float32)
@@ -314,7 +315,7 @@ def test_the_adapters_permutation_leaves_every_q_dot_k(built):
     with jax.default_matmul_precision("highest"):
         rq, rk, rv, tail = zaya_ref.cca_qkv(
             u, ref_w, fam.reference_hp(d), lambda x: x, lambda x: x)
-    turn = rm.zaya_channel_order(1, cfg.head_dim, cfg.rotary_dim)
+    turn = adapters.zaya.zaya_channel_order(1, cfg.head_dim, cfg.rotary_dim)
     assert sorted(turn[:cfg.rotary_dim]) == list(range(cfg.rotary_dim))
     assert (turn[:4] == [0, cfg.rotary_dim // 2, 1,
                          cfg.rotary_dim // 2 + 1]).all()
@@ -334,23 +335,23 @@ def test_the_adapters_permutation_leaves_every_q_dot_k(built):
 
 def test_every_layer_addresses_both_pools(built):
     cfg, model, params = built
-    spec, _ = rm.adapt_zaya(params, cfg)
+    spec, _ = adapters.adapt_zaya(params, cfg)
     L = cfg.num_hidden_layers
     assert spec.layer_kinds is None and spec.cca["taps"] == 2
-    assert rm._layer_holds(spec) == ["both"] * L
-    assert rm.num_page_layers(spec) == rm.num_state_layers(spec) == L
+    assert ms._layer_holds(spec) == ["both"] * L
+    assert ms.num_page_layers(spec) == ms.num_state_layers(spec) == L
     everyone = list(range(L))
-    assert rm._pool_index(spec) == rm._pool_index(spec, "pages") \
-        == rm._pool_index(spec, "state") == everyone
-    assert rm._pool_bases(spec) == [0]
-    assert rm._holds(rm.CcaKind()) == "both"
-    assert "pages and a convolution tail" in rm.describe_layer_kinds(spec)
+    assert ms._pool_index(spec) == ms._pool_index(spec, "pages") \
+        == ms._pool_index(spec, "state") == everyone
+    assert ms._pool_bases(spec) == [0]
+    assert ms._holds(ms.CcaKind()) == "both"
+    assert "pages and a convolution tail" in ms.describe_layer_kinds(spec)
 
 
-A, W = rm.LayerKind(None, False, False), rm.LayerKind(64, True, True)
-M, D, ME = rm.MambaKind(), rm.DeltaKind(True), rm.MambaKind(True)
-BM, BA, BE = (rm.BlockKind("mamba"), rm.BlockKind("attention"),
-              rm.BlockKind("moe"))
+A, W = ms.LayerKind(None, False, False), ms.LayerKind(64, True, True)
+M, D, ME = ms.MambaKind(), ms.DeltaKind(True), ms.MambaKind(True)
+BM, BA, BE = (ms.BlockKind("mamba"), ms.BlockKind("attention"),
+              ms.BlockKind("moe"))
 
 
 @pytest.mark.parametrize("kinds, holds, index, pages, state", [
@@ -361,14 +362,14 @@ BM, BA, BE = (rm.BlockKind("mamba"), rm.BlockKind("attention"),
     # jamba: Mamba-1 beside attention
     ((M, M, A, M), ["state", "state", "pages", "state"], [0, 1, 0, 2], 1, 3),
     # granite: Mamba-2 over experts beside attention
-    ((ME, ME, rm.LayerKind(None, False, True), ME),
+    ((ME, ME, ms.LayerKind(None, False, True), ME),
      ["state", "state", "pages", "state"], [0, 1, 0, 2], 1, 3),
     # nemotron_h: one block a layer
     ((BM, BE, BM, BA, BE, BM, BE),
      ["state", None, "state", "pages", None, "state", None],
      [0, 0, 1, 0, 1, 2, 2], 1, 3),
     # qwen3_next: three delta layers and an attention layer, twice
-    ((D, D, D, rm.LayerKind(None, True, True)) * 2,
+    ((D, D, D, ms.LayerKind(None, True, True)) * 2,
      ["state"] * 3 + ["pages"] + ["state"] * 3 + ["pages"],
      [0, 1, 2, 0, 3, 4, 5, 1], 2, 6),
 ])
@@ -377,14 +378,14 @@ def test_the_accepted_families_pools_are_as_they_were(kinds, holds, index,
     """What a layer of each accepted family addresses, and where, is what it
     was before a layer could address both pools."""
     n = 4 if kinds is None else len(kinds)
-    spec = rm.RaggedModelSpec("x", n, 128, 4, 2, 32, 256, layer_kinds=kinds,
+    spec = ms.RaggedModelSpec("x", n, 128, 4, 2, 32, 256, layer_kinds=kinds,
                               mamba={"d_inner": 8} if state else None)
-    assert rm._layer_holds(spec) == holds
-    assert rm._pool_index(spec) == index
-    assert (rm.num_page_layers(spec), rm.num_state_layers(spec)) == (pages,
+    assert ms._layer_holds(spec) == holds
+    assert ms._pool_index(spec) == index
+    assert (ms.num_page_layers(spec), ms.num_state_layers(spec)) == (pages,
                                                                      state)
     for pool in ("pages", "state"):
-        ranks = [i for i, h in zip(rm._pool_index(spec, pool), holds)
+        ranks = [i for i, h in zip(ms._pool_index(spec, pool), holds)
                  if h == pool]
         assert ranks == list(range(len(ranks)))
 
@@ -392,13 +393,13 @@ def test_the_accepted_families_pools_are_as_they_were(kinds, holds, index,
 def test_a_tail_beside_layers_of_one_pool_is_refused():
     """The layer loop hands a layer ONE index: a model that mixes layers
     that keep a tail beside their pages with layers of one pool says so."""
-    kinds = (rm.MambaKind(), rm.CcaKind(), rm.CcaKind())
-    spec = rm.RaggedModelSpec("x", 3, 128, 4, 2, 128, 256, layer_kinds=kinds,
+    kinds = (ms.MambaKind(), ms.CcaKind(), ms.CcaKind())
+    spec = ms.RaggedModelSpec("x", 3, 128, 4, 2, 128, 256, layer_kinds=kinds,
                               mamba={"d_inner": 8}, cca={"taps": 2})
-    assert rm._pool_index(spec) == [0, 0, 1]
-    assert rm._pool_index(spec, "state") == [0, 1, 2]
-    assert rm._pool_index(spec, "pages") == [0, 0, 1]
-    assert (rm.num_page_layers(spec), rm.num_state_layers(spec)) == (2, 3)
+    assert ms._pool_index(spec) == [0, 0, 1]
+    assert ms._pool_index(spec, "state") == [0, 1, 2]
+    assert ms._pool_index(spec, "pages") == [0, 0, 1]
+    assert (ms.num_page_layers(spec), ms.num_state_layers(spec)) == (2, 3)
     with pytest.raises(NotImplementedError, match="one index"):
         rm._scan_layers(spec, ({}, {}), None, ())
 
@@ -465,7 +466,7 @@ def test_the_scopes_the_metrics_read_are_in_the_step(built):
     metrics read (``chipbench/layer_metrics/cca_*.json``,
     ``moe_router_share.reason64.json``)."""
     cfg, model, params = built
-    spec, weights = rm.adapt_zaya(params, cfg)
+    spec, weights = adapters.adapt_zaya(params, cfg)
     pool = StatePoolConfig.tails_only(3, 4, 2, spec.cca["tail_channels"])
     from deepspeed_tpu.inference.v2.ragged.state_pool import StatefulKV
     kv = StatefulKV(jnp.zeros((3, 9, 2, 2, 16, 128), jnp.float32),
