@@ -66,42 +66,51 @@ import functools
 import time as _time
 
 
-#: what the programs of a held share returned as their fourth result and no
-#: host has read yet (``serve/moe/held_overflow_turns``): int32 scalars on
-#: the device, read by :func:`_count_held_turns` once their program is done
+#: what programs returned after their three results and no host has read
+#: yet: ``(the always-on counter it adds to, an int32 scalar on the device)``,
+#: read by :func:`_count_held_turns` once its program is done
 _held_turns_pending: "collections.deque" = collections.deque()
+
+#: the counters programs feed that way: a held share's turns past the first
+#: (``ragged_model._stream_turns``), and a selection's walks over its tiles
+#: and the blocks of query rows that took them (``ragged_mla._select_counts``)
+_PROGRAM_COUNTERS = ("serve/moe/held_overflow_turns",
+                     "serve/dsa/select_sweeps", "serve/dsa/select_blocks")
 
 
 def _count_held_turns() -> None:
-    """Add the finished programs' overflow turns to the always-on counter
-    ``serve/moe/held_overflow_turns``. Called where the host is fetching a
-    step's results anyway (:func:`fetch_to_host`); it reads only scalars
-    whose program has finished, oldest first, and waits for none."""
+    """Add what the finished programs counted to the always-on counters of
+    :data:`_PROGRAM_COUNTERS`. Called where the host is fetching a step's
+    results anyway (:func:`fetch_to_host`); it reads only scalars whose
+    program has finished, oldest first, and waits for none."""
     while _held_turns_pending:
         try:
-            turns = _held_turns_pending.popleft()
+            name, count = _held_turns_pending.popleft()
         except IndexError:      # another thread took the last
             return
-        if not turns.is_ready():
-            _held_turns_pending.appendleft(turns)
+        if not count.is_ready():
+            _held_turns_pending.appendleft((name, count))
             return
-        _tracer.bump("serve/moe/held_overflow_turns",
-                     float(np.asarray(turns)))  # jaxlint: disable=JL007 -- 4 bytes of a finished pass
+        _tracer.bump(name, float(np.asarray(count)))  # jaxlint: disable=JL007 -- 4 bytes of a finished pass
 
 
 class _ThreeResults:
     """A jitted prefill pass or decode step, called for its three results.
-    The program of a held share returns a fourth, its MoE layers' turns past
-    the first (``ragged_model._stream_turns``): that is left on the device
-    for :func:`_count_held_turns`. Everything else (``lower``, the cache's
-    counters) is the program's own."""
+    What a program returns after them it has counted — a held share's turns
+    past the first (a scalar), a selection's walks and blocks (a dict by
+    counter) — and that is left on the device for :func:`_count_held_turns`.
+    Everything else (``lower``, the cache's counters) is the program's
+    own."""
 
     def __init__(self, prog):
         self.prog = prog
 
     def __call__(self, *args):
-        first, second, new_kv, *turns = self.prog(*args)
-        _held_turns_pending.extend(turns)
+        first, second, new_kv, *counted = self.prog(*args)
+        for c in counted:
+            _held_turns_pending.extend(
+                c.items() if isinstance(c, dict)
+                else [(_PROGRAM_COUNTERS[0], c)])
         return first, second, new_kv
 
     def __getattr__(self, name):
@@ -503,6 +512,12 @@ class InferenceEngineV2:
             _tracer.note("serve/index/bytes_per_token",
                          kv_cfg.index_dim * item)
             _tracer.note("serve/index/pool_bytes", self.kv.kv[1].nbytes)
+            # the walks ``dsa_select`` took over its tiles and the blocks of
+            # query rows that took them, in passes and decode steps: their
+            # ratio is how soon the bisection's bounds and its stop let go
+            # (ops/pallas/sparse_mla.py, item 2)
+            _tracer.bump("serve/dsa/select_sweeps", 0.0)
+            _tracer.bump("serve/dsa/select_blocks", 0.0)
             log_dist(f"engine_v2: a selection over the latent pages: "
                      f"{self.index['heads']} index heads of "
                      f"{self.index['head_dim']} keep the top "
@@ -1078,14 +1093,16 @@ class InferenceEngineV2:
         from deepspeed_tpu.utils.compile_cache import (setup_summary,
                                                        with_stack_room)
         # set-up's second stage (tracer.stage), a child a family of the grid
-        counted = _tracer.totals.get("serve/moe/held_overflow_turns")
+        counted = {n: _tracer.totals[n] for n in _PROGRAM_COUNTERS
+                   if n in _tracer.totals}
         with _tracer.stage("warmup"):
             built = with_stack_room(lambda: self._warmup(buckets, spec_ks))
-        if counted is not None:
+        if counted:
             # scratch rows are no traffic: a warmed decode step's rows are
             # all alike, so a router may send every one to a held expert
             _held_turns_pending.clear()
-            _tracer.note("serve/moe/held_overflow_turns", counted)
+            for name, before in counted.items():
+                _tracer.note(name, before)
         if not self._setup_logged:      # once: a rejoin warms again
             self._setup_logged = True
             log_dist(f"engine_v2: {setup_summary()}", ranks=[0])

@@ -118,11 +118,42 @@ def _kept(topk: int, seen):
     return jnp.clip(jnp.minimum(seen, topk), 1)
 
 
+def _walks(walks, ctx):
+    """``sparse_mla.select_counted``'s third result as the two counts a
+    program hands back (:func:`_select_counts`): ``[the walks of the blocks
+    of the slots that hold a token, how many such blocks]`` int32."""
+    return jnp.stack([jnp.sum(walks),
+                      jnp.sum(ctx > 0) * walks.shape[1]]).astype(jnp.int32)
+
+
+def _no_walks(pools) -> tuple:
+    """What a layer loop carries last beside an index pool: the selections'
+    :func:`_walks` so far."""
+    return (jnp.zeros((2,), jnp.int32),) if len(pools) > 1 else ()
+
+
+def _select_counts(pools, carried) -> tuple:
+    """What a program with a selection returns after its other results: its
+    layers' :func:`_walks`, summed, under the always-on counters they feed
+    (``engine_v2._ThreeResults``: a dict names its own)."""
+    if len(pools) == 1:
+        return ()
+    return ({"serve/dsa/select_sweeps": carried[-1][0],
+             "serve/dsa/select_blocks": carried[-1][1]},)
+
+
 def select_chunk(spec: RaggedModelSpec, q_idx, w_idx, ipages, block_tables,
                  q0, ctx):
     """The selection of ``NC`` chunk slots' query tokens: ``(tiled scores,
     thr [NC, Cs], pcut [NC, Cs])`` (``sparse_mla.index_scores``,
     ``select``)."""
+    return chunk_selection(spec, q_idx, w_idx, ipages, block_tables, q0,
+                           ctx)[:3]
+
+
+def chunk_selection(spec: RaggedModelSpec, q_idx, w_idx, ipages,
+                    block_tables, q0, ctx):
+    """:func:`select_chunk` and, fourth, the selection's :func:`_walks`."""
     Cs = q_idx.shape[1]
     with jax.named_scope("index"):
         with jax.named_scope("score"):
@@ -131,16 +162,24 @@ def select_chunk(spec: RaggedModelSpec, q_idx, w_idx, ipages, block_tables,
         with jax.named_scope("select"):
             seen = jnp.minimum(ctx[:, None], q0[:, None] + 1 + jnp.arange(
                 Cs, dtype=jnp.int32)[None])
-            thr, pcut = sparse_mla.select(
+            thr, pcut, walks = sparse_mla.select_counted(
                 scores, _kept(spec.mla["index"]["topk"], seen), ctx)
-    return scores, thr, pcut
+    return scores, thr, pcut, _walks(walks, ctx)
 
 
 def select_decode(spec: RaggedModelSpec, q_idx, w_idx, k_own, ipages, rows,
                   block_tables, q_pos, ctx):
+    """:func:`decode_selection` without its fourth result."""
+    return decode_selection(spec, q_idx, w_idx, k_own, ipages, rows,
+                            block_tables, q_pos, ctx)[:3]
+
+
+def decode_selection(spec: RaggedModelSpec, q_idx, w_idx, k_own, ipages,
+                     rows, block_tables, q_pos, ctx):
     """The selection of ``S`` decode rows and its latent rows: ``(rows
     [S, topk, W] gathered from ``rows`` [pages * bs, W], how many of them are
-    live [S], whether the row's OWN token is chosen [S])``.
+    live [S], whether the row's OWN token is chosen [S], the selection's
+    :func:`_walks`)``.
 
     A row at position ``q_pos`` scores the ``ctx`` tokens its pages hold.
     Where the pages hold the row's own token too (``ctx == q_pos + 1``: a
@@ -173,9 +212,9 @@ def select_decode(spec: RaggedModelSpec, q_idx, w_idx, k_own, ipages, rows,
             # the rows of a step as the query rows of ONE slot: the sweeps
             # then fill whole registers (a row a sublane, not a row a tile)
             seen = jnp.minimum(ctx, q_pos + 1) + int(own)
-            thr, pcut = sparse_mla.select(
-                scores.transpose(2, 1, 0, 3), _kept(topk, seen)[None],
-                jnp.max(ctx, keepdims=True) + int(own))
+            top = jnp.max(ctx, keepdims=True) + int(own)
+            thr, pcut, walks = sparse_mla.select_counted(
+                scores.transpose(2, 1, 0, 3), _kept(topk, seen)[None], top)
             thr, pcut = thr[0][:, None], pcut[0][:, None]
         with jax.named_scope("gather"):
             keep = sparse_mla.keep_mask(scores, thr, pcut)[:, 0]  # [S, C*T]
@@ -194,7 +233,7 @@ def select_decode(spec: RaggedModelSpec, q_idx, w_idx, k_own, ipages, rows,
                               precision="highest").astype(jnp.int32)
             got = rows[jnp.where(live, page * bs + chosen % bs, 0)]
     return got, jnp.sum(live, axis=-1, dtype=jnp.int32), \
-        own_on.astype(jnp.int32)
+        own_on.astype(jnp.int32), _walks(walks, top)
 
 
 def _finish(spec, weights, x):
@@ -251,8 +290,9 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
                         for f, r in zip(flats, (lat,) + index[2:]))
                     pages = flats_[0].reshape(L * NB, bs, W)
                     if index:
-                        return (selected(q_nope, q_rope, pages, flats_,
-                                         *index[:2]),) + flats_
+                        out, walks = selected(q_nope, q_rope, pages, flats_,
+                                              *index[:2])
+                        return (out,) + flats_ + (flats[-1] + walks,)
                     with jax.named_scope("absorb"):
                         q = mla_absorb_q(rs, w, q_nope, q_rope, W)
                     with jax.named_scope("prefill"):
@@ -272,13 +312,14 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
                     return (out,) + flats_
 
                 def selected(q_nope, q_rope, pages, flats_, q_idx, w_idx):
-                    """Every row over what its indexer chose: ``[N, H * v]``.
-                    The chunk rows of a pass that holds ONE sequence attend
-                    expanded (:func:`_chunk_expanded`), else absorbed."""
+                    """Every row over what its indexer chose: ``([N, H * v],
+                    the two selections' walks)``. The chunk rows of a pass
+                    that holds ONE sequence attend expanded
+                    (:func:`_chunk_expanded`), else absorbed."""
                     kw = dict(v_dim=R, softmax_scale=_scale(rs))
                     ipages = flats_[1].reshape(L * NB, bs, -1)
                     bt_c = b["chunk_block_tables"] + l * NB
-                    sel = select_chunk(
+                    *sel, walks_c = chunk_selection(
                         rs, q_idx[:CT].reshape((NC, Cs) + q_idx.shape[1:]),
                         w_idx[:CT].reshape(NC, Cs, -1), ipages, bt_c,
                         b["chunk_q0"], b["chunk_ctx_lens"])
@@ -303,7 +344,7 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
                                                    b["chunk_ctx_lens"], 0),
                                 *sel),
                             absorbed)
-                    got, live, _ = select_decode(
+                    got, live, _, walks_d = decode_selection(
                         rs, q_idx[CT:], w_idx[CT:], None, ipages, flats_[0],
                         b["decode_block_tables"] + l * NB,
                         b["decode_ctx_lens"] - 1, b["decode_ctx_lens"])
@@ -313,7 +354,7 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
                         o_d = sparse_mla.attend_decode(q, got, live, **kw)
                     with jax.named_scope("absorb"):
                         return jnp.concatenate([o_c, mla_absorb_o(w, o_d)],
-                                               axis=0)
+                                               axis=0), walks_c + walks_d
 
                 x, flats = _transformer_layer(rs, w, x, positions, attend,
                                               experts=experts, l=l - l0)
@@ -321,10 +362,12 @@ def build_paged_pass(spec: RaggedModelSpec) -> Callable:
 
             return layer_fn
 
+        # (beside an index pool the carry ends in the selections' walks)
         x, *flats = _scan_layers(
             spec, weights["layers"], make_body,
-            (x,) + tuple(p.reshape(L * NB * bs, p.shape[-1]) for p in pools))
-        turns = _stream_turns(x)
+            (x,) + tuple(p.reshape(L * NB * bs, p.shape[-1]) for p in pools)
+            + _no_walks(pools))
+        turns = _stream_turns(x) + _select_counts(pools, flats)
         x = _finish(spec, weights, _stream_out(x))
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))
@@ -446,7 +489,8 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
                     with jax.named_scope("absorb"):
                         q = mla_absorb_q(rs, w, q_nope, q_rope, W)
                     if index:
-                        o_lat = selected(q, lat, *index)
+                        o_lat, walks = selected(q, lat, *index)
+                        sides_ += (sides[-1] + walks,)
                     else:
                         with jax.named_scope("decode"):
                             o_lat = ak.latent(
@@ -456,7 +500,7 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
                         return (mla_absorb_o(w, o_lat),) + sides_
 
                 def selected(q, lat, q_idx, w_idx, k_idx):
-                    got, live, own_on = select_decode(
+                    got, live, own_on, walks = decode_selection(
                         rs, q_idx, w_idx, k_idx.astype(pools[1].dtype),
                         pools[1].reshape(L * NB, bs, -1),
                         pages.reshape(L * NB * bs, W), block_tables + l * NB,
@@ -466,7 +510,7 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
                                       ((0, 0), (0, 7), (0, 0)))
                         return sparse_mla.attend_decode(
                             q, got, live, v_dim=R, softmax_scale=_scale(rs),
-                            side=own, side_on=own_on)
+                            side=own, side_on=own_on), walks
 
                 x, sides = _transformer_layer(
                     rs, w, x, positions, attend, experts=experts, l=l - l0)
@@ -477,8 +521,8 @@ def build_decode_step(spec: RaggedModelSpec, do_sample: bool,
         x, *sides = _scan_layers(
             spec, weights["layers"], make_body,
             (x,) + tuple(jnp.zeros((L, S, 8, p.shape[-1]), p.dtype)
-                         for p in pools))
-        turns = _stream_turns(x)
+                         for p in pools) + _no_walks(pools))
+        turns = _stream_turns(x) + _select_counts(pools, sides)
         logits = _unembed(spec, weights,
                           _finish(spec, weights, _stream_out(x)))
         # the kernels READ the pools inside the layers; the barrier orders

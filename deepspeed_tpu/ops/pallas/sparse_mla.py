@@ -21,11 +21,22 @@ Three pieces of work, each a Mosaic kernel with a plain ``jnp`` twin
    with ``-inf`` where a query may not look (past itself, past the context):
    the form the two readers below take a tile of at a time.
 2. :func:`select` (``dsa_select``): per query row the EXACT k-th largest
-   score — a bisection on the float32 bit pattern, 32 compare-and-count
+   score — a bisection on the float32 bit pattern by compare-and-count
    sweeps of the row in VMEM, no sort — and ``pcut``, the position up to
    which scores EQUAL to it are kept, so that exactly ``k`` are
    (``jax.lax.top_k``'s rule: of equal scores the lower position first).
-   ``keep(s) = I > thr or (I == thr and s <= pcut)``.
+   ``keep(s) = I > thr or (I == thr and s <= pcut)``. A block of query rows
+   (:func:`_select_block`: as many as a walk's fixed cost wants and VMEM
+   holds) walks its tiles once for bounds — the row's largest score above,
+   the smallest of 2,048 disjoint groups' maxima below, which the k-th
+   largest cannot lie under — then once a HALVING of what lies between, and
+   stops when every row has a candidate that counts exactly ``k`` scores at
+   or over it: no score lies between that candidate and the k-th largest,
+   so one more walk for the smallest score at or over it gives ``thr``, and
+   no tie is cut. Some 25 walks where a sweep a bit took 1 + 32 + 2; a row
+   whose ties outnumber its quota runs to the last bit and through 32 more
+   for ``pcut``, as it always did. :func:`select_counted` hands back the
+   walks a block took.
 3. :func:`attend_decode` (``dsa_attend_decode``): a decode row's absorbed
    attention over its chosen rows, gathered by XLA into ``[N, K, W]``
    (:func:`chosen_positions` compacts the kept positions without a sort,
@@ -59,7 +70,6 @@ LANES = 128
 CHUNK_TILE = 512
 #: keys a tile of a decode row's scores (a copy of 512 KiB of index keys)
 DECODE_TILE = 2048
-_INT_MIN = -(1 << 31)
 
 
 def tile_pages(block_size: int, max_blocks: int, tile: int) -> int:
@@ -299,58 +309,215 @@ def _unsortable(k):
         jnp.where(k < 0, k ^ jnp.int32(0x7FFFFFFF), k), jnp.float32)
 
 
-def _select_kernel(ext_ref, s_ref, k_ref, thr_ref, pcut_ref, key_sc, *,
-                   tile):
+#: float32 registers (8 sublanes x 128 lanes) a tile of a row block may fill
+#: (a sweep keeps a candidate and a running count a row beside the tile it
+#: reads, in a register file of 64), and those an iteration of a sweep's
+#: loop reads where a tile is smaller
+_SELECT_TILE_REGISTERS = 64
+_SELECT_BODY_REGISTERS = 32
+#: bytes of scores a grid step may hold (Pallas keeps two such blocks)
+_SELECT_BLOCK_BYTES = 18 << 20
+#: the most a row may keep for the first walk to bound its threshold from
+#: below (GLM-5 keeps 2,048): that many disjoint groups of a row's scores
+#: keep their maxima, ``[groups // T, rows, T]`` float32 of VMEM
+_SELECT_GROUPS = 2048
+_KEY_NEG_INF = -2139095041           # _sortable(-inf)
+
+
+def _select_block(R: int, C: int, T: int) -> Tuple[int, int]:
+    """``(query rows a grid step, tiles a loop iteration)`` of
+    :func:`select` for ``R`` rows of ``C`` tiles of ``T`` scores: the most
+    rows whose tile fills no more than :data:`_SELECT_TILE_REGISTERS`
+    registers and whose block :data:`_SELECT_BLOCK_BYTES` — what a walk
+    costs beside its loads (the counts' sum along the lanes, the next
+    candidate, the scalar that ends the bisection: some 0.4 us on a v5e) is
+    then spread over them — and as many tiles an iteration as
+    :data:`_SELECT_BODY_REGISTERS` hold."""
+    fits = [r for r in range(8, R + 1, 8) if R % r == 0
+            and r // 8 * (T // LANES) <= _SELECT_TILE_REGISTERS
+            and C * r * T * 4 <= _SELECT_BLOCK_BYTES]
+    rows = max(fits) if fits else (8 if R % 8 == 0 else R)
+    registers = -(-rows // 8) * (T // LANES)
+    return rows, max(1, min(C, _SELECT_BODY_REGISTERS // registers))
+
+
+def _select_kernel(ext_ref, s_ref, k_ref, thr_ref, pcut_ref, sweeps_ref,
+                   gmax_sc, *, tile, unroll):
     """One grid step = (slot, block of query rows): the rows' tiles of
-    scores are in VMEM; every sweep counts over the ``ext`` tiles that can
-    hold a valid score."""
+    scores are in VMEM and every sweep walks the ``ext`` tiles that can hold
+    a valid score, ``unroll`` tiles a loop iteration, a PIECE of a tile at a
+    time: 16 registers of it (128 lanes of 128 rows, 1,024 of 16), beside as
+    many of the candidate and of the running result. The walk compares the
+    float32 scores themselves (-0.0 is +0.0 to it, as to a sort); only the
+    candidates are bisected as sortable integers, a column a row."""
     n = pl.program_id(0)
     ext = ext_ref[n]
-    rows = s_ref.shape[2]
+    C, rows = s_ref.shape[1], s_ref.shape[2]
+    G = gmax_sc.shape[0]
+    W = LANES * max(1, min(tile // LANES, 16 // max(1, rows // 8)))
+    while tile % W:
+        W -= LANES
     want = k_ref[0]                                          # [rows, 1]
-    zeros = jnp.zeros((rows, tile), jnp.int32)
+    wide = lambda col: jnp.broadcast_to(col, (rows, W))
+    # [rows, W] -> [rows, 1] by ``op``, the lanes' registers first
+    down = lambda op, along, x: along(functools.reduce(op, [
+        x[:, j:j + LANES] for j in range(0, W, LANES)]), axis=1,
+        keepdims=True)
 
-    def fill(c, carry):
-        key_sc[c] = _sortable(s_ref[0, c])
-        return carry
+    def walk(step, carry, U=unroll):
+        """``carry = step(c, u, carry)`` over the tiles: ``U`` an iteration
+        (``u`` the tile's place in it), then what ``C % U`` leaves. The last
+        iteration may run past ``ext``: those tiles hold -inf alone, which
+        is under every candidate and counts for none."""
+        def group(i, carry):
+            for u in range(U):
+                carry = step(i * U + u, u, carry)
+            return carry
 
-    jax.lax.fori_loop(0, ext, fill, 0)
+        groups = jnp.minimum(jax.lax.div(ext + (U - 1), U), C // U)
+        carry = jax.lax.fori_loop(0, groups, group, carry)
+        if C % U == 0:
+            return carry
+        return jax.lax.fori_loop(groups * U, ext,
+                                 lambda c, carry: step(c, 0, carry), carry)
+
+    def pieces(op, init):
+        """``acc = op(acc, piece [rows, W], its first position)`` over every
+        piece of every tile: ``[rows, W]``."""
+        def step(c, u, acc):
+            for j in range(0, tile, W):
+                acc = op(acc, s_ref[0, c, :, j:j + W], (c, j))
+            return acc
+
+        return walk(step, jnp.full((rows, W), init))
 
     def count(pred):
-        """Per row, the keys of the first ``ext`` tiles ``pred(key, c)``
+        """Per row the scores ``pred(piece, (tile, lane of its first))``
         holds for: ``[rows, 1]``."""
-        acc = jax.lax.fori_loop(
-            0, ext, lambda c, a: a + pred(key_sc[c], c).astype(jnp.int32),
-            zeros)
-        return jnp.sum(acc, axis=1, keepdims=True)
+        return down(jnp.add, jnp.sum, pieces(
+            lambda acc, s, at: acc + pred(s, at).astype(jnp.int32),
+            jnp.int32(0)))
 
-    # the largest v with count(key >= v) >= k, a bit at a time from the top
-    # (int32 wraps: INT_MIN + 2^31 = 0)
-    def bit(i, lo):
-        cand = lo + jnp.left_shift(jnp.int32(1), 31 - i)
-        return jnp.where(count(lambda k, c: k >= cand) >= want, cand, lo)
+    # 1. bounds, from one walk: the maxima of G * tile disjoint groups of a
+    # row's scores (a tile's index modulo G, a score's place in its tile).
+    # The largest is the row's largest; each group that is not empty holds
+    # a score at or over the smallest, so the k-th largest is at or over it
+    # where k is no more than the groups (-inf where a group is empty: no
+    # narrowing, still exact)
+    gmax_sc[...] = jnp.full(gmax_sc.shape, -jnp.inf, jnp.float32)
 
-    lo = jax.lax.fori_loop(0, 32, bit,
-                           jnp.full((rows, 1), _INT_MIN, jnp.int32))
-    above = count(lambda k, c: k > lo)
-    equal = count(lambda k, c: k == lo)
-    quota = want - above                  # of the equal ones, the first few
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+    def maxima(c, u, carry):
+        gmax_sc[u] = jnp.maximum(gmax_sc[u], s_ref[0, c])
+        return carry
 
-    # the largest p with count(key == thr and pos < p) < quota: position p
-    # holds the last equal score kept. Only where a row has more equal
-    # scores than it may keep (17 sweeps more; else none)
-    def pbit(i, p):
-        cand = p + jnp.left_shift(jnp.int32(1), 30 - i)
-        seen = count(lambda k, c: jnp.logical_and(k == lo,
-                                                  c * tile + col < cand))
-        return jnp.where(seen < quota, cand, p)
+    walk(maxima, 0, G)
+    fold = lambda op, along: down(op, along, functools.reduce(op, [
+        gmax_sc[g, :, j:j + W] for g in range(G) for j in range(0, tile, W)]))
+    top = fold(jnp.maximum, jnp.max)
+    low = jnp.where(want <= G * tile, fold(jnp.minimum, jnp.min), -jnp.inf)
+    lo0 = jnp.maximum(_sortable(low), jnp.int32(_KEY_NEG_INF))
+    hi0 = jnp.maximum(_sortable(top), lo0)
 
-    crowded = jnp.max(jnp.where(equal > quota, 1, 0))
-    p = jax.lax.fori_loop(0, jnp.where(crowded > 0, 31, 0), pbit,
+    # 2. the largest v of [lo, hi] with count(score >= v) >= k, halving the
+    # interval a sweep (the differences wrap: read as unsigned). A row whose
+    # candidate counts exactly k is done — no score lies between the
+    # candidate and the k-th largest — and the block is when every row is
+    def halve(carry):
+        lo, hi, seen, _, sweeps = carry
+        span = hi - lo
+        mid = lo + jax.lax.shift_right_logical(span, 1) + (span & 1)
+        cand = wide(_unsortable(mid))
+        cnt = count(lambda s, _: s >= cand)
+        ok = cnt >= want
+        lo = jnp.where(ok, mid, lo)
+        hi = jnp.where(cnt == want, mid, jnp.where(ok, hi, mid - 1))
+        return (lo, hi, jnp.where(ok, cnt, seen),
+                jnp.max(jnp.where(hi != lo, 1, 0)), sweeps + 1)
+
+    lo, _, seen, _, sweeps = jax.lax.while_loop(
+        lambda carry: carry[3] > 0, halve,
+        (lo0, hi0, jnp.full((rows, 1), -1, jnp.int32),
+         jnp.max(jnp.where(hi0 != lo0, 1, 0)), jnp.int32(1)))
+
+    # 3. the threshold is the smallest score at or over what was accepted
+    # (+inf for a row that holds none: it keeps nothing)
+    floor, none = wide(_unsortable(lo)), jnp.full((rows, W), jnp.inf)
+    thr = down(jnp.minimum, jnp.min, pieces(
+        lambda m, s, _: jnp.minimum(m, jax.lax.select(s >= floor, s, none)),
+        jnp.float32(jnp.inf)))
+    thr = jnp.where(thr == 0.0, 0.0, thr)
+    edge = wide(thr)
+
+    # count(score >= thr) is the accepted candidate's own count; a row that
+    # accepted none (its lower bound was its threshold) is counted now
+    blind = jnp.max(jnp.where(seen < 0, 1, 0))
+    seen = jax.lax.fori_loop(
+        0, blind, lambda _, seen: jnp.where(
+            seen < 0, count(lambda s, _: s >= edge), seen), seen)
+
+    # 4. only where a row has more scores EQUAL to its threshold than it may
+    # keep (1 + 31 sweeps more; else none): the largest p with
+    # count(score == thr and pos < p) < quota — position p holds the last
+    # equal score kept
+    crowded = seen > want
+    any_crowded = jnp.max(jnp.where(crowded, 1, 0))
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, W), 1)
+
+    def cut(_, p):
+        quota = want - count(lambda s, _: s > edge)
+
+        def pbit(i, p):
+            cand = p + jnp.left_shift(jnp.int32(1), 30 - i)
+            at = wide(cand)
+            return jnp.where(count(lambda s, cj: jnp.logical_and(
+                s == edge, cj[0] * tile + cj[1] + col < at)) < quota, cand, p)
+
+        return jax.lax.fori_loop(0, 31, pbit, p)
+
+    p = jax.lax.fori_loop(0, any_crowded, cut,
                           jnp.zeros((rows, 1), jnp.int32))
-    thr_ref[0] = _unsortable(lo)
-    pcut_ref[0] = jnp.where(equal > quota, p, jnp.int32(2**31 - 1))
+    thr_ref[0] = thr
+    pcut_ref[0] = jnp.where(crowded, p, jnp.int32(2**31 - 1))
+    # (a slot that holds nothing walks nothing: it counts for none)
+    sweeps_ref[n, pl.program_id(1)] = jnp.where(
+        ext > 0, sweeps + 1 + blind + 32 * any_crowded, 0)
+
+
+def select_counted(scores: jax.Array, k: jax.Array, ctx_lens: jax.Array
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`select` and, third, the walks over its tiles each block of
+    query rows took: ``[N, R // rows a block]`` int32 (the bounds, a sweep a
+    halving, the threshold's; 32 more where a row's ties were cut; 0 for a
+    slot whose ``ctx_lens`` is 0)."""
+    N, C, R, T = scores.shape
+    assert T % LANES == 0, T
+    Rs, U = _select_block(R, C, T)
+    assert R % Rs == 0 and (Rs % 8 == 0 or Rs == R), (R, Rs)
+    G = max(1, min(C, _SELECT_GROUPS // T))
+    ext = jnp.clip((ctx_lens.astype(jnp.int32) + T - 1) // T, 0, C)
+    idx = lambda n, r, *_: (n, r, 0)
+    call = pl.pallas_call(
+        functools.partial(_select_kernel, tile=T, unroll=U),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(N, R // Rs),
+            in_specs=[pl.BlockSpec((1, C, Rs, T),
+                                   lambda n, r, *_: (n, 0, r, 0)),
+                      pl.BlockSpec((1, Rs, 1), idx)],
+            out_specs=[pl.BlockSpec((1, Rs, 1), idx),
+                       pl.BlockSpec((1, Rs, 1), idx),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.VMEM((G, Rs, T), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((N, R, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((N, R, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((N, R // Rs), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=(2 * C + G) * Rs * T * 4 + (8 << 20)),
+        interpret=_backend.interpret(),
+    )
+    with jax.named_scope("dsa_select"):
+        thr, pcut, sweeps = call(ext, scores, k.astype(jnp.int32)[..., None])
+    return thr[..., 0], pcut[..., 0], sweeps
 
 
 def select(scores: jax.Array, k: jax.Array, ctx_lens: jax.Array
@@ -365,30 +532,7 @@ def select(scores: jax.Array, k: jax.Array, ctx_lens: jax.Array
     positions of the row satisfy ``score > thr or (score == thr and position
     <= pcut)`` — of equal scores the lower positions, ``jax.lax.top_k``'s
     rule."""
-    N, C, R, T = scores.shape
-    Rs = R if R <= 16 else 16
-    assert R % Rs == 0 and (Rs % 8 == 0 or Rs == R), (R, Rs)
-    ext = jnp.clip((ctx_lens.astype(jnp.int32) + T - 1) // T, 0, C)
-    idx = lambda n, r, *_: (n, r, 0)
-    call = pl.pallas_call(
-        functools.partial(_select_kernel, tile=T),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(N, R // Rs),
-            in_specs=[pl.BlockSpec((1, C, Rs, T),
-                                   lambda n, r, *_: (n, 0, r, 0)),
-                      pl.BlockSpec((1, Rs, 1), idx)],
-            out_specs=[pl.BlockSpec((1, Rs, 1), idx),
-                       pl.BlockSpec((1, Rs, 1), idx)],
-            scratch_shapes=[pltpu.VMEM((C, Rs, T), jnp.int32)]),
-        out_shape=[jax.ShapeDtypeStruct((N, R, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((N, R, 1), jnp.int32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=_backend.interpret(),
-    )
-    with jax.named_scope("dsa_select"):
-        thr, pcut = call(ext, scores, k.astype(jnp.int32)[..., None])
-    return thr[..., 0], pcut[..., 0]
+    return select_counted(scores, k, ctx_lens)[:2]
 
 
 def select_reference(scores, k, ctx_lens=None):

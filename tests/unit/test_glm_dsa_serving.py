@@ -21,7 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 from deepspeed_tpu.inference.v2 import (  # noqa: E402
-    adapters, model_spec as ms, ragged_mla)
+    adapters, engine_v2, model_spec as ms, ragged_mla)
 from deepspeed_tpu.inference.v2.attention import INDEX_POOL_MSG  # noqa: E402
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2  # noqa: E402
 from deepspeed_tpu.inference.v2.ragged.kv_cache import KVCacheConfig  # noqa: E402
@@ -227,6 +227,32 @@ def test_engine_holds_both_pools_and_says_what_a_token_costs(served):
     assert tracer.totals["serve/index/bytes_per_token"] == 128 * 4
     assert tracer.totals["serve/index/topk"] == 24
     assert tracer.totals["serve/index/pool_bytes"] == idx.nbytes
+
+
+def test_the_selections_walks_and_blocks_are_counted():
+    """A paged pass and fused decode steps hand back their ``dsa_select``
+    calls' walks and blocks beside their results, and the drain adds them to
+    ``serve/dsa/select_sweeps`` / ``select_blocks`` by name: a block walks
+    its tiles at least twice (the bounds and the threshold) and no more
+    often than a sweep a bit and a cut did; warm-up's scratch rows are
+    counted out."""
+    names = ("serve/dsa/select_sweeps", "serve/dsa/select_blocks")
+    _, model, params = build()
+    eng = engine_for(model, params)
+    eng.warmup()
+    zero = [tracer.totals[n] for n in names]
+    eng.put([1], [IDS[:40]])
+    eng.decode_pipeline([1]).run(3)
+    # (a drain reads what has finished and waits for nothing: wait here)
+    jax.block_until_ready([c for _, c in engine_v2._held_turns_pending])
+    last_logits(eng, 1)
+    sweeps, blocks = [tracer.totals[n] - z for n, z in zip(names, zero)]
+    # 4 layers; a chunk slot's 16 rows are one block, a step's rows one: a
+    # pass of 32 tokens, one of 8, three steps
+    assert blocks == (2 + 1 + 3) * 4, blocks
+    assert 2 * blocks <= sweeps <= (1 + 32 + 2 + 31) * blocks, (sweeps,
+                                                                 blocks)
+    eng.flush([1])
 
 
 def test_adapter_reads_the_indexer_and_joyai_has_none():

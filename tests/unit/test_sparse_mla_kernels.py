@@ -2,6 +2,8 @@
 each against its XLA twin and against a float32 formula written here, at
 contexts below, at and above ``topk``, across a page boundary, with ties."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,34 +80,181 @@ def _scores(seed, n, r, ctx, ties):
     return flat
 
 
-@pytest.mark.parametrize("ties", [False, True])
-@pytest.mark.parametrize("rows,tile", [(1, 256), (16, 128), (32, 128)])
-def test_select_keeps_exactly_k_as_top_k_does(rows, tile, ties):
+def _drawn(rows, tile, ties):
+    """Six contexts below, at and above ``topk`` = 24, a slot each."""
     ctx = [1, 7, 24, 25, 130, 256]
-    topk = 24
     flat = _scores(1, len(ctx), rows, ctx, ties)
-    scores = _tiled(flat, tile)
-    k = jnp.broadcast_to(jnp.minimum(jnp.asarray(ctx), topk)[:, None],
-                         (len(ctx), rows)).astype(jnp.int32)
+    k = np.broadcast_to(np.minimum(ctx, 24)[:, None], (len(ctx), rows))
+    return dict(flat=flat, k=k, ctx=ctx, tile=tile, cut=ties, compact=True)
+
+
+def _bits(x):
+    return np.asarray(x, np.uint32).view(np.float32)
+
+
+def _signed_zeros():
+    """Scores of both signs, a quarter of them zeros of either sign. The
+    threshold above the zeros, AT them (every zero kept, whichever its
+    sign: a comparison holds them equal) and below them."""
+    rng = np.random.default_rng(2)
+    flat = rng.standard_normal((3, 16, 256)).astype(np.float32)
+    zero = rng.random(flat.shape) < 0.25
+    flat[zero] = np.where(rng.random(flat.shape) < 0.5, 0.0, -0.0)[zero]
+    flat[2, 5] = -np.abs(flat[2, 5])       # a row whose largest is -0.0
+    k = np.stack([(flat[0] > 0).sum(-1), (flat[1] >= 0).sum(-1),
+                  (flat[2] >= 0).sum(-1) + 5])
+    return dict(flat=flat, k=k, ctx=[256] * 3, tile=128, cut=False)
+
+
+def _subnormals():
+    """Subnormal scores of both signs (and the smallest of all) among
+    ordinary ones; the threshold the smallest ordinary score above them,
+    then the largest below them."""
+    rng = np.random.default_rng(3)
+    flat = rng.standard_normal((2, 16, 256)).astype(np.float32)
+    tiny = rng.random(flat.shape) < 0.3
+    small = _bits(rng.integers(1, 1 << 23, flat.shape)
+                  | (rng.integers(0, 2, flat.shape) << 31))
+    flat[tiny] = small[tiny]
+    flat[:, :, 3], flat[:, :, 4] = _bits(1), _bits((1 << 31) | 1)
+    k = np.stack([(flat[0] > 1e-30).sum(-1), (flat[1] > -1e-30).sum(-1) + 1])
+    return dict(flat=flat, k=k, ctx=[256] * 2, tile=128, cut=False)
+
+
+def _exponents():
+    """Sixty powers of ten in one row, both signs, any k."""
+    rng = np.random.default_rng(4)
+    flat = (rng.choice([-1.0, 1.0], (4, 16, 256))
+            * 10.0 ** rng.uniform(-30, 30, (4, 16, 256))).astype(np.float32)
+    ctx = [256, 200, 129, 31]
+    for i, c in enumerate(ctx):
+        flat[i, :, c:] = -np.inf
+    k = np.stack([rng.integers(1, c + 1, 16) for c in ctx])
+    return dict(flat=flat, k=k, ctx=ctx, tile=128, cut=False)
+
+
+def _one_value():
+    """A row of ONE value: the bounds meet before any halving, and the
+    count at the threshold is taken after it."""
+    flat = np.full((3, 16, 256), -np.inf, np.float32)
+    ctx = [256, 100, 9]
+    for i, (c, v) in enumerate(zip(ctx, [0.5, -3.0, 0.0])):
+        flat[i, :, :c] = v
+    k = np.stack([np.full(16, 7), np.arange(1, 17) * 6, np.full(16, 9)])
+    return dict(flat=flat, k=k, ctx=ctx, tile=128, cut=True)
+
+
+def _k_extremes():
+    """k = 1 and k = every finite score, row by row of one block."""
+    ctx = [256, 130, 24, 1]
+    flat = _scores(5, len(ctx), 16, ctx, False)
+    k = np.stack([np.where(np.arange(16) % 2, c, 1) for c in ctx])
+    return dict(flat=flat, k=k, ctx=ctx, tile=128, cut=False)
+
+
+def _stops_differ():
+    """Rows of ONE block that reach a candidate counting exactly k at
+    different sweeps: row r's scores lie 2**-r apart around 1, around -1 and
+    around 0."""
+    rng = np.random.default_rng(6)
+    steps = rng.permutation(256)[None, None, :].astype(np.float32) - 128
+    flat = (np.asarray([1.0, -1.0, 0.0], np.float32)[:, None, None]
+            + steps * 2.0 ** -np.arange(16, dtype=np.float32)[None, :, None])
+    k = rng.integers(1, 257, (3, 16))
+    return dict(flat=flat.astype(np.float32), k=k, ctx=[256] * 3, tile=128,
+                cut=False)
+
+
+def _crowded_beside_early():
+    """Slot 0: a row whose ties outnumber its quota beside fifteen that stop
+    early; slot 1: sixteen that stop early. The first block runs to the last
+    bit and through the cut; the second takes fewer walks than a sweep a
+    bit."""
+    flat = _scores(7, 2, 16, [256, 256], False)
+    flat[0, 3] = np.round(flat[0, 3])
+    k = np.full((2, 16), 24)
+    return dict(flat=flat, k=k, ctx=[256] * 2, tile=128, cut=True,
+                crowded=[(0, 3)], walks=lambda w: (w[0, 0] >= 34 + 31
+                                                   and w[1, 0] < 34))
+
+
+def _tiles_and_unroll():
+    """20 tiles of 128 walked 16 an iteration (``_select_block``): ``ext``
+    of 1, one under the unroll (the iteration runs a tile past it: -inf
+    alone), the unroll, one over it (a tile for the second loop) and C; the
+    last slot keeps more than the 2,048 groups of the bounds' walk (no
+    lower bound from them)."""
+    assert sm._select_block(16, 20, 128) == (16, 16)
+    ctx = [100, 15 * 128, 16 * 128 - 5, 16 * 128 + 1, 2560, 2560]
+    rng = np.random.default_rng(8)
+    flat = rng.standard_normal((len(ctx), 16, 2560)).astype(np.float32)
+    for i, c in enumerate(ctx):
+        flat[i, :, c:] = -np.inf
+    k = np.stack([rng.integers(1, c + 1, 16) for c in ctx])
+    k[-1] = rng.integers(2049, 2561, 16)
+    return dict(flat=flat, k=k, ctx=ctx, tile=128, cut=False)
+
+
+def _no_lower_bound():
+    """Blocks whose lower bound is -inf: each row sees fewer scores than the
+    bounds' walk has groups, and some of the groups are empty."""
+    ctx = [40, 129, 255]
+    flat = _scores(9, len(ctx), 32, ctx, False)
+    k = np.stack([np.full(32, 24), np.full(32, 100), np.arange(1, 33) * 7])
+    return dict(flat=flat, k=k, ctx=ctx, tile=128, cut=False)
+
+
+SELECT_CASES = {
+    **{f"{rows}-{tile}-{ties}": functools.partial(_drawn, rows, tile, ties)
+       for ties in (False, True)
+       for rows, tile in [(1, 256), (16, 128), (32, 128)]},
+    "signed-zeros": _signed_zeros, "subnormals": _subnormals,
+    "exponents": _exponents, "one-value": _one_value,
+    "k-extremes": _k_extremes, "stops-differ": _stops_differ,
+    "crowded-beside-early": _crowded_beside_early,
+    "tiles-and-unroll": _tiles_and_unroll, "no-lower-bound": _no_lower_bound,
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+def test_select_keeps_exactly_k_as_top_k_does(case):
+    c = SELECT_CASES[case]()
+    flat, ctx = c["flat"], c["ctx"]
+    k = jnp.asarray(c["k"], jnp.int32)
+    scores = _tiled(flat, c["tile"])
     cl = jnp.asarray(ctx, jnp.int32)
-    thr, pcut = sm.select(scores, k, cl)
+    thr, pcut, walks = sm.select_counted(scores, k, cl)
     thr_t, pcut_t = sm.select_reference(scores, k, cl)
     np.testing.assert_array_equal(np.asarray(thr), np.asarray(thr_t))
     np.testing.assert_array_equal(np.asarray(pcut), np.asarray(pcut_t))
+    for got, same in zip(sm.select(scores, k, cl), (thr, pcut)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
     keep = np.asarray(sm.keep_mask(scores, thr, pcut))
     # the formula: jax.lax.top_k on the float32 scores (equal scores: the
     # lower position first)
-    _, idx = jax.lax.top_k(jnp.asarray(flat), topk)
+    _, idx = jax.lax.top_k(jnp.asarray(flat), int(k.max()))
     want = np.zeros_like(keep)
-    for i, c in enumerate(ctx):
-        for r in range(rows):
-            want[i, r, np.asarray(idx[i, r, :min(c, topk)])] = True
+    for i in range(flat.shape[0]):
+        for r in range(flat.shape[1]):
+            want[i, r, np.asarray(idx[i, r, :int(k[i, r])])] = True
     np.testing.assert_array_equal(keep, want)
-    if ties:
-        assert (np.asarray(pcut) < 2**31 - 1).any(), "no row had a tie to cut"
+    cut = np.asarray(pcut) < 2**31 - 1
+    assert cut.any() == c["cut"], "a row had a tie to cut, or none had"
+    if "crowded" in c:
+        assert sorted(zip(*np.nonzero(cut))) == c["crowded"]
+    # a block of rows walks its tiles for the bounds, a halving at a time
+    # and for the threshold; a sweep a bit was 1 + 32 + 2
+    walks = np.asarray(walks)
+    assert walks.shape == (flat.shape[0], flat.shape[1] // sm._select_block(
+        flat.shape[1], scores.shape[1], c["tile"])[0])
+    assert (walks >= 2).all() and (walks <= 2 + 32 + 1 + 32).all(), walks
+    assert c.get("walks", lambda w: True)(walks), walks
+    if not c.get("compact"):
+        return
     # the decode rows' compaction of it
+    topk = int(k.max())
     pos = np.asarray(sm.chosen_positions(jnp.asarray(keep[:, 0]), topk))
-    for i, c in enumerate(ctx):
+    for i in range(len(ctx)):
         chosen = np.flatnonzero(want[i, 0])
         np.testing.assert_array_equal(pos[i, :len(chosen)], chosen)
         assert (pos[i, len(chosen):] == 256).all()
