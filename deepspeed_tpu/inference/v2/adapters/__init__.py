@@ -22,6 +22,7 @@ from deepspeed_tpu.inference.v2.adapters.joyai import (adapt_glm_dsa,
 from deepspeed_tpu.inference.v2.adapters.llama import adapt_llama
 from deepspeed_tpu.inference.v2.adapters.nemotron_h import adapt_nemotron_h
 from deepspeed_tpu.inference.v2.adapters.qwen3_next import adapt_qwen3_next
+from deepspeed_tpu.inference.v2.adapters.sdar import adapt_sdar
 from deepspeed_tpu.inference.v2.adapters.zaya import adapt_zaya
 from deepspeed_tpu.inference.v2.model_spec import RaggedModelSpec
 
@@ -78,6 +79,11 @@ ADAPTERS: Dict[str, Callable] = {
     # and its normaliser in the state pool: PowerKind, _pr_mixer), q and k
     # normed and rotated in front of it; no layer holds pages
     "brumby": adapt_brumby,
+    # a plain GQA MoE decoder (q/k norm, 128 small experts, all held) that
+    # GENERATES by diffusion over blocks: attention causal by blocks of
+    # ``spec.causal_block`` positions, a block of mask tokens denoised in
+    # place (build_block_step, blocks/pipeline.py)
+    "sdar_moe": adapt_sdar,
 }
 
 #: families whose attention needs a bias the ragged kernels don't carry —

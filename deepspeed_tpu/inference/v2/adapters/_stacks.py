@@ -16,6 +16,20 @@ def _stack(trees: List[Any]) -> Any:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
+def _stack_leaf_by_leaf(trees: List[Any]) -> Any:
+    """:func:`_stack`, waiting for each leaf's stack before the next leaf is
+    begun. Layers handed over on the host are put on the device as they are
+    stacked, and nothing waits for a dispatch: :func:`_stack` has every
+    leaf's layers AND every leaf's stack on the device before the first copy
+    is done and freed — the layers' bytes twice, which six layers of 128
+    experts (7.25 GiB) do not leave room for beside what a 16 GB chip holds
+    later. Here the device holds the stacks made so far, and one leaf twice
+    (15.38 GiB -> under the weights and pool's own 13.7 as the engine comes
+    up: chip, PR 61)."""
+    return jax.tree_util.tree_map(
+        lambda *xs: jax.block_until_ready(jnp.stack(xs)), *trees)
+
+
 def _stack_units(spec: RaggedModelSpec, layer: Callable[[int], Any]) -> Tuple:
     """``weights["layers"]`` of a model of several kinds, from ``layer(i)``
     (layer ``i``'s canonical weights): one entry per unit of

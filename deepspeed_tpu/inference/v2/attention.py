@@ -79,6 +79,16 @@ STATE_SNAPSHOT_MSG = (
     "no program writes")
 
 
+#: .. and beside generation by diffusion over blocks (``spec.causal_block``
+#: > 1: attention causal by blocks, a block denoised in place over the pages)
+BLOCK_DIFFUSION_MSG = (
+    "{what} is not wired for a model that generates by diffusion over "
+    "blocks (causal_block > 1): a block's positions are written to the "
+    "pages several times before their tokens are final, and only the chunk "
+    "kernel knows the block rule (docs/SERVING.md \"Block-diffusion "
+    "generation\")")
+
+
 class AttentionKernelSpec:
     """Kernel dispatch for one model spec on one mesh.
 
@@ -123,9 +133,12 @@ class AttentionKernelSpec:
             self._decode = functools.partial(
                 paged_decode_attention, window=spec.window, alibi=spec.alibi,
                 **scale)
+            # (the block rule of a model that generates by diffusion over
+            # blocks; 1 for every other)
             self._chunk = functools.partial(
                 paged_chunk_attention_batched, window=spec.window,
-                alibi=spec.alibi, **scale)
+                alibi=spec.alibi, **scale,
+                causal_block=getattr(spec, "causal_block", 1))
             self._step = functools.partial(
                 paged_decode_attention_step, window=spec.window,
                 alibi=spec.alibi, **scale)
@@ -160,6 +173,48 @@ class AttentionKernelSpec:
         decode, preempt-offload and the cross-engine page fabric (the PR
         that collapsed those three former refusals into this table)."""
         tp = cfg.tensor_parallel
+        if getattr(spec, "causal_block", 1) > 1:
+            B = spec.causal_block
+            if B & (B - 1):
+                raise ValueError(
+                    f"causal_block={B} is not a power of two: a row's causal "
+                    "limit is q_pos | (B - 1) in the chunk kernel")
+            sm = cfg.state_manager
+            if sm.chunk_slot_size % B or cfg.kv_cache.block_size % B:
+                raise ValueError(
+                    f"chunk slot size {sm.chunk_slot_size} and page size "
+                    f"{cfg.kv_cache.block_size} must be multiples of "
+                    f"causal_block={B}: a block split over two chunk slots "
+                    "or two pages' walks could not see its later half")
+            attn = getattr(cfg, "attention", None)
+            refused = {
+                "spec_decode.enabled (a verify step's k + 1 rows are causal "
+                "by position)": cfg.spec_decode.enabled,
+                "prefix_cache.enabled (a cached page would have to end on a "
+                "committed block)": cfg.prefix_cache.enabled,
+                "kv_quant.enabled (a denoise pass's rows would be quantized "
+                "and re-quantized in place)": cfg.kv_quant.enabled,
+                "a sliding window (the page ring aliases the block's write "
+                "span)": spec.window is not None
+                or bool(spec.layer_kinds),
+                "tensor_parallel > 1 (the block step runs outside any "
+                "shard_map)": tp > 1,
+                "lora.enabled (the block step takes no adapter operands)":
+                    cfg.lora.enabled,
+                "attention.decode_splits > 1 (the split-K chunk kernel is "
+                "causal by position)":
+                    attn is not None and attn.decode_splits > 1,
+            }   # (serving.preemption is the frontend's to refuse, export_kv
+            #      / import_kv the engine's)
+            for what, on in refused.items():
+                if on:
+                    raise NotImplementedError(BLOCK_DIFFUSION_MSG.format(
+                        what=what))
+            if spec.mla is not None or spec.mamba is not None \
+                    or spec.cca is not None or spec.alibi:
+                raise NotImplementedError(BLOCK_DIFFUSION_MSG.format(
+                    what="latent pages, a recurrent state, a convolution "
+                    "tail or ALiBi"))
         # latent pages (multi-head latent attention): one row a token a
         # layer with no head axis. What reads or moves pages by the K/V
         # pair's shape, or shards them by heads, cannot carry them yet

@@ -568,6 +568,57 @@ class AttentionConfig:
 
 
 @dataclass
+class BlockDecodeConfig:
+    """Generation by diffusion over blocks (``inference/v2/blocks/``;
+    docs/SERVING.md "Block-diffusion generation") — read only by an engine
+    whose model generates so (``spec.causal_block`` > 1; there is no other
+    way to generate from such a model, so nothing here switches it on).
+
+    A block of ``causal_block`` mask tokens takes ``denoising_steps`` denoise
+    passes and one commit pass. ``remasking``:
+
+    - ``"low_confidence_static"``: pass ``i`` of a block fills the
+      ``num_transfer_tokens(block, steps)[i]`` masked positions of highest
+      confidence (``block // steps`` each, the first ``block % steps`` passes
+      one more; what is left, if fewer). The schedule needs nothing from the
+      device, so the pipeline builds pass N + 1 while pass N runs.
+    - ``"low_confidence_dynamic"``: a pass fills every masked position whose
+      confidence is over ``confidence_threshold`` if those are at least the
+      static count, else the static count — a block may finish in fewer
+      passes, and the host reads one int32 row a pass (masks left a row)
+      before it builds the next.
+
+    Greedy only for now (``do_sample`` is refused)."""
+    denoising_steps: int = 4
+    remasking: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.denoising_steps < 1:
+            raise ValueError("block_decode.denoising_steps must be >= 1, got "
+                             f"{self.denoising_steps}")
+        if self.remasking not in ("low_confidence_static",
+                                  "low_confidence_dynamic"):
+            raise ValueError(
+                "block_decode.remasking must be 'low_confidence_static' or "
+                f"'low_confidence_dynamic', got {self.remasking!r}")
+        if not 0.0 <= self.confidence_threshold <= 1.0:
+            raise ValueError("block_decode.confidence_threshold must lie in "
+                             f"[0, 1], got {self.confidence_threshold}")
+
+    def transfer_schedule(self, block: int):
+        """``num_transfer_tokens(block, denoising_steps)``: how many masked
+        positions each denoise pass of a block fills, as a tuple."""
+        steps = self.denoising_steps
+        if steps > block:
+            raise ValueError(f"block_decode.denoising_steps={steps} exceeds "
+                             f"the block length {block}: a pass would fill "
+                             "nothing")
+        return tuple(block // steps + (i < block % steps)
+                     for i in range(steps))
+
+
+@dataclass
 class RaggedInferenceEngineConfig:
     state_manager: DSStateManagerConfig = field(default_factory=DSStateManagerConfig)
     kv_cache: KVCacheSizingConfig = field(default_factory=KVCacheSizingConfig)
@@ -577,6 +628,7 @@ class RaggedInferenceEngineConfig:
     compile: CompileConfig = field(default_factory=CompileConfig)
     serving: ServingConfig = field(default_factory=ServingConfig)
     spec_decode: SpecDecodeConfig = field(default_factory=SpecDecodeConfig)
+    block_decode: BlockDecodeConfig = field(default_factory=BlockDecodeConfig)
     lora: LoraConfig = field(default_factory=LoraConfig)
     attention: AttentionConfig = field(default_factory=AttentionConfig)
     tensor_parallel: int = 1
@@ -610,13 +662,16 @@ class RaggedInferenceEngineConfig:
             sv = ServingConfig(**sv) if isinstance(sv, dict) else sv
             sd = d.pop("spec_decode", {})
             sd = SpecDecodeConfig(**sd) if isinstance(sd, dict) else sd
+            bd = d.pop("block_decode", {})
+            bd = BlockDecodeConfig(**bd) if isinstance(bd, dict) else bd
             lr = d.pop("lora", {})
             lr = LoraConfig(**lr) if isinstance(lr, dict) else lr
             at = d.pop("attention", {})
             at = AttentionConfig(**at) if isinstance(at, dict) else at
             cfg = cls(state_manager=sm, kv_cache=kv, quantization=qz,
                       kv_quant=kq, prefix_cache=pc, compile=co, serving=sv,
-                      spec_decode=sd, lora=lr, attention=at, **d)
+                      spec_decode=sd, block_decode=bd, lora=lr, attention=at,
+                      **d)
         if cfg.state_manager.chunk_budget <= 0:
             raise ValueError("max_ragged_batch_size must exceed max_ragged_sequence_count")
         return cfg

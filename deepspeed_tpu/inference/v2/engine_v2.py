@@ -37,7 +37,8 @@ from deepspeed_tpu.comm.mesh import (TENSOR_AXIS, MeshTopology, build_topology,
                                      set_topology)
 from deepspeed_tpu.config import MeshConfig
 from deepspeed_tpu.inference.v2.adapters import adapt_model
-from deepspeed_tpu.inference.v2.attention import (INDEX_POOL_MSG,
+from deepspeed_tpu.inference.v2.attention import (BLOCK_DIFFUSION_MSG,
+                                                  INDEX_POOL_MSG,
                                                   STATE_SNAPSHOT_MSG,
                                                   AttentionKernelSpec)
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
@@ -47,8 +48,8 @@ from deepspeed_tpu.inference.v2.model_spec import (
 from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache, KVCacheConfig
 from deepspeed_tpu.inference.v2.ragged_model import (
-    PAGED_PASS_KEYS, PREFILL_PASS_KEYS, STATE_PASS_KEYS, build_decode_step,
-    build_prefill_forward, build_ragged_forward, build_verify_step,
+    PAGED_PASS_KEYS, PREFILL_PASS_KEYS, STATE_PASS_KEYS, build_block_step,
+    build_decode_step, build_prefill_forward, build_ragged_forward, build_verify_step,
     pass_held_rows_bound, quantize_weights_int4, quantize_weights_int8)
 from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
 from deepspeed_tpu.monitor.trace import install_from_env as _trace_from_env
@@ -353,6 +354,20 @@ class InferenceEngineV2:
         self.scheduler = DynamicSplitFuseScheduler(sm, self.kv, self.allocator,
                                                    prefix_cache=self.prefix_cache)
         self.scheduler.pageless = pageless
+        # generation by diffusion over blocks (``adapt_sdar``): the scheduler
+        # cuts prompts at multiples of the block, ``decode_pipeline`` is the
+        # block pipeline, and the schedule of a block's denoise passes is the
+        # configuration's (``block_decode``)
+        self.block_schedule: Tuple[int, ...] = ()
+        if self.spec.causal_block > 1:
+            self.scheduler.causal_block = self.spec.causal_block
+            self.block_schedule = cfg.block_decode.transfer_schedule(
+                self.spec.causal_block)
+            _tracer.note("serve/block/length", self.spec.causal_block)
+            _tracer.note("serve/block/steps", len(self.block_schedule))
+            for name in ("passes", "row_passes", "commit_row_passes",
+                         "tokens_committed", "overhang_dropped"):
+                _tracer.bump(f"serve/block/{name}", 0.0)
         # the recurrent-state pools of a model with state-space layers: one
         # slot per tracked sequence (+ the dump slot), riding with the pages
         # as ONE donated pytree through every program
@@ -453,6 +468,8 @@ class InferenceEngineV2:
         # compiled verify-step programs (spec/pipeline.py), keyed by
         # (bucket, k) — the speculation grid warmup() pre-compiles
         self._verify_progs: LRUCache = LRUCache(maxsize=16)
+        # compiled block-step programs (blocks/pipeline.py), keyed by bucket
+        self._block_progs: LRUCache = LRUCache(maxsize=16)
         self._spec_warned_sampling = False
         # KV page host round-trip programs (gather, scatter) — the serving
         # frontend's preempt-offload path (serving/kv_offload.py); built
@@ -831,6 +848,15 @@ class InferenceEngineV2:
         return jax.device_put(np.zeros((bucket,), np.int32),
                               self.topology.replicated())
 
+    def _zero_block(self, bucket: int):
+        """``[bucket, causal_block]`` token ids of zeros committed to the
+        engine's mesh: what a run's first block step is handed where the
+        pass before's blocks would be (every live row's block is then the
+        host's; see ``_scratch_step_args`` for why committed)."""
+        return jax.device_put(
+            np.zeros((bucket, self.spec.causal_block), np.int32),
+            self.topology.replicated())
+
     def _decode_step_prog(self, bucket: int, do_sample: bool, top_k: int,
                           rb: int = 0, sp: Optional[int] = None):
         """The fused single-step decode program (forward + on-device sampling,
@@ -1013,6 +1039,27 @@ class InferenceEngineV2:
         return self._verify_progs.get_or_create(
             (bucket, int(k), int(rb), sp), _build)
 
+    def _block_step_prog(self, bucket: int):
+        """The block step (``ragged_model.build_block_step``) for one bucket
+        — the BlockDecodePipeline's hot program, ``jit_serve_block_step`` in
+        a device trace. LRU-cached; warmup() pre-compiles the grid."""
+        def _build():
+            self.compiles += 1
+            return _program(
+                build_block_step(self.spec, mesh=self.topology.mesh),
+                "serve_block_step", donate_argnums=(1,))
+
+        return self._block_progs.get_or_create(int(bucket), _build)
+
+    def block_reserve_tokens(self, n_passes: int) -> int:
+        """KV tokens a run of ``n_passes`` block steps reserves a row up
+        front: a block takes a denoise pass and a commit pass at the least,
+        so a row commits at most ``n // 2 + 1`` blocks in it, and writes one
+        block more past its last commit; run-end ``rollback_reserved``
+        returns what it did not reach. The frontend's funding
+        (``admission.slice_tokens``) is this number."""
+        return self.spec.causal_block * (int(n_passes) // 2 + 2)
+
     def decode_pipeline(self, uids: Sequence[int], do_sample: bool = False,
                         temperature: float = 1.0, top_k: int = 0):
         """The steady-state decode pipeline over ``uids`` (all must be in
@@ -1026,7 +1073,20 @@ class InferenceEngineV2:
         per-step advance; callers branch their ``on_tokens`` shape on
         ``pipe.spec``). Speculation is greedy-only for now: ``do_sample``
         cleanly bypasses it with a one-time warning rather than silently
-        degrading sampled streams."""
+        degrading sampled streams.
+
+        A model that generates by diffusion over blocks
+        (``spec.causal_block`` > 1) gets the ``blocks.BlockDecodePipeline``:
+        there is no other way to generate from it. Greedy only for now."""
+        if self.spec.causal_block > 1:
+            if do_sample:
+                raise NotImplementedError(
+                    "sampling is not wired for generation by diffusion over "
+                    "blocks: a denoise pass fills positions by the greedy "
+                    "token's confidence (do_sample=True would need a sampled "
+                    "token and its probability a position)")
+            from deepspeed_tpu.inference.v2.blocks import BlockDecodePipeline
+            return BlockDecodePipeline(self, uids)
         if self.config.spec_decode.enabled:
             if do_sample:
                 if not self._spec_warned_sampling:
@@ -1149,9 +1209,24 @@ class InferenceEngineV2:
         with stage("passes"):
             self._warm_passes()
         mb = self.scheduler.max_blocks
-        with stage("decode_grid"):
-            for sp in attn_rungs:
+        if self.spec.causal_block > 1:
+            # generation by blocks: the block step over the decode buckets is
+            # the whole decode grid (no one-token step, no sampler: a pass
+            # chooses its tokens itself)
+            self._block_progs.maxsize = max(self._block_progs.maxsize,
+                                            len(grid) + 2)
+            with stage("block_grid"):
                 for b in grid:
+                    new_ids, *_, new_kv = self._block_step_prog(b)(
+                        self.weights, self.kv.kv,
+                        *self._scratch_block_args(b, mb))
+                    self.kv.update(new_kv)
+                    jax.block_until_ready(new_ids)
+        # (nothing of the one-token grids runs for that family)
+        step_grid = [] if self.spec.causal_block > 1 else grid
+        with family("decode_grid", step_grid):
+            for sp in attn_rungs:
+                for b in step_grid:
                     prog = self._decode_step_prog(b, False, 0, sp=sp)
                     args = self._scratch_step_args(b, mb)
                     nxt, _logits, new_kv = prog(self.weights, self.kv.kv,
@@ -1214,8 +1289,8 @@ class InferenceEngineV2:
         src_rows = {sm.num_chunk_slots, sm.max_ragged_sequence_count} | set(grid)
         # (the logits source committed to the mesh, as a program's output is:
         # see _scratch_step_args)
-        with stage("sampler"):
-            for nr in src_rows:
+        with family("sampler", step_grid):
+            for nr in src_rows if step_grid else ():
                 logits = jax.device_put(jnp.zeros((nr, V), jnp.float32),
                                         self.topology.replicated())
                 for b in grid:
@@ -1268,6 +1343,19 @@ class InferenceEngineV2:
             return ()
         pt = np.full((bucket, rb), self.lora.pool.zero_page, np.int32)
         return (self.lora.pool.pool, jnp.asarray(pt))
+
+    def _scratch_block_args(self, bucket: int, max_blocks: int):
+        """All-pad-row inputs for a block-step program: every row the inert
+        scratch-page fake sequence at context 0, no mask in its block, nothing
+        to take. ``block_ids`` is committed to the engine's mesh, as the row
+        traffic hands a pass is (the pass before's output; see
+        ``_scratch_step_args``)."""
+        B = self.spec.causal_block
+        ids = self._zero_block(bucket)
+        zeros = np.zeros((bucket,), np.int32)
+        bt = np.full((bucket, max_blocks), self.scratch_block, np.int32)
+        return (ids, np.zeros((bucket, B), np.int32), zeros, zeros, bt, zeros,
+                np.float32(np.inf))
 
     def _scratch_verify_args(self, bucket: int, k: int, max_blocks: int):
         """All-pad-row inputs for a verify-step program (spec decode
@@ -1351,7 +1439,10 @@ class InferenceEngineV2:
         not where a selection keeps fewer tokens than the pass can hold of
         one sequence: the packed program selects nothing."""
         sm = self.config.state_manager
-        return not self.spec.alibi and (
+        # (nor under the block rule of generation by diffusion over blocks,
+        # which only the paged chunk kernel knows: prompts are a small share
+        # of such a model's passes)
+        return not self.spec.alibi and self.spec.causal_block == 1 and (
             not self.index or sm.num_chunk_slots * sm.chunk_slot_size
             <= self.index["topk"])
 
@@ -1576,6 +1667,12 @@ class InferenceEngineV2:
             raise NotImplementedError(INDEX_POOL_MSG.format(
                 what=what + " (handing a sequence's pages to another engine)"))
 
+    def _refuse_beside_blocks(self, what: str) -> None:
+        if self.spec.causal_block > 1:
+            raise NotImplementedError(BLOCK_DIFFUSION_MSG.format(
+                what=what + " (handing a sequence's pages to another engine: "
+                "its open block is the pipeline's)"))
+
     def export_kv(self, uid: int) -> Tuple[np.ndarray, np.ndarray]:
         """``(pages, logits)``: the whole logical KV of a fully-prefilled
         sequence fetched to host in one bucketed gather, plus its last
@@ -1589,6 +1686,7 @@ class InferenceEngineV2:
         prefill replica stays warm for the next matching prompt."""
         uid = int(uid)
         self._refuse_beside_index("export_kv")
+        self._refuse_beside_blocks("export_kv")
         if self.state_config is not None:
             raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
                 what="export_kv (handing a sequence's pages to another "
@@ -1617,6 +1715,7 @@ class InferenceEngineV2:
         admit it directly. Returns the allocated block ids."""
         uid = int(uid)
         self._refuse_beside_index("import_kv")
+        self._refuse_beside_blocks("import_kv")
         if self.state_config is not None:
             raise NotImplementedError(STATE_SNAPSHOT_MSG.format(
                 what="import_kv (adopting a sequence whose pages were "
@@ -1758,9 +1857,16 @@ class InferenceEngineV2:
         if not self.can_schedule(uids, [len(p) for p in prompts]):
             raise RuntimeError("cannot schedule: insufficient KV blocks or "
                                "sequence slots")
+        by_blocks = self.spec.causal_block > 1
+        if by_blocks:       # (refuses sampling before anything is scheduled)
+            pipe = self.decode_pipeline((), do_sample=do_sample)
         self._put_nofetch(uids, [np.asarray(p, np.int32) for p in prompts])
-        pipe = self.decode_pipeline(uids, do_sample=do_sample,
-                                    temperature=temperature, top_k=top_k)
+        if by_blocks:
+            # (the block pipeline cuts a row's last block at its budget)
+            pipe.admit(uids, budgets=[max_new_tokens] * len(uids))
+        else:
+            pipe = self.decode_pipeline(uids, do_sample=do_sample,
+                                        temperature=temperature, top_k=top_k)
         is_spec = getattr(pipe, "spec", False)
         live = set(uids)
         budget = {u: max_new_tokens for u in uids}
@@ -1774,7 +1880,7 @@ class InferenceEngineV2:
                 # plain steps one token. Tokens past the budget (a spec
                 # step's in-step overshoot) are discarded — their KV is
                 # stale past the flush below, never read.
-                for t in (row[i] if is_spec else row[i:i + 1]):
+                for t in (row[i] if pipe.token_batches else row[i:i + 1]):
                     t = int(t)
                     outs[idx_of[u]].append(t)
                     budget[u] -= 1
@@ -1817,6 +1923,18 @@ class InferenceEngineV2:
                     pipe = DecodePipeline(self, uids_left)
                     is_spec = False
                     continue
+            elif by_blocks:
+                # passes, not tokens: clamp the run to the rows' max_context
+                # headroom (a run reserves ``block_reserve_tokens`` up front)
+                n = steps
+                room = min(max_ctx - self.scheduler.seqs[u].seen_tokens
+                           for u in pipe.uids)
+                while n >= 1 and self.block_reserve_tokens(n) > room:
+                    n -= 1
+                if n < 1:
+                    raise RuntimeError(
+                        "max_context leaves no room for a block step's "
+                        "reservation past the longest live sequence")
             else:
                 n = min(steps, max(budget[u] for u in pipe.uids))
             before = set(pipe.uids)

@@ -272,6 +272,13 @@ class RaggedModelSpec:
     # LayerNorm right after the embedding
     alibi: bool = False
     embed_norm: bool = False
+    # generation by diffusion over blocks (sdar): attention is causal by
+    # BLOCKS of ``causal_block`` positions (a power of two) — key s is visible
+    # to query t iff s // B <= t // B, for prompt rows and block rows alike —
+    # and ``mask_token_id`` is the token a not-yet-denoised position of the
+    # current block holds. 1 / None: causal by position, one token a step
+    causal_block: int = 1
+    mask_token_id: Optional[int] = None
     dtype: Any = jnp.bfloat16
 
 

@@ -100,6 +100,16 @@ class DecodePipeline:
     not a bug.
     """
 
+    #: The pipelines' contract with ``on_tokens(step, uids, row)``, written
+    #: once (``SpecDecodePipeline`` and ``BlockDecodePipeline`` set it True):
+    #: False — ``row`` is an int32 array, ONE token a live row a step; True —
+    #: ``row`` is a list of int32 arrays, row i's token BATCH of this step
+    #: (an accepted draft prefix and its bonus token; a committed block; an
+    #: empty array where the step gave the row nothing), every token of
+    #: which became host-visible at once. Callers (``engine.generate``, the
+    #: serving frontend's ``_on_tokens``) branch on it and on nothing else.
+    token_batches = False
+
     def __init__(self, engine, uids: Sequence[int], do_sample: bool = False,
                  temperature: float = 1.0, top_k: int = 0):
         self.engine = engine

@@ -48,6 +48,11 @@ class DSSequenceDescriptor:
     # slot of the recurrent-state pool (ragged/state_pool.py) this sequence
     # holds from admission to flush; -1 for a model with no such layers
     state_slot: int = -1
+    # generation by diffusion over blocks (scheduler.causal_block B > 1): the
+    # last ``P mod B`` prompt tokens, which are NOT prefilled — they open the
+    # first block the block pipeline denoises (blocks/pipeline.py)
+    block_open: np.ndarray = field(
+        default_factory=lambda: np.zeros((0,), np.int32))
 
     @property
     def cur_allocated_blocks(self) -> int:

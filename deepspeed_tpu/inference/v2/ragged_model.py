@@ -46,7 +46,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.attention import (STATE_SNAPSHOT_MSG,
+from deepspeed_tpu.inference.v2.attention import (BLOCK_DIFFUSION_MSG,
+                                                  STATE_SNAPSHOT_MSG,
                                                   AttentionKernelSpec)
 from deepspeed_tpu.inference.v2.model_spec import (
     RaggedModelSpec, _pool_index, index_width, latent_width, layer_units,
@@ -1917,7 +1918,9 @@ def build_ragged_forward(spec: RaggedModelSpec,
                          n_splits: int = 1) -> Callable:
     """Returns ``fwd(weights, kv_pages, batch) ->
     (chunk_logits [NC, V], decode_logits [S, V], new_kv)`` where
-    ``chunk_logits[j]`` holds the logits after slot j's last token — and,
+    ``chunk_logits[j]`` holds the logits after slot j's last token
+    (``decode_logits`` is ``[0, V]`` for a model that generates by blocks,
+    whose passes hold no decode row) — and,
     where the model holds a share of its experts that gives the pass a bound
     (:func:`pass_held_rows_bound`), a fourth result: the int32 count of the
     turns its MoE layers took past their first (:func:`_stream_turns`).
@@ -2008,6 +2011,14 @@ def build_ragged_forward(spec: RaggedModelSpec,
         # the reference also gathers the needed rows before the unembed GEMM)
         last_rows = (jnp.arange(NC) * Cs
                      + jnp.maximum(b["chunk_ntok"] - 1, 0))    # [NC]
+        if spec.causal_block > 1:
+            # a model that generates by blocks has no decode row in a pass
+            # (the block step is its only decode): no logits of the S idle
+            # rows — 78 MB of float32 a pass at 128 rows of 151,936, held
+            # from the moment a pass is ENQUEUED, and a burst of prompts
+            # enqueues a pass every 2,048 tokens (PERF.md, PR 61)
+            logits = _unembed(spec, weights, x[last_rows])
+            return (logits, logits[:0], new_kv) + turns
         xs = jnp.concatenate([x[last_rows], x[CT:]], axis=0)   # [NC + S, hid]
         logits = _unembed(spec, weights, xs)
         return (logits[:NC], logits[NC:], new_kv) + turns
@@ -2519,20 +2530,14 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
             assert not lora_args, "lora operands on a non-LoRA program"
             lora_ops = None
         L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
-        MB = block_tables.shape[1]
         kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)
         r8 = _scale_tile_rows(Hkv, bs) if kvq else 0
         sc0 = kv_sc.reshape(L * NB * r8 * 128) if kvq else None
         tokens = jnp.concatenate([ids[:, None], draft], axis=1)    # [S, K1]
         positions = positions0[:, None] + jnp.arange(K1, dtype=jnp.int32)[None]
         pos_flat = positions.reshape(-1)
-        # flat pool write destinations for every row: the run's reservation
-        # covers positions0 + K1, so the logical page index is always inside
-        # the table (pad rows' all-scratch tables clamp to the scratch page)
-        page = jnp.take_along_axis(block_tables,
-                                   jnp.minimum(positions // bs, MB - 1),
-                                   axis=1)                          # [S, K1]
-        dest = (page * bs + positions % bs).reshape(-1)
+        # the run's reservation covers positions0 + K1
+        dest = _rows_dest(block_tables, positions, bs)
 
         x = _embed_in(spec, weights, tokens.reshape(-1), pos_flat)
 
@@ -2594,6 +2599,20 @@ def build_verify_step(spec: RaggedModelSpec, k: int, mesh=None,
     return fwd
 
 
+def _rows_dest(block_tables, positions, bs):
+    """Flat pool write destinations (page * bs + slot, before the layer's
+    offset: :func:`_layer_dest`) of the rows at ``positions`` ``[S, R]`` of
+    sequences with ``block_tables`` ``[S, MB]`` — the verify step's and the
+    block step's. The caller's reservation covers the positions, so the
+    logical page index is always inside the table (pad rows' all-scratch
+    tables clamp to the scratch page)."""
+    MB = block_tables.shape[1]
+    page = jnp.take_along_axis(block_tables,
+                               jnp.minimum(positions // bs, MB - 1),
+                               axis=1)                              # [S, R]
+    return (page * bs + positions % bs).reshape(-1)
+
+
 def _greedy_accept(logits, draft, n_draft):
     """The verify step's accept rule on its logits ``[S, k + 1, V]``:
     ``(accept_row [2, S], next_ids [S], final_logits [S, V])``. The SAME
@@ -2610,3 +2629,133 @@ def _greedy_accept(logits, draft, n_draft):
         logits, accept[:, None, None], axis=1)[:, 0]               # [S, V]
     accept_row = jnp.stack([accept, next_ids]).astype(jnp.int32)
     return accept_row, next_ids, final_logits
+
+
+def build_block_step(spec: RaggedModelSpec, mesh=None, tp: int = 1) -> Callable:
+    """The block step of a model that generates by diffusion over blocks
+    (``spec.causal_block`` B > 1, ``spec.mask_token_id`` m; ``inference/v2/
+    blocks/``; docs/SERVING.md "Block-diffusion generation"): ONE pass over
+    the current block of every live sequence, whatever phase each is in.
+
+    Each sequence contributes its block's B rows at positions ``ctx0 .. ctx0
+    + B - 1`` — tokens already chosen and mask tokens side by side. Every
+    layer scatters the B rows' K/V into the pages there (the verify step's
+    flat scatter: write-then-attend) and attends with the batched chunk
+    kernel under the BLOCK rule (``AttentionKernelSpec.chunk`` binds
+    ``causal_block``): every row of the block sees the whole cached context
+    and all B rows of its own block. The logits at a row score the token AT
+    that row (no shift). After the head, ON THE DEVICE: at each still-masked
+    position ``x0 = argmax`` (the mask token itself is never chosen) and
+    ``conf = max softmax`` in float32; of a row's masked positions those
+    with ``conf > threshold`` take their ``x0`` if they are at least
+    ``n_take`` of them, else the ``n_take`` of highest ``conf`` (ties to the
+    lower position) — the static schedule hands ``threshold = inf``, the
+    dynamic rule a finite one, the same program. ``n_take = 0`` takes
+    nothing: a pad row, or a row at its COMMIT — its block holds no mask, so
+    this pass's write IS the final K/V of the block (a denoise pass's rows
+    saw masks beside them and are overwritten), and the host advances it by
+    B and hands it the next block.
+
+    The block a row runs on is ``block_ids`` (device-resident: the pass
+    before's ``new_ids``) or, where ``fresh`` is set, ``fresh_ids`` (the
+    host's: a block of masks after a commit, with the left-over prompt tokens
+    in front for a first block).
+
+    Returns ``fwd(weights, kv_pages, block_ids [S, B], fresh_ids [S, B],
+    fresh [S], n_take [S], block_tables [S, MB], ctx0 [S], threshold f32)
+    -> (new_ids [S, B] int32, masks_left [S] int32, logits [S * B, V] f32
+    (row ``i * B + r`` is block row ``r`` of sequence ``i``), new_kv)``; the
+    logits stay on the device for a check to fetch."""
+    B, mask_id = spec.causal_block, spec.mask_token_id
+    if B <= 1 or mask_id is None:
+        raise ValueError("the block step is for a model that generates by "
+                         "diffusion over blocks (causal_block > 1 and a "
+                         "mask_token_id)")
+    if num_state_layers(spec) or spec.mla is not None or spec.layer_kinds:
+        raise NotImplementedError(BLOCK_DIFFUSION_MSG.format(
+            what="a block step beside a state, latent pages or layers of "
+            "several kinds"))
+    H, Hkv, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
+    dtype = spec.dtype
+
+    def fwd(weights, kv_pages, block_ids, fresh_ids, fresh, n_take,
+            block_tables, ctx0, threshold):
+        kv_pages, kv_sc = _kv_unpack(kv_pages)
+        assert kv_sc is None, "int8 KV pages + the block step not wired"
+        S = block_ids.shape[0]
+        L, NB, bs = kv_pages.shape[0], kv_pages.shape[1], kv_pages.shape[4]
+        kvp0 = kv_pages.reshape(L * NB * 2 * Hkv * bs, D)
+        ids = jnp.where(fresh[:, None] > 0, fresh_ids, block_ids)   # [S, B]
+        positions = ctx0[:, None] + jnp.arange(B, dtype=jnp.int32)[None]
+        pos_flat = positions.reshape(-1)
+        # the run's reservation covers ctx0 + B
+        dest = _rows_dest(block_tables, positions, bs)
+
+        x = _embed_in(spec, weights, ids.reshape(-1), pos_flat)
+
+        def make_body(rs, experts, l0):
+            ak = AttentionKernelSpec(rs, mesh=mesh, tp=tp)
+
+            def layer_fn(carry, scanned):
+                x, kvp = carry
+                w, l = scanned
+
+                def attend(q, k_, v):
+                    kvp_ = _kv_page_write(kvp, k_, v,
+                                          _layer_dest(dest, l, NB, bs, L),
+                                          Hkv, bs)
+                    out = ak.chunk(q.reshape(S, B, H, D),
+                                   kvp_.reshape(L * NB, 2, Hkv, bs, D),
+                                   block_tables + l * NB, ctx0, ctx0 + B)
+                    return out.reshape(S * B, H, D), kvp_
+
+                x, (kvp,) = _transformer_layer(rs, w, x, pos_flat, attend,
+                                               experts=experts, l=l - l0)
+                return (x, kvp), None
+
+            return layer_fn
+
+        with jax.named_scope("block_step"):
+            x, kvp = _scan_layers(spec, weights["layers"], make_body,
+                                  (x, kvp0))
+            x = _norm(x, weights["final_norm"], spec.norm, spec.eps, dtype,
+                      spec.norm_plus_one)
+            # (the logits stay [S * B, V]: as [S, B, V] the chip's tiles of
+            # 8 x 128 pad B = 4 to 8 and the reshape is a copy of 311 MB at
+            # 512 rows of 151,936 — 1.6 ms of a 27 ms pass; PERF.md, PR 61)
+            logits = _unembed(spec, weights, x)
+            with jax.named_scope("denoise"):
+                new_ids, left = denoise_choice(logits, ids, n_take, mask_id,
+                                               threshold)
+        return new_ids, left, logits, kvp.reshape(L, NB, 2, Hkv, bs, D)
+
+    return fwd
+
+
+def denoise_choice(logits, ids, n_take, mask_id: int, threshold):
+    """One denoise pass's choice (:func:`build_block_step`): ``logits`` ``[S
+    * B, V]`` float32 of the blocks ``ids`` ``[S, B]``, ``n_take`` ``[S]`` ->
+    ``(new_ids [S, B], masks_left [S])``. B is small: the ``n_take`` best of
+    a row's masked positions are those fewer than ``n_take`` others come
+    before, by confidence and then by position — a few compares, no sort."""
+    S, B = ids.shape
+    V = logits.shape[-1]
+    lg = jnp.where(jnp.arange(V, dtype=jnp.int32) == mask_id, -jnp.inf,
+                   logits)
+    x0 = jnp.argmax(lg, axis=-1).astype(jnp.int32).reshape(S, B)
+    top = jnp.max(lg, axis=-1)
+    conf = (1.0 / jnp.sum(jnp.exp(lg - top[..., None]), axis=-1)
+            ).reshape(S, B)                                         # (0, 1]
+    masked = ids == mask_id
+    conf = jnp.where(masked, conf, -1.0)
+    at = jnp.arange(B, dtype=jnp.int32)
+    ci, cj = conf[:, :, None], conf[:, None, :]
+    before = (cj > ci) | ((cj == ci) & (at[None, None, :] < at[None, :, None]))
+    rank = jnp.sum(before, axis=-1).astype(jnp.int32)               # [S, B]
+    over = masked & (conf > threshold)
+    enough = jnp.sum(over, axis=-1) >= n_take
+    take = masked & jnp.where(enough[:, None], over,
+                              rank < n_take[:, None])
+    new_ids = jnp.where(take, x0, ids)
+    left = jnp.sum(masked & ~take, axis=-1).astype(jnp.int32)
+    return new_ids, left

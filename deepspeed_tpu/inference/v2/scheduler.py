@@ -73,6 +73,13 @@ class DynamicSplitFuseScheduler:
         # sequence's block table stays empty (every entry the scratch page),
         # and admission rests on the tracked-sequence count alone
         self.pageless = False
+        # set by the engine for a model that generates by diffusion over
+        # blocks (``spec.causal_block`` B > 1): a prompt's whole blocks are
+        # prefilled, in chunks cut at multiples of B (a block split over two
+        # chunk slots could not see its later half: the slot size is a
+        # multiple of B, ``validate_engine_build``), and its last ``P mod B``
+        # tokens are kept for the block pipeline (``seq.block_open``)
+        self.causal_block = 1
 
     @property
     def _dump_slot(self) -> int:
@@ -125,6 +132,11 @@ class DynamicSplitFuseScheduler:
             raise ValueError(f"sequence {uid}: {total} tokens > max_context "
                              f"{self.config.max_context}")
         new_seq = seq is None
+        if self.causal_block > 1 and not new_seq:
+            raise ValueError(
+                f"sequence {uid}: a model that generates by diffusion over "
+                "blocks takes a sequence's prompt once (its later tokens are "
+                "the block pipeline's)")
         if new_seq:
             if len(self.seqs) >= self.config.max_tracked_sequences:
                 raise RuntimeError(
@@ -149,6 +161,10 @@ class DynamicSplitFuseScheduler:
                     seq.seen_tokens = m.n_cached
                     seq.cached_tokens = m.n_cached
                     tokens = tokens[m.n_cached:]
+        if self.causal_block > 1:
+            whole = len(tokens) - len(tokens) % self.causal_block
+            seq.block_open = tokens[whole:]
+            tokens = tokens[:whole]
         seq.extend_pending(tokens)
 
     @property

@@ -129,6 +129,10 @@ class AdmissionController:
         False`` funds at the plain rate even on a spec-enabled engine
         (funding at the spec rate would over-reserve ~(k+1)x and preempt
         or shed requests the pool can actually serve)."""
+        if self.engine.spec.causal_block > 1:
+            # generation by blocks: a slice is ``decode_slice`` PASSES, and
+            # the block pipeline reserves by blocks
+            return self.engine.block_reserve_tokens(self.config.decode_slice)
         sd = self.engine.config.spec_decode
         mult = sd.k + 1 if (sd.enabled and self.config.spec) else 1
         return self.config.decode_slice * mult + 1

@@ -43,7 +43,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from deepspeed_tpu.inference.v2.attention import (INDEX_POOL_MSG,
+from deepspeed_tpu.inference.v2.attention import (BLOCK_DIFFUSION_MSG,
+                                                  INDEX_POOL_MSG,
                                                   STATE_SNAPSHOT_MSG)
 from deepspeed_tpu.inference.v2.config_v2 import ServingConfig
 from deepspeed_tpu.inference.v2.serving.admission import AdmissionController
@@ -268,6 +269,10 @@ class ServingFrontend:
                 and engine.kv.config.index_dim is not None:
             raise NotImplementedError(INDEX_POOL_MSG.format(
                 what="preemption='offload' (run 'recompute' or 'none')"))
+        if cfg.preemption == "offload" and engine.spec.causal_block > 1:
+            raise NotImplementedError(BLOCK_DIFFUSION_MSG.format(
+                what="preemption='offload' (a preempted row's open block is "
+                "the pipeline's, not the pages'; run 'recompute' or 'none')"))
         if cfg.preemption == "recompute" and getattr(engine, "lora", None) \
                 is not None:
             raise NotImplementedError(
@@ -299,7 +304,9 @@ class ServingFrontend:
             KVOffloadManager(engine, max_bytes=cfg.max_offload_bytes,
                              max_buffers=cfg.offload_buffers)
             if cfg.preemption == "offload" else None)
-        if cfg.spec:
+        if cfg.spec or engine.spec.causal_block > 1:
+            # (a model that generates by blocks has one pipeline, whatever
+            # ``spec`` says)
             self._pipe = engine.decode_pipeline(())
         else:
             # per-frontend spec opt-out (ServingConfig.spec): greedy
@@ -310,6 +317,10 @@ class ServingFrontend:
         # speculative pipeline: steps emit token BATCHES (accepted draft
         # prefix + bonus) — on_tokens shape and TBT accounting branch on it
         self._spec = bool(getattr(self._pipe, "spec", False))
+        # .. and so does a block pipeline's (a committed block, or nothing):
+        # ``DecodePipeline.token_batches`` is the contract's one home
+        self._batches = bool(self._pipe.token_batches)
+        self._blocks = engine.spec.causal_block > 1
         self._ctl: "queue.Queue" = queue.Queue()
         self._reqs: Dict[int, RequestHandle] = {}       # every non-terminal
         self._live: Dict[int, RequestHandle] = {}       # in the pipeline
@@ -927,6 +938,11 @@ class ServingFrontend:
         if self._spec:
             self._pipe.admit([req.uid], histories=[np.concatenate(
                 [req.prompt, np.asarray(req.tokens, np.int32)])])
+        elif self._blocks:
+            # the block pipeline cuts the request's last block at what it
+            # still asks for
+            self._pipe.admit([req.uid], budgets=[
+                req.max_new_tokens - len(req.tokens)])
         else:
             self._pipe.admit([req.uid])
 
@@ -1311,7 +1327,10 @@ class ServingFrontend:
         latency the SLOs are defined over — so the batch's FIRST token
         carries the inter-step gap and the rest record 0 ms TBT; tokens
         past ``max_new_tokens``/EOS within a batch are discarded (in-step
-        overshoot, flushed with the request at the run boundary)."""
+        overshoot, flushed with the request at the run boundary). A block
+        pipeline's batch is a committed block — a step that commits nothing
+        for a row hands it an empty batch — and a request's first token is
+        its first COMMIT."""
         now = time.perf_counter()
         if self._fenced:
             return list(uids)                  # down: emit nothing, stop all
@@ -1320,7 +1339,7 @@ class ServingFrontend:
             req = self._live.get(u)
             if req is None:
                 continue                       # stopped earlier this run
-            batch = row[i] if self._spec else row[i:i + 1]
+            batch = row[i] if self._batches else row[i:i + 1]
             # emission rides the handle's seal lock (uncontended except at
             # the instant a failover migration snapshots the stream): a
             # sealed handle belongs to another replica now — drop the row

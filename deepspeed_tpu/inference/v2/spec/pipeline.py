@@ -95,13 +95,15 @@ class SpecDecodePipeline:
                                  # each step emits 1..k+1 tokens per row)
         pipe.retire(done); engine.flush(done); pipe.admit(new)
 
-    ``spec`` is True (callers branch their ``on_tokens`` shape on it).
-    Greedy streams are byte-identical to the spec-off pipeline; sampling is
+    ``spec`` is True and so is ``token_batches``
+    (``DecodePipeline.token_batches``: callers branch their ``on_tokens``
+    shape on that). Greedy streams are byte-identical to the spec-off pipeline; sampling is
     not supported here (the engine routes sampled pipelines to the plain
     ``DecodePipeline`` with a one-time warning).
     """
 
     spec = True
+    token_batches = True
 
     def __init__(self, engine, uids: Sequence[int],
                  proposer: Optional[DraftProposer] = None):

@@ -8,7 +8,9 @@ qwen3_next (Qwen3-Next: Gated DeltaNet delta-rule layers beside gated
 attention, 512 small experts) and zaya (ZAYA1: compressed convolutional
 attention, a top-1 MLP router with a state) and brumby (Brumby: power
 retention in every layer, no attention over cached keys) and glm_dsa (GLM-5:
-latent attention over a learned top-k selection, an indexer a layer) — matching the reference's model coverage (module_inject/containers,
+latent attention over a learned top-k selection, an indexer a layer) and sdar
+(SDAR-30B-A3B: a Qwen3-MoE body that generates by diffusion over blocks,
+attention causal by block) — matching the reference's model coverage (module_inject/containers,
 inference/v2/model_implementations)."""
 
 from deepspeed_tpu.models.afmoe import AfmoeConfig, AfmoeForCausalLM
@@ -27,6 +29,7 @@ from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
                                              NemotronHForCausalLM)
 from deepspeed_tpu.models.qwen3_next import (Qwen3NextConfig,
                                              Qwen3NextForCausalLM)
+from deepspeed_tpu.models.sdar import SdarMoeConfig, SdarMoeForCausalLM
 from deepspeed_tpu.models.zaya import ZayaConfig, ZayaForCausalLM
 from deepspeed_tpu.models.diffusion import (DiffusionConfig,
                                             DiffusionPipeline,

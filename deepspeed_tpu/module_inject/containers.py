@@ -275,6 +275,58 @@ class MixtralPolicy(_LlamaBase):
         }}
 
 
+@register_policy
+class SdarMoePolicy(_LlamaBase):
+    """HF ``sdar_moe`` (JetLM SDAR; a Qwen3-MoE body that generates by
+    diffusion over blocks) -> models.sdar.SdarMoeForCausalLM. Per-head q/k
+    norm gains take the rotate-half -> interleaved permutation their columns
+    take; per-expert gate/up/down Linears stack into [E, ...] tensors.
+    ``block_length`` and ``mask_token_id`` are ``generate.py``'s arguments,
+    not config keys: read from the config where a checkpoint states them,
+    else the family's Chat defaults (models/sdar.py)."""
+
+    model_types = ("sdar_moe",)
+
+    def build(self, hf_config, dtype):
+        from deepspeed_tpu.models.sdar import SdarMoeConfig, SdarMoeForCausalLM
+        kw = self._cfg_kwargs(hf_config)
+        for key in ("block_length", "mask_token_id"):
+            if getattr(hf_config, key, None) is not None:
+                kw[key] = getattr(hf_config, key)
+        cfg = SdarMoeConfig(
+            head_dim=self._head_dim(hf_config),
+            moe_intermediate_size=hf_config.moe_intermediate_size,
+            num_experts=hf_config.num_experts,
+            num_experts_per_tok=hf_config.num_experts_per_tok,
+            norm_topk_prob=hf_config.norm_topk_prob,
+            decoder_sparse_step=getattr(hf_config, "decoder_sparse_step", 1),
+            mlp_only_layers=tuple(getattr(hf_config, "mlp_only_layers", ())),
+            dtype=dtype, **kw)
+        return SdarMoeForCausalLM(cfg), cfg
+
+    def _block_extra(self, hf_config, sd, l):
+        hd = self._head_dim(hf_config)
+        mlp = f"{l}.mlp"
+        stack = lambda name: np.stack([
+            linear_t(sd[f"{mlp}.experts.{e}.{name}.weight"])
+            for e in range(hf_config.num_experts)])
+        return {"mlp": {
+            "gate": {"kernel": linear_t(sd[f"{mlp}.gate.weight"])},
+            "w_gate": stack("gate_proj"), "w_up": stack("up_proj"),
+            "w_down": stack("down_proj"),
+        }, "_qk_norm": {
+            name: {"weight": rope_permute(
+                to_np(sd[f"{l}.self_attn.{name}.weight"]), 1, hd)}
+            for name in ("q_norm", "k_norm")}}
+
+    def convert(self, hf_config, sd) -> Dict[str, Any]:
+        p = super().convert(hf_config, sd)
+        for i in range(hf_config.num_hidden_layers):
+            layer = p[f"layers_{i}"]
+            layer["self_attn"].update(layer.pop("_qk_norm"))
+        return p
+
+
 # --------------------------------------------------------------------------- #
 # DecoderLM families: opt / falcon / phi / gpt_neox / gptj / bloom            #
 # --------------------------------------------------------------------------- #

@@ -1569,11 +1569,14 @@ def _chunk_scale_row(sc_buf, slot, pages, flat0, bs):
     return jnp.concatenate(pieces, axis=1) if len(pieces) > 1 else pieces[0]
 
 
-def _chunk_group_inner(k0, T, row0, bq, ctx, window):
+def _chunk_group_inner(k0, T, row0, bq, ctx, window, causal_block=1):
     """Is every key of the group ``[k0, k0 + T)`` visible to every row of
-    the q-block ``[row0, row0 + bq)`` — below its first row, inside ``ctx``
-    and inside its last row's window? Such a group builds no mask."""
-    inner = (k0 + T - 1 <= row0) & (k0 + T <= ctx)
+    the q-block ``[row0, row0 + bq)`` — below its first row (with
+    ``causal_block`` B > 1: the last row of the first row's block of B
+    positions), inside ``ctx`` and inside its last row's window? Such a
+    group builds no mask."""
+    first = row0 if causal_block == 1 else row0 | (causal_block - 1)
+    inner = (k0 + T - 1 <= first) & (k0 + T <= ctx)
     if window is not None:
         inner = inner & (k0 > row0 + bq - 1 - window)
     return inner
@@ -1583,7 +1586,7 @@ def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
                           q_sc, kv_buf, sems, acc_sc, m_sc, l_sc, *, scale,
                           block_size, block_q, pages, max_blocks, h_kv,
                           groups, window=None, sc_hbm=None, sc_buf=None,
-                          alibi=False):
+                          alibi=False, causal_block=1):
     """Grid (slot, q-block); each slot is an independent prompt chunk with
     its own block table and (q_start, ctx) row in ``meta_ref``. A step walks
     the GROUPS of ``pages`` consecutive pages that hold a key some row of the
@@ -1593,7 +1596,12 @@ def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
     attends a whole group at once. Slot padding (ctx 0) walks no group and
     writes zeros. With ``window``, row q_pos attends only k_pos > q_pos -
     window. ``sc_hbm``/``sc_buf`` (int8 pages): the pages' scale tiles,
-    applied as score-column (K) and p-column (V) multipliers.
+    applied as score-column (K) and p-column (V) multipliers. With
+    ``causal_block`` B > 1 (a power of two) a key is visible iff its BLOCK of
+    B positions is not later than the row's — ``k_pos // B <= q_pos // B``,
+    causal across blocks and two-way inside one: a row's causal limit is the
+    last position of its block, ``q_pos | (B - 1)``, which the mask, the walk's
+    last page and the no-mask test follow; B = 1 traces what it traced.
 
     The MXU takes the operands as they are stored: bfloat16 q against
     bfloat16 (or int8, widened exactly) pages is one pass to float32; any
@@ -1609,7 +1617,8 @@ def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
     ctx = jnp.minimum(meta_ref[sl, 1], max_blocks * bs)   # the table's keys
     row0 = q0 + iq * bq                      # the q-block's first position
     # keys [lo, hi) are visible to some row of this q-block
-    hi = jnp.minimum(ctx, row0 + bq)
+    hi = jnp.minimum(ctx, row0 + bq if causal_block == 1
+                     else ((row0 + bq - 1) | (causal_block - 1)) + 1)
     lo = jnp.int32(0) if window is None \
         else jnp.maximum(row0 - window + 1, 0)
     g_lo = jax.lax.div(lo, T)
@@ -1643,7 +1652,9 @@ def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
             # the mask is built as the scores lie, [R, T]
             r = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
             k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
-            mask = ((k_pos - row0) * G <= r) & (k_pos < ctx)
+            # (a block's keys all count as its first: k_pos & -B)
+            k_lim = k_pos if causal_block == 1 else k_pos & -causal_block
+            mask = ((k_lim - row0) * G <= r) & (k_pos < ctx)
             if window is not None:
                 mask = mask & (r < (k_pos - row0 + window) * G)
 
@@ -1692,7 +1703,8 @@ def _chunk_kernel_batched(bt_ref, meta_ref, q_ref, kv_hbm, o_ref,
         for cp in copies(g, slot):
             cp.wait()
         k0 = g * T
-        inner = _chunk_group_inner(k0, T, row0, bq, ctx, window)
+        inner = _chunk_group_inner(k0, T, row0, bq, ctx, window,
+                                   causal_block)
 
         @pl.when(inner)
         def _():
@@ -1730,7 +1742,8 @@ def paged_chunk_attention_batched(q: jax.Array,
                                   block_q: int = 128,
                                   window: Optional[int] = None,
                                   kv_scales: Optional[jax.Array] = None,
-                                  alibi: bool = False) -> jax.Array:
+                                  alibi: bool = False,
+                                  causal_block: int = 1) -> jax.Array:
     """Prefill flash attention for SEVERAL prompt chunks in one kernel.
 
     Multi-chunk SplitFuse: a pass that carries one chunk per pallas call
@@ -1743,9 +1756,16 @@ def paged_chunk_attention_batched(q: jax.Array,
     q_starts:     [NC] int32 — absolute position of each slot's row 0
     ctx_lens:     [NC] int32 — KV tokens visible per slot (0 = empty slot)
     kv_scales:    [NB, 2, H_kv, bs] f32 — int8 pages (dequant in-kernel)
+    causal_block: B, a power of two — causal by BLOCKS of B positions (the
+                  kernel's docstring); 1 is causal by position
 
     Returns [NC, Cs, H, D]; empty slots return zeros.
     """
+    if causal_block < 1 or causal_block & (causal_block - 1):
+        raise ValueError("causal_block must be a power of two (a row's causal "
+                         f"limit is q_pos | (B - 1)), got {causal_block}")
+    assert causal_block == 1 or window is None, \
+        "a window beside the block rule is not wired"
     NC, Cs, H, D = q.shape
     NB, two, Hkv, bs, _ = kv_pages.shape
     assert two == 2 and H % Hkv == 0
@@ -1771,7 +1791,8 @@ def paged_chunk_attention_batched(q: jax.Array,
     kernel = functools.partial(
         _chunk_kernel_batched_quant if quant else _chunk_kernel_batched,
         scale=scale, block_size=bs, block_q=bq, pages=P, max_blocks=MB,
-        h_kv=Hkv, groups=G, window=window, alibi=alibi)
+        h_kv=Hkv, groups=G, window=window, alibi=alibi,
+        causal_block=causal_block)
     in_specs = [
         pl.BlockSpec((1, bq, H, D), lambda sl, iq, bt, m: (sl, iq, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),
@@ -1955,21 +1976,23 @@ def paged_chunk_attention_batched_reference(q, kv_pages, block_tables,
                                             q_starts, ctx_lens,
                                             softmax_scale: Optional[float] = None,
                                             window: Optional[int] = None,
-                                            alibi: bool = False):
+                                            alibi: bool = False,
+                                            causal_block: int = 1):
     """jnp reference: per-slot single-chunk reference, stacked."""
     outs = []
     for sl in range(q.shape[0]):
         outs.append(paged_chunk_attention_reference(
             q[sl], kv_pages, block_tables[sl],
             q_starts[sl], ctx_lens[sl], softmax_scale, window=window,
-            alibi=alibi))
+            alibi=alibi, causal_block=causal_block))
     return jnp.stack(outs)
 
 
 def paged_chunk_attention_reference(q, kv_pages, block_table, q_start,
                                     ctx_len, softmax_scale: Optional[float] = None,
                                     window: Optional[int] = None,
-                                    alibi: bool = False):
+                                    alibi: bool = False,
+                                    causal_block: int = 1):
     """jnp reference for the chunk kernel (materialises the [C, MB*bs] scores)."""
     C, H, D = q.shape
     NB, _, Hkv, bs, _ = kv_pages.shape
@@ -1986,7 +2009,8 @@ def paged_chunk_attention_reference(q, kv_pages, block_table, q_start,
                    * jnp.arange(MB * bs, dtype=jnp.float32)[None, None, :])
     q_pos = q_start + jnp.arange(C)
     k_pos = jnp.arange(MB * bs)
-    mask = (k_pos[None, :] <= q_pos[:, None]) & (k_pos[None, :] < ctx_len)
+    k_lim = k_pos if causal_block == 1 else k_pos & -causal_block
+    mask = (k_lim[None, :] <= q_pos[:, None]) & (k_pos[None, :] < ctx_len)
     if window is not None:
         mask = mask & (k_pos[None, :] > q_pos[:, None] - window)
     sc = jnp.where(mask[None], sc, NEG_INF)

@@ -1750,3 +1750,65 @@ def test_glm5_paged_pass_holds_both_chunk_kernels_and_fits(v5e, monkeypatch):
     print(f"glm5 paged pass: temporaries {mem.temp_size_in_bytes / 2**20:.1f}"
           " MiB")
     assert mem.temp_size_in_bytes < 766 << 20, mem.temp_size_in_bytes >> 20
+
+
+def _sdar_stage(arr, pages=3448):
+    """Spec, stacked weight tree (shapes only) and the page pool of
+    SDAR-30B-A3B as the benchmark's configuration runs it: 6 of 48 layers at
+    published widths, all 128 experts of 768, the whole 151,936-row
+    vocabulary untied (8.12 GiB of weights), ``pages`` pages of 128 tokens
+    (5.05 GiB)."""
+    from deepspeed_tpu.inference.v2 import adapters
+    from deepspeed_tpu.models.sdar import SdarMoeConfig, SdarMoeForCausalLM
+    cfg = SdarMoeConfig.sdar_30b_a3b(dtype=BF16, num_hidden_layers=6)
+    model = SdarMoeForCausalLM(cfg)
+    shapes = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), I32))["params"],
+        jax.random.PRNGKey(0))
+    held = {}
+
+    def adapt(p):
+        held["spec"], w = adapters.adapt_sdar(p, cfg)
+        return w
+
+    weights = jax.tree_util.tree_map(
+        lambda a: arr(BF16, *a.shape), jax.eval_shape(adapt, shapes))
+    spec = held["spec"]
+    spec.dtype = BF16
+    return spec, weights, arr(BF16, 6, pages + 1, 2, 4, BS, 128)
+
+
+def test_sdar_block_step_holds_the_chunk_kernel_and_the_pool_in_place(
+        v5e, monkeypatch):
+    """SDAR's block step as the benchmark's cell runs it (128 rows of 4
+    block rows, 80-page tables): the attention is the batched chunk kernel
+    under the block rule and the experts' products the Pallas grouped kernel
+    (128 experts of 2048 x 768: ``moe_grouped_kernel``'s small-expert case,
+    fed by 512 rows x 8 choices); the pool is the output's buffer, and the
+    temporaries — the float32 logits of 512 rows x 151,936 apart, which are
+    an output — stay far under the configuration's 1 GiB of headroom."""
+    from deepspeed_tpu.inference.v2 import ragged_model as rm
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    arr = _on(SingleDeviceSharding(v5e[0]))
+    spec, weights, kv = _sdar_stage(arr)
+    assert (spec.causal_block, spec.mask_token_id) == (4, 151669)
+    weight_bytes = sum(math.prod(a.shape) * 2
+                       for a in jax.tree_util.tree_leaves(weights))
+    assert weight_bytes == 8722111488
+    S, MB, B = 128, 80, 4
+    compiled = jax.jit(rm.build_block_step(spec), donate_argnums=(1,)).lower(
+        weights, kv, arr(I32, S, B), arr(I32, S, B), arr(I32, S),
+        arr(I32, S), arr(I32, S, MB), arr(I32, S), arr(F32)).compile()
+    text = compiled.as_text()
+    mosaic = {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+    assert mosaic == {"paged_chunk", "moe_grouped_matmul"}, mosaic
+    pool = math.prod(kv.shape) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool > 5.0 * 2 ** 30
+    logits = S * B * 151936 * 4
+    assert mem.output_size_in_bytes - pool < logits + (1 << 20)
+    print(f"sdar block step: temporaries {mem.temp_size_in_bytes / 2**20:.1f}"
+          " MiB")
+    assert mem.temp_size_in_bytes < 320 << 20, mem.temp_size_in_bytes >> 20
