@@ -262,6 +262,7 @@ class BlockDecodePipeline:
             _tracer.bump("serve/block/tokens_committed", float(give.sum()))
             _tracer.bump("serve/block/overhang_dropped", float(
                 (np.where(commit, B - lead, 0) - give).sum()))
+            e.count_kv_rows(B, n_active * B)     # a live row's block: a run
             # the state pass j + 1 starts from (arrays rebound, never
             # written in place: pass j's dispatch may still read them)
             fresh = commit

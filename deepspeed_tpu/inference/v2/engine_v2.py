@@ -50,7 +50,8 @@ from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache, KVCacheCo
 from deepspeed_tpu.inference.v2.ragged_model import (
     PAGED_PASS_KEYS, PREFILL_PASS_KEYS, STATE_PASS_KEYS, build_block_step,
     build_decode_step, build_prefill_forward, build_ragged_forward, build_verify_step,
-    pass_held_rows_bound, quantize_weights_int4, quantize_weights_int8)
+    kv_write_run_group, pass_held_rows_bound, quantize_weights_int4,
+    quantize_weights_int8)
 from deepspeed_tpu.inference.v2.scheduler import DynamicSplitFuseScheduler
 from deepspeed_tpu.monitor.trace import install_from_env as _trace_from_env
 from deepspeed_tpu.monitor.trace import tracer as _tracer
@@ -514,6 +515,14 @@ class InferenceEngineV2:
                              compile_hook=_count_compile),
                 swap_buffers=cfg.lora.swap_buffers,
                 max_rank=cfg.lora.max_rank)
+        if self.spec.mla is None and not pageless:
+            # the K/V rows the paged passes and block steps write, always on
+            # (tracer.totals): those that go as part of a run (a chunk
+            # slot's, a block's: ``ragged_model._kv_run_write``) and those
+            # scattered one by one (a decode row; every row of a pool the
+            # run writer turns away)
+            _tracer.bump("serve/kv_write/run_rows", 0.0)
+            _tracer.bump("serve/kv_write/single_rows", 0.0)
         ring = self.scheduler.ring_pages
         if self.spec.mla is not None:
             # always-on values (tracer.totals; docs/OBSERVABILITY.md): what a
@@ -1457,6 +1466,18 @@ class InferenceEngineV2:
             self.compiles += 1
         return self._pass_prefill
 
+    def count_kv_rows(self, n: int, rows: float, singles: float = 0.0) -> None:
+        """``rows`` live rows in runs of ``n`` and ``singles`` rows of their
+        own go to the pages in the program being dispatched: counted as the
+        program writes them (``kv_write_run_group``: the same question)."""
+        if self.spec.mla is not None or self.scheduler.pageless:
+            return
+        if kv_write_run_group(self.kv.kv, n,
+                              self.config.tensor_parallel) is None:
+            rows, singles = 0.0, singles + rows
+        _tracer.bump("serve/kv_write/run_rows", float(rows))
+        _tracer.bump("serve/kv_write/single_rows", float(singles))
+
     def _run_pass(self):
         """Schedule and dispatch one pass; returns its batch (None when
         nothing was pending) so that a caller can say what the pass held."""
@@ -1487,6 +1508,8 @@ class InferenceEngineV2:
         else:
             # the paged pass of this step's split rung (rung 1: self._pass)
             _count_selecting_pass(self.index, batch)
+            self.count_kv_rows(batch.slot_size, batch.chunk_ntok.sum(),
+                               len(batch.decode_uids))
             pass_fn = self._pass_rungs.get(self._attn_rung(), self._pass)
             arrays = self._pass_arrays(arrays, PAGED_PASS_KEYS)
         chunk_logits, decode_logits, new_kv = pass_fn(

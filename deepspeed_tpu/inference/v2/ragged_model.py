@@ -12,7 +12,8 @@ the layer body, the MoE FFN, the mixers, the page writes and the builders.
 
 Pass structure (see ``ragged/ragged_batch.py``): tokens = [NC prompt-chunk
 slots | decode rows]. Each layer writes the pass's K/V into the paged cache
-(one flat scatter), then attends:
+(a chunk slot's rows as one run, ``_kv_run_write``; the decode rows by a flat
+scatter, ``_kv_page_write``), then attends:
 
   - chunk slots -> ``AttentionKernelSpec.chunk`` (flash over pages for all
     slots in one kernel, causal by absolute position)
@@ -58,7 +59,8 @@ from deepspeed_tpu.ops.pallas.gdn import gdn_chunk_scan, gdn_decode_step
 from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
                                                      plan_visits, row_tile)
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    _scale_tile_rows, kv_quantize_rows, kv_write_dequant, paged_kv_row_write)
+    KvRunPlan, _scale_tile_rows, kv_quantize_rows, kv_run_group, kv_run_plan,
+    kv_write_dequant, paged_kv_row_write, paged_kv_run_write)
 from deepspeed_tpu.ops.pallas.power_retention import (pr_chunk_scan,
                                                       pr_decode_step)
 from deepspeed_tpu.ops.pallas.ssm import (ssd_chunk_scan, ssd_decode_step,
@@ -1755,11 +1757,60 @@ def _kv_page_write(kvp, k, v, dest_tok, Hkv, bs):
     per-layer layout — pools as scan xs/ys with a per-layer dynamic-slice +
     scatter + re-stack — materialised two full pool copies per pass and was
     the single largest cost in the decode step (measured ~5 ms of a 16 ms
-    step at 0.55B/32 seqs on v5e; see docs/ROUND3_NOTES.md)."""
+    step at 0.55B/32 seqs on v5e; see docs/ROUND3_NOTES.md).
+
+    XLA prices the scatter by the index — a row a KV head, K and V, about 70
+    ns each on a v5e whatever the bytes — so rows that lie in consecutive
+    slots go through :func:`_kv_run_write` (PR 62) and this is left with
+    what is ONE row a sequence or runs on no chip's main path: a paged
+    pass's decode rows, the verify step's ``k + 1`` rows (no cell
+    speculates), every write of a pool :func:`_kv_run_write` turns away (a
+    head width that is no whole number of lane tiles) and, as
+    ``_kv_page_write_quant``, int8 pools (their scale tiles want a writer of
+    their own)."""
     T = dest_tok.shape[0]
     rows = _kv_write_rows(dest_tok, Hkv, bs)
     new = jnp.concatenate([k.reshape(T * Hkv, -1), v.reshape(T * Hkv, -1)])
     return kvp.at[rows].set(new.astype(kvp.dtype), mode="drop")
+
+
+def kv_write_run_group(kv, n: int, tp: int = 1) -> Optional[int]:
+    """The slots a step of the run writer takes for runs of ``n`` rows into
+    the pool ``kv`` (as a program is handed it: pages ``[L, NB, 2, Hkv, bs,
+    D]``, with an int8 pool's scales or a state pool beside them), or None
+    where the rows keep the row scatter: an int8 pool, a pool sharded over
+    ``tp`` chips (the scatter partitions by itself, a kernel would want a
+    shard_map), or what ``paged_attention.kv_run_group`` turns away. The
+    choice reads the pool and the mesh, nothing else — the programs ask it
+    as they trace, the engine as it counts rows (``serve/kv_write/*``)."""
+    pages, sc = _kv_unpack(_state_unpack(kv)[0])
+    return None if sc is not None or tp > 1 else kv_run_group(pages, n)
+
+
+def _run_write_plan(kv, tables, pos0, count, n: int, tp: int,
+                    aligned: bool = False) -> Optional[KvRunPlan]:
+    """The run writer's plan (``paged_attention.kv_run_plan``: made once a
+    program, outside the scan over layers) for runs of ``n`` rows —
+    ``count[r]`` of them from position ``pos0[r]`` through ``tables[r]`` (no
+    layer's offset; ``aligned``: every ``pos0`` a multiple of ``n``) — into
+    the pool ``kv``, or None where it keeps the row scatter
+    (:func:`kv_write_run_group`)."""
+    group = kv_write_run_group(kv, n, tp)
+    if group is None:
+        return None
+    bs = _kv_unpack(_state_unpack(kv)[0])[0].shape[4]
+    return kv_run_plan(tables, pos0, count, n, group, bs, aligned)
+
+
+def _kv_run_write(kvp, k, v, l, plan: KvRunPlan, pool_shape):
+    """The planned runs' rows (``k`` / ``v`` ``[R * n, Hkv, D]``) to layer
+    ``l``'s pages of the flat pool the scan carries — bit for bit what
+    :func:`_kv_page_write` leaves, by ``paged_kv_run_write``: the pool goes
+    through the kernel aliased, as through the scatter."""
+    L, NB, _, Hkv, bs, D = pool_shape
+    kv5 = paged_kv_run_write(kvp.reshape(L * NB, 2, Hkv, bs, D), k, v, plan,
+                             l * NB)
+    return kv5.reshape(-1, D)
 
 
 def _scale_dest(rows, Hkv, bs):
@@ -1939,13 +1990,17 @@ def build_ragged_forward(spec: RaggedModelSpec,
     dtype = spec.dtype
 
     def fwd(weights, kv_pages, b):
-        kv_pages, st0 = _state_unpack(kv_pages)
-        kv_pages, kv_sc = _kv_unpack(kv_pages)
-        kvq = kv_sc is not None
         NC = b["chunk_ntok"].shape[0]
         CT = b["chunk_tokens"].shape[0]
         Cs = CT // NC
         S = b["decode_tokens"].shape[0]
+        # a chunk slot's rows are ONE run from chunk_q0 through its table;
+        # the S decode rows are a row a sequence and stay a row scatter
+        rw = _run_write_plan(kv_pages, b["chunk_block_tables"], b["chunk_q0"],
+                             b["chunk_ntok"], Cs, tp)
+        kv_pages, st0 = _state_unpack(kv_pages)
+        kv_pages, kv_sc = _kv_unpack(kv_pages)
+        kvq = kv_sc is not None
         rows = None if st0 is None else _StateRows(
             b["chunk_state_slot"], b["chunk_state_mode"], b["chunk_ntok"],
             b["decode_state_slot"])
@@ -1972,13 +2027,19 @@ def build_ragged_forward(spec: RaggedModelSpec,
 
                 def attend(q, k, v):
                     dest = _layer_dest(b["kv_dest"], l, NB, bs, L)
-                    if kvq:
-                        kvp_, sc_ = _kv_page_write_quant(kvp, sc, k, v, dest,
-                                                         Hkv, bs)
-                        scales = sc_.reshape(L * NB, r8, 128)
-                    else:
-                        kvp_ = _kv_page_write(kvp, k, v, dest, Hkv, bs)
-                        sc_, scales = sc, None
+                    sc_, scales = sc, None
+                    with jax.named_scope("kv_write"):
+                        if kvq:
+                            kvp_, sc_ = _kv_page_write_quant(
+                                kvp, sc, k, v, dest, Hkv, bs)
+                            scales = sc_.reshape(L * NB, r8, 128)
+                        elif rw is None:
+                            kvp_ = _kv_page_write(kvp, k, v, dest, Hkv, bs)
+                        else:
+                            kvp_ = _kv_run_write(kvp, k[:CT], v[:CT], l, rw,
+                                                 kv_pages.shape)
+                            kvp_ = _kv_page_write(kvp_, k[CT:], v[CT:],
+                                                  dest[CT:], Hkv, bs)
                     kv_l = kvp_.reshape(L * NB, 2, Hkv, bs, D)
                     out_c = ak.chunk(q[:CT].reshape(NC, Cs, H, D), kv_l,
                                      b["chunk_block_tables"] + l * NB,
@@ -2639,8 +2700,8 @@ def build_block_step(spec: RaggedModelSpec, mesh=None, tp: int = 1) -> Callable:
 
     Each sequence contributes its block's B rows at positions ``ctx0 .. ctx0
     + B - 1`` — tokens already chosen and mask tokens side by side. Every
-    layer scatters the B rows' K/V into the pages there (the verify step's
-    flat scatter: write-then-attend) and attends with the batched chunk
+    layer writes the B rows' K/V into the pages there (a block is one run:
+    ``_kv_run_write``; write-then-attend) and attends with the batched chunk
     kernel under the BLOCK rule (``AttentionKernelSpec.chunk`` binds
     ``causal_block``): every row of the block sees the whole cached context
     and all B rows of its own block. The logits at a row score the token AT
@@ -2688,8 +2749,11 @@ def build_block_step(spec: RaggedModelSpec, mesh=None, tp: int = 1) -> Callable:
         ids = jnp.where(fresh[:, None] > 0, fresh_ids, block_ids)   # [S, B]
         positions = ctx0[:, None] + jnp.arange(B, dtype=jnp.int32)[None]
         pos_flat = positions.reshape(-1)
-        # the run's reservation covers ctx0 + B
-        dest = _rows_dest(block_tables, positions, bs)
+        # the run's reservation covers ctx0 + B. A block's rows are ONE run
+        # of B from ctx0, a multiple of B (the block rule's own premise)
+        rw = _run_write_plan(kv_pages, block_tables, ctx0,
+                             jnp.full_like(ctx0, B), B, tp, aligned=True)
+        dest = _rows_dest(block_tables, positions, bs) if rw is None else None
 
         x = _embed_in(spec, weights, ids.reshape(-1), pos_flat)
 
@@ -2701,9 +2765,14 @@ def build_block_step(spec: RaggedModelSpec, mesh=None, tp: int = 1) -> Callable:
                 w, l = scanned
 
                 def attend(q, k_, v):
-                    kvp_ = _kv_page_write(kvp, k_, v,
-                                          _layer_dest(dest, l, NB, bs, L),
-                                          Hkv, bs)
+                    with jax.named_scope("kv_write"):
+                        if rw is None:
+                            kvp_ = _kv_page_write(
+                                kvp, k_, v, _layer_dest(dest, l, NB, bs, L),
+                                Hkv, bs)
+                        else:
+                            kvp_ = _kv_run_write(kvp, k_, v, l, rw,
+                                                 kv_pages.shape)
                     out = ak.chunk(q.reshape(S, B, H, D),
                                    kvp_.reshape(L * NB, 2, Hkv, bs, D),
                                    block_tables + l * NB, ctx0, ctx0 + B)

@@ -61,7 +61,7 @@ not copy the KV.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -1141,6 +1141,227 @@ def paged_kv_row_write(kv_pages: jax.Array, side_k: jax.Array,
         out = call(block_tables.astype(jnp.int32), prefix.astype(jnp.int32),
                    *sides, *pools)
     return tuple(out) if quant else out[0]
+
+
+def kv_run_group(kv_pages: jax.Array, n: int) -> Optional[int]:
+    """The slots one grid step of :func:`paged_kv_run_write` reads, merges and
+    writes back for runs of ``n`` rows into ``kv_pages`` ``[.., 2, H_kv, bs,
+    D]`` — or None where the kernel does not apply and the caller keeps its
+    row scatter (a head width that is no whole number of lane tiles, a page
+    smaller than the pool's tile, a run that is no whole number of tiles and
+    not shorter than one either).
+
+    The unit is the pool's tile of slots (16 of a bf16 pool: Mosaic moves no
+    less of the slot dimension). A run shorter than a tile takes one tile a
+    step; a longer one takes as many tiles as a page, the run and 4 MiB of
+    float32 rows (the run with a group of its neighbours' rows either side,
+    K and V) allow: the bytes are small either way, the steps are what cost."""
+    *_, Hkv, bs, D = kv_pages.shape
+    G = 32 // jnp.dtype(kv_pages.dtype).itemsize
+    if D % 128 or bs % G or (n >= G and n % G):
+        return None
+    group = G
+    while (n >= 2 * group and bs % (2 * group) == 0
+           and 2 * Hkv * (n + 4 * group) * D * 4 <= 4 << 20):
+        group *= 2
+    return group
+
+
+def _run_steps(n: int, group: int, aligned: bool) -> int:
+    """Grid steps a run: the aligned groups of ``group`` slots that ``n``
+    rows can straddle (``aligned``: from a multiple of ``min(n, group)``)."""
+    return -(-n // group) if aligned else (n + 2 * group - 2) // group
+
+
+class KvRunPlan(NamedTuple):
+    """The grid of :func:`paged_kv_run_write`, a step an entry of each int32
+    array: the run whose rows the step lays, the page (before the layer's
+    offset) and the group of slots in it that it reads and writes back, the
+    position of that group's first slot, the positions ``[lo, hi)`` it may
+    write, and where the group's rows start in the run's rows as the kernel
+    is handed them. The arrays are padded to whole tiles of 128: a scalar
+    operand shorter than a tile that XLA keeps in VMEM reaches a kernel's
+    SMEM wrong (the block step's buckets of 2 to 64 rows halted the core;
+    PERF.md, PR 62). ``n``, ``group`` and ``steps`` are the plan's statics:
+    a run's rows, the slots a step takes, the steps in all."""
+    n: int
+    group: int
+    steps: int
+    run: jax.Array
+    page: jax.Array
+    slot: jax.Array
+    base: jax.Array
+    lo: jax.Array
+    hi: jax.Array
+    off: jax.Array
+
+
+def kv_run_plan(block_tables: jax.Array, pos0: jax.Array, count: jax.Array,
+                n: int, group: int, bs: int,
+                aligned: bool = False) -> KvRunPlan:
+    """Plan the writer's steps for runs of ``n`` rows over pages of ``bs``
+    slots: run ``r``'s ``count[r]`` rows go to positions from ``pos0[r]``
+    through ``block_tables[r]``. Run ``r``'s steps walk the groups of
+    ``group`` slots its positions fall in and stay at the last one; the
+    steps of a run that holds no row (a padding slot) repeat the step before
+    them (those in front of the first run that holds rows, its first step).
+    A repeated step fetches and writes nothing — so an empty run touches no
+    page at all, where its table's page might be another run's — and lays
+    what the step it repeats laid.
+
+    Runs ``r`` and ``r + 1`` that are one sequence's consecutive chunks
+    (``n >= group``; the same page, ``pos0`` apart by ``n``, the first one
+    whole) lie consecutively in ROW order too, so where one's last group is
+    the other's first, either step lays BOTH runs' rows (``[lo, hi)``
+    reaches over the neighbour's): the block is fetched once for the two
+    steps, and each must leave it whole. Runs shorter than a group share no
+    group with another run of the call but on a page nobody reads. The plan
+    depends on no layer: callers make it once, outside their scan."""
+    R, MB = block_tables.shape
+    steps = _run_steps(n, group, aligned)
+    tables = block_tables.astype(jnp.int32)
+    pos0, count = pos0.astype(jnp.int32), count.astype(jnp.int32)
+    g = jnp.arange(steps, dtype=jnp.int32)[None]
+    last = (pos0 + jnp.maximum(count, 1) - 1) // group
+    grp = jnp.minimum((pos0 // group)[:, None] + g, last[:, None])  # [R, st]
+    at = jnp.arange(R * steps, dtype=jnp.int32)
+    held = jnp.where(jnp.repeat(count > 0, steps), at, -1)
+    seen = jax.lax.cummax(held)
+    src = jnp.where(seen >= 0, seen, jnp.argmax(held >= 0).astype(jnp.int32))
+    run, base = src // steps, grp.reshape(-1)[src] * group
+    page_at = jnp.minimum(base // bs, MB - 1)
+    page = tables[run, page_at]
+    lo = pos0[run]
+    hi = lo + count[run]
+    if n >= group:
+        before, after = jnp.maximum(run - 1, 0), jnp.minimum(run + 1, R - 1)
+        lo = jnp.where((before < run) & (pos0[before] + n == pos0[run])
+                       & (count[before] == n)
+                       & (tables[before, page_at] == page), pos0[before], lo)
+        hi = jnp.where((after > run) & (pos0[after] == pos0[run] + n)
+                       & (count[run] == n)
+                       & (tables[after, page_at] == page),
+                       pos0[after] + count[after], hi)
+    arrays = (run, page, base % bs // group, base, lo, hi,
+              base - pos0[run] + group)
+    return KvRunPlan(n, group, R * steps, *(
+        jnp.pad(a, (0, -a.shape[0] % 128)) for a in arrays))
+
+
+def _kv_run_write_kernel(run_ref, page_ref, slot_ref, base_ref, lo_ref, hi_ref,
+                         off_ref, k_ref, v_ref, kv_in, kv_out, *, n, h_kv,
+                         group):
+    """One grid step = one group of slots of one run: the group's K and V
+    rows come in, the rows of the step's positions ``[lo, hi)`` that fall in
+    it are laid over theirs, the group goes back.
+
+    ``n >= group`` (a prompt chunk): ``k_ref`` / ``v_ref`` hold the run's
+    rows head-major with ``group`` rows of its neighbours in ROW order either
+    side, and the rows of the group are one slice of them at a dynamic
+    offset. ``n < group`` (a block of a few positions): the run's rows are
+    picked one by one, as ``_kv_row_write_kernel`` does."""
+    del run_ref, page_ref, slot_ref
+    i = pl.program_id(0)
+    base, lo, hi = base_ref[i], lo_ref[i], hi_ref[i]
+    D = kv_out.shape[-1]
+    if n < group:
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (group, D), 0)
+        rows = [jnp.where(lo + j < hi, lo + j, -1) for j in range(n)]
+        for kv, src in ((0, k_ref), (1, v_ref)):
+            for h in range(h_kv):
+                cur = kv_in[0, kv, h].astype(jnp.float32)
+                for j in range(n):
+                    cur = jnp.where(pos == rows[j],
+                                    src[0, pl.ds(j * h_kv + h, 1), :], cur)
+                kv_out[0, kv, h] = cur.astype(kv_out.dtype)
+        return
+    # a slice at a dynamic row is taken of rows ONE lane tile wide
+    pos = base + jax.lax.broadcasted_iota(jnp.int32, (group, 128), 0)
+    take = (pos >= lo) & (pos < hi)
+    off = off_ref[i]                 # in [1, n + group - 1]
+    for kv, src in ((0, k_ref), (1, v_ref)):
+        for h in range(h_kv):
+            for c in range(D // 128):
+                lanes = pl.ds(c * 128, 128)
+                new = src[0, h * (D // 128) + c, pl.ds(off, group), :]
+                cur = kv_in[0, kv, h, :, lanes].astype(jnp.float32)
+                kv_out[0, kv, h, :, lanes] = jnp.where(
+                    take, new, cur).astype(kv_out.dtype)
+
+
+def _run_rows_with_neighbours(x, R: int, n: int, group: int):
+    """``x`` ``[R * n, H_kv, D]`` -> float32 ``[R, H_kv * D / 128, n + 2 *
+    group, 128]``: run ``r``'s rows head-major — a head's lane tiles as rows
+    of their own, ``h * D / 128 + c`` — with the ``group`` rows before and
+    after it in ROW order (zeros past either end). Static slices only."""
+    T = R * n
+    length = n + 2 * group
+    pieces = -(-length // n)
+    xt = jnp.moveaxis(x.astype(jnp.float32).reshape(T, -1, 128), 1, 0)
+    xt = jnp.pad(xt, ((0, 0), (group, (pieces - 1) * n - group), (0, 0)))
+    Hkv, _, D = xt.shape
+    parts = [xt[:, j * n:j * n + T].reshape(Hkv, R, n, D)[
+        :, :, :min(n, length - j * n)] for j in range(pieces)]
+    return jnp.moveaxis(jnp.concatenate(parts, axis=2), 1, 0)
+
+
+def paged_kv_run_write(kv_pages: jax.Array, k: jax.Array, v: jax.Array,
+                       plan: KvRunPlan, page0) -> jax.Array:
+    """Write RUNS of new K/V rows into the pages, in place: rows ``r * n +
+    i`` of ``k`` / ``v``, ``i < count[r]``, are the tokens at positions
+    ``pos0[r] + i`` of the sequence with ``block_tables[r]`` — a prompt
+    chunk's rows in a paged pass, a block's in a block step — as
+    :func:`kv_run_plan` laid them out. The cost follows the groups of slots
+    the runs fill, not the rows: XLA's scatter prices every (row, head)
+    index (about 70 ns each on a v5e, whatever the bytes; PERF.md, PR 62).
+
+    kv_pages: [NB, 2, H_kv, bs, D] — ALIASED: the returned pool reuses the
+              input buffer
+    k, v:     [R * n, H_kv, D]
+    plan:     :func:`kv_run_plan` of the runs, at ``group`` =
+              :func:`kv_run_group` of the pool and ``n``
+    page0:    int32 scalar added to the plan's pages: ``l * NB`` for layer
+              ``l``'s pages of a pool of several layers
+
+    Every slot outside a run keeps what it held (a page the ring reuses may
+    hold live rows beside the run's). A run of no rows touches no page. The
+    positions are inside the table (the scheduler's reservation). Runs write
+    different slots, except on a page nobody reads (the engine's pad rows,
+    all at its scratch page, leave any of theirs there)."""
+    NB, two, Hkv, bs, D = kv_pages.shape
+    n, group, steps, *arrays = plan
+    R = k.shape[0] // n
+    assert two == 2 and k.shape == v.shape == (R * n, Hkv, D)
+    assert bs % group == 0 and (n < group or n % group == 0), (group, n, bs)
+    if n < group:
+        rows = (n * Hkv, D)
+        sides = [x.astype(jnp.float32).reshape(R, *rows) for x in (k, v)]
+        side_spec = pl.BlockSpec(
+            (1, *rows), lambda i, run, *_: (run[i], 0, 0))
+    else:
+        rows = (Hkv * D // 128, n + 2 * group, 128)
+        sides = [_run_rows_with_neighbours(x, R, n, group) for x in (k, v)]
+        side_spec = pl.BlockSpec(
+            (1, *rows), lambda i, run, *_: (run[i], 0, 0, 0))
+    pool_spec = pl.BlockSpec(
+        (1, 2, Hkv, group, D),
+        lambda i, run, page, slot, *_: (page[i], 0, 0, slot[i], 0))
+    call = pl.pallas_call(
+        functools.partial(_kv_run_write_kernel, n=n, h_kv=Hkv, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7, grid=(steps,),
+            in_specs=[side_spec, side_spec, pool_spec],
+            out_specs=pool_spec),
+        out_shape=jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
+        input_output_aliases={9: 0},
+        compiler_params=pltpu.CompilerParams(
+            # a group two runs share is handed from step to step
+            dimension_semantics=("arbitrary",)),
+        interpret=_backend.interpret(),
+    )
+    arrays[1] = plan.page + page0
+    with jax.named_scope("paged_kv_run_write"):
+        return call(*arrays, *sides, kv_pages)
 
 
 def _decode_kernel_smalld(bt_ref, cl_ref, q_ref, kv_ref, o_ref,

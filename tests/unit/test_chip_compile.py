@@ -761,37 +761,91 @@ def test_decode_step_reads_qkv_weights_in_place(rows, tree, v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.fixture(scope="module")
+def compiled_pass(v5e):
+    """``program -> compiled``: the two prefill programs of one engine at
+    Mistral-7B width (16 layers, 4 slots of 256 tokens, 32 decode rows, a
+    pool of 792 pages), each compiled once for the tests that read it (the
+    caller turns the kernels' interpreter off first)."""
+    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
+    from deepspeed_tpu.inference.v2.ragged_model import (
+        build_prefill_forward, build_ragged_forward)
+    build = {"serve_prefill_packed": build_prefill_forward,
+             "serve_paged_pass": build_ragged_forward}
+    built = {}
+
+    def get(program):
+        if program not in built:
+            L, pages, slots, slot, rows = 16, 792, 4, 256, 32
+            arr = _on(SingleDeviceSharding(v5e[0]))
+            spec, weights = _mistral_7b(arr, L)
+            # the arrays a pass is handed, as the scheduler sizes them
+            host = RaggedBatch(num_slots=slots, slot_size=slot,
+                               max_sequences=rows,
+                               max_blocks=MB).device_arrays()
+            pages_written = slots * slot // BS + slots
+            batch = {k: arr(I32, pages_written) if v is None
+                     else arr(I32, *v.shape) for k, v in host.items()}
+            built[program] = jax.jit(
+                build[program](spec), donate_argnums=(1,)).lower(
+                weights, arr(BF16, L, pages, 2, HKV, BS, D), batch).compile()
+        return built[program]
+
+    return get
+
+
 @pytest.mark.parametrize("program", ["serve_prefill_packed",
                                      "serve_paged_pass"])
-def test_prefill_programs_stage_no_projection_weights(program, v5e,
+def test_prefill_programs_stage_no_projection_weights(program, compiled_pass,
                                                       monkeypatch):
     """The two prefill programs of the same engine (4 slots of 256 tokens,
     32 decode rows) run the same layer body. Before the projections kept
     their 2-D results the packed pass staged and transposed all three
     matrices (6 ``[1, 4096, *]`` temporaries a layer) and the paged pass two
     of them (4); neither may gain one back."""
-    from deepspeed_tpu.inference.v2.ragged.ragged_batch import RaggedBatch
-    from deepspeed_tpu.inference.v2.ragged_model import (
-        build_prefill_forward, build_ragged_forward)
     monkeypatch.setattr(_backend, "interpret", lambda: False)
-    L, pages, slots, slot, rows = 16, 792, 4, 256, 32
-    arr = _on(SingleDeviceSharding(v5e[0]))
-    spec, weights = _mistral_7b(arr, L)
-    # the arrays a pass is handed, as the scheduler sizes them
-    host = RaggedBatch(num_slots=slots, slot_size=slot, max_sequences=rows,
-                       max_blocks=MB).device_arrays()
-    pages_written = slots * slot // BS + slots
-    batch = {k: arr(I32, pages_written) if v is None else arr(I32, *v.shape)
-             for k, v in host.items()}
-    build = {"serve_prefill_packed": build_prefill_forward,
-             "serve_paged_pass": build_ragged_forward}[program]
-    compiled = jax.jit(build(spec), donate_argnums=(1,)).lower(
-        weights, arr(BF16, L, pages, 2, HKV, BS, D), batch).compile()
-    instructions, params = _executed(compiled.as_text())
+    L = 16
+    instructions, params = _executed(compiled_pass(program).as_text())
     staged = _layer_matrices(instructions, whole_layers_only=True)
     assert not staged, f"a layer's projection matrix is materialised: {staged}"
     assert _stacks_read_in_place(instructions, params, L).count(
         (HID, HKV * D)) == 2
+
+
+def _copies_of_the_pool(text, pool_elems):
+    """The instructions of a compiled program that produce a value as large
+    as the page pool by copying (what a second consumer of the scan's carry
+    costs: ``_kv_page_write``'s docstring)."""
+    produced = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* (\S+?)\(")
+    return [line.strip()[:100] for line in text.splitlines()
+            for m in [produced.match(line)]
+            if m and m.group(2).startswith("copy") and math.prod(
+                int(d) for d in m.group(1).split(",")) == pool_elems]
+
+
+def test_paged_pass_writes_its_chunks_as_runs_in_place(compiled_pass,
+                                                       monkeypatch):
+    """The paged pass at Mistral-7B width as cell 1 runs it (16 layers, 4
+    slots of 256 tokens, 32 decode rows, pages of 128, a pool of 792 pages:
+    6.2 GiB). Its K/V write was one scatter index a row a KV head, K and V —
+    16,896 a layer at about 70 ns each, whatever the bytes (PERF.md, PR 62).
+    Now a kernel writes each slot's rows as one run, the pool aliased
+    through it inside the scan, and the 32 decode rows alone are scattered
+    after it: the kernel is in the program, nothing copies the pool, the
+    pool is the output's buffer and the temporaries stay small."""
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
+    L, pages = 16, 792
+    compiled = compiled_pass("serve_paged_pass")
+    text = compiled.as_text()
+    mosaic = {m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+    assert "paged_kv_run_write" in mosaic, mosaic
+    pool_elems = L * pages * 2 * HKV * BS * D
+    assert not _copies_of_the_pool(text, pool_elems)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_elems * 2
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes >> 20
 
 
 def _jamba2_3b(arr):
@@ -1803,7 +1857,13 @@ def test_sdar_block_step_holds_the_chunk_kernel_and_the_pool_in_place(
     mosaic = {m.group(1) for m in re.finditer(
         r"^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*"
         r'custom_call_target="tpu_custom_call"', text, re.M)}
-    assert mosaic == {"paged_chunk", "moe_grouped_matmul"}, mosaic
+    # (and the blocks' rows go to the pages as runs of 4: PR 62)
+    assert mosaic == {"paged_chunk", "moe_grouped_matmul",
+                      "paged_kv_run_write"}, mosaic
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and "kv_write" in line], \
+        "a row scatter is back in the block step's write"
+    assert not _copies_of_the_pool(text, math.prod(kv.shape))
     pool = math.prod(kv.shape) * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool > 5.0 * 2 ** 30

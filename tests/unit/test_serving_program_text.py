@@ -22,7 +22,14 @@ were, from 83a3dac, and how every family's decode step was by PR 45, whose
 tree took the step loop — a ``lax.scan`` of length one around the pass —
 out of that program's text, and how the K/V families' paged pass was by PR
 49, whose chunk kernel walks a slot's own pages in groups — JoyAI's paged
-pass, over latent pages, kept its hash; the packed prefill of all six is
+pass, over latent pages, kept its hash; and how jamba's and zaya's paged
+pass was by PR 62 (``--write <file> "PR 62 on 96dbd0a" serve_paged_pass``
+into a copy, the two hashes that differ taken from it): the only toy
+programs whose heads are 128 wide, so the only ones whose chunk slots' K/V
+rows go to the pages as runs (``paged_attention.paged_kv_run_write``) — the
+narrow-headed families' paged pass, JoyAI's, every decode step and every
+packed prefill kept their hashes, which is how cells 8 and 14 and every
+decode step are known to run what they ran; the packed prefill of all six is
 still the older commits'; and how PR 51 recorded the nine programs of
 ``NEWER`` and, anew, jamba's decode step and paged pass — with zaya's the
 only toy programs whose heads are 128 wide, so the only ones that hold the
